@@ -18,10 +18,12 @@ RUN apt-get update && apt-get install -y --no-install-recommends \
         g++ make \
     && rm -rf /var/lib/apt/lists/*
 
-# TPU-enabled jax; pin versions in production images.
-RUN pip install --no-cache-dir "jax[tpu]" \
+# The one installation the code is written for (horovod_tpu/compat.py and
+# chip_smoke.py assume exactly these; PR 21 ran on them on a v5e).
+RUN pip install --no-cache-dir "jax==0.9.0" "jaxlib==0.9.0" "libtpu==0.0.34" \
         -f https://storage.googleapis.com/jax-releases/libtpu_releases.html \
-    && pip install --no-cache-dir flax optax numpy pytest
+    && pip install --no-cache-dir "flax==0.12.3" "optax==0.2.6" \
+        "orbax-checkpoint==0.11.32" "numpy==2.0.2" pytest
 
 WORKDIR /opt/horovod_tpu
 COPY . .

@@ -467,8 +467,9 @@ def project_pod_efficiency(step_ms: float | None = None,
     chip — the measured eager-plane cross-byte ratio is the same effect.
     """
     if step_ms is None:
-        # measured single-chip rate from bench.py (BENCH_r03: 2489 img/s,
-        # batch 128)
+        # single-chip rate bench.py recorded on an earlier installation
+        # (2489 img/s at batch 128, before PR 1; ROADMAP.md) — not measured
+        # on this one
         step_ms = 128.0 / 2489.0 * 1e3
     t_step = step_ms / 1e3
     rows = []

@@ -68,7 +68,10 @@ def main():
 
     model = TransformerLM(vocab=256, dim=args.dim, heads=8,
                           layers=args.layers, sp_axis="sp",
-                          attention=args.attention)
+                          attention=args.attention,
+                          # the virtual CPU mesh was asked for by flag, and
+                          # with it the Pallas interpreter for the kernels
+                          flash_interpret=bool(args.virtual_devices))
     tokens = jnp.asarray(
         np.random.default_rng(0).integers(0, 256, size=(2 * args.dp, args.seq_len)),
         jnp.int32)

@@ -23,7 +23,7 @@ import os
 import threading
 from typing import Optional, Sequence
 
-from .config import Config, _env_bool, enable_latency_hiding_scheduler
+from .config import Config
 from .topology import Topology, detect, num_devices, num_local_devices
 from ..utils.logging import log
 
@@ -64,8 +64,9 @@ def _maybe_init_jax_distributed() -> None:
     HOROVOD_JAX_DISTRIBUTED=1) makes init() federate the processes so
     ``jax.devices()`` becomes the GLOBAL device list and jitted collectives
     span process boundaries (N hosts x M local chips, the pod execution
-    shape). Off by default: a single-chip box can't share its chip between
-    workers, and eager/torch-only jobs don't need a JAX backend at all.
+    shape). Off by default: eager/torch-only jobs don't need a JAX backend
+    at all. One worker per TPU HOST: several workers on one TPU host are
+    refused (:func:`_refuse_workers_sharing_tpu_host`).
     """
     if os.environ.get("HOROVOD_JAX_DISTRIBUTED") != "1":
         return
@@ -86,23 +87,17 @@ def _maybe_init_jax_distributed() -> None:
 
     if distributed_is_initialized():
         return  # re-init after shutdown(): the runtime outlives the hvd state
-    try:  # diagnostics-only guard on a private API: skip if jax moved it
-        from jax._src import xla_bridge
+    _refuse_workers_sharing_tpu_host()
+    from jax._src import xla_bridge
 
-        backend_up = xla_bridge.backends_are_initialized()
-    except Exception:  # pragma: no cover - jax internals changed
-        backend_up = False
-    if backend_up:  # pragma: no cover - misuse guard
+    if xla_bridge.backends_are_initialized():  # pragma: no cover - misuse
         raise RuntimeError(
             "hvd.init() with HOROVOD_JAX_DISTRIBUTED=1 must run before any "
             "JAX computation: the backend is already initialized, so this "
             "process can no longer join the multi-process runtime.")
     # Cross-process collectives on the CPU backend (virtual-device testing,
     # SURVEY.md §4) ride gloo; a no-op for the TPU backend, which uses ICI/DCN.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - older jaxlib without the option
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coord,
         num_processes=int(os.environ["HOROVOD_SIZE"]),
@@ -116,6 +111,39 @@ def _maybe_init_jax_distributed() -> None:
         f"{os.environ['HOROVOD_RANK']}/{os.environ['HOROVOD_SIZE']}")
 
 
+def _refuse_workers_sharing_tpu_host() -> None:
+    """Several workers on ONE TPU host cannot each take chips: a chip belongs
+    to one process at a time, and nothing in ``runner/`` pins chips per
+    process, so every worker's libtpu claims every local chip. Observed on a
+    four-chip v5e host (PR 21): with ``hvdrun -np 2 --jax-distributed`` the
+    second worker loses the chips, drops to the CPU backend and both hang
+    for minutes in the distributed runtime's barriers before dying. So the
+    shape is refused up front, with the one that works named. Workers pinned
+    to the CPU platform (the virtual-device test path) and hosts without TPU
+    chips are not affected; an operator who exports libtpu's own
+    ``TPU_VISIBLE_CHIPS`` per worker has taken the pinning on themselves."""
+    if int(os.environ.get("HOROVOD_LOCAL_SIZE") or 1) <= 1:
+        return
+    if os.environ.get("TPU_VISIBLE_CHIPS"):
+        return
+    import jax
+    from jax._src import hardware_utils
+
+    if (jax.config.jax_platforms or "").split(",")[0] == "cpu":
+        return
+    chips, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    if chips == 0:
+        return
+    raise RuntimeError(
+        f"HOROVOD_JAX_DISTRIBUTED=1 with {os.environ['HOROVOD_LOCAL_SIZE']} "
+        f"workers on one TPU host ({chips} chips): a chip belongs to one "
+        "process at a time and the launcher pins no chips per worker, so the "
+        "workers would fight for the same chips and hang. On one host run "
+        "ONE process — it drives every local chip by SPMD (plain `python "
+        "train.py`, or `hvdrun -np 1`); use --jax-distributed with one "
+        "worker per HOST (`hvdrun -H host1:1,host2:1`).")
+
+
 def init(comm: Optional[Sequence[int]] = None) -> None:
     """Initialize. ``comm`` may be a list of ranks forming a subset world
     (reference horovod_init with ranks[], operations.cc:2415; mpi4py comms have
@@ -123,10 +151,6 @@ def init(comm: Optional[Sequence[int]] = None) -> None:
     with _state._lock:
         if _state.initialized:
             return
-        if _env_bool("HOROVOD_LATENCY_HIDING"):
-            # Must happen before anything touches the XLA backend (detect()
-            # below counts devices): jax snapshots XLA_FLAGS at first use.
-            enable_latency_hiding_scheduler()
         _maybe_init_jax_distributed()
         topo = detect()
         if comm is not None:

@@ -96,38 +96,6 @@ def _env_stall_check_time(default: float = STALL_WARNING_TIME_S) -> float:
             pass
     return _env_float("HOROVOD_STALL_WARNING_TIME", default)
 
-# XLA compile options that let the scheduler hide collective latency behind
-# compute — the compiled-plane analog of the reference's background thread
-# starting allreduces while the backward pass still runs. Appended to
-# XLA_FLAGS (not jax.config: these are DebugOptions, which jax only reads
-# from the env) by enable_latency_hiding_scheduler() BEFORE backend init.
-LATENCY_HIDING_XLA_FLAGS = (
-    "--xla_tpu_enable_latency_hiding_scheduler=true",
-)
-
-
-def enable_latency_hiding_scheduler(env=None) -> bool:
-    """Append the latency-hiding scheduler flags to XLA_FLAGS (idempotent).
-
-    Returns True when the flags are (now) present. Must run before the XLA
-    backend initializes — jax reads XLA_FLAGS once at first device use — so
-    callers (init(), bench.py) invoke it as early as possible. Gated behind
-    HOROVOD_LATENCY_HIDING because the scheduler changes compile time and
-    schedule shape; the A/B bench measures whether it pays on a platform.
-    """
-    if env is None:
-        env = os.environ
-    flags = env.get("XLA_FLAGS", "")
-    added = False
-    for f in LATENCY_HIDING_XLA_FLAGS:
-        if f.split("=")[0] not in flags:
-            flags = (flags + " " + f).strip()
-            added = True
-    if added:
-        env["XLA_FLAGS"] = flags
-    return True
-
-
 def clamp_shm_bytes(v: int) -> int:
     """Mirror of the native clamp (shm_ring.h shm_ring_capacity): power of
     two in [64 KiB, 1 GiB], so config() reports the EFFECTIVE capacity."""
@@ -144,7 +112,6 @@ class Config:
 
     fusion_threshold: int = DEFAULT_FUSION_THRESHOLD      # HOROVOD_FUSION_THRESHOLD
     num_buckets: int = DEFAULT_NUM_BUCKETS                # HOROVOD_NUM_BUCKETS
-    latency_hiding: bool = False                          # HOROVOD_LATENCY_HIDING
     cycle_time_ms: float = DEFAULT_CYCLE_TIME_MS          # HOROVOD_CYCLE_TIME
     timeline: str = ""                                    # HOROVOD_TIMELINE
     timeline_mark_cycles: bool = False                    # HOROVOD_TIMELINE_MARK_CYCLES
@@ -234,7 +201,6 @@ class Config:
         cfg = cls(
             fusion_threshold=_env_int("HOROVOD_FUSION_THRESHOLD", DEFAULT_FUSION_THRESHOLD),
             num_buckets=max(1, _env_int("HOROVOD_NUM_BUCKETS", DEFAULT_NUM_BUCKETS)),
-            latency_hiding=_env_bool("HOROVOD_LATENCY_HIDING"),
             cycle_time_ms=_env_float("HOROVOD_CYCLE_TIME", DEFAULT_CYCLE_TIME_MS),
             timeline=os.environ.get("HOROVOD_TIMELINE", ""),
             timeline_mark_cycles=_env_bool("HOROVOD_TIMELINE_MARK_CYCLES"),

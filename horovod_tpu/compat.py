@@ -1,91 +1,29 @@
-"""JAX version compatibility shims.
+"""One import site for the JAX entry points the other ~20 modules (library,
+tests, examples, bench) share.
 
-One import site for APIs that moved between JAX releases, so the other ~20
-modules (library, tests, examples, bench) never spell a version check
-themselves.
-
-``shard_map``: promoted from ``jax.experimental.shard_map`` to ``jax.shard_map``
-around jax 0.6, and the replication-checking kwarg was renamed
-``check_rep`` -> ``check_vma`` in the same move. Callers here write the
-NEW spelling (``jax.shard_map`` signature with ``check_vma=``); on older
-JAX the wrapper translates the kwarg and dispatches to the experimental
-entry point.
+The code is written for the one installation there is (jax/jaxlib 0.9.0):
+``jax.shard_map`` with ``check_vma=``, ``jax.lax.axis_size``,
+``jax_num_cpu_devices`` and ``jax.distributed.is_initialized`` all exist
+there, so these are plain aliases — kept so the import sites need not move.
 """
 
 from __future__ import annotations
 
 import jax as _jax
 
-if hasattr(_jax, "shard_map"):
-    shard_map = _jax.shard_map
-    HAS_NATIVE_SHARD_MAP = True
-else:  # jax < 0.6: experimental module, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    HAS_NATIVE_SHARD_MAP = False
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _experimental_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-if hasattr(_jax.lax, "axis_size"):
-    axis_size = _jax.lax.axis_size
-else:  # jax < 0.6: psum of the literal 1 over the axis — constant-folded to
-    # the axis size inside a trace, and raises the same NameError outside
-    # one, so callers' error handling is identical on both spellings.
-
-    def axis_size(axis_name):
-        return _jax.lax.psum(1, axis_name)
+shard_map = _jax.shard_map
+axis_size = _jax.lax.axis_size
 
 
 def set_num_cpu_devices(n: int) -> None:
-    """Request ``n`` virtual CPU devices BEFORE backend init.
-
-    Newer jax has the ``jax_num_cpu_devices`` config; older releases only
-    honor the ``--xla_force_host_platform_device_count`` XLA flag. Raises
-    RuntimeError (like the config path) if a backend is already up, so
-    callers' error handling stays one code path."""
-    try:
-        _jax.config.update("jax_num_cpu_devices", n)
-        return
-    except AttributeError:  # jax < 0.5: no such config option
-        pass
-    try:
-        from jax._src import xla_bridge
-
-        if xla_bridge.backends_are_initialized():
-            raise RuntimeError(
-                "jax backend already initialized; set device count earlier")
-    except (ImportError, AttributeError):  # pragma: no cover - private API
-        pass
-    import os
-    import re
-
-    flags = os.environ.get("XLA_FLAGS", "")
-    if re.search(r"xla_force_host_platform_device_count=\d+", flags):
-        flags = re.sub(r"xla_force_host_platform_device_count=\d+",
-                       f"xla_force_host_platform_device_count={n}", flags)
-    else:
-        flags = (flags + f" --xla_force_host_platform_device_count={n}").strip()
-    os.environ["XLA_FLAGS"] = flags
+    """Request ``n`` virtual CPU devices. Raises RuntimeError if a backend
+    is already up (the option is read at backend init)."""
+    _jax.config.update("jax_num_cpu_devices", n)
 
 
 def distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` appeared after 0.4.x; older
-    releases expose the same fact as a non-None client on the distributed
-    global state."""
-    if hasattr(_jax.distributed, "is_initialized"):
-        return bool(_jax.distributed.is_initialized())
-    try:
-        from jax._src.distributed import global_state
-
-        return global_state.client is not None
-    except (ImportError, AttributeError):  # pragma: no cover - future move
-        return False
+    return bool(_jax.distributed.is_initialized())
 
 
 __all__ = ["shard_map", "axis_size", "distributed_is_initialized",
-           "set_num_cpu_devices", "HAS_NATIVE_SHARD_MAP"]
+           "set_num_cpu_devices"]

@@ -113,8 +113,8 @@ def measure_steps_per_s(run_step: Callable[[], None], warmup: int = 2,
                         sync: Optional[Callable[[], None]] = None) -> float:
     """Median-window step rate — THE timing methodology (bench.py uses this
     too): warmup for compile, chain ``iters`` dispatches per timed window
-    with ONE host sync at the window end (per-step syncs would measure RPC
-    jitter on a tunneled backend, not the step), median of ``reps`` windows.
+    with ONE host sync at the window end (per-step syncs would add a host
+    round trip to every step), median of ``reps`` windows.
 
     ``run_step`` may block itself (then omit ``sync``) or dispatch
     asynchronously with ``sync`` providing the window-end fence."""

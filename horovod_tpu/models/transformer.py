@@ -60,6 +60,10 @@ class Block(nn.Module):
     # examples/transformer_benchmark.py --sweep-blocks)
     block_q: Optional[int] = None
     block_k: Optional[int] = None
+    # True runs the flash kernels in the Pallas interpreter (the CPU tests
+    # ask for it); the default compiles them for the TPU and raises without
+    # one — never inferred from the platform.
+    flash_interpret: bool = False
 
     @nn.compact
     def __call__(self, x, positions):
@@ -103,7 +107,7 @@ class Block(nn.Module):
 
                 # positional: custom_vjp nondiff_argnums
                 attn = ring_flash_attention(q, k, v, self.sp_axis, False,
-                                            bq, bk)
+                                            bq, bk, self.flash_interpret)
             else:
                 from ..ops.ring_attention import ring_attention
 
@@ -111,7 +115,8 @@ class Block(nn.Module):
         elif self.attention == "flash":
             from ..ops.flash_attention import flash_attention
 
-            attn = flash_attention(q, k, v, block_q=bq, block_k=bk)
+            attn = flash_attention(q, k, v, block_q=bq, block_k=bk,
+                                   interpret=self.flash_interpret)
         else:
             attn = causal_attention(q, k, v)
         attn = attn.reshape(b, t, self.dim)
@@ -150,7 +155,9 @@ class TransformerLM(nn.Module):
     # "flash" runs attention through the pallas fused kernel (O(T*D) HBM
     # traffic; trains at sequence lengths where the dense schedule cannot
     # even compile — measured on v5e: seq 8192 dense OOMs the compiler,
-    # flash runs). Sequence length must tile into 128-blocks. Combined
+    # flash runs). Sequence length must tile into 128-blocks. The kernels
+    # compile for the TPU only: a flash model on a machine without one
+    # raises unless flash_interpret asks for the interpreter. Combined
     # with sp_axis it selects ring_flash_attention: ring schedule between
     # chips, fused flash blocks within each chip.
     attention: str = "dense"
@@ -168,6 +175,8 @@ class TransformerLM(nn.Module):
     # sweep per sequence length with transformer_benchmark --sweep-blocks)
     block_q: Optional[int] = None
     block_k: Optional[int] = None
+    # Pallas interpreter for the flash kernels (CPU tests); see Block.
+    flash_interpret: bool = False
     # dtype of the lm_head matmul AND the stored logits. f32 (default) is
     # the conservative choice; bf16 halves the logits pipeline's HBM
     # traffic (B*T*vocab bytes through head matmul epilogue, reshape,
@@ -195,6 +204,7 @@ class TransformerLM(nn.Module):
                 kv_heads=self.kv_heads,
                 block_q=self.block_q,
                 block_k=self.block_k,
+                flash_interpret=self.flash_interpret,
                 moe_experts=(self.moe_experts
                              if self.moe_experts > 0 and i % self.moe_every == self.moe_every - 1
                              else 0),

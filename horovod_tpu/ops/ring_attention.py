@@ -180,7 +180,8 @@ def causal_reference(q, k, v):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def ulysses_attention(q, k, v, axis_name: str, impl: str = "dense"):
+def ulysses_attention(q, k, v, axis_name: str, impl: str = "dense",
+                      interpret: bool = False):
     """All-to-all sequence parallelism (DeepSpeed-Ulysses schedule): re-shard
     [B, T/n, H, D] -> [B, T, H/n, D], causal attention on the full sequence
     with a head shard, re-shard back.
@@ -189,7 +190,8 @@ def ulysses_attention(q, k, v, axis_name: str, impl: str = "dense"):
     kernel (flash_attention.py) instead of dense einsums — after the
     all-to-all each shard holds the FULL sequence, which is exactly the
     regime the fused kernel exists for (the dense schedule materializes
-    the (T, T) logits and stops compiling around seq 8k)."""
+    the (T, T) logits and stops compiling around seq 8k); ``interpret``
+    is passed through to it (flash_attention's contract)."""
     n = axis_size(axis_name)
     h = q.shape[2]
     kvh = k.shape[2]
@@ -228,7 +230,7 @@ def ulysses_attention(q, k, v, axis_name: str, impl: str = "dense"):
     if impl == "flash":
         from .flash_attention import flash_attention
 
-        out = flash_attention(qh, kh, vh)
+        out = flash_attention(qh, kh, vh, interpret=interpret)
     else:
         scale = q.shape[-1] ** -0.5
         logits = jnp.einsum("bqhd,bkhd->bhqk", qh, kh).astype(jnp.float32) * scale

@@ -51,7 +51,6 @@ from .flash_attention import (
     NEG_INF,
     _check_blocks,
     _gqa_group,
-    _interpret_default,
     _kv_row,
     _q_row,
     _rows,
@@ -302,7 +301,7 @@ def _kpos_arr(pos, t):
 def ring_flash_attention(q, k, v, axis_name: str, zigzag: bool = False,
                          block_q: int = DEFAULT_BLOCK_Q,
                          block_k: int = DEFAULT_BLOCK_K,
-                         interpret: bool | None = None):
+                         interpret: bool = False):
     """Causal ring attention over ``axis_name`` with pallas-fused local
     blocks, trainable. q: ``(B, T_local, H, D)``; k, v: same or
     ``(B, T_local, Hkv, D)`` with ``H % Hkv == 0`` (grouped-query
@@ -310,7 +309,8 @@ def ring_flash_attention(q, k, v, axis_name: str, zigzag: bool = False,
     their gradients, so GQA cuts ICI traffic by the group factor too).
     Sequence already sharded on ``axis_name``. Same semantics as
     :func:`ring_attention.ring_attention` (including ``zigzag``), same
-    block-size contract as :func:`flash_attention.flash_attention`."""
+    block-size and ``interpret`` contract as
+    :func:`flash_attention.flash_attention`."""
     out, _ = _rf_fwd(q, k, v, axis_name, zigzag, block_q, block_k, interpret)
     return out
 
@@ -320,8 +320,6 @@ def _rf_fwd(q, k, v, axis_name, zigzag, block_q, block_k, interpret):
     my = lax.axis_index(axis_name)
     b, t, h, d = q.shape
     h, hkv, group = _gqa_group(q, k, v)
-    if interpret is None:
-        interpret = _interpret_default()
     bq, bk = _check_blocks(t, block_q, block_k, interpret)
     qr = _rows(q, b, t, h, d)
     kr, vr = (_rows(x, b, t, hkv, d) for x in (k, v))
@@ -365,8 +363,6 @@ def _rf_bwd(axis_name, zigzag, block_q, block_k, interpret, res, dout):
     my = lax.axis_index(axis_name)
     b, t, h, d = q.shape
     h, hkv, group = _gqa_group(q, k, v)
-    if interpret is None:
-        interpret = _interpret_default()
     bq, bk = _check_blocks(t, block_q, block_k, interpret)
     qr, dor = (_rows(x, b, t, h, d) for x in (q, dout))
     kr, vr = (_rows(x, b, t, hkv, d) for x in (k, v))

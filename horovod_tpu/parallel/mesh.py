@@ -14,9 +14,13 @@ communicators, we lay devices out on a named :class:`jax.sharding.Mesh`:
 - ``training_mesh``: general ``(dp, fsdp, pp, tp, sp, ep)`` builder for the
   model-parallel families layered on top of the Horovod-parity core.
 
-All builders go through ``mesh_utils.create_device_mesh`` so the ICI axis maps
-to physically adjacent chips (torus-aware ordering), which is what makes the
-``psum`` over 'ici' ride ICI instead of DCN.
+All builders go through ``mesh_utils.create_device_mesh``, so on a TPU the
+most-minor mesh axis maps to physically adjacent chips (on the four chips of
+a v5e 2x2 host that is the ring 0-1-3-2, not ``jax.devices()`` order), which
+is what makes the ``psum`` over 'ici' and the ring ``ppermute`` ride
+neighbour links. A shape that does not fit the physical topology raises —
+there is no fallback to a plain reshape. Off-TPU (the virtual CPU test mesh)
+``create_device_mesh`` itself is the plain reshape.
 """
 
 from __future__ import annotations
@@ -25,7 +29,7 @@ import math
 from typing import Sequence
 
 import jax
-import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 from ..compat import axis_size
@@ -51,9 +55,11 @@ def _devices(devices=None):
 
 def data_parallel_mesh(devices=None) -> Mesh:
     """All chips on one named axis ``'hvd'`` — rank i of the reference maps to
-    mesh position i."""
+    mesh position i (on a TPU, position i is the i-th chip of the physical
+    ring, see the module docstring)."""
     devs = _devices(devices)
-    return Mesh(np.asarray(devs), (HVD_AXIS,))
+    return Mesh(mesh_utils.create_device_mesh((len(devs),), devices=devs),
+                (HVD_AXIS,))
 
 
 def hierarchical_mesh(devices=None, ici_size: int | None = None) -> Mesh:
@@ -71,7 +77,8 @@ def hierarchical_mesh(devices=None, ici_size: int | None = None) -> Mesh:
             ici_size = math.gcd(n, ici_size) or 1
     if n % ici_size != 0:
         raise ValueError(f"device count {n} not divisible by ici_size {ici_size}")
-    arr = np.asarray(devs).reshape(n // ici_size, ici_size)
+    arr = mesh_utils.create_device_mesh((n // ici_size, ici_size),
+                                        devices=devs)
     return Mesh(arr, (DCN_AXIS, ICI_AXIS))
 
 
@@ -104,12 +111,7 @@ def training_mesh(
         sizes[sizes.index(-1)] = n // known
     if math.prod(sizes) != n:
         raise ValueError(f"mesh {dict(zip(axis_names, sizes))} needs {math.prod(sizes)} devices, have {n}")
-    try:
-        from jax.experimental import mesh_utils
-
-        arr = mesh_utils.create_device_mesh(tuple(sizes), devices=devs)
-    except Exception:
-        arr = np.asarray(devs).reshape(tuple(sizes))
+    arr = mesh_utils.create_device_mesh(tuple(sizes), devices=devs)
     return Mesh(arr, tuple(axis_names))
 
 
@@ -213,13 +215,7 @@ def sharded_mesh(batch: int | None = None, shard: int | None = None,
     three_d = want_model_axis or model != 1
     shape = (batch, shard, model) if three_d else (batch, shard)
     names = (BATCH_AXIS, SHARD_AXIS, MODEL_AXIS)[:len(shape)]
-    try:
-        from jax.experimental import mesh_utils
-
-        arr = mesh_utils.create_device_mesh(shape, devices=devs)
-    except Exception:
-        arr = np.asarray(devs).reshape(shape)
-    return Mesh(arr, names)
+    return Mesh(mesh_utils.create_device_mesh(shape, devices=devs), names)
 
 
 def mesh_rank(axis_name: str = HVD_AXIS):

@@ -12,9 +12,9 @@ purely on the wire: the per-bucket ``allreduce`` of the DP planner becomes
 
 over a named 2-D ``('batch', 'shard')`` mesh (mesh.sharded_mesh):
 gradients still average across 'batch' (plain DP replicas), while 'shard'
-carries the ZeRO partition. The degenerate ``shard=1`` mesh compiles to
-BITWISE the DP plan — same buckets, same wire casts, same psum — so the
-sharded path is a strict superset, not a fork.
+carries the ZeRO partition. The degenerate ``shard=1`` mesh traces to the
+DP plan's exchange — same buckets, same wire casts, same psum, equation
+for equation — so the sharded path is a strict superset, not a fork.
 
 ISSUE 19 adds the third ``'model'`` axis (parallel/tensor.py): each model
 rank plans and exchanges its LOCAL tensor-parallel slice tree through the
@@ -248,8 +248,9 @@ def reduce_scatter_gradients(
 
     On a degenerate ``shard=1`` mesh the exchange is literally
     ``collectives.bucketed_allreduce`` over ``batch_axis`` — the same call,
-    cast sequence, and plan the DP path compiles — so sharded==DP holds
-    bitwise there.
+    cast sequence, and plan the DP path traces — so the exchange is
+    sharded==DP equation for equation there (the two whole steps remain
+    separately compiled programs and agree to float32 rounding).
 
     On a 3-D ``('batch','shard','model')`` mesh (ISSUE 19) NOTHING extra
     goes on the wire here: ``grads`` is one model rank's LOCAL gradient
@@ -322,7 +323,7 @@ def reduce_scatter_gradients(
     with jax.named_scope(
             f"hvd_sharded_reduce_scatter_k{len(buffers)}s{shard_size}"):
         if shard_size == 1:
-            # Bitwise the DP path: identical collective call over the batch
+            # The DP path's exchange: identical collective call over the batch
             # axis (pmean divides at the wire dtype exactly as
             # fused_allreduce does), then the identical back-cast.
             reduced = collectives.bucketed_allreduce(buffers, batch_axis, op)

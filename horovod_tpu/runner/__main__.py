@@ -39,9 +39,11 @@ def check_build() -> int:
                       ("torch (eager binding)", "torch")):
         print(f"  {label}: {'yes' if has(mod) else 'NO'}")
     if has("jax"):
-        # Probe devices in a CHILD with a hard timeout: a wedged accelerator
-        # runtime (dead TPU tunnel, driver hang) blocks jax.devices()
-        # forever, and a diagnostics command must report that, not hang.
+        # Probe devices in a CHILD with a hard timeout: this parent stays
+        # off jax (it must not take the chip from the job it diagnoses), and
+        # a wedged accelerator runtime or a chip held by another process
+        # blocks jax.devices() — a diagnostics command must report that,
+        # not hang.
         import subprocess
 
         # One |-delimited line after a sentinel, so banner noise on stdout
@@ -63,7 +65,8 @@ def check_build() -> int:
                 print(f"  devices: backend init failed ({err[:120]})")
         except subprocess.TimeoutExpired:
             print("  devices: backend init HUNG (>60s) — accelerator "
-                  "runtime/tunnel unreachable; CPU-only work is unaffected")
+                  "runtime unreachable, or the chip is held by another "
+                  "process (a chip belongs to one process at a time)")
         except Exception as e:  # noqa: BLE001 - report, don't crash
             print(f"  devices: probe failed ({e})")
     print("  collectives: allreduce allgather broadcast alltoall "

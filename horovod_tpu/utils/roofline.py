@@ -25,9 +25,27 @@ import os
 import tempfile
 from typing import Callable, Optional
 
-# Nominal v5e numbers for the "% of roof" columns (public spec).
-V5E_HBM_GBS = 819.0
-V5E_BF16_TFLOPS = 197.0
+# Published per-chip peaks for the "% of roof" columns, keyed by
+# ``jax.devices()[0].device_kind``. Source: Google Cloud documentation,
+# "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM). A device that is not in the
+# table is an error, not a default: the roofs of one chip say nothing about
+# another.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"hbm_gbs": 819.0, "bf16_tflops": 197.0},
+}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """The published peaks of ``device_kind``; raises on a kind the table
+    does not know."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}); add it to "
+            "horovod_tpu.utils.roofline.DEVICE_PEAKS with its source"
+        ) from None
 
 
 def _load_latest_trace(logdir: str) -> list:
@@ -90,13 +108,15 @@ def profile_device_ops(run_step: Callable[[], None], steps: int = 5,
         return {"ok": False,
                 "reason": "no TPU device ops with cost fields in trace "
                           f"(tracks: {sorted(set(pids.values()))})"}
+    device_kind = jax.devices()[0].device_kind
+    hbm_gbs = device_peaks(device_kind)["hbm_gbs"]
 
     def row(key, t, b, f):
         return {
             "name": key,
             "ms_per_step": round(t / steps * 1e3, 3),
             "gbs": round(b / t / 1e9, 1) if t else 0.0,
-            "pct_hbm_roof": round(b / t / 1e9 / V5E_HBM_GBS * 100, 1) if t else 0.0,
+            "pct_hbm_roof": round(b / t / 1e9 / hbm_gbs * 100, 1) if t else 0.0,
             "tflops": round(f / t / 1e12, 2) if t else 0.0,
         }
 
@@ -106,11 +126,12 @@ def profile_device_ops(run_step: Callable[[], None], steps: int = 5,
                sorted(ops.items(), key=lambda kv: -kv[1][0])[:12]]
     return {
         "ok": True,
+        "device_kind": device_kind,
         "steps": steps,
         "device_ms_per_step": round(tot_t / steps * 1e3, 2),
         "model_bytes_gb_per_step": round(tot_b / steps / 1e9, 2),
         "achieved_gbs": round(tot_b / tot_t / 1e9, 1),
-        "pct_hbm_roof": round(tot_b / tot_t / 1e9 / V5E_HBM_GBS * 100, 1),
+        "pct_hbm_roof": round(tot_b / tot_t / 1e9 / hbm_gbs * 100, 1),
         "model_tflop_per_step": round(tot_f / steps / 1e12, 3),
         "achieved_tflops": round(tot_f / tot_t / 1e12, 1),
         "categories": categories,
@@ -122,13 +143,15 @@ def profile_device_ops(run_step: Callable[[], None], steps: int = 5,
 def format_report(rep: dict) -> str:
     if not rep.get("ok"):
         return f"roofline: unavailable ({rep.get('reason')})"
+    kind = rep["device_kind"]
+    bf16_tflops = device_peaks(kind)["bf16_tflops"]
     lines = [
         f"device busy {rep['device_ms_per_step']} ms/step | "
         f"XLA-model bytes {rep['model_bytes_gb_per_step']} GB/step | "
         f"achieved {rep['achieved_gbs']} GB/s "
-        f"({rep['pct_hbm_roof']}% of v5e HBM) | "
+        f"({rep['pct_hbm_roof']}% of {kind} HBM) | "
         f"{rep['achieved_tflops']} TFLOP/s "
-        f"({round(rep['achieved_tflops'] / V5E_BF16_TFLOPS * 100, 1)}% of bf16 peak)",
+        f"({round(rep['achieved_tflops'] / bf16_tflops * 100, 1)}% of bf16 peak)",
         f"{'category':<24}{'ms/step':>9}{'GB/s':>8}{'%roof':>7}{'TFLOP/s':>9}",
     ]
     for r in rep["categories"]:

@@ -1,0 +1,80 @@
+"""chip_smoke.py's contract, exercised on every tier-1 run without a size
+switch in its ``main()``: off-chip the script fails fast and names what it
+found, and its phase functions — imported, and run here at tiny sizes on
+the virtual CPU mesh with the Pallas interpreter asked for explicitly —
+pass on a correct system and fail on a broken one."""
+
+from __future__ import annotations
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import bench  # noqa: E402
+import chip_smoke  # noqa: E402
+
+
+def test_off_chip_exits_nonzero_and_names_the_platform():
+    proc = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=REPO, capture_output=True,
+        text=True, timeout=120, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode != 0
+    assert "platform='cpu'" in proc.stderr
+    assert '"ok"' not in proc.stdout          # no result line
+
+
+def test_collective_check(hvd, capsys):
+    chip_smoke.phase_collective(hvd.default_mesh())
+    line = capsys.readouterr().out
+    assert "phase=collective platform=cpu" in line and "devices=8" in line
+    assert "compile_s=" in line and "ranks=8" in line
+
+
+def test_collective_check_catches_an_absent_allreduce(hvd, monkeypatch):
+    """The reason the phase exists: rank-dependent gradients make a no-op
+    exchange visible (a synthetic all-ones batch would not)."""
+    monkeypatch.setattr(hvd.jax, "allreduce_gradients",
+                        lambda grads, **kw: grads)
+    with pytest.raises(chip_smoke.SmokeFailure, match="analytic mean"):
+        chip_smoke.phase_collective(hvd.default_mesh())
+
+
+def test_tiny_resnet_step(hvd, capsys):
+    from horovod_tpu.models.resnet import BottleneckBlock, ResNet
+
+    # ResNet-50's block and stem, two stages of one block at 8 filters.
+    tiny = ResNet(stage_sizes=(1, 1), block_cls=BottleneckBlock,
+                  num_classes=10, num_filters=8)
+    chip_smoke.phase_resnet(
+        bench.build_resnet_step(tiny, image=32, per_dev_batch=2), steps=5)
+    line = capsys.readouterr().out
+    assert "phase=resnet" in line and "replicas_bit_equal=True" in line
+
+
+def test_tiny_flash_step_interpreted(capsys):
+    chip_smoke.phase_kernels(cases=((64, 4, 2, 32, "float32"),),
+                             interpret=True)
+    line = capsys.readouterr().out
+    assert "phase=kernels" in line and "interpret=True" in line
+    assert "max_abs_err=out/dq/dk/dv=" in line
+
+
+def test_tiny_transformer_trainer(hvd, capsys):
+    from horovod_tpu.models import TransformerLM
+
+    chip_smoke.phase_transformer(
+        TransformerLM(vocab=64, dim=32, heads=4, layers=2, attention="flash",
+                      flash_interpret=True),
+        seq=64, per_dev_batch=1, steps=3)
+    assert "phase=transformer" in capsys.readouterr().out
+
+
+def test_four_device_legs_on_the_virtual_mesh(hvd, capsys):
+    chip_smoke.phase_four_chip(t_local=16, interpret=True)
+    line = capsys.readouterr().out
+    assert "phase=four_chip" in line and "pipeline_ppermute=True" in line

@@ -21,7 +21,7 @@ def qkv(seed=0, t=T):
 def test_forward_matches_oracle():
     q, k, v = qkv()
     with jax.default_matmul_precision("highest"):
-        out = flash_attention(q, k, v, True, 32, 32)
+        out = flash_attention(q, k, v, True, 32, 32, True)
         ref = causal_reference(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-6, rtol=2e-6)
@@ -32,7 +32,7 @@ def test_gradients_match_oracle():
     g = jax.random.normal(jax.random.PRNGKey(9), q.shape, jnp.float32)
     with jax.default_matmul_precision("highest"):
         gf = jax.grad(lambda q, k, v: jnp.sum(
-            flash_attention(q, k, v, True, 32, 32) * g), argnums=(0, 1, 2))(q, k, v)
+            flash_attention(q, k, v, True, 32, 32, True) * g), argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(lambda q, k, v: jnp.sum(
             causal_reference(q, k, v) * g), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
@@ -55,12 +55,12 @@ def test_gqa_matches_replicated_oracle():
         return jnp.repeat(x, group, axis=2)
 
     with jax.default_matmul_precision("highest"):
-        out = flash_attention(q, k, v, True, 16, 8)
+        out = flash_attention(q, k, v, True, 16, 8, True)
         ref = causal_reference(q, rep(k), rep(v))
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-6, rtol=2e-6)
         gf = jax.grad(lambda q, k, v: jnp.sum(
-            flash_attention(q, k, v, True, 16, 8) * g), argnums=(0, 1, 2))(q, k, v)
+            flash_attention(q, k, v, True, 16, 8, True) * g), argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(lambda q, k, v: jnp.sum(
             causal_reference(q, rep(k), rep(v)) * g), argnums=(0, 1, 2))(q, k, v)
     for a, b, name in zip(gf, gr, "qkv"):
@@ -73,13 +73,13 @@ def test_gqa_rejects_bad_heads():
     k3 = jnp.repeat(k[:, :, :1], 3, axis=2)  # 3 kv heads, H=2 q heads
     v3 = jnp.repeat(v[:, :, :1], 3, axis=2)
     with pytest.raises(ValueError, match="not divisible"):
-        flash_attention(q, k3, v3, True, 32, 32)
+        flash_attention(q, k3, v3, True, 32, 32, True)
 
 
 def test_non_causal_full_softmax():
     q, k, v = qkv(2)
     with jax.default_matmul_precision("highest"):
-        out = flash_attention(q, k, v, False, 32, 32)
+        out = flash_attention(q, k, v, False, 32, 32, True)
         # dense non-causal oracle
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (D ** -0.5)
         p = jax.nn.softmax(s, axis=-1)
@@ -104,7 +104,7 @@ def test_block_sizes_are_ceilings():
     assert _check_blocks(4096, 64, 64, False) == (128, 64)
     q, k, v = qkv(3, t=96)
     with jax.default_matmul_precision("highest"):
-        out = flash_attention(q, k, v, True, 64, 64)
+        out = flash_attention(q, k, v, True, 64, 64, True)
         ref = causal_reference(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-6, rtol=2e-6)
@@ -117,7 +117,7 @@ def test_transformer_flash_equals_dense():
     tok = jax.random.randint(jax.random.PRNGKey(4), (2, 128), 0, 64)
     dense = TransformerLM(vocab=64, dim=32, heads=4, layers=2, dtype=jnp.float32)
     flash = TransformerLM(vocab=64, dim=32, heads=4, layers=2, dtype=jnp.float32,
-                          attention="flash")
+                          attention="flash", flash_interpret=True)
     params = dense.init(jax.random.PRNGKey(0), tok)["params"]
     with jax.default_matmul_precision("highest"):
         od = dense.apply({"params": params}, tok)
@@ -136,7 +136,7 @@ def test_transformer_gqa_flash_equals_dense():
     kw = dict(vocab=64, dim=32, heads=4, kv_heads=2, layers=2,
               dtype=jnp.float32)
     dense = TransformerLM(**kw)
-    flash = TransformerLM(**kw, attention="flash")
+    flash = TransformerLM(**kw, attention="flash", flash_interpret=True)
     params = dense.init(jax.random.PRNGKey(0), tok)["params"]
     # GQA swaps the fused qkv kernel for split q/kv projections
     assert "q_proj" in params["block_0"] and "kv_proj" in params["block_0"]
@@ -159,12 +159,28 @@ def test_non_causal_gradients_match_oracle():
 
     with jax.default_matmul_precision("highest"):
         gf = jax.grad(lambda q, k, v: jnp.sum(
-            flash_attention(q, k, v, False, 32, 32) * g), argnums=(0, 1, 2))(q, k, v)
+            flash_attention(q, k, v, False, 32, 32, True) * g), argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(lambda q, k, v: jnp.sum(
             dense_nc(q, k, v) * g), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-6, rtol=5e-6)
+
+
+def test_compiled_kernel_without_tpu_raises():
+    """Interpret mode is the caller's to ask for, never inferred from the
+    platform: on this CPU mesh the default (compiled) kernel raises, and so
+    does a flash model built without flash_interpret."""
+    from horovod_tpu.models import TransformerLM
+
+    q, k, v = qkv()
+    with pytest.raises(ValueError, match="[Ii]nterpret"):
+        flash_attention(q, k, v, True, 32, 32)
+    tok = jnp.ones((1, 32), jnp.int32)
+    model = TransformerLM(vocab=8, dim=16, heads=2, layers=1,
+                          attention="flash")
+    with pytest.raises(ValueError, match="[Ii]nterpret"):
+        model.init(jax.random.PRNGKey(0), tok)
 
 
 def test_unknown_attention_value_rejected():

@@ -83,7 +83,7 @@ def test_transformer_remat_matches_no_remat():
 
     tok = jax.random.randint(jax.random.PRNGKey(4), (2, 64), 0, 64)
     kw = dict(vocab=64, dim=32, heads=4, layers=2, dtype=jnp.float32,
-              attention="flash")
+              attention="flash", flash_interpret=True)
     plain = TransformerLM(**kw)
     remat = TransformerLM(**kw, remat=True)
     params = plain.init(jax.random.PRNGKey(0), tok)["params"]

@@ -119,6 +119,28 @@ def test_init_refuses_without_coordinator(monkeypatch):
     hvd.shutdown()
 
 
+def test_init_refuses_workers_sharing_a_tpu_host():
+    """Two --jax-distributed workers on one TPU host would fight for the
+    same chips and hang for minutes (observed on a four-chip v5e, PR 21):
+    hvd.init() refuses the shape at once and names the one that works. The
+    worker here only believes it sits on a TPU host (the PCI scan is
+    faked); CPU-pinned workers — every test above — are not affected."""
+    code = ("from jax._src import hardware_utils as h; "
+            "h.num_available_tpu_chips_and_device_id = lambda: (4, None); "
+            "import horovod_tpu as hvd; hvd.init()")
+    env = dict(os.environ, JAX_PLATFORMS="tpu,cpu",
+               HOROVOD_JAX_DISTRIBUTED="1",
+               HOROVOD_JAX_COORDINATOR="127.0.0.1:1", HOROVOD_RANK="0",
+               HOROVOD_SIZE="2", HOROVOD_LOCAL_RANK="0",
+               HOROVOD_LOCAL_SIZE="2",
+               PYTHONPATH=os.path.dirname(os.path.dirname(SCRIPT)))
+    proc = subprocess.run([sys.executable, "-c", code], env=env, timeout=60,
+                          capture_output=True, text=True)
+    assert proc.returncode != 0
+    assert "2 workers on one TPU host (4 chips)" in proc.stderr
+    assert "ONE process" in proc.stderr
+
+
 @pytest.mark.slow
 def test_hvdrun_cli_end_to_end(tmp_path):
     """The literal CLI: `python -m horovod_tpu.runner -np 2 --jax-distributed

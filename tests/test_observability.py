@@ -5,7 +5,7 @@ replica stall-watchdog wiring, and perf_gate --trend.
 Everything here is deterministic: the anomaly rules are driven by hand
 (synthetic registry series, explicit tick() calls), the flight ring's
 SIGKILL survival is proven with a real killed subprocess, and the trend
-satellite is asserted against the checked-in BENCH_r01–r05 records.
+satellite is asserted against records in the driver's shape written here.
 """
 
 from __future__ import annotations
@@ -480,13 +480,20 @@ def _trend(args):
          "--trend"] + args, capture_output=True, text=True, cwd=REPO)
 
 
-def test_perf_gate_trend_on_checked_in_bench_records():
-    r = _trend(["--history", os.path.join(REPO, "BENCH_r0*.json")])
+def test_perf_gate_trend_skips_a_timed_out_record(tmp_path):
+    """The driver's record shape: four usable runs and one that exited
+    rc=124 with nothing parsed. The timed-out run is excluded, and the
+    trajectory of the rest is monotone up, so latest == best."""
+    rec = {"metric": "resnet50_images_per_sec", "unit": "img/s"}
+    for i, v in enumerate((1700.0, 2470.0, 2490.0, 2500.0), 1):
+        with open(tmp_path / f"BENCH_r{i:02d}.json", "w") as f:
+            json.dump({"n": i, "rc": 0, "parsed": dict(rec, value=v)}, f)
+    with open(tmp_path / "BENCH_r05.json", "w") as f:
+        json.dump({"n": 5, "rc": 124, "tail": "", "parsed": None}, f)
+    r = _trend(["--history", str(tmp_path / "BENCH_r0*.json")])
     assert r.returncode == 0, r.stdout + r.stderr
     (line,) = [ln for ln in r.stdout.splitlines()
                if "resnet50_images_per_sec" in ln]
-    # r05 exited rc=124 -> excluded; four usable records remain and the
-    # trajectory is monotone up, so latest == best.
     assert "n=4" in line and "latest/best=1.000" in line
     assert "skipping" in r.stdout and "rc=124" in r.stdout
 

@@ -167,19 +167,6 @@ def test_num_buckets_env_knob(monkeypatch):
     assert Config.from_env().num_buckets == 1
 
 
-def test_latency_hiding_flags_idempotent():
-    from horovod_tpu.common.config import (LATENCY_HIDING_XLA_FLAGS,
-                                           enable_latency_hiding_scheduler)
-
-    env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
-    enable_latency_hiding_scheduler(env)
-    for f in LATENCY_HIDING_XLA_FLAGS:
-        assert f in env["XLA_FLAGS"]
-    once = env["XLA_FLAGS"]
-    enable_latency_hiding_scheduler(env)
-    assert env["XLA_FLAGS"] == once           # no duplicate accumulation
-
-
 # --------------------------------------------------- joint autotuning
 
 

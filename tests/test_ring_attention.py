@@ -78,7 +78,8 @@ def test_ulysses_flash_matches_oracle(sp_mesh):
     q, k, v = qkv()
     w = jax.random.normal(jax.random.PRNGKey(9), q.shape, jnp.float32)
     uly = shard_map(
-        lambda a, b, c: ulysses_attention(a, b, c, "sp", impl="flash"),
+        lambda a, b, c: ulysses_attention(a, b, c, "sp", impl="flash",
+                                          interpret=True),
         mesh=sp_mesh, in_specs=P(None, "sp"), out_specs=P(None, "sp"),
         check_vma=False)
     with jax.default_matmul_precision("highest"):
@@ -201,7 +202,8 @@ def test_ulysses_gqa_matches_oracle(sp_mesh, impl):
         ref = causal_reference(q, jnp.repeat(k, rep, axis=2),
                                jnp.repeat(v, rep, axis=2))
         out = _run_sharded(
-            lambda a, b, c: ulysses_attention(a, b, c, "sp", impl=impl),
+            lambda a, b, c: ulysses_attention(a, b, c, "sp", impl=impl,
+                                          interpret=True),
             sp_mesh, q, k, v)
     tol = 2e-2 if impl == "flash" else 2e-5
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=tol, rtol=tol)

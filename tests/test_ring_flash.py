@@ -49,7 +49,7 @@ def test_ring_flash_matches_oracle(sp_mesh):
     with jax.default_matmul_precision("highest"):
         ref = causal_reference(q, k, v)
         out = _sharded(sp_mesh, lambda a, b, c: ring_flash_attention(
-            a, b, c, "sp"))(q, k, v)
+            a, b, c, "sp", interpret=True))(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -62,7 +62,7 @@ def test_ring_flash_zigzag_matches_oracle(sp_mesh):
     with jax.default_matmul_precision("highest"):
         ref = causal_reference(q, k, v)
         out_z = _sharded(sp_mesh, lambda a, b, c: ring_flash_attention(
-            a, b, c, "sp", zigzag=True))(qz, kz, vz)
+            a, b, c, "sp", zigzag=True, interpret=True))(qz, kz, vz)
         out = zigzag_unshard(out_z, n)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
@@ -77,7 +77,7 @@ def test_ring_flash_multiblock_matches_oracle(sp_mesh):
     with jax.default_matmul_precision("highest"):
         ref = causal_reference(q, k, v)
         out = _sharded(sp_mesh, lambda a, b, c: ring_flash_attention(
-            a, b, c, "sp", block_q=8, block_k=4))(q, k, v)
+            a, b, c, "sp", block_q=8, block_k=4, interpret=True))(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -87,7 +87,7 @@ def test_ring_flash_multiblock_grads_match_oracle(sp_mesh):
     q, k, v = qkv(t=32)  # t_local=8 with bq=4/bk=2: 2x4 grid per step
     w = jax.random.normal(jax.random.PRNGKey(9), q.shape, jnp.float32)
     ring = _sharded(sp_mesh, lambda a, b, c: ring_flash_attention(
-        a, b, c, "sp", block_q=4, block_k=2))
+        a, b, c, "sp", block_q=4, block_k=2, interpret=True))
     with jax.default_matmul_precision("highest"):
         g_ring = jax.grad(lambda a, b, c: jnp.sum(ring(a, b, c) * w),
                           argnums=(0, 1, 2))(q, k, v)
@@ -113,7 +113,7 @@ def test_ring_flash_zigzag_grads_match_oracle(sp_mesh):
     wz = zigzag_shard(w, n)
 
     ring = _sharded(sp_mesh, lambda a, b, c: ring_flash_attention(
-        a, b, c, "sp", zigzag=True))
+        a, b, c, "sp", zigzag=True, interpret=True))
     with jax.default_matmul_precision("highest"):
         g_ring = jax.grad(lambda a, b, c: jnp.sum(ring(a, b, c) * wz),
                           argnums=(0, 1, 2))(qz, kz, vz)
@@ -141,7 +141,8 @@ def test_ring_flash_gqa_matches_replicated_oracle(sp_mesh):
     def rep(x):
         return jnp.repeat(x, group, axis=2)
 
-    ring = _sharded(sp_mesh, lambda a, b, c: ring_flash_attention(a, b, c, "sp"))
+    ring = _sharded(sp_mesh, lambda a, b, c: ring_flash_attention(
+        a, b, c, "sp", interpret=True))
     with jax.default_matmul_precision("highest"):
         out = ring(q, k, v)
         ref = causal_reference(q, rep(k), rep(v))
@@ -167,7 +168,8 @@ def test_transformer_sp_flash_equals_dense(sp_mesh):
     dense = TransformerLM(vocab=64, dim=32, heads=4, layers=2,
                           dtype=jnp.float32)
     sp = TransformerLM(vocab=64, dim=32, heads=4, layers=2,
-                       dtype=jnp.float32, sp_axis="sp", attention="flash")
+                       dtype=jnp.float32, sp_axis="sp", attention="flash",
+                       flash_interpret=True)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 64)
     params = dense.init(jax.random.PRNGKey(0), tokens)["params"]
 

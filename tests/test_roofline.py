@@ -8,7 +8,9 @@ numbers come from `bench.py --roofline` on the chip (docs/benchmarks.md).
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.utils.roofline import (V5E_BF16_TFLOPS, format_report,
+import pytest
+
+from horovod_tpu.utils.roofline import (device_peaks, format_report,
                                         profile_device_ops)
 
 
@@ -31,6 +33,7 @@ def test_cpu_trace_degrades_gracefully(tmp_path):
 def test_report_formatting_from_synthetic():
     rep = {
         "ok": True,
+        "device_kind": "TPU v5 lite",
         "device_ms_per_step": 46.9,
         "model_bytes_gb_per_step": 43.9,
         "achieved_gbs": 937.0,
@@ -50,5 +53,15 @@ def test_report_formatting_from_synthetic():
     assert "92.6" in out
     assert "tiny" not in out          # sub-0.01ms rows are dropped
     # the summary line carries both roofs: HBM % and % of bf16 peak
-    assert "% of v5e HBM" in out
-    assert f"{round(65.2 / V5E_BF16_TFLOPS * 100, 1)}" in out
+    assert "% of TPU v5 lite HBM" in out
+    peak = device_peaks("TPU v5 lite")["bf16_tflops"]
+    assert f"{round(65.2 / peak * 100, 1)}" in out
+
+
+def test_unknown_device_kind_raises():
+    """The peaks of one chip are never applied to another: a device kind
+    the table does not know is an error, in the lookup and in a report."""
+    with pytest.raises(ValueError, match="no published peaks"):
+        device_peaks("cpu")
+    with pytest.raises(ValueError, match="TPU v9"):
+        format_report({"ok": True, "device_kind": "TPU v9"})
