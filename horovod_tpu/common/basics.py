@@ -120,17 +120,18 @@ def _refuse_workers_sharing_tpu_host() -> None:
     for minutes in the distributed runtime's barriers before dying. So the
     shape is refused up front, with the one that works named. Workers pinned
     to the CPU platform (the virtual-device test path) and hosts without TPU
-    chips are not affected; an operator who exports libtpu's own
-    ``TPU_VISIBLE_CHIPS`` per worker has taken the pinning on themselves."""
+    chips are not affected."""
     if int(os.environ.get("HOROVOD_LOCAL_SIZE") or 1) <= 1:
         return
-    if os.environ.get("TPU_VISIBLE_CHIPS"):
-        return
     import jax
-    from jax._src import hardware_utils
 
     if (jax.config.jax_platforms or "").split(",")[0] == "cpu":
         return
+    # Counting chips must not start a backend (that would take them). jax has
+    # no public call for it; this is the PCI scan its own cloud_tpu_init uses,
+    # private as of jax 0.9.0 — the installation this code is written for.
+    from jax._src import hardware_utils
+
     chips, _ = hardware_utils.num_available_tpu_chips_and_device_id()
     if chips == 0:
         return

@@ -14,13 +14,18 @@ communicators, we lay devices out on a named :class:`jax.sharding.Mesh`:
 - ``training_mesh``: general ``(dp, fsdp, pp, tp, sp, ep)`` builder for the
   model-parallel families layered on top of the Horovod-parity core.
 
-All builders go through ``mesh_utils.create_device_mesh``, so on a TPU the
-most-minor mesh axis maps to physically adjacent chips (on the four chips of
-a v5e 2x2 host that is the ring 0-1-3-2, not ``jax.devices()`` order), which
-is what makes the ``psum`` over 'ici' and the ring ``ppermute`` ride
-neighbour links. A shape that does not fit the physical topology raises —
-there is no fallback to a plain reshape. Off-TPU (the virtual CPU test mesh)
-``create_device_mesh`` itself is the plain reshape.
+``data_parallel_mesh`` and ``hierarchical_mesh`` keep ``jax.devices()``
+order, which is process order: rank i is device i, and a row of the
+hierarchical mesh is ``ici_size`` consecutive devices — one host's chips at
+the default ``ici_size`` — so the ``psum`` over 'ici' never leaves the host.
+(On the four chips of a v5e 2x2 host that order has two 2-hop ring
+neighbours where the topology-aware 0-1-3-2 has none; a 64 MiB/device ring
+``ppermute`` took 2.4-2.5 ms in either order — one observation, PR 21.)
+``training_mesh`` and ``sharded_mesh`` go through
+``mesh_utils.create_device_mesh``, which maps the most-minor mesh axis to
+physically adjacent chips; a shape that does not fit the physical topology
+raises — there is no fallback to a plain reshape. Off-TPU (the virtual CPU
+test mesh) ``create_device_mesh`` itself is the plain reshape.
 """
 
 from __future__ import annotations
@@ -29,6 +34,7 @@ import math
 from typing import Sequence
 
 import jax
+import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
@@ -55,11 +61,9 @@ def _devices(devices=None):
 
 def data_parallel_mesh(devices=None) -> Mesh:
     """All chips on one named axis ``'hvd'`` — rank i of the reference maps to
-    mesh position i (on a TPU, position i is the i-th chip of the physical
-    ring, see the module docstring)."""
+    mesh position i."""
     devs = _devices(devices)
-    return Mesh(mesh_utils.create_device_mesh((len(devs),), devices=devs),
-                (HVD_AXIS,))
+    return Mesh(np.asarray(devs), (HVD_AXIS,))
 
 
 def hierarchical_mesh(devices=None, ici_size: int | None = None) -> Mesh:
@@ -77,8 +81,7 @@ def hierarchical_mesh(devices=None, ici_size: int | None = None) -> Mesh:
             ici_size = math.gcd(n, ici_size) or 1
     if n % ici_size != 0:
         raise ValueError(f"device count {n} not divisible by ici_size {ici_size}")
-    arr = mesh_utils.create_device_mesh((n // ici_size, ici_size),
-                                        devices=devs)
+    arr = np.asarray(devs).reshape(n // ici_size, ici_size)
     return Mesh(arr, (DCN_AXIS, ICI_AXIS))
 
 

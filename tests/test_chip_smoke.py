@@ -61,7 +61,29 @@ def test_tiny_flash_step_interpreted(capsys):
                              interpret=True)
     line = capsys.readouterr().out
     assert "phase=kernels" in line and "interpret=True" in line
+    assert "precision=highest" in line
     assert "max_abs_err=out/dq/dk/dv=" in line
+
+
+def test_flash_step_tells_bf16_arithmetic_from_f32(monkeypatch):
+    """What the f32 cases' tight bound is for: operands rounded to bf16 on
+    the way in (what the MXU does at the default precision) must fail."""
+    import importlib
+
+    import jax.numpy as jnp
+
+    # the package re-exports the function under the module's name
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+    real = fa.flash_attention
+
+    def rounded(q, k, v, *rest):
+        q, k, v = (t.astype(jnp.bfloat16).astype(t.dtype) for t in (q, k, v))
+        return real(q, k, v, *rest)
+
+    monkeypatch.setattr(fa, "flash_attention", rounded)
+    with pytest.raises(chip_smoke.SmokeFailure, match="exceeds"):
+        chip_smoke.phase_kernels(cases=((64, 4, 2, 32, "float32"),),
+                                 interpret=True)
 
 
 def test_tiny_transformer_trainer(hvd, capsys):

@@ -124,11 +124,13 @@ def test_init_refuses_workers_sharing_a_tpu_host():
     same chips and hang for minutes (observed on a four-chip v5e, PR 21):
     hvd.init() refuses the shape at once and names the one that works. The
     worker here only believes it sits on a TPU host (the PCI scan is
-    faked); CPU-pinned workers — every test above — are not affected."""
+    faked); CPU-pinned workers — every test above — are not affected. A
+    job-wide ``TPU_VISIBLE_CHIPS`` both workers inherit pins nothing per
+    worker and must not lift the refusal."""
     code = ("from jax._src import hardware_utils as h; "
             "h.num_available_tpu_chips_and_device_id = lambda: (4, None); "
             "import horovod_tpu as hvd; hvd.init()")
-    env = dict(os.environ, JAX_PLATFORMS="tpu,cpu",
+    env = dict(os.environ, JAX_PLATFORMS="tpu,cpu", TPU_VISIBLE_CHIPS="0,1",
                HOROVOD_JAX_DISTRIBUTED="1",
                HOROVOD_JAX_COORDINATOR="127.0.0.1:1", HOROVOD_RANK="0",
                HOROVOD_SIZE="2", HOROVOD_LOCAL_RANK="0",
