@@ -210,6 +210,9 @@ def init(comm: Optional[Sequence[int]] = None) -> None:
         _state.config = Config.from_env()
         _state.initialized = True
         _start_metrics(topo, _state.config)
+        from ..utils.compile_cache import install_compile_ledger
+
+        install_compile_ledger()
         if not _state._atexit_registered:
             atexit.register(shutdown)
             _state._atexit_registered = True

@@ -1,0 +1,17 @@
+"""Names the program gives its own device work.
+
+Scopes are HLO metadata (``op_name``) and kernel names are the Mosaic custom
+calls' own, so neither adds an operation to the step; a device profile, or
+the benchmark's ``breakdown``, shows each piece under its name.
+"""
+
+FLASH_FWD = "hvd_flash_fwd"
+FLASH_BWD_DQ = "hvd_flash_bwd_dq"
+FLASH_BWD_DKV = "hvd_flash_bwd_dkv"
+RING_FLASH_FWD = "hvd_ring_flash_fwd"
+RING_FLASH_BWD_DQ = "hvd_ring_flash_bwd_dq"
+RING_FLASH_BWD_DKV = "hvd_ring_flash_bwd_dkv"
+FUSION_PACK = "hvd_fusion_pack"         # fuse + compress + the wire cast
+FUSION_UNPACK = "hvd_fusion_unpack"     # the cast back + decompress + unfuse
+OPTIMIZER_UPDATE = "hvd_optimizer_update"   # the wrapped optax update
+FUSED_ALLREDUCE = "hvd_fused_allreduce_k"   # + the number of buckets

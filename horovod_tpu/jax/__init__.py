@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ..common.device_names import OPTIMIZER_UPDATE
 from ..compression import Compression, Compressor
 from ..parallel import collectives, fusion
 from ..parallel import sharded as _sharded
@@ -295,7 +296,9 @@ def DistributedOptimizer(
             batch_axis=batch_axis, shard_axis=shard_axis, op=op,
             compression=_resolved_compression(compression),
             compression_min_bytes=compression_min_bytes)
-        updates, new_state = optimizer.update(reduced, state, params, **extra)
+        with jax.named_scope(OPTIMIZER_UPDATE):
+            updates, new_state = optimizer.update(reduced, state, params,
+                                                  **extra)
         return _sharded.mask_pad_updates(updates, plan, shard_axis), new_state
 
     def update_fn(grads, state, params=None, **extra):
@@ -313,7 +316,8 @@ def DistributedOptimizer(
             dcn_compression=dcn_compression,
             dcn_threshold=dcn_threshold,
         )
-        return optimizer.update(reduced, state, params, **extra)
+        with jax.named_scope(OPTIMIZER_UPDATE):
+            return optimizer.update(reduced, state, params, **extra)
 
     wrapped = optax.GradientTransformationExtraArgs(
         optimizer.init, sharded_update_fn if sharded else update_fn)

@@ -274,7 +274,7 @@ def tune(step_factory: Callable[..., Callable[[], None]],
     joins as the FOURTH joint dimension (ISSUE 7) — categorical like the
     wire dtype, explored exhaustively, with the continuous (threshold,
     buckets) GP/EI refinement run per (compression, hierarchical) branch.
-    This is the compiled-plane mirror of the native ParameterManager's
+    This is the compiled plane mirror of the native ParameterManager's
     hier_allreduce categorical (cc/src/autotuner.h): the tuner decides
     per PLATFORM whether the two-level ladder pays, instead of trusting
     the env knob. The factory is then called with an extra

@@ -44,6 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common.device_names import FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD
+
 NEG_INF = -1e30
 
 
@@ -301,6 +303,7 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret):
             pltpu.VMEM((1, 8, block_q), jnp.float32),   # l
         ],
         interpret=interpret,
+        name=FLASH_FWD,
     )(qr, kr, vr)
     return _unrows(out, b, t, h, d), (q, k, v, out, lse)
 
@@ -339,6 +342,7 @@ def _bwd_rule(causal, block_q, block_k, interpret, res, dout):
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_q, d), jnp.float32)],
         interpret=interpret,
+        name=FLASH_BWD_DQ,
     )(qr, kr, vr, dor, lse, delta)
 
     # dK/dV: one grid row per KV row; the innermost dim sweeps (g, qi) so a
@@ -364,6 +368,7 @@ def _bwd_rule(causal, block_q, block_k, interpret, res, dout):
             pltpu.VMEM((1, block_k, d), jnp.float32),   # dv acc
         ],
         interpret=interpret,
+        name=FLASH_BWD_DKV,
     )(qr, kr, vr, dor, lse, delta)
 
     return (_unrows(dq, b, t, h, d), _unrows(dk, b, t, hkv, d),

@@ -43,6 +43,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common.device_names import (RING_FLASH_BWD_DKV, RING_FLASH_BWD_DQ,
+                                   RING_FLASH_FWD)
 from ..compat import axis_size
 
 from .flash_attention import (
@@ -225,6 +227,7 @@ def _fwd_block_call(qr, k_blk, v_blk, o, m, l, qpos, kpos, bq, bk,
         # state that is dead on entry (~2x carry HBM traffic per step).
         input_output_aliases={3: 0, 4: 1, 5: 2},
         interpret=interpret,
+        name=RING_FLASH_FWD,
     )(qr, k_blk, v_blk, o, m, l, qpos, kpos)
 
 
@@ -246,6 +249,7 @@ def _dq_block_call(qr, k_blk, v_blk, dor, lse, delta, qpos, kpos, dq,
         scratch_shapes=[pltpu.VMEM((1, bq, d), jnp.float32)],
         input_output_aliases={8: 0},  # dq accumulator updates in place
         interpret=interpret,
+        name=RING_FLASH_BWD_DQ,
     )(qr, k_blk, v_blk, dor, lse, delta, qpos, kpos, dq)
 
 
@@ -278,6 +282,7 @@ def _dkv_block_call(qr, k_blk, v_blk, dor, lse, delta, qpos, kpos, dk, dv,
                         pltpu.VMEM((1, bk, d), jnp.float32)],
         input_output_aliases={8: 0, 9: 1},  # dk/dv ride the ring in place
         interpret=interpret,
+        name=RING_FLASH_BWD_DKV,
     )(qr, k_blk, v_blk, dor, lse, delta, qpos, kpos, dk, dv)
 
 

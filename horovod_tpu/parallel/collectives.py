@@ -77,7 +77,7 @@ def bucketed_allreduce(buffers: Sequence, axis_name: str = HVD_AXIS,
     backward compute is still running. Each psum is emitted as its own op
     (no jnp-level dependency between buckets), which is exactly the shape
     XLA's latency-hiding scheduler needs to overlap the ICI transfer of early buckets with the remaining
-    compute — the compiled-plane analog of Horovod's background thread
+    compute — the compiled plane analog of Horovod's background thread
     starting allreduces mid-backward (operations.cc PerformOperation)."""
     return [allreduce(b, axis_name, op) for b in buffers]
 

@@ -29,6 +29,7 @@ import numpy as np
 
 from . import collectives
 from .mesh import HVD_AXIS
+from ..common.device_names import FUSED_ALLREDUCE, FUSION_PACK, FUSION_UNPACK
 from ..common.config import (DEFAULT_COMPRESSION_MIN_BYTES,
                              DEFAULT_FUSION_THRESHOLD, _env_int)
 from ..compat import axis_size
@@ -90,7 +91,7 @@ def build_plan(tree, threshold: int = DEFAULT_FUSION_THRESHOLD, pad_to: int = 1,
     buckets. Issuing one independent collective per bucket in this order
     lets XLA's latency-hiding scheduler start allreducing early buckets
     while the rest of the backward compute is still in flight — the
-    compiled-plane expression of Horovod's background-thread overlap
+    compiled plane expression of Horovod's background-thread overlap
     (PAPER.md L1; same design point as PyTorch DDP's reverse-order
     gradient buckets). ``threshold`` remains a hard cap on bucket bytes,
     so the two knobs compose: K sets the minimum split, the threshold
@@ -309,7 +310,7 @@ def fused_allreduce(
             log("warning",
                 "HOROVOD_COMPRESSION=topk applies to the eager engines "
                 "only; the compiled plane ships dense buckets (use "
-                "bf16/adaptive for a compiled-plane wire cut)")
+                "bf16/adaptive for a compiled plane wire cut)")
         _ici_fmt, _dcn_fmt = compiled_formats(_comp_name)
         if dcn_compression is None:
             dcn_compression = (os.environ.get("HOROVOD_DCN_COMPRESSION", "")
@@ -355,33 +356,26 @@ def fused_allreduce(
     from ..metrics import record_plan, record_wire_plan
 
     record_plan(plan, threshold)
-    buffers = fuse(tree, plan)
-    orig_dtypes = [buf.dtype for buf in buffers]
-    if compress is not None:
-        buffers = [compress(buf) for buf in buffers]
-    # Wire compression (ISSUE 5): per-bucket cast to the 16-bit wire dtype
-    # around the collective. Decided at trace time, so the hot path carries
-    # exactly one convert pair per eligible bucket and nothing else.
-    wire = [wire_dtype_for_bucket(compression, buf.dtype, int(buf.nbytes), op,
-                                  compression_min_bytes)
-            for buf in buffers]
-    record_wire_plan(
-        compression_name(compression),
-        [(int(b.nbytes), w is not None,
-          int(b.size) * (jnp.dtype(w).itemsize if w is not None else 0))
-         for b, w in zip(buffers, wire)])
-    # Distributed tracing (ISSUE 6): annotate the bucket plan into the trace
-    # directory at TRACE time (once per compile — the compiled hot path
-    # carries zero instrumentation), and name-scope the collectives so the
-    # device profile's HLO ops carry the same bucket identity the pod trace
-    # shows. No-ops when HOROVOD_TRACE_DIR is unset.
-    from ..tracing import record_compiled_plan
-
-    record_compiled_plan(
-        plan.num_buckets, [int(b.nbytes) for b in buffers],
-        compression_name(compression), [w is not None for w in wire])
-    buffers = [b.astype(w) if w is not None else b
-               for b, w in zip(buffers, wire)]
+    with jax.named_scope(FUSION_PACK):
+        buffers = fuse(tree, plan)
+        orig_dtypes = [buf.dtype for buf in buffers]
+        if compress is not None:
+            buffers = [compress(buf) for buf in buffers]
+        # Wire compression (ISSUE 5): per-bucket cast to the 16-bit wire
+        # dtype around the collective. Decided at trace time, so the hot
+        # path carries exactly one convert pair per eligible bucket and
+        # nothing else.
+        wire = [wire_dtype_for_bucket(compression, buf.dtype,
+                                      int(buf.nbytes), op,
+                                      compression_min_bytes)
+                for buf in buffers]
+        record_wire_plan(
+            compression_name(compression),
+            [(int(b.nbytes), w is not None,
+              int(b.size) * (jnp.dtype(w).itemsize if w is not None else 0))
+             for b, w in zip(buffers, wire)])
+        buffers = [b.astype(w) if w is not None else b
+                   for b, w in zip(buffers, wire)]
     # Per-fabric-tier wire dtype (ISSUE 7): the DCN psum of the hierarchical
     # ladder may run at its own (usually narrower) wire dtype. Computed
     # against the AS-SHIPPED buffer dtype — a bucket already cast to a
@@ -417,7 +411,7 @@ def fused_allreduce(
 
                 _metrics_registry().counter(
                     "horovod_compiled_adaptive_fallback_total",
-                    help="compiled-plane traces where an 'adaptive' DCN "
+                    help="compiled plane traces where an 'adaptive' DCN "
                          "tier answered topk and shipped the designed "
                          "substitute (common/policy.py "
                          "COMPILED_TOPK_SUBSTITUTE) instead — by design, "
@@ -446,7 +440,10 @@ def fused_allreduce(
                                           if dw is not None
                                           else b.dtype.itemsize)
             for b, dw in zip(buffers, dcn_wire)] if hierarchical else [])
-    with jax.named_scope(f"hvd_fused_allreduce_k{len(buffers)}"):
+    # Named scopes (common/device_names.py): the device profile's HLO ops
+    # carry the bucket count on the collectives and pack / unpack on the
+    # copies and casts around them. Metadata only: no operation is added.
+    with jax.named_scope(f"{FUSED_ALLREDUCE}{len(buffers)}"):
         if hierarchical:
             reduced = [
                 collectives.hierarchical_allreduce(
@@ -457,11 +454,13 @@ def fused_allreduce(
             ]
         else:
             reduced = collectives.bucketed_allreduce(buffers, axis_name, op)
-    reduced = [r.astype(dt) if w is not None else r
-               for r, w, dt in zip(reduced, wire, orig_dtypes)]
-    if decompress is not None:
-        reduced = [decompress(r, dt) for r, dt in zip(reduced, orig_dtypes)]
-    return unfuse(reduced, plan)
+    with jax.named_scope(FUSION_UNPACK):
+        reduced = [r.astype(dt) if w is not None else r
+                   for r, w, dt in zip(reduced, wire, orig_dtypes)]
+        if decompress is not None:
+            reduced = [decompress(r, dt)
+                       for r, dt in zip(reduced, orig_dtypes)]
+        return unfuse(reduced, plan)
 
 
 def _axis_size(axis_name: str):

@@ -313,11 +313,6 @@ def reduce_scatter_gradients(
                        for b, w in zip(buffers, wire)],
         gather_bytes=[int(b.nbytes) for b in buffers],
         model_size=model_size)
-    from ..tracing import record_compiled_plan
-
-    record_compiled_plan(
-        plan.num_buckets, [int(b.nbytes) for b in buffers],
-        compression_name(compression), [w is not None for w in wire])
     buffers = [b.astype(w) if w is not None else b
                for b, w in zip(buffers, wire)]
     with jax.named_scope(

@@ -4,16 +4,14 @@ Set ``HOROVOD_TRACE_DIR=/path`` (or ``Config(trace_dir=...)``) and every
 collective gets a trace ID at first enqueue — ``<name>#<submission-seq>``,
 deterministic and identical across ranks — that links its spans (enqueue,
 negotiate, cache-tick, wire send/recv per hop, reduce, done) across ALL
-ranks and all three data planes:
+ranks and both eager data planes:
 
 - eager Python engine: spans from common/engine.py + ring-hop IO from
   runner/network.py's Channel hook; the request dicts and ring directives
   carry the ID so the coordinator verifies cross-rank agreement;
 - native C++ engine: cc/src/engine.cc stamps ``Request.trace_seq`` on the
   wire (cc/src/wire.h) and records spans drained through
-  ``hvd_trace_drain`` into the same per-rank file (cc/native_engine.py);
-- compiled plane: parallel/fusion.py annotates each traced bucket plan
-  into the trace directory (trace-time only — zero hot-path cost).
+  ``hvd_trace_drain`` into the same per-rank file (cc/native_engine.py).
 
 Workflow: run with the env set, then merge + analyze:
 
@@ -28,10 +26,8 @@ stall watchdog's report.
 
 from __future__ import annotations
 
-import json
 import os
 import threading
-import time
 from typing import Optional
 
 from .clock import estimate_offset_ns  # noqa: F401
@@ -99,33 +95,3 @@ def close_recorder() -> None:
         if _recorder is not None:
             _recorder.close()
             _recorder = None
-
-
-def record_compiled_plan(num_buckets: int, bucket_bytes: list,
-                         compression: str = "none",
-                         wire_flags: Optional[list] = None) -> None:
-    """Trace-time annotation of a compiled-plane fusion plan (called by
-    parallel/fusion.fused_allreduce once per trace/compile): drop the
-    bucket geometry into the trace directory so the merged pod trace can be
-    read next to the device profile. No-op when tracing is off; never
-    raises (annotation must not break a jit trace)."""
-    trace_dir = trace_dir_from_env()
-    if not trace_dir:
-        return
-    rank = int(os.environ.get("HOROVOD_RANK", "0"))
-    rec = {
-        "compiled_plan": 1,
-        "rank": rank,
-        "time_unix_s": time.time(),
-        "num_buckets": int(num_buckets),
-        "bucket_bytes": [int(b) for b in bucket_bytes],
-        "compression": str(compression),
-        "wire_compressed": [bool(w) for w in (wire_flags or [])],
-    }
-    try:
-        os.makedirs(trace_dir, exist_ok=True)
-        path = os.path.join(trace_dir, f"compiled-plan-rank{rank}.jsonl")
-        with open(path, "a") as f:
-            f.write(json.dumps(rec) + "\n")
-    except OSError:
-        pass

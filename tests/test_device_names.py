@@ -1,0 +1,63 @@
+"""The names the program gives its device work (common/device_names.py) reach
+the lowered module as metadata: present in the text with debug info, absent
+from the text without it, so the step executes the same operations."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import PartitionSpec as P
+
+from horovod_tpu.common import device_names as names
+from horovod_tpu.compat import shard_map
+from horovod_tpu.ops.flash_attention import flash_attention
+
+
+@pytest.fixture(scope="module")
+def optimizer_step_text():
+    import horovod_tpu as hvd
+
+    mesh = hvd.data_parallel_mesh(jax.devices()[:4])
+    opt = hvd.jax.DistributedOptimizer(optax.sgd(0.1), compression=hvd.Compression.bf16,
+                                       compression_min_bytes=0)
+    params = {"w": jnp.ones((16, 8)), "b": jnp.zeros((8,))}
+
+    def train_step(params, opt_state, x):
+        grads = jax.grad(lambda p: jnp.mean((x @ p["w"] + p["b"]) ** 2))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
+
+    step = jax.jit(shard_map(train_step, mesh=mesh,
+                             in_specs=(P(), P(), P(hvd.HVD_AXIS)),
+                             out_specs=(P(), P()), check_vma=False))
+    lowered = step.lower(params, opt.init(params), jnp.ones((8, 16)))
+    return {True: lowered.as_text(debug_info=True),
+            False: lowered.as_text(debug_info=False)}
+
+
+@pytest.fixture(scope="module")
+def flash_text():
+    q = jnp.ones((1, 128, 2, 32), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
+    return lowered.as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", [names.FUSION_PACK, names.FUSION_UNPACK,
+                                  names.OPTIMIZER_UPDATE,
+                                  names.FUSED_ALLREDUCE,
+                                  names.FLASH_FWD, names.FLASH_BWD_DQ,
+                                  names.FLASH_BWD_DKV])
+def test_name_is_in_the_lowered_module_as_metadata_only(name, request):
+    if name.startswith("hvd_flash"):
+        assert name in request.getfixturevalue("flash_text")
+        return
+    text = request.getfixturevalue("optimizer_step_text")
+    assert name in text[True]
+    if name != names.FUSED_ALLREDUCE:   # the three new ones: metadata alone
+        assert name not in text[False]
