@@ -143,6 +143,23 @@ def last_wire_plan() -> Optional[tuple]:
     return _last_wire_plan
 
 
+def record_flash_plan(live: int, masked: int) -> float:
+    """Record how the latest traced flash-attention forward splits its live
+    k-block steps between its two bodies (trace time, once per compile —
+    same reasoning as record_wire_plan). ``live``: block steps a head
+    executes; ``masked``: those the causal diagonal crosses, which build and
+    apply the mask (``ops.flash_attention.block_census``). The gauge is the
+    share that runs the unmasked body: 0 for one block per row, 1 for
+    non-causal attention."""
+    share = (live - masked) / max(1, live)
+    registry().gauge(
+        "horovod_flash_unmasked_block_share",
+        help="share of the latest traced flash forward's live k-block steps "
+             "that lie wholly below the causal diagonal and skip the mask"
+    ).set(share)
+    return share
+
+
 # Latest fabric-tier plan of the hierarchical compiled path (ISSUE 7):
 # {"hierarchical": bool, "ici_wire": str, "dcn_wire": str, "ici_size": int,
 #  "bytes_per_step": {"ici": n, "dcn": n}, "buckets": int}.

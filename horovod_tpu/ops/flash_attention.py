@@ -16,8 +16,14 @@ length — no full K/V row staging, no VMEM ceiling at long context.
 
 Three kernels behind one ``jax.custom_vjp``:
 - forward: grid (batch·head, q-block, k-block); scratch-carried online
-  (m, l, acc); emits the per-row logsumexp residual L in a
-  sublane-replicated layout that satisfies TPU block tiling.
+  (m, l, acc). The row statistics m and l are ``(block_q, 128)`` f32, one
+  row per sublane row and replicated along the lanes — the orientation of
+  the score tile's own rows, so no vector moves between lanes and sublanes
+  inside a block step. The loaded tile is walked in 256 x 512 sub-tiles.
+  Matmul operands stay in the caller's dtype and accumulate in f32; the
+  scale multiplies the f32 product, as in both backward kernels. Emits
+  the per-row logsumexp residual L in a sublane-replicated ``(8, t)``
+  layout that satisfies TPU block tiling (one transpose per q block).
 - backward dQ: same grid; recomputes p = exp(s − L) blockwise and
   accumulates dQ = scale · Σ_k [p ∘ (dO·Vᵀ − D)] · K in scratch.
 - backward dK/dV: grid (batch·head, k-block, q-block); accumulates
@@ -25,7 +31,11 @@ Three kernels behind one ``jax.custom_vjp``:
 (D = rowsum(dO ∘ O) is an elementwise reduction computed outside.)
 
 Causal programs skip the dead triangle with ``pl.when`` — no compute for
-fully-masked blocks.
+fully-masked blocks — and build the mask only on the blocks the diagonal
+crosses (forward and dK/dV: two bodies; 16 of 136 live blocks at 16k /
+1024 / 1024); there the forward also skips the sub-tiles above the
+diagonal. ``horovod_flash_unmasked_block_share`` says how often the
+unmasked body engages (:func:`block_census`).
 
 Pairs with the sequence-parallel schedules in ring_attention.py (which move
 K/V between chips); `causal_reference` is the oracle both are tested
@@ -49,12 +59,75 @@ from ..common.device_names import FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD
 NEG_INF = -1e30
 
 
+# ------------------------------------------------- causal block geometry
+
+def _live(qi, ki, block_q, block_k):
+    """Some row of q block ``qi`` sees a column of k block ``ki``: the block
+    lies on or below the causal diagonal (``block_q % block_k == 0``)."""
+    return ki < (qi + 1) * (block_q // block_k)
+
+
+def _crossed(qi, ki, block_q, block_k):
+    """A live block the diagonal runs through: only these need the mask;
+    live blocks before them lie wholly below the diagonal."""
+    return ki >= qi * (block_q // block_k)
+
+
+def block_census(t, block_q, block_k, causal):
+    """(live, masked): the k-block steps one head's forward executes at
+    these (fitted) blocks, and how many of them the causal diagonal crosses
+    — the closed form of :func:`_live` / :func:`_crossed` over the grid."""
+    nq, nk = t // block_q, t // block_k
+    if not causal:
+        return nq * nk, 0
+    ratio = block_q // block_k
+    return ratio * nq * (nq + 1) // 2, ratio * nq
+
+
 # ------------------------------------------------------------------- forward
+
+# Row statistics (running max m, running sum l) live as (block_q, 128) f32,
+# one row per sublane row and the value replicated along the lanes — the
+# orientation of the score tile's rows, so max, sum, exp(s - m) and the
+# rescaling of acc never move a vector between lanes and sublanes.
+STAT_LANES = 128
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+
+
+def _lanes(x, n):
+    """Widen (or narrow) a lane-replicated ``(rows, STAT_LANES)`` statistic
+    to ``n`` lanes: whole-register copies when ``n`` is a multiple of the
+    lane count, a lane slice below it."""
+    w = x.shape[1]
+    if n == w:
+        return x
+    if n % w == 0:
+        return jnp.tile(x, (1, n // w))
+    if n < w:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _sub_tile(block, want):
+    """Width of the sub-tiles a block is walked in: ``want`` where it
+    divides a larger block, else the whole block."""
+    return want if block > want and block % want == 0 else block
+
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                 block_q, block_k, nk, causal, sm_scale):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    d = q_ref.shape[-1]
+    ratio = block_q // block_k
+    # The loaded tile is walked in (sub_q, sub_k) score sub-tiles: row groups
+    # are independent (the scheduler overlaps one's softmax with the next
+    # one's q @ k.T), and narrower column strips keep a stage's working set
+    # small. Measured on v5e at 1024/1024, D=128: 256 x 512 (PERF.md §6).
+    sub_q = _sub_tile(block_q, 256)
+    sub_k = _sub_tile(block_k, 512)
 
     @pl.when(ki == 0)
     def _init():
@@ -62,34 +135,66 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    live = (ki < (qi + 1) * (block_q // block_k)) if causal else (ki >= 0)
-
-    @pl.when(live)
-    def _update():
-        q = q_ref[0].astype(jnp.float32) * sm_scale      # (block_q, d)
-        k = k_ref[0].astype(jnp.float32)                 # (block_k, d)
-        v = v_ref[0].astype(jnp.float32)
-        s = q @ k.T                                      # (block_q, block_k)
-        if causal:
-            q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
-            k_pos = ki * block_k + jax.lax.iota(jnp.int32, block_k)
-            s = jnp.where(q_pos[:, None] >= k_pos[None, :], s, NEG_INF)
-        m_prev = m_ref[0, 0, :]
-        l_prev = l_ref[0, 0, :]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+    def sub_tile(r0, c0, off):
+        """Online-softmax update of rows [r0, r0 + sub_q) with columns
+        [c0, c0 + sub_k) of the loaded tile. ``off`` is None where no mask
+        is needed, else the sub-tile's first column less its first row."""
+        rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
+        v = v_ref[0, cols, :]
+        s = jax.lax.dot_general(
+            q_ref[0, rows, :], k_ref[0, cols, :], _NT,
+            preferred_element_type=jnp.float32) * sm_scale
+        if off is not None:
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(row - col >= off, s, NEG_INF)
+        m_prev = m_ref[rows, :]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = jnp.broadcast_to(
-            (l_prev * alpha + p.sum(axis=-1))[None, None, :], l_ref.shape)
-        acc_ref[0] = acc_ref[0] * alpha[:, None] + p @ v
-        m_ref[...] = jnp.broadcast_to(m_new[None, None, :], m_ref.shape)
+        p = jnp.exp(s - _lanes(m_new, sub_k))
+        l_ref[rows, :] = alpha * l_ref[rows, :] + p.sum(axis=1, keepdims=True)
+        m_ref[rows, :] = m_new
+        acc_ref[0, rows, :] = (
+            acc_ref[0, rows, :] * _lanes(alpha, d)
+            + jax.lax.dot_general(p.astype(v.dtype), v, _NN,
+                                  preferred_element_type=jnp.float32))
+
+    def below():
+        """A block wholly below the diagonal (or non-causal): no mask. The
+        row groups are one traced body, unrolled when lowered."""
+        def row_group(i, carry):
+            for c0 in range(0, block_k, sub_k):
+                sub_tile(pl.multiple_of(i * sub_q, sub_q), c0, None)
+            return carry
+        jax.lax.fori_loop(0, block_q // sub_q, row_group, 0, unroll=True)
+
+    def crossed(first_col):
+        """A block the diagonal crosses; ``first_col`` is its first column
+        less the q block's first row (static). Sub-tiles wholly above the
+        diagonal are skipped, those it crosses are masked, those below it
+        are not."""
+        for r0 in range(0, block_q, sub_q):
+            for c0 in range(0, block_k, sub_k):
+                off = first_col + c0 - r0
+                if off <= sub_q - 1:
+                    sub_tile(r0, c0, off if off + sub_k - 1 > 0 else None)
+
+    if causal:
+        # Two bodies: blocks wholly below the diagonal never build a mask;
+        # the ``ratio`` blocks the diagonal crosses each know where.
+        pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k)))(below)
+        for j in range(ratio):
+            pl.when(ki == qi * ratio + j)(
+                functools.partial(crossed, j * block_k))
+    else:
+        below()
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        l = l_ref[0, 0, :]
-        o_ref[0] = (acc_ref[0] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(
-            (m_ref[0, 0, :] + jnp.log(l))[None, :], lse_ref.shape[1:])
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[0] / _lanes(l, d)).astype(o_ref.dtype)
+        # the one move per q block: rows down the sublanes -> along the lanes
+        lse_ref[0] = (m_ref[...] + jnp.log(l)).T[:lse_ref.shape[1]]
 
 
 # ---------------------------------------------------------------- backward dQ
@@ -103,7 +208,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
 
-    live = (ki < (qi + 1) * (block_q // block_k)) if causal else (ki >= 0)
+    live = _live(qi, ki, block_q, block_k) if causal else (ki >= 0)
 
     @pl.when(live)
     def _update():
@@ -145,11 +250,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    # first q-block whose rows can see this k-block
-    live = (qi >= (ki * block_k) // block_q) if causal else (qi >= 0)
-
-    @pl.when(live)
-    def _update():
+    def update(masked):
         k = k_ref[0].astype(jnp.float32)                 # (block_k, d)
         v = v_ref[0].astype(jnp.float32)
         q = q_ref[0].astype(jnp.float32)                 # (block_q, d)
@@ -157,7 +258,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         lse = lse_ref[0, 0]                              # (block_q,)
         delta = delta_ref[0, 0]
         s = (q @ k.T) * sm_scale
-        if causal:
+        if masked:
             q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
             k_pos = ki * block_k + jax.lax.iota(jnp.int32, block_k)
             s = jnp.where(q_pos[:, None] >= k_pos[None, :], s, NEG_INF)
@@ -165,6 +266,17 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dv_acc_ref[0] = dv_acc_ref[0] + p.T @ do
         ds = p * (do @ v.T - delta[:, None])
         dk_acc_ref[0] = dk_acc_ref[0] + (ds.T @ q) * sm_scale
+
+    if causal:
+        # The mask on the blocks the diagonal crosses only, as in the
+        # forward (this kernel's transposes leave the vector units less
+        # slack than dq's: it gains 2.5% at 16384 / 1024 / 1024, dq nothing).
+        live = _live(qi, ki, block_q, block_k)
+        crossed = _crossed(qi, ki, block_q, block_k)
+        pl.when(live & crossed)(functools.partial(update, True))
+        pl.when(jnp.logical_not(crossed))(functools.partial(update, False))
+    else:
+        update(False)
 
     @pl.when(j == nq * group - 1)
     def _finalize():
@@ -195,11 +307,13 @@ def _fit_block(t, want, quantum):
 
 
 # Default kernel tiles — the single source of truth (Block/TransformerLM
-# and the benchmark read these). Measured by the r3 sweep
+# and the benchmark read these). Chosen by the r3 sweep
 # (examples/transformer_benchmark.py --sweep-blocks, table in
-# docs/benchmarks.md): 1024/1024 wins at every feasible sequence length on
-# v5e at D=64 (+12% over the old 1024/512 at seq 4k, +27% at 16k);
-# block_q=2048 exceeds the backward kernel's scoped VMEM (19.3M > 16M).
+# docs/benchmarks.md) at D=64, with the forward the kernels had then:
+# 1024/1024 won at every feasible sequence length on v5e (+12% over the old
+# 1024/512 at seq 4k, +27% at 16k); block_q=2048 exceeds the backward
+# kernel's scoped VMEM (19.3M > 16M). Not re-swept at D=128 since the
+# forward's cost per block step changed (PERF.md §7).
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 
@@ -262,14 +376,29 @@ def flash_attention(q, k, v, causal: bool = True,
     ``block_q`` and ``block_q`` of ``block_k`` (both clamp down to the
     sequence length for short inputs; the defaults measured fastest on v5e
     at d=64 — bigger blocks amortize scratch round-trips and feed the MXU
-    wider). ``interpret=True`` runs the kernels in the Pallas interpreter
-    (CPU tests); the default compiles them for the TPU and raises on a
-    machine that has none."""
+    wider). q, k and v go to the MXU in the dtype they arrive in; the
+    softmax statistics, the accumulators and ``exp`` are f32 throughout.
+    ``interpret=True`` runs the kernels in the Pallas interpreter (CPU
+    tests); the default compiles them for the TPU and raises on a machine
+    that has none."""
     out, _ = _fwd(q, k, v, causal, block_q, block_k, interpret)
     return out
 
 
 def _fwd(q, k, v, causal, block_q, block_k, interpret):
+    from ..metrics import record_flash_plan
+    t = q.shape[1]
+    record_flash_plan(*block_census(
+        t, *_check_blocks(t, block_q, block_k, interpret), causal))
+    return _fwd_call(q, k, v, causal, block_q, block_k, interpret)
+
+
+# The calls are jitted so that the layers of a model, which call them with
+# one signature, share ONE traced and lowered copy of each kernel: the
+# kernels' bodies are unrolled and cost seconds to trace and lower, and a
+# step is traced and lowered on every start, warm or cold.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _fwd_call(q, k, v, causal, block_q, block_k, interpret):
     b, t, h, d = q.shape
     h, hkv, group = _gqa_group(q, k, v)
     block_q, block_k = _check_blocks(t, block_q, block_k, interpret)
@@ -298,9 +427,9 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((b * h, 8, t), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, block_q, d), jnp.float32),   # acc
-            pltpu.VMEM((1, 8, block_q), jnp.float32),   # m
-            pltpu.VMEM((1, 8, block_q), jnp.float32),   # l
+            pltpu.VMEM((1, block_q, d), jnp.float32),        # acc
+            pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # m
+            pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # l
         ],
         interpret=interpret,
         name=FLASH_FWD,
@@ -308,6 +437,7 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret):
     return _unrows(out, b, t, h, d), (q, k, v, out, lse)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
 def _bwd_rule(causal, block_q, block_k, interpret, res, dout):
     q, k, v, out, lse = res
     b, t, h, d = q.shape

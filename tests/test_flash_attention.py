@@ -18,6 +18,16 @@ def qkv(seed=0, t=T):
     return tuple(jax.random.normal(k, (B, t, H, D), jnp.float32) for k in ks)
 
 
+def _dense(q, k, v, causal):
+    """f32 oracle for either mode, kv heads replicated for GQA."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    if causal:
+        return causal_reference(q, k, v)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (q.shape[-1] ** -0.5)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
 def test_forward_matches_oracle():
     q, k, v = qkv()
     with jax.default_matmul_precision("highest"):
@@ -38,6 +48,99 @@ def test_gradients_match_oracle():
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-6, rtol=5e-6)
+
+
+# name: (t, h, hkv, d, block_q, block_k, causal, dtype). Which of the
+# forward's two bodies each reaches (blocks below the diagonal: no mask;
+# blocks the diagonal crosses: masked, dead sub-tiles skipped):
+BODY_CASES = {
+    # 4 q blocks: 6 unmasked + 4 masked block steps, and the seam between
+    "square_blocks": (128, 2, 2, 32, 32, 32, True, jnp.float32),
+    # block_q = 2 x block_k: the diagonal crosses two k blocks per q block
+    "diagonal_crosses_two": (128, 2, 2, 32, 64, 32, True, jnp.float32),
+    "one_block": (64, 2, 2, 32, 64, 64, True, jnp.float32),
+    "non_causal": (128, 2, 2, 32, 32, 32, False, jnp.float32),
+    "gqa": (128, 4, 2, 32, 32, 32, True, jnp.float32),
+    "gqa_crosses_two": (128, 4, 2, 32, 64, 32, True, jnp.float32),
+    "bf16": (128, 2, 2, 32, 32, 32, True, jnp.bfloat16),
+    "bf16_non_causal": (128, 2, 2, 32, 32, 32, False, jnp.bfloat16),
+    # the default blocks: sub-tiles of 256 x 512 inside a 1024 x 1024 block,
+    # two of the eight wholly above the diagonal in a crossed block
+    "sub_tiles_one_block": (1024, 1, 1, 16, 1024, 1024, True, jnp.float32),
+    "sub_tiles_both_bodies": (2048, 1, 1, 16, 1024, 1024, True, jnp.float32),
+    "sub_tiles_non_causal": (1024, 1, 1, 16, 512, 1024, False, jnp.float32),
+    "sub_tiles_bf16": (2048, 1, 1, 16, 1024, 1024, True, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BODY_CASES))
+def test_both_bodies_match_oracle(case):
+    """Forward and all three gradients against the dense f32 oracle for
+    every way a block step can run."""
+    t, h, hkv, d, block_q, block_k, causal, dtype = BODY_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(t + h + block_q), 4)
+    q, k, v = (jax.random.normal(kk, (1, t, n, d), jnp.float32).astype(dtype)
+               for kk, n in zip(ks, (h, hkv, hkv)))
+    g = jax.random.normal(ks[3], q.shape, jnp.float32)
+    # bf16: the kernels feed bf16 operands (and a bf16 p) to the products,
+    # the oracle sees the same inputs in f32
+    out_tol, grad_tol = ((2e-6, 5e-6) if dtype == jnp.float32
+                         else (2e-2, 4e-2))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal, block_q, block_k, True)
+
+    def both(fn, *x):
+        out, vjp = jax.vjp(fn, *x)
+        return (out,) + vjp(g.astype(out.dtype))
+
+    with jax.default_matmul_precision("highest"):
+        got = both(flash, q, k, v)
+        want = both(lambda *x: _dense(*x, causal),
+                    *(x.astype(jnp.float32) for x in (q, k, v)))
+    for name, a, b, tol in zip(("out", "dq", "dk", "dv"), got, want,
+                               (out_tol,) + (grad_tol,) * 3):
+        assert a.dtype == dtype
+        np.testing.assert_allclose(np.asarray(a.astype(jnp.float32)),
+                                   np.asarray(b), atol=tol, rtol=tol,
+                                   err_msg=f"{case}: {name}")
+
+
+@pytest.mark.parametrize("t,block_q,block_k,causal,live,masked", [
+    (16384, 1024, 1024, True, 136, 16),     # lm217m_long_1chip
+    (1024, 1024, 1024, True, 1, 1),         # lm217m_short_1chip
+    (16384, 1024, 1024, False, 256, 0),
+    (128, 64, 32, True, 6, 4),
+    (96, 48, 48, True, 3, 2),
+])
+def test_block_census(t, block_q, block_k, causal, live, masked):
+    """The census's closed form counts what the kernels' own predicates
+    select over the grid."""
+    from horovod_tpu.ops.flash_attention import (_crossed, _live,
+                                                 block_census)
+
+    assert block_census(t, block_q, block_k, causal) == (live, masked)
+    if causal:
+        grid = [(qi, ki) for qi in range(t // block_q)
+                for ki in range(t // block_k)
+                if _live(qi, ki, block_q, block_k)]
+        assert len(grid) == live
+        assert sum(_crossed(qi, ki, block_q, block_k)
+                   for qi, ki in grid) == masked
+
+
+@pytest.mark.parametrize("t,block,causal,share", [
+    (128, 32, True, 0.6),       # 10 live block steps, 4 on the diagonal
+    (64, 64, True, 0.0),        # one block per row: always the masked body
+    (128, 32, False, 1.0),
+])
+def test_unmasked_share_gauge_after_a_traced_call(t, block, causal, share):
+    from horovod_tpu.metrics import registry
+
+    q, k, v = qkv(7, t=t)
+    flash_attention(q, k, v, causal, block, block, True)
+    got = registry().snapshot()["gauges"]["horovod_flash_unmasked_block_share"]
+    assert got == pytest.approx(share)
 
 
 def test_gqa_matches_replicated_oracle():
@@ -80,10 +183,7 @@ def test_non_causal_full_softmax():
     q, k, v = qkv(2)
     with jax.default_matmul_precision("highest"):
         out = flash_attention(q, k, v, False, 32, 32, True)
-        # dense non-causal oracle
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (D ** -0.5)
-        p = jax.nn.softmax(s, axis=-1)
-        ref = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+        ref = _dense(q, k, v, False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-6, rtol=2e-6)
 
@@ -152,16 +252,11 @@ def test_non_causal_gradients_match_oracle():
     q, k, v = qkv(5)
     g = jax.random.normal(jax.random.PRNGKey(6), q.shape, jnp.float32)
 
-    def dense_nc(q, k, v):
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (D ** -0.5)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-
     with jax.default_matmul_precision("highest"):
         gf = jax.grad(lambda q, k, v: jnp.sum(
             flash_attention(q, k, v, False, 32, 32, True) * g), argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(lambda q, k, v: jnp.sum(
-            dense_nc(q, k, v) * g), argnums=(0, 1, 2))(q, k, v)
+            _dense(q, k, v, False) * g), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-6, rtol=5e-6)

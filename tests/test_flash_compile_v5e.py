@@ -1,0 +1,73 @@
+"""The three flash kernels COMPILED for a described v5e at the benchmark
+cells' shapes (no chip attached, nothing runs): what interpret mode cannot
+see — scoped VMEM, tiling and layout faults of a kernel edit — is refused
+here, on the CPU, by the TPU's own compiler.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU's library, and every xdist worker imports
+every test file. Keep these tests in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from horovod_tpu.common.device_names import (FLASH_BWD_DKV, FLASH_BWD_DQ,
+                                             FLASH_FWD)
+from horovod_tpu.ops.flash_attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q,
+                                             flash_attention)
+
+# (B, T, H, D) of lm217m_long_1chip and lm217m_short_1chip (bf16 activations)
+CELL_SHAPES = {"long": (1, 16384, 8, 128), "short": (16, 1024, 8, 128)}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler on this machine
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one: keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _flash(q, k, v):
+    return flash_attention(q, k, v, True, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
+
+
+def _forward_backward(q, k, v):
+    return jax.grad(lambda *a: jnp.sum(_flash(*a).astype(jnp.float32)),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+@pytest.mark.parametrize("fn,kernels", [
+    (_flash, (FLASH_FWD,)),
+    (_forward_backward, (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)),
+], ids=["forward", "forward_backward"])
+def test_kernels_compile_for_v5e(one_chip, no_persistent_cache, cell, fn,
+                                 kernels):
+    x = jax.ShapeDtypeStruct(CELL_SHAPES[cell], jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    assert "tpu_custom_call" in text
+    for name in kernels:
+        assert name in text, f"{name} is not in the compiled module"
