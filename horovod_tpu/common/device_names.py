@@ -15,3 +15,7 @@ FUSION_PACK = "hvd_fusion_pack"         # fuse + compress + the wire cast
 FUSION_UNPACK = "hvd_fusion_unpack"     # the cast back + decompress + unfuse
 OPTIMIZER_UPDATE = "hvd_optimizer_update"   # the wrapped optax update
 FUSED_ALLREDUCE = "hvd_fused_allreduce_k"   # + the number of buckets
+MOE_ROUTE = "hvd_moe_route"             # softmax + top-k of the router
+MOE_DISPATCH = "hvd_moe_dispatch"       # sort by expert + gather into that order
+MOE_EXPERTS = "hvd_moe_experts"         # the grouped SwiGLU products
+MOE_COMBINE = "hvd_moe_combine"         # back to token order + weighted sum

@@ -1,14 +1,27 @@
-"""Flax MoE layer for the transformer — switch-style top-1 routing with the
-same capacity/dispatch math as ops/moe.py, expressed densely so it drops
-into any model. Expert parallelism at scale comes from GSPMD: shard `w_in`/
-`w_out` with PartitionSpec('ep', None, None) (see ep_param_specs) and XLA
-partitions the expert einsums and inserts the token exchanges — the
-explicitly scheduled shard_map twin lives in ops/moe.py.
+"""Flax MoE layer for the transformer, in the two routings ``ops/moe.py`` has.
 
-The router's load-balancing auxiliary loss is sowed under
-intermediates/"moe_lb_loss"; training loops add
-`sum(intermediates) * aux_weight` to the task loss (Switch Transformer
-recipe, coefficient ~1e-2).
+``top_k = 0`` (the default) is the switch form: top-1 routing with a
+capacity, overflow dropped, ungated ReLU experts ``w_in`` / ``w_out``,
+expressed densely (scatter into an (E, C, D) buffer, two einsums). Expert
+parallelism at scale comes from GSPMD: shard the expert tensors with
+PartitionSpec('ep', None, None) (see ep_param_specs) and XLA partitions the
+expert einsums and inserts the token exchanges - the explicitly scheduled
+shard_map twin is ``ops.moe.moe_apply``. ROADMAP C2 retires this form.
+
+``top_k > 0`` is OLMoE's (arXiv:2409.02060): softmax then the ``top_k``
+largest probabilities, not renormalised, NO capacity and no dropped pair,
+SwiGLU experts ``w_gate`` / ``w_up`` / ``w_down`` of width ``hidden`` (a
+number of its own, 1024 = dim / 2 in OLMoE-1B-7B), computed as grouped
+products over the pairs sorted by expert (``ops.moe.dropless_experts``). All
+experts live with the tokens (data-parallel replicas).
+
+What the layer sows under ``intermediates`` (read with
+``mutable=["intermediates"]``; nothing is computed for a caller that does
+not): ``moe_lb_loss`` (both forms), and in the top-k form ``moe_z_loss``,
+``moe_router_logits`` (N, E; for ``ops.moe.record_expert_load``) and
+``moe_chosen_experts`` (N, top_k).
+:func:`aux_losses` sums the two losses over the layers for the caller's loss
+function, which multiplies them by its coefficients (OLMoE: 0.01 and 0.001).
 """
 
 from __future__ import annotations
@@ -19,7 +32,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.moe import load_balancing_loss, top1_route
+from ..ops.moe import (dropless_experts, load_balancing_loss, router_z_loss,
+                       top1_route, topk_load_balancing_loss, topk_route)
 
 
 class MoEMLP(nn.Module):
@@ -28,11 +42,14 @@ class MoEMLP(nn.Module):
     n_experts: int
     capacity_factor: float = 1.25
     dtype: Any = jnp.bfloat16
+    top_k: int = 0      # > 0: OLMoE's dropless top-k SwiGLU form
 
     @nn.compact
     def __call__(self, x):
         b, t, d = x.shape
         tokens = x.reshape(-1, d)
+        if self.top_k > 0:
+            return self._topk_swiglu(tokens).reshape(b, t, d)
         n_tok = b * t
         capacity = max(int(self.capacity_factor * n_tok / self.n_experts), 1)
 
@@ -56,6 +73,44 @@ class MoEMLP(nn.Module):
         out = y[expert, pos] * (prob * keep).astype(self.dtype)[:, None]
         return out.reshape(b, t, d)
 
+    def _topk_swiglu(self, tokens):
+        d, e, h = tokens.shape[-1], self.n_experts, self.hidden
+        if not 0 < self.top_k <= e:
+            raise ValueError(f"top_k {self.top_k} of {e} experts")
+        router = self.param("router", nn.initializers.lecun_normal(), (d, e),
+                            jnp.float32)
+        # the expert axis is a batch axis: each expert's fan-in is its own
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        w_gate, w_up, w_down = (
+            self.param(name, init, shape, jnp.float32).astype(self.dtype)
+            for name, shape in (("w_gate", (e, d, h)), ("w_up", (e, d, h)),
+                                ("w_down", (e, h, d))))
+        # The router runs in float32 at full precision whatever the
+        # activations' dtype: 2*N*D*E operations, and a coarser product
+        # flips a token's 8th expert against its 9th far more often.
+        logits = jnp.dot(tokens.astype(jnp.float32), router,
+                         precision=jax.lax.Precision.HIGHEST)
+        probs, weights, experts = topk_route(logits, self.top_k)
+        self.sow("intermediates", "moe_lb_loss",
+                 topk_load_balancing_loss(probs, experts))
+        self.sow("intermediates", "moe_z_loss", router_z_loss(logits))
+        self.sow("intermediates", "moe_router_logits", logits)
+        self.sow("intermediates", "moe_chosen_experts", experts)
+        return dropless_experts(tokens.astype(self.dtype), weights, experts,
+                                w_gate, w_up, w_down)
+
+
+def aux_losses(intermediates):
+    """(load-balancing loss, router z-loss), each summed over the MoE layers
+    found in a model's ``intermediates`` collection; zeros where there is
+    none."""
+    sums = {"moe_lb_loss": 0.0, "moe_z_loss": 0.0}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
+        for name in sums:
+            if any(getattr(p, "key", None) == name for p in path):
+                sums[name] = sums[name] + leaf
+    return sums["moe_lb_loss"], sums["moe_z_loss"]
+
 
 def ep_param_specs(params, ep_axis: str = "ep"):
     """PartitionSpecs sharding every MoE expert tensor over ``ep_axis``
@@ -66,7 +121,8 @@ def ep_param_specs(params, ep_axis: str = "ep"):
     def spec(path, leaf):
         names = "/".join(str(getattr(p, "key", getattr(p, "name", "")))
                          for p in path)
-        if ("w_in" in names or "w_out" in names) and leaf.ndim == 3:
+        if leaf.ndim == 3 and any(w in names for w in (
+                "w_in", "w_out", "w_gate", "w_up", "w_down")):
             return P(ep_axis, None, None)
         return P()
 

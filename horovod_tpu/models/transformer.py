@@ -53,7 +53,11 @@ class Block(nn.Module):
     mlp_ratio: int = 4
     dtype: Any = jnp.bfloat16
     sp_axis: Optional[str] = None  # sequence-parallel mesh axis (ring attention)
-    moe_experts: int = 0           # >0: switch-MoE MLP instead of dense
+    moe_experts: int = 0           # >0: MoE MLP (models/moe.py) instead of dense
+    moe_top_k: int = 0             # >0: OLMoE's dropless top-k SwiGLU experts
+    moe_hidden: Optional[int] = None    # one expert's width (None: mlp_ratio * dim)
+    qk_norm: bool = False          # RMSNorm over the whole projected q and k
+    rms_norm_eps: float = 1e-6
     attention: str = "dense"       # "dense" | "flash" (pallas fused kernel)
     kv_heads: Optional[int] = None  # < heads: grouped-query attention
     # flash kernel tile sizes (None = kernel defaults; sweep with
@@ -75,7 +79,7 @@ class Block(nn.Module):
         if kvh < 1 or self.heads % kvh:
             raise ValueError(
                 f"kv_heads {kvh} must be >= 1 and divide heads {self.heads}")
-        h = nn.RMSNorm(dtype=self.dtype)(x)
+        h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
         b, t = x.shape[0], x.shape[1]
         if kvh == self.heads:
             qkv = nn.Dense(3 * self.dim, use_bias=False, dtype=self.dtype, name="qkv")(h)
@@ -86,6 +90,13 @@ class Block(nn.Module):
             kv = nn.Dense(2 * kvh * head_dim, use_bias=False,
                           dtype=self.dtype, name="kv_proj")(h)
             k, v = jnp.split(kv, 2, axis=-1)
+        if self.qk_norm:
+            # OLMoE: over ALL heads x head_dim features, before the split
+            # into heads, each with a weight of that length.
+            q = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype,
+                           name="q_norm")(q)
+            k = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype,
+                           name="k_norm")(k)
         q = _rope(q.reshape(b, t, self.heads, head_dim), positions)
         k = _rope(k.reshape(b, t, kvh, head_dim), positions)
         v = v.reshape(b, t, kvh, head_dim)
@@ -121,13 +132,15 @@ class Block(nn.Module):
             attn = causal_attention(q, k, v)
         attn = attn.reshape(b, t, self.dim)
         x = x + nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="o_proj")(attn)
-        h = nn.RMSNorm(dtype=self.dtype)(x)
+        h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
         if self.moe_experts > 0:
             from .moe import MoEMLP
 
-            x = x + MoEMLP(dim=self.dim, hidden=self.mlp_ratio * self.dim,
-                           n_experts=self.moe_experts, dtype=self.dtype,
-                           name="moe")(h)
+            hidden = (self.mlp_ratio * self.dim if self.moe_hidden is None
+                      else self.moe_hidden)
+            x = x + MoEMLP(dim=self.dim, hidden=hidden,
+                           n_experts=self.moe_experts, top_k=self.moe_top_k,
+                           dtype=self.dtype, name="moe")(h)
         else:
             h = nn.Dense(self.mlp_ratio * self.dim, use_bias=False, dtype=self.dtype, name="mlp_in")(h)
             h = nn.gelu(h)
@@ -148,10 +161,19 @@ class TransformerLM(nn.Module):
     mlp_ratio: int = 4
     dtype: Any = jnp.bfloat16
     sp_axis: Optional[str] = None
-    # >0 turns every `moe_every`-th block's MLP into a switch-MoE with this
-    # many experts (models/moe.py; shard experts over 'ep' via ep_param_specs)
+    # >0 turns every `moe_every`-th block's MLP into a mixture of this many
+    # experts (models/moe.py): the switch form (top-1, capacity, ReLU) by
+    # default; with moe_top_k > 0 OLMoE's dropless top-k SwiGLU experts of
+    # width moe_hidden, whose auxiliary losses the caller reads with
+    # models.moe.aux_losses. Shard experts over 'ep' via ep_param_specs.
     moe_experts: int = 0
     moe_every: int = 2
+    moe_top_k: int = 0
+    moe_hidden: Optional[int] = None
+    # OLMoE's QK-norm: RMSNorm over the whole projected q and k (all heads),
+    # before the split into heads and RoPE.
+    qk_norm: bool = False
+    rms_norm_eps: float = 1e-6      # every RMSNorm of the model
     # "flash" runs attention through the pallas fused kernel (O(T*D) HBM
     # traffic; trains at sequence lengths where the dense schedule cannot
     # even compile — measured on v5e: seq 8192 dense OOMs the compiler,
@@ -208,9 +230,13 @@ class TransformerLM(nn.Module):
                 moe_experts=(self.moe_experts
                              if self.moe_experts > 0 and i % self.moe_every == self.moe_every - 1
                              else 0),
+                moe_top_k=self.moe_top_k,
+                moe_hidden=self.moe_hidden,
+                qk_norm=self.qk_norm,
+                rms_norm_eps=self.rms_norm_eps,
                 name=f"block_{i}",
             )(x, positions)
-        x = nn.RMSNorm(dtype=self.dtype)(x)
+        x = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
         head = nn.Dense(self.vocab, use_bias=False, dtype=self.logits_dtype,
                         name="lm_head")
         if return_hidden:

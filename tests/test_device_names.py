@@ -48,6 +48,27 @@ def flash_text():
     return lowered.as_text(debug_info=True)
 
 
+@pytest.fixture(scope="module")
+def moe_text():
+    from horovod_tpu.models import MoEMLP
+
+    layer = MoEMLP(dim=16, hidden=8, n_experts=4, top_k=2, dtype=jnp.float32)
+    x = jnp.ones((1, 8, 16), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    lowered = jax.jit(jax.grad(lambda p: layer.apply({"params": p}, x).sum())
+                      ).lower(params)
+    return {True: lowered.as_text(debug_info=True),
+            False: lowered.as_text(debug_info=False)}
+
+
+@pytest.mark.parametrize("name", [names.MOE_ROUTE, names.MOE_DISPATCH,
+                                  names.MOE_EXPERTS, names.MOE_COMBINE])
+def test_moe_scope_is_in_the_lowered_module_as_metadata_only(name, moe_text):
+    assert name in moe_text[True]
+    assert name not in moe_text[False]
+    assert "dot_general" in moe_text[False]     # the work itself is there
+
+
 @pytest.mark.parametrize("name", [names.FUSION_PACK, names.FUSION_UNPACK,
                                   names.OPTIMIZER_UPDATE,
                                   names.FUSED_ALLREDUCE,
