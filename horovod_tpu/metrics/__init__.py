@@ -33,6 +33,7 @@ from .overlap import (  # noqa: F401
     last_tier_plan,
     last_wire_plan,
     measure_overlap,
+    record_chunked_loss_plan,
     record_flash_plan,
     record_plan,
     record_shard_plan,

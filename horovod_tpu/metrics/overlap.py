@@ -160,6 +160,19 @@ def record_flash_plan(live: int, masked: int) -> float:
     return share
 
 
+def record_chunked_loss_plan(products: int) -> None:
+    """Record how many vocabulary products a chunk the latest traced
+    ``models.transformer.chunked_lm_loss`` issues (trace time, once per
+    compile — same reasoning as record_wire_plan): 1 when the call is not
+    differentiated (logits), 3 under ``jax.grad`` (logits, d-hidden,
+    d-kernel, all in the forward loop; no logits computed again)."""
+    registry().gauge(
+        "horovod_chunked_loss_products_per_chunk",
+        help="vocabulary products a chunk of the latest traced "
+             "chunked_lm_loss: 1 not differentiated, 3 with its gradients"
+    ).set(products)
+
+
 # Latest fabric-tier plan of the hierarchical compiled path (ISSUE 7):
 # {"hierarchical": bool, "ici_wire": str, "dcn_wire": str, "ici_size": int,
 #  "bytes_per_step": {"ici": n, "dcn": n}, "buckets": int}.

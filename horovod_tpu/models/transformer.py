@@ -15,6 +15,7 @@ none; the TPU build makes it first-class). Design:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -252,35 +253,99 @@ class TransformerLM(nn.Module):
 
 def chunked_lm_loss(hidden, head_kernel, targets, chunk: int = 2048):
     """Next-token cross entropy without ever materializing the full
-    (B, T, vocab) logits: map the lm_head + softmax-CE over sequence
-    chunks, with the chunk body checkpointed so the backward pass also
-    re-computes each chunk's logits instead of saving them.
+    (B, T, vocab) logits: the lm_head + softmax-CE run over sequence chunks,
+    and under ``jax.grad`` each chunk's gradients are formed in the same
+    loop iteration that computes its logits, so no logits are saved and
+    none are computed a second time (three vocabulary products a chunk:
+    logits, d-hidden, d-kernel; a call that is not differentiated issues
+    the first alone).
 
     Use with ``model.apply(..., return_hidden=True)``; ``head_kernel`` is
-    ``params["lm_head"]["kernel"]``. Peak extra memory is one chunk's
-    logits (B·chunk·vocab f32) in both passes — the difference between
-    OOM and training at 32k+ tokens with a 32k vocab.
-    """
-    import optax
+    ``params["lm_head"]["kernel"]``. Peak extra memory is at most one chunk's
+    logits and their gradient (B·chunk·vocab f32 each) plus, when
+    differentiated, the f32 (d, vocab) accumulator of the kernel's
+    gradient — the difference between OOM and training at 32k+ tokens
+    with a 32k vocab. The loss is the mean over every position; logits,
+    softmax and the kernel's gradient are float32 whatever ``hidden``'s
+    dtype, and the three products follow ``jax.default_matmul_precision``
+    as a plain ``@`` does.
 
+    The gradients come from a ``jax.custom_vjp``: reverse mode only, once.
+    Forward mode (``jvp`` / ``jacfwd`` / ``linearize``) and second
+    derivatives (``hessian``) through this loss are not available; nothing
+    in horovod_tpu, benchmarks/ or examples/ takes one.
+    """
     b, t, d = hidden.shape
     if chunk <= 0:
         raise ValueError(f"loss chunk must be positive, got {chunk}")
     chunk = min(chunk, t)
     if t % chunk:
         raise ValueError(f"sequence {t} not divisible by loss chunk {chunk}")
-    n = t // chunk
-    h = hidden.reshape(b, n, chunk, d).swapaxes(0, 1)    # (n, b, chunk, d)
-    tg = targets.reshape(b, n, chunk).swapaxes(0, 1)
+    return _chunked_lm_loss(hidden, head_kernel, targets, chunk)
 
-    @jax.checkpoint
+
+def _loss_chunks(hidden, targets, chunk):
+    b, t, d = hidden.shape
+    n = t // chunk
+    return (hidden.reshape(b, n, chunk, d).swapaxes(0, 1),  # (n, b, chunk, d)
+            targets.reshape(b, n, chunk).swapaxes(0, 1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _chunked_lm_loss(hidden, head_kernel, targets, chunk):
+    import optax
+
+    from ..metrics import record_chunked_loss_plan
+
+    record_chunked_loss_plan(1)
+
     def one(ht):
         hc, tc = ht
         logits = hc.astype(jnp.float32) @ head_kernel    # (b, chunk, vocab)
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, tc).mean()
 
-    return jax.lax.map(one, (h, tg)).mean()
+    return jax.lax.map(one, _loss_chunks(hidden, targets, chunk)).mean()
+
+
+def _chunked_lm_loss_fwd(hidden, head_kernel, targets, chunk):
+    """The loss AND its two gradients (for a cotangent of 1) from one scan
+    over chunks; the residuals are the gradients themselves."""
+    from ..metrics import record_chunked_loss_plan
+
+    record_chunked_loss_plan(3)
+    b, t, d = hidden.shape
+    vocab = head_kernel.shape[-1]
+
+    def one(d_kernel, ht):
+        hc, tc = ht
+        hf = hc.astype(jnp.float32)
+        logits = hf @ head_kernel                        # (b, chunk, vocab)
+        shifted = logits - logits.max(-1, keepdims=True)
+        e = jnp.exp(shifted)
+        z = e.sum(-1, keepdims=True)
+        hit = jax.lax.broadcasted_iota(tc.dtype, shifted.shape, 2) == tc[..., None]
+        loss = (jnp.log(z[..., 0])
+                - jnp.where(hit, shifted, 0.0).sum(-1)).mean()
+        dlogits = (e / z - hit) / (b * t)
+        d_hidden = (dlogits @ head_kernel.T).astype(hidden.dtype)
+        d_kernel = d_kernel + jnp.tensordot(hf, dlogits, ((0, 1), (0, 1)))
+        return d_kernel, (loss, d_hidden)
+
+    d_kernel, (losses, d_hidden) = jax.lax.scan(
+        one, jnp.zeros((d, vocab), jnp.float32),
+        _loss_chunks(hidden, targets, chunk))
+    return losses.mean(), (d_hidden.swapaxes(0, 1).reshape(b, t, d),
+                           d_kernel.astype(head_kernel.dtype))
+
+
+def _chunked_lm_loss_bwd(chunk, gradients, g):
+    d_hidden, d_kernel = gradients
+    return ((g * d_hidden).astype(d_hidden.dtype),
+            (g * d_kernel).astype(d_kernel.dtype), None)
+
+
+_chunked_lm_loss.defvjp(_chunked_lm_loss_fwd, _chunked_lm_loss_bwd)
 
 
 def tp_param_specs(params, tp_axis: str = "tp"):

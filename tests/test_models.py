@@ -105,8 +105,9 @@ def test_transformer_remat_matches_no_remat():
 
 @pytest.mark.slow
 def test_chunked_lm_loss_matches_full():
-    """Chunked loss head: identical loss AND gradients to the full-logits
-    path (the chunk body is checkpointed; only shapes change)."""
+    """Chunked loss head under a whole model: identical loss AND gradients
+    to the full-logits path (the gradients come out of the loss's forward
+    loop; the tier-1 cases on a bare head are below)."""
     import optax
 
     from horovod_tpu.models import TransformerLM
@@ -135,3 +136,97 @@ def test_chunked_lm_loss_matches_full():
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5), g1, g0)
+
+
+def _tiny_head(dtype, b, t=16, d=8, vocab=40):
+    hidden = jax.random.normal(jax.random.PRNGKey(0), (b, t, d), dtype)
+    kernel = 0.3 * jax.random.normal(jax.random.PRNGKey(1), (d, vocab))
+    targets = jax.random.randint(jax.random.PRNGKey(2), (b, t), 0, vocab)
+    return hidden, kernel, targets
+
+
+def _full_logits_loss(hidden, kernel, targets):
+    return optax.softmax_cross_entropy_with_integer_labels(
+        hidden.astype(jnp.float32) @ kernel, targets).mean()
+
+
+def _assert_head_grads(got, want, hidden_dtype, scale=1.0):
+    assert got[0].dtype == hidden_dtype and got[1].dtype == jnp.float32
+    # bf16 hidden: d_hidden is rounded to bf16 (eps 2^-8) on both sides.
+    tol = 1e-5 if hidden_dtype == jnp.float32 else 2 ** -7
+    np.testing.assert_allclose(np.asarray(got[0], np.float32),
+                               scale * np.asarray(want[0], np.float32),
+                               atol=tol * 1e-2, rtol=tol)
+    np.testing.assert_allclose(np.asarray(got[1]), scale * np.asarray(want[1]),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("b", [1, 3])
+@pytest.mark.parametrize("chunks", [1, 2, 4])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_chunked_lm_loss_and_gradients_match_full_logits(dtype, chunks, b):
+    """The loss (differentiated or not) and both gradients of the chunked
+    head against optax's cross entropy on the full logits."""
+    from horovod_tpu.models.transformer import chunked_lm_loss
+
+    hidden, kernel, targets = _tiny_head(dtype, b)
+    chunk = hidden.shape[1] // chunks
+    with jax.default_matmul_precision("highest"):
+        want, want_grads = jax.value_and_grad(_full_logits_loss, (0, 1))(
+            hidden, kernel, targets)
+        plain = chunked_lm_loss(hidden, kernel, targets, chunk)
+        got, got_grads = jax.value_and_grad(chunked_lm_loss, (0, 1))(
+            hidden, kernel, targets, chunk)
+    np.testing.assert_allclose(float(plain), float(want), atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(float(got), float(want), atol=1e-5, rtol=1e-5)
+    _assert_head_grads(got_grads, want_grads, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_chunked_lm_loss_scales_with_its_cotangent(dtype):
+    from horovod_tpu.models.transformer import chunked_lm_loss
+
+    hidden, kernel, targets = _tiny_head(dtype, 3)
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(_full_logits_loss, (0, 1))(hidden, kernel, targets)
+        got = jax.grad(lambda h, w: 3.0 * chunked_lm_loss(h, w, targets, 4),
+                       (0, 1))(hidden, kernel)
+    _assert_head_grads(got, want, dtype, scale=3.0)
+
+
+@pytest.mark.parametrize("chunk,message", [
+    (0, "loss chunk must be positive, got 0"),
+    (5, "sequence 16 not divisible by loss chunk 5"),
+])
+def test_chunked_lm_loss_refuses_a_chunk_that_does_not_fit(chunk, message):
+    from horovod_tpu.models.transformer import chunked_lm_loss
+
+    with pytest.raises(ValueError, match=message):
+        chunked_lm_loss(*_tiny_head(jnp.float32, 1), chunk)
+
+
+def test_chunked_lm_loss_issues_three_products_a_chunk_and_no_remat():
+    """The mechanism: differentiated, the one loop over chunks holds the
+    logits product and the two gradient products and nothing is checkpointed
+    (no logits computed again); not differentiated, the logits product
+    alone. The gauge says which of the two was traced last."""
+    from horovod_tpu.metrics import registry
+    from horovod_tpu.models.transformer import chunked_lm_loss
+
+    hidden, kernel, targets = _tiny_head(jnp.bfloat16, 3)
+
+    def gauge():
+        return registry().snapshot()["gauges"][
+            "horovod_chunked_loss_products_per_chunk"]
+
+    def loss(h, w):
+        return chunked_lm_loss(h, w, targets, 4)
+
+    # The loop is rolled: its body, and so each product, is printed once.
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1)))(hidden, kernel))
+    assert text.count(" scan[") == 1 and text.count("dot_general[") == 3
+    assert "remat" not in text and "checkpoint" not in text
+    assert gauge() == 3
+    text = str(jax.make_jaxpr(loss)(hidden, kernel))
+    assert text.count(" scan[") == 1 and text.count("dot_general[") == 1
+    assert gauge() == 1
