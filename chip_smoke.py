@@ -27,7 +27,7 @@ and its ``compile_s``; any failure raises, so the exit code is non-zero):
                  attention="flash")``, seq 4096, batch 1 per chip, AdamW.
 6. four_chip   — only with >= 4 devices: ring-flash over an ``sp`` axis of 4,
                  the sharded and hierarchical optimizers on 2x2 meshes against
-                 flat data parallelism, the ``all_to_all`` MoE and ``ppermute``
+                 flat data parallelism, the data-parallel MoE and ``ppermute``
                  pipeline legs of ``__graft_entry__`` on the real devices.
 
 The last line of stdout is one JSON object,
@@ -486,13 +486,13 @@ def phase_four_chip(t_local: int = 1024, interpret: bool = False):
     t0 = time.monotonic()
     ring_errs = _ring_flash_leg(devices, t_local, interpret)
     _optimizer_parity_leg(devices)
-    legs._moe_ep_step(4)        # all_to_all expert parallelism
+    legs._moe_dp_step(4)        # dropless experts, data parallel
     legs._pipeline_pp_step(4)   # ppermute pipeline, against its oracle
     report("four_chip", compile_s=f"{time.monotonic() - t0:.2f}",
            ring_flash_t_local=t_local,
            ring_flash_max_abs_err=ring_errs,
            ring_flash_tol=f"{RING_REL_TOL}*max|single|",
-           sharded_2x2=True, hierarchical_2x2=True, moe_all_to_all=True,
+           sharded_2x2=True, hierarchical_2x2=True, moe_data_parallel=True,
            pipeline_ppermute=True, parity_tol=PARITY_TOL["rtol"], ok=True)
 
 

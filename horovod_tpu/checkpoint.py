@@ -284,8 +284,8 @@ def save_sharded(path: str, state: Any, plan, step: Optional[int] = None) -> Non
     the CONSOLIDATED full leaves, so it is mesh-shape independent: restore
     onto any ('batch','shard') shape, including plain DP. Consolidation
     also drops the zero-pad tail — pad garbage can never be carried in a
-    checkpoint (the fsdp pad-leak fix's checkpoint half). Rank-0-writes +
-    completion barrier, exactly like :func:`save`."""
+    checkpoint. Rank-0-writes + completion barrier, exactly like
+    :func:`save`."""
     from .parallel import sharded as _sharded
 
     save(path, _sharded.unshard_tree(state, plan), step)

@@ -1,25 +1,16 @@
-"""Flax MoE layer for the transformer, in the two routings ``ops/moe.py`` has.
+"""Flax MoE layer for the transformer: OLMoE's (arXiv:2409.02060).
 
-``top_k = 0`` (the default) is the switch form: top-1 routing with a
-capacity, overflow dropped, ungated ReLU experts ``w_in`` / ``w_out``,
-expressed densely (scatter into an (E, C, D) buffer, two einsums). Expert
-parallelism at scale comes from GSPMD: shard the expert tensors with
-PartitionSpec('ep', None, None) (see ep_param_specs) and XLA partitions the
-expert einsums and inserts the token exchanges - the explicitly scheduled
-shard_map twin is ``ops.moe.moe_apply``. ROADMAP C2 retires this form.
-
-``top_k > 0`` is OLMoE's (arXiv:2409.02060): softmax then the ``top_k``
-largest probabilities, not renormalised, NO capacity and no dropped pair,
-SwiGLU experts ``w_gate`` / ``w_up`` / ``w_down`` of width ``hidden`` (a
-number of its own, 1024 = dim / 2 in OLMoE-1B-7B), computed as grouped
-products over the pairs sorted by expert (``ops.moe.dropless_experts``). All
-experts live with the tokens (data-parallel replicas).
+Softmax, then the ``top_k`` largest probabilities, not renormalised, NO
+capacity and no dropped pair, SwiGLU experts ``w_gate`` / ``w_up`` /
+``w_down`` of width ``hidden`` (a number of its own, 1024 = dim / 2 in
+OLMoE-1B-7B), computed as grouped products over the pairs sorted by expert
+(``ops.moe.dropless_experts``). All experts live with the tokens
+(data-parallel replicas).
 
 What the layer sows under ``intermediates`` (read with
 ``mutable=["intermediates"]``; nothing is computed for a caller that does
-not): ``moe_lb_loss`` (both forms), and in the top-k form ``moe_z_loss``,
-``moe_router_logits`` (N, E; for ``ops.moe.record_expert_load``) and
-``moe_chosen_experts`` (N, top_k).
+not): ``moe_lb_loss``, ``moe_z_loss``, ``moe_router_logits`` (N, E; for
+``ops.moe.record_expert_load``) and ``moe_chosen_experts`` (N, top_k).
 :func:`aux_losses` sums the two losses over the layers for the caller's loss
 function, which multiplies them by its coefficients (OLMoE: 0.01 and 0.001).
 """
@@ -32,46 +23,21 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.moe import (dropless_experts, load_balancing_loss, router_z_loss,
-                       top1_route, topk_load_balancing_loss, topk_route)
+from ..ops.moe import (dropless_experts, router_z_loss,
+                       topk_load_balancing_loss, topk_route)
 
 
 class MoEMLP(nn.Module):
     dim: int
     hidden: int
     n_experts: int
-    capacity_factor: float = 1.25
+    top_k: int
     dtype: Any = jnp.bfloat16
-    top_k: int = 0      # > 0: OLMoE's dropless top-k SwiGLU form
 
     @nn.compact
     def __call__(self, x):
         b, t, d = x.shape
-        tokens = x.reshape(-1, d)
-        if self.top_k > 0:
-            return self._topk_swiglu(tokens).reshape(b, t, d)
-        n_tok = b * t
-        capacity = max(int(self.capacity_factor * n_tok / self.n_experts), 1)
-
-        init = nn.initializers.lecun_normal()
-        gate_w = self.param("gate", init, (d, self.n_experts), jnp.float32)
-        w_in = self.param("w_in", init, (self.n_experts, d, self.hidden),
-                          jnp.float32).astype(self.dtype)
-        w_out = self.param("w_out", init, (self.n_experts, self.hidden, d),
-                           jnp.float32).astype(self.dtype)
-
-        logits = tokens.astype(jnp.float32) @ gate_w
-        expert, prob, pos, keep = top1_route(logits, capacity)
-        self.sow("intermediates", "moe_lb_loss",
-                 load_balancing_loss(logits, expert, self.n_experts))
-
-        kept = jnp.where(keep[:, None], tokens, jnp.zeros_like(tokens))
-        disp = jnp.zeros((self.n_experts, capacity, d), self.dtype
-                         ).at[expert, pos].add(kept.astype(self.dtype))
-        h = jax.nn.relu(jnp.einsum("ecd,edh->ech", disp, w_in))
-        y = jnp.einsum("ech,ehd->ecd", h, w_out)
-        out = y[expert, pos] * (prob * keep).astype(self.dtype)[:, None]
-        return out.reshape(b, t, d)
+        return self._topk_swiglu(x.reshape(-1, d)).reshape(b, t, d)
 
     def _topk_swiglu(self, tokens):
         d, e, h = tokens.shape[-1], self.n_experts, self.hidden
@@ -121,8 +87,8 @@ def ep_param_specs(params, ep_axis: str = "ep"):
     def spec(path, leaf):
         names = "/".join(str(getattr(p, "key", getattr(p, "name", "")))
                          for p in path)
-        if leaf.ndim == 3 and any(w in names for w in (
-                "w_in", "w_out", "w_gate", "w_up", "w_down")):
+        if leaf.ndim == 3 and any(
+                w in names for w in ("w_gate", "w_up", "w_down")):
             return P(ep_axis, None, None)
         return P()
 

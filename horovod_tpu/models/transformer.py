@@ -55,7 +55,7 @@ class Block(nn.Module):
     dtype: Any = jnp.bfloat16
     sp_axis: Optional[str] = None  # sequence-parallel mesh axis (ring attention)
     moe_experts: int = 0           # >0: MoE MLP (models/moe.py) instead of dense
-    moe_top_k: int = 0             # >0: OLMoE's dropless top-k SwiGLU experts
+    moe_top_k: int = 0             # experts a token uses: 0 < it <= moe_experts
     moe_hidden: Optional[int] = None    # one expert's width (None: mlp_ratio * dim)
     qk_norm: bool = False          # RMSNorm over the whole projected q and k
     rms_norm_eps: float = 1e-6
@@ -163,10 +163,11 @@ class TransformerLM(nn.Module):
     dtype: Any = jnp.bfloat16
     sp_axis: Optional[str] = None
     # >0 turns every `moe_every`-th block's MLP into a mixture of this many
-    # experts (models/moe.py): the switch form (top-1, capacity, ReLU) by
-    # default; with moe_top_k > 0 OLMoE's dropless top-k SwiGLU experts of
-    # width moe_hidden, whose auxiliary losses the caller reads with
-    # models.moe.aux_losses. Shard experts over 'ep' via ep_param_specs.
+    # experts (models/moe.py): OLMoE's dropless SwiGLU experts of width
+    # moe_hidden, moe_top_k of them a token (MoEMLP raises a ValueError
+    # unless 0 < moe_top_k <= moe_experts), whose auxiliary losses the caller
+    # reads with models.moe.aux_losses. ep_param_specs names the expert
+    # tensors for a mesh that shards them.
     moe_experts: int = 0
     moe_every: int = 2
     moe_top_k: int = 0

@@ -1,18 +1,13 @@
 """TPU compute ops beyond stock XLA: sequence-parallel attention schedules
-(ring / Ulysses), mixture of experts (switch and dropless top-k), and a pallas
+(ring / Ulysses), mixture of experts (dropless top-k), and a pallas
 flash-attention kernel (fused, trainable) for the hot op."""
 
 from .flash_attention import flash_attention  # noqa: F401
 
 from .moe import (  # noqa: F401
-    MoEParams,
     dropless_experts,
-    init_moe_params,
-    load_balancing_loss,
-    moe_apply,
     record_expert_load,
     router_z_loss,
-    top1_route,
     topk_load_balancing_loss,
     topk_route,
 )

@@ -1,32 +1,10 @@
-"""Mixture-of-experts with expert parallelism (EP) over a mesh axis.
+"""Mixture of experts, dropless: OLMoE's routing and expert products.
 
 Beyond the reference's scope (Horovod v0.16 is data-parallel only, SURVEY.md
-§2.8) but first-class on TPU: experts shard across the ``ep`` axis and
-tokens reach their expert through a single ``lax.all_to_all`` each way — the
-canonical Switch-Transformer dispatch expressed as XLA collectives instead
-of a runtime router.
-
-Design (top-1 / switch routing, capacity-bounded, drop-on-overflow):
-
-1. Each rank routes its LOCAL tokens: softmax gate → argmax expert, position
-   within that expert's per-rank capacity C via a cumulative count; tokens
-   beyond capacity are dropped (contribute zero, standard switch behavior).
-2. Dispatch buffer (E, C, D) scatter-filled from kept tokens, viewed as
-   (ep, E_local, C, D) and exchanged with ``all_to_all``: afterwards each
-   rank holds, for each of ITS E_local experts, up to C tokens from every
-   rank.
-3. Local experts run as one batched einsum over the stacked expert weights
-   (the MXU sees one big matmul, not a Python loop over experts).
-4. The inverse ``all_to_all`` returns expert outputs to the owning ranks;
-   tokens gather their row back and scale by the gate probability.
-
-Everything is shape-static (capacity fixes the buffers), so the whole layer
-jits into one program — no host round-trips, no dynamic shapes.
-
-The second routing, beside the switch one (OLMoE, arXiv:2409.02060): softmax,
-then the ``top_k`` largest probabilities as weights, NOT renormalised, and no
-capacity - every chosen (token, expert) pair is computed, under any
-imbalance, at static shapes (:func:`topk_route`, :func:`dropless_experts`):
+§2.8). Softmax, then the ``top_k`` largest probabilities as weights, NOT
+renormalised, and no capacity (OLMoE, arXiv:2409.02060) - every chosen
+(token, expert) pair is computed, under any imbalance, at static shapes
+(:func:`topk_route`, :func:`dropless_experts`):
 
 1. the N x top_k pairs are sorted by expert (stable, so a token's rows keep
    their order inside an expert's group) and the tokens gathered into that
@@ -41,102 +19,19 @@ imbalance, at static shapes (:func:`topk_route`, :func:`dropless_experts`):
 Both permutations are gathers in the forward AND the backward pass
 (:func:`_take_rows`): the transpose of a gather is a scatter-add, and the
 inverse permutation is at hand, so the backward gathers through it instead.
-This form holds all experts on the rank that holds the tokens (data-parallel
-replicas); the ``all_to_all`` exchange of ``moe_apply`` is not part of it yet.
+All experts live on the rank that holds the tokens (data-parallel replicas).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..common import device_names
-from ..compat import axis_size
 
-EP_AXIS = "ep"
-
-
-class MoEParams(NamedTuple):
-    gate: jax.Array  # (D, E)        — replicated
-    w_in: jax.Array  # (E_local, D, H) — this rank's experts
-    w_out: jax.Array  # (E_local, H, D)
-
-
-def init_moe_params(key, dim, hidden, n_experts, ep_size, dtype=jnp.float32):
-    """Full (unsharded) parameter set; shard w_in/w_out with P('ep') on dim 0
-    (n_experts must be divisible by ep_size)."""
-    if n_experts % ep_size:
-        raise ValueError(f"{n_experts} experts not divisible by ep={ep_size}")
-    kg, k1, k2 = jax.random.split(key, 3)
-    scale = 1.0 / jnp.sqrt(dim)
-    return MoEParams(
-        gate=(jax.random.normal(kg, (dim, n_experts)) * scale).astype(dtype),
-        w_in=(jax.random.normal(k1, (n_experts, dim, hidden)) * scale).astype(dtype),
-        w_out=(jax.random.normal(k2, (n_experts, hidden, dim)) * scale).astype(dtype),
-    )
-
-
-def top1_route(logits, capacity: int):
-    """Per-token expert choice + position within the expert's capacity.
-
-    Returns (expert, prob, pos, keep): argmax expert id, its gate
-    probability, the token's slot in the (expert, capacity) buffer, and the
-    keep mask (False = overflowed capacity → dropped)."""
-    n_experts = logits.shape[-1]
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    expert = jnp.argmax(probs, axis=-1)
-    prob = jnp.take_along_axis(probs, expert[:, None], axis=-1)[:, 0]
-    onehot = jax.nn.one_hot(expert, n_experts, dtype=jnp.int32)
-    # slot = how many earlier tokens picked the same expert
-    pos = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=-1)
-    keep = pos < capacity
-    return expert, prob, pos, keep
-
-
-def moe_apply(params: MoEParams, x, capacity: int, axis_name: str = EP_AXIS):
-    """Switch-MoE forward for this rank's local tokens ``x (T, D)``; call
-    inside shard_map with tokens sharded and experts sharded over
-    ``axis_name``. Differentiable end to end (all_to_all transposes to the
-    reverse exchange)."""
-    ep = axis_size(axis_name)
-    e_local, d, _h = params.w_in.shape
-    n_experts = ep * e_local
-
-    logits = x @ params.gate  # (T, E)
-    expert, prob, pos, keep = top1_route(logits, capacity)
-
-    # 2. dispatch buffer (E, C, D) → exchange → (ep, E_local, C, D)
-    kept = jnp.where(keep[:, None], x, jnp.zeros_like(x))
-    disp = jnp.zeros((n_experts, capacity, d), x.dtype).at[expert, pos].add(kept)
-    disp = disp.reshape(ep, e_local, capacity, d)
-    recv = lax.all_to_all(disp, axis_name, split_axis=0, concat_axis=0,
-                          tiled=False)  # (ep, E_local, C, D): rank r's tokens
-
-    # 3. batched expert MLP over (rank, expert, slot)
-    h = jax.nn.relu(jnp.einsum("recd,edh->rech", recv, params.w_in))
-    y = jnp.einsum("rech,ehd->recd", h, params.w_out)
-
-    # 4. send results home; tokens gather their slot back
-    back = lax.all_to_all(y, axis_name, split_axis=0, concat_axis=0,
-                          tiled=False).reshape(n_experts, capacity, d)
-    out = back[expert, pos] * (prob * keep)[:, None].astype(x.dtype)
-    return out
-
-
-def load_balancing_loss(logits, expert, n_experts: int):
-    """Switch-Transformer auxiliary loss: n_e * Σ_e (fraction routed to e) ×
-    (mean gate prob of e) — pushes the router toward uniform expert use."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    frac = jnp.mean(jax.nn.one_hot(expert, n_experts, dtype=jnp.float32), axis=0)
-    mean_prob = jnp.mean(probs, axis=0)
-    return n_experts * jnp.sum(frac * mean_prob)
-
-
-# ----------------------------------------------------- top-k, dropless (OLMoE)
 
 def topk_route(logits, top_k: int):
     """Softmax in float32, then the ``top_k`` largest probabilities.

@@ -1,11 +1,6 @@
 """horovod_tpu.parallel — meshes, in-jit collectives, fusion, pipelining,
-fully-sharded data parallelism."""
+ZeRO-sharded data parallelism (``sharded.py``)."""
 
-from .fsdp import (  # noqa: F401
-    fsdp_gather_params,
-    fsdp_shard_params,
-    fsdp_unshard_params,
-)
 from .pipeline import (  # noqa: F401
     last_stage_value,
     masked_last_stage_loss,

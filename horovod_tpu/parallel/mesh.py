@@ -47,9 +47,8 @@ ICI_AXIS = "ici"
 # average over 'batch' (plain DP replicas), parameters/grads/optimizer
 # state shard 1/shard_size over 'shard' (the ZeRO wire pattern), and the
 # third 'model' axis partitions the model itself — tensor-parallel
-# column/row matmul pairs and expert-parallel MoE dispatch (parallel/
-# tensor.py). A spec that never names the model axis gets model=1 and the
-# 2-D mesh, bit-for-bit as before ISSUE 19.
+# column/row matmul pairs (parallel/tensor.py). A spec that never names the
+# model axis gets model=1 and the 2-D mesh, bit-for-bit as before ISSUE 19.
 BATCH_AXIS = "batch"
 SHARD_AXIS = "shard"
 MODEL_AXIS = "model"

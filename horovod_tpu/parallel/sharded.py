@@ -23,8 +23,7 @@ because the ``psum('model')`` inside each column/row matmul pair already
 broadcasts its cotangent under AD. ``model_size=1`` plans and exchanges
 are bitwise the 2-D ones (no new HLO enters the step).
 
-The bucket layout IS the shard layout (the fsdp.py ``(axis_size, chunk)``
-prototype promoted to the planner's substrate): fusion.build_plan packs
+The bucket layout IS the shard layout: fusion.build_plan packs
 leaves into same-dtype buckets padded to a multiple of the shard axis size,
 and each rank owns one ``(1, chunk)`` row per bucket. Because buckets are
 the unit of exchange, everything the planner already knows — per-tier
@@ -346,7 +345,7 @@ def mask_pad_updates(updates, plan: ShardPlan, shard_axis: str = SHARD_AXIS):
     optimizer chain is free to move zero-gradient entries (weight decay on
     restored garbage, gradient noise, schedule interpolation) — this mask
     is what makes 'the tail stays bitwise 0.0' an invariant instead of a
-    hope (the fsdp.py prototype's pad-leak fix, applied natively here).
+    hope.
 
     Buckets without padding (always the case on shard=1) are untouched —
     no mask op enters the HLO, preserving the degenerate mesh's bitwise

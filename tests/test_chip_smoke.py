@@ -100,3 +100,4 @@ def test_four_device_legs_on_the_virtual_mesh(hvd, capsys):
     chip_smoke.phase_four_chip(t_local=16, interpret=True)
     line = capsys.readouterr().out
     assert "phase=four_chip" in line and "pipeline_ppermute=True" in line
+    assert "moe_data_parallel=True" in line

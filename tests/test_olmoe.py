@@ -1,8 +1,9 @@
 """OLMoE through ``TransformerLM`` against ``tests/references/olmoe.py`` (plain
 jax.numpy, float32 at ``highest``, a loop over the experts) on seeded weights
 at tiny sizes: logits, loss with both auxiliary terms, ALL gradients; no
-dropped pair under the worst imbalance; weights not renormalised; QK-norm
-over the whole projection; data parallel on the 4-device CPU mesh through
+dropped pair under the worst imbalance; the layer alone against a per-token
+loop at ``top_k`` 1, 2 and all experts; weights not renormalised; experts
+without a ``top_k`` refused; QK-norm over the whole projection; data parallel on the 4-device CPU mesh through
 ``DistributedOptimizer`` against the reference played rank by rank; and the
 placeholder ``lm217m`` model untouched by the new options at their defaults.
 
@@ -35,7 +36,8 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from references import olmoe as ref  # noqa: E402
 
 from horovod_tpu.compat import shard_map  # noqa: E402
-from horovod_tpu.models import MoEMLP, TransformerLM, aux_losses  # noqa: E402
+from horovod_tpu.models import (MoEMLP, TransformerLM, aux_losses,  # noqa: E402
+                                ep_param_specs)
 from horovod_tpu.ops import moe as moe_ops  # noqa: E402
 
 CFG = dict(hidden=64, heads=2, experts=8, top_k=2, expert_width=32, vocab=128,
@@ -148,15 +150,20 @@ def one_layer(bias):
     return layer, x.at[:, 0].set(1.0)
 
 
-def moe_system(layer, x):
+def probe(y):
+    """A scalar of the layer's output whose cotangent differs on every entry."""
+    return jnp.sum(y * jnp.cos(jnp.arange(y.size).reshape(y.shape)))
+
+
+def moe_system(layer, x, top_k=CFG["top_k"]):
     params = {k: layer[k] for k in ("router", "w_gate", "w_up", "w_down")}
     m = MoEMLP(dim=CFG["hidden"], hidden=CFG["expert_width"],
-               n_experts=CFG["experts"], top_k=CFG["top_k"], dtype=jnp.float32)
+               n_experts=CFG["experts"], top_k=top_k, dtype=jnp.float32)
 
     def f(params, x):
         y, state = m.apply({"params": params}, x[None],
                            mutable=["intermediates"])
-        return jnp.sum(y * jnp.cos(jnp.arange(y.size).reshape(y.shape))), y[0]
+        return probe(y), y[0]
 
     with jax.default_matmul_precision("highest"):
         (_, y), grads = jax.jit(jax.value_and_grad(f, argnums=(0, 1),
@@ -168,7 +175,7 @@ def moe_reference(layer, x):
     def f(layer, x):
         with jax.default_matmul_precision("highest"):
             y, stats = ref.experts(layer, x, CFG)
-        return jnp.sum(y * jnp.cos(jnp.arange(y.size).reshape(y.shape))), (y, stats)
+        return probe(y), (y, stats)
 
     (_, (y, stats)), grads = jax.value_and_grad(f, argnums=(0, 1),
                                                 has_aux=True)(layer, x)
@@ -199,6 +206,44 @@ def test_no_pair_is_dropped_under_any_imbalance(bias):
         assert share(g_params[name], want_layer[name]) <= tol, name
 
 
+def per_token_loop(layer, x, top_k):
+    """The layer one token at a time: its ``top_k`` most probable experts,
+    each expert's SwiGLU on that token alone, summed with the probabilities.
+    Same probe as ``moe_system``; (y, gradients of (layer, x))."""
+    probs = np.asarray(jax.nn.softmax(x @ layer["router"], axis=-1))
+    chosen = np.argsort(-probs, axis=-1, kind="stable")[:, :top_k]
+
+    def f(layer, x):
+        rows = []
+        for n, row in enumerate(x):
+            p = jax.nn.softmax(row @ layer["router"])
+            rows.append(sum(
+                p[e] * ((jax.nn.silu(row @ layer["w_gate"][e])
+                         * (row @ layer["w_up"][e])) @ layer["w_down"][e])
+                for e in chosen[n]))
+        y = jnp.stack(rows)
+        return probe(y), y
+
+    with jax.default_matmul_precision("highest"):
+        (_, y), grads = jax.value_and_grad(f, argnums=(0, 1),
+                                           has_aux=True)(layer, x)
+    return y, grads
+
+
+@pytest.mark.parametrize("top_k", [1, 2, CFG["experts"]])
+def test_layer_alone_matches_a_per_token_loop(top_k):
+    """``top_k = 1`` is one expert a token, weighted by its probability;
+    ``top_k = n_experts`` is every expert on every token."""
+    layer, x = one_layer([0.0] * 8)
+    x = x[:24]
+    y, (g_params, g_x) = moe_system(layer, x, top_k)
+    want_y, (want_layer, want_x) = per_token_loop(layer, x, top_k)
+    assert share(y, want_y) <= F32_TOL
+    assert share(g_x, want_x) <= F32_TOL
+    for name in ("router", "w_gate", "w_up", "w_down"):
+        assert share(g_params[name], want_layer[name]) <= F32_TOL, name
+
+
 def test_weights_are_the_probabilities_not_renormalised():
     logits = jax.random.normal(jax.random.PRNGKey(4), (50, 8)) * 2.0
     probs, weights, experts = moe_ops.topk_route(logits, 3)
@@ -219,6 +264,21 @@ def test_auxiliary_losses_by_hand():
     z = jnp.asarray([[1.0, 1.0], [0.0, 0.0]])
     assert float(moe_ops.router_z_loss(z)) == pytest.approx(
         ((1 + np.log(2)) ** 2 + np.log(2) ** 2) / 2, rel=1e-6)
+
+
+def test_load_balancing_loss_of_a_balanced_router_is_one():
+    """Token n picks experts n .. n + top_k - 1 (mod E), evenly: f_e = P_e =
+    1 / E, so E * sum_e f_e P_e = 1 at every top_k, its minimum. (Counting
+    f_e by N alone, as the paper's formula does, gives top_k times this.)"""
+    n, e = 64, 8
+    for top_k in (1, 2, 4):
+        picks = (jnp.arange(n)[:, None] + jnp.arange(top_k)) % e
+        logits = 20.0 * jnp.sum(jax.nn.one_hot(picks, e), axis=1)
+        probs, _, experts = moe_ops.topk_route(logits, top_k)
+        np.testing.assert_array_equal(np.sort(np.asarray(experts), axis=-1),
+                                      np.sort(np.asarray(picks), axis=-1))
+        assert float(moe_ops.topk_load_balancing_loss(probs, experts)) == \
+            pytest.approx(1.0, abs=1e-5), top_k
 
 
 def test_expert_load_gauge_is_set_by_the_helper_alone(hvd):
@@ -287,3 +347,37 @@ def test_the_placeholder_model_is_untouched_by_the_new_options():
     for absent in ("ragged_dot", "top_k", " sort[", "9.999999747378752e-06", "1e-05"):
         assert absent not in text, absent
     assert text.count("9.999999974752427e-07") == 2 * 2 + 1      # float32(1e-6)
+
+
+def test_experts_without_a_top_k_are_refused():
+    """``moe_experts > 0`` names no layer by itself: ``moe_top_k`` says how
+    many experts a token uses, and its default of 0 is not a number of them."""
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    for top_k in (0, 5):
+        m = TransformerLM(vocab=32, dim=16, heads=2, layers=2, moe_experts=4,
+                          moe_top_k=top_k)
+        with pytest.raises(ValueError, match=f"top_k {top_k} of 4 experts"):
+            m.init(jax.random.PRNGKey(0), tokens)
+
+
+def test_every_second_block_holds_the_experts():
+    params = jax.eval_shape(
+        model(layers=4, moe_every=2).init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]
+    for i in range(4):
+        mlp = set(params[f"block_{i}"]) - {"RMSNorm_0", "RMSNorm_1", "qkv",
+                                           "q_norm", "k_norm", "o_proj"}
+        assert mlp == ({"moe"} if i in (1, 3) else {"mlp_in", "mlp_out"}), i
+
+
+def test_ep_param_specs_name_the_three_expert_tensors():
+    params = jax.eval_shape(model().init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    specs = ep_param_specs(params, "ep")
+    sharded = {jax.tree_util.keystr(path): spec for path, spec in
+               jax.tree_util.tree_flatten_with_path(
+                   specs, is_leaf=lambda s: isinstance(s, P))[0] if spec != P()}
+    assert sharded == {
+        f"['block_{i}']['moe']['{w}']": P("ep", None, None)
+        for i in range(LAYERS) for w in ("w_gate", "w_up", "w_down")}
+    assert specs["block_0"]["moe"]["router"] == P()

@@ -9,7 +9,7 @@ Coverage map (the ISSUE's test satellite):
   mesh (exact integer payloads — any mismatch is a routing bug);
 - sharded == DP on a degenerate shard=1 mesh: the exchange traces to the
   same equations, and the full training loop through DistributedOptimizer
-  agrees to float32 rounding; within dtype tolerance on 2x2;
+  agrees to float32 rounding; within dtype tolerance on 2x2, 1x4, 2x4;
 - zero-pad discipline: the tail receives zero gradients, the masked update
   keeps it bitwise 0.0 even under an optimizer chain that moves
   zero-gradient entries (gradient noise);
@@ -159,10 +159,16 @@ def test_dcn_threshold_caps_shard_buckets():
         assert padded * jnp.dtype(dt).itemsize <= (16 << 10) * 4
 
 
-def test_shard_unshard_roundtrip():
-    params = make_params()
+@pytest.mark.parametrize("ragged", [True, False], ids=["ragged", "divisible"])
+def test_shard_unshard_roundtrip(ragged):
+    """A tree of 858 elements (leaves of 33 and 9 columns: padded at shard
+    sizes 4 and 8) and one every shard size divides (never padded)."""
+    params = make_params() if ragged else {
+        "w": jax.random.normal(jax.random.PRNGKey(0), (16, 32)),
+        "b": jnp.arange(32.0)}
     for s in (1, 2, 4, 8):
         plan = sh.build_shard_plan(params, s, threshold=1 << 20)
+        assert (plan.raw_sizes != plan.padded_sizes) == (ragged and s > 2)
         back = sh.unshard_params(sh.shard_params(params, plan), plan)
         for a, b in zip(jax.tree_util.tree_leaves(params),
                         jax.tree_util.tree_leaves(back)):
@@ -222,12 +228,9 @@ def test_reduce_scatter_matches_dense_oracle_2x4(mesh8):
 
 
 def _train(mesh, batch, shard, params, x, y, steps=5, num_buckets=2,
-           noise=False):
+           inner=optax.adam(1e-2)):
     """Run the full DistributedOptimizer loop and return the final FULL
     params. shard=1 exercises the degenerate (bitwise-DP) plan."""
-    inner = optax.adam(1e-2)
-    if noise:
-        inner = optax.chain(inner, optax.add_noise(0.01, 0.0, 0))
     plan = sh.build_shard_plan(params, shard, threshold=1 << 20,
                                num_buckets=num_buckets)
     sp = sh.shard_params(params, plan)
@@ -318,13 +321,17 @@ def test_sharded_equals_dp_on_shard1(mesh8):
                                    err_msg=f"{k}: shard=1 diverged from DP")
 
 
-def test_sharded_trajectory_matches_dp_2x2(mesh8):
+@pytest.mark.parametrize("batch,shard", [(2, 2), (1, 4), (2, 4)],
+                         ids=["2x2", "1x4", "2x4"])
+def test_sharded_trajectory_matches_dp(mesh8, batch, shard):
+    """Replicated DP against ZeRO composed with a batch axis (2x2, 2x4) and
+    alone (1x4: every rank owns a quarter of every bucket)."""
     del mesh8
     params = make_params()
-    x, y = make_data(4)
+    x, y = make_data(batch * shard)
     with jax.default_matmul_precision("highest"):
-        dp = _train_dp(params, x, y, world=4)
-        got, _, _ = _train(grid_mesh(2, 2), 2, 2, params, x, y)
+        dp = _train_dp(params, x, y, world=batch * shard)
+        got, _, _ = _train(grid_mesh(batch, shard), batch, shard, params, x, y)
     for k in params:
         np.testing.assert_allclose(np.asarray(dp[k]), np.asarray(got[k]),
                                    atol=2e-6, rtol=2e-6)
@@ -333,15 +340,20 @@ def test_sharded_trajectory_matches_dp_2x2(mesh8):
 # ------------------------------------------------------- zero-pad discipline
 
 
-def test_pad_tail_stays_zero_under_noise(mesh8):
+@pytest.mark.parametrize("inner", [
+    optax.chain(optax.adam(1e-2), optax.add_noise(0.01, 0.0, 0)),
+    optax.adamw(1e-2, weight_decay=0.1),
+], ids=["noise", "adamw_weight_decay"])
+def test_pad_tail_stays_zero_under_noise(mesh8, inner):
     """An optimizer chain that moves zero-gradient entries (gradient noise)
     would drift the pad tail; the masked update pins it to bitwise 0.0 —
-    the leak named by the ISSUE satellite."""
+    the leak named by the ISSUE satellite. AdamW's decay reads the
+    parameters themselves: the tail must give it nothing to decay."""
     del mesh8
     params = make_params()
     x, y = make_data(8)
     _, sp, plan = _train(grid_mesh(2, 4), 2, 4, params, x, y, steps=4,
-                         noise=True)
+                         inner=inner)
     padded_any = False
     for b, buf in enumerate(sp):
         flat = np.asarray(buf).reshape(-1)

@@ -17,9 +17,7 @@ Coverage map:
   dense DP oracle within pinned tolerance, with the model-stacked
   ``(model*shard, chunk)`` host layout and ``P(('model','shard'))``
   specs;
-- trace-time gauges record the model axis;
-- EP promotion: ``moe_apply`` rides the 3-D mesh's 'model' axis and still
-  matches its dense per-token oracle.
+- trace-time gauges record the model axis.
 """
 
 from __future__ import annotations
@@ -441,48 +439,3 @@ def test_autotune_sixth_dimension():
     assert set(seen) == {"8x1x1", "4x2x1", "2x2x2"}
     assert report.best.mesh_shape == "2x2x2"
     assert report.best.config.get("mesh") == "2x2x2"
-
-
-# ---------------------------------------------------------- EP promotion
-
-
-def test_moe_rides_model_axis(mesh8):
-    """Expert parallelism promoted onto the 3-D mesh: ``moe_apply`` with
-    axis_name='model' dispatches over the mesh's third axis (experts
-    sharded over 'model', tokens over ('batch','model')) and still matches
-    the dense per-token oracle when capacity is generous."""
-    del mesh8
-    from horovod_tpu.ops.moe import MoEParams, init_moe_params, moe_apply
-
-    DIM, HIDDEN, EXPERTS, S = 8, 16, 8, 4
-    params = init_moe_params(jax.random.PRNGKey(0), DIM, HIDDEN, EXPERTS, S)
-    tokens_per_rank = 8
-    mesh = sharded_mesh(batch=2, shard=1, model=S)
-    x = jax.random.normal(jax.random.PRNGKey(1),
-                          (2 * S * tokens_per_rank, DIM))
-
-    def dense_oracle(params, x):
-        logits = x @ params.gate
-        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        expert = jnp.argmax(probs, axis=-1)
-        prob = jnp.take_along_axis(probs, expert[:, None], axis=-1)[:, 0]
-        h = jax.nn.relu(jnp.einsum("td,edh->teh", x, params.w_in))
-        yv = jnp.einsum("teh,ehd->ted", h, params.w_out)
-        chosen = jnp.take_along_axis(
-            yv, expert[:, None, None].repeat(DIM, axis=2), axis=1)[:, 0]
-        return chosen * prob[:, None]
-
-    def fn(gate, w_in, w_out, x):
-        return moe_apply(MoEParams(gate, w_in, w_out), x,
-                         capacity=2 * S * tokens_per_rank,
-                         axis_name="model")
-
-    got = jax.jit(shard_map(
-        fn, mesh=mesh,
-        in_specs=(P(), P("model"), P("model"), P(("batch", "model"))),
-        out_specs=P(("batch", "model")), check_vma=False))(
-            params.gate, params.w_in, params.w_out, x)
-    with jax.default_matmul_precision("highest"):
-        want = dense_oracle(params, x)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
