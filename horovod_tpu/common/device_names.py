@@ -18,4 +18,8 @@ FUSED_ALLREDUCE = "hvd_fused_allreduce_k"   # + the number of buckets
 MOE_ROUTE = "hvd_moe_route"             # softmax + top-k of the router
 MOE_DISPATCH = "hvd_moe_dispatch"       # sort by expert + gather into that order
 MOE_EXPERTS = "hvd_moe_experts"         # the grouped SwiGLU products
+# The grouped products' own kernels (ops/grouped_matmul.py). Both names hold
+# MOE_EXPERTS: the benchmark finds the experts' time by that substring.
+MOE_EXPERTS_GMM = "hvd_moe_experts_gmm"     # rows x weights: Y and dX
+MOE_EXPERTS_TGMM = "hvd_moe_experts_tgmm"   # rows^T x rows: dW
 MOE_COMBINE = "hvd_moe_combine"         # back to token order + weighted sum

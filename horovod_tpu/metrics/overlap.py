@@ -173,6 +173,20 @@ def record_chunked_loss_plan(products: int) -> None:
     ).set(products)
 
 
+def record_moe_grouped_plan(border_overhead: float) -> None:
+    """Record which path the latest traced ``ops.moe.dropless_experts`` gave
+    its grouped products (trace time, once per compile): the worst-case row
+    blocks multiplied over row blocks of work, ``(B + E - 1) / B`` with ``B =
+    rows / 128`` (``ops.grouped_matmul.border_overhead``), of the repo's
+    kernels, or 0 where the shapes kept ``lax.ragged_dot``."""
+    registry().gauge(
+        "horovod_moe_grouped_border_overhead",
+        help="worst-case row blocks multiplied over row blocks of work of the "
+             "grouped-product kernels in the latest traced dropless_experts; "
+             "0 = lax.ragged_dot"
+    ).set(border_overhead)
+
+
 # Latest fabric-tier plan of the hierarchical compiled path (ISSUE 7):
 # {"hierarchical": bool, "ici_wire": str, "dcn_wire": str, "ici_size": int,
 #  "bytes_per_step": {"ici": n, "dcn": n}, "buckets": int}.

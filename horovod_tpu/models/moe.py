@@ -33,6 +33,10 @@ class MoEMLP(nn.Module):
     n_experts: int
     top_k: int
     dtype: Any = jnp.bfloat16
+    # True runs the grouped-product kernels (ops/grouped_matmul.py), where
+    # the shapes take them, in the Pallas interpreter: ``Block`` hands its
+    # ``flash_interpret`` down, one flag for every Pallas kernel of a block.
+    interpret: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -63,7 +67,7 @@ class MoEMLP(nn.Module):
         self.sow("intermediates", "moe_router_logits", logits)
         self.sow("intermediates", "moe_chosen_experts", experts)
         return dropless_experts(tokens.astype(self.dtype), weights, experts,
-                                w_gate, w_up, w_down)
+                                w_gate, w_up, w_down, self.interpret)
 
 
 def aux_losses(intermediates):

@@ -141,7 +141,8 @@ class Block(nn.Module):
                       else self.moe_hidden)
             x = x + MoEMLP(dim=self.dim, hidden=hidden,
                            n_experts=self.moe_experts, top_k=self.moe_top_k,
-                           dtype=self.dtype, name="moe")(h)
+                           dtype=self.dtype, interpret=self.flash_interpret,
+                           name="moe")(h)
         else:
             h = nn.Dense(self.mlp_ratio * self.dim, use_bias=False, dtype=self.dtype, name="mlp_in")(h)
             h = nn.gelu(h)

@@ -1,5 +1,6 @@
 """TPU compute ops beyond stock XLA: sequence-parallel attention schedules
-(ring / Ulysses), mixture of experts (dropless top-k), and a pallas
+(ring / Ulysses), mixture of experts (dropless top-k) with its grouped
+products as pallas kernels (``grouped_matmul``), and a pallas
 flash-attention kernel (fused, trainable) for the hot op."""
 
 from .flash_attention import flash_attention  # noqa: F401

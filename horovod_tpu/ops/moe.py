@@ -9,10 +9,14 @@ renormalised, and no capacity (OLMoE, arXiv:2409.02060) - every chosen
 1. the N x top_k pairs are sorted by expert (stable, so a token's rows keep
    their order inside an expert's group) and the tokens gathered into that
    order: N x top_k rows whatever the imbalance, only the group sizes vary;
-2. the three SwiGLU products run as grouped products over the ragged groups
-   (``jax.lax.ragged_dot``: on the TPU libtpu lowers it to a Mosaic grouped
-   matmul that walks row tiles group by group, not to a masked dense product
-   - PERF.md, PR 26);
+2. the three SwiGLU products run as grouped products over the ragged groups,
+   and so do the six of their backward pass. Shapes the repo's own kernels
+   tile (``grouped_matmul.takes_kernel``: bf16 or f32, both widths multiples
+   of 128, the rows a multiple of the row tile) go through them: an expert's
+   weight block resident, row tiles of 512 cut into blocks of 128 where a
+   group border crosses them (PERF.md, PR 29). Any other shape
+   goes through ``jax.lax.ragged_dot``, the general path (on the TPU libtpu's
+   grouped matmul at 512-cube tiles, PERF.md, PR 26);
 3. the rows go back to token order through the inverse permutation and are
    summed with their weights.
 
@@ -31,6 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..common import device_names
+from . import grouped_matmul as gm
 
 
 def topk_route(logits, top_k: int):
@@ -80,14 +85,19 @@ def _expert_counts(flat_experts, n_experts: int):
                    axis=0, dtype=jnp.int32)
 
 
-def dropless_experts(x, weights, experts, w_gate, w_up, w_down):
+def dropless_experts(x, weights, experts, w_gate, w_up, w_down,
+                     interpret: bool = False):
     """Every chosen (token, expert) pair through its SwiGLU expert, summed
     with its weight: ``sum_j weights[n, j] * down_e(silu(gate_e x_n) * up_e x_n)``
     with ``e = experts[n, j]``.
 
     x: (N, D); weights, experts: (N, top_k); w_gate, w_up: (E, D, H);
     w_down: (E, H, D), all in x's dtype. Returns (N, D) in x's dtype. The
-    work is N x top_k rows whatever the routing."""
+    work is N x top_k rows whatever the routing. ``interpret`` runs the
+    grouped-product kernels, where the shapes take them, in the Pallas
+    interpreter (asked for by the CPU tests, never inferred)."""
+    from ..metrics import record_moe_grouped_plan
+
     n, d = x.shape
     top_k, n_experts = experts.shape[1], w_gate.shape[0]
     with jax.named_scope(device_names.MOE_DISPATCH):
@@ -97,9 +107,21 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down):
         group_sizes = _expert_counts(flat, n_experts)
         rows = _take_rows(x, order // top_k, inverse, top_k)
     with jax.named_scope(device_names.MOE_EXPERTS):
-        gate = lax.ragged_dot(rows, w_gate, group_sizes)
-        up = lax.ragged_dot(rows, w_up, group_sizes)
-        out = lax.ragged_dot(jax.nn.silu(gate) * up, w_down, group_sizes)
+        if gm.takes_kernel(rows, w_gate):
+            plan = gm.grouped_plan(group_sizes, n * top_k,
+                                   gm.row_tile(rows.dtype.itemsize))
+            record_moe_grouped_plan(gm.border_overhead(n * top_k, n_experts))
+
+            def product(a, w):
+                return gm.grouped_matmul(a, w, plan, interpret)
+        else:
+            record_moe_grouped_plan(0.0)
+
+            def product(a, w):
+                return lax.ragged_dot(a, w, group_sizes)
+
+        gate, up = product(rows, w_gate), product(rows, w_up)
+        out = product(jax.nn.silu(gate) * up, w_down)
     with jax.named_scope(device_names.MOE_COMBINE):
         pairs = _take_rows(out, inverse, order, 1).reshape(n, top_k, d)
         return jnp.sum(pairs.astype(jnp.float32) * weights[:, :, None],

@@ -61,6 +61,50 @@ def moe_text():
             False: lowered.as_text(debug_info=False)}
 
 
+def _moe_layer_text(dim, hidden, tokens, dtype, grad):
+    """(lowered text with debug info, the border-overhead gauge after the
+    trace) of a 4-expert top-2 layer, forward alone or with its gradients."""
+    from horovod_tpu.metrics import registry
+    from horovod_tpu.models import MoEMLP
+
+    layer = MoEMLP(dim=dim, hidden=hidden, n_experts=4, top_k=2, dtype=dtype,
+                   interpret=True)
+    x = jnp.ones((1, tokens, dim), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+
+    def f(p, x):
+        return layer.apply({"params": p}, x).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1)) if grad else f).lower(
+        params, x).as_text(debug_info=True)
+    return text, registry().gauge("horovod_moe_grouped_border_overhead").value
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_aligned_experts_lower_to_the_grouped_kernels_by_name(grad):
+    """OLMoE-shaped at an aligned size (widths of 128, 256 tokens x top-2 =
+    one bf16 row tile of 512 = 4 row blocks): the rows x weights kernel in
+    the forward, both kernels in the backward, under names the benchmark's
+    ``hvd_moe_experts`` finds; no ``ragged_dot``; the gauge reads
+    (4 + 4 - 1) / 4."""
+    for name in (names.MOE_EXPERTS_GMM, names.MOE_EXPERTS_TGMM):
+        assert names.MOE_EXPERTS in name
+    text, overhead = _moe_layer_text(128, 128, 256, jnp.bfloat16, grad)
+    assert names.MOE_EXPERTS_GMM in text
+    assert (names.MOE_EXPERTS_TGMM in text) is grad
+    assert "ragged_dot" not in text
+    assert overhead == 1.75
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_tiny_unaligned_experts_keep_ragged_dot(grad):
+    text, overhead = _moe_layer_text(16, 8, 8, jnp.float32, grad)
+    assert "ragged_dot" in text
+    assert names.MOE_EXPERTS_GMM not in text
+    assert names.MOE_EXPERTS_TGMM not in text
+    assert overhead == 0.0
+
+
 @pytest.mark.parametrize("name", [names.MOE_ROUTE, names.MOE_DISPATCH,
                                   names.MOE_EXPERTS, names.MOE_COMBINE])
 def test_moe_scope_is_in_the_lowered_module_as_metadata_only(name, moe_text):

@@ -1,5 +1,5 @@
-"""The three flash kernels COMPILED for a described v5e at the benchmark
-cells' shapes (no chip attached, nothing runs): what interpret mode cannot
+"""The three flash kernels and the two grouped-product kernels COMPILED for a
+described v5e at the benchmark cells' shapes (no chip attached, nothing runs): what interpret mode cannot
 see — scoped VMEM, tiling and layout faults of a kernel edit — is refused
 here, on the CPU, by the TPU's own compiler.
 
@@ -15,7 +15,9 @@ import jax.numpy as jnp
 import pytest
 
 from horovod_tpu.common.device_names import (FLASH_BWD_DKV, FLASH_BWD_DQ,
-                                             FLASH_FWD)
+                                             FLASH_FWD, MOE_EXPERTS_GMM,
+                                             MOE_EXPERTS_TGMM)
+from horovod_tpu.ops import grouped_matmul as gm
 from horovod_tpu.ops.flash_attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q,
                                              flash_attention)
 
@@ -71,3 +73,38 @@ def test_kernels_compile_for_v5e(one_chip, no_persistent_cache, cell, fn,
     assert "tpu_custom_call" in text
     for name in kernels:
         assert name in text, f"{name} is not in the compiled module"
+
+
+# olmoe_seq4096_1chip: 16,384 tokens x top-8 rows through 64 experts of
+# 2048 x 1024 in the step (bf16); one row of 4096 tokens in the check's two
+# legs (bf16 as trained, f32 traced under "highest").
+@pytest.mark.parametrize("rows,dtype,precision", [
+    (131072, jnp.bfloat16, None),
+    (32768, jnp.bfloat16, None),
+    (32768, jnp.float32, "highest"),
+], ids=["step_bf16", "check_bf16", "check_f32_highest"])
+def test_grouped_kernels_compile_for_v5e(one_chip, no_persistent_cache, rows,
+                                         dtype, precision):
+    dim, width, experts = 2048, 1024, 64
+
+    def swiglu_grads(x, w_gate, w_down, sizes):
+        plan = gm.grouped_plan(sizes, rows, gm.row_tile(x.dtype.itemsize))
+
+        def loss(x, w_gate, w_down):
+            h = gm.grouped_matmul(x, w_gate, plan)
+            return jnp.sum(gm.grouped_matmul(h, w_down, plan)
+                           .astype(jnp.float32))
+
+        return jax.grad(loss, argnums=(0, 1, 2))(x, w_gate, w_down)
+
+    def shape(*dims, of=dtype):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    args = (shape(rows, dim), shape(experts, dim, width),
+            shape(experts, width, dim), shape(experts, of=jnp.int32))
+    assert gm.takes_kernel(*args[:2])
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(swiglu_grads).lower(*args).compile().as_text()
+    for name in (MOE_EXPERTS_GMM, MOE_EXPERTS_TGMM):
+        assert name in text, f"{name} is not in the compiled module"
+    assert "ragged" not in text

@@ -1,0 +1,149 @@
+"""The grouped-product kernels (``ops/grouped_matmul.py``) in the Pallas
+interpreter against a per-group loop in float64: the forward, the input
+gradient and the weight gradient, in bf16 and f32, over group layouts that
+put borders inside row tiles and inside their blocks of 128 rows, a group
+smaller than one block, an empty group (its weight gradient is exact zeros)
+and every row in one group.
+
+Tolerances, as shares of max|reference|: f32 1e-5 (the same arithmetic in
+another order of sums); bf16 4e-3 (f32 accumulation rounded once, 2^-9)."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.ops import grouped_matmul as gm
+
+K, N = 128, 256
+
+# group sizes in units of 1/16 of the dtype's row tile (512 rows of bf16, 256
+# of f32: 32 and 16 rows, so borders fall inside the border blocks of 128)
+LAYOUTS = {
+    "borders_inside_tiles": [21, 19, 11, 13],
+    "groups_smaller_than_a_block": [16, 3, 1, 1, 2, 9],
+    "empty_groups": [5, 0, 18, 0, 9, 0],
+    "all_rows_in_one_group": [0, 0, 32, 0],
+    "borders_on_tile_edges": [16, 0, 32, 16],
+}
+
+
+@pytest.fixture(scope="module")
+def computed():
+    cache = {}
+
+    def get(layout, dtype):
+        if (layout, dtype) not in cache:
+            cache[layout, dtype] = _compute(LAYOUTS[layout], dtype)
+        return cache[layout, dtype]
+
+    return get
+
+
+def _compute(sixteenths, dtype):
+    tm = gm.row_tile(jnp.dtype(dtype).itemsize)
+    sizes = np.asarray(sixteenths, np.int32) * (tm // 16)
+    m, e = int(sizes.sum()), len(sizes)
+    kx, kw, kd = jax.random.split(jax.random.PRNGKey(len(sixteenths)), 3)
+    x = jax.random.normal(kx, (m, K), dtype)
+    w = jax.random.normal(kw, (e, K, N), dtype)
+    dy = jax.random.normal(kd, (m, N), dtype)
+    assert gm.takes_kernel(x, w)
+    plan = gm.grouped_plan(jnp.asarray(sizes), m, tm)
+    with jax.default_matmul_precision("highest"):
+        y, vjp = jax.vjp(lambda x, w: gm.grouped_matmul(x, w, plan, True), x, w)
+        dx, dw = vjp(dy)
+    x64, w64, dy64 = (np.asarray(a, np.float64) for a in (x, w, dy))
+    want = {"forward": np.zeros((m, N)), "dx": np.zeros((m, K)),
+            "dw": np.zeros((e, K, N))}
+    start = 0
+    for g, size in enumerate(sizes):
+        rows = slice(start, start + size)
+        want["forward"][rows] = x64[rows] @ w64[g]
+        want["dx"][rows] = dy64[rows] @ w64[g].T
+        want["dw"][g] = x64[rows].T @ dy64[rows]
+        start += size
+    return {"forward": y, "dx": dx, "dw": dw}, want, sizes
+
+
+@pytest.mark.parametrize("product", ["forward", "dx", "dw"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_kernels_match_a_per_group_loop(computed, layout, dtype, product):
+    got, want, sizes = computed(layout, dtype)
+    got, want = np.asarray(got[product], np.float64), want[product]
+    assert got.shape == want.shape and np.isfinite(got).all()
+    tol = 4e-3 if dtype == jnp.bfloat16 else 1e-5
+    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want))
+    if product == "dw":
+        for g in np.flatnonzero(sizes == 0):
+            assert not got[g].any()         # exact zeros, not small numbers
+
+
+@pytest.mark.parametrize("sizes, tm", [
+    ([100, 0, 28, 300, 84, 0], 128),
+    ([0, 0, 512], 256),
+    ([256, 256], 256),
+    ([1, 1, 1, 125], 128),
+])
+def test_plan_visits_every_tile_a_group_has_rows_in(sizes, tm):
+    rows, e = sum(sizes), len(sizes)
+    offsets, groups, tiles, steps = (np.asarray(a) for a in gm.grouped_plan(
+        jnp.asarray(sizes, jnp.int32), rows, tm))
+    steps = int(steps[0])
+    assert len(groups) == len(tiles) == rows // tm + e - 1 >= steps
+    assert offsets.tolist() == np.concatenate([[0], np.cumsum(sizes)]).tolist()
+    want = []
+    for g, size in enumerate(sizes):
+        first, last = offsets[g] // tm, (offsets[g + 1] - 1) // tm
+        want += ([(g, t) for t in range(first, last + 1)] if size
+                 else [(g, min(first, rows // tm - 1))])
+    assert list(zip(groups[:steps], tiles[:steps])) == want
+    # the steps past the end repeat the last one
+    assert set(zip(groups[steps:], tiles[steps:])) <= {want[-1]}
+
+
+@pytest.mark.parametrize("shape, dtype, takes", [
+    ((512, 256, 128), jnp.bfloat16, True),
+    ((256, 128, 128), jnp.float32, True),
+    ((256, 128, 128), jnp.bfloat16, False),     # rows under the bf16 tile
+    ((512, 64, 128), jnp.bfloat16, False),      # K not a multiple of 128
+    ((512, 128, 96), jnp.float32, False),       # N not a multiple of 128
+    ((512, 128, 128), jnp.float16, False),
+])
+def test_which_shapes_take_the_kernels(shape, dtype, takes):
+    m, k, n = shape
+    x = jax.ShapeDtypeStruct((m, k), dtype)
+    w = jax.ShapeDtypeStruct((4, k, n), dtype)
+    assert gm.takes_kernel(x, w) is takes
+
+
+def test_tiles_follow_shapes_and_itemsize():
+    # OLMoE's: the whole 2048 x 1024 bf16 block of an expert is resident
+    assert gm._column_tile(2048, 1024, 2) == 1024
+    assert gm._column_tile(1024, 2048, 2) == 2048
+    assert gm._column_tile(2048, 1024, 4) == 512     # f32: half the columns
+    assert gm.row_tile(2) == 512 and gm.row_tile(4) == 256
+    # the weight gradient accumulates an expert's whole block in f32
+    assert gm._weight_grad_tiles(2048, 1024) == (2048, 1024)
+    assert gm._weight_grad_tiles(1024, 2048) == (1024, 2048)
+    assert gm._weight_grad_tiles(4096, 2048) == (1024, 2048)
+    assert gm._weight_grad_tiles(128, 384) == (128, 384)
+    assert gm.border_overhead(131072, 64) == pytest.approx(1087 / 1024)
+
+
+def test_compiled_kernels_without_tpu_raise():
+    """The interpreter is the caller's to ask for, never inferred from the
+    platform: on this CPU an aligned layer built without ``interpret``
+    raises at lowering, as a flash model without ``flash_interpret`` does."""
+    from horovod_tpu.models import MoEMLP
+
+    layer = MoEMLP(dim=128, hidden=128, n_experts=4, top_k=2)
+    x = jnp.ones((1, 256, 128), jnp.float32)
+    with pytest.raises(ValueError, match="[Ii]nterpret"):
+        layer.init(jax.random.PRNGKey(0), x)
+    tiny = MoEMLP(dim=16, hidden=8, n_experts=4, top_k=2)    # ragged_dot
+    tiny.init(jax.random.PRNGKey(0), jnp.ones((1, 8, 16), jnp.float32))

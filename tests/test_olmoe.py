@@ -141,12 +141,17 @@ def test_bfloat16_activations_stay_in_their_band(seeded, attention):
     assert abs(float(total) - float(want_total)) <= 2e-2 * float(want_total)
 
 
-def one_layer(bias):
+# Widths and rows the grouped-product kernels tile (ops/grouped_matmul.py):
+# multiples of 128, and 128 tokens x top_k 2 = one f32 row tile of 256.
+ALIGNED = dict(CFG, hidden=128, expert_width=128)
+
+
+def one_layer(bias, cfg=CFG, tokens=96):
     """A seeded MoE layer in the reference's layout and inputs whose first
     feature is a constant 1, so that the router's first row is a bias."""
-    layer = ref.init_params(jax.random.PRNGKey(2), CFG, scale=0.1)["layers"][0]
+    layer = ref.init_params(jax.random.PRNGKey(2), cfg, scale=0.1)["layers"][0]
     layer["router"] = layer["router"].at[0].set(jnp.asarray(bias, jnp.float32))
-    x = jax.random.normal(jax.random.PRNGKey(3), (96, CFG["hidden"]))
+    x = jax.random.normal(jax.random.PRNGKey(3), (tokens, cfg["hidden"]))
     return layer, x.at[:, 0].set(1.0)
 
 
@@ -155,10 +160,11 @@ def probe(y):
     return jnp.sum(y * jnp.cos(jnp.arange(y.size).reshape(y.shape)))
 
 
-def moe_system(layer, x, top_k=CFG["top_k"]):
+def moe_system(layer, x, top_k=CFG["top_k"], cfg=CFG):
     params = {k: layer[k] for k in ("router", "w_gate", "w_up", "w_down")}
-    m = MoEMLP(dim=CFG["hidden"], hidden=CFG["expert_width"],
-               n_experts=CFG["experts"], top_k=top_k, dtype=jnp.float32)
+    m = MoEMLP(dim=cfg["hidden"], hidden=cfg["expert_width"],
+               n_experts=cfg["experts"], top_k=top_k, dtype=jnp.float32,
+               interpret=True)
 
     def f(params, x):
         y, state = m.apply({"params": params}, x[None],
@@ -230,13 +236,24 @@ def per_token_loop(layer, x, top_k):
     return y, grads
 
 
-@pytest.mark.parametrize("top_k", [1, 2, CFG["experts"]])
-def test_layer_alone_matches_a_per_token_loop(top_k):
+@pytest.mark.parametrize("top_k, cfg, tokens, border_overhead", [
+    (1, CFG, 24, 0.0), (2, CFG, 24, 0.0), (CFG["experts"], CFG, 24, 0.0),
+    pytest.param(2, ALIGNED, 128, 4.5, id="2-aligned_takes_the_kernels"),
+])
+def test_layer_alone_matches_a_per_token_loop(top_k, cfg, tokens,
+                                              border_overhead):
     """``top_k = 1`` is one expert a token, weighted by its probability;
-    ``top_k = n_experts`` is every expert on every token."""
-    layer, x = one_layer([0.0] * 8)
-    x = x[:24]
-    y, (g_params, g_x) = moe_system(layer, x, top_k)
+    ``top_k = n_experts`` is every expert on every token. At the aligned
+    widths the nine grouped products run through the repo's kernels (the
+    gauge says so: 2 row blocks of work, 8 groups), at the others through
+    ``lax.ragged_dot`` (0)."""
+    from horovod_tpu.metrics import registry
+
+    layer, x = one_layer([0.0] * 8, cfg, max(tokens, 96))
+    x = x[:tokens]
+    y, (g_params, g_x) = moe_system(layer, x, top_k, cfg)
+    assert registry().gauge(
+        "horovod_moe_grouped_border_overhead").value == border_overhead
     want_y, (want_layer, want_x) = per_token_loop(layer, x, top_k)
     assert share(y, want_y) <= F32_TOL
     assert share(g_x, want_x) <= F32_TOL
@@ -286,11 +303,15 @@ def test_expert_load_gauge_is_set_by_the_helper_alone(hvd):
     logits[:, 0], logits[:, 1] = 3.0, 2.0          # everyone picks 0 and 1
     assert moe_ops.record_expert_load(logits, 2) == pytest.approx(2.0)
     layer, x = one_layer([0.0] * 8)
-    moe_system(layer, x)        # tracing and running the layer sets no gauge
+    # tracing and running the layer leaves the load as the helper set it; the
+    # one gauge a trace sets says which path the grouped products took
+    moe_system(layer, x)
     gauges = hvd.metrics.registry().snapshot()["gauges"]
     assert gauges["horovod_moe_expert_load_max_over_mean"] == pytest.approx(2.0)
-    assert [name for name in gauges if name.startswith("horovod_moe_")] == [
-        "horovod_moe_expert_load_max_over_mean"]
+    assert gauges["horovod_moe_grouped_border_overhead"] == 0.0
+    assert sorted(name for name in gauges if name.startswith("horovod_moe_")) == [
+        "horovod_moe_expert_load_max_over_mean",
+        "horovod_moe_grouped_border_overhead"]
 
 
 def test_data_parallel_through_distributed_optimizer(hvd, seeded):
