@@ -23,3 +23,11 @@ MOE_EXPERTS = "hvd_moe_experts"         # the grouped SwiGLU products
 MOE_EXPERTS_GMM = "hvd_moe_experts_gmm"     # rows x weights: Y and dX
 MOE_EXPERTS_TGMM = "hvd_moe_experts_tgmm"   # rows^T x rows: dW
 MOE_COMBINE = "hvd_moe_combine"         # back to token order + weighted sum
+# The Mamba-2 mixer (models/mamba.py) and its chunked state-space scan
+# (ops/ssd.py). The benchmark finds the mixer's time by the substrings
+# ``hvd_mamba`` and ``hvd_ssd``, the scan's by ``hvd_ssd``.
+MAMBA_PROJ = "hvd_mamba_proj"           # the input and output projections
+MAMBA_CONV = "hvd_mamba_conv"           # causal depthwise convolution + silu
+MAMBA_GATE_NORM = "hvd_mamba_gate_norm"     # y * silu(z), then RMSNorm
+SSD_SCAN = "hvd_ssd_scan"               # the scan over blocks of chunks: chunk
+#                                         states, the recurrence, the outputs

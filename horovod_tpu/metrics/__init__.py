@@ -38,6 +38,7 @@ from .overlap import (  # noqa: F401
     record_moe_grouped_plan,
     record_plan,
     record_shard_plan,
+    record_ssd_plan,
     record_sharded_state_bytes,
     record_tier_plan,
     record_wire_plan,

@@ -187,6 +187,17 @@ def record_moe_grouped_plan(border_overhead: float) -> None:
     ).set(border_overhead)
 
 
+def record_ssd_plan(chunk: int) -> None:
+    """Record the chunk length the latest traced ``ops.ssd.ssd`` cut its rows
+    into (trace time, once per compile): the configured chunk, or the row's
+    own length where that is shorter. 0 until a state-space scan is traced."""
+    registry().gauge(
+        "horovod_ssd_chunk_len",
+        help="positions a chunk of the latest traced ops.ssd.ssd (the "
+             "chunked state-space scan); 0 = none traced"
+    ).set(chunk)
+
+
 # Latest fabric-tier plan of the hierarchical compiled path (ISSUE 7):
 # {"hierarchical": bool, "ici_wire": str, "dcn_wire": str, "ici_size": int,
 #  "bytes_per_step": {"ici": n, "dcn": n}, "buckets": int}.

@@ -22,6 +22,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .mamba import Mamba2Dims, Mamba2Mixer
+
 
 def _rope(x, positions):
     """Rotary position embedding on the last dim (pairs)."""
@@ -34,10 +36,11 @@ def _rope(x, positions):
     return rotated.astype(x.dtype)
 
 
-def causal_attention(q, k, v, seq_offset=0):
+def causal_attention(q, k, v, seq_offset=0, scale=None):
     """Dense causal attention. q,k,v: [B, T, H, D]. Runs on-chip in one block —
-    fine up to ~8k tokens; ring attention takes over beyond that."""
-    scale = q.shape[-1] ** -0.5
+    fine up to ~8k tokens; ring attention takes over beyond that. ``scale``
+    multiplies the scores (None: D ** -0.5)."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     t_q, t_k = q.shape[1], k.shape[1]
     q_pos = jnp.arange(t_q) + seq_offset
@@ -69,19 +72,63 @@ class Block(nn.Module):
     # ask for it); the default compiles them for the TPU and raises without
     # one — never inferred from the platform.
     flash_interpret: bool = False
+    # What a hybrid model's configuration states (TransformerLM documents
+    # them): a Mamba-2 mixer in place of attention, a SwiGLU MLP of width
+    # mlp_hidden in place of the ungated GELU one, no rotary embedding, a
+    # softmax scale of its own, every branch times residual_scale.
+    mamba: Optional[Mamba2Dims] = None
+    mlp_hidden: Optional[int] = None
+    rope: bool = True
+    attention_scale: Optional[float] = None
+    residual_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x, positions):
         if self.attention not in ("dense", "flash"):
             raise ValueError(
                 f"unknown attention={self.attention!r}; use 'dense' or 'flash'")
+        h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
+        if self.mamba is not None:
+            mixed = Mamba2Mixer(dim=self.dim, dims=self.mamba,
+                                rms_norm_eps=self.rms_norm_eps,
+                                dtype=self.dtype, name="mixer")(h)
+        else:
+            mixed = self._attention(h, positions)
+        x = self._add(x, mixed)
+        h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
+        if self.moe_experts > 0:
+            from .moe import MoEMLP
+
+            hidden = (self.mlp_ratio * self.dim if self.moe_hidden is None
+                      else self.moe_hidden)
+            return self._add(x, MoEMLP(
+                dim=self.dim, hidden=hidden, n_experts=self.moe_experts,
+                top_k=self.moe_top_k, dtype=self.dtype,
+                interpret=self.flash_interpret, name="moe")(h))
+        if self.mlp_hidden is not None:
+            gate, up = (nn.Dense(self.mlp_hidden, use_bias=False,
+                                 dtype=self.dtype, name=name)(h)
+                        for name in ("mlp_gate", "mlp_up"))
+            return self._add(x, nn.Dense(self.dim, use_bias=False,
+                                         dtype=self.dtype, name="mlp_down")(
+                nn.silu(gate) * up))
+        h = nn.Dense(self.mlp_ratio * self.dim, use_bias=False, dtype=self.dtype, name="mlp_in")(h)
+        h = nn.gelu(h)
+        return self._add(x, nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="mlp_out")(h))
+
+    def _add(self, x, branch):
+        if self.residual_scale != 1.0:
+            branch = branch * jnp.asarray(self.residual_scale, branch.dtype)
+        return x + branch
+
+    def _attention(self, h, positions):
+        """Causal self-attention of the normed ``h``, through o_proj."""
         head_dim = self.dim // self.heads
         kvh = self.heads if self.kv_heads is None else self.kv_heads
         if kvh < 1 or self.heads % kvh:
             raise ValueError(
                 f"kv_heads {kvh} must be >= 1 and divide heads {self.heads}")
-        h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
-        b, t = x.shape[0], x.shape[1]
+        b, t = h.shape[0], h.shape[1]
         if kvh == self.heads:
             qkv = nn.Dense(3 * self.dim, use_bias=False, dtype=self.dtype, name="qkv")(h)
             q, k, v = jnp.split(qkv, 3, axis=-1)
@@ -98,8 +145,14 @@ class Block(nn.Module):
                            name="q_norm")(q)
             k = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype,
                            name="k_norm")(k)
-        q = _rope(q.reshape(b, t, self.heads, head_dim), positions)
-        k = _rope(k.reshape(b, t, kvh, head_dim), positions)
+        # q wholly before k, as ever: the older models' lowered text is held
+        # byte for byte
+        q = q.reshape(b, t, self.heads, head_dim)
+        if self.rope:
+            q = _rope(q, positions)
+        k = k.reshape(b, t, kvh, head_dim)
+        if self.rope:
+            k = _rope(k, positions)
         v = v.reshape(b, t, kvh, head_dim)
         if self.attention == "dense" and kvh != self.heads and self.sp_axis is None:
             # The local dense einsum path is plain multi-head; replicate kv
@@ -114,6 +167,9 @@ class Block(nn.Module):
         bq = self.block_q if self.block_q is not None else DEFAULT_BLOCK_Q
         bk = self.block_k if self.block_k is not None else DEFAULT_BLOCK_K
         if self.sp_axis is not None:
+            if self.attention_scale is not None:
+                raise ValueError("the ring schedules scale by head_dim ** -0.5; "
+                                 "attention_scale needs sp_axis=None")
             if self.attention == "flash":
                 from ..ops.ring_flash import ring_flash_attention
 
@@ -127,35 +183,29 @@ class Block(nn.Module):
         elif self.attention == "flash":
             from ..ops.flash_attention import flash_attention
 
-            attn = flash_attention(q, k, v, block_q=bq, block_k=bk,
-                                   interpret=self.flash_interpret)
+            # positional: custom_vjp nondiff_argnums
+            attn = flash_attention(q, k, v, True, bq, bk,
+                                   self.flash_interpret, self.attention_scale)
         else:
-            attn = causal_attention(q, k, v)
+            # the keyword only where a scale is stated: the benchmark's lm217m
+            # reference swaps this function for one that takes none
+            attn = (causal_attention(q, k, v) if self.attention_scale is None
+                    else causal_attention(q, k, v, scale=self.attention_scale))
         attn = attn.reshape(b, t, self.dim)
-        x = x + nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="o_proj")(attn)
-        h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
-        if self.moe_experts > 0:
-            from .moe import MoEMLP
-
-            hidden = (self.mlp_ratio * self.dim if self.moe_hidden is None
-                      else self.moe_hidden)
-            x = x + MoEMLP(dim=self.dim, hidden=hidden,
-                           n_experts=self.moe_experts, top_k=self.moe_top_k,
-                           dtype=self.dtype, interpret=self.flash_interpret,
-                           name="moe")(h)
-        else:
-            h = nn.Dense(self.mlp_ratio * self.dim, use_bias=False, dtype=self.dtype, name="mlp_in")(h)
-            h = nn.gelu(h)
-            x = x + nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="mlp_out")(h)
-        return x
+        return nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="o_proj")(attn)
 
 
 class TransformerLM(nn.Module):
-    # TPU sizing note (measured, docs/benchmarks.md "head_dim and the MXU"):
-    # keep head_dim = dim // heads >= 128. The MXU contracts 128 lanes per
-    # pass, so head_dim 64 runs every attention matmul at half width —
-    # measured 33% tokens/sec swing at dim 1024 between heads=16 (hd 64)
-    # and heads=8 (hd 128), identical FLOPs and params.
+    # TPU sizing note (docs/benchmarks.md "head_dim and the MXU"): prefer
+    # head_dim = dim // heads >= 128 where the architecture is yours to
+    # choose. The MXU contracts 128 lanes per pass, so head_dim 64 runs every
+    # attention matmul at half width. Round 3 (the kernels of that time)
+    # measured a 33% tokens/sec swing at dim 1024 between heads=16 (hd 64) and
+    # heads=8 (hd 128), identical FLOPs and params. Re-read with PR 25's
+    # kernels (PERF.md §5, PR 30, one v5e chip at 16,384 tokens): at head_dim
+    # 64 (32 query heads over 8, granite4h_long_1chip) the forward, dq and dkv
+    # kernels run at 38 / 39 / 37% of the bf16 peak on their needed products;
+    # at head_dim 128 (lm217m_long_1chip) at 68 / 84 / 76%. Still half.
     vocab: int = 32000
     dim: int = 512
     heads: int = 8
@@ -211,12 +261,46 @@ class TransformerLM(nn.Module):
     # the remaining numerics change is the one-time bf16 rounding of the
     # logit values themselves. Kernel params stay f32 either way.
     logits_dtype: Any = jnp.float32
+    # A hybrid model (Granite 4.0-H: docs/mamba-hybrid.md), each as the
+    # model's own configuration states it. layer_types: one of "attention" /
+    # "mamba" a layer (len == layers; None: attention everywhere), "mamba"
+    # putting a Mamba-2 mixer of the sizes in ``mamba`` (models/mamba.py) in
+    # attention's place. mlp_hidden: a SwiGLU MLP of that width in every
+    # block in place of the ungated GELU one. rope=False: no rotary
+    # embedding ("nope"). tie_embeddings: the head is the embedding's
+    # transpose, one leaf that receives both gradients (no lm_head leaf).
+    # The four multipliers: the embedding's output times
+    # embedding_multiplier, attention scores times attention_multiplier in
+    # place of head_dim ** -0.5 (handed to the flash kernels as their
+    # softmax scale), every residual branch times residual_multiplier,
+    # logits divided by logits_scaling — with return_hidden the hidden states
+    # come back already divided by it, so that ``hidden @ head`` is the logits
+    # for chunked_lm_loss, whose ``head`` is then ``embedding.T``.
+    layer_types: Optional[tuple] = None
+    mamba: Optional[Mamba2Dims] = None
+    mlp_hidden: Optional[int] = None
+    rope: bool = True
+    tie_embeddings: bool = False
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden: bool = False):
         if positions is None:
             positions = jnp.arange(tokens.shape[1])[None, :]
-        x = nn.Embed(self.vocab, self.dim, dtype=self.dtype, name="embed")(tokens)
+        kinds = (("attention",) * self.layers if self.layer_types is None
+                 else tuple(self.layer_types))
+        if len(kinds) != self.layers or set(kinds) - {"attention", "mamba"}:
+            raise ValueError(f"layer_types {kinds} must name 'attention' or "
+                             f"'mamba' for each of the {self.layers} layers")
+        if "mamba" in kinds and self.mamba is None:
+            raise ValueError("a 'mamba' layer needs the mixer's sizes (mamba=)")
+        embed = nn.Embed(self.vocab, self.dim, dtype=self.dtype, name="embed")
+        x = embed(tokens)
+        if self.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(self.embedding_multiplier, x.dtype)
         block_cls = nn.remat(Block) if self.remat else Block
         for i in range(self.layers):
             x = block_cls(
@@ -237,9 +321,21 @@ class TransformerLM(nn.Module):
                 moe_hidden=self.moe_hidden,
                 qk_norm=self.qk_norm,
                 rms_norm_eps=self.rms_norm_eps,
+                mamba=self.mamba if kinds[i] == "mamba" else None,
+                mlp_hidden=self.mlp_hidden,
+                rope=self.rope,
+                attention_scale=self.attention_multiplier,
+                residual_scale=self.residual_multiplier,
                 name=f"block_{i}",
             )(x, positions)
         x = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
+        if self.logits_scaling != 1.0:
+            x = x / jnp.asarray(self.logits_scaling, x.dtype)
+        if self.tie_embeddings:
+            if return_hidden:
+                return x
+            return jnp.dot(x.astype(self.logits_dtype),
+                           embed.embedding.T.astype(self.logits_dtype))
         head = nn.Dense(self.vocab, use_bias=False, dtype=self.logits_dtype,
                         name="lm_head")
         if return_hidden:

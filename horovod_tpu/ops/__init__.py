@@ -1,7 +1,9 @@
 """TPU compute ops beyond stock XLA: sequence-parallel attention schedules
 (ring / Ulysses), mixture of experts (dropless top-k) with its grouped
 products as pallas kernels (``grouped_matmul``), and a pallas
-flash-attention kernel (fused, trainable) for the hot op."""
+flash-attention kernel (fused, trainable) for the hot op, and Mamba-2's
+chunked state-space scan with its causal depthwise convolution (the module
+``ssd``: ``from horovod_tpu.ops.ssd import ssd, causal_depthwise_conv``)."""
 
 from .flash_attention import flash_attention  # noqa: F401
 

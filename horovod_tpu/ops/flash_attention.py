@@ -363,11 +363,12 @@ def _q_row(r, j, nq, h, hkv, group):
     return (r // hkv) * h + (r % hkv) * group + j // nq
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = False):
+                    interpret: bool = False,
+                    sm_scale: float | None = None):
     """Fused attention, trainable. q: ``(B, T, H, D)``, k/v: ``(B, T, H, D)``
     or ``(B, T, Hkv, D)`` with ``H % Hkv == 0`` for grouped-query attention
     (each kv head serves a contiguous group of q heads — no head
@@ -378,27 +379,29 @@ def flash_attention(q, k, v, causal: bool = True,
     at d=64 — bigger blocks amortize scratch round-trips and feed the MXU
     wider). q, k and v go to the MXU in the dtype they arrive in; the
     softmax statistics, the accumulators and ``exp`` are f32 throughout.
+    ``sm_scale`` multiplies the f32 scores inside the kernels, forward and
+    backward (None: ``D ** -0.5``; Granite's ``attention_multiplier`` is not).
     ``interpret=True`` runs the kernels in the Pallas interpreter (CPU
     tests); the default compiles them for the TPU and raises on a machine
     that has none."""
-    out, _ = _fwd(q, k, v, causal, block_q, block_k, interpret)
+    out, _ = _fwd(q, k, v, causal, block_q, block_k, interpret, sm_scale)
     return out
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret):
+def _fwd(q, k, v, causal, block_q, block_k, interpret, sm_scale):
     from ..metrics import record_flash_plan
     t = q.shape[1]
     record_flash_plan(*block_census(
         t, *_check_blocks(t, block_q, block_k, interpret), causal))
-    return _fwd_call(q, k, v, causal, block_q, block_k, interpret)
+    return _fwd_call(q, k, v, causal, block_q, block_k, interpret, sm_scale)
 
 
 # The calls are jitted so that the layers of a model, which call them with
 # one signature, share ONE traced and lowered copy of each kernel: the
 # kernels' bodies are unrolled and cost seconds to trace and lower, and a
 # step is traced and lowered on every start, warm or cold.
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
-def _fwd_call(q, k, v, causal, block_q, block_k, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _fwd_call(q, k, v, causal, block_q, block_k, interpret, sm_scale):
     b, t, h, d = q.shape
     h, hkv, group = _gqa_group(q, k, v)
     block_q, block_k = _check_blocks(t, block_q, block_k, interpret)
@@ -407,7 +410,7 @@ def _fwd_call(q, k, v, causal, block_q, block_k, interpret):
     nk = t // block_k
     kernel = functools.partial(
         _fwd_kernel, block_q=block_q, block_k=block_k, nk=nk, causal=causal,
-        sm_scale=d ** -0.5)
+        sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
     kv_spec = pl.BlockSpec(
         (1, block_k, d), lambda r, qi, ki: (_kv_row(r, h, hkv, group), ki, 0))
     out, lse = pl.pallas_call(
@@ -437,8 +440,8 @@ def _fwd_call(q, k, v, causal, block_q, block_k, interpret):
     return _unrows(out, b, t, h, d), (q, k, v, out, lse)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def _bwd_rule(causal, block_q, block_k, interpret, res, dout):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, res, dout):
     q, k, v, out, lse = res
     b, t, h, d = q.shape
     h, hkv, group = _gqa_group(q, k, v)
@@ -453,7 +456,7 @@ def _bwd_rule(causal, block_q, block_k, interpret, res, dout):
 
     nq, nk = t // block_q, t // block_k
     common = dict(block_q=block_q, block_k=block_k, causal=causal,
-                  sm_scale=d ** -0.5)
+                  sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
     kv_spec = pl.BlockSpec(
         (1, block_k, d), lambda r, qi, ki: (_kv_row(r, h, hkv, group), ki, 0))
 

@@ -126,3 +126,60 @@ def test_name_is_in_the_lowered_module_as_metadata_only(name, request):
     assert name in text[True]
     if name != names.FUSED_ALLREDUCE:   # the three new ones: metadata alone
         assert name not in text[False]
+
+
+# ------------------------------------------------- the Mamba-2 mixer's scopes
+
+MAMBA_SCOPES = [names.MAMBA_PROJ, names.MAMBA_CONV, names.MAMBA_GATE_NORM,
+                names.SSD_SCAN]
+
+
+@pytest.fixture(scope="module")
+def mamba_op_names():
+    """The ``op_name``s of a tiny hybrid's gradients (16 chunks: the scan's
+    scan runs as one loop over blocks of chunks, as at the
+    published sizes; recomputation on, as the cell runs), and the text
+    without them."""
+    import re
+
+    from horovod_tpu.models import TransformerLM
+    from horovod_tpu.models.mamba import Mamba2Dims
+
+    model = TransformerLM(
+        vocab=64, dim=32, heads=4, kv_heads=2, layers=2,
+        layer_types=("mamba", "attention"),
+        mamba=Mamba2Dims(heads=4, head_dim=4, state=8, chunk=4),
+        mlp_hidden=48, rope=False, tie_embeddings=True, remat=True,
+        dtype=jnp.float32)
+    tokens = jnp.zeros((1, 64), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+    lowered = jax.jit(jax.grad(
+        lambda p: model.apply({"params": p}, tokens).sum())).lower(params)
+    found = set(re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)))
+    return found, lowered.as_text(debug_info=False)
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+@pytest.mark.parametrize("name", MAMBA_SCOPES)
+def test_mamba_scope_survives_the_breakdowns_label(name, backward,
+                                                   mamba_op_names):
+    """In the module as metadata only, on forward and backward operations,
+    and still there in what ``benchmarks/reduce_trace.op_label`` keeps of an
+    ``op_name``: its last three segments."""
+    found, bare = mamba_op_names
+    assert name not in bare
+    kept = {"/".join(n.split("/")[-3:]) for n in found
+            if ("transpose(jvp(" in n) is backward}
+    assert any(name in label for label in kept), sorted(kept)[:20]
+
+
+def test_the_scans_loop_is_named_once(mamba_op_names):
+    """The scan over blocks of chunks is a ``while`` op that carries the
+    scope; the ops of its body end in ``while/body/...`` and their kept label
+    does not, so a reader that sums labels holding ``hvd_ssd`` counts the loop
+    once (the device trace lists the loop AND its body's ops)."""
+    found, _ = mamba_op_names
+    assert any(n.endswith(f"{names.SSD_SCAN}/while") for n in found)
+    inside = [n for n in found if "/while/body/" in n and "hvd_ssd" in n]
+    assert inside
+    assert not any("hvd_ssd" in "/".join(n.split("/")[-3:]) for n in inside)

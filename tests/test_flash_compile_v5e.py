@@ -1,4 +1,6 @@
-"""The three flash kernels and the two grouped-product kernels COMPILED for a
+"""The three flash kernels (multi-head at D = 128, and grouped-query 32 over 8
+at D = 64 with a softmax scale of its own), the two grouped-product kernels
+and the chunked state-space scan COMPILED for a
 described v5e at the benchmark cells' shapes (no chip attached, nothing runs): what interpret mode cannot
 see — scoped VMEM, tiling and layout faults of a kernel edit — is refused
 here, on the CPU, by the TPU's own compiler.
@@ -108,3 +110,60 @@ def test_grouped_kernels_compile_for_v5e(one_chip, no_persistent_cache, rows,
     for name in (MOE_EXPERTS_GMM, MOE_EXPERTS_TGMM):
         assert name in text, f"{name} is not in the compiled module"
     assert "ragged" not in text
+
+
+# granite4h_long_1chip: 32 query heads over 8 key/value heads of 64 at 16,384
+# tokens, scores scaled by attention_multiplier (not 64 ** -0.5): the
+# grouped-query index maps and the lane slice of the row statistics below 128.
+GQA = dict(t=16384, heads=32, kv_heads=8, head_dim=64, scale=0.015625)
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "forward_backward"])
+def test_grouped_query_kernels_at_head_size_64_compile_for_v5e(
+        one_chip, no_persistent_cache, backward):
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K,
+                               False, GQA["scale"])
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(flash(*a).astype(jnp.float32)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    def shape(heads):
+        return jax.ShapeDtypeStruct((1, GQA["t"], heads, GQA["head_dim"]),
+                                    jnp.bfloat16, sharding=one_chip)
+
+    q, kv = shape(GQA["heads"]), shape(GQA["kv_heads"])
+    text = jax.jit(grads if backward else flash).lower(q, kv, kv).compile().as_text()
+    for name in ((FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV) if backward
+                 else (FLASH_FWD,)):
+        assert name in text, f"{name} is not in the compiled module"
+
+
+@pytest.mark.parametrize("dtype,precision", [(jnp.bfloat16, None),
+                                             (jnp.float32, "highest")],
+                         ids=["bf16", "f32_highest"])
+def test_the_scan_compiles_for_v5e_at_the_cells_shape(one_chip,
+                                                      no_persistent_cache,
+                                                      dtype, precision):
+    """(16384, 64 heads x 64, state 128) at chunk 256, forward and backward:
+    plain XLA, no kernel; the compiler's own count of its temporaries stays
+    under 2 GiB (all 64 chunks' masked scores at once would be 1 GiB each)."""
+    from horovod_tpu.ops.ssd import ssd
+
+    t, h, p, n = 16384, 64, 64, 128
+
+    def grads(u, dt, A, B, C, D):
+        return jax.grad(lambda *a: jnp.sum(ssd(*a, 256).astype(jnp.float32)),
+                        argnums=(0, 1, 2, 3, 4, 5))(u, dt, A, B, C, D)
+
+    def shape(*dims, of=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    args = (shape(1, t, h, p, of=dtype), shape(1, t, h), shape(h),
+            shape(1, t, 1, n, of=dtype), shape(1, t, 1, n, of=dtype), shape(h))
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(grads).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
