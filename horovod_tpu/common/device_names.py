@@ -29,5 +29,13 @@ MOE_COMBINE = "hvd_moe_combine"         # back to token order + weighted sum
 MAMBA_PROJ = "hvd_mamba_proj"           # the input and output projections
 MAMBA_CONV = "hvd_mamba_conv"           # causal depthwise convolution + silu
 MAMBA_GATE_NORM = "hvd_mamba_gate_norm"     # y * silu(z), then RMSNorm
+# The two chains' own kernels (ops/mamba_fused.py), for the shapes they tile;
+# the scopes above stay on the jax.numpy forms of every other shape. Four
+# names, all holding ``hvd_mamba``: four labels of a few ms each, so that no
+# one of them passes the scan's loop among the breakdown's longest.
+MAMBA_CONV_FWD = "hvd_mamba_conv_fwd"
+MAMBA_CONV_BWD = "hvd_mamba_conv_bwd"
+MAMBA_GATE_NORM_FWD = "hvd_mamba_gate_norm_fwd"
+MAMBA_GATE_NORM_BWD = "hvd_mamba_gate_norm_bwd"
 SSD_SCAN = "hvd_ssd_scan"               # the scan over blocks of chunks: chunk
 #                                         states, the recurrence, the outputs

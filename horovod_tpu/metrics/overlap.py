@@ -198,6 +198,19 @@ def record_ssd_plan(chunk: int) -> None:
     ).set(chunk)
 
 
+def record_mamba_fused_passes(passes: int) -> None:
+    """Record how many of the latest traced ``models.mamba.Mamba2Mixer``'s two
+    elementwise chains (convolution + silu, the gated norm) went through a
+    kernel of ``ops.mamba_fused`` (trace time, once per compile): 2, 1, or 0
+    where both shapes kept ``jax.numpy``. 0 until a mixer is traced."""
+    registry().gauge(
+        "horovod_mamba_fused_passes",
+        help="chains of the latest traced Mamba2Mixer (convolution + silu, "
+             "gated norm) that went through a fused kernel; 0 = none traced "
+             "or both in jax.numpy"
+    ).set(passes)
+
+
 # Latest fabric-tier plan of the hierarchical compiled path (ISSUE 7):
 # {"hierarchical": bool, "ici_wire": str, "dcn_wire": str, "ici_size": int,
 #  "bytes_per_step": {"ici": n, "dcn": n}, "buckets": int}.

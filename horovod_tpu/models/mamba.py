@@ -17,7 +17,16 @@ projection::
 Numerics: float32 parameters; the projections, the convolution's output and
 ``u``, ``B``, ``C`` in ``dtype`` (bf16 as trained); ``dt``, ``A``, the decays
 and the carried state in float32 (``ops.ssd``); the gated norm's statistics
-in float32.
+in float32, its result in ``dtype``.
+
+The two elementwise chains, ``silu(conv(xBC))`` and ``RMSNorm(y * silu(z))``,
+run as fused kernels with a backward of their own (``ops/mamba_fused.py``:
+one pass over HBM each way, float32 inside, the same roundings) where their
+shapes tile: channels and a group's features multiples of 128, the row a
+whole number of row tiles. Every other shape keeps the ``jax.numpy`` forms
+(``ops.ssd.causal_depthwise_conv`` + silu, :func:`gated_rms_norm`), which
+stay the definitions. The shapes choose and nothing else does; the gauge
+``horovod_mamba_fused_passes`` says how many of the two took a kernel.
 
 Initialisation is Mamba-2's: ``A`` uniform in [1, 16], ``dt`` log-uniform in
 [0.001, 0.1] stored through the inverse of the softplus, ``D`` = 1, norm
@@ -37,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from ..common import device_names
+from ..ops import mamba_fused
 from ..ops.ssd import causal_depthwise_conv, ssd
 
 
@@ -82,9 +92,15 @@ class Mamba2Mixer(nn.Module):
     dims: Mamba2Dims
     rms_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    # True runs the two chains' kernels (ops/mamba_fused.py), where the
+    # shapes take them, in the Pallas interpreter: ``Block`` hands its
+    # ``flash_interpret`` down, one flag for every Pallas kernel of a block.
+    interpret: bool = False
 
     @nn.compact
     def __call__(self, h):
+        from ..metrics import record_mamba_fused_passes
+
         m = self.dims
         b, t, _ = h.shape
         inner, bc = m.heads * m.head_dim, m.groups * m.state
@@ -100,10 +116,21 @@ class Mamba2Mixer(nn.Module):
                                  (m.conv, channels), jnp.float32)
         conv_bias = self.param("conv_bias", nn.initializers.zeros,
                                (channels,), jnp.float32)
-        xbc = causal_depthwise_conv(xbc, conv_kernel, conv_bias)
-        with jax.named_scope(device_names.MAMBA_CONV):
-            xbc = nn.silu(xbc)
-        u, B, C = jnp.split(xbc, [inner, inner + bc], axis=-1)
+        # Where the shapes tile, the kernels read xBC and z out of zxbcdt
+        # where they lie and write u and B | C as two outputs: no slice of
+        # 16,384 x 4,096 is copied on either side (B and C are 3% of it).
+        fused_conv = mamba_fused.conv_takes_kernel(xbc, conv_kernel,
+                                                   (inner, 2 * bc))
+        if fused_conv:
+            u, BC = mamba_fused.conv_silu(
+                xbc, conv_kernel, conv_bias, self.interpret,
+                splits=(inner, 2 * bc), wide=zxbcdt, start=inner)
+            B, C = jnp.split(BC, 2, axis=-1)
+        else:
+            xbc = causal_depthwise_conv(xbc, conv_kernel, conv_bias)
+            with jax.named_scope(device_names.MAMBA_CONV):
+                xbc = nn.silu(xbc)
+            u, B, C = jnp.split(xbc, [inner, inner + bc], axis=-1)
         a_log = self.param("A_log", _a_log_init, (m.heads,), jnp.float32)
         dt_bias = self.param("dt_bias", _dt_bias_init, (m.heads,), jnp.float32)
         skip = self.param("D", nn.initializers.ones, (m.heads,), jnp.float32)
@@ -113,8 +140,16 @@ class Mamba2Mixer(nn.Module):
                 C.reshape(b, t, m.groups, m.state), skip, m.chunk)
         scale = self.param("gate_norm", nn.initializers.ones, (inner,),
                            jnp.float32)
-        y = gated_rms_norm(y.reshape(b, t, inner), z, scale, m.groups,
-                           self.rms_norm_eps).astype(self.dtype)
+        y = y.reshape(b, t, inner)
+        fused_norm = mamba_fused.norm_takes_kernel(y, z, m.groups)
+        if fused_norm:
+            y = mamba_fused.gate_norm(y, z, scale, m.groups,
+                                      self.rms_norm_eps, self.interpret,
+                                      wide=zxbcdt)
+        else:
+            y = gated_rms_norm(y, z, scale, m.groups,
+                               self.rms_norm_eps).astype(self.dtype)
+        record_mamba_fused_passes(fused_conv + fused_norm)
         with jax.named_scope(device_names.MAMBA_PROJ):
             return nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
                             name="out_proj")(y)

@@ -90,8 +90,8 @@ class Block(nn.Module):
         h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
         if self.mamba is not None:
             mixed = Mamba2Mixer(dim=self.dim, dims=self.mamba,
-                                rms_norm_eps=self.rms_norm_eps,
-                                dtype=self.dtype, name="mixer")(h)
+                                rms_norm_eps=self.rms_norm_eps, dtype=self.dtype,
+                                interpret=self.flash_interpret, name="mixer")(h)
         else:
             mixed = self._attention(h, positions)
         x = self._add(x, mixed)

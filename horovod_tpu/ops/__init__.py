@@ -3,7 +3,9 @@
 products as pallas kernels (``grouped_matmul``), and a pallas
 flash-attention kernel (fused, trainable) for the hot op, and Mamba-2's
 chunked state-space scan with its causal depthwise convolution (the module
-``ssd``: ``from horovod_tpu.ops.ssd import ssd, causal_depthwise_conv``)."""
+``ssd``: ``from horovod_tpu.ops.ssd import ssd, causal_depthwise_conv``) and
+the mixer's two elementwise chains as pallas kernels (the module
+``mamba_fused``: ``conv_silu``, ``gate_norm``)."""
 
 from .flash_attention import flash_attention  # noqa: F401
 

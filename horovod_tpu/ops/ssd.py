@@ -33,10 +33,13 @@ state are float32 whatever the activations' dtype. The three products with a
 for the product, as the published kernels do) and accumulate in float32;
 they follow ``jax.default_matmul_precision`` as a plain ``@`` does.
 
-Plain ``jax.numpy`` / ``lax``: differentiable by JAX, no kernel. The masked
-scores of all heads (heads x chunk x chunk a chunk) go through HBM; a block
-runs under ``jax.checkpoint`` so that no more than ``CHUNK_BLOCK`` chunks'
-scores are alive at once, forward or backward (PERF.md §6, PR 30).
+The scan is plain ``jax.numpy`` / ``lax``: differentiable by JAX, no kernel.
+The masked scores of all heads (heads x chunk x chunk a chunk) go through
+HBM; a block runs under ``jax.checkpoint`` so that no more than
+``CHUNK_BLOCK`` chunks' scores are alive at once, forward or backward
+(PERF.md §6, PR 30). :func:`causal_depthwise_conv` is the convolution's
+DEFINITION, in ``jax.numpy`` too; shapes that tile run it fused with the silu
+that follows it as a kernel pair (``ops/mamba_fused.py`` ``conv_silu``, PR 31).
 """
 
 from __future__ import annotations
@@ -59,7 +62,10 @@ CHUNK_BLOCK = 8
 def causal_depthwise_conv(x, kernel, bias):
     """``out[t, c] = bias[c] + sum_j kernel[j, c] * x[t - (K - 1) + j, c]``
     with zeros before the row's start. x: (B, T, C); kernel: (K, C); bias:
-    (C,). Computed as K shifted multiply-adds in float32; returns x's dtype."""
+    (C,). Computed as K shifted multiply-adds in float32; returns x's dtype.
+    The padded row goes through HBM in float32 and its K slices are not
+    aligned to a tile: 9x the bytes' time at Granite's widths, which is why
+    ``Mamba2Mixer`` takes ``mamba_fused.conv_silu`` where the shape tiles."""
     k, t = kernel.shape[0], x.shape[1]
     with jax.named_scope(device_names.MAMBA_CONV):
         padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)

@@ -173,13 +173,72 @@ def test_mamba_scope_survives_the_breakdowns_label(name, backward,
     assert any(name in label for label in kept), sorted(kept)[:20]
 
 
-def test_the_scans_loop_is_named_once(mamba_op_names):
+@pytest.fixture(scope="module")
+def mamba_kernel_op_names():
+    """The ``op_name``s of a tiny hybrid's gradients at sizes the mixer's
+    fused kernels tile (inner 128, channels 384, 256 rows of float32),
+    LOWERED FOR THE TPU (nothing compiles or runs): the four ``pallas_call``s
+    are there as the chip names them, inside ``nn.remat(Block)``."""
+    import re
+
+    from horovod_tpu.models import TransformerLM
+    from horovod_tpu.models.mamba import Mamba2Dims
+
+    model = TransformerLM(
+        vocab=64, dim=32, heads=4, kv_heads=2, layers=2,
+        layer_types=("mamba", "attention"),
+        mamba=Mamba2Dims(heads=4, head_dim=32, state=128, chunk=16),
+        mlp_hidden=48, rope=False, tie_embeddings=True, remat=True,
+        dtype=jnp.float32)
+    tokens = jnp.zeros((1, 256), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    lowered = jax.jit(jax.grad(
+        lambda p: model.apply({"params": p}, tokens).sum())).trace(
+            params).lower(lowering_platforms=("tpu",))
+    return set(re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)))
+
+
+@pytest.mark.parametrize("name,call,where", [
+    (names.MAMBA_CONV_FWD, "_conv_fwd_call", "forward"),
+    (names.MAMBA_CONV_FWD, "_conv_fwd_call", "recomputed"),
+    (names.MAMBA_CONV_BWD, "_conv_bwd_call", "backward"),
+    (names.MAMBA_GATE_NORM_FWD, "_norm_fwd_call", "forward"),
+    (names.MAMBA_GATE_NORM_FWD, "_norm_fwd_call", "recomputed"),
+    (names.MAMBA_GATE_NORM_BWD, "_norm_bwd_call", "backward"),
+])
+def test_mixer_kernel_name_survives_the_breakdowns_label(
+        name, call, where, mamba_kernel_op_names):
+    """A kernel is one jitted call whose body names the ``pallas_call``: the
+    device op's ``op_name`` is the call site's plus the body's, and what
+    ``benchmarks/reduce_trace.op_label`` keeps of it, the last three segments,
+    holds ``hvd_mamba``: the mixer's readers find it."""
+    found = mamba_kernel_op_names
+    inside = f"{name}/pallas_call"
+    assert inside in found
+    sites = [n for n in found if n.endswith(f"/mixer/jit({call})")]
+    site, = [n for n in sites if {
+        "forward": "transpose(jvp(" not in n,
+        "recomputed": "/checkpoint/rematted_computation/" in n,
+        "backward": "transpose(jvp(" in n and "rematted" not in n}[where]]
+    label = "/".join(f"{site}/{inside}".split("/")[-3:])
+    assert label == f"jit({call})/{name}/pallas_call"
+    assert "hvd_mamba" in label and "hvd_ssd" not in label
+
+
+@pytest.mark.parametrize("fixture", ["mamba_op_names",
+                                     "mamba_kernel_op_names"])
+def test_the_scans_loop_is_named_once(fixture, request):
     """The scan over blocks of chunks is a ``while`` op that carries the
     scope; the ops of its body end in ``while/body/...`` and their kept label
     does not, so a reader that sums labels holding ``hvd_ssd`` counts the loop
-    once (the device trace lists the loop AND its body's ops)."""
-    found, _ = mamba_op_names
-    assert any(n.endswith(f"{names.SSD_SCAN}/while") for n in found)
+    once (the device trace lists the loop AND its body's ops). With the
+    mixer's fused kernels beside it too."""
+    found = request.getfixturevalue(fixture)
+    found = found[0] if isinstance(found, tuple) else found
+    loops = {"/".join(n.split("/")[-3:]) for n in found
+             if n.endswith(f"{names.SSD_SCAN}/while")}
+    assert loops == {f"mixer/{names.SSD_SCAN}/while"}
     inside = [n for n in found if "/while/body/" in n and "hvd_ssd" in n]
     assert inside
     assert not any("hvd_ssd" in "/".join(n.split("/")[-3:]) for n in inside)

@@ -1,6 +1,7 @@
 """The three flash kernels (multi-head at D = 128, and grouped-query 32 over 8
-at D = 64 with a softmax scale of its own), the two grouped-product kernels
-and the chunked state-space scan COMPILED for a
+at D = 64 with a softmax scale of its own), the two grouped-product kernels,
+the chunked state-space scan and the Mamba-2 mixer's four fused kernels
+(convolution + silu, gated norm) COMPILED for a
 described v5e at the benchmark cells' shapes (no chip attached, nothing runs): what interpret mode cannot
 see — scoped VMEM, tiling and layout faults of a kernel edit — is refused
 here, on the CPU, by the TPU's own compiler.
@@ -167,3 +168,47 @@ def test_the_scan_compiles_for_v5e_at_the_cells_shape(one_chip,
         compiled = jax.jit(grads).lower(*args).compile()
     assert "tpu_custom_call" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+@pytest.mark.parametrize("rows,dtype", [(16384, jnp.bfloat16),
+                                        (1024, jnp.float32)],
+                         ids=["cell_bf16", "check_f32"])
+@pytest.mark.parametrize("chain", ["conv_silu", "gate_norm"])
+def test_the_mixers_fused_kernels_compile_for_v5e(one_chip,
+                                                  no_persistent_cache, chain,
+                                                  rows, dtype):
+    """granite4h_long_1chip's mixer: the convolution + silu over (16384,
+    4352 channels, 4 taps) and the gated norm over (16384, 4096), forward and
+    backward, as trained and at the 1024 rows of the check's float32 leg
+    (``ops/mamba_fused.py``; blocks of 4.25 MiB under a 64 MiB VMEM limit)."""
+    from horovod_tpu.common.device_names import (MAMBA_CONV_BWD,
+                                                 MAMBA_CONV_FWD,
+                                                 MAMBA_GATE_NORM_BWD,
+                                                 MAMBA_GATE_NORM_FWD)
+    from horovod_tpu.ops import mamba_fused
+
+    def shape(*dims, of=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    if chain == "conv_silu":
+        def fused(x, kernel, bias):
+            return mamba_fused.conv_silu(x, kernel, bias)
+
+        args = (shape(1, rows, 4352, of=dtype), shape(4, 4352), shape(4352))
+        kernels = (MAMBA_CONV_FWD, MAMBA_CONV_BWD)
+        assert mamba_fused.conv_takes_kernel(*args[:2])
+    else:
+        def fused(y, z, scale):
+            return mamba_fused.gate_norm(y, z, scale, 1, 1e-5)
+
+        args = (shape(1, rows, 4096, of=dtype),) * 2 + (shape(4096),)
+        kernels = (MAMBA_GATE_NORM_FWD, MAMBA_GATE_NORM_BWD)
+        assert mamba_fused.norm_takes_kernel(*args[:2], 1)
+
+    def value_and_grads(*a):    # the value too: a forward nobody reads is cut
+        out, vjp = jax.vjp(fused, *a)
+        return out, vjp(out)
+
+    text = jax.jit(value_and_grads).lower(*args).compile().as_text()
+    for name in kernels:
+        assert name in text, f"{name} is not in the compiled module"
