@@ -23,6 +23,11 @@ MOE_EXPERTS = "hvd_moe_experts"         # the grouped SwiGLU products
 MOE_EXPERTS_GMM = "hvd_moe_experts_gmm"     # rows x weights: Y and dX
 MOE_EXPERTS_TGMM = "hvd_moe_experts_tgmm"   # rows^T x rows: dW
 MOE_COMBINE = "hvd_moe_combine"         # back to token order + weighted sum
+MOE_SHARED = "hvd_moe_shared"           # the shared SwiGLU expert every token takes
+# Latent attention (models/transformer.py, ``Block.mla``). The benchmark finds
+# the mixer's time by the substrings ``hvd_mla`` and ``hvd_flash_``.
+MLA_PROJ = "hvd_mla_proj"               # q, kv-down, kv-up, o + the latent's norm
+MLA_ROPE = "hvd_mla_rope"               # split, rotary, assembling q and k
 # The Mamba-2 mixer (models/mamba.py) and its chunked state-space scan
 # (ops/ssd.py). The benchmark finds the mixer's time by the substrings
 # ``hvd_mamba`` and ``hvd_ssd``, the scan's by ``hvd_ssd``.

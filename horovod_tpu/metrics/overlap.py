@@ -187,6 +187,18 @@ def record_moe_grouped_plan(border_overhead: float) -> None:
     ).set(border_overhead)
 
 
+def record_moe_dispatch_rows(rows: int) -> None:
+    """Record the rows of the sorted buffer the latest traced
+    ``ops.moe.dropless_experts`` gathers its tokens into and its results out
+    of (trace time, once per compile): ``N x top_k`` while the gathers move
+    the worst-case buffer, whatever share of the pairs falls on held experts."""
+    registry().gauge(
+        "horovod_moe_dispatch_rows",
+        help="rows of the sorted buffer the latest traced dropless_experts "
+             "gathers per layer; 0 = none traced"
+    ).set(rows)
+
+
 def record_ssd_plan(chunk: int) -> None:
     """Record the chunk length the latest traced ``ops.ssd.ssd`` cut its rows
     into (trace time, once per compile): the configured chunk, or the row's

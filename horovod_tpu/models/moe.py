@@ -1,30 +1,48 @@
-"""Flax MoE layer for the transformer: OLMoE's (arXiv:2409.02060).
+"""Flax MoE layer for the transformer, in two published forms.
 
-Softmax, then the ``top_k`` largest probabilities, not renormalised, NO
-capacity and no dropped pair, SwiGLU experts ``w_gate`` / ``w_up`` /
-``w_down`` of width ``hidden`` (a number of its own, 1024 = dim / 2 in
-OLMoE-1B-7B), computed as grouped products over the pairs sorted by expert
-(``ops.moe.dropless_experts``). All experts live with the tokens
-(data-parallel replicas).
+NO capacity and no dropped pair under either; SwiGLU experts ``w_gate`` /
+``w_up`` / ``w_down`` of width ``hidden`` (a number of its own: 1024 = dim / 2
+in OLMoE-1B-7B, 768 in kanana-2-30b-a3b), computed as grouped products over
+the pairs sorted by expert (``ops.moe.dropless_experts``).
+
+``router="softmax"`` (OLMoE, arXiv:2409.02060): softmax, then the ``top_k``
+largest probabilities, not renormalised. ``router="sigmoid"`` (DeepSeek-V3,
+arXiv:2412.19437 §2.1.2, ``noaux_tc`` with one group): sigmoid scores, the
+``top_k`` by score + bias, the chosen scores renormalised and multiplied by
+``route_scale``; the bias is the variable ``router_bias`` of the collection
+``moe_bias`` (not ``params``: no gradient, no optimizer, no weight decay),
+moved by the caller after each step (``ops.moe.router_bias_update``) from the
+``moe_expert_counts`` the layer sows. ``shared_hidden`` > 0 adds a SwiGLU
+expert of that width that every token takes, unweighted.
+
+A rank holds every expert (``held`` None: data-parallel replicas) or the
+experts ``[first, first + count)`` of the ``n_experts`` the router chooses
+among (``held = (first, count)``: one expert-parallel rank's share, the
+expert weights ``(count, ...)``); the layer then adds its own experts' part
+and the shared expert's, and nothing for the absent ones.
 
 What the layer sows under ``intermediates`` (read with
 ``mutable=["intermediates"]``; nothing is computed for a caller that does
-not): ``moe_lb_loss``, ``moe_z_loss``, ``moe_router_logits`` (N, E; for
-``ops.moe.record_expert_load``) and ``moe_chosen_experts`` (N, top_k).
-:func:`aux_losses` sums the two losses over the layers for the caller's loss
-function, which multiplies them by its coefficients (OLMoE: 0.01 and 0.001).
+not): ``moe_router_logits`` (N, E; for ``ops.moe.record_expert_load``) and
+``moe_chosen_experts`` (N, top_k); under softmax ``moe_lb_loss`` and
+``moe_z_loss``, which :func:`aux_losses` sums over the layers for the
+caller's loss function (OLMoE's coefficients: 0.01 and 0.001); under sigmoid
+``moe_expert_counts`` (E,), the pairs routed to each of ALL the experts.
 """
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.moe import (dropless_experts, router_z_loss,
-                       topk_load_balancing_loss, topk_route)
+from ..common import device_names
+from ..ops.moe import (_expert_counts, dropless_experts, router_z_loss,
+                       sigmoid_route, topk_load_balancing_loss, topk_route)
+
+BIAS_COLLECTION = "moe_bias"    # the sigmoid router's bias: state, not params
 
 
 class MoEMLP(nn.Module):
@@ -37,37 +55,69 @@ class MoEMLP(nn.Module):
     # the shapes take them, in the Pallas interpreter: ``Block`` hands its
     # ``flash_interpret`` down, one flag for every Pallas kernel of a block.
     interpret: bool = False
+    # What a DeepSeek-V3-family configuration states (the module docstring
+    # has the equations); the defaults are OLMoE's layer, operation for
+    # operation.
+    router: str = "softmax"
+    route_scale: float = 1.0
+    shared_hidden: int = 0
+    held: Optional[tuple] = None    # (first, count) of n_experts; None: all
 
     @nn.compact
     def __call__(self, x):
         b, t, d = x.shape
-        return self._topk_swiglu(x.reshape(-1, d)).reshape(b, t, d)
+        tokens = x.reshape(-1, d)
+        out = self._topk_swiglu(tokens)
+        if self.shared_hidden:
+            out = out + self._shared(tokens)
+        return out.reshape(b, t, d)
+
+    def _shared(self, tokens):
+        with jax.named_scope(device_names.MOE_SHARED):
+            gate, up = (nn.Dense(self.shared_hidden, use_bias=False,
+                                 dtype=self.dtype, name=name)(tokens)
+                        for name in ("shared_gate", "shared_up"))
+            return nn.Dense(tokens.shape[-1], use_bias=False, dtype=self.dtype,
+                            name="shared_down")(nn.silu(gate) * up)
 
     def _topk_swiglu(self, tokens):
         d, e, h = tokens.shape[-1], self.n_experts, self.hidden
         if not 0 < self.top_k <= e:
             raise ValueError(f"top_k {self.top_k} of {e} experts")
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"router {self.router!r}: 'softmax' or 'sigmoid'")
+        first, here = (0, e) if self.held is None else self.held
+        if not 0 <= first < first + here <= e:
+            raise ValueError(f"held {self.held} of {e} experts")
         router = self.param("router", nn.initializers.lecun_normal(), (d, e),
                             jnp.float32)
         # the expert axis is a batch axis: each expert's fan-in is its own
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         w_gate, w_up, w_down = (
             self.param(name, init, shape, jnp.float32).astype(self.dtype)
-            for name, shape in (("w_gate", (e, d, h)), ("w_up", (e, d, h)),
-                                ("w_down", (e, h, d))))
+            for name, shape in (("w_gate", (here, d, h)), ("w_up", (here, d, h)),
+                                ("w_down", (here, h, d))))
         # The router runs in float32 at full precision whatever the
         # activations' dtype: 2*N*D*E operations, and a coarser product
         # flips a token's 8th expert against its 9th far more often.
         logits = jnp.dot(tokens.astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
-        probs, weights, experts = topk_route(logits, self.top_k)
-        self.sow("intermediates", "moe_lb_loss",
-                 topk_load_balancing_loss(probs, experts))
-        self.sow("intermediates", "moe_z_loss", router_z_loss(logits))
+        if self.router == "sigmoid":
+            bias = self.variable(BIAS_COLLECTION, "router_bias", jnp.zeros,
+                                 (e,), jnp.float32).value
+            _, weights, experts = sigmoid_route(logits, bias, self.top_k,
+                                                self.route_scale)
+            self.sow("intermediates", "moe_expert_counts",
+                     _expert_counts(experts.reshape(-1), e))
+        else:
+            probs, weights, experts = topk_route(logits, self.top_k)
+            self.sow("intermediates", "moe_lb_loss",
+                     topk_load_balancing_loss(probs, experts))
+            self.sow("intermediates", "moe_z_loss", router_z_loss(logits))
         self.sow("intermediates", "moe_router_logits", logits)
         self.sow("intermediates", "moe_chosen_experts", experts)
         return dropless_experts(tokens.astype(self.dtype), weights, experts,
-                                w_gate, w_up, w_down, self.interpret)
+                                w_gate, w_up, w_down, self.interpret, self.held)
 
 
 def aux_losses(intermediates):
@@ -80,6 +130,15 @@ def aux_losses(intermediates):
             if any(getattr(p, "key", None) == name for p in path):
                 sums[name] = sums[name] + leaf
     return sums["moe_lb_loss"], sums["moe_z_loss"]
+
+
+def expert_counts(intermediates):
+    """The ``moe_expert_counts`` the sigmoid-routed layers sowed, as ``{block
+    name: (E,) int32}``: what the step hands, summed over ranks, to
+    ``ops.moe.router_bias_update`` for that block's bias."""
+    return {block: leaves["moe"]["moe_expert_counts"][0]
+            for block, leaves in intermediates.items()
+            if "moe_expert_counts" in leaves.get("moe", {})}
 
 
 def ep_param_specs(params, ep_axis: str = "ep"):

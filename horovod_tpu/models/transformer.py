@@ -15,22 +15,52 @@ none; the TPU build makes it first-class). Design:
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..common import device_names
 from .mamba import Mamba2Dims, Mamba2Mixer
 
 
-def _rope(x, positions):
-    """Rotary position embedding on the last dim (pairs)."""
+@dataclasses.dataclass(frozen=True)
+class LatentDims:
+    """Latent attention's (MLA's) sizes, as a DeepSeek-V3-family ``config.json``
+    states them: ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+    ``v_head_dim``. q and k have head size ``qk_nope + qk_rope``, v has ``v``."""
+    kv_rank: int
+    qk_nope: int
+    qk_rope: int
+    v: int
+
+
+def _rope(x, positions, theta=10000.0, interleave=False):
+    """Rotary position embedding on the last dim (pairs): component i with
+    i + half, or with ``interleave`` 2i with 2i + 1, turned by
+    ``positions * theta ** (-i / half)``."""
     half = x.shape[-1] // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., T, half]
     cos, sin = jnp.cos(angles)[..., None, :], jnp.sin(angles)[..., None, :]  # add head dim
+    if interleave:
+        # In place, lane-dense: x * cos + swap(x) * sin with swap(x)[2i] =
+        # -x[2i+1], swap(x)[2i+1] = x[2i] as a product with a constant matrix
+        # of 0 and +-1 (exact in any dtype: one term a sum). A reshape to
+        # (..., half, 2) or a stride-2 slice would put 2 of 128 lanes to use.
+        i = np.arange(half)
+        swap = np.zeros((2 * half, 2 * half), np.float32)
+        swap[2 * i + 1, 2 * i], swap[2 * i, 2 * i + 1] = -1.0, 1.0
+        swapped = jnp.dot(x, jnp.asarray(swap, x.dtype),
+                          preferred_element_type=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST
+                          if x.dtype == jnp.float32 else None)
+        cos, sin = (jnp.repeat(t, 2, axis=-1) for t in (cos, sin))
+        return (x * cos + swapped * sin).astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return rotated.astype(x.dtype)
@@ -81,6 +111,17 @@ class Block(nn.Module):
     rope: bool = True
     attention_scale: Optional[float] = None
     residual_scale: float = 1.0
+    # What a DeepSeek-V3-family configuration states (TransformerLM documents
+    # them): latent attention in place of multi-head, the rotary base and
+    # pairing, the experts' router, its scale, the shared expert's width, the
+    # share of the experts held here.
+    mla: Optional[LatentDims] = None
+    rope_theta: float = 10000.0
+    rope_interleave: bool = False
+    moe_router: str = "softmax"
+    moe_route_scale: float = 1.0
+    moe_shared_hidden: int = 0
+    moe_held: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, x, positions):
@@ -92,6 +133,8 @@ class Block(nn.Module):
             mixed = Mamba2Mixer(dim=self.dim, dims=self.mamba,
                                 rms_norm_eps=self.rms_norm_eps, dtype=self.dtype,
                                 interpret=self.flash_interpret, name="mixer")(h)
+        elif self.mla is not None:
+            mixed = self._latent_attention(h, positions)
         else:
             mixed = self._attention(h, positions)
         x = self._add(x, mixed)
@@ -104,7 +147,10 @@ class Block(nn.Module):
             return self._add(x, MoEMLP(
                 dim=self.dim, hidden=hidden, n_experts=self.moe_experts,
                 top_k=self.moe_top_k, dtype=self.dtype,
-                interpret=self.flash_interpret, name="moe")(h))
+                interpret=self.flash_interpret, router=self.moe_router,
+                route_scale=self.moe_route_scale,
+                shared_hidden=self.moe_shared_hidden, held=self.moe_held,
+                name="moe")(h))
         if self.mlp_hidden is not None:
             gate, up = (nn.Dense(self.mlp_hidden, use_bias=False,
                                  dtype=self.dtype, name=name)(h)
@@ -149,10 +195,10 @@ class Block(nn.Module):
         # byte for byte
         q = q.reshape(b, t, self.heads, head_dim)
         if self.rope:
-            q = _rope(q, positions)
+            q = _rope(q, positions, self.rope_theta, self.rope_interleave)
         k = k.reshape(b, t, kvh, head_dim)
         if self.rope:
-            k = _rope(k, positions)
+            k = _rope(k, positions, self.rope_theta, self.rope_interleave)
         v = v.reshape(b, t, kvh, head_dim)
         if self.attention == "dense" and kvh != self.heads and self.sp_axis is None:
             # The local dense einsum path is plain multi-head; replicate kv
@@ -162,10 +208,7 @@ class Block(nn.Module):
             # head via the grid index map and never materialize the copies.
             k = jnp.repeat(k, self.heads // kvh, axis=2)
             v = jnp.repeat(v, self.heads // kvh, axis=2)
-        from ..ops.flash_attention import DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
-
-        bq = self.block_q if self.block_q is not None else DEFAULT_BLOCK_Q
-        bk = self.block_k if self.block_k is not None else DEFAULT_BLOCK_K
+        bq, bk = self._flash_blocks()
         if self.sp_axis is not None:
             if self.attention_scale is not None:
                 raise ValueError("the ring schedules scale by head_dim ** -0.5; "
@@ -193,6 +236,61 @@ class Block(nn.Module):
                     else causal_attention(q, k, v, scale=self.attention_scale))
         attn = attn.reshape(b, t, self.dim)
         return nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="o_proj")(attn)
+
+    def _flash_blocks(self):
+        """(block_q, block_k) for the flash kernels: the fields, or the
+        kernels' own defaults."""
+        from ..ops.flash_attention import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q
+
+        return (self.block_q if self.block_q is not None else DEFAULT_BLOCK_Q,
+                self.block_k if self.block_k is not None else DEFAULT_BLOCK_K)
+
+    def _latent_attention(self, h, positions):
+        """Latent attention (MLA, arXiv:2412.19437 §2.1.1, q without a latent)
+        of the normed ``h``, through o_proj: keys and values come out of a
+        normed latent of ``kv_rank``, the rotary part of the key is ONE head
+        that every query head shares, and q | k have head size ``qk_nope +
+        qk_rope`` where v has ``v``. The shared rotary key is broadcast to the
+        heads where k is assembled (its gradient is summed over them by JAX);
+        a kernel that reads it through an index map, as the grouped-query
+        path reads a shared head, is not built."""
+        m, heads = self.mla, self.heads
+        if self.sp_axis is not None or self.kv_heads not in (None, heads):
+            raise ValueError("latent attention is multi-head on one chip: "
+                             "no sp_axis, no kv_heads")
+        b, t = h.shape[0], h.shape[1]
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype, name=name)
+
+        with jax.named_scope(device_names.MLA_PROJ):
+            q = dense(heads * (m.qk_nope + m.qk_rope), "q_proj")(h)
+            latent, k_rope = jnp.split(
+                dense(m.kv_rank + m.qk_rope, "kv_a_proj")(h), [m.kv_rank], axis=-1)
+            latent = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype,
+                                name="kv_a_norm")(latent)
+            kv = dense(heads * (m.qk_nope + m.v), "kv_b_proj")(latent)
+        with jax.named_scope(device_names.MLA_ROPE):
+            q_nope, q_rope = jnp.split(
+                q.reshape(b, t, heads, m.qk_nope + m.qk_rope), [m.qk_nope], axis=-1)
+            k_nope, v = jnp.split(
+                kv.reshape(b, t, heads, m.qk_nope + m.v), [m.qk_nope], axis=-1)
+            q_rope = _rope(q_rope, positions, self.rope_theta, self.rope_interleave)
+            k_rope = _rope(k_rope.reshape(b, t, 1, m.qk_rope), positions,
+                           self.rope_theta, self.rope_interleave)
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, (b, t, heads, m.qk_rope))], axis=-1)
+        if self.attention == "flash":
+            from ..ops.flash_attention import flash_attention
+
+            # positional: custom_vjp nondiff_argnums
+            attn = flash_attention(q, k, v, True, *self._flash_blocks(),
+                                   self.flash_interpret, self.attention_scale)
+        else:
+            attn = causal_attention(q, k, v, scale=self.attention_scale)
+        with jax.named_scope(device_names.MLA_PROJ):
+            return dense(self.dim, "o_proj")(attn.reshape(b, t, heads * m.v))
 
 
 class TransformerLM(nn.Module):
@@ -285,6 +383,29 @@ class TransformerLM(nn.Module):
     attention_multiplier: Optional[float] = None
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # A DeepSeek-V3-family model (kanana-2-30b-a3b: docs/mla-moe.md), each as
+    # the model's own configuration states it. mla: latent attention of these
+    # sizes in every attention layer. rope_theta, rope_interleave: the rotary
+    # base, and pairs (2i, 2i + 1) in place of (i, i + half). first_k_dense:
+    # the first k layers take the dense MLP (mlp_hidden, or the GELU one) and
+    # every later one the experts; it is DeepSeek's pattern, counted from the
+    # first layer, where moe_every counts from a period's last: set together
+    # they would state two patterns, so first_k_dense > 0 wants moe_every = 1
+    # (every layer after the dense ones) and raises otherwise. moe_router
+    # "sigmoid": scores by sigmoid, the choice by score + bias, the chosen
+    # scores renormalised and times moe_route_scale (models/moe.py; the bias
+    # is the collection ``moe_bias``, moved by the caller's step).
+    # moe_shared_hidden: a shared SwiGLU expert of that width beside the
+    # routed ones. moe_held = (first, count): this rank holds those of the
+    # moe_experts the router chooses among (one expert-parallel rank's share).
+    mla: Optional[LatentDims] = None
+    rope_theta: float = 10000.0
+    rope_interleave: bool = False
+    first_k_dense: int = 0
+    moe_router: str = "softmax"
+    moe_route_scale: float = 1.0
+    moe_shared_hidden: int = 0
+    moe_held: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden: bool = False):
@@ -297,6 +418,11 @@ class TransformerLM(nn.Module):
                              f"'mamba' for each of the {self.layers} layers")
         if "mamba" in kinds and self.mamba is None:
             raise ValueError("a 'mamba' layer needs the mixer's sizes (mamba=)")
+        if self.first_k_dense and (self.moe_experts <= 0 or self.moe_every != 1):
+            raise ValueError(
+                f"first_k_dense={self.first_k_dense} states dense layers "
+                f"before experts in EVERY later layer: it needs moe_experts > 0 "
+                f"and moe_every=1, not {self.moe_experts} and {self.moe_every}")
         embed = nn.Embed(self.vocab, self.dim, dtype=self.dtype, name="embed")
         x = embed(tokens)
         if self.embedding_multiplier != 1.0:
@@ -316,6 +442,7 @@ class TransformerLM(nn.Module):
                 flash_interpret=self.flash_interpret,
                 moe_experts=(self.moe_experts
                              if self.moe_experts > 0 and i % self.moe_every == self.moe_every - 1
+                             and i >= self.first_k_dense
                              else 0),
                 moe_top_k=self.moe_top_k,
                 moe_hidden=self.moe_hidden,
@@ -326,6 +453,13 @@ class TransformerLM(nn.Module):
                 rope=self.rope,
                 attention_scale=self.attention_multiplier,
                 residual_scale=self.residual_multiplier,
+                mla=self.mla,
+                rope_theta=self.rope_theta,
+                rope_interleave=self.rope_interleave,
+                moe_router=self.moe_router,
+                moe_route_scale=self.moe_route_scale,
+                moe_shared_hidden=self.moe_shared_hidden,
+                moe_held=self.moe_held,
                 name=f"block_{i}",
             )(x, positions)
         x = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)

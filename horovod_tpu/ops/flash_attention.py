@@ -120,7 +120,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                 block_q, block_k, nk, causal, sm_scale):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    d = q_ref.shape[-1]
+    d = v_ref.shape[-1]     # the accumulator's width: v's, not q's
     ratio = block_q // block_k
     # The loaded tile is walked in (sub_q, sub_k) score sub-tiles: row groups
     # are independent (the scheduler overlaps one's softmax with the next
@@ -347,6 +347,8 @@ def _gqa_group(q, k, v):
         raise ValueError(f"k has {hkv} heads but v has {v.shape[2]}")
     if h % hkv:
         raise ValueError(f"q heads {h} not divisible by kv heads {hkv}")
+    if q.shape[3] != k.shape[3]:
+        raise ValueError(f"q has head size {q.shape[3]} but k has {k.shape[3]}")
     return h, hkv, h // hkv
 
 
@@ -373,7 +375,10 @@ def flash_attention(q, k, v, causal: bool = True,
     or ``(B, T, Hkv, D)`` with ``H % Hkv == 0`` for grouped-query attention
     (each kv head serves a contiguous group of q heads — no head
     replication ever materializes; the kernels alias the shared kv block
-    via the grid index map). Sequence length must be a multiple of
+    via the grid index map). v's head size may differ from q's and k's
+    (latent attention: 192 | 128): the output, dO, dV and their accumulators
+    follow v, dq and dk follow q; the default scale is q's ``D ** -0.5``.
+    Sequence length must be a multiple of
     ``block_q`` and ``block_q`` of ``block_k`` (both clamp down to the
     sequence length for short inputs; the defaults measured fastest on v5e
     at d=64 — bigger blocks amortize scratch round-trips and feed the MXU
@@ -404,40 +409,43 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, sm_scale):
 def _fwd_call(q, k, v, causal, block_q, block_k, interpret, sm_scale):
     b, t, h, d = q.shape
     h, hkv, group = _gqa_group(q, k, v)
+    dv = v.shape[3]
     block_q, block_k = _check_blocks(t, block_q, block_k, interpret)
     qr = _rows(q, b, t, h, d)
-    kr, vr = (_rows(x, b, t, hkv, d) for x in (k, v))
+    kr, vr = _rows(k, b, t, hkv, d), _rows(v, b, t, hkv, dv)
     nk = t // block_k
     kernel = functools.partial(
         _fwd_kernel, block_q=block_q, block_k=block_k, nk=nk, causal=causal,
         sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
-    kv_spec = pl.BlockSpec(
-        (1, block_k, d), lambda r, qi, ki: (_kv_row(r, h, hkv, group), ki, 0))
+    def kv_spec(width):
+        return pl.BlockSpec((1, block_k, width), lambda r, qi, ki: (
+            _kv_row(r, h, hkv, group), ki, 0))
+
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, t // block_q, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda r, qi, ki: (r, qi, 0)),
-            kv_spec,
-            kv_spec,
+            kv_spec(d),
+            kv_spec(dv),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda r, qi, ki: (r, qi, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda r, qi, ki: (r, qi, 0)),
             pl.BlockSpec((1, 8, block_q), lambda r, qi, ki: (r, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, t, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, t), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, block_q, d), jnp.float32),        # acc
+            pltpu.VMEM((1, block_q, dv), jnp.float32),       # acc
             pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # m
             pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # l
         ],
         interpret=interpret,
         name=FLASH_FWD,
     )(qr, kr, vr)
-    return _unrows(out, b, t, h, d), (q, k, v, out, lse)
+    return _unrows(out, b, t, h, dv), (q, k, v, out, lse)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
@@ -445,9 +453,10 @@ def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, res, dout):
     q, k, v, out, lse = res
     b, t, h, d = q.shape
     h, hkv, group = _gqa_group(q, k, v)
+    dv = v.shape[3]
     block_q, block_k = _check_blocks(t, block_q, block_k, interpret)
-    qr, dor = (_rows(x, b, t, h, d) for x in (q, dout))
-    kr, vr = (_rows(x, b, t, hkv, d) for x in (k, v))
+    qr, dor = _rows(q, b, t, h, d), _rows(dout, b, t, h, dv)
+    kr, vr = _rows(k, b, t, hkv, d), _rows(v, b, t, hkv, dv)
     outr = out  # saved in rows layout by _fwd
     # D_i = rowsum(dO ∘ O): cheap elementwise reduction, done outside;
     # broadcast to the same (rows, 8, t) sublane layout as lse
@@ -457,17 +466,19 @@ def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, res, dout):
     nq, nk = t // block_q, t // block_k
     common = dict(block_q=block_q, block_k=block_k, causal=causal,
                   sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
-    kv_spec = pl.BlockSpec(
-        (1, block_k, d), lambda r, qi, ki: (_kv_row(r, h, hkv, group), ki, 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, block_k, width), lambda r, qi, ki: (
+            _kv_row(r, h, hkv, group), ki, 0))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, nk=nk, **common),
         grid=(b * h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda r, qi, ki: (r, qi, 0)),
-            kv_spec,
-            kv_spec,
-            pl.BlockSpec((1, block_q, d), lambda r, qi, ki: (r, qi, 0)),
+            kv_spec(d),
+            kv_spec(dv),
+            pl.BlockSpec((1, block_q, dv), lambda r, qi, ki: (r, qi, 0)),
             pl.BlockSpec((1, 8, block_q), lambda r, qi, ki: (r, 0, qi)),
             pl.BlockSpec((1, 8, block_q), lambda r, qi, ki: (r, 0, qi)),
         ],
@@ -484,28 +495,33 @@ def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, res, dout):
     def q_row(r, j):
         return _q_row(r, j, nq, h, hkv, group)
 
-    qd = pl.BlockSpec((1, block_q, d), lambda r, ki, j: (q_row(r, j), j % nq, 0))
+    def qd(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda r, ki, j: (q_row(r, j), j % nq, 0))
+
+    def kd(width):
+        return pl.BlockSpec((1, block_k, width), lambda r, ki, j: (r, ki, 0))
+
     row = pl.BlockSpec((1, 8, block_q), lambda r, ki, j: (q_row(r, j), 0, j % nq))
-    kd = pl.BlockSpec((1, block_k, d), lambda r, ki, j: (r, ki, 0))
-    dk, dv = pl.pallas_call(
+    dk, dv_rows = pl.pallas_call(
         functools.partial(_dkv_kernel, nq=nq, group=group, **common),
         grid=(b * hkv, nk, nq * group),
-        in_specs=[qd, kd, kd, qd, row, row],
-        out_specs=[kd, kd],
+        in_specs=[qd(d), kd(d), kd(dv), qd(dv), row, row],
+        out_specs=[kd(d), kd(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((b * hkv, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b * hkv, t, d), v.dtype),
+            jax.ShapeDtypeStruct((b * hkv, t, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, block_k, d), jnp.float32),   # dk acc
-            pltpu.VMEM((1, block_k, d), jnp.float32),   # dv acc
+            pltpu.VMEM((1, block_k, dv), jnp.float32),  # dv acc
         ],
         interpret=interpret,
         name=FLASH_BWD_DKV,
     )(qr, kr, vr, dor, lse, delta)
 
     return (_unrows(dq, b, t, h, d), _unrows(dk, b, t, hkv, d),
-            _unrows(dv, b, t, hkv, d))
+            _unrows(dv_rows, b, t, hkv, dv))
 
 
 flash_attention.defvjp(_fwd, _bwd_rule)

@@ -1,10 +1,14 @@
-"""Mixture of experts, dropless: OLMoE's routing and expert products.
+"""Mixture of experts, dropless: two routing rules and the expert products.
 
 Beyond the reference's scope (Horovod v0.16 is data-parallel only, SURVEY.md
-§2.8). Softmax, then the ``top_k`` largest probabilities as weights, NOT
-renormalised, and no capacity (OLMoE, arXiv:2409.02060) - every chosen
-(token, expert) pair is computed, under any imbalance, at static shapes
-(:func:`topk_route`, :func:`dropless_experts`):
+§2.8). Two routers: softmax, then the ``top_k`` largest probabilities as
+weights, NOT renormalised (OLMoE, arXiv:2409.02060; :func:`topk_route`), and
+sigmoid scores chosen by score + bias, the chosen scores renormalised and
+scaled, the bias moved after each step by the sign of the experts' load and
+never by a gradient (DeepSeek-V3, arXiv:2412.19437 §2.1.2;
+:func:`sigmoid_route`, :func:`router_bias_update`). No capacity under either:
+every chosen (token, expert) pair whose expert this rank holds is computed,
+under any imbalance, at static shapes (:func:`dropless_experts`):
 
 1. the N x top_k pairs are sorted by expert (stable, so a token's rows keep
    their order inside an expert's group) and the tokens gathered into that
@@ -23,7 +27,17 @@ renormalised, and no capacity (OLMoE, arXiv:2409.02060) - every chosen
 Both permutations are gathers in the forward AND the backward pass
 (:func:`_take_rows`): the transpose of a gather is a scatter-add, and the
 inverse permutation is at hand, so the backward gathers through it instead.
-All experts live on the rank that holds the tokens (data-parallel replicas).
+
+A rank holds either every expert (data-parallel replicas: ``held`` None) or
+the experts ``[first, first + count)`` of the ``E`` the router chooses among
+(one expert-parallel rank's share: ``held = (first, count)``, the weights
+``(count, ...)``). It then routes over all ``E``, sorts the pairs of absent
+experts behind the held ones, multiplies the held pairs' tiles only (the
+group sizes sum to less than the row buffer; the plan's steps end with the
+last held pair) and adds nothing for the absent ones. What the absent ranks
+would add is not computed, and nothing here stands in for their exchange. The
+two gathers still move the worst-case ``N x top_k`` buffer
+(``horovod_moe_dispatch_rows``).
 """
 
 from __future__ import annotations
@@ -55,24 +69,59 @@ def topk_route(logits, top_k: int):
     return probs, weights, experts
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_rows(x, take, back, fan: int):
+def sigmoid_route(logits, bias, top_k: int, scale: float):
+    """DeepSeek-V3's router with one group: scores ``sigmoid(logits)`` in
+    float32, the ``top_k`` experts by ``score + bias``, their weights the
+    scores themselves (the bias chooses and never weighs), renormalised over
+    the ``top_k`` and multiplied by ``scale``.
+
+    Returns (scores (N, E), weights (N, top_k), experts (N, top_k)). ``bias``
+    (E,) receives no gradient: it is state, moved by
+    :func:`router_bias_update`."""
+    with jax.named_scope(device_names.MOE_ROUTE):
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        _, experts = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+        # the weights through a one-hot product, as in topk_route
+        onehot = experts[:, :, None] == jnp.arange(scores.shape[-1])
+        weights = jnp.sum(jnp.where(onehot, scores[:, None, :], 0.0), axis=-1)
+        weights = scale * weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                                     + 1e-20)
+    return scores, weights, experts
+
+
+def router_bias_update(bias, counts, rate: float):
+    """The auxiliary-loss-free balancing rule: ``bias + rate * sign(mean(c) -
+    c)`` with ``c`` (E,) the pairs routed to each expert in the step, summed
+    over ranks by the caller so that replicas keep one bias."""
+    counts = counts.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(counts) - counts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _take_rows(x, take, back, live, fan: int):
     """``x[take]``, whose backward is a gather too: row i of ``x`` is taken
     by exactly ``fan`` rows of the output, the rows ``back[i*fan:(i+1)*fan]``
-    (``back`` is the inverse of the permutation ``take`` is made from)."""
+    (``back`` is the inverse of the permutation ``take`` is made from).
+    ``live`` None: every row of the output is computed on. ``live`` a count:
+    only the first ``live`` rows are; the gradient of the rows behind them
+    was never written (the grouped products skip their tiles) and counts as
+    zero."""
     return x[take]
 
 
-def _take_rows_fwd(x, take, back, fan):
-    return x[take], back
+def _take_rows_fwd(x, take, back, live, fan):
+    return x[take], (back, live)
 
 
-def _take_rows_bwd(fan, back, g):
+def _take_rows_bwd(fan, res, g):
+    back, live = res
     dx = g[back]
+    if live is not None:
+        dx = jnp.where((back < live)[:, None], dx, jnp.zeros((), g.dtype))
     if fan > 1:
         dx = dx.reshape(-1, fan, g.shape[-1]).astype(jnp.float32).sum(
             axis=1).astype(g.dtype)
-    return dx, None, None
+    return dx, None, None, None
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
@@ -86,26 +135,41 @@ def _expert_counts(flat_experts, n_experts: int):
 
 
 def dropless_experts(x, weights, experts, w_gate, w_up, w_down,
-                     interpret: bool = False):
+                     interpret: bool = False, held=None):
     """Every chosen (token, expert) pair through its SwiGLU expert, summed
     with its weight: ``sum_j weights[n, j] * down_e(silu(gate_e x_n) * up_e x_n)``
-    with ``e = experts[n, j]``.
+    with ``e = experts[n, j]``, over the ``j`` whose expert this rank holds.
 
     x: (N, D); weights, experts: (N, top_k); w_gate, w_up: (E, D, H);
-    w_down: (E, H, D), all in x's dtype. Returns (N, D) in x's dtype. The
-    work is N x top_k rows whatever the routing. ``interpret`` runs the
-    grouped-product kernels, where the shapes take them, in the Pallas
-    interpreter (asked for by the CPU tests, never inferred)."""
-    from ..metrics import record_moe_grouped_plan
+    w_down: (E, H, D), all in x's dtype. Returns (N, D) in x's dtype.
+    ``held`` None: the weights are every expert's, and the work is N x top_k
+    rows whatever the routing. ``held = (first, count)``: ``experts`` index
+    all the router's experts, the weights are those of ``[first, first +
+    count)`` alone, the products visit the tiles of their pairs and the other
+    pairs add nothing; no held pair is dropped under any routing (the row
+    buffer is N x top_k). ``interpret`` runs the grouped-product kernels,
+    where the shapes take them, in the Pallas interpreter (asked for by the
+    CPU tests, never inferred)."""
+    from ..metrics import record_moe_dispatch_rows, record_moe_grouped_plan
 
     n, d = x.shape
     top_k, n_experts = experts.shape[1], w_gate.shape[0]
+    record_moe_dispatch_rows(n * top_k)
     with jax.named_scope(device_names.MOE_DISPATCH):
         flat = experts.reshape(-1)
+        if held is not None:
+            first, count = held
+            if count != n_experts:
+                raise ValueError(f"held {held} but {n_experts} experts' weights")
+            # an absent expert's pairs sort behind every held one's
+            flat = jnp.where((flat >= first) & (flat < first + count),
+                             flat - first, count)
         order = jnp.argsort(flat, stable=True)          # sorted row -> pair
         inverse = jnp.argsort(order)                    # pair -> sorted row
         group_sizes = _expert_counts(flat, n_experts)
-        rows = _take_rows(x, order // top_k, inverse, top_k)
+        # sorted rows that hold held pairs: all of them where all are held
+        live = None if held is None else jnp.sum(group_sizes)
+        rows = _take_rows(x, order // top_k, inverse, live, top_k)
     with jax.named_scope(device_names.MOE_EXPERTS):
         if gm.takes_kernel(rows, w_gate):
             plan = gm.grouped_plan(group_sizes, n * top_k,
@@ -123,7 +187,10 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down,
         gate, up = product(rows, w_gate), product(rows, w_up)
         out = product(jax.nn.silu(gate) * up, w_down)
     with jax.named_scope(device_names.MOE_COMBINE):
-        pairs = _take_rows(out, inverse, order, 1).reshape(n, top_k, d)
+        pairs = _take_rows(out, inverse, order, None, 1).reshape(n, top_k, d)
+        if held is not None:    # rows behind the live ones were never written
+            pairs = jnp.where((inverse < live).reshape(n, top_k, 1), pairs,
+                              jnp.zeros((), pairs.dtype))
         return jnp.sum(pairs.astype(jnp.float32) * weights[:, :, None],
                        axis=1).astype(x.dtype)
 

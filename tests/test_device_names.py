@@ -242,3 +242,64 @@ def test_the_scans_loop_is_named_once(fixture, request):
     inside = [n for n in found if "/while/body/" in n and "hvd_ssd" in n]
     assert inside
     assert not any("hvd_ssd" in "/".join(n.split("/")[-3:]) for n in inside)
+
+
+# ------------------------------- latent attention's and the shared expert's
+
+MLA_SCOPES = [names.MLA_PROJ, names.MLA_ROPE, names.MOE_SHARED]
+
+
+@pytest.fixture(scope="module")
+def mla_op_names():
+    """The ``op_name``s of a tiny latent-attention mixture of experts'
+    gradients (a dense layer, then an expert layer holding a share of the
+    experts; recomputation on, as the cell runs), and the text without them."""
+    import re
+
+    from horovod_tpu.models import BIAS_COLLECTION, LatentDims, TransformerLM
+
+    model = TransformerLM(
+        vocab=64, dim=32, heads=4, layers=2, dtype=jnp.float32, remat=True,
+        mla=LatentDims(kv_rank=16, qk_nope=8, qk_rope=4, v=8), rope_theta=1e6,
+        rope_interleave=True, first_k_dense=1, mlp_hidden=48, moe_experts=8,
+        moe_every=1, moe_top_k=2, moe_hidden=16, moe_router="sigmoid",
+        moe_route_scale=2.448, moe_shared_hidden=24, moe_held=(2, 2))
+    tokens = jnp.zeros((1, 32), jnp.int32)
+    variables = model.init(jax.random.PRNGKey(0), tokens)
+    lowered = jax.jit(jax.grad(lambda p: model.apply(
+        {"params": p, BIAS_COLLECTION: variables[BIAS_COLLECTION]},
+        tokens).sum())).lower(variables["params"])
+    found = set(re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)))
+    return found, lowered.as_text(debug_info=False)
+
+
+def test_the_new_names_are_what_the_benchmark_looks_for():
+    assert (names.MLA_PROJ, names.MLA_ROPE, names.MOE_SHARED) == (
+        "hvd_mla_proj", "hvd_mla_rope", "hvd_moe_shared")
+    # the flash kernels keep their names: the cell's readers find them by
+    # ``hvd_flash_`` and the mixer by ``hvd_mla``
+    assert (names.FLASH_FWD, names.FLASH_BWD_DQ, names.FLASH_BWD_DKV) == (
+        "hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")
+    assert names.MOE_EXPERTS not in names.MOE_SHARED     # not the experts' time
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+@pytest.mark.parametrize("name", MLA_SCOPES)
+def test_mla_scope_survives_the_breakdowns_label(name, backward, mla_op_names):
+    """In the module as metadata only, on forward and backward operations,
+    and still there in what ``benchmarks/reduce_trace.op_label`` keeps of an
+    ``op_name``: its last three segments."""
+    found, bare = mla_op_names
+    assert name not in bare
+    kept = {"/".join(n.split("/")[-3:]) for n in found
+            if ("transpose(jvp(" in n) is backward}
+    assert any(name in label for label in kept), sorted(kept)[:20]
+
+
+def test_the_four_projections_and_the_latents_norm_are_under_mla_proj(
+        mla_op_names):
+    found, _ = mla_op_names
+    for leaf in ("q_proj", "kv_a_proj", "kv_a_norm", "kv_b_proj", "o_proj"):
+        assert any(f"{names.MLA_PROJ}/{leaf}" in n for n in found), leaf
+    for leaf in ("shared_gate", "shared_up", "shared_down"):
+        assert any(f"{names.MOE_SHARED}/{leaf}" in n for n in found), leaf

@@ -1,5 +1,7 @@
-"""The three flash kernels (multi-head at D = 128, and grouped-query 32 over 8
-at D = 64 with a softmax scale of its own), the two grouped-product kernels,
+"""The three flash kernels (multi-head at D = 128, grouped-query 32 over 8
+at D = 64 with a softmax scale of its own, and latent attention's 192-wide q
+and k against a 128-wide v), the two grouped-product kernels (all of 64
+experts, and a share of 16 whose groups do not fill the row buffer),
 the chunked state-space scan and the Mamba-2 mixer's four fused kernels
 (convolution + silu, gated norm) COMPILED for a
 described v5e at the benchmark cells' shapes (no chip attached, nothing runs): what interpret mode cannot
@@ -212,3 +214,70 @@ def test_the_mixers_fused_kernels_compile_for_v5e(one_chip,
     text = jax.jit(value_and_grads).lower(*args).compile().as_text()
     for name in kernels:
         assert name in text, f"{name} is not in the compiled module"
+
+
+# kanana2_seq8192_1chip: latent attention, 32 heads whose q and k are 192
+# wide (1.5 x the MXU's 128 lanes: the first shape of the repo that is no power
+# of two) against v, the output, dO and dV at 128; 2 rows of 8,192 in the step.
+MLA = dict(b=2, t=8192, heads=32, d_qk=192, d_v=128)
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "forward_backward"])
+def test_latent_attention_kernels_at_192_128_compile_for_v5e(
+        one_chip, no_persistent_cache, backward):
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(flash(*a).astype(jnp.float32)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    def shape(width):
+        return jax.ShapeDtypeStruct((MLA["b"], MLA["t"], MLA["heads"], width),
+                                    jnp.bfloat16, sharding=one_chip)
+
+    q, v = shape(MLA["d_qk"]), shape(MLA["d_v"])
+    compiled = jax.jit(grads if backward else flash).lower(q, q, v).compile()
+    text = compiled.as_text()
+    for name in ((FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV) if backward
+                 else (FLASH_FWD,)):
+        assert name in text, f"{name} is not in the compiled module"
+    if not backward:        # the output follows v, not q
+        out, = jax.tree_util.tree_leaves(jax.eval_shape(flash, q, q, v))
+        assert out.shape == (MLA["b"], MLA["t"], MLA["heads"], MLA["d_v"])
+
+
+# kanana2_seq8192_1chip: 16 held experts of 2048 x 768 over a row buffer of
+# 16,384 tokens x top-6 = 98,304 rows of which an eighth are live (the group
+# sizes sum to LESS than the buffer); 2,048 tokens in the check's two legs.
+@pytest.mark.parametrize("rows,dtype,precision", [
+    (98304, jnp.bfloat16, None),
+    (12288, jnp.bfloat16, None),
+    (12288, jnp.float32, "highest"),
+], ids=["step_bf16", "check_bf16", "check_f32_highest"])
+def test_grouped_kernels_compile_for_v5e_at_a_share_of_the_experts(
+        one_chip, no_persistent_cache, rows, dtype, precision):
+    dim, width, experts = 2048, 768, 16
+
+    def swiglu_grads(x, w_gate, w_down, sizes):
+        plan = gm.grouped_plan(sizes, rows, gm.row_tile(x.dtype.itemsize))
+
+        def loss(x, w_gate, w_down):
+            h = gm.grouped_matmul(x, w_gate, plan)
+            return jnp.sum(gm.grouped_matmul(h, w_down, plan)
+                           .astype(jnp.float32))
+
+        return jax.grad(loss, argnums=(0, 1, 2))(x, w_gate, w_down)
+
+    def shape(*dims, of=dtype):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    args = (shape(rows, dim), shape(experts, dim, width),
+            shape(experts, width, dim), shape(experts, of=jnp.int32))
+    assert gm.takes_kernel(*args[:2])
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(swiglu_grads).lower(*args).compile().as_text()
+    for name in (MOE_EXPERTS_GMM, MOE_EXPERTS_TGMM):
+        assert name in text, f"{name} is not in the compiled module"
+    assert "ragged" not in text

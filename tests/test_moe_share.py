@@ -1,0 +1,266 @@
+"""The sigmoid router, the shared expert and the share of the experts a rank
+holds, on the CPU at tiny sizes against ``tests/references/kanana2.py``: the
+guide's share test (all shares' routed parts plus the shared expert counted
+once are the uncut layer), no held pair dropped under the worst routing, the
+bias rule, and that the optimizer never sees the bias."""
+
+from __future__ import annotations
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+from references import kanana2  # noqa: E402
+
+from horovod_tpu.models import BIAS_COLLECTION, MoEMLP, expert_counts  # noqa: E402
+from horovod_tpu.ops import moe as ops_moe  # noqa: E402
+
+E, TOP_K, D, W, WS, SCALE = 16, 3, 32, 16, 24, 2.448
+CFG = {"top_k": TOP_K, "route_scale": SCALE}
+
+
+def layer_of(held=None, **kw):
+    return MoEMLP(dim=D, hidden=W, n_experts=E, top_k=TOP_K, dtype=jnp.float32,
+                  router="sigmoid", route_scale=SCALE, shared_hidden=WS,
+                  held=held, **kw)
+
+
+def close(got, want, rel):
+    """max|got - want| within ``rel`` of max|want|."""
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    assert got.shape == want.shape
+    assert np.max(np.abs(got - want)) <= rel * max(np.max(np.abs(want)), 1e-30)
+
+
+def seeded(shape, seed, scale=0.5):
+    return scale * jax.random.normal(jax.random.PRNGKey(seed), shape)
+
+
+@pytest.fixture(scope="module")
+def whole():
+    """(x, params of the layer holding all E experts, bias)."""
+    x = seeded((2, 24, D), 0, 1.0)
+    params = layer_of().init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.tree_util.tree_map(lambda p: seeded(p.shape, p.size % 97), params)
+    return x, params, seeded((E,), 2, 0.2)
+
+
+def reference_layer(params, first=0, count=E):
+    return {"router": params["router"],
+            "w_gate": params["w_gate"][first:first + count],
+            "w_up": params["w_up"][first:first + count],
+            "w_down": params["w_down"][first:first + count],
+            "s_gate": params["shared_gate"]["kernel"],
+            "s_up": params["shared_up"]["kernel"],
+            "s_down": params["shared_down"]["kernel"]}
+
+
+def share_params(params, first, count):
+    return {**params, **{k: params[k][first:first + count]
+                         for k in ("w_gate", "w_up", "w_down")}}
+
+
+def test_router_against_the_reference(whole):
+    x, params, bias = whole
+    tokens = x.reshape(-1, D)
+    logits = jnp.dot(tokens, params["router"], precision="highest")
+    scores, weights, experts = ops_moe.sigmoid_route(logits, bias, TOP_K, SCALE)
+    with jax.default_matmul_precision("highest"):
+        want_w, chosen, want_s = kanana2.route(tokens, params["router"], bias, CFG)
+    np.testing.assert_allclose(scores, want_s, atol=1e-6)
+    got_chosen = jnp.any(experts[:, :, None] == jnp.arange(E), axis=1)
+    assert bool(jnp.all(got_chosen == chosen))
+    dense = jnp.zeros((tokens.shape[0], E)).at[
+        jnp.arange(tokens.shape[0])[:, None], experts].set(weights)
+    np.testing.assert_allclose(dense, want_w, atol=1e-6)
+    np.testing.assert_allclose(weights.sum(-1), SCALE, rtol=1e-6)   # renormalised
+    # the bias chooses and never weighs: without it, other experts somewhere
+    _, _, plain_experts = ops_moe.sigmoid_route(logits, jnp.zeros(E), TOP_K, SCALE)
+    assert bool(jnp.any(jnp.sort(plain_experts) != jnp.sort(experts)))
+
+
+@pytest.mark.parametrize("held", [None, (4, 4), (0, 8), (12, 4)])
+def test_layer_and_its_gradients_against_the_reference(whole, held):
+    x, params, bias = whole
+    first, count = held or (0, E)
+    mine = share_params(params, first, count)
+    layer = layer_of(held)
+    cfg = {**CFG, "held": (first, count)}
+
+    def got(p, x):
+        return layer.apply({"params": p, BIAS_COLLECTION:
+                            {"router_bias": bias}}, x)
+
+    def want(p, x):
+        with jax.default_matmul_precision("highest"):
+            y, _ = kanana2.experts(reference_layer(p, 0, count), bias,
+                                   x.reshape(-1, D), cfg)
+        return y.reshape(x.shape)
+
+    close(got(mine, x), want(mine, x), 2e-6)
+    g = seeded(x.shape, 9, 1.0)
+    got_grads = jax.grad(lambda p, x: jnp.sum(got(p, x) * g), argnums=(0, 1))(mine, x)
+    want_grads = jax.grad(lambda p, x: jnp.sum(want(p, x) * g), argnums=(0, 1))(mine, x)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got_grads)[0],
+                            jax.tree_util.tree_leaves(want_grads)):
+        assert float(jnp.max(jnp.abs(a - b))) <= 2e-5 * float(
+            jnp.max(jnp.abs(b))), path
+
+
+def test_the_shares_add_up_to_the_uncut_layer(whole):
+    """Four ranks of four experts each: their routed parts, plus the shared
+    expert counted ONCE, are what the uncut reference gives for the whole
+    layer; so are the gradients (a shared leaf's summed once, an expert's
+    leaf from the rank that holds it)."""
+    x, params, bias = whole
+    g = seeded(x.shape, 9, 1.0)
+    tokens = x.reshape(-1, D)
+
+    def shared_part(p, x):
+        s = reference_layer(p)
+        with jax.default_matmul_precision("highest"):
+            return kanana2.swiglu(x.reshape(-1, D), s["s_gate"], s["s_up"],
+                                  s["s_down"]).reshape(x.shape)
+
+    def share(p, x, first):
+        out = layer_of((first, 4)).apply(
+            {"params": share_params(p, first, 4),
+             BIAS_COLLECTION: {"router_bias": bias}}, x)
+        return out - shared_part(p, x)          # the routed part alone
+
+    def summed(p, x):
+        return sum(share(p, x, first) for first in range(0, E, 4)) \
+            + shared_part(p, x)
+
+    def uncut(p, x):
+        with jax.default_matmul_precision("highest"):
+            y, _ = kanana2.experts(reference_layer(p), bias, x.reshape(-1, D),
+                                   {**CFG, "held": (0, E)})
+        return y.reshape(x.shape)
+
+    close(summed(params, x), uncut(params, x), 3e-6)
+    got = jax.grad(lambda p, x: jnp.sum(summed(p, x) * g), argnums=(0, 1))(params, x)
+    want = jax.grad(lambda p, x: jnp.sum(uncut(p, x) * g), argnums=(0, 1))(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert float(jnp.max(jnp.abs(a - b))) <= 3e-5 * float(jnp.max(jnp.abs(b)))
+    assert tokens.shape[0] == 48
+
+
+@pytest.mark.parametrize("interpret_kernels", [False, True],
+                         ids=["ragged_dot", "kernels"])
+def test_no_held_pair_is_dropped_when_every_token_picks_held_experts(
+        interpret_kernels):
+    """The worst routing for a share: a bias that sends EVERY token to held
+    experts fills the whole N x top_k row buffer, and every pair is computed.
+    With the kernels (bf16, aligned, interpreter) the plan's group sizes sum
+    to the buffer; with a bias the other way they sum to 0 and the layer adds
+    the shared expert alone."""
+    if interpret_kernels:
+        d, w, n, dtype, tol = 128, 128, 512, jnp.bfloat16, 1e-2
+    else:
+        d, w, n, dtype, tol = D, W, 48, jnp.float32, 2e-5
+    layer = MoEMLP(dim=d, hidden=w, n_experts=E, top_k=TOP_K, dtype=dtype,
+                   router="sigmoid", route_scale=SCALE, shared_hidden=0,
+                   held=(4, 4), interpret=interpret_kernels)
+    x = seeded((1, n, d), 3, 1.0)
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.tree_util.tree_map(lambda p: seeded(p.shape, p.size % 89, 0.2),
+                                    params)
+    onto = jnp.where((jnp.arange(E) >= 4) & (jnp.arange(E) < 8), 10.0, 0.0)
+    for bias, all_held in ((onto, True), (-onto, False)):
+        out, state = layer.apply({"params": params, BIAS_COLLECTION:
+                                  {"router_bias": bias}}, x,
+                                 mutable=["intermediates"])
+        counts = state["intermediates"]["moe_expert_counts"][0]
+        assert int(counts.sum()) == n * TOP_K
+        assert int(counts[4:8].sum()) == (n * TOP_K if all_held else 0)
+        ref = {"router": params["router"], "w_gate": params["w_gate"],
+               "w_up": params["w_up"], "w_down": params["w_down"]}
+        tokens = x.reshape(-1, d).astype(dtype).astype(jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            weights, _, _ = kanana2.route(tokens, ref["router"], bias, CFG)
+            want = sum(kanana2.expert_term(
+                tokens, weights[:, 4 + i],
+                *(ref[k][i].astype(dtype).astype(jnp.float32)
+                  for k in ("w_gate", "w_up", "w_down"))) for i in range(4))
+        assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+        # bf16 rounds gate, up and their product: by the Euclidean norm
+        error = np.asarray(out.reshape(-1, d).astype(jnp.float32) - want)
+        assert np.linalg.norm(error) <= tol * max(np.linalg.norm(want), 1e-30)
+        if not all_held:
+            assert float(jnp.max(jnp.abs(out.astype(jnp.float32)))) == 0.0
+        # the gradient reaches x through held pairs only, and is finite
+        dx = jax.grad(lambda x: jnp.sum(layer.apply(
+            {"params": params, BIAS_COLLECTION: {"router_bias": bias}},
+            x).astype(jnp.float32)))(x)
+        assert bool(jnp.all(jnp.isfinite(dx.astype(jnp.float32))))
+
+
+def test_bias_rule_against_the_reference():
+    counts = jnp.array([0, 5, 9, 9, 9, 20, 11, 9], jnp.int32)   # mean 9
+    bias = seeded((8,), 4, 0.01)
+    got = ops_moe.router_bias_update(bias, counts, 0.001)
+    np.testing.assert_array_equal(got, kanana2.bias_update(bias, counts, 0.001))
+    np.testing.assert_allclose(
+        got - bias, 0.001 * np.array([1, 1, 0, 0, 0, -1, -1, 0]), atol=1e-9)
+
+
+def test_the_bias_is_state_that_adamw_never_touches(whole):
+    """The bias is a collection of its own: ``params`` has no such leaf, so
+    the optimizer's state has no moment for it and weight decay cannot reach
+    it; it receives no gradient; the rule alone moves it, by the sown counts."""
+    x, _, _ = whole
+    layer = layer_of((0, 4))
+    variables = layer.init(jax.random.PRNGKey(1), x)
+    assert set(variables) == {"params", BIAS_COLLECTION}
+    assert "router_bias" not in jax.tree_util.tree_flatten_with_path(
+        variables["params"])[1].__repr__()
+    bias = {"router_bias": seeded((E,), 2, 0.2)}
+    opt = optax.adamw(1e-2, weight_decay=0.1)
+    opt_state = opt.init(variables["params"])
+
+    def loss(params, bias):
+        out, state = layer.apply({"params": params, BIAS_COLLECTION: bias}, x,
+                                 mutable=["intermediates"])
+        return jnp.sum(out ** 2), state["intermediates"]["moe_expert_counts"][0]
+
+    (_, counts), (grads, bias_grad) = jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)(variables["params"], bias)
+    assert float(jnp.max(jnp.abs(bias_grad["router_bias"]))) == 0.0
+    updates, _ = opt.update(grads, opt_state, variables["params"])
+    new_params = optax.apply_updates(variables["params"], updates)
+    assert jax.tree_util.tree_structure(new_params) == \
+        jax.tree_util.tree_structure(variables["params"])
+    moved = ops_moe.router_bias_update(bias["router_bias"], counts, 0.001)
+    step = np.asarray(moved - bias["router_bias"])
+    assert set(np.round(step / 0.001).astype(int)) <= {-1, 0, 1}
+    assert int(counts.sum()) == x.shape[0] * x.shape[1] * TOP_K
+    # expert_counts finds the sown counts by block
+    inter = {"block_1": {"moe": {"moe_expert_counts": (counts,)}},
+             "block_0": {"RMSNorm_0": {}}}
+    assert list(expert_counts(inter)) == ["block_1"]
+
+
+def test_softmax_layer_keeps_its_parameters_and_sows():
+    """OLMoE's layer is what it was: no bias collection, the old sows."""
+    layer = MoEMLP(dim=D, hidden=W, n_experts=4, top_k=2, dtype=jnp.float32)
+    x = seeded((1, 8, D), 5)
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    assert set(variables) == {"params"}
+    assert sorted(variables["params"]) == ["router", "w_down", "w_gate", "w_up"]
+    _, state = layer.apply(variables, x, mutable=["intermediates"])
+    assert sorted(state["intermediates"]) == [
+        "moe_chosen_experts", "moe_lb_loss", "moe_router_logits", "moe_z_loss"]
+    with pytest.raises(ValueError, match="router"):
+        MoEMLP(dim=D, hidden=W, n_experts=4, top_k=2, router="tanh").init(
+            jax.random.PRNGKey(0), x)
+    with pytest.raises(ValueError, match="held"):
+        MoEMLP(dim=D, hidden=W, n_experts=4, top_k=2, held=(2, 4)).init(
+            jax.random.PRNGKey(0), x)

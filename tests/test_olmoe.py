@@ -309,7 +309,9 @@ def test_expert_load_gauge_is_set_by_the_helper_alone(hvd):
     gauges = hvd.metrics.registry().snapshot()["gauges"]
     assert gauges["horovod_moe_expert_load_max_over_mean"] == pytest.approx(2.0)
     assert gauges["horovod_moe_grouped_border_overhead"] == 0.0
+    assert gauges["horovod_moe_dispatch_rows"] == 96 * CFG["top_k"]   # N x top_k
     assert sorted(name for name in gauges if name.startswith("horovod_moe_")) == [
+        "horovod_moe_dispatch_rows",
         "horovod_moe_expert_load_max_over_mean",
         "horovod_moe_grouped_border_overhead"]
 
