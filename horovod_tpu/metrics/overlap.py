@@ -188,14 +188,19 @@ def record_moe_grouped_plan(border_overhead: float) -> None:
 
 
 def record_moe_dispatch_rows(rows: int) -> None:
-    """Record the rows of the sorted buffer the latest traced
-    ``ops.moe.dropless_experts`` gathers its tokens into and its results out
-    of (trace time, once per compile): ``N x top_k`` while the gathers move
-    the worst-case buffer, whatever share of the pairs falls on held experts."""
+    """Record the sorted rows the passes of the latest traced
+    ``ops.moe.dropless_experts`` visit (trace time, once per compile): ``N x
+    top_k`` where the rank holds every expert; where it holds ``count`` of
+    ``of``, its share ``N x top_k x count / of`` of them in whole windows
+    (``ops.moe.held_window_rows``): what a balanced router makes the passes
+    move. The rows a step really visits follow the routing (the layer sows
+    them as ``moe_live_rows``)."""
     registry().gauge(
         "horovod_moe_dispatch_rows",
-        help="rows of the sorted buffer the latest traced dropless_experts "
-             "gathers per layer; 0 = none traced"
+        help="sorted rows the passes of the latest traced dropless_experts "
+             "visit per layer under a balanced router: N x top_k with every "
+             "expert held, the held share in whole windows else; 0 = none "
+             "traced"
     ).set(rows)
 
 

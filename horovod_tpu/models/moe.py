@@ -27,7 +27,9 @@ not): ``moe_router_logits`` (N, E; for ``ops.moe.record_expert_load``) and
 ``moe_chosen_experts`` (N, top_k); under softmax ``moe_lb_loss`` and
 ``moe_z_loss``, which :func:`aux_losses` sums over the layers for the
 caller's loss function (OLMoE's coefficients: 0.01 and 0.001); under sigmoid
-``moe_expert_counts`` (E,), the pairs routed to each of ALL the experts.
+``moe_expert_counts`` (E,), the pairs routed to each of ALL the experts, and
+``moe_live_rows`` (scalar), those of them on the experts this rank holds: the
+rows the layer's passes visit in the step, for a training loop to log.
 """
 
 from __future__ import annotations
@@ -107,8 +109,10 @@ class MoEMLP(nn.Module):
                                  (e,), jnp.float32).value
             _, weights, experts = sigmoid_route(logits, bias, self.top_k,
                                                 self.route_scale)
-            self.sow("intermediates", "moe_expert_counts",
-                     _expert_counts(experts.reshape(-1), e))
+            counts = _expert_counts(experts.reshape(-1), e)
+            self.sow("intermediates", "moe_expert_counts", counts)
+            self.sow("intermediates", "moe_live_rows",
+                     jnp.sum(counts[first:first + here]))
         else:
             probs, weights, experts = topk_route(logits, self.top_k)
             self.sow("intermediates", "moe_lb_loss",
@@ -117,7 +121,8 @@ class MoEMLP(nn.Module):
         self.sow("intermediates", "moe_router_logits", logits)
         self.sow("intermediates", "moe_chosen_experts", experts)
         return dropless_experts(tokens.astype(self.dtype), weights, experts,
-                                w_gate, w_up, w_down, self.interpret, self.held)
+                                w_gate, w_up, w_down, self.interpret,
+                                None if self.held is None else (first, here, e))
 
 
 def aux_losses(intermediates):

@@ -29,15 +29,24 @@ Both permutations are gathers in the forward AND the backward pass
 inverse permutation is at hand, so the backward gathers through it instead.
 
 A rank holds either every expert (data-parallel replicas: ``held`` None) or
-the experts ``[first, first + count)`` of the ``E`` the router chooses among
-(one expert-parallel rank's share: ``held = (first, count)``, the weights
-``(count, ...)``). It then routes over all ``E``, sorts the pairs of absent
-experts behind the held ones, multiplies the held pairs' tiles only (the
-group sizes sum to less than the row buffer; the plan's steps end with the
-last held pair) and adds nothing for the absent ones. What the absent ranks
-would add is not computed, and nothing here stands in for their exchange. The
-two gathers still move the worst-case ``N x top_k`` buffer
-(``horovod_moe_dispatch_rows``).
+the experts ``[first, first + count)`` of the ``of`` the router chooses among
+(one expert-parallel rank's share: ``held = (first, count, of)``, the weights
+``(count, ...)``). It then routes over all of them and sorts the pairs of
+absent experts behind the held ones, so the rows that hold a held pair are
+the sorted order's first ``live``, a count known on the device alone. NO
+pass of that path moves the worst-case ``N x top_k`` rows
+(:func:`_held_experts`): the gathers, the activation, the weighted sum and
+their backward are loops over the windows of ``_WINDOW_ROWS`` rows that hold
+live rows, the grouped products visit the held pairs' tiles (the plan's steps
+end with the last held pair), and the row buffers between them are N x top_k
+rows of ADDRESSES whose live prefix alone is written and read. Back in token
+order nothing is scattered: the live pairs, sorted again by token, each sum
+their token's pairs up to themselves, and a token takes the sum at its last
+(:func:`_sum_by_token`). Every window runs where every pair is held, none
+where none is: no capacity, no dropped pair. What the absent ranks would add
+is not computed, and nothing here stands in for their exchange.
+``horovod_moe_dispatch_rows`` reads the rows a layer's passes visit under a
+balanced router (:func:`held_window_rows`).
 """
 
 from __future__ import annotations
@@ -47,6 +56,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
 
 from ..common import device_names
 from . import grouped_matmul as gm
@@ -97,31 +107,24 @@ def router_bias_update(bias, counts, rate: float):
     return bias + rate * jnp.sign(jnp.mean(counts) - counts)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _take_rows(x, take, back, live, fan: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, take, back, fan: int):
     """``x[take]``, whose backward is a gather too: row i of ``x`` is taken
     by exactly ``fan`` rows of the output, the rows ``back[i*fan:(i+1)*fan]``
-    (``back`` is the inverse of the permutation ``take`` is made from).
-    ``live`` None: every row of the output is computed on. ``live`` a count:
-    only the first ``live`` rows are; the gradient of the rows behind them
-    was never written (the grouped products skip their tiles) and counts as
-    zero."""
+    (``back`` is the inverse of the permutation ``take`` is made from)."""
     return x[take]
 
 
-def _take_rows_fwd(x, take, back, live, fan):
-    return x[take], (back, live)
+def _take_rows_fwd(x, take, back, fan):
+    return x[take], back
 
 
-def _take_rows_bwd(fan, res, g):
-    back, live = res
+def _take_rows_bwd(fan, back, g):
     dx = g[back]
-    if live is not None:
-        dx = jnp.where((back < live)[:, None], dx, jnp.zeros((), g.dtype))
     if fan > 1:
         dx = dx.reshape(-1, fan, g.shape[-1]).astype(jnp.float32).sum(
             axis=1).astype(g.dtype)
-    return dx, None, None, None
+    return dx, None, None
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
@@ -134,6 +137,247 @@ def _expert_counts(flat_experts, n_experts: int):
                    axis=0, dtype=jnp.int32)
 
 
+def _grouped_product(group_sizes, rows: int, dtype, w, interpret: bool):
+    """``a, w -> a[rows of g] @ w[g]`` for ``a`` of ``rows`` rows in groups
+    of ``group_sizes``: the repo's kernels where the shapes take them, else
+    ``lax.ragged_dot``; which, goes to ``horovod_moe_grouped_border_overhead``."""
+    from ..metrics import record_moe_grouped_plan
+
+    like = jax.ShapeDtypeStruct((rows, w.shape[1]), dtype)
+    if not gm.takes_kernel(like, w):
+        record_moe_grouped_plan(0.0)
+        return lambda a, w: lax.ragged_dot(a, w, group_sizes)
+    plan = gm.grouped_plan(group_sizes, rows, gm.row_tile(like.dtype.itemsize))
+    record_moe_grouped_plan(gm.border_overhead(rows, w.shape[0]))
+    return lambda a, w: gm.grouped_matmul(a, w, plan, interpret)
+
+
+def _swiglu(gate, up):
+    return jax.nn.silu(gate) * up
+
+
+# -------------------------------------------------- one rank's share of them
+
+# Sorted rows a pass of the held path visits at a time: whole row tiles of
+# the grouped products at either itemsize, and 8 MiB of bf16 rows at 2,048
+# columns, large enough that a gather runs at the rate of a long one.
+_WINDOW_ROWS = 2048
+
+
+def _window(pairs: int) -> int:
+    """Rows of one window of a sorted order of ``pairs`` rows; an order that
+    is no whole number of windows (a shorter one, above all) is ONE window."""
+    return _WINDOW_ROWS if pairs % _WINDOW_ROWS == 0 else pairs
+
+
+def held_window_rows(pairs: int, count: int, n_experts: int) -> int:
+    """Sorted rows the passes of a layer that holds ``count`` of
+    ``n_experts`` experts visit when the router is balanced: its share of
+    the ``pairs`` pairs, in whole windows."""
+    window = _window(pairs)
+    return -(-pairs * count // (n_experts * window)) * window
+
+
+def _over_live_windows(live, pairs: int, body, carry):
+    """``carry = body(r, carry)`` for the first row ``r`` of every window of
+    the sorted order that holds a live row (a row ``< live``), in order: the
+    trip count is known on the device alone, the shapes are static."""
+    window = _window(pairs)
+    return lax.fori_loop(
+        0, lax.div(live + (window - 1), jnp.int32(window)),
+        lambda i, c: body(i * window, c), carry)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _as_it_comes(after, shape, dtype, interpret):
+    return pl.pallas_call(
+        lambda after, out: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY), interpret=interpret)(after)
+
+
+def _row_buffer(rows: int, width: int, w, after, interpret: bool):
+    """``(rows, width)`` in the dtype of the weights ``w``, whose rows a caller
+    writes before it reads them. Beside the kernels (``rows`` rows take them
+    through ``w``) it is memory as it comes (a ``pallas_call`` that writes
+    nothing, jitted so that a model's layers share one copy): zeroing 98,304
+    rows of which an eighth is ever used costs what the pass over the live
+    ones does. ``after`` is an array the buffer is not needed before: the
+    call takes it as an operand it never reads, or XLA, seeing a call that
+    depends on nothing, makes every layer's buffers at the program's start
+    and keeps them. Elsewhere zeros."""
+    if not gm.takes_kernel(jax.ShapeDtypeStruct((rows, w.shape[1]), w.dtype), w):
+        return jnp.zeros((rows, width), w.dtype)
+    return _as_it_comes(after, (rows, width), w.dtype, interpret)
+
+
+def _sorted_share(flat, top_k: int, n_experts: int):
+    """What the passes of the held path index by, from the pairs' experts
+    ``flat`` (P,), ``n_experts`` for an absent one. Absent experts' pairs
+    sort behind every held one's, so the ``live`` rows that hold a held pair
+    are the sorted order's first. ``order`` (P,): a sorted row's pair;
+    ``group_sizes``: rows per held expert. The live pairs AGAIN in pair
+    order, which is token order, a token's pairs one after the other:
+    ``rows`` (top_k - 1 + P,), the sorted row of the q-th such pair at
+    ``top_k - 1 + q`` (behind the live ones anything); ``last`` (N,) the q
+    of a token's last live pair and ``some`` (N,) whether it has one. Two
+    sorts of keys alone, as where every expert is held: a sort is what a
+    TPU program compiles longest, one that carries a payload longer still,
+    and a gather of P scalars takes a millisecond, so what a pass needs of a
+    pair (its token, its weight) it gathers for its window's pairs."""
+    pairs = flat.shape[0]
+    at = jnp.arange(pairs, dtype=jnp.int32)
+    order = jnp.argsort(flat, stable=True)
+    group_sizes = _expert_counts(flat, n_experts)
+    live = jnp.sum(group_sizes)
+    rows = jnp.argsort(jnp.where(at < live, order, pairs + at))
+    mine = jnp.sum((flat < n_experts).reshape(-1, top_k), axis=1,
+                   dtype=jnp.int32)
+    return {"order": order, "live": live, "group_sizes": group_sizes,
+            "rows": jnp.concatenate([jnp.zeros(top_k - 1, jnp.int32), rows]),
+            "last": jnp.cumsum(mine) - 1, "some": mine > 0}
+
+
+def _sum_by_token(share, value_at, top_k: int, sums):
+    """``y[n] = sum of value_at(sorted rows, their pairs)`` over token n's
+    live pairs, in float32 and rounded once to the dtype of ``sums``, the (P,
+    width) buffer the sums are written to; (N, width). No row is scattered:
+    a window of the live pairs in token order gathers its rows, every pair
+    sums its token's pairs up to itself (a token's are at most ``top_k``
+    neighbours), and a token then takes the sum at its last pair."""
+    front, pairs = top_k - 1, share["order"].shape[0]
+    window = _window(pairs)
+
+    def runs(q, sums):
+        rows = lax.dynamic_slice(share["rows"], (q,), (front + window,))
+        pair = share["order"][rows]
+        # the pairs in front of the first belong to no token
+        owner = jnp.where(q + jnp.arange(front + window) >= front,
+                          pair // top_k, -1)
+        values = value_at(rows, pair)
+        total = values[front:]
+        for back in range(1, front + 1):
+            # rows behind the live ones hold anything: selected, never added
+            total = total + jnp.where(
+                (owner[front - back:-back] == owner[front:])[:, None],
+                values[front - back:-back], 0.0)
+        return lax.dynamic_update_slice(sums, total.astype(sums.dtype), (q, 0))
+
+    sums = _over_live_windows(share["live"], pairs, runs, sums)
+    return jnp.where(share["some"][:, None],
+                     sums[jnp.maximum(share["last"], 0)],
+                     jnp.zeros((), sums.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _held_experts(x, weights, flat, w_gate, w_up, w_down, interpret: bool):
+    """:func:`dropless_experts` for a rank that holds the experts of
+    ``w_gate`` alone; ``flat`` (N x top_k,) is each pair's expert counted
+    from the rank's first, ``w_gate.shape[0]`` for an absent one. Every pass
+    outside the grouped products (which visit the held pairs' tiles as they
+    are) is a loop over the windows that hold live rows
+    (:func:`_over_live_windows`): the row buffers keep their worst-case
+    N x top_k rows as ADDRESSES, and only the live prefix of each is ever
+    written or read."""
+    return _held_forward(x, weights, flat, w_gate, w_up, w_down, interpret)[0]
+
+
+def _held_forward(x, weights, flat, w_gate, w_up, w_down, interpret):
+    d, pairs, hidden = x.shape[1], flat.shape[0], w_gate.shape[2]
+    window, top_k = _window(pairs), weights.shape[1]
+    with jax.named_scope(device_names.MOE_DISPATCH):
+        share = _sorted_share(flat, top_k, w_gate.shape[0])
+        live = share["live"]
+
+        def gather(r, rows):
+            pair = lax.dynamic_slice(share["order"], (r,), (window,))
+            return lax.dynamic_update_slice(rows, x[pair // top_k], (r, 0))
+
+        rows = _over_live_windows(live, pairs, gather,
+                                  _row_buffer(pairs, d, w_gate, share["order"],
+                                              interpret))
+    with jax.named_scope(device_names.MOE_EXPERTS):
+        product = _grouped_product(share["group_sizes"], pairs, x.dtype,
+                                   w_gate, interpret)
+        gate, up = product(rows, w_gate), product(rows, w_up)
+
+        def activate(r, h):
+            return lax.dynamic_update_slice(
+                h, _swiglu(lax.dynamic_slice(gate, (r, 0), (window, hidden)),
+                           lax.dynamic_slice(up, (r, 0), (window, hidden))),
+                (r, 0))
+
+        h = _over_live_windows(live, pairs, activate,
+                               _row_buffer(pairs, hidden, w_gate, gate,
+                                           interpret))
+        out = product(h, w_down)
+    with jax.named_scope(device_names.MOE_COMBINE):
+        y = _sum_by_token(
+            share, lambda rows, pair: out[rows].astype(jnp.float32)
+            * weights.reshape(-1)[pair][:, None], top_k,
+            _row_buffer(pairs, d, w_gate, out, interpret))
+    return y, (share, weights, rows, gate, up, h, out, w_gate, w_up, w_down)
+
+
+def _held_backward(interpret, res, g):
+    share, weights, rows, gate, up, h, out, w_gate, w_up, w_down = res
+    d, pairs, hidden = g.shape[1], share["order"].shape[0], w_gate.shape[2]
+    window, top_k, live = _window(pairs), weights.shape[1], share["live"]
+    product = _grouped_product(share["group_sizes"], pairs, g.dtype, w_gate,
+                               interpret)
+
+    def grads(a, w, dy):
+        return jax.vjp(product, a, w)[1](dy)
+
+    with jax.named_scope(device_names.MOE_COMBINE):
+
+        def pull(r, carry):
+            dout, dweights = carry
+            pair = lax.dynamic_slice(share["order"], (r,), (window,))
+            dy = g[pair // top_k].astype(jnp.float32)
+            # rows behind the live ones were never written
+            dweight = jnp.where(
+                r + jnp.arange(window) < live,
+                jnp.sum(dy * lax.dynamic_slice(out, (r, 0), (window, d)),
+                        axis=1), 0.0)
+            weight = weights.reshape(-1)[pair]
+            # scalars to distinct places: the one scatter the chip runs at
+            # a gather's rate (12 us a window, where gathering all P back
+            # through the pairs' ranks takes 1.3 ms)
+            return (lax.dynamic_update_slice(
+                dout, (dy * weight[:, None]).astype(g.dtype), (r, 0)),
+                    dweights.at[pair].set(dweight, unique_indices=True,
+                                          mode="promise_in_bounds"))
+
+        dout, dweights = _over_live_windows(
+            live, pairs, pull,
+            (_row_buffer(pairs, d, w_gate, g, interpret),
+             jnp.zeros((pairs,), jnp.float32)))
+    with jax.named_scope(device_names.MOE_EXPERTS):
+        dh, dw_down = grads(h, w_down, dout)
+
+        def activate(r, carry):
+            # dgate's and dup's rows are still gate's and up's
+            at = (lax.dynamic_slice(a, (r, 0), (window, hidden)) for a in carry)
+            dgate, dup = jax.vjp(_swiglu, *at)[1](
+                lax.dynamic_slice(dh, (r, 0), (window, hidden)))
+            return (lax.dynamic_update_slice(carry[0], dgate, (r, 0)),
+                    lax.dynamic_update_slice(carry[1], dup, (r, 0)))
+
+        dgate, dup = _over_live_windows(live, pairs, activate, (gate, up))
+        (by_gate, dw_gate), (by_up, dw_up) = (grads(rows, w_gate, dgate),
+                                              grads(rows, w_up, dup))
+    with jax.named_scope(device_names.MOE_DISPATCH):
+        dx = _sum_by_token(
+            share, lambda rows, pair: by_gate[rows].astype(jnp.float32)
+            + by_up[rows], top_k,
+            _row_buffer(pairs, d, w_gate, by_up, interpret))
+    return (dx, dweights.reshape(weights.shape), None, dw_gate, dw_up, dw_down)
+
+
+_held_experts.defvjp(_held_forward, _held_backward)
+
+
 def dropless_experts(x, weights, experts, w_gate, w_up, w_down,
                      interpret: bool = False, held=None):
     """Every chosen (token, expert) pair through its SwiGLU expert, summed
@@ -143,54 +387,44 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down,
     x: (N, D); weights, experts: (N, top_k); w_gate, w_up: (E, D, H);
     w_down: (E, H, D), all in x's dtype. Returns (N, D) in x's dtype.
     ``held`` None: the weights are every expert's, and the work is N x top_k
-    rows whatever the routing. ``held = (first, count)``: ``experts`` index
-    all the router's experts, the weights are those of ``[first, first +
-    count)`` alone, the products visit the tiles of their pairs and the other
-    pairs add nothing; no held pair is dropped under any routing (the row
-    buffer is N x top_k). ``interpret`` runs the grouped-product kernels,
-    where the shapes take them, in the Pallas interpreter (asked for by the
-    CPU tests, never inferred)."""
-    from ..metrics import record_moe_dispatch_rows, record_moe_grouped_plan
+    rows whatever the routing. ``held = (first, count, of)``: ``experts``
+    index the ``of`` experts the router chooses among, the weights are those
+    of ``[first, first + count)`` alone, and every pass (the gathers, the
+    products, the weighted sum, and their backward) visits the held pairs'
+    rows in whole windows (:func:`held_window_rows` under a balanced router)
+    and adds nothing for the other pairs. No held pair is dropped under any
+    routing: where every pair is held, every window runs. ``interpret`` runs
+    the grouped-product kernels, where the shapes take them, in the Pallas
+    interpreter (asked for by the CPU tests, never inferred)."""
+    from ..metrics import record_moe_dispatch_rows
 
     n, d = x.shape
     top_k, n_experts = experts.shape[1], w_gate.shape[0]
-    record_moe_dispatch_rows(n * top_k)
-    with jax.named_scope(device_names.MOE_DISPATCH):
-        flat = experts.reshape(-1)
-        if held is not None:
-            first, count = held
-            if count != n_experts:
-                raise ValueError(f"held {held} but {n_experts} experts' weights")
+    if held is not None:
+        first, count, of = held
+        if count != n_experts:
+            raise ValueError(f"held {held} but {n_experts} experts' weights")
+        record_moe_dispatch_rows(held_window_rows(n * top_k, count, of))
+        with jax.named_scope(device_names.MOE_DISPATCH):
+            flat = experts.reshape(-1)
             # an absent expert's pairs sort behind every held one's
             flat = jnp.where((flat >= first) & (flat < first + count),
                              flat - first, count)
+        return _held_experts(x, weights, flat, w_gate, w_up, w_down, interpret)
+    record_moe_dispatch_rows(n * top_k)
+    with jax.named_scope(device_names.MOE_DISPATCH):
+        flat = experts.reshape(-1)
         order = jnp.argsort(flat, stable=True)          # sorted row -> pair
         inverse = jnp.argsort(order)                    # pair -> sorted row
         group_sizes = _expert_counts(flat, n_experts)
-        # sorted rows that hold held pairs: all of them where all are held
-        live = None if held is None else jnp.sum(group_sizes)
-        rows = _take_rows(x, order // top_k, inverse, live, top_k)
+        rows = _take_rows(x, order // top_k, inverse, top_k)
     with jax.named_scope(device_names.MOE_EXPERTS):
-        if gm.takes_kernel(rows, w_gate):
-            plan = gm.grouped_plan(group_sizes, n * top_k,
-                                   gm.row_tile(rows.dtype.itemsize))
-            record_moe_grouped_plan(gm.border_overhead(n * top_k, n_experts))
-
-            def product(a, w):
-                return gm.grouped_matmul(a, w, plan, interpret)
-        else:
-            record_moe_grouped_plan(0.0)
-
-            def product(a, w):
-                return lax.ragged_dot(a, w, group_sizes)
-
+        product = _grouped_product(group_sizes, n * top_k, rows.dtype, w_gate,
+                                   interpret)
         gate, up = product(rows, w_gate), product(rows, w_up)
         out = product(jax.nn.silu(gate) * up, w_down)
     with jax.named_scope(device_names.MOE_COMBINE):
-        pairs = _take_rows(out, inverse, order, None, 1).reshape(n, top_k, d)
-        if held is not None:    # rows behind the live ones were never written
-            pairs = jnp.where((inverse < live).reshape(n, top_k, 1), pairs,
-                              jnp.zeros((), pairs.dtype))
+        pairs = _take_rows(out, inverse, order, 1).reshape(n, top_k, d)
         return jnp.sum(pairs.astype(jnp.float32) * weights[:, :, None],
                        axis=1).astype(x.dtype)
 

@@ -1,7 +1,8 @@
 """The three flash kernels (multi-head at D = 128, grouped-query 32 over 8
 at D = 64 with a softmax scale of its own, and latent attention's 192-wide q
 and k against a 128-wide v), the two grouped-product kernels (all of 64
-experts, and a share of 16 whose groups do not fill the row buffer),
+experts, and a share of 16 whose groups do not fill the row buffer), the
+expert layer of such a share whole (its loops over the live windows),
 the chunked state-space scan and the Mamba-2 mixer's four fused kernels
 (convolution + silu, gated norm) COMPILED for a
 described v5e at the benchmark cells' shapes (no chip attached, nothing runs): what interpret mode cannot
@@ -281,3 +282,38 @@ def test_grouped_kernels_compile_for_v5e_at_a_share_of_the_experts(
     for name in (MOE_EXPERTS_GMM, MOE_EXPERTS_TGMM):
         assert name in text, f"{name} is not in the compiled module"
     assert "ragged" not in text
+
+
+def test_the_held_layer_compiles_for_v5e_at_the_cells_shape(
+        one_chip, no_persistent_cache):
+    """``dropless_experts`` for one expert-parallel rank's share, forward and
+    backward at ``kanana2_seq8192_1chip``'s shapes: the loops over the live
+    windows, the buffers that are memory as it comes (a ``pallas_call`` that
+    writes nothing) and the nine grouped products. No zero-filled and no
+    copied 98,304-row array: the buffers are written in place."""
+    from horovod_tpu.ops import moe
+
+    tokens, top_k, dim, width, held, of = 16384, 6, 2048, 768, 16, 128
+
+    def loss(x, weights, w_gate, w_up, w_down, experts):
+        return jnp.sum(moe.dropless_experts(
+            x, weights, experts, w_gate, w_up, w_down,
+            held=(0, held, of)).astype(jnp.float32))
+
+    def shape(*dims, of=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        shape(tokens, dim), shape(tokens, top_k, of=jnp.float32),
+        shape(held, dim, width), shape(held, dim, width),
+        shape(held, width, dim), shape(tokens, top_k, of=jnp.int32)).compile()
+    text = compiled.as_text()
+    for name in (MOE_EXPERTS_GMM, MOE_EXPERTS_TGMM):
+        assert name in text, f"{name} is not in the compiled module"
+    assert " while(" in text
+    rows = f"[{tokens * top_k},{dim}]"
+    for line in text.splitlines():
+        if " broadcast(" in line or " copy(" in line:
+            assert rows not in line.split(" = ")[1].split("(")[0], line
+    # rows, gate | up | h, out, a run-sum buffer each way, dout, dx's two
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30

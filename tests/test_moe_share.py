@@ -203,6 +203,163 @@ def test_no_held_pair_is_dropped_when_every_token_picks_held_experts(
         assert bool(jnp.all(jnp.isfinite(dx.astype(jnp.float32))))
 
 
+def ops_moe_gauges():
+    from horovod_tpu.metrics import registry
+    return registry().snapshot()["gauges"]
+
+
+WINDOW = 32     # the tests' window: a tiny order is several of them
+
+
+def routed(n, live, seed, first=4, count=4):
+    """``experts`` (n, TOP_K) of E, a token's distinct, with EXACTLY ``live``
+    pairs on the held experts ``[first, first + count)``, and weights."""
+    rng = np.random.default_rng(seed)
+    on_held = np.zeros(n * TOP_K, bool)
+    on_held[rng.permutation(n * TOP_K)[:live]] = True
+    held = np.arange(first, first + count)
+    absent = np.setdiff1d(np.arange(E), held)
+    experts = np.empty((n, TOP_K), np.int32)
+    for token, flags in enumerate(on_held.reshape(n, TOP_K)):
+        picks = iter(rng.permutation(held)), iter(rng.permutation(absent))
+        experts[token] = [next(picks[0 if flag else 1]) for flag in flags]
+    weights = rng.uniform(0.1, 0.9, (n, TOP_K)).astype(np.float32)
+    return jnp.asarray(experts), jnp.asarray(weights)
+
+
+def held_reference(x, weights, experts, w_gate, w_up, w_down, first):
+    """The held experts' terms, each expert on every row (the file's
+    reference): ``weights`` scattered to (N, E), 0 where not chosen."""
+    dense = jnp.zeros((x.shape[0], E), weights.dtype).at[
+        jnp.arange(x.shape[0])[:, None], experts].set(weights)
+    x, w_gate, w_up, w_down = (a.astype(jnp.float32)
+                               for a in (x, w_gate, w_up, w_down))
+    with jax.default_matmul_precision("highest"):
+        return sum(kanana2.expert_term(x, dense[:, first + i], w_gate[i],
+                                       w_up[i], w_down[i])
+                   for i in range(w_gate.shape[0]))
+
+
+# (tokens, live pairs): a window is WINDOW sorted rows, the order 192
+ROUTINGS = {"every_pair_held": (64, 192), "no_pair_held": (64, 0),
+            "on_a_window_border": (64, 64), "one_row_past_it": (64, 65),
+            "one_row_short_of_it": (64, 63), "a_share_of_0.19": (64, 36)}
+
+
+@pytest.mark.parametrize("routing", list(ROUTINGS))
+def test_the_held_path_follows_the_live_rows(monkeypatch, routing):
+    """Several windows of the sorted order, the live rows ending anywhere in
+    them: output, ``dx``, ``dweights`` and the three weight gradients are the
+    reference's. Every pair held: every window runs, and the layer is
+    ``held=None`` on the same weights; none held: no window runs."""
+    monkeypatch.setattr(ops_moe, "_WINDOW_ROWS", WINDOW)
+    n, live = ROUTINGS[routing]
+    first, count = (0, E) if live == n * TOP_K else (4, 4)
+    experts, weights = routed(n, live, 11, first, count)
+    assert int(jnp.sum((experts >= first) & (experts < first + count))) == live
+    x = seeded((n, D), 5, 1.0)
+    w_gate, w_up, w_down = (seeded(shape, seed, 0.3) for shape, seed in (
+        ((count, D, W), 6), ((count, D, W), 7), ((count, W, D), 8)))
+    g = seeded((n, D), 9, 1.0)
+
+    def system(held):
+        return lambda x, weights, *w: ops_moe.dropless_experts(
+            x, weights, experts, *w, held=held)
+
+    def value_and_grads(f):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(f(*a) * g), argnums=(0, 1, 2, 3, 4))(
+                x, weights, w_gate, w_up, w_down)
+
+    got = value_and_grads(system((first, count, E)))
+    want = value_and_grads(lambda x, weights, *w: held_reference(
+        x, weights, experts, *w, first))
+    close(system((first, count, E))(x, weights, w_gate, w_up, w_down),
+          held_reference(x, weights, experts, w_gate, w_up, w_down, first), 2e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        close(a, b, 2e-5)
+    if live == 0:
+        assert all(float(jnp.max(jnp.abs(a))) == 0.0
+                   for a in jax.tree_util.tree_leaves(got))
+    if live == n * TOP_K:
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(value_and_grads(system(None)))):
+            close(a, b, 2e-6)
+    gauges = ops_moe_gauges()
+    assert gauges["horovod_moe_dispatch_rows"] == (
+        192 if count == E else 64)      # the balanced share 48, in windows
+
+
+@pytest.mark.parametrize("live", [0, 512, 513, 700, 1536])
+def test_the_held_path_through_the_kernels(monkeypatch, live):
+    """The same with the grouped-product kernels in the interpreter (bf16,
+    three windows of one row tile each): rows behind the live ones are never
+    written by a kernel, and nothing of them reaches a result."""
+    monkeypatch.setattr(ops_moe, "_WINDOW_ROWS", 512)
+    n, d, w = 512, 128, 128
+    experts, weights = routed(n, live, live)
+    x = seeded((n, d), 5, 1.0).astype(jnp.bfloat16)
+    w_gate, w_up, w_down = (seeded(shape, seed, 0.2).astype(jnp.bfloat16)
+                            for shape, seed in (((4, d, w), 6), ((4, d, w), 7),
+                                                ((4, w, d), 8)))
+    g = seeded((n, d), 9, 1.0)
+
+    def value_and_grads(f):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(f(*a).astype(jnp.float32) * g),
+            argnums=(0, 1, 2, 3, 4))(x, weights, w_gate, w_up, w_down)
+
+    got = value_and_grads(lambda x, weights, *ws: ops_moe.dropless_experts(
+        x, weights, experts, *ws, interpret=True, held=(4, 4, E)))
+    want = value_and_grads(lambda x, weights, *ws: held_reference(
+        x, weights, experts, *ws, 4))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        a, b = (np.asarray(v, np.float32) for v in (a, b))
+        assert np.all(np.isfinite(a))
+        # bf16 rounds gate, up, their product and dout: by the Euclidean norm
+        assert np.linalg.norm(a - b) <= 2e-2 * max(np.linalg.norm(b), 1e-30)
+        if live == 0:
+            assert not a.any()
+    assert ops_moe_gauges()["horovod_moe_grouped_border_overhead"] > 1.0
+
+
+def test_every_expert_held_by_all_lowers_to_what_it_did():
+    """``held=None`` (OLMoE's layer) is the function it was before the held
+    path followed the live rows: its lowered text, forward and backward, at a
+    tiny float32 size. A digest that moves with an edit that was meant to
+    change that path is pinned again; one that moves with the held path's
+    edits is a fault."""
+    import hashlib
+
+    shape = jax.ShapeDtypeStruct
+
+    def loss(x, weights, experts, *w):
+        return jnp.sum(ops_moe.dropless_experts(x, weights, experts, *w))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+        shape((48, 32), jnp.float32), shape((48, 3), jnp.float32),
+        shape((48, 3), jnp.int32), shape((8, 32, 16), jnp.float32),
+        shape((8, 32, 16), jnp.float32), shape((8, 16, 32), jnp.float32)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "5b8f0009b29a8d1f8ff15dd7ce8672dd840b965be761dfdfa67d6a79265d16f3")
+
+
+def test_the_layer_sows_the_rows_it_visits(whole):
+    """``moe_live_rows`` beside ``moe_expert_counts``: the pairs on the held
+    experts, which is how many sorted rows the layer's passes visit."""
+    x, params, bias = whole
+    _, state = layer_of((4, 4)).apply(
+        {"params": share_params(params, 4, 4),
+         BIAS_COLLECTION: {"router_bias": bias}}, x, mutable=["intermediates"])
+    counts = state["intermediates"]["moe_expert_counts"][0]
+    assert int(state["intermediates"]["moe_live_rows"][0]) == int(
+        counts[4:8].sum()) > 0
+    assert ops_moe_gauges()["horovod_moe_dispatch_rows"] == 144     # one window
+
+
 def test_bias_rule_against_the_reference():
     counts = jnp.array([0, 5, 9, 9, 9, 20, 11, 9], jnp.int32)   # mean 9
     bias = seeded((8,), 4, 0.01)
