@@ -1,0 +1,10 @@
+"""Fusion + exchange layer: device time per step of fuse + compress + the wire
+cast before the exchange (``hvd_fusion_pack``), by the program's own names from
+the whole trace (``benchmarks/named_device_time.py``); 0.0 where the window
+never ran them."""
+
+from benchmarks.named_device_time import ms
+
+
+def read(run):
+    return ms(run, "hvd_fusion_pack")

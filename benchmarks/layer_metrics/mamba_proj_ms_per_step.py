@@ -1,0 +1,10 @@
+"""Mamba-2 mixer layer: device time per step of the mixer's input and output
+projections (``hvd_mamba_proj``), by the program's own names from the whole
+trace (``benchmarks/named_device_time.py``); 0.0 where the window never ran
+them."""
+
+from benchmarks.named_device_time import ms
+
+
+def read(run):
+    return ms(run, "hvd_mamba_proj")

@@ -1,0 +1,9 @@
+"""Experts layer: device time per step of the router's scores and top-k
+(``hvd_moe_route``), by the program's own names from the whole trace
+(``benchmarks/named_device_time.py``); 0.0 where the window never ran them."""
+
+from benchmarks.named_device_time import ms
+
+
+def read(run):
+    return ms(run, "hvd_moe_route")

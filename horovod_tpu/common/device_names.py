@@ -44,3 +44,12 @@ MAMBA_GATE_NORM_FWD = "hvd_mamba_gate_norm_fwd"
 MAMBA_GATE_NORM_BWD = "hvd_mamba_gate_norm_bwd"
 SSD_SCAN = "hvd_ssd_scan"               # the scan over blocks of chunks: chunk
 #                                         states, the recurrence, the outputs
+
+# Names that a number completes in the module (``hvd_fused_allreduce_k3``); a
+# reader of a device profile finds these by prefix, every other by equality.
+PREFIXES = (FUSED_ALLREDUCE,)
+# Every name above, for the device-profile table (metrics/device_profile.py):
+# built from the module itself, so a new name is in the table the day it is
+# introduced.
+ALL = tuple(value for key, value in list(globals().items())
+            if key.isupper() and isinstance(value, str))

@@ -15,6 +15,8 @@ One always-on, process-local registry that every layer reports through:
   registry through the c_api collector).
 - ``measure_overlap`` / plan gauges — the compiled path's bucket
   overlap-efficiency instruments (overlap.py).
+- ``profile_step`` — where a warmed step's device time goes, by the names of
+  ``common/device_names.py`` (device_profile.py; imported on first use).
 - ``merge_snapshots`` — pod-wide aggregation of per-rank snapshots
   (aggregate.py; used by the runner's DriverService, MetricsCallback and
   ``bench.py --metrics``).
@@ -54,6 +56,15 @@ from .registry import (  # noqa: F401
 )
 from .schema import validate_snapshot  # noqa: F401
 from .watchdog import StallInfo, StallReport, StallWatchdog  # noqa: F401
+
+
+def profile_step(*args, **kwargs) -> dict:
+    """``device_profile.profile_step``: profile a warmed step and return the
+    table of its device time by the program's names. The module is imported
+    here, on first use, and by nothing at ``import horovod_tpu``."""
+    from .device_profile import profile_step as run
+
+    return run(*args, **kwargs)
 
 
 def snapshot() -> dict:

@@ -14,11 +14,11 @@ complementary instruments fix that:
    nothing, and the bound rises monotonically as the tail bucket shrinks.
 
 2. **Measured efficiency** (`measure_overlap`): run the step under
-   ``jax.profiler.trace`` and parse the device trace the way
-   utils/roofline.py parses cost fields — collective op spans vs the union
-   of concurrent compute spans. ``overlap_efficiency`` = hidden collective
-   time / total collective time. Requires a backend whose profile carries
-   per-op device spans (TPU); on CPU hosts the parser reports
+   ``jax.profiler.trace`` and read the profiler's ``.xplane.pb`` through
+   ``metrics/device_profile.py`` — collective op spans (by HLO opcode) vs
+   the union of the same device's other ops. ``overlap_efficiency`` = hidden
+   collective time / total collective time. Requires a backend whose profile
+   carries per-op device spans (TPU); on CPU hosts the report is
    ``ok=False`` and only the plan gauges are populated.
 
 Both write the same registry, so `bench.py --metrics` snapshots carry
@@ -27,26 +27,10 @@ Both write the same registry, so `bench.py --metrics` snapshots carry
 
 from __future__ import annotations
 
-import collections
-import glob
-import gzip
-import json
-import os
 import tempfile
 from typing import Callable, Optional
 
 from .registry import DEFAULT_BYTE_BUCKETS, registry
-
-# Substrings identifying collective device ops in XLA traces (op name or
-# hlo_category). Covers the psum/all-gather/reduce-scatter family the
-# compiled data plane emits (parallel/collectives.py).
-_COLLECTIVE_MARKERS = (
-    "all-reduce", "all_reduce", "allreduce",
-    "all-gather", "all_gather", "allgather",
-    "reduce-scatter", "reduce_scatter", "reducescatter",
-    "all-to-all", "all_to_all", "alltoall",
-    "collective-permute", "collective_permute",
-)
 
 # Latest recorded plan, for tests and snapshot annotations: list of
 # (issue_index, nbytes) in collective-issue order.
@@ -347,110 +331,33 @@ def record_sharded_state_bytes(total_bytes: int, shard_size: int,
     return per_rank
 
 
-# --------------------------------------------------------------- trace parse
+# ------------------------------------------------------- measured overlap
 
 
-def _load_latest_trace(logdir: str) -> list:
-    paths = sorted(glob.glob(os.path.join(logdir, "**", "*.trace.json.gz"),
-                             recursive=True))
-    if not paths:
-        raise FileNotFoundError(f"no trace.json.gz under {logdir}")
-    with gzip.open(paths[-1]) as f:
-        return json.load(f)["traceEvents"]
-
-
-def _is_collective(name: str, category: str) -> bool:
-    s = (name + " " + category).lower()
-    return any(m in s for m in _COLLECTIVE_MARKERS)
-
-
-def _union_len(intervals: list) -> float:
-    """Total length of the union of (start, end) intervals."""
-    if not intervals:
-        return 0.0
-    intervals.sort()
-    total, cur_s, cur_e = 0.0, intervals[0][0], intervals[0][1]
-    for s, e in intervals[1:]:
-        if s > cur_e:
-            total += cur_e - cur_s
-            cur_s, cur_e = s, e
-        else:
-            cur_e = max(cur_e, e)
-    return total + (cur_e - cur_s)
-
-
-def _overlap_len(span: tuple, union: list) -> float:
-    """Length of `span`'s intersection with a sorted disjoint union."""
-    s0, e0 = span
-    out = 0.0
-    for s, e in union:
-        if e <= s0:
-            continue
-        if s >= e0:
-            break
-        out += min(e, e0) - max(s, s0)
-    return out
-
-
-def parse_overlap(events: list) -> dict:
-    """Compute collective/compute overlap from raw Chrome-trace events.
-
-    Uses host-clock spans (``ts``/``dur``, µs) of device ops — the fields
-    every XLA device track carries — grouping by track (pid) so overlap is
-    only counted within one device's own timeline (a collective on chip A
-    overlapping compute on chip B is parallelism, not latency hiding)."""
-    pids = {e["pid"]: e["args"].get("name", "")
-            for e in events
-            if e.get("ph") == "M" and e.get("name") == "process_name"
-            and "args" in e}
-    per_dev: dict = collections.defaultdict(lambda: {"coll": [], "comp": []})
-    for e in events:
-        if e.get("ph") != "X" or "dur" not in e or "ts" not in e:
-            continue
-        a = e.get("args") or {}
-        if "device_duration_ps" not in a:
-            continue   # host/python frames — not device ops
-        track = pids.get(e["pid"], "")
-        if "TPU" not in track and "GPU" not in track:
-            continue
-        span = (float(e["ts"]), float(e["ts"]) + float(e["dur"]))
-        name = e.get("name", "")
-        cat = str(a.get("hlo_category", ""))
-        kind = "coll" if _is_collective(name, cat) else "comp"
-        per_dev[e["pid"]][kind].append((span, name))
-    coll_total = hidden = 0.0
-    n_coll = 0
-    buckets = []
-    for dev in per_dev.values():
-        comp_union = sorted(s for s, _ in dev["comp"])
-        # normalize to a disjoint union once per device
-        merged: list = []
-        for s, e in comp_union:
-            if merged and s <= merged[-1][1]:
-                merged[-1] = (merged[-1][0], max(merged[-1][1], e))
-            else:
-                merged.append((s, e))
-        for span, name in dev["coll"]:
-            dur = span[1] - span[0]
-            ov = _overlap_len(span, merged)
-            coll_total += dur
-            hidden += ov
-            n_coll += 1
-            buckets.append({"name": name, "ms": dur / 1e3,
-                            "hidden_ms": ov / 1e3,
-                            "start_us": span[0], "end_us": span[1]})
-    if n_coll == 0:
+def overlap_report(collectives: list) -> dict:
+    """The report of :func:`measure_overlap` from collective intervals
+    ``[(device, name, start_ns, end_ns, hidden_ns)]`` (``device_profile.
+    collective_overlap``): ``hidden_ns`` is the part of an interval covered by
+    non-collective ops of the SAME device (a collective on chip A over compute
+    on chip B is parallelism, not latency hiding)."""
+    if not collectives:
         return {"ok": False,
-                "reason": "no device collective spans in trace (CPU backend "
-                          "traces carry host frames only; run on TPU)"}
-    buckets.sort(key=lambda b: b["start_us"])
+                "reason": "no device collective spans in trace (a CPU "
+                          "backend's trace carries host frames only; a world "
+                          "of one chip runs no collective)"}
+    total = sum(end - start for _, _, start, end, _ in collectives)
+    hidden = sum(h for *_, h in collectives)
+    spans = [{"name": name, "ms": (end - start) / 1e6, "hidden_ms": h / 1e6,
+              "start_us": start / 1e3, "end_us": end / 1e3}
+             for _, name, start, end, h in
+             sorted(collectives, key=lambda c: c[2])]
     return {
         "ok": True,
-        "collectives": n_coll,
-        "collective_ms": round(coll_total / 1e3, 3),
-        "hidden_ms": round(hidden / 1e3, 3),
-        "overlap_efficiency": round(hidden / coll_total, 4) if coll_total else 0.0,
-        "spans": buckets[:64],
+        "collectives": len(collectives),
+        "collective_ms": round(total / 1e6, 3),
+        "hidden_ms": round(hidden / 1e6, 3),
+        "overlap_efficiency": round(hidden / total, 4) if total else 0.0,
+        "spans": spans[:64],
     }
 
 
@@ -458,8 +365,12 @@ def measure_overlap(run_step: Callable[[], None], steps: int = 3,
                     sync: Optional[Callable[[], None]] = None,
                     logdir: Optional[str] = None) -> dict:
     """Profile ``steps`` calls of a warmed ``run_step`` and publish the
-    measured overlap-efficiency gauge. Returns the parse report."""
+    measured overlap-efficiency gauge. Returns the report; the device trace
+    is read by ``metrics/device_profile.py`` from the profiler's
+    ``.xplane.pb`` (collectives by HLO opcode)."""
     import jax
+
+    from . import device_profile
 
     fence = sync or (lambda: None)
     logdir = logdir or tempfile.mkdtemp(prefix="hvd_overlap_")
@@ -468,8 +379,9 @@ def measure_overlap(run_step: Callable[[], None], steps: int = 3,
             run_step()
         fence()
     try:
-        rep = parse_overlap(_load_latest_trace(logdir))
-    except (FileNotFoundError, KeyError, ValueError) as e:
+        rep = overlap_report(device_profile.collective_overlap(
+            device_profile.load(device_profile.find_xplane(logdir))))
+    except (FileNotFoundError, IndexError, ValueError) as e:
         rep = {"ok": False, "reason": f"trace unreadable: {e}"}
     rep["logdir"] = logdir
     if rep.get("ok"):

@@ -414,31 +414,9 @@ def test_bucket_overlap_metrics_consistent_with_plan(mesh8):
         >= hist_before + recorded_buckets
 
 
-def test_overlap_trace_parser_interval_math():
-    """parse_overlap on a synthetic device trace: one collective fully
-    hidden under compute, one fully exposed -> efficiency 0.5."""
-    from horovod_tpu.metrics.overlap import parse_overlap
-
-    def ev(pid, name, ts, dur, cat):
-        return {"ph": "X", "pid": pid, "ts": ts, "dur": dur, "name": name,
-                "args": {"device_duration_ps": int(dur * 1e6),
-                         "hlo_category": cat}}
-
-    events = [
-        {"ph": "M", "pid": 1, "name": "process_name",
-         "args": {"name": "/device:TPU:0"}},
-        ev(1, "fusion.1", 0, 100, "convolution"),
-        ev(1, "all-reduce.1", 20, 50, "all reduce"),    # inside compute
-        ev(1, "all-reduce.2", 200, 50, "all reduce"),   # after compute ends
-    ]
-    rep = parse_overlap(events)
-    assert rep["ok"] and rep["collectives"] == 2
-    assert rep["collective_ms"] == pytest.approx(0.1)
-    assert rep["hidden_ms"] == pytest.approx(0.05)
-    assert rep["overlap_efficiency"] == pytest.approx(0.5)
-    # host-only traces (CPU backend) degrade explicitly, not silently
-    assert parse_overlap([{"ph": "X", "pid": 9, "ts": 0, "dur": 5,
-                           "name": "python_frame", "args": {}}])["ok"] is False
+# measure_overlap's interval math over the device-profile loader:
+# tests/test_device_profile.py (test_overlap_report_interval_math,
+# test_measure_overlap_keeps_its_keys_and_gauges).
 
 
 # ------------------------------------------------------- runner aggregation
