@@ -127,20 +127,30 @@ def last_wire_plan() -> Optional[tuple]:
     return _last_wire_plan
 
 
-def record_flash_plan(live: int, masked: int) -> float:
-    """Record how the latest traced flash-attention forward splits its live
-    k-block steps between its two bodies (trace time, once per compile —
-    same reasoning as record_wire_plan). ``live``: block steps a head
-    executes; ``masked``: those the causal diagonal crosses, which build and
-    apply the mask (``ops.flash_attention.block_census``). The gauge is the
-    share that runs the unmasked body: 0 for one block per row, 1 for
-    non-causal attention."""
+def record_flash_plan(live: int, masked: int, bwd_sub_tiles: int,
+                      bwd_skipped: int) -> float:
+    """Record how the latest traced flash-attention call splits its work
+    (trace time, once per compile — same reasoning as record_wire_plan;
+    ``ops.flash_attention.block_census`` counts all four). ``live``: block
+    steps a head executes; ``masked``: those the causal diagonal crosses,
+    which build and apply the mask. The first gauge is the share that runs
+    the forward's unmasked body: 0 for one block per row, 1 for non-causal
+    attention. ``bwd_sub_tiles``: the sub-tiles the backward's two kernels
+    walk those live blocks in; ``bwd_skipped``: those wholly above the
+    diagonal, which they never compute. The second gauge is their share: 0
+    for non-causal attention, largest for one block per row."""
     share = (live - masked) / max(1, live)
     registry().gauge(
         "horovod_flash_unmasked_block_share",
         help="share of the latest traced flash forward's live k-block steps "
              "that lie wholly below the causal diagonal and skip the mask"
     ).set(share)
+    registry().gauge(
+        "horovod_flash_bwd_skipped_subtile_share",
+        help="share of the sub-tiles of the latest traced flash backward's "
+             "live blocks that lie wholly above the causal diagonal and are "
+             "skipped"
+    ).set(bwd_skipped / max(1, bwd_sub_tiles))
     return share
 
 

@@ -14,28 +14,37 @@ sequentially, so VMEM scratch carries the running (max, sum, acc) across
 block iterations): per-program VMEM is O(block·D), independent of sequence
 length — no full K/V row staging, no VMEM ceiling at long context.
 
-Three kernels behind one ``jax.custom_vjp``:
+Three kernels behind one ``jax.custom_vjp``, one plan: matmul operands go
+to the MXU in the dtype they arrive in and accumulate in f32 (``p`` and
+``ds`` are cast to that dtype just before their products); the scale
+multiplies the f32 scores; ``exp``, the statistics and the mask are f32; the
+loaded tile is walked in unrolled sub-tiles; no vector moves between lanes
+and sublanes inside a block step, and no score-sized tile is transposed.
 - forward: grid (batch·head, q-block, k-block); scratch-carried online
   (m, l, acc). The row statistics m and l are ``(block_q, 128)`` f32, one
   row per sublane row and replicated along the lanes — the orientation of
-  the score tile's own rows, so no vector moves between lanes and sublanes
-  inside a block step. The loaded tile is walked in 256 x 512 sub-tiles.
-  Matmul operands stay in the caller's dtype and accumulate in f32; the
-  scale multiplies the f32 product, as in both backward kernels. Emits
-  the per-row logsumexp residual L in a sublane-replicated ``(8, t)``
-  layout that satisfies TPU block tiling (one transpose per q block).
-- backward dQ: same grid; recomputes p = exp(s − L) blockwise and
-  accumulates dQ = scale · Σ_k [p ∘ (dO·Vᵀ − D)] · K in scratch.
-- backward dK/dV: grid (batch·head, k-block, q-block); accumulates
-  dV = Σ pᵀ·dO and dK = scale · Σ [p ∘ (dO·Vᵀ − D)]ᵀ·Q in scratch.
+  the score tile's own rows. Sub-tiles of 256 x 512. Emits the per-row
+  logsumexp residual L in a sublane-replicated ``(8, t)`` layout that
+  satisfies TPU block tiling (one transpose per q block).
+- backward dQ: same grid, query-major like the forward; L and D are turned
+  ONCE per q block into the same lane-replicated ``(block_q, 128)`` scratch;
+  recomputes p = exp(s − L) per sub-tile and accumulates
+  dQ = scale · Σ_k [p ∘ (dO·Vᵀ − D)] · K in scratch.
+- backward dK/dV: grid (batch·kv-head, k-block, group · q-block), KEY-major:
+  the score sub-tile is sᵀ = K·Qᵀ (keys down the sublanes, queries along
+  the lanes), so L and D are used as they are stored (along the lanes) and
+  dV = Σ pᵀ·dO, dK = scale · Σ [pᵀ ∘ (V·dOᵀ − D)]·Q are plain a @ b
+  products of the tile as it lies.
 (D = rowsum(dO ∘ O) is an elementwise reduction computed outside.)
 
-Causal programs skip the dead triangle with ``pl.when`` — no compute for
-fully-masked blocks — and build the mask only on the blocks the diagonal
-crosses (forward and dK/dV: two bodies; 16 of 136 live blocks at 16k /
-1024 / 1024); there the forward also skips the sub-tiles above the
-diagonal. ``horovod_flash_unmasked_block_share`` says how often the
-unmasked body engages (:func:`block_census`).
+Causal programs run nothing above the diagonal: blocks wholly above it are
+skipped by ``pl.when``; blocks wholly below it build no mask; in the blocks
+it crosses (16 of 136 live blocks at 16k / 1024 / 1024, every block at 1k)
+sub-tiles wholly above it are skipped, those it crosses are masked and those
+below it are not — in all three kernels, the backward's in sub-tiles of
+256 x 256 there. ``horovod_flash_unmasked_block_share`` says how often the
+unmasked body engages and ``horovod_flash_bwd_skipped_subtile_share`` how
+much of the live blocks the backward never computes (:func:`block_census`).
 
 Pairs with the sequence-parallel schedules in ring_attention.py (which move
 K/V between chips); `causal_reference` is the oracle both are tested
@@ -61,27 +70,37 @@ NEG_INF = -1e30
 
 # ------------------------------------------------- causal block geometry
 
-def _live(qi, ki, block_q, block_k):
-    """Some row of q block ``qi`` sees a column of k block ``ki``: the block
-    lies on or below the causal diagonal (``block_q % block_k == 0``)."""
-    return ki < (qi + 1) * (block_q // block_k)
-
-
 def _crossed(qi, ki, block_q, block_k):
-    """A live block the diagonal runs through: only these need the mask;
-    live blocks before them lie wholly below the diagonal."""
+    """Not wholly below the causal diagonal (``block_q % block_k == 0``):
+    of these, the ``block_q // block_k`` blocks from ``ki == qi * ratio`` on
+    are the ones the diagonal runs through, which alone need the mask; the
+    blocks after them lie wholly above it and are never computed."""
     return ki >= qi * (block_q // block_k)
 
 
 def block_census(t, block_q, block_k, causal):
-    """(live, masked): the k-block steps one head's forward executes at
-    these (fitted) blocks, and how many of them the causal diagonal crosses
-    — the closed form of :func:`_live` / :func:`_crossed` over the grid."""
+    """(live, masked, bwd_sub_tiles, bwd_skipped) of one head at these
+    (fitted) blocks. ``live``: the k-block steps the forward executes, and
+    ``masked``: how many of them the causal diagonal crosses — the closed
+    form of the kernels' predicates (:func:`_crossed`) over the grid. Each
+    backward kernel runs the same block steps and walks a crossed block in
+    sub-tiles (``_BWD_SUB_CROSSED``): ``bwd_skipped`` of them lie wholly
+    above the diagonal and are never computed (:func:`_mask_offset`, as the
+    kernels ask it), of the ``bwd_sub_tiles`` of that size that the live
+    blocks hold: the skipped share of the live blocks' area."""
     nq, nk = t // block_q, t // block_k
+    sub_q = _sub_tile(block_q, _BWD_SUB_CROSSED[0])
+    sub_k = _sub_tile(block_k, _BWD_SUB_CROSSED[1])
+    per_block = (block_q // sub_q) * (block_k // sub_k)
     if not causal:
-        return nq * nk, 0
+        return nq * nk, 0, nq * nk * per_block, 0
     ratio = block_q // block_k
-    return ratio * nq * (nq + 1) // 2, ratio * nq
+    live = ratio * nq * (nq + 1) // 2
+    skipped = sum(
+        _mask_offset(j * block_k + k0 - q0, sub_q, sub_k) is False
+        for j in range(ratio) for q0 in range(0, block_q, sub_q)
+        for k0 in range(0, block_k, sub_k))
+    return live, ratio * nq, live * per_block, nq * skipped
 
 
 # ------------------------------------------------------------------- forward
@@ -197,46 +216,151 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         lse_ref[0] = (m_ref[...] + jnp.log(l)).T[:lse_ref.shape[1]]
 
 
-# ---------------------------------------------------------------- backward dQ
+# ------------------------------------------------------------------ backward
+
+# Both backward kernels walk the loaded tile in score sub-tiles of (query
+# positions, key positions), as the forward does: the f32 score-sized
+# temporaries stay at a sub-tile's size. Below the diagonal the forward's
+# 256 x 512: the size moves neither kernel by 0.5% from there to the whole
+# tile with bf16 operands, but f32 operands traced under "highest" at 1024
+# blocks overflow dkv's 16 MiB of scoped VMEM from 512 x 512 on (16 heads x
+# 4096 x 128). A block the diagonal crosses is walked finer: there only the
+# sub-tiles on or below the diagonal run (one strip per row group), and
+# 256 x 256 skips 6 of a 1024 x 1024 block's 16 where 256 x 512 skips 2 of
+# 8 (PERF.md §6, PR 35: the sweeps on the chip).
+_BWD_SUB = (256, 512)
+_BWD_SUB_CROSSED = (256, 256)
+
+
+def _mask_offset(first, sub_q, sub_k):
+    """How a score sub-tile of ``sub_q`` query by ``sub_k`` key positions
+    meets the causal diagonal; ``first`` is its first key position less its
+    first query position. False: wholly above the diagonal (skipped); None:
+    wholly on or below it (no mask); else ``first``: the entry at query a,
+    key b of the sub-tile is live where ``a - b >= first``."""
+    if first > sub_q - 1:
+        return False
+    return first if first + sub_k - 1 > 0 else None
+
+
+def _masked(s, off, q_axis):
+    """A score sub-tile with its entries above the diagonal at NEG_INF;
+    ``off`` is :func:`_mask_offset`'s answer (None: as it is), ``q_axis``
+    the axis its queries lie along."""
+    if off is None:
+        return s
+    a = jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    b = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    return jnp.where(a - b >= off, s, NEG_INF)
+
+
+def _walk(causal, q_major, qi, ki, block_q, block_k, tile, add):
+    """The walk both backward kernels share over block step ``(qi, ki)``.
+    The accumulators' rows lie along the queries (``q_major``: dq) or along
+    the keys (dk/dv); the other axis is summed over. ``tile(rows, cols,
+    off)`` returns one sub-tile's contributions to the accumulators' rows
+    (``rows`` and ``cols`` are slices of the loaded block, ``off`` is None
+    or :func:`_mask_offset`'s offset); ``add(rows, parts)`` adds a row
+    group's sum once."""
+    def extents(sub):
+        """(rows, a row group's, columns, a strip's) of the loaded tile."""
+        sub_q, sub_k = _sub_tile(block_q, sub[0]), _sub_tile(block_k, sub[1])
+        return ((block_q, sub_q, block_k, sub_k) if q_major
+                else (block_k, sub_k, block_q, sub_q))
+
+    def below():
+        """Wholly below the diagonal (or non-causal): no mask. Row groups
+        are one traced body, unrolled when lowered."""
+        n_rows, sub_rows, n_cols, sub_cols = extents(_BWD_SUB)
+
+        def row_group(i, carry):
+            rows = pl.ds(pl.multiple_of(i * sub_rows, sub_rows), sub_rows)
+            strips = [tile(rows, pl.ds(c0, sub_cols), None)
+                      for c0 in range(0, n_cols, sub_cols)]
+            add(rows, [sum(part) for part in zip(*strips)])
+            return carry
+        jax.lax.fori_loop(0, n_rows // sub_rows, row_group, 0, unroll=True)
+
+    def crossed(j):
+        """The ``j``-th block the diagonal crosses (all static): of a row
+        group's sub-tiles, those wholly above the diagonal are skipped and
+        the rest, a contiguous run, are one masked strip."""
+        n_rows, sub_rows, n_cols, sub_cols = extents(_BWD_SUB_CROSSED)
+
+        def offset(r0, c0, width):
+            (q0, k0), (ext_q, ext_k) = (
+                ((r0, c0), (sub_rows, width)) if q_major
+                else ((c0, r0), (width, sub_rows)))
+            return _mask_offset(j * block_k + k0 - q0, ext_q, ext_k)
+
+        for r0 in range(0, n_rows, sub_rows):
+            rows = pl.ds(r0, sub_rows)
+            live = [c0 for c0 in range(0, n_cols, sub_cols)
+                    if offset(r0, c0, sub_cols) is not False]
+            if live:
+                width = live[-1] + sub_cols - live[0]
+                add(rows, tile(rows, pl.ds(live[0], width),
+                               offset(r0, live[0], width)))
+
+    if not causal:
+        return below()
+    # Blocks above the diagonal match neither: nothing runs there.
+    ratio = block_q // block_k
+    pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k)))(below)
+    for j in range(ratio):
+        pl.when(ki == qi * ratio + j)(functools.partial(crossed, j))
+
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_acc_ref, *, block_q, block_k, nk, causal, sm_scale):
+               dq_acc_ref, lse_rows_ref, delta_rows_ref, *, block_q, block_k,
+               nk, causal, sm_scale):
+    """Query-major: a score sub-tile has its queries down the sublanes, and
+    lse and delta are read from lane-replicated scratch, as the forward
+    keeps m and l."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
         dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
+        # the one move per q block: along the lanes (as stored) -> rows down
+        # the sublanes, replicated along the lanes
+        for ref, rows_ref in ((lse_ref, lse_rows_ref),
+                              (delta_ref, delta_rows_ref)):
+            rows_ref[...] = jnp.broadcast_to(
+                ref[0, :1, :], (STAT_LANES, block_q)).T
 
-    live = _live(qi, ki, block_q, block_k) if causal else (ki >= 0)
+    def tile(rows, cols, off):
+        k = k_ref[0, cols, :]
+        s = _masked(jax.lax.dot_general(
+            q_ref[0, rows, :], k, _NT,
+            preferred_element_type=jnp.float32) * sm_scale, off, 0)
+        p = jnp.exp(s - _lanes(lse_rows_ref[rows, :], s.shape[1]))
+        dp = jax.lax.dot_general(
+            do_ref[0, rows, :], v_ref[0, cols, :], _NT,
+            preferred_element_type=jnp.float32)
+        ds = p * (dp - _lanes(delta_rows_ref[rows, :], s.shape[1]))
+        return (jax.lax.dot_general(ds.astype(k.dtype), k, _NN,
+                                    preferred_element_type=jnp.float32),)
 
-    @pl.when(live)
-    def _update():
-        q = q_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0]                              # (block_q,)
-        delta = delta_ref[0, 0]
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = (q @ k.T) * sm_scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
-            k_pos = ki * block_k + jax.lax.iota(jnp.int32, block_k)
-            s = jnp.where(q_pos[:, None] >= k_pos[None, :], s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        ds = p * (do @ v.T - delta[:, None])
-        dq_acc_ref[0] = dq_acc_ref[0] + (ds @ k) * sm_scale
+    def add(rows, parts):
+        dq_acc_ref[0, rows, :] = dq_acc_ref[0, rows, :] + parts[0]
+
+    _walk(causal, True, qi, ki, block_q, block_k, tile, add)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        dq_ref[0] = dq_acc_ref[0].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_acc_ref[0] * sm_scale).astype(dq_ref.dtype)
 
-
-# ------------------------------------------------------------- backward dK/dV
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                 dv_ref, dk_acc_ref, dv_acc_ref, *, block_q, block_k, nq,
                 group, causal, sm_scale):
+    """Key-major: a score sub-tile is TRANSPOSED, keys down the sublanes and
+    queries along the lanes, so the four products are a @ b.T (k . q^T,
+    v . dO^T) and a @ b (pT @ dO, dsT @ q), none with a transposed left
+    operand, and lse and delta are used as they are stored: along the lanes,
+    broadcast down the sublanes."""
     ki = pl.program_id(1)
     # Innermost grid dim walks (g, qi): for GQA (group > 1) the same
     # k/v-head block accumulates gradient contributions from every q head
@@ -250,37 +374,29 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    def update(masked):
-        k = k_ref[0].astype(jnp.float32)                 # (block_k, d)
-        v = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32)                 # (block_q, d)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0]                              # (block_q,)
-        delta = delta_ref[0, 0]
-        s = (q @ k.T) * sm_scale
-        if masked:
-            q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
-            k_pos = ki * block_k + jax.lax.iota(jnp.int32, block_k)
-            s = jnp.where(q_pos[:, None] >= k_pos[None, :], s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                    # (block_q, block_k)
-        dv_acc_ref[0] = dv_acc_ref[0] + p.T @ do
-        ds = p * (do @ v.T - delta[:, None])
-        dk_acc_ref[0] = dk_acc_ref[0] + (ds.T @ q) * sm_scale
+    def tile(rows, cols, off):
+        q, do = q_ref[0, cols, :], do_ref[0, cols, :]
+        st = _masked(jax.lax.dot_general(
+            k_ref[0, rows, :], q, _NT,
+            preferred_element_type=jnp.float32) * sm_scale, off, 1)
+        pt = jnp.exp(st - lse_ref[0, :1, cols])
+        dpt = jax.lax.dot_general(
+            v_ref[0, rows, :], do, _NT, preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[0, :1, cols])
+        return (jax.lax.dot_general(dst.astype(q.dtype), q, _NN,
+                                    preferred_element_type=jnp.float32),
+                jax.lax.dot_general(pt.astype(do.dtype), do, _NN,
+                                    preferred_element_type=jnp.float32))
 
-    if causal:
-        # The mask on the blocks the diagonal crosses only, as in the
-        # forward (this kernel's transposes leave the vector units less
-        # slack than dq's: it gains 2.5% at 16384 / 1024 / 1024, dq nothing).
-        live = _live(qi, ki, block_q, block_k)
-        crossed = _crossed(qi, ki, block_q, block_k)
-        pl.when(live & crossed)(functools.partial(update, True))
-        pl.when(jnp.logical_not(crossed))(functools.partial(update, False))
-    else:
-        update(False)
+    def add(rows, parts):
+        dk_acc_ref[0, rows, :] = dk_acc_ref[0, rows, :] + parts[0]
+        dv_acc_ref[0, rows, :] = dv_acc_ref[0, rows, :] + parts[1]
+
+    _walk(causal, False, qi, ki, block_q, block_k, tile, add)
 
     @pl.when(j == nq * group - 1)
     def _finalize():
-        dk_ref[0] = dk_acc_ref[0].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc_ref[0] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[0].astype(dv_ref.dtype)
 
 
@@ -484,7 +600,11 @@ def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, res, dout):
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda r, qi, ki: (r, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((1, block_q, d), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((1, block_q, d), jnp.float32),        # dq acc
+            pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # lse, by rows
+            pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # delta, by rows
+        ],
         interpret=interpret,
         name=FLASH_BWD_DQ,
     )(qr, kr, vr, dor, lse, delta)

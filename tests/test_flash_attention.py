@@ -18,13 +18,16 @@ def qkv(seed=0, t=T):
     return tuple(jax.random.normal(k, (B, t, H, D), jnp.float32) for k in ks)
 
 
-def _dense(q, k, v, causal):
-    """f32 oracle for either mode, kv heads replicated for GQA."""
+def _dense(q, k, v, causal, sm_scale=None):
+    """f32 oracle for either mode, kv heads replicated for GQA; v's head
+    size may differ from q's and k's, and the scale may be given."""
     group = q.shape[2] // k.shape[2]
     k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (
+        q.shape[-1] ** -0.5 if sm_scale is None else sm_scale)
     if causal:
-        return causal_reference(q, k, v)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (q.shape[-1] ** -0.5)
+        t = q.shape[1]
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
 
 
@@ -50,9 +53,10 @@ def test_gradients_match_oracle():
                                    atol=5e-6, rtol=5e-6)
 
 
-# name: (t, h, hkv, d, block_q, block_k, causal, dtype). Which of the
-# forward's two bodies each reaches (blocks below the diagonal: no mask;
-# blocks the diagonal crosses: masked, dead sub-tiles skipped):
+# name: (t, h, hkv, d, block_q, block_k, causal, dtype[, v's head size,
+# sm_scale]). Which bodies of the three kernels each reaches (blocks below
+# the diagonal: no mask; blocks the diagonal crosses: sub-tiles masked where
+# it crosses them, unmasked below it, skipped above it):
 BODY_CASES = {
     # 4 q blocks: 6 unmasked + 4 masked block steps, and the seam between
     "square_blocks": (128, 2, 2, 32, 32, 32, True, jnp.float32),
@@ -70,6 +74,24 @@ BODY_CASES = {
     "sub_tiles_both_bodies": (2048, 1, 1, 16, 1024, 1024, True, jnp.float32),
     "sub_tiles_non_causal": (1024, 1, 1, 16, 512, 1024, False, jnp.float32),
     "sub_tiles_bf16": (2048, 1, 1, 16, 1024, 1024, True, jnp.bfloat16),
+    # block_q = 2 x block_k at T = one q block: both crossed blocks, the
+    # second with its first q columns dead against every key of the block
+    "sub_tiles_crosses_two": (1024, 1, 1, 16, 1024, 512, True, jnp.float32),
+    # dK/dV's walk over a group's q heads and q blocks (j = g * nq + qi)
+    "sub_tiles_gqa_group_walk": (2048, 4, 2, 16, 1024, 1024, True,
+                                 jnp.float32),
+    # latent attention: q and k 192 wide, v / dO / dV 128
+    "sub_tiles_192_128": (1024, 1, 1, 192, 1024, 1024, True, jnp.bfloat16,
+                          128),
+    "192_128_f32": (128, 2, 2, 48, 64, 32, True, jnp.float32, 32),
+    # Granite: grouped-query heads of 64, scores x 1/64 (not 64 ** -0.5)
+    "sub_tiles_d64_scale": (1024, 2, 1, 64, 1024, 1024, True, jnp.bfloat16,
+                            64, 0.015625),
+    "non_causal_scale": (128, 2, 2, 32, 32, 32, False, jnp.float32, 32, 0.3),
+    # blocks no sub-tile divides: walked whole (_sub_tile's fall-back)
+    "block_no_sub_tile_divides": (768, 1, 1, 16, 384, 384, True,
+                                  jnp.float32),
+    "f32_at_512_blocks": (1024, 1, 1, 16, 512, 512, True, jnp.float32),
 }
 
 
@@ -77,18 +99,20 @@ BODY_CASES = {
 def test_both_bodies_match_oracle(case):
     """Forward and all three gradients against the dense f32 oracle for
     every way a block step can run."""
-    t, h, hkv, d, block_q, block_k, causal, dtype = BODY_CASES[case]
+    t, h, hkv, d, block_q, block_k, causal, dtype, *rest = BODY_CASES[case]
+    dv, sm_scale = rest + [d, None][len(rest):]
     ks = jax.random.split(jax.random.PRNGKey(t + h + block_q), 4)
-    q, k, v = (jax.random.normal(kk, (1, t, n, d), jnp.float32).astype(dtype)
-               for kk, n in zip(ks, (h, hkv, hkv)))
-    g = jax.random.normal(ks[3], q.shape, jnp.float32)
+    q, k, v = (jax.random.normal(kk, (1, t, n, w), jnp.float32).astype(dtype)
+               for kk, n, w in zip(ks, (h, hkv, hkv), (d, d, dv)))
+    g = jax.random.normal(ks[3], (1, t, h, dv), jnp.float32)
     # bf16: the kernels feed bf16 operands (and a bf16 p) to the products,
     # the oracle sees the same inputs in f32
     out_tol, grad_tol = ((2e-6, 5e-6) if dtype == jnp.float32
                          else (2e-2, 4e-2))
 
     def flash(q, k, v):
-        return flash_attention(q, k, v, causal, block_q, block_k, True)
+        return flash_attention(q, k, v, causal, block_q, block_k, True,
+                               sm_scale)
 
     def both(fn, *x):
         out, vjp = jax.vjp(fn, *x)
@@ -96,7 +120,7 @@ def test_both_bodies_match_oracle(case):
 
     with jax.default_matmul_precision("highest"):
         got = both(flash, q, k, v)
-        want = both(lambda *x: _dense(*x, causal),
+        want = both(lambda *x: _dense(*x, causal, sm_scale),
                     *(x.astype(jnp.float32) for x in (q, k, v)))
     for name, a, b, tol in zip(("out", "dq", "dk", "dv"), got, want,
                                (out_tol,) + (grad_tol,) * 3):
@@ -106,41 +130,90 @@ def test_both_bodies_match_oracle(case):
                                    err_msg=f"{case}: {name}")
 
 
-@pytest.mark.parametrize("t,block_q,block_k,causal,live,masked", [
-    (16384, 1024, 1024, True, 136, 16),     # lm217m_long_1chip
-    (1024, 1024, 1024, True, 1, 1),         # lm217m_short_1chip
-    (16384, 1024, 1024, False, 256, 0),
-    (128, 64, 32, True, 6, 4),
-    (96, 48, 48, True, 3, 2),
+@pytest.mark.parametrize("t,block_q,block_k,causal,live,masked,skipped", [
+    (16384, 1024, 1024, True, 136, 16, 96),     # lm217m_long_1chip, granite
+    (8192, 1024, 1024, True, 36, 8, 48),        # kanana2_seq8192_1chip
+    (4096, 1024, 1024, True, 10, 4, 24),        # olmoe_seq4096_1chip
+    (1024, 1024, 1024, True, 1, 1, 6),          # lm217m_short_1chip
+    (16384, 1024, 1024, False, 256, 0, 0),
+    (1024, 1024, 512, True, 2, 2, 6),           # block_q = 2 x block_k
+    (1024, 512, 512, True, 3, 2, 2),
+    (128, 64, 32, True, 6, 4, 0),               # blocks under a sub-tile
+    (96, 48, 48, True, 3, 2, 0),
 ])
-def test_block_census(t, block_q, block_k, causal, live, masked):
+def test_block_census(t, block_q, block_k, causal, live, masked, skipped):
     """The census's closed form counts what the kernels' own predicates
-    select over the grid."""
-    from horovod_tpu.ops.flash_attention import (_crossed, _live,
-                                                 block_census)
+    select over the grid; the backward's sub-tile counts, what a walk over
+    every position of the crossed blocks finds."""
+    from horovod_tpu.ops.flash_attention import (_BWD_SUB_CROSSED, _crossed,
+                                                 _sub_tile, block_census)
 
-    assert block_census(t, block_q, block_k, causal) == (live, masked)
+    got = block_census(t, block_q, block_k, causal)
+    assert got[:2] == (live, masked) and got[3] == skipped
+    sub_q = _sub_tile(block_q, _BWD_SUB_CROSSED[0])
+    sub_k = _sub_tile(block_k, _BWD_SUB_CROSSED[1])
+    assert got[2] == live * (block_q // sub_q) * (block_k // sub_k)
     if causal:
+        # by positions: a block is live where some query of it sees a key
+        # of it, and a sub-tile is skipped where none does
+        q_pos, k_pos = np.arange(t)[:, None], np.arange(t)[None, :]
+        block_sees = (q_pos >= k_pos).reshape(
+            t // block_q, block_q, t // block_k, block_k).any(axis=(1, 3))
         grid = [(qi, ki) for qi in range(t // block_q)
-                for ki in range(t // block_k)
-                if _live(qi, ki, block_q, block_k)]
+                for ki in range(t // block_k) if block_sees[qi, ki]]
         assert len(grid) == live
-        assert sum(_crossed(qi, ki, block_q, block_k)
-                   for qi, ki in grid) == masked
+        crossed = [(qi, ki) for qi, ki in grid
+                   if _crossed(qi, ki, block_q, block_k)]
+        assert len(crossed) == masked
+        sees = (q_pos >= k_pos).reshape(
+            t // sub_q, sub_q, t // sub_k, sub_k).any(axis=(1, 3))
+        dead = sum(
+            not sees[(qi * block_q + q0) // sub_q,
+                     (ki * block_k + k0) // sub_k]
+            for qi, ki in crossed for q0 in range(0, block_q, sub_q)
+            for k0 in range(0, block_k, sub_k))
+        assert dead == skipped
 
 
-@pytest.mark.parametrize("t,block,causal,share", [
-    (128, 32, True, 0.6),       # 10 live block steps, 4 on the diagonal
-    (64, 64, True, 0.0),        # one block per row: always the masked body
-    (128, 32, False, 1.0),
+@pytest.mark.parametrize("first,sub_q,sub_k,want", [
+    (-512, 256, 512, None),     # wholly below: no mask
+    (-511, 256, 512, None),     # its last key is its first query's own
+    (-510, 256, 512, -510),     # its last key passes its first query
+    (0, 256, 256, 0),           # on the diagonal
+    (255, 256, 256, 255),       # one live entry: last query, first key
+    (256, 256, 256, False),     # wholly above: skipped
+    (1, 1, 1, False),
+    (0, 1, 1, None),
 ])
-def test_unmasked_share_gauge_after_a_traced_call(t, block, causal, share):
+def test_mask_offset(first, sub_q, sub_k, want):
+    from horovod_tpu.ops.flash_attention import _mask_offset
+
+    got = _mask_offset(first, sub_q, sub_k)
+    assert got is want or got == want
+    a, b = np.arange(sub_q)[:, None], np.arange(sub_k)[None, :]
+    live = a - b >= first
+    assert (got is False) == (not live.any())
+    assert (got is None) == bool(live.all())
+
+
+@pytest.mark.parametrize("t,block,causal,share,skipped", [
+    (128, 32, True, 0.6, 0.0),      # 10 live block steps, 4 on the diagonal
+    (64, 64, True, 0.0, 0.0),       # one block per row: always the masked body
+    (128, 32, False, 1.0, 0.0),
+    (1024, 1024, True, 0.0, 0.375),     # 6 of the one block's 16 sub-tiles
+    (1024, 512, True, 1 / 3, 1 / 6),    # 2 of 3 live blocks x 4, one each
+    (1024, 1024, False, 1.0, 0.0),
+])
+def test_unmasked_share_gauge_after_a_traced_call(t, block, causal, share,
+                                                  skipped):
     from horovod_tpu.metrics import registry
 
     q, k, v = qkv(7, t=t)
     flash_attention(q, k, v, causal, block, block, True)
-    got = registry().snapshot()["gauges"]["horovod_flash_unmasked_block_share"]
-    assert got == pytest.approx(share)
+    gauges = registry().snapshot()["gauges"]
+    assert gauges["horovod_flash_unmasked_block_share"] == pytest.approx(share)
+    assert gauges["horovod_flash_bwd_skipped_subtile_share"] == pytest.approx(
+        skipped)
 
 
 def test_gqa_matches_replicated_oracle():

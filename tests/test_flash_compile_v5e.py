@@ -249,6 +249,32 @@ def test_latent_attention_kernels_at_192_128_compile_for_v5e(
         assert out.shape == (MLA["b"], MLA["t"], MLA["heads"], MLA["d_v"])
 
 
+# float32 operands stay float32 in all three kernels and are traced under
+# ``highest``: the kanana2 check's float32 leg (512 / 512 blocks, the row's
+# first 2,048 tokens, 192 | 128), and the DEFAULT blocks at 16 heads x 4096 x
+# 128, where the backward's score-sized temporaries are what fills scoped
+# VMEM (sub-tiles of 512 x 512 below the diagonal overflow it in dkv there).
+@pytest.mark.parametrize("t,heads,d_qk,d_v,blocks", [
+    (2048, MLA["heads"], MLA["d_qk"], MLA["d_v"], (512, 512)),
+    (4096, 16, 128, 128, (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)),
+], ids=["kanana2_check_leg", "default_blocks"])
+def test_float32_kernels_under_highest_compile_for_v5e(
+        one_chip, no_persistent_cache, t, heads, d_qk, d_v, blocks):
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(
+            flash_attention(*a, True, *blocks)), argnums=(0, 1, 2))(q, k, v)
+
+    def shape(width):
+        return jax.ShapeDtypeStruct((1, t, heads, width), jnp.float32,
+                                    sharding=one_chip)
+
+    q, v = shape(d_qk), shape(d_v)
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(grads).lower(q, q, v).compile().as_text()
+    for name in (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV):
+        assert name in text, f"{name} is not in the compiled module"
+
+
 # kanana2_seq8192_1chip: 16 held experts of 2048 x 768 over a row buffer of
 # 16,384 tokens x top-6 = 98,304 rows of which an eighth are live (the group
 # sizes sum to LESS than the buffer); 2,048 tokens in the check's two legs.
