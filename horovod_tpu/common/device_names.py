@@ -8,6 +8,11 @@ the benchmark's ``breakdown``, shows each piece under its name.
 FLASH_FWD = "hvd_flash_fwd"
 FLASH_BWD_DQ = "hvd_flash_bwd_dq"
 FLASH_BWD_DKV = "hvd_flash_bwd_dkv"
+# The same three kernels under a window (``flash_attention(window=)``): names
+# of their own, so that a device profile prices band and causal-dense apart.
+FLASH_WIN_FWD = "hvd_flash_win_fwd"
+FLASH_WIN_BWD_DQ = "hvd_flash_win_bwd_dq"
+FLASH_WIN_BWD_DKV = "hvd_flash_win_bwd_dkv"
 RING_FLASH_FWD = "hvd_ring_flash_fwd"
 RING_FLASH_BWD_DQ = "hvd_ring_flash_bwd_dq"
 RING_FLASH_BWD_DKV = "hvd_ring_flash_bwd_dkv"
@@ -28,6 +33,10 @@ MOE_SHARED = "hvd_moe_shared"           # the shared SwiGLU expert every token t
 # the mixer's time by the substrings ``hvd_mla`` and ``hvd_flash_``.
 MLA_PROJ = "hvd_mla_proj"               # q, kv-down, kv-up, o + the latent's norm
 MLA_ROPE = "hvd_mla_rope"               # split, rotary, assembling q and k
+# Multi-head attention's parts that only some models have (models/transformer.py,
+# ``Block.rotary`` / ``Block.attn_gate``).
+ATTN_ROPE = "hvd_attn_rope"             # a rotary scheme: part of a head, YaRN
+ATTN_GATE = "hvd_attn_gate"             # the per-head sigmoid gate on the output
 # The Mamba-2 mixer (models/mamba.py) and its chunked state-space scan
 # (ops/ssd.py). The benchmark finds the mixer's time by the substrings
 # ``hvd_mamba`` and ``hvd_ssd``, the scan's by ``hvd_ssd``.

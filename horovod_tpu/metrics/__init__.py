@@ -37,6 +37,7 @@ from .overlap import (  # noqa: F401
     measure_overlap,
     record_chunked_loss_plan,
     record_flash_plan,
+    record_flash_window_plan,
     record_mamba_fused_passes,
     record_moe_dispatch_rows,
     record_moe_grouped_plan,

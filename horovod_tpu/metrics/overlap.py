@@ -154,6 +154,22 @@ def record_flash_plan(live: int, masked: int, bwd_sub_tiles: int,
     return share
 
 
+def record_flash_window_plan(live: int, dense_live: int) -> float:
+    """Record what the latest traced WINDOWED flash-attention call saves
+    (trace time, once per compile): the block steps a head executes under
+    its window over those of the causal-dense call at the same blocks
+    (``ops.flash_attention.block_census`` counts both). 1 would be a window
+    that reaches every key; 2 T / (window + block) is about what a band a
+    block or so wide comes to."""
+    share = live / max(1, dense_live)
+    registry().gauge(
+        "horovod_flash_window_block_share",
+        help="block steps of the latest traced windowed flash forward over "
+             "those of the causal-dense call at the same blocks"
+    ).set(share)
+    return share
+
+
 def record_chunked_loss_plan(products: int) -> None:
     """Record how many vocabulary products a chunk the latest traced
     ``models.transformer.chunked_lm_loss`` issues (trace time, once per

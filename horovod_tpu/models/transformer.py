@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common import device_names
+from ..ops.moe import CHOSEN_EXPERTS
 from .mamba import Mamba2Dims, Mamba2Mixer
 
 
@@ -37,6 +38,81 @@ class LatentDims:
     qk_nope: int
     qk_rope: int
     v: int
+
+
+@dataclasses.dataclass(frozen=True)
+class RotaryScheme:
+    """One kind of layer's rotary embedding, as a Hugging Face
+    ``rope_parameters`` entry states it: the base ``theta``; ``dims``, how
+    many of a head's LEADING dimensions turn (``partial_rotary_factor`` x the
+    head size; None: all of them), the rest passing through; with a
+    ``factor`` YaRN's blended frequencies over those dimensions
+    (``rope_type`` ``"yarn"``: ``factor``, ``original_max``, ``beta_fast``,
+    ``beta_slow``, as ``transformers``' ``_compute_yarn_parameters`` computes
+    them with ``truncate`` at its default), and ``attention_factor``, which
+    multiplies cos and sin, so q's and k's rotated dimensions alone (None:
+    YaRN's ``0.1 ln(factor) + 1``, and 1 without a factor). Pairs are
+    ``(i, i + dims / 2)``."""
+    theta: float = 10000.0
+    dims: Optional[int] = None
+    factor: Optional[float] = None
+    original_max: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+    def inv_freq(self, dims):
+        """(dims / 2,) float32: the angle a position turns each pair by."""
+        f = self.theta ** (-np.arange(0, dims, 2, dtype=np.float64) / dims)
+        if self.factor is None:
+            return f.astype(np.float32)
+
+        def correction(rotations):      # the pair that turns that often
+            return (dims * np.log(self.original_max / (rotations * 2 * np.pi))
+                    / (2 * np.log(self.theta)))
+
+        low = max(np.floor(correction(self.beta_fast)), 0)
+        high = min(np.ceil(correction(self.beta_slow)), dims - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(dims // 2) - low) / (high - low), 0, 1)
+        return (f / self.factor * ramp + f * (1 - ramp)).astype(np.float32)
+
+    def scale(self):
+        if self.attention_factor is not None:
+            return float(self.attention_factor)
+        return 1.0 if self.factor is None else 0.1 * np.log(self.factor) + 1.0
+
+
+def _rope_scheme(x, positions, scheme):
+    """A :class:`RotaryScheme` on the last dim of ``x`` (..., T, heads, D):
+    the leading ``scheme.dims`` turn in pairs (i, i + half), the rest pass.
+    In place and lane-dense, as ``_rope``'s interleaved form: ``x * cos +
+    swap(x) * sin`` with ``swap(x)[i] = -x[i + half]``, ``swap(x)[i + half] =
+    x[i]`` a product with a constant matrix of 0 and +-1 (exact in any dtype:
+    one term a sum), cos 1 and sin 0 on the dimensions that pass. Slicing a
+    head into halves of 32 or 64 lanes and concatenating them again took 2.6 x
+    and 1.8 x as long on the chip (PERF.md §6, PR 36)."""
+    d = x.shape[-1]
+    dims = d if scheme.dims is None else scheme.dims
+    half = dims // 2
+    angles = positions[..., None].astype(jnp.float32) * jnp.asarray(
+        scheme.inv_freq(dims))
+
+    def widen(turned, passing):     # (..., T, half) -> (..., T, 1, D)
+        rest = jnp.full(angles.shape[:-1] + (d - dims,), passing, jnp.float32)
+        return jnp.concatenate([turned * scheme.scale()] * 2 + [rest],
+                               axis=-1)[..., None, :]
+
+    i = np.arange(half)
+    swap = np.zeros((d, d), np.float32)
+    swap[i + half, i], swap[i, i + half] = -1.0, 1.0
+    swapped = jnp.dot(x, jnp.asarray(swap, x.dtype),
+                      preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST
+                      if x.dtype == jnp.float32 else None)
+    return (x * widen(jnp.cos(angles), 1.0)
+            + swapped * widen(jnp.sin(angles), 0.0)).astype(x.dtype)
 
 
 def _rope(x, positions, theta=10000.0, interleave=False):
@@ -66,16 +142,19 @@ def _rope(x, positions, theta=10000.0, interleave=False):
     return rotated.astype(x.dtype)
 
 
-def causal_attention(q, k, v, seq_offset=0, scale=None):
+def causal_attention(q, k, v, seq_offset=0, scale=None, window=None):
     """Dense causal attention. q,k,v: [B, T, H, D]. Runs on-chip in one block —
     fine up to ~8k tokens; ring attention takes over beyond that. ``scale``
-    multiplies the scores (None: D ** -0.5)."""
+    multiplies the scores (None: D ** -0.5). ``window``: a query at position
+    p sees the keys ``p - window < j <= p`` (None: every earlier key)."""
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     t_q, t_k = q.shape[1], k.shape[1]
     q_pos = jnp.arange(t_q) + seq_offset
     k_pos = jnp.arange(t_k)
     mask = q_pos[:, None] >= k_pos[None, :]
+    if window is not None:
+        mask &= k_pos[None, :] > q_pos[:, None] - window
     logits = jnp.where(mask[None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -122,6 +201,15 @@ class Block(nn.Module):
     moe_route_scale: float = 1.0
     moe_shared_hidden: int = 0
     moe_held: Optional[tuple] = None
+    # What a window / full hybrid's configuration states (Laguna:
+    # TransformerLM documents them): a head size of its own, q and o then
+    # heads x head_dim wide whatever dim is; a window (the query at p sees
+    # the keys p - window < j <= p); a rotary scheme in place of rope_theta's
+    # plain one; a sigmoid gate a head and token on the attention output.
+    head_dim: Optional[int] = None
+    window: Optional[int] = None
+    rotary: Optional[RotaryScheme] = None
+    attn_gate: bool = False
 
     @nn.compact
     def __call__(self, x, positions):
@@ -169,17 +257,27 @@ class Block(nn.Module):
 
     def _attention(self, h, positions):
         """Causal self-attention of the normed ``h``, through o_proj."""
-        head_dim = self.dim // self.heads
+        if self.head_dim is None and self.dim % self.heads:
+            raise ValueError(
+                f"dim {self.dim} is no multiple of heads {self.heads}: a head "
+                f"size of its own is head_dim's to state")
+        head_dim = (self.dim // self.heads if self.head_dim is None
+                    else self.head_dim)
+        width = self.heads * head_dim       # of q and of o_proj's input
         kvh = self.heads if self.kv_heads is None else self.kv_heads
         if kvh < 1 or self.heads % kvh:
             raise ValueError(
-                f"kv_heads {kvh} must be >= 1 and divide heads {self.heads}")
+                f"kv_heads {kvh} must be >= 1 and divide heads {self.heads} "
+                f"(each of head_dim {head_dim})")
+        if self.window is not None and self.sp_axis is not None:
+            raise ValueError("the ring schedules have no window: "
+                             "window needs sp_axis=None")
         b, t = h.shape[0], h.shape[1]
         if kvh == self.heads:
-            qkv = nn.Dense(3 * self.dim, use_bias=False, dtype=self.dtype, name="qkv")(h)
+            qkv = nn.Dense(3 * width, use_bias=False, dtype=self.dtype, name="qkv")(h)
             q, k, v = jnp.split(qkv, 3, axis=-1)
         else:
-            q = nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
+            q = nn.Dense(width, use_bias=False, dtype=self.dtype,
                          name="q_proj")(h)
             kv = nn.Dense(2 * kvh * head_dim, use_bias=False,
                           dtype=self.dtype, name="kv_proj")(h)
@@ -193,12 +291,16 @@ class Block(nn.Module):
                            name="k_norm")(k)
         # q wholly before k, as ever: the older models' lowered text is held
         # byte for byte
+        plain_rope = self.rope and self.rotary is None
         q = q.reshape(b, t, self.heads, head_dim)
-        if self.rope:
+        if plain_rope:
             q = _rope(q, positions, self.rope_theta, self.rope_interleave)
         k = k.reshape(b, t, kvh, head_dim)
-        if self.rope:
+        if plain_rope:
             k = _rope(k, positions, self.rope_theta, self.rope_interleave)
+        if self.rotary is not None:
+            with jax.named_scope(device_names.ATTN_ROPE):
+                q, k = (_rope_scheme(x, positions, self.rotary) for x in (q, k))
         v = v.reshape(b, t, kvh, head_dim)
         if self.attention == "dense" and kvh != self.heads and self.sp_axis is None:
             # The local dense einsum path is plain multi-head; replicate kv
@@ -227,14 +329,29 @@ class Block(nn.Module):
             from ..ops.flash_attention import flash_attention
 
             # positional: custom_vjp nondiff_argnums
-            attn = flash_attention(q, k, v, True, bq, bk,
-                                   self.flash_interpret, self.attention_scale)
+            if self.window is None:
+                attn = flash_attention(q, k, v, True, bq, bk,
+                                       self.flash_interpret, self.attention_scale)
+            else:
+                # blocks the model does not state are the kernels' to choose
+                # from the window
+                attn = flash_attention(q, k, v, True, self.block_q,
+                                       self.block_k, self.flash_interpret,
+                                       self.attention_scale, self.window)
+        elif self.window is not None:
+            attn = causal_attention(q, k, v, scale=self.attention_scale,
+                                    window=self.window)
         else:
             # the keyword only where a scale is stated: the benchmark's lm217m
             # reference swaps this function for one that takes none
             attn = (causal_attention(q, k, v) if self.attention_scale is None
                     else causal_attention(q, k, v, scale=self.attention_scale))
-        attn = attn.reshape(b, t, self.dim)
+        if self.attn_gate:
+            with jax.named_scope(device_names.ATTN_GATE):
+                gate = nn.sigmoid(nn.Dense(self.heads, use_bias=False,
+                                           dtype=self.dtype, name="gate_proj")(h))
+                attn = attn * gate[..., None]
+        attn = attn.reshape(b, t, width)
         return nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="o_proj")(attn)
 
     def _flash_blocks(self):
@@ -406,6 +523,24 @@ class TransformerLM(nn.Module):
     moe_route_scale: float = 1.0
     moe_shared_hidden: int = 0
     moe_held: Optional[tuple] = None
+    # A window / full hybrid (Laguna-XS.2: docs/window-attention.md), each as
+    # the model's own configuration states it. layer_types may also name
+    # "full_attention" / "sliding_attention": causal attention over every
+    # earlier key, or over the sliding_window last ones (the query's own
+    # included; through the flash kernels' ``window``), each kind with a
+    # rotary scheme of its own (full_rotary, sliding_rotary: a RotaryScheme;
+    # None: rope_theta's plain one). head_dim: a head's size where it is not
+    # dim // heads; q and o are then heads x head_dim wide. heads_per_layer:
+    # the query heads of each layer (len == layers; None: heads everywhere)
+    # over the same kv_heads. attn_gate: the attention output of head a is
+    # multiplied by sigmoid(h Wg)_a, one number a head and token, before
+    # o_proj.
+    head_dim: Optional[int] = None
+    heads_per_layer: Optional[tuple] = None
+    sliding_window: Optional[int] = None
+    full_rotary: Optional[RotaryScheme] = None
+    sliding_rotary: Optional[RotaryScheme] = None
+    attn_gate: bool = False
 
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden: bool = False):
@@ -413,11 +548,24 @@ class TransformerLM(nn.Module):
             positions = jnp.arange(tokens.shape[1])[None, :]
         kinds = (("attention",) * self.layers if self.layer_types is None
                  else tuple(self.layer_types))
-        if len(kinds) != self.layers or set(kinds) - {"attention", "mamba"}:
-            raise ValueError(f"layer_types {kinds} must name 'attention' or "
-                             f"'mamba' for each of the {self.layers} layers")
+        if len(kinds) != self.layers or set(kinds) - {
+                "attention", "mamba", "full_attention", "sliding_attention"}:
+            raise ValueError(
+                f"layer_types {kinds} must name 'attention', 'mamba', "
+                f"'full_attention' or 'sliding_attention' for each of the "
+                f"{self.layers} layers")
         if "mamba" in kinds and self.mamba is None:
             raise ValueError("a 'mamba' layer needs the mixer's sizes (mamba=)")
+        if "sliding_attention" in kinds and self.sliding_window is None:
+            raise ValueError("a 'sliding_attention' layer needs its window "
+                             "(sliding_window=)")
+        heads = ((self.heads,) * self.layers if self.heads_per_layer is None
+                 else tuple(self.heads_per_layer))
+        if len(heads) != self.layers:
+            raise ValueError(f"heads_per_layer {heads} must state the query "
+                             f"heads of each of the {self.layers} layers")
+        rotary = {"full_attention": self.full_rotary,
+                  "sliding_attention": self.sliding_rotary}
         if self.first_k_dense and (self.moe_experts <= 0 or self.moe_every != 1):
             raise ValueError(
                 f"first_k_dense={self.first_k_dense} states dense layers "
@@ -427,11 +575,16 @@ class TransformerLM(nn.Module):
         x = embed(tokens)
         if self.embedding_multiplier != 1.0:
             x = x * jnp.asarray(self.embedding_multiplier, x.dtype)
-        block_cls = nn.remat(Block) if self.remat else Block
+        block_cls = Block
+        if self.remat:
+            # the routers' choice is saved, never recomputed (ops/moe.py)
+            block_cls = nn.remat(Block, policy=(
+                jax.checkpoint_policies.save_only_these_names(CHOSEN_EXPERTS)
+                if self.moe_experts > 0 else None))
         for i in range(self.layers):
             x = block_cls(
                 dim=self.dim,
-                heads=self.heads,
+                heads=heads[i],
                 mlp_ratio=self.mlp_ratio,
                 dtype=self.dtype,
                 sp_axis=self.sp_axis,
@@ -460,6 +613,11 @@ class TransformerLM(nn.Module):
                 moe_route_scale=self.moe_route_scale,
                 moe_shared_hidden=self.moe_shared_hidden,
                 moe_held=self.moe_held,
+                head_dim=self.head_dim,
+                window=(self.sliding_window if kinds[i] == "sliding_attention"
+                        else None),
+                rotary=rotary.get(kinds[i]),
+                attn_gate=self.attn_gate,
                 name=f"block_{i}",
             )(x, positions)
         x = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
@@ -590,8 +748,9 @@ def tp_param_specs(params, tp_axis: str = "tp"):
         names = [getattr(p, "key", getattr(p, "name", "")) for p in path]
         joined = "/".join(str(n) for n in names)
         if leaf.ndim == 2:
+            # the gate is one column a query head: sharded with q_proj's
             if ("qkv" in joined or "q_proj" in joined or "kv_proj" in joined
-                    or "mlp_in" in joined):
+                    or "gate_proj" in joined or "mlp_in" in joined):
                 return P(None, tp_axis)
             if "o_proj" in joined or "mlp_out" in joined or "lm_head" in joined:
                 return P(tp_axis, None)

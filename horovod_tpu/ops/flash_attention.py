@@ -63,7 +63,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common.device_names import FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD
+from ..common.device_names import (FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD,
+                                   FLASH_WIN_BWD_DKV, FLASH_WIN_BWD_DQ,
+                                   FLASH_WIN_FWD)
 
 NEG_INF = -1e30
 
@@ -78,7 +80,7 @@ def _crossed(qi, ki, block_q, block_k):
     return ki >= qi * (block_q // block_k)
 
 
-def block_census(t, block_q, block_k, causal):
+def block_census(t, block_q, block_k, causal, window=None):
     """(live, masked, bwd_sub_tiles, bwd_skipped) of one head at these
     (fitted) blocks. ``live``: the k-block steps the forward executes, and
     ``masked``: how many of them the causal diagonal crosses — the closed
@@ -87,13 +89,31 @@ def block_census(t, block_q, block_k, causal):
     sub-tiles (``_BWD_SUB_CROSSED``): ``bwd_skipped`` of them lie wholly
     above the diagonal and are never computed (:func:`_mask_offset`, as the
     kernels ask it), of the ``bwd_sub_tiles`` of that size that the live
-    blocks hold: the skipped share of the live blocks' area."""
+    blocks hold: the skipped share of the live blocks' area. With a
+    ``window`` (causal: query p sees keys ``p - window < j <= p``) ``live``
+    are the block steps the band touches, ``masked`` those either of its
+    edges crosses, ``bwd_skipped`` the sub-tiles of those that lie wholly
+    outside it (:func:`_band_offsets`: an offset is met once a q block, less
+    the q blocks whose k block would lie before the sequence)."""
     nq, nk = t // block_q, t // block_k
     sub_q = _sub_tile(block_q, _BWD_SUB_CROSSED[0])
     sub_k = _sub_tile(block_k, _BWD_SUB_CROSSED[1])
     per_block = (block_q // sub_q) * (block_k // sub_k)
     if not causal:
         return nq * nk, 0, nq * nk * per_block, 0
+    if window is not None and window < t:
+        crossed, inside = _band_offsets(t, block_q, block_k, window)
+
+        def met(first):     # q blocks whose block at this offset is in range
+            return nq - max(0, -(first // block_q))
+
+        live = sum(map(met, crossed + inside))
+        skipped = sum(
+            met(first) * (_mask_offset(first + k0 - q0, sub_q, sub_k,
+                                       window) is False)
+            for first in crossed for q0 in range(0, block_q, sub_q)
+            for k0 in range(0, block_k, sub_k))
+        return live, sum(map(met, crossed)), live * per_block, skipped
     ratio = block_q // block_k
     live = ratio * nq * (nq + 1) // 2
     skipped = sum(
@@ -101,6 +121,52 @@ def block_census(t, block_q, block_k, causal):
         for j in range(ratio) for q0 in range(0, block_q, sub_q)
         for k0 in range(0, block_k, sub_k))
     return live, ratio * nq, live * per_block, nq * skipped
+
+
+def _band_steps(block_q, block_k, window):
+    """(k blocks a q block's band can touch, q blocks a k block's can): the
+    inner extents of a windowed call's grids. A block step is placed by
+    ``first``, its first key position less its first query position, a
+    multiple of ``block_k``: live from ``2 - block_k - window`` (its last key
+    is the first query's oldest) to ``block_q - block_k`` (the diagonal's
+    last)."""
+    return (block_q // block_k + (window + block_k - 2) // block_k,
+            1 + (window + block_q - 2) // block_q)
+
+
+def _band_k_block(qi, step, ratio, steps):
+    """The k block that step ``step`` of q block ``qi``'s band is, the
+    diagonal's last block at the last of the ``steps``; negative before the
+    sequence's first block (the kernels run nothing there, the index maps
+    clamp to block 0 so that nothing is fetched)."""
+    return step + (qi + 1) * ratio - steps
+
+
+def _kv_spec(block_k, width, heads, band=None):
+    """The k / v ``BlockSpec`` of the forward's and dQ's grids ``(row, qi,
+    step)``; ``heads = (h, hkv, group)``. ``band = (ratio, steps)`` under a
+    window: the step is the band's (:func:`_band_k_block`), else the k
+    block itself."""
+    if band is None:
+        return pl.BlockSpec((1, block_k, width), lambda r, qi, ki: (
+            _kv_row(r, *heads), ki, 0))
+    return pl.BlockSpec((1, block_k, width), lambda r, qi, step: (
+        _kv_row(r, *heads), jnp.maximum(_band_k_block(qi, step, *band), 0), 0))
+
+
+def _band_offsets(t, block_q, block_k, window):
+    """(crossed, inside): the ``first`` of every block step a windowed call
+    of ``t`` positions meets, those an edge of the band crosses (the diagonal,
+    the window's lower edge, or both: each walked with its own static
+    offsets) and those wholly inside it (no mask; a contiguous run)."""
+    steps, _ = _band_steps(block_q, block_k, window)
+    top = block_q - block_k
+    offsets = [first for first in range(top - (steps - 1) * block_k,
+                                        top + 1, block_k)
+               if first >= block_q - t]       # the grid's farthest: ki = 0
+    kinds = [_mask_offset(first, block_q, block_k, window) for first in offsets]
+    return ([f for f, kind in zip(offsets, kinds) if kind not in (None, False)],
+            [f for f, kind in zip(offsets, kinds) if kind is None])
 
 
 # ------------------------------------------------------------------- forward
@@ -136,9 +202,9 @@ def _sub_tile(block, want):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                block_q, block_k, nk, causal, sm_scale):
+                block_q, block_k, nk, causal, sm_scale, window=None, t=None):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    ki = step = pl.program_id(2)
     d = v_ref.shape[-1]     # the accumulator's width: v's, not q's
     ratio = block_q // block_k
     # The loaded tile is walked in (sub_q, sub_k) score sub-tiles: row groups
@@ -148,25 +214,21 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     sub_q = _sub_tile(block_q, 256)
     sub_k = _sub_tile(block_k, 512)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def sub_tile(r0, c0, off):
+    def sub_tile(r0, c0, off, sub_k=sub_k):
         """Online-softmax update of rows [r0, r0 + sub_q) with columns
         [c0, c0 + sub_k) of the loaded tile. ``off`` is None where no mask
-        is needed, else the sub-tile's first column less its first row."""
+        is needed, else :func:`_mask_offset`'s answer."""
         rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
         v = v_ref[0, cols, :]
-        s = jax.lax.dot_general(
+        s = _masked(jax.lax.dot_general(
             q_ref[0, rows, :], k_ref[0, cols, :], _NT,
-            preferred_element_type=jnp.float32) * sm_scale
-        if off is not None:
-            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(row - col >= off, s, NEG_INF)
+            preferred_element_type=jnp.float32) * sm_scale, off, 0)
         m_prev = m_ref[rows, :]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -187,18 +249,35 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
             return carry
         jax.lax.fori_loop(0, block_q // sub_q, row_group, 0, unroll=True)
 
-    def crossed(first_col):
-        """A block the diagonal crosses; ``first_col`` is its first column
-        less the q block's first row (static). Sub-tiles wholly above the
-        diagonal are skipped, those it crosses are masked, those below it
-        are not."""
+    def crossed(first_col, sub_k=sub_k):
+        """A block an edge of the band crosses; ``first_col`` is its first
+        column less the q block's first row (static). Sub-tiles wholly
+        outside the band (above the diagonal; under a window, below its
+        lower edge too) are skipped, those an edge crosses are masked, the
+        others are not."""
         for r0 in range(0, block_q, sub_q):
             for c0 in range(0, block_k, sub_k):
-                off = first_col + c0 - r0
-                if off <= sub_q - 1:
-                    sub_tile(r0, c0, off if off + sub_k - 1 > 0 else None)
+                off = _mask_offset(first_col + c0 - r0, sub_q, sub_k, window)
+                if off is not False:
+                    sub_tile(r0, c0, off, sub_k)
 
-    if causal:
+    if window is not None:
+        # The grid's last axis holds only the k blocks a q block's band can
+        # touch, the diagonal's last one last: a block step's place against
+        # the band is static, and only its being in the sequence is not.
+        # Crossed blocks are walked in the backward's finer columns: at a
+        # window of a block or so most of their area lies outside the band.
+        ki = _band_k_block(qi, step, ratio, nk)
+        crossed_at, inside = _band_offsets(t, block_q, block_k, window)
+        first = (step + ratio - nk) * block_k
+        fine = _sub_tile(block_k, _BWD_SUB_CROSSED[1])
+        if inside:
+            pl.when((ki >= 0) & (first >= inside[0]) & (first <= inside[-1]))(
+                below)
+        for at in crossed_at:
+            pl.when((ki >= 0) & (first == at))(
+                functools.partial(crossed, at, fine))
+    elif causal:
         # Two bodies: blocks wholly below the diagonal never build a mask;
         # the ``ratio`` blocks the diagonal crosses each know where.
         pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k)))(below)
@@ -208,7 +287,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     else:
         below()
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         l = l_ref[...]
         o_ref[0] = (acc_ref[0] / _lanes(l, d)).astype(o_ref.dtype)
@@ -232,36 +311,55 @@ _BWD_SUB = (256, 512)
 _BWD_SUB_CROSSED = (256, 256)
 
 
-def _mask_offset(first, sub_q, sub_k):
+def _mask_offset(first, sub_q, sub_k, window=None):
     """How a score sub-tile of ``sub_q`` query by ``sub_k`` key positions
     meets the causal diagonal; ``first`` is its first key position less its
     first query position. False: wholly above the diagonal (skipped); None:
     wholly on or below it (no mask); else ``first``: the entry at query a,
-    key b of the sub-tile is live where ``a - b >= first``."""
+    key b of the sub-tile is live where ``a - b >= first``. With a
+    ``window`` the band has a lower edge too, ``a - b < first + window``:
+    False also where the sub-tile lies wholly below it, None where neither
+    edge crosses it, else ``(lo, hi)`` with the entry live where ``lo <=
+    a - b < hi``, an edge that does not cross it None."""
     if first > sub_q - 1:
         return False
-    return first if first + sub_k - 1 > 0 else None
+    lo = first if first + sub_k - 1 > 0 else None
+    if window is None:
+        return lo
+    hi = first + window
+    if hi <= -(sub_k - 1):
+        return False
+    hi = hi if hi <= sub_q - 1 else None
+    return None if lo is None and hi is None else (lo, hi)
 
 
 def _masked(s, off, q_axis):
-    """A score sub-tile with its entries above the diagonal at NEG_INF;
+    """A score sub-tile with its entries outside the band at NEG_INF;
     ``off`` is :func:`_mask_offset`'s answer (None: as it is), ``q_axis``
     the axis its queries lie along."""
     if off is None:
         return s
     a = jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     b = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
-    return jnp.where(a - b >= off, s, NEG_INF)
+    if not isinstance(off, tuple):
+        return jnp.where(a - b >= off, s, NEG_INF)
+    lo, hi = off
+    live = (a - b < hi if lo is None else a - b >= lo if hi is None
+            else (a - b >= lo) & (a - b < hi))
+    return jnp.where(live, s, NEG_INF)
 
 
-def _walk(causal, q_major, qi, ki, block_q, block_k, tile, add):
+def _walk(causal, q_major, qi, ki, block_q, block_k, tile, add, window=None,
+          t=None):
     """The walk both backward kernels share over block step ``(qi, ki)``.
     The accumulators' rows lie along the queries (``q_major``: dq) or along
     the keys (dk/dv); the other axis is summed over. ``tile(rows, cols,
     off)`` returns one sub-tile's contributions to the accumulators' rows
     (``rows`` and ``cols`` are slices of the loaded block, ``off`` is None
     or :func:`_mask_offset`'s offset); ``add(rows, parts)`` adds a row
-    group's sum once."""
+    group's sum once. With a ``window`` (of a sequence of ``t``) a block step
+    outside the sequence, which a windowed grid's first or last rows hold,
+    runs nothing."""
     def extents(sub):
         """(rows, a row group's, columns, a strip's) of the loaded tile."""
         sub_q, sub_k = _sub_tile(block_q, sub[0]), _sub_tile(block_k, sub[1])
@@ -281,17 +379,19 @@ def _walk(causal, q_major, qi, ki, block_q, block_k, tile, add):
             return carry
         jax.lax.fori_loop(0, n_rows // sub_rows, row_group, 0, unroll=True)
 
-    def crossed(j):
-        """The ``j``-th block the diagonal crosses (all static): of a row
-        group's sub-tiles, those wholly above the diagonal are skipped and
-        the rest, a contiguous run, are one masked strip."""
+    def crossed(first):
+        """A block an edge of the band crosses, its first key less its first
+        query ``first`` (all static; ``j * block_k`` for the ``j``-th block
+        the diagonal crosses): of a row group's sub-tiles, those wholly
+        outside the band are skipped and the rest, a contiguous run, are one
+        masked strip."""
         n_rows, sub_rows, n_cols, sub_cols = extents(_BWD_SUB_CROSSED)
 
         def offset(r0, c0, width):
             (q0, k0), (ext_q, ext_k) = (
                 ((r0, c0), (sub_rows, width)) if q_major
                 else ((c0, r0), (width, sub_rows)))
-            return _mask_offset(j * block_k + k0 - q0, ext_q, ext_k)
+            return _mask_offset(first + k0 - q0, ext_q, ext_k, window)
 
         for r0 in range(0, n_rows, sub_rows):
             rows = pl.ds(r0, sub_rows)
@@ -304,23 +404,34 @@ def _walk(causal, q_major, qi, ki, block_q, block_k, tile, add):
 
     if not causal:
         return below()
+    if window is not None:
+        crossed_at, inside = _band_offsets(t, block_q, block_k, window)
+        first = ki * block_k - qi * block_q
+        here = (ki >= 0) & (qi < t // block_q)
+        if inside:
+            pl.when(here & (first >= inside[0]) & (first <= inside[-1]))(below)
+        for at in crossed_at:
+            pl.when(here & (first == at))(functools.partial(crossed, at))
+        return
     # Blocks above the diagonal match neither: nothing runs there.
     ratio = block_q // block_k
     pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k)))(below)
     for j in range(ratio):
-        pl.when(ki == qi * ratio + j)(functools.partial(crossed, j))
+        pl.when(ki == qi * ratio + j)(functools.partial(crossed, j * block_k))
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                dq_acc_ref, lse_rows_ref, delta_rows_ref, *, block_q, block_k,
-               nk, causal, sm_scale):
+               nk, causal, sm_scale, window=None, t=None):
     """Query-major: a score sub-tile has its queries down the sublanes, and
     lse and delta are read from lane-replicated scratch, as the forward
     keeps m and l."""
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    ki = step = pl.program_id(2)
+    if window is not None:      # the forward's grid: the band's k blocks
+        ki = _band_k_block(qi, step, block_q // block_k, nk)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
         # the one move per q block: along the lanes (as stored) -> rows down
@@ -346,16 +457,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def add(rows, parts):
         dq_acc_ref[0, rows, :] = dq_acc_ref[0, rows, :] + parts[0]
 
-    _walk(causal, True, qi, ki, block_q, block_k, tile, add)
+    _walk(causal, True, qi, ki, block_q, block_k, tile, add, window, t)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         dq_ref[0] = (dq_acc_ref[0] * sm_scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                 dv_ref, dk_acc_ref, dv_acc_ref, *, block_q, block_k, nq,
-                group, causal, sm_scale):
+                group, causal, sm_scale, window=None, t=None):
     """Key-major: a score sub-tile is TRANSPOSED, keys down the sublanes and
     queries along the lanes, so the four products are a @ b.T (k . q^T,
     v . dO^T) and a @ b (pT @ dO, dsT @ q), none with a transposed left
@@ -368,6 +479,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     # group's q blocks. group == 1 reduces to the plain j == qi walk.
     j = pl.program_id(2)
     qi = j % nq
+    if window is not None:
+        # ``nq`` is the q blocks a k block's band can touch, from the one
+        # that holds the k block's own positions on
+        qi = qi + ki // (block_q // block_k)
 
     @pl.when(j == 0)
     def _init():
@@ -392,7 +507,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dk_acc_ref[0, rows, :] = dk_acc_ref[0, rows, :] + parts[0]
         dv_acc_ref[0, rows, :] = dv_acc_ref[0, rows, :] + parts[1]
 
-    _walk(causal, False, qi, ki, block_q, block_k, tile, add)
+    _walk(causal, False, qi, ki, block_q, block_k, tile, add, window, t)
 
     @pl.when(j == nq * group - 1)
     def _finalize():
@@ -481,12 +596,30 @@ def _q_row(r, j, nq, h, hkv, group):
     return (r // hkv) * h + (r % hkv) * group + j // nq
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _plan(t, block_q, block_k, interpret, window):
+    """(block_q, block_k, window) a call runs at. A window that reaches the
+    sequence's first position from its last is no window: the call is the
+    causal-dense one, bit for bit. Blocks the caller did not give (None) are
+    the defaults, under a window too: at 16,384 x 64 over 8 x 128 and a
+    window of 512 the 1024 x 1024 blocks (31 block steps a head, the
+    sub-tiles outside the band skipped) ran the three kernels in 13.2 ms a
+    call, blocks of the window's own size (63 steps) in 15.8, 256 x 256 in
+    24.0 (PERF.md §6, PR 36: a block step's fixed cost outweighs the area)."""
+    if window is not None and window >= t:
+        window = None
+    block_q, block_k = _check_blocks(
+        t, DEFAULT_BLOCK_Q if block_q is None else block_q,
+        DEFAULT_BLOCK_K if block_k is None else block_k, interpret)
+    return block_q, block_k, window
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = True,
-                    block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
+                    block_q: int | None = None,
+                    block_k: int | None = None,
                     interpret: bool = False,
-                    sm_scale: float | None = None):
+                    sm_scale: float | None = None,
+                    window: int | None = None):
     """Fused attention, trainable. q: ``(B, T, H, D)``, k/v: ``(B, T, H, D)``
     or ``(B, T, Hkv, D)`` with ``H % Hkv == 0`` for grouped-query attention
     (each kv head serves a contiguous group of q heads — no head
@@ -496,46 +629,65 @@ def flash_attention(q, k, v, causal: bool = True,
     follow v, dq and dk follow q; the default scale is q's ``D ** -0.5``.
     Sequence length must be a multiple of
     ``block_q`` and ``block_q`` of ``block_k`` (both clamp down to the
-    sequence length for short inputs; the defaults measured fastest on v5e
+    sequence length for short inputs; None: ``DEFAULT_BLOCK_Q`` /
+    ``DEFAULT_BLOCK_K``, which measured fastest on v5e
     at d=64 — bigger blocks amortize scratch round-trips and feed the MXU
     wider). q, k and v go to the MXU in the dtype they arrive in; the
     softmax statistics, the accumulators and ``exp`` are f32 throughout.
     ``sm_scale`` multiplies the f32 scores inside the kernels, forward and
     backward (None: ``D ** -0.5``; Granite's ``attention_multiplier`` is not).
+    ``window`` (causal only): the query at position p sees the keys ``p -
+    window < j <= p``, itself included (Hugging Face's sliding-window mask).
+    All three kernels then run grids that hold only the k blocks (q blocks)
+    a block's band can touch, fetch and compute nothing else, skip the
+    sub-tiles wholly outside the band and mask those an edge crosses; they
+    carry names of their own (``hvd_flash_win_*``).
     ``interpret=True`` runs the kernels in the Pallas interpreter (CPU
     tests); the default compiles them for the TPU and raises on a machine
     that has none."""
-    out, _ = _fwd(q, k, v, causal, block_q, block_k, interpret, sm_scale)
+    out, _ = _fwd(q, k, v, causal, block_q, block_k, interpret, sm_scale,
+                  window)
     return out
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret, sm_scale):
-    from ..metrics import record_flash_plan
+def _fwd(q, k, v, causal, block_q, block_k, interpret, sm_scale, window):
+    from ..metrics import record_flash_plan, record_flash_window_plan
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window={window} needs causal=True and window >= 1")
     t = q.shape[1]
-    record_flash_plan(*block_census(
-        t, *_check_blocks(t, block_q, block_k, interpret), causal))
-    return _fwd_call(q, k, v, causal, block_q, block_k, interpret, sm_scale)
+    block_q, block_k, window = _plan(t, block_q, block_k, interpret, window)
+    census = block_census(t, block_q, block_k, causal, window)
+    record_flash_plan(*census)
+    if window is not None:
+        record_flash_window_plan(
+            census[0], block_census(t, block_q, block_k, causal)[0])
+    return _fwd_call(q, k, v, causal, block_q, block_k, interpret, sm_scale,
+                     window)
 
 
 # The calls are jitted so that the layers of a model, which call them with
 # one signature, share ONE traced and lowered copy of each kernel: the
 # kernels' bodies are unrolled and cost seconds to trace and lower, and a
 # step is traced and lowered on every start, warm or cold.
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
-def _fwd_call(q, k, v, causal, block_q, block_k, interpret, sm_scale):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def _fwd_call(q, k, v, causal, block_q, block_k, interpret, sm_scale,
+              window=None):
     b, t, h, d = q.shape
     h, hkv, group = _gqa_group(q, k, v)
     dv = v.shape[3]
     block_q, block_k = _check_blocks(t, block_q, block_k, interpret)
     qr = _rows(q, b, t, h, d)
     kr, vr = _rows(k, b, t, hkv, d), _rows(v, b, t, hkv, dv)
-    nk = t // block_k
+    nk, banded, band = t // block_k, {}, None
+    if window is not None:
+        nk = _band_steps(block_q, block_k, window)[0]
+        banded, band = dict(window=window, t=t), (block_q // block_k, nk)
     kernel = functools.partial(
         _fwd_kernel, block_q=block_q, block_k=block_k, nk=nk, causal=causal,
-        sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
+        sm_scale=d ** -0.5 if sm_scale is None else sm_scale, **banded)
+
     def kv_spec(width):
-        return pl.BlockSpec((1, block_k, width), lambda r, qi, ki: (
-            _kv_row(r, h, hkv, group), ki, 0))
+        return _kv_spec(block_k, width, (h, hkv, group), band)
 
     out, lse = pl.pallas_call(
         kernel,
@@ -559,13 +711,21 @@ def _fwd_call(q, k, v, causal, block_q, block_k, interpret, sm_scale):
             pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # l
         ],
         interpret=interpret,
-        name=FLASH_FWD,
+        name=FLASH_FWD if window is None else FLASH_WIN_FWD,
     )(qr, kr, vr)
     return _unrows(out, b, t, h, dv), (q, k, v, out, lse)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
-def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, res, dout):
+def _bwd(causal, block_q, block_k, interpret, sm_scale, window, res, dout):
+    block_q, block_k, window = _plan(res[0].shape[1], block_q, block_k,
+                                     interpret, window)
+    return _bwd_rule(causal, block_q, block_k, interpret, sm_scale, window,
+                     res, dout)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
+def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, window, res,
+              dout):
     q, k, v, out, lse = res
     b, t, h, d = q.shape
     h, hkv, group = _gqa_group(q, k, v)
@@ -582,14 +742,19 @@ def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, res, dout):
     nq, nk = t // block_q, t // block_k
     common = dict(block_q=block_q, block_k=block_k, causal=causal,
                   sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
+    ratio = block_q // block_k
+    k_steps, q_steps, band = nk, nq, None       # the grids' inner extents
+    if window is not None:
+        k_steps, q_steps = _band_steps(block_q, block_k, window)
+        common.update(window=window, t=t)
+        band = (ratio, k_steps)
 
     def kv_spec(width):
-        return pl.BlockSpec((1, block_k, width), lambda r, qi, ki: (
-            _kv_row(r, h, hkv, group), ki, 0))
+        return _kv_spec(block_k, width, (h, hkv, group), band)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, nk=nk, **common),
-        grid=(b * h, nq, nk),
+        functools.partial(_dq_kernel, nk=k_steps, **common),
+        grid=(b * h, nq, k_steps),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda r, qi, ki: (r, qi, 0)),
             kv_spec(d),
@@ -606,26 +771,34 @@ def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, res, dout):
             pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # delta, by rows
         ],
         interpret=interpret,
-        name=FLASH_BWD_DQ,
+        name=FLASH_BWD_DQ if window is None else FLASH_WIN_BWD_DQ,
     )(qr, kr, vr, dor, lse, delta)
 
     # dK/dV: one grid row per KV row; the innermost dim sweeps (g, qi) so a
     # shared kv head accumulates all of its group's q-head contributions in
     # scratch before writing out (grid dim 0 = b*hkv, not b*h).
     def q_row(r, j):
-        return _q_row(r, j, nq, h, hkv, group)
+        return _q_row(r, j, q_steps, h, hkv, group)
+
+    def q_block(ki, j):
+        if window is None:
+            return j % nq
+        # the band's q blocks of this k block, from its own on; those past
+        # the sequence are the last one again, and nothing is fetched
+        return jnp.minimum(j % q_steps + ki // ratio, nq - 1)
 
     def qd(width):
         return pl.BlockSpec((1, block_q, width),
-                            lambda r, ki, j: (q_row(r, j), j % nq, 0))
+                            lambda r, ki, j: (q_row(r, j), q_block(ki, j), 0))
 
     def kd(width):
         return pl.BlockSpec((1, block_k, width), lambda r, ki, j: (r, ki, 0))
 
-    row = pl.BlockSpec((1, 8, block_q), lambda r, ki, j: (q_row(r, j), 0, j % nq))
+    row = pl.BlockSpec((1, 8, block_q),
+                       lambda r, ki, j: (q_row(r, j), 0, q_block(ki, j)))
     dk, dv_rows = pl.pallas_call(
-        functools.partial(_dkv_kernel, nq=nq, group=group, **common),
-        grid=(b * hkv, nk, nq * group),
+        functools.partial(_dkv_kernel, nq=q_steps, group=group, **common),
+        grid=(b * hkv, nk, q_steps * group),
         in_specs=[qd(d), kd(d), kd(dv), qd(dv), row, row],
         out_specs=[kd(d), kd(dv)],
         out_shape=[
@@ -637,11 +810,11 @@ def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, res, dout):
             pltpu.VMEM((1, block_k, dv), jnp.float32),  # dv acc
         ],
         interpret=interpret,
-        name=FLASH_BWD_DKV,
+        name=FLASH_BWD_DKV if window is None else FLASH_WIN_BWD_DKV,
     )(qr, kr, vr, dor, lse, delta)
 
     return (_unrows(dq, b, t, h, d), _unrows(dk, b, t, hkv, d),
             _unrows(dv_rows, b, t, hkv, dv))
 
 
-flash_attention.defvjp(_fwd, _bwd_rule)
+flash_attention.defvjp(_fwd, _bwd)

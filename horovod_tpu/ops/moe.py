@@ -56,10 +56,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from ..common import device_names
 from . import grouped_matmul as gm
+
+# The name both routers give the experts they chose, before anything reads
+# them: a caller that recomputes a layer in the backward pass saves them by it
+# (``TransformerLM(remat=True)``: ``save_only_these_names``). A recomputed
+# router whose scores differ from the forward's in the last bit breaks a
+# float32 tie between a token's last chosen expert and the next the other
+# way, and the backward then differentiates another expert set than the
+# forward ran (seen on the v5e: one token of 2,048, up to 5% of the largest
+# entry of the layer's gradients; PERF.md, PR 36).
+CHOSEN_EXPERTS = "moe_chosen_experts"
 
 
 def topk_route(logits, top_k: int):
@@ -70,7 +81,7 @@ def topk_route(logits, top_k: int):
     token's weights sum to less than 1."""
     with jax.named_scope(device_names.MOE_ROUTE):
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        _, experts = lax.top_k(probs, top_k)
+        experts = checkpoint_name(lax.top_k(probs, top_k)[1], CHOSEN_EXPERTS)
         # The weights through a one-hot product, not top_k's values: their
         # backward is then a product too, where top_k's is a scatter-add of
         # N x top_k scalars.
@@ -90,7 +101,9 @@ def sigmoid_route(logits, bias, top_k: int, scale: float):
     :func:`router_bias_update`."""
     with jax.named_scope(device_names.MOE_ROUTE):
         scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-        _, experts = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+        experts = checkpoint_name(
+            lax.top_k(scores + lax.stop_gradient(bias), top_k)[1],
+            CHOSEN_EXPERTS)
         # the weights through a one-hot product, as in topk_route
         onehot = experts[:, :, None] == jnp.arange(scores.shape[-1])
         weights = jnp.sum(jnp.where(onehot, scores[:, None, :], 0.0), axis=-1)

@@ -99,8 +99,12 @@ def zigzag_unshard(x, n: int, axis: int = 1):
     return jnp.take(x, jnp.asarray(inv), axis=axis)
 
 
-def ring_attention(q, k, v, axis_name: str, zigzag: bool = False):
+def ring_attention(q, k, v, axis_name: str, zigzag: bool = False,
+                   window=None):
     """Causal ring attention over ``axis_name`` (sequence-sharded).
+    ``window`` must stay None: no ring schedule masks a band's lower edge or
+    skips the ring steps wholly below it, and one raises rather than attend
+    to every earlier key.
 
     With contiguous sharding (default), blocks from src > rank are fully
     masked — ~half the ring steps do dead work and the last rank is the
@@ -116,6 +120,10 @@ def ring_attention(q, k, v, axis_name: str, zigzag: bool = False):
     on the fly (the flash variant in ring_flash.py aliases the shared head
     in-kernel instead).
     """
+    if window is not None:
+        raise NotImplementedError(
+            f"ring_attention has no window (got window={window}): the flash "
+            f"kernels on one chip do (ops.flash_attention)")
     n = axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, t, h, d = q.shape
@@ -168,13 +176,17 @@ def ring_attention(q, k, v, axis_name: str, zigzag: bool = False):
     return out.astype(q.dtype)
 
 
-def causal_reference(q, k, v):
+def causal_reference(q, k, v, window=None):
     """Single-device dense causal attention — the oracle the sequence-parallel
-    schedules are tested against. q,k,v: [B, T, H, D]."""
+    schedules are tested against. q,k,v: [B, T, H, D]. ``window``: the query
+    at position p sees the keys ``p - window < j <= p`` (the flash kernels'
+    ``window``; no ring schedule has one)."""
     scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     t = q.shape[1]
     mask = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+    if window is not None:
+        mask &= jnp.arange(t)[None, :] > jnp.arange(t)[:, None] - window
     logits = jnp.where(mask[None, None], logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)

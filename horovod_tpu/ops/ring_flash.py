@@ -302,11 +302,10 @@ def _kpos_arr(pos, t):
     return jnp.broadcast_to(pos[:, None].astype(jnp.int32), (t, 128))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def ring_flash_attention(q, k, v, axis_name: str, zigzag: bool = False,
                          block_q: int = DEFAULT_BLOCK_Q,
                          block_k: int = DEFAULT_BLOCK_K,
-                         interpret: bool = False):
+                         interpret: bool = False, window=None):
     """Causal ring attention over ``axis_name`` with pallas-fused local
     blocks, trainable. q: ``(B, T_local, H, D)``; k, v: same or
     ``(B, T_local, Hkv, D)`` with ``H % Hkv == 0`` (grouped-query
@@ -315,7 +314,20 @@ def ring_flash_attention(q, k, v, axis_name: str, zigzag: bool = False,
     Sequence already sharded on ``axis_name``. Same semantics as
     :func:`ring_attention.ring_attention` (including ``zigzag``), same
     block-size and ``interpret`` contract as
-    :func:`flash_attention.flash_attention`."""
+    :func:`flash_attention.flash_attention`. ``window`` must stay None: the
+    ring's kernels mask by position against the diagonal alone, and a window
+    raises rather than be ignored."""
+    if window is not None:
+        raise NotImplementedError(
+            f"ring_flash_attention has no window (got window={window}): the "
+            f"flash kernels on one chip do (ops.flash_attention)")
+    return _ring_flash_attention(q, k, v, axis_name, zigzag, block_q, block_k,
+                                 interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _ring_flash_attention(q, k, v, axis_name, zigzag, block_q, block_k,
+                          interpret):
     out, _ = _rf_fwd(q, k, v, axis_name, zigzag, block_q, block_k, interpret)
     return out
 
@@ -418,4 +430,4 @@ def _rf_bwd(axis_name, zigzag, block_q, block_k, interpret, res, dout):
             _unrows(dv_blk.astype(v.dtype), b, t, hkv, d))
 
 
-ring_flash_attention.defvjp(_rf_fwd, _rf_bwd)
+_ring_flash_attention.defvjp(_rf_fwd, _rf_bwd)

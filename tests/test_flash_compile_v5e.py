@@ -343,3 +343,46 @@ def test_the_held_layer_compiles_for_v5e_at_the_cells_shape(
             assert rows not in line.split(" = ")[1].split("(")[0], line
     # rows, gate | up | h, out, a run-sum buffer each way, dout, dx's two
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+
+
+# laguna_xs2_seq16384_1chip: 64 query heads over 8 key/value heads of 128
+# under a window of 512 on the sliding layers (the default blocks; grids that
+# hold only the band's blocks, index maps that clamp at the sequence's ends;
+# and at blocks of the window's size), 48 over 8 causal-dense on the full ones,
+# and the check's float32 leg at 512 / 512 blocks on the row's first 2,048.
+SWA = dict(t=16384, kv_heads=8, head_dim=128, window=512)
+
+
+@pytest.mark.parametrize("heads,window,t,dtype,precision,blocks", [
+    (64, SWA["window"], SWA["t"], jnp.bfloat16, None, (None, None)),
+    (48, None, SWA["t"], jnp.bfloat16, None, (None, None)),
+    (64, SWA["window"], 2048, jnp.float32, "highest", (512, 512)),
+    (64, SWA["window"], SWA["t"], jnp.bfloat16, None, (1024, 512)),
+    (64, SWA["window"], SWA["t"], jnp.bfloat16, None, (512, 512)),
+], ids=["sliding_64_over_8", "full_48_over_8", "sliding_check_leg_f32",
+        "sliding_block_q_twice_block_k", "sliding_blocks_of_the_window"])
+def test_window_and_full_kernels_of_the_hybrid_compile_for_v5e(
+        one_chip, no_persistent_cache, heads, window, t, dtype, precision,
+        blocks):
+    from horovod_tpu.common.device_names import (FLASH_WIN_BWD_DKV,
+                                                 FLASH_WIN_BWD_DQ,
+                                                 FLASH_WIN_FWD)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, True, *blocks, False, None, window).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    def shape(n):
+        return jax.ShapeDtypeStruct((1, t, n, SWA["head_dim"]), dtype,
+                                    sharding=one_chip)
+
+    q, kv = shape(heads), shape(SWA["kv_heads"])
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(grads).lower(q, kv, kv).compile().as_text()
+    names = ((FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV) if window is None
+             else (FLASH_WIN_FWD, FLASH_WIN_BWD_DQ, FLASH_WIN_BWD_DKV))
+    for name in names:
+        assert name in text, f"{name} is not in the compiled module"
+    if window is not None:
+        assert FLASH_FWD not in text and FLASH_BWD_DQ not in text

@@ -421,3 +421,35 @@ def test_softmax_layer_keeps_its_parameters_and_sows():
     with pytest.raises(ValueError, match="held"):
         MoEMLP(dim=D, hidden=W, n_experts=4, top_k=2, held=(2, 4)).init(
             jax.random.PRNGKey(0), x)
+
+
+@pytest.mark.parametrize("router", ["softmax", "sigmoid"])
+def test_a_recomputed_block_keeps_the_forwards_chosen_experts(router):
+    """``TransformerLM(remat=True)`` saves what the routers chose (the name
+    ``ops.moe.CHOSEN_EXPERTS``) and recomputes no ``top_k``: a recomputed
+    score that differs in its last bit cannot hand the backward another
+    expert set than the forward ran (PERF.md, PR 36). The gradients are the
+    unrecomputed model's."""
+    from horovod_tpu.models import TransformerLM
+
+    tokens = jnp.arange(32, dtype=jnp.int32).reshape(2, 16) % 64
+
+    def model(remat):
+        return TransformerLM(vocab=64, dim=32, heads=2, layers=3, moe_experts=8,
+                             moe_top_k=2, moe_hidden=16, moe_every=1,
+                             moe_router=router, dtype=jnp.float32, remat=remat)
+
+    variables = model(False).init(jax.random.PRNGKey(0), tokens)
+
+    def grad_of(remat):
+        def loss(params):
+            return jnp.sum(model(remat).apply({**variables, "params": params},
+                                              tokens) ** 2)
+        return jax.grad(loss)
+
+    plain, recomputed = grad_of(False), grad_of(True)
+    count = lambda f: str(jax.make_jaxpr(f)(variables["params"])).count(" top_k[")
+    assert count(plain) == count(recomputed) == 3       # one a layer, forward only
+    for got, want in zip(*(jax.tree_util.tree_leaves(f(variables["params"]))
+                           for f in (recomputed, plain))):
+        close(got, want, 1e-5)
