@@ -128,7 +128,8 @@ def last_wire_plan() -> Optional[tuple]:
 
 
 def record_flash_plan(live: int, masked: int, bwd_sub_tiles: int,
-                      bwd_skipped: int) -> float:
+                      bwd_skipped: int,
+                      grid_steps: Optional[int] = None) -> float:
     """Record how the latest traced flash-attention call splits its work
     (trace time, once per compile — same reasoning as record_wire_plan;
     ``ops.flash_attention.block_census`` counts all four). ``live``: block
@@ -138,7 +139,12 @@ def record_flash_plan(live: int, masked: int, bwd_sub_tiles: int,
     attention. ``bwd_sub_tiles``: the sub-tiles the backward's two kernels
     walk those live blocks in; ``bwd_skipped``: those wholly above the
     diagonal, which they never compute. The second gauge is their share: 0
-    for non-causal attention, largest for one block per row."""
+    for non-causal attention, largest for one block per row. ``grid_steps``
+    (causal-dense calls alone; a windowed or non-causal call leaves the
+    third gauge as it was): the steps a head of the forward's grid really
+    holds, live or not. The third gauge is the share of them that run
+    nothing: 0 where the grid is the folded triangle of an even number of q
+    blocks or one block, ``1 / (nq + 1)`` for an odd number."""
     share = (live - masked) / max(1, live)
     registry().gauge(
         "horovod_flash_unmasked_block_share",
@@ -151,6 +157,12 @@ def record_flash_plan(live: int, masked: int, bwd_sub_tiles: int,
              "live blocks that lie wholly above the causal diagonal and are "
              "skipped"
     ).set(bwd_skipped / max(1, bwd_sub_tiles))
+    if grid_steps is not None:
+        registry().gauge(
+            "horovod_flash_dead_step_share",
+            help="share of the grid steps a head of the latest traced "
+                 "causal-dense flash forward holds that run nothing"
+        ).set((grid_steps - live) / max(1, grid_steps))
     return share
 
 

@@ -20,31 +20,48 @@ to the MXU in the dtype they arrive in and accumulate in f32 (``p`` and
 multiplies the f32 scores; ``exp``, the statistics and the mask are f32; the
 loaded tile is walked in unrolled sub-tiles; no vector moves between lanes
 and sublanes inside a block step, and no score-sized tile is transposed.
-- forward: grid (batch·head, q-block, k-block); scratch-carried online
-  (m, l, acc). The row statistics m and l are ``(block_q, 128)`` f32, one
-  row per sublane row and replicated along the lanes — the orientation of
-  the score tile's own rows. Sub-tiles of 256 x 512. Emits the per-row
+- forward: grid (batch·head, q-block, k-block), causal-dense calls the
+  folded triangle of it (below); scratch-carried online (m, l, acc). The
+  row statistics m and l are ``(block_q, 128)`` f32, one row per sublane
+  row and replicated along the lanes — the orientation of the score tile's
+  own rows. Sub-tiles of 256 x 512. Emits the per-row
   logsumexp residual L in a sublane-replicated ``(8, t)`` layout that
   satisfies TPU block tiling (one transpose per q block).
 - backward dQ: same grid, query-major like the forward; L and D are turned
   ONCE per q block into the same lane-replicated ``(block_q, 128)`` scratch;
   recomputes p = exp(s − L) per sub-tile and accumulates
   dQ = scale · Σ_k [p ∘ (dO·Vᵀ − D)] · K in scratch.
-- backward dK/dV: grid (batch·kv-head, k-block, group · q-block), KEY-major:
+- backward dK/dV: grid (batch·kv-head, k-block, group · q-block), folded
+  likewise over k blocks, KEY-major:
   the score sub-tile is sᵀ = K·Qᵀ (keys down the sublanes, queries along
   the lanes), so L and D are used as they are stored (along the lanes) and
   dV = Σ pᵀ·dO, dK = scale · Σ [pᵀ ∘ (V·dOᵀ − D)]·Q are plain a @ b
   products of the tile as it lies.
 (D = rowsum(dO ∘ O) is an elementwise reduction computed outside.)
 
-Causal programs run nothing above the diagonal: blocks wholly above it are
-skipped by ``pl.when``; blocks wholly below it build no mask; in the blocks
-it crosses (16 of 136 live blocks at 16k / 1024 / 1024, every block at 1k)
-sub-tiles wholly above it are skipped, those it crosses are masked and those
-below it are not — in all three kernels, the backward's in sub-tiles of
-256 x 256 there. ``horovod_flash_unmasked_block_share`` says how often the
-unmasked body engages and ``horovod_flash_bwd_skipped_subtile_share`` how
-much of the live blocks the backward never computes (:func:`block_census`).
+Causal programs hold, fetch and run nothing above the diagonal. The GRIDS of
+a causal-dense call hold the live block steps alone (a rectangle's other
+half, 120 of 256 steps a head at 16k / 1024 / 1024, were grid steps that
+fetched a K and a V block, or a Q and a dO block, to compute nothing): the
+triangle is folded, a grid row walks q block ``p`` over its k blocks and
+then q block ``nq - 1 - p`` over its own, ``nq // 2`` rows of ``ratio *
+(nq + 1)`` steps; dK/dV folds k blocks the same way, the group's sweep
+inside each half (:func:`_q_major_grid`, :func:`_k_major_grid`; the kernels
+decode a step with the index maps' functions). Per q block the k blocks
+still come in ascending order, per k block the (head, q block) sweep
+likewise: every accumulator adds the terms it added on the rectangle, in the
+same order. An odd ``nq``'s middle block has a row of its own whose second
+half is dead, its maps held at the last live block; one block a row is the
+plain grid; non-causal calls keep the rectangle (all of it is live) and
+windowed calls the band's grids. ``horovod_flash_dead_step_share`` counts the
+steps of the traced grid that run nothing. Blocks wholly below the diagonal
+build no mask; in the blocks it crosses (16 of 136 live blocks at 16k / 1024
+/ 1024, every block at 1k) sub-tiles wholly above it are skipped, those it
+crosses are masked and those below it are not — in all three kernels, the
+backward's in sub-tiles of 256 x 256 there.
+``horovod_flash_unmasked_block_share`` says how often the unmasked body
+engages and ``horovod_flash_bwd_skipped_subtile_share`` how much of the live
+blocks the backward never computes (:func:`block_census`).
 
 Pairs with the sequence-parallel schedules in ring_attention.py (which move
 K/V between chips); `causal_reference` is the oracle both are tested
@@ -142,18 +159,6 @@ def _band_k_block(qi, step, ratio, steps):
     return step + (qi + 1) * ratio - steps
 
 
-def _kv_spec(block_k, width, heads, band=None):
-    """The k / v ``BlockSpec`` of the forward's and dQ's grids ``(row, qi,
-    step)``; ``heads = (h, hkv, group)``. ``band = (ratio, steps)`` under a
-    window: the step is the band's (:func:`_band_k_block`), else the k
-    block itself."""
-    if band is None:
-        return pl.BlockSpec((1, block_k, width), lambda r, qi, ki: (
-            _kv_row(r, *heads), ki, 0))
-    return pl.BlockSpec((1, block_k, width), lambda r, qi, step: (
-        _kv_row(r, *heads), jnp.maximum(_band_k_block(qi, step, *band), 0), 0))
-
-
 def _band_offsets(t, block_q, block_k, window):
     """(crossed, inside): the ``first`` of every block step a windowed call
     of ``t`` positions meets, those an edge of the band crosses (the diagonal,
@@ -167,6 +172,157 @@ def _band_offsets(t, block_q, block_k, window):
     kinds = [_mask_offset(first, block_q, block_k, window) for first in offsets]
     return ([f for f, kind in zip(offsets, kinds) if kind not in (None, False)],
             [f for f, kind in zip(offsets, kinds) if kind is None])
+
+
+# ------------------------------------------------------- the grids' steps
+
+# A causal-dense call's live block steps are a triangle: q block ``i`` sees
+# ``ratio * (i + 1)`` k blocks, k block ``j`` is seen by ``nq - j // ratio``
+# q blocks. Block ``p`` and block ``n - 1 - p`` together hold the same number
+# of steps for every ``p``: a grid row walks one, then the other (the module's
+# docstring). Nothing but integer arithmetic on the grid's indices; the index
+# maps and the kernels decode a step with the same functions.
+
+def _div(a, b):
+    """``a // b`` of non-negative integers as ONE operation (``//`` on a
+    traced value is floor division's dozen, and an index map is traced and
+    lowered for every operand of every program: set-up time), none where
+    ``b`` is 1."""
+    return a if isinstance(b, int) and b == 1 else jax.lax.div(a, b)
+
+
+def _fold(p, s, n, head):
+    """Step ``s`` of row ``p`` of a triangle over ``n > 1`` blocks folded in
+    half: the row's first ``head`` steps are block ``p``'s, the others block
+    ``n - 1 - p``'s. -> (block, i, live): the step is the block's ``i``-th.
+    An odd ``n``'s middle block is its own partner: the second half of its
+    row is dead (``live`` False; True, the Python value, for an even ``n``)
+    and ``i`` stays at the block's last step there, so that an index map
+    fetches nothing."""
+    second = s >= head
+    block = jnp.where(second, n - 1 - p, p)
+    i = jnp.where(second, s - head, s)
+    if n % 2 == 0:
+        return block, i, True
+    live = jnp.logical_or(jnp.logical_not(second), block != p)
+    return block, jnp.where(live, i, head - 1), live
+
+
+def _q_fold(p, s, nq, ratio):
+    """Step ``s`` of row ``p`` of the forward's and dQ's folded grids ->
+    (qi, ki, live): a q block's k blocks in ascending order."""
+    return _fold(p, s, nq, ratio * (p + 1))
+
+
+def _k_fold(p, s, nq, ratio, group):
+    """Step ``s`` of row ``p`` of dK/dV's folded grid -> (ki, g, qi, i,
+    count, live): the ``i``-th of the ``count`` steps of k block ``ki``'s
+    sweep over the group's heads ``g`` and, inside a head, up the q blocks
+    ``qi`` from the one that holds its own positions on."""
+    ki, i, live = _fold(p, s, nq * ratio, group * (nq - _div(p, ratio)))
+    seen = nq - _div(ki, ratio)
+    g = _div(i, seen)
+    return ki, g, nq - seen + i - g * seen, i, group * seen, live
+
+
+def _folded(nq, causal, window):
+    """Whether a call's three grids are the folded triangle: causal-dense,
+    and more than one q block a row (one is its own partner)."""
+    return causal and window is None and nq > 1
+
+
+def _q_major_grid(t, block_q, block_k, causal, window):
+    """(rows, steps, q_block, k_block) of the forward's and dQ's grids ``(b *
+    h, rows, steps)``; ``q_block(row, step)`` and ``k_block(row, step)`` are
+    the blocks the index maps name there. Causal-dense: the folded triangle,
+    ``nq // 2`` rows of ``ratio * (nq + 1)`` steps, all live (an odd ``nq``
+    has one more row, half of it dead). Under a window: a q block a row and
+    the k blocks its band can touch (:func:`_band_k_block`; those before the
+    sequence are block 0 again). Else the rectangle, all of it live."""
+    nq, ratio = t // block_q, block_q // block_k
+    if window is not None:
+        steps = _band_steps(block_q, block_k, window)[0]
+        return (nq, steps, lambda qi, step: qi, lambda qi, step: jnp.maximum(
+            _band_k_block(qi, step, ratio, steps), 0))
+    if _folded(nq, causal, window):
+        return ((nq + 1) // 2, ratio * (nq + 1),
+                lambda p, s: _q_fold(p, s, nq, ratio)[0],
+                lambda p, s: _q_fold(p, s, nq, ratio)[1])
+    return nq, t // block_k, lambda qi, ki: qi, lambda qi, ki: ki
+
+
+def _k_major_grid(t, block_q, block_k, group, causal, window):
+    """(rows, steps, k_block, head, q_block) of dK/dV's grid ``(b * hkv,
+    rows, steps)``: the k block, the head of its group and the q block the
+    index maps name at ``(row, step)``. Causal-dense: the folded triangle,
+    ``nk // 2`` rows of ``group * (nq + 1)`` steps. Under a window: a k block
+    a row and, a head, the q blocks its band can touch from its own on (those
+    past the sequence are the last one again). Else the rectangle."""
+    nq, nk, ratio = t // block_q, t // block_k, block_q // block_k
+    if window is not None:
+        q_steps = _band_steps(block_q, block_k, window)[1]
+        return (nk, group * q_steps, lambda ki, j: ki,
+                lambda ki, j: j // q_steps, lambda ki, j: jnp.minimum(
+                    j % q_steps + ki // ratio, nq - 1))
+    if _folded(nq, causal, window):
+        def at(p, s):
+            return _k_fold(p, s, nq, ratio, group)
+
+        return ((nk + 1) // 2, group * (nq + 1), lambda p, s: at(p, s)[0],
+                lambda p, s: at(p, s)[1], lambda p, s: at(p, s)[2])
+    return (nk, group * nq, lambda ki, j: ki, lambda ki, j: j // nq,
+            lambda ki, j: j % nq)
+
+
+def _q_major_step(row, step, block_q, block_k, nk, causal, window):
+    """Where the forward's and dQ's kernels stand at ``(row, step)`` of their
+    grid: (qi, ki, i, count): the step is q block ``qi``'s ``i``-th of
+    ``count``, the first ``_init``'s and the last ``_finalize``'s. ``nk``:
+    the k blocks of a row. Under a window (``nk``: those the band can touch)
+    the step is the band's, and the kernels place it."""
+    ratio = block_q // block_k
+    if not _folded(nk // ratio, causal, window):
+        return row, step, step, nk
+    qi, ki, live = _q_fold(row, step, nk // ratio, ratio)
+    if live is not True:    # the middle row's dead half: above the diagonal
+        ki = jnp.where(live, ki, nk)
+    return qi, ki, ki, ratio * (qi + 1)
+
+
+def _k_major_step(row, j, block_q, block_k, nq, group, causal, window):
+    """Where dK/dV's kernel stands at ``(row, j)`` of its grid: (qi, ki, i,
+    count): the step is k block ``ki``'s ``i``-th of ``count``. ``nq``: the q
+    blocks of a column, under a window those its band can touch, from the one
+    that holds the k block's own positions on."""
+    ratio = block_q // block_k
+    if not _folded(nq, causal, window):
+        qi = j % nq if window is None else j % nq + row // ratio
+        return qi, row, j, nq * group
+    ki, _, qi, i, count, live = _k_fold(row, j, nq, ratio, group)
+    if live is not True:    # the middle row's dead half: above the diagonal
+        ki, i = jnp.where(live, ki, nq * ratio), jnp.where(live, i, -1)
+    return qi, ki, i, count
+
+
+def _q_major_specs(t, block_q, block_k, heads, causal, window):
+    """((rows, steps), q_spec, kv_spec, stat_spec) of the forward's and dQ's
+    grids (:func:`_q_major_grid`): ``q_spec(width)`` for q, o, dO and dq,
+    ``kv_spec(width)`` for k and v at the q row's key/value head (``heads =
+    (h, hkv, group)``), ``stat_spec`` for lse and delta."""
+    rows, steps, q_block, k_block = _q_major_grid(t, block_q, block_k, causal,
+                                                  window)
+
+    def q_spec(width):
+        return pl.BlockSpec((1, block_q, width), lambda r, p, s: (
+            r, q_block(p, s), 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, block_k, width), lambda r, p, s: (
+            _kv_row(r, *heads), k_block(p, s), 0))
+
+    stat_spec = pl.BlockSpec((1, 8, block_q), lambda r, p, s: (
+        r, 0, q_block(p, s)))
+    return (rows, steps), q_spec, kv_spec, stat_spec
 
 
 # ------------------------------------------------------------------- forward
@@ -203,8 +359,9 @@ def _sub_tile(block, want):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                 block_q, block_k, nk, causal, sm_scale, window=None, t=None):
-    qi = pl.program_id(1)
-    ki = step = pl.program_id(2)
+    qi, ki, step, steps = _q_major_step(
+        pl.program_id(1), pl.program_id(2), block_q, block_k, nk, causal,
+        window)
     d = v_ref.shape[-1]     # the accumulator's width: v's, not q's
     ratio = block_q // block_k
     # The loaded tile is walked in (sub_q, sub_k) score sub-tiles: row groups
@@ -287,7 +444,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     else:
         below()
 
-    @pl.when(step == nk - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         l = l_ref[...]
         o_ref[0] = (acc_ref[0] / _lanes(l, d)).astype(o_ref.dtype)
@@ -426,8 +583,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     """Query-major: a score sub-tile has its queries down the sublanes, and
     lse and delta are read from lane-replicated scratch, as the forward
     keeps m and l."""
-    qi = pl.program_id(1)
-    ki = step = pl.program_id(2)
+    qi, ki, step, steps = _q_major_step(
+        pl.program_id(1), pl.program_id(2), block_q, block_k, nk, causal,
+        window)
     if window is not None:      # the forward's grid: the band's k blocks
         ki = _band_k_block(qi, step, block_q // block_k, nk)
 
@@ -459,7 +617,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     _walk(causal, True, qi, ki, block_q, block_k, tile, add, window, t)
 
-    @pl.when(step == nk - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         dq_ref[0] = (dq_acc_ref[0] * sm_scale).astype(dq_ref.dtype)
 
@@ -472,19 +630,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     v . dO^T) and a @ b (pT @ dO, dsT @ q), none with a transposed left
     operand, and lse and delta are used as they are stored: along the lanes,
     broadcast down the sublanes."""
-    ki = pl.program_id(1)
-    # Innermost grid dim walks (g, qi): for GQA (group > 1) the same
+    # The innermost grid dim walks (g, qi): for GQA (group > 1) the same
     # k/v-head block accumulates gradient contributions from every q head
-    # in its group — the grid dim 0 row is a KV row, and j sweeps the
-    # group's q blocks. group == 1 reduces to the plain j == qi walk.
-    j = pl.program_id(2)
-    qi = j % nq
-    if window is not None:
-        # ``nq`` is the q blocks a k block's band can touch, from the one
-        # that holds the k block's own positions on
-        qi = qi + ki // (block_q // block_k)
+    # in its group — the grid dim 0 row is a KV row, and the sweep runs over
+    # the group's q blocks. group == 1 reduces to the plain walk over qi.
+    qi, ki, step, steps = _k_major_step(
+        pl.program_id(1), pl.program_id(2), block_q, block_k, nq, group,
+        causal, window)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
@@ -509,7 +663,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 
     _walk(causal, False, qi, ki, block_q, block_k, tile, add, window, t)
 
-    @pl.when(j == nq * group - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         dk_ref[0] = (dk_acc_ref[0] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[0].astype(dv_ref.dtype)
@@ -588,12 +742,18 @@ def _kv_row(r, h, hkv, group):
     return (r // h) * hkv + (r % h) // group
 
 
+def _group_q_row(r, h, hkv, group):
+    """Inverse of :func:`_kv_row` for the dK/dV grids: the first head of
+    kv-row ``r``'s group is q-row b*h + kv_head*group, its head ``g`` the
+    ``g``-th after it. The single definition keeps the group layout in one
+    place — the two must stay inverses."""
+    return (r // hkv) * h + (r % hkv) * group
+
+
 def _q_row(r, j, nq, h, hkv, group):
-    """Inverse walk for the dK/dV grids: kv-row ``r`` with innermost grid
-    index ``j`` sweeping (g, qi) maps to q-row b*h + kv_head*group + g.
-    The single definition keeps the group layout in one place with
-    :func:`_kv_row` — the two must stay inverses."""
-    return (r // hkv) * h + (r % hkv) * group + j // nq
+    """The q-row at a rectangular grid's innermost index ``j``, which sweeps
+    (g, qi) over ``nq`` q blocks a head."""
+    return _group_q_row(r, h, hkv, group) + j // nq
 
 
 def _plan(t, block_q, block_k, interpret, window):
@@ -657,7 +817,9 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, sm_scale, window):
     t = q.shape[1]
     block_q, block_k, window = _plan(t, block_q, block_k, interpret, window)
     census = block_census(t, block_q, block_k, causal, window)
-    record_flash_plan(*census)
+    rows, steps = _q_major_grid(t, block_q, block_k, causal, window)[:2]
+    record_flash_plan(*census, grid_steps=(
+        rows * steps if causal and window is None else None))
     if window is not None:
         record_flash_window_plan(
             census[0], block_census(t, block_q, block_k, causal)[0])
@@ -678,29 +840,19 @@ def _fwd_call(q, k, v, causal, block_q, block_k, interpret, sm_scale,
     block_q, block_k = _check_blocks(t, block_q, block_k, interpret)
     qr = _rows(q, b, t, h, d)
     kr, vr = _rows(k, b, t, hkv, d), _rows(v, b, t, hkv, dv)
-    nk, banded, band = t // block_k, {}, None
-    if window is not None:
-        nk = _band_steps(block_q, block_k, window)[0]
-        banded, band = dict(window=window, t=t), (block_q // block_k, nk)
+    (rows, steps), q_spec, kv_spec, stat_spec = _q_major_specs(
+        t, block_q, block_k, (h, hkv, group), causal, window)
+    banded = {} if window is None else dict(window=window, t=t)
     kernel = functools.partial(
-        _fwd_kernel, block_q=block_q, block_k=block_k, nk=nk, causal=causal,
+        _fwd_kernel, block_q=block_q, block_k=block_k,
+        nk=t // block_k if window is None else steps, causal=causal,
         sm_scale=d ** -0.5 if sm_scale is None else sm_scale, **banded)
-
-    def kv_spec(width):
-        return _kv_spec(block_k, width, (h, hkv, group), band)
 
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, t // block_q, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda r, qi, ki: (r, qi, 0)),
-            kv_spec(d),
-            kv_spec(dv),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda r, qi, ki: (r, qi, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda r, qi, ki: (r, 0, qi)),
-        ],
+        grid=(b * h, rows, steps),
+        in_specs=[q_spec(d), kv_spec(d), kv_spec(dv)],
+        out_specs=[q_spec(dv), stat_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, t, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, t), jnp.float32),
@@ -739,31 +891,21 @@ def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, window, res,
     delta = jnp.sum(dor.astype(jnp.float32) * outr.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[:, None, :], (b * h, 8, t))
 
-    nq, nk = t // block_q, t // block_k
     common = dict(block_q=block_q, block_k=block_k, causal=causal,
                   sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
-    ratio = block_q // block_k
-    k_steps, q_steps, band = nk, nq, None       # the grids' inner extents
+    k_steps, q_steps = t // block_k, t // block_q   # a row's blocks, a column's
     if window is not None:
         k_steps, q_steps = _band_steps(block_q, block_k, window)
         common.update(window=window, t=t)
-        band = (ratio, k_steps)
 
-    def kv_spec(width):
-        return _kv_spec(block_k, width, (h, hkv, group), band)
-
+    (rows, steps), q_spec, kv_spec, stat_spec = _q_major_specs(
+        t, block_q, block_k, (h, hkv, group), causal, window)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, nk=k_steps, **common),
-        grid=(b * h, nq, k_steps),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda r, qi, ki: (r, qi, 0)),
-            kv_spec(d),
-            kv_spec(dv),
-            pl.BlockSpec((1, block_q, dv), lambda r, qi, ki: (r, qi, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda r, qi, ki: (r, 0, qi)),
-            pl.BlockSpec((1, 8, block_q), lambda r, qi, ki: (r, 0, qi)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda r, qi, ki: (r, qi, 0)),
+        grid=(b * h, rows, steps),
+        in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv), stat_spec,
+                  stat_spec],
+        out_specs=q_spec(d),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((1, block_q, d), jnp.float32),        # dq acc
@@ -777,28 +919,25 @@ def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, window, res,
     # dK/dV: one grid row per KV row; the innermost dim sweeps (g, qi) so a
     # shared kv head accumulates all of its group's q-head contributions in
     # scratch before writing out (grid dim 0 = b*hkv, not b*h).
-    def q_row(r, j):
-        return _q_row(r, j, q_steps, h, hkv, group)
+    rows, steps, k_block, head, q_block = _k_major_grid(
+        t, block_q, block_k, group, causal, window)
 
-    def q_block(ki, j):
-        if window is None:
-            return j % nq
-        # the band's q blocks of this k block, from its own on; those past
-        # the sequence are the last one again, and nothing is fetched
-        return jnp.minimum(j % q_steps + ki // ratio, nq - 1)
+    def q_row(r, p, s):
+        return _group_q_row(r, h, hkv, group) + head(p, s)
 
     def qd(width):
-        return pl.BlockSpec((1, block_q, width),
-                            lambda r, ki, j: (q_row(r, j), q_block(ki, j), 0))
+        return pl.BlockSpec((1, block_q, width), lambda r, p, s: (
+            q_row(r, p, s), q_block(p, s), 0))
 
     def kd(width):
-        return pl.BlockSpec((1, block_k, width), lambda r, ki, j: (r, ki, 0))
+        return pl.BlockSpec((1, block_k, width), lambda r, p, s: (
+            r, k_block(p, s), 0))
 
-    row = pl.BlockSpec((1, 8, block_q),
-                       lambda r, ki, j: (q_row(r, j), 0, q_block(ki, j)))
+    row = pl.BlockSpec((1, 8, block_q), lambda r, p, s: (
+        q_row(r, p, s), 0, q_block(p, s)))
     dk, dv_rows = pl.pallas_call(
         functools.partial(_dkv_kernel, nq=q_steps, group=group, **common),
-        grid=(b * hkv, nk, q_steps * group),
+        grid=(b * hkv, rows, steps),
         in_specs=[qd(d), kd(d), kd(dv), qd(dv), row, row],
         out_specs=[kd(d), kd(dv)],
         out_shape=[

@@ -92,6 +92,27 @@ BODY_CASES = {
     "block_no_sub_tile_divides": (768, 1, 1, 16, 384, 384, True,
                                   jnp.float32),
     "f32_at_512_blocks": (1024, 1, 1, 16, 512, 512, True, jnp.float32),
+    # the folded causal-dense grids (a row walks q block p, then nq - 1 - p;
+    # dK/dV the same over k blocks): an even count of q blocks ...
+    "folded_eight_q_blocks": (256, 2, 2, 32, 32, 32, True, jnp.float32),
+    # ... an ODD one, whose middle block has a row of its own, half of it dead
+    "folded_odd": (96, 2, 2, 32, 32, 32, True, jnp.float32),
+    "folded_five_q_blocks_bf16": (160, 2, 2, 32, 32, 32, True, jnp.bfloat16),
+    # block_q = 2 x block_k: q blocks fold in threes, k blocks in sixes
+    "folded_odd_crosses_two": (192, 2, 2, 32, 64, 32, True, jnp.float32),
+    "folded_crosses_two": (256, 2, 2, 32, 64, 32, True, jnp.float32),
+    # block_q = 3 x block_k at 3 q blocks: 9 k blocks, the middle one alone
+    "folded_odd_crosses_three": (288, 1, 1, 16, 96, 32, True, jnp.float32),
+    # grouped-query heads: each half of a dK/dV row sweeps the group's heads
+    "folded_gqa_odd": (96, 4, 2, 32, 32, 32, True, jnp.float32),
+    "folded_gqa_six_over_one": (128, 6, 1, 32, 64, 32, True, jnp.float32),
+    "folded_gqa_d64_scale": (160, 8, 2, 64, 32, 32, True, jnp.bfloat16, 64,
+                             0.015625),
+    # latent attention's 192 | 128 over an even and an odd count
+    "folded_192_128": (128, 2, 2, 48, 32, 32, True, jnp.float32, 32),
+    "folded_192_128_odd": (96, 2, 2, 48, 32, 32, True, jnp.float32, 32),
+    "folded_sub_tiles_192_128_odd": (3072, 1, 1, 192, 1024, 1024, True,
+                                     jnp.bfloat16, 128),
 }
 
 
@@ -140,16 +161,35 @@ def test_both_bodies_match_oracle(case):
     (1024, 512, 512, True, 3, 2, 2),
     (128, 64, 32, True, 6, 4, 0),               # blocks under a sub-tile
     (96, 48, 48, True, 3, 2, 0),
+    (3072, 1024, 1024, True, 6, 3, 18),         # an odd count of q blocks
+    (160, 32, 32, True, 15, 5, 0),
 ])
 def test_block_census(t, block_q, block_k, causal, live, masked, skipped):
     """The census's closed form counts what the kernels' own predicates
     select over the grid; the backward's sub-tile counts, what a walk over
     every position of the crossed blocks finds."""
     from horovod_tpu.ops.flash_attention import (_BWD_SUB_CROSSED, _crossed,
+                                                 _q_major_grid, _q_major_step,
                                                  _sub_tile, block_census)
 
     got = block_census(t, block_q, block_k, causal)
     assert got[:2] == (live, masked) and got[3] == skipped
+    # ``live`` is what the grid the call runs holds of live steps: every
+    # step of it but an odd count's half row (none of a rectangle's, whose
+    # steps above the diagonal the causal-dense grids no longer hold)
+    rows, steps = _q_major_grid(t, block_q, block_k, causal, None)[:2]
+    row, step = (x.ravel() for x in np.meshgrid(
+        np.arange(rows, dtype=np.int32), np.arange(steps, dtype=np.int32),
+        indexing="ij"))
+    qi, ki, _, _ = _q_major_step(row, step, block_q, block_k, t // block_k,
+                                 causal, None)
+    ratio = block_q // block_k
+    runs = (np.ones(rows * steps, bool) if not causal
+            else np.asarray(ki) < (np.asarray(qi) + 1) * ratio)
+    assert runs.sum() == live
+    nq = t // block_q
+    assert rows * steps - live == (
+        steps // 2 if causal and nq > 1 and nq % 2 else 0)
     sub_q = _sub_tile(block_q, _BWD_SUB_CROSSED[0])
     sub_k = _sub_tile(block_k, _BWD_SUB_CROSSED[1])
     assert got[2] == live * (block_q // sub_q) * (block_k // sub_k)

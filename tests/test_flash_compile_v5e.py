@@ -27,8 +27,12 @@ from horovod_tpu.ops import grouped_matmul as gm
 from horovod_tpu.ops.flash_attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q,
                                              flash_attention)
 
-# (B, T, H, D) of lm217m_long_1chip and lm217m_short_1chip (bf16 activations)
-CELL_SHAPES = {"long": (1, 16384, 8, 128), "short": (16, 1024, 8, 128)}
+# (B, T, H, D) of lm217m_long_1chip and lm217m_short_1chip (bf16 activations);
+# olmoe_seq4096_1chip's four q blocks; and three q blocks, no cell's: the
+# folded causal-dense grid's odd case (the middle block's row, half of it
+# dead, its index maps clamped and its steps' predicates dynamic)
+CELL_SHAPES = {"long": (1, 16384, 8, 128), "short": (16, 1024, 8, 128),
+               "olmoe": (4, 4096, 16, 128), "odd": (1, 3072, 8, 128)}
 
 
 @pytest.fixture(scope="module")
@@ -359,8 +363,10 @@ SWA = dict(t=16384, kv_heads=8, head_dim=128, window=512)
     (64, SWA["window"], 2048, jnp.float32, "highest", (512, 512)),
     (64, SWA["window"], SWA["t"], jnp.bfloat16, None, (1024, 512)),
     (64, SWA["window"], SWA["t"], jnp.bfloat16, None, (512, 512)),
+    (48, None, 3072, jnp.bfloat16, None, (1024, 512)),
 ], ids=["sliding_64_over_8", "full_48_over_8", "sliding_check_leg_f32",
-        "sliding_block_q_twice_block_k", "sliding_blocks_of_the_window"])
+        "sliding_block_q_twice_block_k", "sliding_blocks_of_the_window",
+        "full_48_over_8_odd_block_q_twice_block_k"])
 def test_window_and_full_kernels_of_the_hybrid_compile_for_v5e(
         one_chip, no_persistent_cache, heads, window, t, dtype, precision,
         blocks):
