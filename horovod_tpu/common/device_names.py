@@ -28,7 +28,15 @@ MOE_EXPERTS = "hvd_moe_experts"         # the grouped SwiGLU products
 MOE_EXPERTS_GMM = "hvd_moe_experts_gmm"     # rows x weights: Y and dX
 MOE_EXPERTS_TGMM = "hvd_moe_experts_tgmm"   # rows^T x rows: dW
 MOE_COMBINE = "hvd_moe_combine"         # back to token order + weighted sum
-MOE_SHARED = "hvd_moe_shared"           # the shared SwiGLU expert every token takes
+MOE_SHARED = "hvd_moe_shared"           # the shared expert every token takes
+# Experts that live in a latent (models/moe.py, ``MoEMLP.latent``): the
+# projection down before the dispatch and the one up after the weighted sum.
+MOE_LATENT = "hvd_moe_latent"
+# The multi-token-prediction module's own work (models/transformer.py,
+# ``TransformerLM.mtp_layer_types``): its two norms, the concatenation, the
+# projection 2 dim -> dim, its final norm and its pass of the shared head
+# (``lm_loss_with_mtp``). Its blocks' work goes by the blocks' own names.
+MTP = "hvd_mtp"
 # Latent attention (models/transformer.py, ``Block.mla``). The benchmark finds
 # the mixer's time by the substrings ``hvd_mla`` and ``hvd_flash_``.
 MLA_PROJ = "hvd_mla_proj"               # q, kv-down, kv-up, o + the latent's norm

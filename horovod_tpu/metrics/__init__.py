@@ -41,6 +41,7 @@ from .overlap import (  # noqa: F401
     record_mamba_fused_passes,
     record_moe_dispatch_rows,
     record_moe_grouped_plan,
+    record_moe_live_rows,
     record_plan,
     record_shard_plan,
     record_ssd_plan,

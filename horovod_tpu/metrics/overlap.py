@@ -209,14 +209,24 @@ def record_moe_grouped_plan(border_overhead: float) -> None:
     ).set(border_overhead)
 
 
-def record_moe_dispatch_rows(rows: int) -> None:
+def record_moe_dispatch_rows(rows: int, row_bytes: int) -> None:
     """Record the sorted rows the passes of the latest traced
     ``ops.moe.dropless_experts`` visit (trace time, once per compile): ``N x
     top_k`` where the rank holds every expert; where it holds ``count`` of
     ``of``, its share ``N x top_k x count / of`` of them in whole windows
     (``ops.moe.held_window_rows``): what a balanced router makes the passes
     move. The rows a step really visits follow the routing (the layer sows
-    them as ``moe_live_rows``)."""
+    them as ``moe_live_rows``). ``row_bytes`` is what ONE dispatched row
+    holds (``horovod_moe_dispatch_row_bytes``): the width the experts live
+    in times the activations' itemsize - 4,096 for bf16 rows of a 2,048-wide
+    model, 2,048 where the experts live in a 1,024-wide latent. It is what
+    an expert-parallel ``all_to_all`` would carry a pair."""
+    registry().gauge(
+        "horovod_moe_dispatch_row_bytes",
+        help="bytes of one row the latest traced dropless_experts dispatches "
+             "(the experts' input width x the activations' itemsize); 0 = "
+             "none traced"
+    ).set(row_bytes)
     registry().gauge(
         "horovod_moe_dispatch_rows",
         help="sorted rows the passes of the latest traced dropless_experts "
@@ -224,6 +234,33 @@ def record_moe_dispatch_rows(rows: int) -> None:
              "expert held, the held share in whole windows else; 0 = none "
              "traced"
     ).set(rows)
+
+
+def record_moe_live_rows(live_rows, window: int) -> None:
+    """Record what the expert layers' passes REALLY visited in the steps a
+    training loop hands in: ``live_rows`` is (steps, layers) of the
+    ``moe_live_rows`` each layer sowed in each step, CONCRETE (read on the
+    host between steps, by a logging hook or a registry collector; never
+    from inside a jitted step). ``horovod_moe_live_rows_per_step`` is the
+    layers' sum a step, its mean over the steps;
+    ``horovod_moe_live_windows_per_step`` the windows of ``window`` rows that
+    hold them (one pass of every layer: the trip counts of the held path's
+    loops). Beside ``horovod_moe_dispatch_rows`` (a balanced router's rows a
+    layer, from shapes) they say how far the routing is from balance."""
+    steps = [[int(rows) for rows in step] for step in live_rows]
+    if not steps:
+        return
+    registry().gauge(
+        "horovod_moe_live_rows_per_step",
+        help="rows on the held experts that the expert layers' passes "
+             "visited a step (sum over layers, mean over the recorded steps)"
+    ).set(sum(map(sum, steps)) / len(steps))
+    registry().gauge(
+        "horovod_moe_live_windows_per_step",
+        help="windows of sorted rows holding those live rows, one pass of "
+             "every expert layer (mean over the recorded steps)"
+    ).set(sum(-(-rows // window) for step in steps for rows in step)
+          / len(steps))
 
 
 def record_ssd_plan(chunk: int) -> None:

@@ -1,9 +1,21 @@
-"""Flax MoE layer for the transformer, in two published forms.
+"""Flax MoE layer for the transformer, in the published forms of three
+families.
 
-NO capacity and no dropped pair under either; SwiGLU experts ``w_gate`` /
-``w_up`` / ``w_down`` of width ``hidden`` (a number of its own: 1024 = dim / 2
-in OLMoE-1B-7B, 768 in kanana-2-30b-a3b), computed as grouped products over
-the pairs sorted by expert (``ops.moe.dropless_experts``).
+NO capacity and no dropped pair under any; experts of width ``hidden`` (a
+number of its own: 1024 = dim / 2 in OLMoE-1B-7B, 768 in kanana-2-30b-a3b,
+2688 in Nemotron-3-Super), computed as grouped products over the pairs sorted
+by expert (``ops.moe.dropless_experts``). ``activation="swiglu"``: gated
+experts ``w_gate`` / ``w_up`` / ``w_down``, ``down(silu(gate x) * up x)``.
+``activation="relu2"`` (Nemotron-H's ``mlp_hidden_act``): experts WITHOUT a
+gate, ``w_up`` / ``w_down`` alone, ``down(relu(up x)^2)``; the shared expert
+takes the same activation as the routed ones.
+
+``latent`` > 0 (Nemotron-3's LatentMoE, ``moe_latent_size``): the routed
+experts live in a latent of that width. ``fc1_latent`` projects a token
+``dim -> latent`` BEFORE the dispatch, the experts are ``latent -> hidden ->
+latent``, and ``fc2_latent`` projects the weighted sum ``latent -> dim``
+after it: a dispatched row is ``latent`` wide (a quarter of the bytes at
+1,024 of 4,096). The router and the shared expert read the full hidden state.
 
 ``router="softmax"`` (OLMoE, arXiv:2409.02060): softmax, then the ``top_k``
 largest probabilities, not renormalised. ``router="sigmoid"`` (DeepSeek-V3,
@@ -12,8 +24,9 @@ arXiv:2412.19437 §2.1.2, ``noaux_tc`` with one group): sigmoid scores, the
 ``route_scale``; the bias is the variable ``router_bias`` of the collection
 ``moe_bias`` (not ``params``: no gradient, no optimizer, no weight decay),
 moved by the caller after each step (``ops.moe.router_bias_update``) from the
-``moe_expert_counts`` the layer sows. ``shared_hidden`` > 0 adds a SwiGLU
-expert of that width that every token takes, unweighted.
+``moe_expert_counts`` the layer sows. ``shared_hidden`` > 0 adds an expert
+of that width (and the layer's ``activation``) that every token takes,
+unweighted.
 
 A rank holds every expert (``held`` None: data-parallel replicas) or the
 experts ``[first, first + count)`` of the ``n_experts`` the router chooses
@@ -64,28 +77,43 @@ class MoEMLP(nn.Module):
     route_scale: float = 1.0
     shared_hidden: int = 0
     held: Optional[tuple] = None    # (first, count) of n_experts; None: all
+    # What a Nemotron-3 configuration states (the module docstring has the
+    # equations): experts without a gate, in a latent of this width.
+    activation: str = "swiglu"      # "swiglu" | "relu2"
+    latent: int = 0
 
     @nn.compact
     def __call__(self, x):
+        if self.activation not in ("swiglu", "relu2"):
+            raise ValueError(f"activation {self.activation!r}: 'swiglu' "
+                             f"(gated experts) or 'relu2' (experts without "
+                             f"a gate)")
         b, t, d = x.shape
         tokens = x.reshape(-1, d)
-        out = self._topk_swiglu(tokens)
+        out = self._routed(tokens)
         if self.shared_hidden:
             out = out + self._shared(tokens)
         return out.reshape(b, t, d)
 
     def _shared(self, tokens):
-        with jax.named_scope(device_names.MOE_SHARED):
-            gate, up = (nn.Dense(self.shared_hidden, use_bias=False,
-                                 dtype=self.dtype, name=name)(tokens)
-                        for name in ("shared_gate", "shared_up"))
-            return nn.Dense(tokens.shape[-1], use_bias=False, dtype=self.dtype,
-                            name="shared_down")(nn.silu(gate) * up)
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype, name=name)
 
-    def _topk_swiglu(self, tokens):
+        with jax.named_scope(device_names.MOE_SHARED):
+            if self.activation == "relu2":
+                hidden = jnp.square(nn.relu(
+                    dense(self.shared_hidden, "shared_up")(tokens)))
+            else:
+                gate, up = (dense(self.shared_hidden, name)(tokens)
+                            for name in ("shared_gate", "shared_up"))
+                hidden = nn.silu(gate) * up
+            return dense(tokens.shape[-1], "shared_down")(hidden)
+
+    def _routed(self, tokens):
         d, e, h = tokens.shape[-1], self.n_experts, self.hidden
         if not 0 < self.top_k <= e:
-            raise ValueError(f"top_k {self.top_k} of {e} experts")
+            raise ValueError(f"top_k {self.top_k} of {e} {self.activation} "
+                             f"experts")
         if self.router not in ("softmax", "sigmoid"):
             raise ValueError(f"router {self.router!r}: 'softmax' or 'sigmoid'")
         first, here = (0, e) if self.held is None else self.held
@@ -95,10 +123,13 @@ class MoEMLP(nn.Module):
                             jnp.float32)
         # the expert axis is a batch axis: each expert's fan-in is its own
         init = nn.initializers.lecun_normal(batch_axis=(0,))
-        w_gate, w_up, w_down = (
-            self.param(name, init, shape, jnp.float32).astype(self.dtype)
-            for name, shape in (("w_gate", (here, d, h)), ("w_up", (here, d, h)),
-                                ("w_down", (here, h, d))))
+        width = self.latent or d    # what the experts read and write
+        shapes = {"w_gate": (here, width, h), "w_up": (here, width, h),
+                  "w_down": (here, h, width)}
+        if self.activation == "relu2":
+            del shapes["w_gate"]    # experts without a gate
+        experts_w = {name: self.param(name, init, shape, jnp.float32).astype(
+            self.dtype) for name, shape in shapes.items()}
         # The router runs in float32 at full precision whatever the
         # activations' dtype: 2*N*D*E operations, and a coarser product
         # flips a token's 8th expert against its 9th far more often.
@@ -120,9 +151,20 @@ class MoEMLP(nn.Module):
             self.sow("intermediates", "moe_z_loss", router_z_loss(logits))
         self.sow("intermediates", "moe_router_logits", logits)
         self.sow("intermediates", "moe_chosen_experts", experts)
-        return dropless_experts(tokens.astype(self.dtype), weights, experts,
-                                w_gate, w_up, w_down, self.interpret,
-                                None if self.held is None else (first, here, e))
+        tokens = tokens.astype(self.dtype)
+        if self.latent:
+            with jax.named_scope(device_names.MOE_LATENT):
+                tokens = nn.Dense(self.latent, use_bias=False, dtype=self.dtype,
+                                  name="fc1_latent")(tokens)
+        out = dropless_experts(
+            tokens, weights, experts, experts_w.get("w_gate"),
+            experts_w["w_up"], experts_w["w_down"], self.interpret,
+            None if self.held is None else (first, here, e))
+        if self.latent:
+            with jax.named_scope(device_names.MOE_LATENT):
+                out = nn.Dense(d, use_bias=False, dtype=self.dtype,
+                               name="fc2_latent")(out)
+        return out
 
 
 def aux_losses(intermediates):

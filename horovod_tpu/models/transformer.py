@@ -210,22 +210,25 @@ class Block(nn.Module):
     window: Optional[int] = None
     rotary: Optional[RotaryScheme] = None
     attn_gate: bool = False
+    # What a Nemotron-H-family configuration states (TransformerLM documents
+    # them): a layer that is ONE sub-layer, ``x + f(norm x)`` with f the
+    # mixer alone ("mixer") or the experts alone ("mlp") where every other
+    # model's block is both, one after the other ("both"); the experts'
+    # activation and the latent they live in (models/moe.py).
+    sublayers: str = "both"
+    moe_activation: str = "swiglu"
+    moe_latent: int = 0
 
     @nn.compact
     def __call__(self, x, positions):
         if self.attention not in ("dense", "flash"):
             raise ValueError(
                 f"unknown attention={self.attention!r}; use 'dense' or 'flash'")
-        h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
-        if self.mamba is not None:
-            mixed = Mamba2Mixer(dim=self.dim, dims=self.mamba,
-                                rms_norm_eps=self.rms_norm_eps, dtype=self.dtype,
-                                interpret=self.flash_interpret, name="mixer")(h)
-        elif self.mla is not None:
-            mixed = self._latent_attention(h, positions)
-        else:
-            mixed = self._attention(h, positions)
-        x = self._add(x, mixed)
+        self._check_halves()
+        if self.sublayers != "mlp":
+            x = self._add(x, self._mixer(x, positions))
+        if self.sublayers == "mixer":
+            return x
         h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
         if self.moe_experts > 0:
             from .moe import MoEMLP
@@ -238,6 +241,7 @@ class Block(nn.Module):
                 interpret=self.flash_interpret, router=self.moe_router,
                 route_scale=self.moe_route_scale,
                 shared_hidden=self.moe_shared_hidden, held=self.moe_held,
+                activation=self.moe_activation, latent=self.moe_latent,
                 name="moe")(h))
         if self.mlp_hidden is not None:
             gate, up = (nn.Dense(self.mlp_hidden, use_bias=False,
@@ -249,6 +253,38 @@ class Block(nn.Module):
         h = nn.Dense(self.mlp_ratio * self.dim, use_bias=False, dtype=self.dtype, name="mlp_in")(h)
         h = nn.gelu(h)
         return self._add(x, nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="mlp_out")(h))
+
+    def _check_halves(self):
+        """A size stated for a half this layer does not have is an error,
+        not something to ignore."""
+        if self.sublayers not in ("both", "mixer", "mlp"):
+            raise ValueError(f"sublayers {self.sublayers!r}: 'both', 'mixer' "
+                             f"or 'mlp'")
+        stated = {"mixer": {"mlp_hidden": self.mlp_hidden is not None,
+                            "moe_experts": self.moe_experts > 0},
+                  "mlp": {"mamba": self.mamba is not None,
+                          "mla": self.mla is not None},
+                  "both": {}}[self.sublayers]
+        stated["moe_shared_hidden"] = (self.moe_shared_hidden > 0
+                                       and self.moe_experts <= 0)
+        extra = sorted(k for k, v in stated.items() if v)
+        if extra:
+            raise ValueError(
+                f"{', '.join(extra)} stated for a layer (sublayers="
+                f"{self.sublayers!r}, moe_experts={self.moe_experts}) that "
+                f"has no such half")
+
+    def _mixer(self, x, positions):
+        """The mixer's branch of the normed ``x``: a Mamba-2 mixer, latent
+        attention or multi-head attention."""
+        h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
+        if self.mamba is not None:
+            return Mamba2Mixer(dim=self.dim, dims=self.mamba,
+                               rms_norm_eps=self.rms_norm_eps, dtype=self.dtype,
+                               interpret=self.flash_interpret, name="mixer")(h)
+        if self.mla is not None:
+            return self._latent_attention(h, positions)
+        return self._attention(h, positions)
 
     def _add(self, x, branch):
         if self.residual_scale != 1.0:
@@ -410,6 +446,11 @@ class Block(nn.Module):
             return dense(self.dim, "o_proj")(attn.reshape(b, t, heads * m.v))
 
 
+# layer_types' names for a layer that is ONE sub-layer -> Block.sublayers
+_ONE_SUBLAYER = {"mamba_only": "mixer", "attention_only": "mixer",
+                 "experts_only": "mlp"}
+
+
 class TransformerLM(nn.Module):
     # TPU sizing note (docs/benchmarks.md "head_dim and the MXU"): prefer
     # head_dim = dim // heads >= 128 where the architecture is yours to
@@ -541,6 +582,26 @@ class TransformerLM(nn.Module):
     full_rotary: Optional[RotaryScheme] = None
     sliding_rotary: Optional[RotaryScheme] = None
     attn_gate: bool = False
+    # A Nemotron-H-family hybrid (Nemotron-3-Super: docs/latent-moe.md), each
+    # as the model's own configuration states it. layer_types may also name
+    # "mamba_only" / "attention_only" / "experts_only" (the pattern's M, * and
+    # E): a layer that is ONE sub-layer, x + f(RMSNorm x), f a Mamba-2 mixer,
+    # an attention or the experts, with no second half; an "experts_only"
+    # layer takes the experts whatever moe_every says, and needs moe_experts
+    # > 0. moe_activation "relu2": experts (and the shared expert) without a
+    # gate, down(relu(up x)^2). moe_latent > 0: the routed experts live in a
+    # latent of that width (models/moe.py). mtp_layer_types: a
+    # multi-token-prediction module of depth 1 (arXiv:2412.19437 §2.2) whose
+    # blocks are of these kinds: h' = W [RMSNorm(h_t) ; RMSNorm(E[x_{t+1}])]
+    # (2 dim -> dim) with h the main model's last residual stream (before its
+    # final norm) and E the model's OWN embedding, through the blocks, a
+    # final norm. The call then returns a PAIR, the main model's result and
+    # the module's (hidden states with return_hidden, else logits through
+    # the SAME head), position t of the module's standing for x_{t+2}:
+    # ``lm_loss_with_mtp`` takes both losses.
+    moe_activation: str = "swiglu"
+    moe_latent: int = 0
+    mtp_layer_types: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden: bool = False):
@@ -548,14 +609,28 @@ class TransformerLM(nn.Module):
             positions = jnp.arange(tokens.shape[1])[None, :]
         kinds = (("attention",) * self.layers if self.layer_types is None
                  else tuple(self.layer_types))
-        if len(kinds) != self.layers or set(kinds) - {
-                "attention", "mamba", "full_attention", "sliding_attention"}:
+        mtp_kinds = tuple(self.mtp_layer_types or ())
+        if len(kinds) != self.layers or set(kinds + mtp_kinds) - {
+                "attention", "mamba", "full_attention", "sliding_attention",
+                *_ONE_SUBLAYER}:
             raise ValueError(
-                f"layer_types {kinds} must name 'attention', 'mamba', "
-                f"'full_attention' or 'sliding_attention' for each of the "
+                f"layer_types {kinds} (mtp_layer_types {mtp_kinds}) must name "
+                f"'attention', 'mamba', 'full_attention', 'sliding_attention' "
+                f"or, for a layer that is one sub-layer, 'mamba_only', "
+                f"'attention_only' or 'experts_only' for each of the "
                 f"{self.layers} layers")
-        if "mamba" in kinds and self.mamba is None:
+        if {"mamba", "mamba_only"} & set(kinds + mtp_kinds) and self.mamba is None:
             raise ValueError("a 'mamba' layer needs the mixer's sizes (mamba=)")
+        if "experts_only" in kinds + mtp_kinds and self.moe_experts <= 0:
+            raise ValueError("an 'experts_only' layer needs its experts "
+                             "(moe_experts=, moe_top_k=)")
+        if self.mlp_hidden is not None and not set(kinds) - set(_ONE_SUBLAYER):
+            raise ValueError(
+                f"mlp_hidden={self.mlp_hidden} but no layer of {kinds} has a "
+                f"dense MLP half")
+        if mtp_kinds and self.tie_embeddings:
+            raise ValueError("the multi-token-prediction module shares an "
+                             "untied head: tie_embeddings must be False")
         if "sliding_attention" in kinds and self.sliding_window is None:
             raise ValueError("a 'sliding_attention' layer needs its window "
                              "(sliding_window=)")
@@ -581,10 +656,16 @@ class TransformerLM(nn.Module):
             block_cls = nn.remat(Block, policy=(
                 jax.checkpoint_policies.save_only_these_names(CHOSEN_EXPERTS)
                 if self.moe_experts > 0 else None))
-        for i in range(self.layers):
-            x = block_cls(
+
+        def block(kind, name, heads, second_is_experts):
+            """One layer of ``kind``; ``second_is_experts``: whether a layer
+            with both halves takes the experts for its second."""
+            sublayers = _ONE_SUBLAYER.get(kind, "both")
+            experts = (self.moe_experts if sublayers == "mlp"
+                       or (sublayers == "both" and second_is_experts) else 0)
+            return block_cls(
                 dim=self.dim,
-                heads=heads[i],
+                heads=heads,
                 mlp_ratio=self.mlp_ratio,
                 dtype=self.dtype,
                 sp_axis=self.sp_axis,
@@ -593,36 +674,64 @@ class TransformerLM(nn.Module):
                 block_q=self.block_q,
                 block_k=self.block_k,
                 flash_interpret=self.flash_interpret,
-                moe_experts=(self.moe_experts
-                             if self.moe_experts > 0 and i % self.moe_every == self.moe_every - 1
-                             and i >= self.first_k_dense
-                             else 0),
+                moe_experts=experts,
                 moe_top_k=self.moe_top_k,
                 moe_hidden=self.moe_hidden,
                 qk_norm=self.qk_norm,
                 rms_norm_eps=self.rms_norm_eps,
-                mamba=self.mamba if kinds[i] == "mamba" else None,
-                mlp_hidden=self.mlp_hidden,
+                mamba=(self.mamba if kind in ("mamba", "mamba_only")
+                       else None),
+                mlp_hidden=self.mlp_hidden if sublayers == "both" else None,
                 rope=self.rope,
                 attention_scale=self.attention_multiplier,
                 residual_scale=self.residual_multiplier,
-                mla=self.mla,
+                mla=self.mla if sublayers != "mlp" else None,
                 rope_theta=self.rope_theta,
                 rope_interleave=self.rope_interleave,
                 moe_router=self.moe_router,
                 moe_route_scale=self.moe_route_scale,
-                moe_shared_hidden=self.moe_shared_hidden,
+                moe_shared_hidden=self.moe_shared_hidden if experts else 0,
                 moe_held=self.moe_held,
                 head_dim=self.head_dim,
-                window=(self.sliding_window if kinds[i] == "sliding_attention"
+                window=(self.sliding_window if kind == "sliding_attention"
                         else None),
-                rotary=rotary.get(kinds[i]),
+                rotary=rotary.get(kind),
                 attn_gate=self.attn_gate,
-                name=f"block_{i}",
-            )(x, positions)
+                sublayers=sublayers,
+                moe_activation=self.moe_activation,
+                moe_latent=self.moe_latent,
+                name=name,
+            )
+
+        for i in range(self.layers):
+            x = block(kinds[i], f"block_{i}", heads[i],
+                      self.moe_experts > 0
+                      and i % self.moe_every == self.moe_every - 1
+                      and i >= self.first_k_dense)(x, positions)
+        if mtp_kinds:
+            with jax.named_scope(device_names.MTP):
+                following = embed(jnp.roll(tokens, -1, axis=1))
+                if self.embedding_multiplier != 1.0:
+                    following = following * jnp.asarray(
+                        self.embedding_multiplier, x.dtype)
+                y = nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
+                             name="mtp_proj")(jnp.concatenate([
+                                 nn.RMSNorm(epsilon=self.rms_norm_eps,
+                                            dtype=self.dtype, name=name)(part)
+                                 for name, part in (
+                                     ("mtp_hidden_norm", x),
+                                     ("mtp_embed_norm", following))], axis=-1))
+            for j, kind in enumerate(mtp_kinds):
+                y = block(kind, f"mtp_block_{j}", self.heads, False)(
+                    y, positions)
+            with jax.named_scope(device_names.MTP):
+                y = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype,
+                               name="mtp_norm")(y)
         x = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
         if self.logits_scaling != 1.0:
             x = x / jnp.asarray(self.logits_scaling, x.dtype)
+            if mtp_kinds:
+                y = y / jnp.asarray(self.logits_scaling, y.dtype)
         if self.tie_embeddings:
             if return_hidden:
                 return x
@@ -637,8 +746,8 @@ class TransformerLM(nn.Module):
             # in sequence chunks with chunked_lm_loss.
             if self.is_initializing():
                 head(x[:, :1])  # param tree must not depend on the flag
-            return x
-        return head(x)
+            return (x, y) if mtp_kinds else x
+        return (head(x), head(y)) if mtp_kinds else head(x)
 
 
 def chunked_lm_loss(hidden, head_kernel, targets, chunk: int = 2048):
@@ -672,6 +781,23 @@ def chunked_lm_loss(hidden, head_kernel, targets, chunk: int = 2048):
     if t % chunk:
         raise ValueError(f"sequence {t} not divisible by loss chunk {chunk}")
     return _chunked_lm_loss(hidden, head_kernel, targets, chunk)
+
+
+def lm_loss_with_mtp(hidden, mtp_hidden, head_kernel, tokens,
+                     mtp_weight: float, chunk: int = 2048):
+    """``(L_main + mtp_weight * L_mtp, (L_main, L_mtp))`` of a model with a
+    multi-token-prediction module (``TransformerLM.mtp_layer_types``; both
+    hidden states from ``return_hidden=True``): the next-token loss of the
+    main model and the module's loss against the token AFTER the next, each
+    a :func:`chunked_lm_loss` over the SAME head, whose gradient is then the
+    sum of both passes'. Targets wrap round the row's end, as every loss of
+    this repo's; the module's pass goes by ``hvd_mtp``."""
+    main = chunked_lm_loss(hidden, head_kernel, jnp.roll(tokens, -1, axis=1),
+                           chunk)
+    with jax.named_scope(device_names.MTP):
+        mtp = chunked_lm_loss(mtp_hidden, head_kernel,
+                              jnp.roll(tokens, -2, axis=1), chunk)
+    return main + mtp_weight * mtp, (main, mtp)
 
 
 def _loss_chunks(hidden, targets, chunk):

@@ -13,8 +13,11 @@ under any imbalance, at static shapes (:func:`dropless_experts`):
 1. the N x top_k pairs are sorted by expert (stable, so a token's rows keep
    their order inside an expert's group) and the tokens gathered into that
    order: N x top_k rows whatever the imbalance, only the group sizes vary;
-2. the three SwiGLU products run as grouped products over the ragged groups,
-   and so do the six of their backward pass. Shapes the repo's own kernels
+2. the experts' products run as grouped products over the ragged groups:
+   three forward and six backward for gated SwiGLU experts (``w_gate``
+   given: ``down(silu(gate x) * up x)``), two and four for experts that are
+   not gated (``w_gate`` None: ``down(relu(up x)^2)``, Nemotron-H's
+   ``relu2``). Shapes the repo's own kernels
    tile (``grouped_matmul.takes_kernel``: bf16 or f32, both widths multiples
    of 128, the rows a multiple of the row tile) go through them: an expert's
    weight block resident, row tiles of 512 cut into blocks of 128 where a
@@ -52,6 +55,7 @@ balanced router (:func:`held_window_rows`).
 from __future__ import annotations
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -169,6 +173,19 @@ def _swiglu(gate, up):
     return jax.nn.silu(gate) * up
 
 
+def _relu2(up):
+    """Nemotron-H's ``relu2``: ``relu(x)^2``, of an expert with no gate."""
+    positive = jax.nn.relu(up)
+    return positive * positive
+
+
+def _activation(w_gate):
+    """(name, function of the products before it) of the experts' activation:
+    SwiGLU of ``(gate, up)`` where the experts have a gate, relu² of ``(up,)``
+    where ``w_gate`` is None."""
+    return ("relu2", _relu2) if w_gate is None else ("swiglu", _swiglu)
+
+
 # -------------------------------------------------- one rank's share of them
 
 # Sorted rows a pass of the held path visits at a time: whole row tiles of
@@ -189,6 +206,18 @@ def held_window_rows(pairs: int, count: int, n_experts: int) -> int:
     the ``pairs`` pairs, in whole windows."""
     window = _window(pairs)
     return -(-pairs * count // (n_experts * window)) * window
+
+
+def _buffer_rows(pairs: int, top_k: int, count: int) -> int:
+    """Rows of the held path's row buffers. A token's ``top_k`` experts are
+    distinct, so at most ``min(top_k, count)`` of its pairs fall on the
+    ``count`` held ones: that many rows a token, in whole windows, hold every
+    live row under any routing. ``pairs`` where ``top_k <= count`` (6 of 16
+    held, 8 of 32); 8 / 22 of them where 8 experts are held and a token
+    chooses 22."""
+    window = _window(pairs)
+    bound = pairs // top_k * min(top_k, count)
+    return min(pairs, -(-bound // window) * window)
 
 
 def _over_live_windows(live, pairs: int, body, carry):
@@ -285,21 +314,23 @@ def _sum_by_token(share, value_at, top_k: int, sums):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _held_experts(x, weights, flat, w_gate, w_up, w_down, interpret: bool):
     """:func:`dropless_experts` for a rank that holds the experts of
-    ``w_gate`` alone; ``flat`` (N x top_k,) is each pair's expert counted
-    from the rank's first, ``w_gate.shape[0]`` for an absent one. Every pass
-    outside the grouped products (which visit the held pairs' tiles as they
-    are) is a loop over the windows that hold live rows
-    (:func:`_over_live_windows`): the row buffers keep their worst-case
-    N x top_k rows as ADDRESSES, and only the live prefix of each is ever
-    written or read."""
+    ``w_up`` alone (``w_gate`` None: experts without a gate); ``flat``
+    (N x top_k,) is each pair's expert counted from the rank's first,
+    ``w_up.shape[0]`` for an absent one. Every pass outside the grouped
+    products (which visit the held pairs' tiles as they are) is a loop over
+    the windows that hold live rows (:func:`_over_live_windows`): the row
+    buffers keep the rows any routing can fill (:func:`_buffer_rows`) as
+    ADDRESSES, and only the live prefix of each is ever written or read."""
     return _held_forward(x, weights, flat, w_gate, w_up, w_down, interpret)[0]
 
 
 def _held_forward(x, weights, flat, w_gate, w_up, w_down, interpret):
-    d, pairs, hidden = x.shape[1], flat.shape[0], w_gate.shape[2]
+    d, pairs, hidden = x.shape[1], flat.shape[0], w_up.shape[2]
     window, top_k = _window(pairs), weights.shape[1]
+    held_rows = _buffer_rows(pairs, top_k, w_up.shape[0])
+    _, activation = _activation(w_gate)
     with jax.named_scope(device_names.MOE_DISPATCH):
-        share = _sorted_share(flat, top_k, w_gate.shape[0])
+        share = _sorted_share(flat, top_k, w_up.shape[0])
         live = share["live"]
 
         def gather(r, rows):
@@ -307,36 +338,39 @@ def _held_forward(x, weights, flat, w_gate, w_up, w_down, interpret):
             return lax.dynamic_update_slice(rows, x[pair // top_k], (r, 0))
 
         rows = _over_live_windows(live, pairs, gather,
-                                  _row_buffer(pairs, d, w_gate, share["order"],
-                                              interpret))
+                                  _row_buffer(held_rows, d, w_up,
+                                              share["order"], interpret))
     with jax.named_scope(device_names.MOE_EXPERTS):
-        product = _grouped_product(share["group_sizes"], pairs, x.dtype,
-                                   w_gate, interpret)
-        gate, up = product(rows, w_gate), product(rows, w_up)
+        product = _grouped_product(share["group_sizes"], held_rows, x.dtype,
+                                   w_up, interpret)
+        # (gate, up), or (up,) for experts without a gate
+        before = tuple(product(rows, w) for w in (w_gate, w_up)
+                       if w is not None)
 
         def activate(r, h):
             return lax.dynamic_update_slice(
-                h, _swiglu(lax.dynamic_slice(gate, (r, 0), (window, hidden)),
-                           lax.dynamic_slice(up, (r, 0), (window, hidden))),
-                (r, 0))
+                h, activation(*(lax.dynamic_slice(a, (r, 0), (window, hidden))
+                                for a in before)), (r, 0))
 
         h = _over_live_windows(live, pairs, activate,
-                               _row_buffer(pairs, hidden, w_gate, gate,
+                               _row_buffer(held_rows, hidden, w_up, before[0],
                                            interpret))
         out = product(h, w_down)
     with jax.named_scope(device_names.MOE_COMBINE):
         y = _sum_by_token(
             share, lambda rows, pair: out[rows].astype(jnp.float32)
             * weights.reshape(-1)[pair][:, None], top_k,
-            _row_buffer(pairs, d, w_gate, out, interpret))
-    return y, (share, weights, rows, gate, up, h, out, w_gate, w_up, w_down)
+            _row_buffer(held_rows, d, w_up, out, interpret))
+    return y, (share, weights, rows, before, h, out, w_gate, w_up, w_down)
 
 
 def _held_backward(interpret, res, g):
-    share, weights, rows, gate, up, h, out, w_gate, w_up, w_down = res
-    d, pairs, hidden = g.shape[1], share["order"].shape[0], w_gate.shape[2]
+    share, weights, rows, before, h, out, w_gate, w_up, w_down = res
+    d, pairs, hidden = g.shape[1], share["order"].shape[0], w_up.shape[2]
     window, top_k, live = _window(pairs), weights.shape[1], share["live"]
-    product = _grouped_product(share["group_sizes"], pairs, g.dtype, w_gate,
+    held_rows = rows.shape[0]
+    _, activation = _activation(w_gate)
+    product = _grouped_product(share["group_sizes"], held_rows, g.dtype, w_up,
                                interpret)
 
     def grads(a, w, dy):
@@ -364,27 +398,29 @@ def _held_backward(interpret, res, g):
 
         dout, dweights = _over_live_windows(
             live, pairs, pull,
-            (_row_buffer(pairs, d, w_gate, g, interpret),
+            (_row_buffer(held_rows, d, w_up, g, interpret),
              jnp.zeros((pairs,), jnp.float32)))
     with jax.named_scope(device_names.MOE_EXPERTS):
         dh, dw_down = grads(h, w_down, dout)
 
         def activate(r, carry):
-            # dgate's and dup's rows are still gate's and up's
+            # the gradients' rows are still those of the products before
             at = (lax.dynamic_slice(a, (r, 0), (window, hidden)) for a in carry)
-            dgate, dup = jax.vjp(_swiglu, *at)[1](
+            dbefore = jax.vjp(activation, *at)[1](
                 lax.dynamic_slice(dh, (r, 0), (window, hidden)))
-            return (lax.dynamic_update_slice(carry[0], dgate, (r, 0)),
-                    lax.dynamic_update_slice(carry[1], dup, (r, 0)))
+            return tuple(lax.dynamic_update_slice(a, da, (r, 0))
+                         for a, da in zip(carry, dbefore))
 
-        dgate, dup = _over_live_windows(live, pairs, activate, (gate, up))
-        (by_gate, dw_gate), (by_up, dw_up) = (grads(rows, w_gate, dgate),
-                                              grads(rows, w_up, dup))
+        dbefore = _over_live_windows(live, pairs, activate, before)
+        by, dws = zip(*(grads(rows, w, da) for w, da in zip(
+            (w for w in (w_gate, w_up) if w is not None), dbefore)))
     with jax.named_scope(device_names.MOE_DISPATCH):
         dx = _sum_by_token(
-            share, lambda rows, pair: by_gate[rows].astype(jnp.float32)
-            + by_up[rows], top_k,
-            _row_buffer(pairs, d, w_gate, by_up, interpret))
+            share, lambda rows, pair: functools.reduce(
+                operator.add, (b[rows].astype(jnp.float32) for b in by)),
+            top_k,
+            _row_buffer(held_rows, d, w_up, by[-1], interpret))
+    dw_gate, dw_up = (None, *dws) if w_gate is None else dws
     return (dx, dweights.reshape(weights.shape), None, dw_gate, dw_up, dw_down)
 
 
@@ -393,12 +429,16 @@ _held_experts.defvjp(_held_forward, _held_backward)
 
 def dropless_experts(x, weights, experts, w_gate, w_up, w_down,
                      interpret: bool = False, held=None):
-    """Every chosen (token, expert) pair through its SwiGLU expert, summed
-    with its weight: ``sum_j weights[n, j] * down_e(silu(gate_e x_n) * up_e x_n)``
-    with ``e = experts[n, j]``, over the ``j`` whose expert this rank holds.
+    """Every chosen (token, expert) pair through its expert, summed with its
+    weight: ``sum_j weights[n, j] * f_e(x_n)`` with ``e = experts[n, j]``, over
+    the ``j`` whose expert this rank holds. The experts are what their
+    weights say: gated SwiGLU, ``f_e(x) = down_e(silu(gate_e x) * up_e x)``,
+    or with ``w_gate`` None experts WITHOUT a gate, ``f_e(x) =
+    down_e(relu(up_e x)^2)`` (Nemotron-H's ``relu2``): two grouped products
+    forward and four backward where the gated ones take three and six.
 
-    x: (N, D); weights, experts: (N, top_k); w_gate, w_up: (E, D, H);
-    w_down: (E, H, D), all in x's dtype. Returns (N, D) in x's dtype.
+    x: (N, D); weights, experts: (N, top_k); w_gate (or None), w_up:
+    (E, D, H); w_down: (E, H, D), all in x's dtype. Returns (N, D) in x's dtype.
     ``held`` None: the weights are every expert's, and the work is N x top_k
     rows whatever the routing. ``held = (first, count, of)``: ``experts``
     index the ``of`` experts the router chooses among, the weights are those
@@ -412,19 +452,22 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down,
     from ..metrics import record_moe_dispatch_rows
 
     n, d = x.shape
-    top_k, n_experts = experts.shape[1], w_gate.shape[0]
+    top_k, n_experts = experts.shape[1], w_up.shape[0]
+    name, activation = _activation(w_gate)
+    record_moe_dispatch_rows(
+        n * top_k if held is None else held_window_rows(n * top_k, *held[1:]),
+        d * x.dtype.itemsize)
     if held is not None:
         first, count, of = held
         if count != n_experts:
-            raise ValueError(f"held {held} but {n_experts} experts' weights")
-        record_moe_dispatch_rows(held_window_rows(n * top_k, count, of))
+            raise ValueError(f"held {held} but {n_experts} {name} experts' "
+                             f"weights")
         with jax.named_scope(device_names.MOE_DISPATCH):
             flat = experts.reshape(-1)
             # an absent expert's pairs sort behind every held one's
             flat = jnp.where((flat >= first) & (flat < first + count),
                              flat - first, count)
         return _held_experts(x, weights, flat, w_gate, w_up, w_down, interpret)
-    record_moe_dispatch_rows(n * top_k)
     with jax.named_scope(device_names.MOE_DISPATCH):
         flat = experts.reshape(-1)
         order = jnp.argsort(flat, stable=True)          # sorted row -> pair
@@ -432,10 +475,10 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down,
         group_sizes = _expert_counts(flat, n_experts)
         rows = _take_rows(x, order // top_k, inverse, top_k)
     with jax.named_scope(device_names.MOE_EXPERTS):
-        product = _grouped_product(group_sizes, n * top_k, rows.dtype, w_gate,
+        product = _grouped_product(group_sizes, n * top_k, rows.dtype, w_up,
                                    interpret)
-        gate, up = product(rows, w_gate), product(rows, w_up)
-        out = product(jax.nn.silu(gate) * up, w_down)
+        out = product(activation(*(product(rows, w) for w in (w_gate, w_up)
+                                   if w is not None)), w_down)
     with jax.named_scope(device_names.MOE_COMBINE):
         pairs = _take_rows(out, inverse, order, 1).reshape(n, top_k, d)
         return jnp.sum(pairs.astype(jnp.float32) * weights[:, :, None],

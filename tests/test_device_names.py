@@ -303,3 +303,81 @@ def test_the_four_projections_and_the_latents_norm_are_under_mla_proj(
         assert any(f"{names.MLA_PROJ}/{leaf}" in n for n in found), leaf
     for leaf in ("shared_gate", "shared_up", "shared_down"):
         assert any(f"{names.MOE_SHARED}/{leaf}" in n for n in found), leaf
+
+
+LATENT_SCOPES = [names.MOE_LATENT, names.MTP, names.MOE_SHARED,
+                 names.MOE_EXPERTS, names.SSD_SCAN]
+
+
+@pytest.fixture(scope="module")
+def latent_moe_op_names():
+    """The ``op_name``s of a tiny one-sub-layer hybrid's gradients (a Mamba-2
+    layer, an expert layer of relu² experts in a latent holding a share of
+    them, an attention layer, and the multi-token-prediction module;
+    recomputation on, as the cell runs; both losses through the shared head),
+    and the text without them."""
+    import re
+
+    from horovod_tpu.models import BIAS_COLLECTION, TransformerLM
+    from horovod_tpu.models.mamba import Mamba2Dims
+    from horovod_tpu.models.transformer import lm_loss_with_mtp
+
+    model = TransformerLM(
+        vocab=64, dim=32, heads=4, kv_heads=1, head_dim=8, layers=3,
+        layer_types=("mamba_only", "experts_only", "attention_only"),
+        mtp_layer_types=("attention_only", "experts_only"),
+        mamba=Mamba2Dims(heads=4, head_dim=4, state=8, chunk=4), rope=False,
+        moe_experts=8, moe_top_k=3, moe_hidden=16, moe_router="sigmoid",
+        moe_route_scale=5.0, moe_shared_hidden=24, moe_held=(2, 2),
+        moe_activation="relu2", moe_latent=16, remat=True, dtype=jnp.float32)
+    tokens = jnp.zeros((1, 64), jnp.int32)
+    variables = model.init(jax.random.PRNGKey(0), tokens)
+
+    def loss(p):
+        hidden = model.apply({"params": p,
+                              BIAS_COLLECTION: variables[BIAS_COLLECTION]},
+                             tokens, return_hidden=True)
+        return lm_loss_with_mtp(*hidden, p["lm_head"]["kernel"], tokens, 0.3,
+                                32)[0]
+
+    lowered = jax.jit(jax.grad(loss)).lower(variables["params"])
+    found = set(re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)))
+    return found, lowered.as_text(debug_info=False)
+
+
+def test_the_latent_and_mtp_names_are_what_the_benchmark_looks_for():
+    assert (names.MOE_LATENT, names.MTP) == ("hvd_moe_latent", "hvd_mtp")
+    assert {names.MOE_LATENT, names.MTP} <= set(names.ALL)
+    # found by equality in a device profile: neither is a prefix of another
+    assert not [n for n in names.ALL if n != names.MTP and names.MTP in n]
+    assert names.MOE_EXPERTS not in names.MOE_LATENT    # not the experts' time
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+@pytest.mark.parametrize("name", LATENT_SCOPES)
+def test_latent_moe_scope_survives_the_breakdowns_label(name, backward,
+                                                        latent_moe_op_names):
+    """In the module as metadata only, on forward and backward operations,
+    and still there in what ``benchmarks/reduce_trace.op_label`` keeps of an
+    ``op_name``: its last three segments."""
+    found, bare = latent_moe_op_names
+    assert name not in bare
+    kept = {"/".join(n.split("/")[-3:]) for n in found
+            if ("transpose(jvp(" in n) is backward}
+    assert any(name in label for label in kept), sorted(kept)[:20]
+
+
+def test_the_latent_projections_and_the_modules_parts_are_under_their_names(
+        latent_moe_op_names):
+    found, _ = latent_moe_op_names
+    for leaf in ("fc1_latent", "fc2_latent"):
+        assert any(f"{names.MOE_LATENT}/{leaf}" in n for n in found), leaf
+    for leaf in ("mtp_hidden_norm", "mtp_embed_norm", "mtp_proj", "mtp_norm"):
+        assert any(f"{names.MTP}/{leaf}" in n for n in found), leaf
+    # experts without a gate: the shared expert has no gate either
+    assert any(f"{names.MOE_SHARED}/shared_up" in n for n in found)
+    assert not any("shared_gate" in n for n in found)
+    # the module's own blocks go by the blocks' names, not by the module's
+    assert not any(f"{names.MTP}/mtp_block" in n for n in found)
+    # the module's loss pass: a second chunked loss under the module's name
+    assert any(names.MTP in n and "while" in n for n in found)

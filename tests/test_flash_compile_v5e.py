@@ -392,3 +392,102 @@ def test_window_and_full_kernels_of_the_hybrid_compile_for_v5e(
         assert name in text, f"{name} is not in the compiled module"
     if window is not None:
         assert FLASH_FWD not in text and FLASH_BWD_DQ not in text
+
+
+# nemotron3s_seq8192_1chip: one tensor- / expert-parallel rank's share of
+# Nemotron-3-Super. 8 held relu² experts of 1,024 x 2,688 (21 x 128: no power
+# of two) chosen 22 of 512 from 16,384 tokens: 360,448 pairs, 131,072 buffer
+# rows (a token's 22 are distinct, 8 held at most), ~5,632 live; the Mamba-2
+# mixer at 16 heads x 64, ONE group of state 128, chunk 128, batch 2; the
+# flash kernels at 4 query heads over 1 key/value head of 128.
+def test_the_latent_experts_compile_for_v5e_at_the_cells_shape(
+        one_chip, no_persistent_cache):
+    """``dropless_experts`` WITHOUT a gate for the rank's share, forward and
+    backward: the two kernels at K = 1,024 / N = 2,688 and its transpose
+    (weight blocks of 5.25 MiB, the weight gradient's accumulator 10.5), six
+    grouped products, row buffers of ``_buffer_rows`` and not of every pair."""
+    from horovod_tpu.ops import moe
+
+    tokens, top_k, latent, width, held, of = 16384, 22, 1024, 2688, 8, 512
+    assert moe._buffer_rows(tokens * top_k, top_k, held) == tokens * held
+
+    def loss(x, weights, w_up, w_down, experts):
+        return jnp.sum(moe.dropless_experts(
+            x, weights, experts, None, w_up, w_down,
+            held=(0, held, of)).astype(jnp.float32))
+
+    def shape(*dims, of=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    assert gm.takes_kernel(shape(tokens * held, latent),
+                           shape(held, latent, width))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        shape(tokens, latent), shape(tokens, top_k, of=jnp.float32),
+        shape(held, latent, width), shape(held, width, latent),
+        shape(tokens, top_k, of=jnp.int32)).compile()
+    text = compiled.as_text()
+    for name in (MOE_EXPERTS_GMM, MOE_EXPERTS_TGMM):
+        assert name in text, f"{name} is not in the compiled module"
+    assert " while(" in text and "ragged" not in text
+    # no array of all 360,448 pairs' ROWS: indices of them alone
+    for dims in (f"[{tokens * top_k},{latent}]", f"[{tokens * top_k},{width}]"):
+        assert dims not in text, dims
+    # rows, up, h, out, a run-sum buffer each way, dout, dh, dx's
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+
+
+@pytest.mark.parametrize("part", ["scan", "conv_silu", "gate_norm", "flash"])
+def test_the_hybrids_mixers_compile_for_v5e_at_the_held_share(
+        one_chip, no_persistent_cache, part):
+    """The shapes no older cell gives the shared kernels: the scan at (2,
+    8192, 16 heads x 64, state 128) and chunk 128; the convolution + silu over
+    1,280 channels and the gated norm over 1,024 at batch 2; the three flash
+    kernels at (2, 8192, 4 over 1, 128). Forward and backward."""
+    from horovod_tpu.common.device_names import (MAMBA_CONV_BWD,
+                                                 MAMBA_CONV_FWD,
+                                                 MAMBA_GATE_NORM_BWD,
+                                                 MAMBA_GATE_NORM_FWD)
+    from horovod_tpu.ops import mamba_fused
+    from horovod_tpu.ops.ssd import ssd
+
+    b, t, h, p, n = 2, 8192, 16, 64, 128
+    bf16 = jnp.bfloat16
+
+    def shape(*dims, of=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    if part == "scan":
+        def fn(*a):
+            return ssd(*a, 128)
+
+        args = (shape(b, t, h, p, of=bf16), shape(b, t, h), shape(h),
+                shape(b, t, 1, n, of=bf16), shape(b, t, 1, n, of=bf16), shape(h))
+        kernels = ()
+    elif part == "conv_silu":
+        def fn(x, kernel, bias):
+            return mamba_fused.conv_silu(x, kernel, bias, splits=(1024, 256))
+
+        args = (shape(b, t, 1280, of=bf16), shape(4, 1280), shape(1280))
+        assert mamba_fused.conv_takes_kernel(*args[:2], (1024, 256))
+        kernels = (MAMBA_CONV_FWD, MAMBA_CONV_BWD)
+    elif part == "gate_norm":
+        def fn(y, z, scale):
+            return mamba_fused.gate_norm(y, z, scale, 1, 1e-5)
+
+        args = (shape(b, t, 1024, of=bf16),) * 2 + (shape(1024),)
+        assert mamba_fused.norm_takes_kernel(*args[:2], 1)
+        kernels = (MAMBA_GATE_NORM_FWD, MAMBA_GATE_NORM_BWD)
+    else:
+        fn = _flash
+        args = (shape(b, t, 4, 128, of=bf16),) + (shape(b, t, 1, 128, of=bf16),) * 2
+        kernels = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
+
+    def value_and_grads(*a):    # the value too: a forward nobody reads is cut
+        out, vjp = jax.vjp(fn, *a)
+        return out, vjp(out)
+
+    text = jax.jit(value_and_grads).lower(*args).compile().as_text()
+    for name in kernels:
+        assert name in text, f"{name} is not in the compiled module"
+    if part == "scan":
+        assert "tpu_custom_call" not in text

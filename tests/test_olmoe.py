@@ -310,7 +310,9 @@ def test_expert_load_gauge_is_set_by_the_helper_alone(hvd):
     assert gauges["horovod_moe_expert_load_max_over_mean"] == pytest.approx(2.0)
     assert gauges["horovod_moe_grouped_border_overhead"] == 0.0
     assert gauges["horovod_moe_dispatch_rows"] == 96 * CFG["top_k"]   # N x top_k
+    assert gauges["horovod_moe_dispatch_row_bytes"] == x.shape[-1] * x.dtype.itemsize
     assert sorted(name for name in gauges if name.startswith("horovod_moe_")) == [
+        "horovod_moe_dispatch_row_bytes",
         "horovod_moe_dispatch_rows",
         "horovod_moe_expert_load_max_over_mean",
         "horovod_moe_grouped_border_overhead"]
@@ -379,7 +381,7 @@ def test_experts_without_a_top_k_are_refused():
     for top_k in (0, 5):
         m = TransformerLM(vocab=32, dim=16, heads=2, layers=2, moe_experts=4,
                           moe_top_k=top_k)
-        with pytest.raises(ValueError, match=f"top_k {top_k} of 4 experts"):
+        with pytest.raises(ValueError, match=f"top_k {top_k} of 4 swiglu experts"):
             m.init(jax.random.PRNGKey(0), tokens)
 
 
