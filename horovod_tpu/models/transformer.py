@@ -84,15 +84,29 @@ class RotaryScheme:
         return 1.0 if self.factor is None else 0.1 * np.log(self.factor) + 1.0
 
 
+def _swap(x, lo, hi):
+    """``swap(x)`` on the last dim, in float32: ``swap(x)[lo] = -x[hi]`` and
+    ``swap(x)[hi] = x[lo]`` for the pairs' index arrays ``lo`` / ``hi``, zero
+    elsewhere: a product with a constant matrix of 0 and +-1 (exact in any
+    dtype: one term a sum). A rotation is then ``x * cos + swap(x) * sin`` in
+    place and lane-dense. Slicing a head into halves of 32 or 64 lanes and
+    concatenating them again took 2.6 x and 1.8 x as long on the chip, and a
+    reshape to (..., half, 2) or a stride-2 slice would put 2 of 128 lanes to
+    use (PERF.md §6, PR 36 and PR 39)."""
+    d = x.shape[-1]
+    swap = np.zeros((d, d), np.float32)
+    swap[hi, lo], swap[lo, hi] = -1.0, 1.0
+    return jnp.dot(x, jnp.asarray(swap, x.dtype),
+                   preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST
+                   if x.dtype == jnp.float32 else None)
+
+
 def _rope_scheme(x, positions, scheme):
     """A :class:`RotaryScheme` on the last dim of ``x`` (..., T, heads, D):
-    the leading ``scheme.dims`` turn in pairs (i, i + half), the rest pass.
-    In place and lane-dense, as ``_rope``'s interleaved form: ``x * cos +
-    swap(x) * sin`` with ``swap(x)[i] = -x[i + half]``, ``swap(x)[i + half] =
-    x[i]`` a product with a constant matrix of 0 and +-1 (exact in any dtype:
-    one term a sum), cos 1 and sin 0 on the dimensions that pass. Slicing a
-    head into halves of 32 or 64 lanes and concatenating them again took 2.6 x
-    and 1.8 x as long on the chip (PERF.md §6, PR 36)."""
+    the leading ``scheme.dims`` turn in pairs (i, i + half), the rest pass:
+    ``x * cos + swap(x) * sin`` (:func:`_swap`), cos 1 and sin 0 on the
+    dimensions that pass."""
     d = x.shape[-1]
     dims = d if scheme.dims is None else scheme.dims
     half = dims // 2
@@ -105,12 +119,7 @@ def _rope_scheme(x, positions, scheme):
                                axis=-1)[..., None, :]
 
     i = np.arange(half)
-    swap = np.zeros((d, d), np.float32)
-    swap[i + half, i], swap[i, i + half] = -1.0, 1.0
-    swapped = jnp.dot(x, jnp.asarray(swap, x.dtype),
-                      preferred_element_type=jnp.float32,
-                      precision=jax.lax.Precision.HIGHEST
-                      if x.dtype == jnp.float32 else None)
+    swapped = _swap(x, i, i + half)
     return (x * widen(jnp.cos(angles), 1.0)
             + swapped * widen(jnp.sin(angles), 0.0)).astype(x.dtype)
 
@@ -118,28 +127,20 @@ def _rope_scheme(x, positions, scheme):
 def _rope(x, positions, theta=10000.0, interleave=False):
     """Rotary position embedding on the last dim (pairs): component i with
     i + half, or with ``interleave`` 2i with 2i + 1, turned by
-    ``positions * theta ** (-i / half)``."""
+    ``positions * theta ** (-i / half)``: ``x * cos + swap(x) * sin``
+    (:func:`_swap`) on whole heads, whichever way the pairs lie."""
     half = x.shape[-1] // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., T, half]
     cos, sin = jnp.cos(angles)[..., None, :], jnp.sin(angles)[..., None, :]  # add head dim
+    i = np.arange(half)
+    lo, hi = (2 * i, 2 * i + 1) if interleave else (i, i + half)
+    swapped = _swap(x, lo, hi)
     if interleave:
-        # In place, lane-dense: x * cos + swap(x) * sin with swap(x)[2i] =
-        # -x[2i+1], swap(x)[2i+1] = x[2i] as a product with a constant matrix
-        # of 0 and +-1 (exact in any dtype: one term a sum). A reshape to
-        # (..., half, 2) or a stride-2 slice would put 2 of 128 lanes to use.
-        i = np.arange(half)
-        swap = np.zeros((2 * half, 2 * half), np.float32)
-        swap[2 * i + 1, 2 * i], swap[2 * i, 2 * i + 1] = -1.0, 1.0
-        swapped = jnp.dot(x, jnp.asarray(swap, x.dtype),
-                          preferred_element_type=jnp.float32,
-                          precision=jax.lax.Precision.HIGHEST
-                          if x.dtype == jnp.float32 else None)
         cos, sin = (jnp.repeat(t, 2, axis=-1) for t in (cos, sin))
-        return (x * cos + swapped * sin).astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return rotated.astype(x.dtype)
+    else:
+        cos, sin = (jnp.concatenate([t, t], axis=-1) for t in (cos, sin))
+    return (x * cos + swapped * sin).astype(x.dtype)
 
 
 def causal_attention(q, k, v, seq_offset=0, scale=None, window=None):
@@ -325,8 +326,7 @@ class Block(nn.Module):
                            name="q_norm")(q)
             k = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype,
                            name="k_norm")(k)
-        # q wholly before k, as ever: the older models' lowered text is held
-        # byte for byte
+        # q wholly before k, as ever
         plain_rope = self.rope and self.rotary is None
         q = q.reshape(b, t, self.heads, head_dim)
         if plain_rope:

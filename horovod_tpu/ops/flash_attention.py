@@ -715,6 +715,15 @@ def _check_blocks(t, block_q, block_k, interpret):
     return bq, bk
 
 
+# Operands cross the kernels' boundary as head-major copies ``(B * H, T,
+# D)``, whatever their width. The free view ``(B, T, H * D)`` with the head
+# chosen by the index maps (possible where ``D % 128 == 0``) was built and
+# measured on the v5e (PERF.md §6, PR 39) and is slower: a block becomes
+# ``block / 8`` runs of one 2 KiB tile a stride of ``H`` tiles apart (the
+# kernels alone +1..+8%), and a 4-D ``(B, T, H, D)`` array is tiled over ``(H,
+# D)``, so the reshape is never a bitcast, while XLA folds most of these
+# transposes into the layout the neighbouring fusions write and read.
+
 def _rows(x, b, t, h, d):
     return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
 
@@ -787,6 +796,8 @@ def flash_attention(q, k, v, causal: bool = True,
     via the grid index map). v's head size may differ from q's and k's
     (latent attention: 192 | 128): the output, dO, dV and their accumulators
     follow v, dq and dk follow q; the default scale is q's ``D ** -0.5``.
+    No width crosses into the kernels without a copy: every operand and
+    result is relaid head-major round the call (:func:`_rows`).
     Sequence length must be a multiple of
     ``block_q`` and ``block_q`` of ``block_k`` (both clamp down to the
     sequence length for short inputs; None: ``DEFAULT_BLOCK_Q`` /

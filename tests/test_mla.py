@@ -69,16 +69,67 @@ def test_flash_refuses_q_and_k_of_two_sizes():
         flash_attention(q, k, k, True, 16, 16, True, None)
 
 
+def _rope_by_halves(x, pos, theta=10000.0):
+    """The rotation as ``_rope`` computed it before it turned in place: each
+    head cut into halves, the halves concatenated again."""
+    half = x.shape[-1] // 2
+    ang = pos[..., None].astype(jnp.float32) * (
+        1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half)))
+    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).astype(x.dtype)
+
+
 def test_rope_defaults_are_the_old_rotation():
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 8, 2, 8))
     pos = jnp.arange(8)[None]
-    half = 4
-    freqs = 1.0 / (10000.0 ** (jnp.arange(half) / half))
-    ang = pos[..., None] * freqs
-    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
-    want = jnp.concatenate([x[..., :half] * cos - x[..., half:] * sin,
-                            x[..., half:] * cos + x[..., :half] * sin], -1)
-    np.testing.assert_allclose(_rope(x, pos), want, atol=1e-6)
+    np.testing.assert_allclose(_rope(x, pos), _rope_by_halves(x, pos),
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype,heads,d", [
+    (jnp.float32, 2, 8), (jnp.float32, 3, 128), (jnp.bfloat16, 8, 128),
+    (jnp.bfloat16, 4, 64)])
+def test_rope_in_place_is_the_rotation_by_halves(dtype, heads, d):
+    """Values to the bit (a term through the matrix of 0 and +-1 is one
+    operand, and ``a - b`` is ``a + (-b)``); gradients to the dtype's
+    rounding (the term through the matrix is rounded to it on the way back)."""
+    t = 32
+    keys = jax.random.split(jax.random.PRNGKey(heads + d), 2)
+    x, g = (jax.random.normal(k, (2, t, heads, d)).astype(dtype) for k in keys)
+    pos = jnp.arange(t)[None] + 7
+    got, got_vjp = jax.vjp(lambda x: _rope(x, pos), x)
+    want, want_vjp = jax.vjp(lambda x: _rope_by_halves(x, pos), x)
+    assert got.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got_vjp(g)[0].astype(jnp.float32),
+                               want_vjp(g)[0].astype(jnp.float32),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("interleave", [False, True])
+def test_rope_builds_no_half_heads(interleave):
+    """Forward and backward hold no array of half a head at every position:
+    whole heads go in, through and out (a half of 64 lanes fills half a
+    vector register's lanes and is relaid twice; PERF.md §6, PR 39)."""
+    x = jnp.ones((1, 64, 8, 128), jnp.bfloat16)
+    pos = jnp.arange(64)[None]
+    jaxpr = jax.make_jaxpr(lambda x, g: jax.vjp(
+        lambda x: _rope(x, pos, 10000.0, interleave), x)[1](g))(x, x)
+
+    def shapes(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield from (v.aval.shape for v in eqn.outvars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from shapes(sub)
+
+    found = list(shapes(jaxpr.jaxpr))
+    assert (1, 64, 8, 128) in found
+    # cos and sin are one head's, (1, 64, 1, 64): no head of x is cut
+    assert not [s for s in found if len(s) == 4 and s[2] > 1 and s[-1] < 128]
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
