@@ -263,15 +263,27 @@ def record_moe_live_rows(live_rows, window: int) -> None:
           / len(steps))
 
 
-def record_ssd_plan(chunk: int) -> None:
+def record_ssd_plan(chunk: int, kernel: bool) -> None:
     """Record the chunk length the latest traced ``ops.ssd.ssd`` cut its rows
     into (trace time, once per compile): the configured chunk, or the row's
-    own length where that is shorter. 0 until a state-space scan is traced."""
+    own length where that is shorter. 0 until a state-space scan is traced.
+    ``kernel`` says whether its shapes took the scan's kernels:
+    ``horovod_ssd_kernel_scans`` counts the traced scans that did since the
+    latest one that kept ``jax.numpy``, which sets it back to 0."""
     registry().gauge(
         "horovod_ssd_chunk_len",
         help="positions a chunk of the latest traced ops.ssd.ssd (the "
              "chunked state-space scan); 0 = none traced"
     ).set(chunk)
+    scans = registry().gauge(
+        "horovod_ssd_kernel_scans",
+        help="traced ops.ssd.ssd calls whose shapes took the scan's kernels "
+             "(hvd_ssd_scan_fwd / _bwd) since the latest one that kept "
+             "jax.numpy; 0 = none traced, or the latest kept jax.numpy")
+    if kernel:
+        scans.inc()
+    else:
+        scans.set(0)
 
 
 def record_mamba_fused_passes(passes: int) -> None:

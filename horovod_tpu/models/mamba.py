@@ -26,7 +26,11 @@ shapes tile: channels and a group's features multiples of 128, the row a
 whole number of row tiles. Every other shape keeps the ``jax.numpy`` forms
 (``ops.ssd.causal_depthwise_conv`` + silu, :func:`gated_rms_norm`), which
 stay the definitions. The shapes choose and nothing else does; the gauge
-``horovod_mamba_fused_passes`` says how many of the two took a kernel.
+``horovod_mamba_fused_passes`` says how many of the two took a kernel. The
+scan between them (``ops.ssd.ssd``) runs as kernels of its own on the same
+terms: it reads ``u``, ``B``, ``C`` and ``dt`` as they lie here and writes
+``y`` (B, T, inner), where its shapes tile (``ops.ssd.takes_kernel``;
+``horovod_ssd_kernel_scans``), and as ``jax.numpy`` otherwise.
 
 Initialisation is Mamba-2's: ``A`` uniform in [1, 16], ``dt`` log-uniform in
 [0.001, 0.1] stored through the inverse of the softplus, ``D`` = 1, norm
@@ -92,9 +96,10 @@ class Mamba2Mixer(nn.Module):
     dims: Mamba2Dims
     rms_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # True runs the two chains' kernels (ops/mamba_fused.py), where the
-    # shapes take them, in the Pallas interpreter: ``Block`` hands its
-    # ``flash_interpret`` down, one flag for every Pallas kernel of a block.
+    # True runs the two chains' kernels (ops/mamba_fused.py) and the scan's
+    # (ops/ssd.py), where the shapes take them, in the Pallas interpreter:
+    # ``Block`` hands its ``flash_interpret`` down, one flag for every Pallas
+    # kernel of a block.
     interpret: bool = False
 
     @nn.compact
@@ -137,7 +142,8 @@ class Mamba2Mixer(nn.Module):
         y = ssd(u.reshape(b, t, m.heads, m.head_dim),
                 jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
                 -jnp.exp(a_log), B.reshape(b, t, m.groups, m.state),
-                C.reshape(b, t, m.groups, m.state), skip, m.chunk)
+                C.reshape(b, t, m.groups, m.state), skip, m.chunk,
+                self.interpret)
         scale = self.param("gate_norm", nn.initializers.ones, (inner,),
                            jnp.float32)
         y = y.reshape(b, t, inner)

@@ -244,6 +244,64 @@ def test_the_scans_loop_is_named_once(fixture, request):
     assert not any("hvd_ssd" in "/".join(n.split("/")[-3:]) for n in inside)
 
 
+@pytest.fixture(scope="module")
+def scan_kernel_op_names():
+    """The ``op_name``s of a tiny hybrid's gradients at sizes the SCAN's
+    kernels tile too (2 heads x 64, state 128, chunk 128 over 256 rows of
+    float32), LOWERED FOR THE TPU (nothing compiles or runs): no loop over
+    blocks of chunks any more, two ``pallas_call``s whose names hold
+    ``hvd_ssd`` and are no names of ``common/device_names.py``."""
+    import re
+
+    from horovod_tpu.models import TransformerLM
+    from horovod_tpu.models.mamba import Mamba2Dims
+
+    model = TransformerLM(
+        vocab=64, dim=32, heads=4, kv_heads=2, layers=2,
+        layer_types=("mamba", "attention"),
+        mamba=Mamba2Dims(heads=2, head_dim=64, state=128, chunk=128),
+        mlp_hidden=48, rope=False, tie_embeddings=True, remat=True,
+        dtype=jnp.float32)
+    tokens = jnp.zeros((1, 256), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    lowered = jax.jit(jax.grad(
+        lambda p: model.apply({"params": p}, tokens).sum())).trace(
+            params).lower(lowering_platforms=("tpu",))
+    return set(re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)))
+
+
+@pytest.mark.parametrize("kernel,call,where", [
+    ("_fwd", "_scan_fwd_call", "forward"),
+    ("_fwd", "_scan_fwd_call", "recomputed"),
+    ("_bwd", "_scan_bwd_call", "backward"),
+])
+def test_the_scans_kernels_are_read_under_the_scans_name(
+        kernel, call, where, scan_kernel_op_names):
+    """Where the shapes tile, the scan is two kernels and no ``while``. Their
+    own names are NOT registered: ``device_profile.name_of`` gives a device
+    op of theirs to ``hvd_ssd_scan``, the scope both rules of the
+    ``custom_vjp`` enter, so ``ssd_scan_ms_per_step`` reads the whole scan;
+    the kept label holds ``hvd_ssd`` for the readers that go by substring."""
+    from horovod_tpu.metrics import device_profile
+
+    found = scan_kernel_op_names
+    name = names.SSD_SCAN + kernel
+    assert name not in names.ALL
+    assert not [n for n in found if n.endswith(f"{names.SSD_SCAN}/while")]
+    inside = f"{name}/pallas_call"
+    assert inside in found
+    sites = [n for n in found if n.endswith(f"/jit({call})")]
+    site, = [n for n in sites if {
+        "forward": "transpose(jvp(" not in n and "rematted" not in n,
+        "recomputed": "/checkpoint/rematted_computation/" in n,
+        "backward": "transpose(jvp(" in n and "rematted" not in n}[where]]
+    assert f"/mixer/{names.SSD_SCAN}/" in site + "/"
+    op_name = f"{site}/{inside}"
+    assert device_profile.name_of(op_name) == names.SSD_SCAN
+    assert "hvd_ssd" in "/".join(op_name.split("/")[-3:])
+
+
 # ------------------------------- latent attention's and the shared expert's
 
 MLA_SCOPES = [names.MLA_PROJ, names.MLA_ROPE, names.MOE_SHARED]
