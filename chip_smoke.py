@@ -17,7 +17,7 @@ and its ``compile_s``; any failure raises, so the exit code is non-zero):
                  the analytic mean (a synthetic all-ones batch cannot tell a
                  broken allreduce from a correct one).
 3. resnet      — ResNet-50, 224 px, 128 images per chip, bf16 compute,
-                 SGD+momentum, donated carries: exactly ``bench._build()``.
+                 SGD+momentum, donated carries: ``_build()`` below.
 4. kernels     — ``ops.flash_attention`` forward and backward COMPILED
                  (``interpret=False``) at the shapes the repo claims, against
                  a float32 ``jax.numpy`` dense reference: the f32 cases with
@@ -38,7 +38,7 @@ phase functions and runs them at tiny sizes on the virtual CPU mesh.
 
 Run it through the chip tool from the root of a checkout:
 ``python chip_smoke.py``. The compile cache is ``JAX_COMPILATION_CACHE_DIR``
-when that is set, else ``<checkout>/.jax_cache`` — the one ``bench.py`` uses.
+when that is set, else ``<checkout>/.jax_cache``.
 """
 
 from __future__ import annotations
@@ -110,13 +110,21 @@ def _fmt(xs, spec=".4g") -> str:
 
 # ------------------------------------------------------------------ 1. device
 
-def phase_device() -> dict:
+def require_tpu(what: str) -> dict:
+    """The full-width steps run on the chip or not at all: exits non-zero,
+    naming what jax found, when the first device is not a TPU. There is no
+    CPU fallback under a device metric's name."""
     d = device_info()
     if d["platform"] != "tpu":
         raise SystemExit(
-            f"chip_smoke: no TPU — jax.devices()[0] is platform="
+            f"{what} needs a TPU, but jax.devices()[0] is platform="
             f"{d['platform']!r} kind={d['kind']!r} ({d['count']} device(s)). "
-            "This script proves the trainer on the chip and has no CPU mode.")
+            "There is no CPU fallback.")
+    return d
+
+
+def phase_device() -> dict:
+    d = require_tpu("chip_smoke.py")
     report("device", ok=True)
     return d
 
@@ -171,8 +179,110 @@ def phase_collective(mesh, num_buckets: int = 3, threshold: int = 16 << 10):
 
 # ------------------------------------------------------------------ 3. resnet
 
+def _build():
+    """The full-width ResNet step: ResNet-50 at 224 px, 128 images per
+    chip, built by :func:`build_resnet_step`. Raises off-chip
+    (:func:`require_tpu`) — it never shrinks to fit a CPU."""
+    from horovod_tpu.models import ResNet50
+
+    require_tpu("the ResNet-50 step")
+    # Per-device batch 128: the reference benchmark uses 64/GPU
+    # (docs/benchmarks.md:22) sized for 2015 Pascal HBM; a v5e chip has the
+    # memory and MXU width for 128.
+    return build_resnet_step(ResNet50(num_classes=1000), image=224,
+                             per_dev_batch=128)
+
+
+def build_resnet_step(model, image, per_dev_batch, hierarchical=False):
+    """Model + jitted train step + fresh state + mesh-sharded synthetic
+    batch, at the size given. The one ResNet step builder: ``main()`` and
+    examples/realdata_benchmark.py reach it through :func:`_build`, and the
+    CPU tests call it at a tiny size.
+    ``hierarchical`` runs the gradient allreduce as the
+    RS(ici)→psum(dcn)→AG(ici) ladder over the 2-D ``('dcn','ici')`` mesh —
+    only meaningful on multi-chip topologies."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from horovod_tpu.compat import shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+
+    mesh = hvd.hierarchical_mesh() if hierarchical else hvd.default_mesh()
+    # Data axis: the flat world, or both levels of the 2-D hierarchy.
+    A = ("dcn", "ici") if hierarchical else hvd.HVD_AXIS
+    n_dev = mesh.size
+    batch = per_dev_batch * n_dev
+
+    # Batch and state are placed as the step's specs lay them out, from the
+    # start: an unplaced batch would be re-scattered from device 0 on every
+    # step, and unplaced state makes the SECOND call compile again (its
+    # inputs are then the first call's mesh-sharded outputs).
+    data = NamedSharding(mesh, P(A))
+    replicated = NamedSharding(mesh, P())
+    x = jax.device_put(jnp.ones((batch, image, image, 3), jnp.float32), data)
+    y = jax.device_put(jnp.zeros((batch,), jnp.int32), data)
+    variables = jax.jit(lambda key, x: model.init(key, x, train=False))(
+        jax.random.PRNGKey(0), jnp.ones((2, image, image, 3), jnp.float32))
+    params = jax.device_put(variables["params"], replicated)
+    # Per-rank BN stats: replicate the initial stats into a leading
+    # device-axis dim; each shard owns row r and never syncs it in-step.
+    batch_stats = jax.device_put(jax.tree_util.tree_map(
+        lambda t: jnp.broadcast_to(t[None], (n_dev,) + t.shape),
+        variables["batch_stats"]), data)
+
+    # Fusion threshold: 256 MiB — the whole ~100 MB gradient set in one
+    # bucket. HOROVOD_FUSION_THRESHOLD still overrides. The `or` spelling
+    # keeps 256 MiB this step's own seed, not a second default for the
+    # knob (the engine default stays config.py's 64 MiB).
+    opt = hvd.jax.DistributedOptimizer(
+        optax.sgd(0.01 * n_dev, momentum=0.9),
+        fusion_threshold=int(
+            os.environ.get("HOROVOD_FUSION_THRESHOLD") or 256 << 20),
+        hierarchical=hierarchical,
+    )
+    opt_state = jax.device_put(opt.init(params), replicated)
+
+    def loss_fn(params, batch_stats, x, y):
+        logits, new_state = model.apply(
+            {"params": params, "batch_stats": batch_stats}, x, train=True,
+            mutable=["batch_stats"],
+        )
+        loss = optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
+        return loss, new_state["batch_stats"]
+
+    def train_step(params, batch_stats, opt_state, x, y):
+        # batch_stats arrive as this rank's (1, ...) shard: drop the rank dim
+        # for the model, restore it for the sharded out_spec.
+        local_stats = jax.tree_util.tree_map(lambda t: t[0], batch_stats)
+        (loss, local_stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, local_stats, x, y
+        )
+        updates, opt_state = opt.update(grads, opt_state, params)
+        params = optax.apply_updates(params, updates)
+        batch_stats = jax.tree_util.tree_map(lambda t: t[None], local_stats)
+        loss = jax.lax.pmean(loss, A)
+        return params, batch_stats, opt_state, loss
+
+    step = jax.jit(
+        shard_map(
+            train_step,
+            mesh=mesh,
+            in_specs=(P(), P(A), P(), P(A), P(A)),
+            out_specs=(P(), P(A), P(), P()),
+            check_vma=False,
+        ),
+        # Donate params/batch_stats/opt_state: they are consumed and
+        # re-produced every step, so XLA can update in place instead of
+        # holding two copies (HBM bandwidth is the usual TPU bottleneck).
+        donate_argnums=(0, 1, 2),
+    )
+    return step, (params, batch_stats, opt_state), (x, y), batch, n_dev
+
+
 def phase_resnet(built, steps: int = 5):
-    """``built`` is what ``bench.build_resnet_step`` returns. One compile
+    """``built`` is what :func:`build_resnet_step` returns. One compile
     step plus ``steps`` fenced steps on a rank-distinct seeded batch."""
     import jax
     import numpy as np
@@ -508,14 +618,13 @@ def main() -> int:
     print(f"[chip_smoke] compile cache {cache_dir} ({n_cached} entries at "
           "start)", flush=True)
 
-    import bench
     import horovod_tpu as hvd
     from horovod_tpu.models import TransformerLM
 
     hvd.init()
     try:
         phase_collective(hvd.default_mesh())
-        phase_resnet(bench._build(), steps=5)
+        phase_resnet(_build(), steps=5)
         phase_kernels()
         phase_transformer(
             TransformerLM(vocab=32000, dim=1024, heads=8, layers=12,

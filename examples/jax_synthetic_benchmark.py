@@ -37,11 +37,9 @@ def main():
     parser.add_argument("--num-warmup-batches", type=int, default=10)
     parser.add_argument("--roofline", action="store_true",
                         help="after the throughput loop, profile the step "
-                             "with the XLA device profiler and print the "
-                             "per-category roofline (bytes/flops/duration "
-                             "aggregation, horovod_tpu/utils/roofline.py — "
-                             "the bench.py --roofline method for any model "
-                             "in the zoo)")
+                             "with the XLA device profiler and print where "
+                             "its device time goes, by the program's names "
+                             "(hvd.metrics.profile_step)")
     args = parser.parse_args()
 
     hvd.init()
@@ -112,11 +110,10 @@ def main():
         # EVERY rank must run the collective steps (rank-0-only would
         # deadlock a multi-process --jax-distributed world); only rank 0
         # prints its device's report.
-        from horovod_tpu.utils.roofline import format_report, profile_device_ops
-
-        rep = profile_device_ops(lambda: run_batches(1), steps=5)
+        rep = hvd.metrics.profile_step(lambda: run_batches(1), steps=5)
         if hvd.rank() == 0:
-            print(format_report(rep))
+            print(rep["text"] if rep["ok"] else
+                  f"profile: unavailable ({rep['reason']})")
 
     hvd.shutdown()
 

@@ -3,11 +3,11 @@
 The reference's benchmark doc has a real-data variant of its headline
 ResNet measurement (reference docs/benchmarks.md:40-63: the same harness
 with `--data-dir` pointing at an ImageNet tree through DistributedSampler).
-This is that variant for the TPU build: the SAME jitted train step as
-bench.py, fed four ways —
+This is that variant for the TPU build: the jitted ResNet-50 train step of
+``chip_smoke._build()`` (224 px, 128 images a chip), fed four ways —
 
-1. ``synthetic``  — device-resident tensors (bench.py's configuration):
-   the input-pipeline-free ceiling.
+1. ``synthetic``  — device-resident tensors: the input-pipeline-free
+   ceiling.
 2. ``stream``     — per-step host pipeline: memmap gather
    (horovod_tpu.data.MemmapArrayDataset + DistributedSampler) -> uint8
    host->device upload -> on-device cast. The classic streaming shape.
@@ -21,8 +21,8 @@ bench.py, fed four ways —
    pipeline cannot be the bottleneck because it does not exist at step time.
 
 Mode 3 exists because per-step streaming pays a host->device transfer
-every step (docs/benchmarks.md "Real-data input pipeline" records what an
-earlier installation measured; not measured on this one). Device-cache
+every step (what it costs has not been measured on this installation:
+PERF.md §7 plans a `resnet50_hoststream_1chip` cell). Device-cache
 wins everywhere the shard fits HBM.
 
 4. ``device-cache-scan`` — mode 3 through the packaged API
@@ -97,10 +97,10 @@ def main() -> int:
                                   MemmapArrayDataset)
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    import bench
+    import chip_smoke
 
     hvd.init()
-    bench.require_tpu("realdata_benchmark (the bench.py ResNet-50 step)")
+    step, state0, (x_syn, y_syn), batch, n_dev = chip_smoke._build()
 
     ensure_dataset(args.data_dir, args.n_images, 224)
     ds = MemmapArrayDataset(args.data_dir)
@@ -110,13 +110,9 @@ def main() -> int:
     if "device-cache" in modes or "device-cache-scan" in modes:
         imgs, labs = ds[shard_idx]
         # horovod_tpu.data.DeviceCache: this rank's shard in HBM + the
-        # sampler contract in-jit. Batch size must match the train step's.
-        per_dev = int(os.environ.get("HVD_BENCH_BATCH") or 128)
-        cache = DeviceCache(imgs, labs, batch_size=per_dev * len(jax.devices()),
-                            seed=sampler.seed)
+        # sampler contract in-jit, at the train step's batch size.
+        cache = DeviceCache(imgs, labs, batch_size=batch, seed=sampler.seed)
         jax.block_until_ready(cache.data)
-
-    step, state0, (x_syn, y_syn), batch, n_dev = bench._build()
 
     @jax.jit
     def cast_norm(x_u8):
@@ -130,7 +126,7 @@ def main() -> int:
                                            tuple(state0)))
 
     def measure(run_step):
-        """bench.py protocol: chained dispatches, one loss fence per window,
+        """Chained dispatches, one loss fence per window,
         median over reps. run_step(state) -> (state, loss)."""
         state = fresh_state()
         loss = None
@@ -201,7 +197,7 @@ def main() -> int:
         # The packaged API: cache sampling + K steps per dispatch in ONE
         # jitted loop (hvd.jax.make_scan_train_loop) — amortizes dispatch
         # latency on top of eliminating per-step transfers. train_step
-        # adapts bench's 4-state step to the loop's 3-state contract by
+        # adapts the 4-state step to the loop's 3-state contract by
         # folding batch_stats into the optimizer-state slot.
         K = args.scan_steps  # <1 rejected by make_scan_train_loop
 
@@ -216,7 +212,7 @@ def main() -> int:
         packed = {"done": False}
 
         def scan_step(state):
-            if not packed["done"]:  # first call: fold bench's 3-part state
+            if not packed["done"]:  # first call: fold the step's 3-part state
                 p, bstats, ostate = state
                 state = [p, (bstats, ostate), cache.counter()]
                 packed["done"] = True

@@ -7,7 +7,7 @@ Full training step: forward + backward + fused-allreduce AdamW update over
 the local data-parallel mesh; bf16 activations, f32 params. The attention
 tier is selectable (--attention dense|flash, --kv-heads for GQA), which is
 the point of the harness: at --seq-len 8192 the dense schedule cannot
-compile while flash trains (docs/benchmarks.md).
+compile while flash trains.
 
     python examples/transformer_benchmark.py --seq-len 4096 --attention flash
 """
@@ -84,9 +84,9 @@ def main():
                              "shape)")
     parser.add_argument("--profile", action="store_true",
                         help="after measuring, profile the step with the XLA "
-                             "device profiler and print the per-op roofline "
-                             "(horovod_tpu/utils/roofline.py) — names where "
-                             "the non-attention time goes")
+                             "device profiler and print where its device "
+                             "time goes, by the program's names "
+                             "(hvd.metrics.profile_step)")
     args = parser.parse_args()
     if args.bf16_logits and args.loss_chunk:
         parser.error("--bf16-logits does not reach the --loss-chunk path "
@@ -303,7 +303,7 @@ def measure(args, mesh, n_dev, block_q, block_k):
             check_vma=False,
         ), donate_argnums=(0, 1))
 
-    # Median-window methodology shared with bench.py/the autotuner
+    # Median-window methodology shared with the autotuner
     # (measure_steps_per_s): chained dispatches per window, one hard sync at
     # each window end, median of 3 windows — a transient hiccup perturbs
     # one window, not the reported number.
@@ -331,12 +331,10 @@ def measure(args, mesh, n_dev, block_q, block_k):
     if getattr(args, "profile", False):
         # All ranks run the collective steps (rank-0-only would deadlock a
         # multi-process world); rank 0 prints.
-        from horovod_tpu.utils.roofline import (format_report,
-                                                profile_device_ops)
-
-        rep = profile_device_ops(run, steps=3, sync=sync)
+        rep = hvd.metrics.profile_step(run, steps=3, sync=sync)
         if hvd.rank() == 0:
-            print(format_report(rep))
+            print(rep["text"] if rep["ok"] else
+                  f"profile: unavailable ({rep['reason']})")
     return batch * args.seq_len * rate, loss_box[0]
 
 

@@ -328,9 +328,10 @@ def restore_sharded(path: str, template: Any, plan,
 
 def merge_stacked_stats(stats: Any, axis: int = 0) -> Any:
     """Consolidate per-device batch statistics that carry a leading device
-    dimension (the single-process sharded layout: bench.py keeps one BN-stat
-    row per mesh position) into single-replica values by averaging over
-    ``axis``. Pure function — usable inside or outside jit."""
+    dimension (the single-process sharded layout: chip_smoke.py's ResNet
+    step keeps one BN-stat row per mesh position) into single-replica values
+    by averaging over ``axis``. Pure function — usable inside or outside
+    jit."""
     import jax
     import jax.numpy as jnp
 

@@ -2,7 +2,7 @@
 
 ``HOROVOD_COMPRESSION=adaptive`` hands the wire-format choice to this
 controller instead of one global knob. The insight from PowerSGD (Vogels
-et al., 2019) and the SCALING_r05 projection is that *which* compressor
+et al., 2019) is that *which* compressor
 wins is tensor- and bandwidth-dependent: the ICI/intra-host fabric is
 rarely the bottleneck (full width is free there), while the DCN/cross-pod
 hop is the scaling cliff — worth paying topk's select/merge cost for a

@@ -16,8 +16,8 @@ dtype* every data plane uses:
 - the native C++ engine reads the same ``HOROVOD_COMPRESSION`` env knob
   (cc/src/engine.cc) and casts at enqueue.
 
-The helpers here are deliberately importable WITHOUT jax (the eager engine
-and ``bench.py --eager-worker`` never import a backend): jax.numpy is only
+The helpers here are deliberately importable WITHOUT jax (the eager engine's
+workers never import a backend): jax.numpy is only
 pulled in lazily by the Compressor classes, and the numpy-side wire-dtype
 resolution uses ml_dtypes for bfloat16.
 """

@@ -28,8 +28,8 @@ that already exist:
 
 ``HOROVOD_CONTROLLER=1`` arms the serving-side controller in the routers
 (serving/server.py, serving/llm/server.py); the training-side controller
-is constructed explicitly (bench.py ``--controller-ab``,
-tools/controller_smoke.py) because it needs the job's step loop.
+is constructed explicitly (tools/controller_smoke.py) because it needs
+the job's step loop.
 """
 
 from .core import ControlLoop, Knob, Proposal

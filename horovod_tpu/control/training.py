@@ -161,7 +161,7 @@ class TrainingController:
             if self.engine is not None:
                 self.engine.set_knobs({name: value})
             elif self.rejit is not None:
-                # Compiled-plane-only job (bench --controller-ab): the wire
+                # Compiled-plane-only job (no eager engine): the wire
                 # format is a trace-time constant there, so re-jitting is
                 # the switch mechanism for it too.
                 self.rejit({name: value})

@@ -115,8 +115,8 @@ class DeviceCache:
     gather, on-device uint8->f32 cast. Zero host->device bytes at step
     time, so the input pipeline cannot become the bottleneck; the
     reference's real-data recipe (docs/benchmarks.md:40-63) streams per
-    step and relies on loader-worker overlap instead. Measured comparison:
-    docs/benchmarks.md "Real-data input pipeline".
+    step and relies on loader-worker overlap instead. The comparison is
+    examples/realdata_benchmark.py's; not measured on this installation.
 
     Shuffle contract — WEAKER than :class:`DistributedSampler`, on
     purpose: the rank's shard is FIXED at upload, and each epoch reshuffles

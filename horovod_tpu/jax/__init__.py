@@ -25,7 +25,7 @@ plane and device-resident input):
   inputs under ``hvdrun --jax-distributed`` (docs/running.md).
 - :func:`make_scan_train_loop` — K optimizer steps per dispatch drawing
   batches from a :class:`horovod_tpu.data.DeviceCache`; amortizes
-  per-dispatch and per-transfer latency (docs/benchmarks.md r5).
+  per-dispatch and per-transfer latency.
 
 Everything here runs inside shard_map/pmap over a named mesh axis (default
 ``'hvd'``); use horovod_tpu.run_on_mesh / shard_map directly to enter SPMD.
@@ -219,7 +219,7 @@ def DistributedOptimizer(
     ``backward_passes_per_step`` (buckets split the one post-accumulation
     allreduce) and with ``hierarchical`` (each bucket rides the
     RS→psum→AG ladder independently). Autotuned jointly with
-    ``fusion_threshold`` by ``bench.py --buckets-ab`` / jax.autotune.tune.
+    ``fusion_threshold`` by jax.autotune.tune.
 
     ``compression`` (or HOROVOD_COMPRESSION) = ``hvd.Compression.bf16`` /
     ``fp16`` halves the bytes each bucket's collective moves: eligible
@@ -227,7 +227,7 @@ def DistributedOptimizer(
     (non-float and tiny buckets opt out per bucket). bf16 is the TPU pick —
     fp32 exponent range, so no loss scaling. The wire dtype joins the
     ``(fusion_threshold, num_buckets)`` joint autotune as a third dimension
-    (``bench.py --compression-ab``), where ``"topk@<ratio>"`` specs put
+    (``autotune.tune(compressions=...)``), where ``"topk@<ratio>"`` specs put
     the sparse ratio on the same categorical axis (ISSUE 9).
     ``hvd.Compression.topk`` / ``adaptive`` resolve here too: the eager
     engines sparsify / apply the per-tier policy, while this compiled
@@ -449,9 +449,9 @@ def make_scan_train_loop(train_step, cache, steps_per_dispatch: int = 8,
 
     Two costs motivate it: per-dispatch host latency, amortized over K
     steps, and per-step host→device transfers, which are zero here because
-    batches come from the device-resident cache. (docs/benchmarks.md r5
-    records what an earlier installation measured; not measured on this
-    one.)
+    batches come from the device-resident cache. (Neither cost has been
+    measured on this installation: examples/realdata_benchmark.py is the
+    harness.)
 
     ``train_step(params, opt_state, x, y) -> (params, opt_state, loss)``.
     Returns a jitted function

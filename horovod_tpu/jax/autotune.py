@@ -18,7 +18,7 @@ native Gaussian process (cc/src/autotuner.h via autotune.gp_fit_predict —
 the same GP/EI math the eager tuner runs, given a Python face over measured
 jit steps).
 
-Usage (bench.py --autotune wires this to the ResNet-50 step):
+Usage:
 
     def step_factory(fusion_threshold, compression, hierarchical):
         opt = hvd.jax.DistributedOptimizer(optax.sgd(...),
@@ -111,8 +111,8 @@ class TuneReport:
 def measure_steps_per_s(run_step: Callable[[], None], warmup: int = 2,
                         iters: int = 5, reps: int = 3,
                         sync: Optional[Callable[[], None]] = None) -> float:
-    """Median-window step rate — THE timing methodology (bench.py uses this
-    too): warmup for compile, chain ``iters`` dispatches per timed window
+    """Median-window step rate — THE timing methodology (the examples use
+    it too): warmup for compile, chain ``iters`` dispatches per timed window
     with ONE host sync at the window end (per-step syncs would add a host
     round trip to every step), median of ``reps`` windows.
 

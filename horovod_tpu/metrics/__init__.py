@@ -18,8 +18,7 @@ One always-on, process-local registry that every layer reports through:
 - ``profile_step`` — where a warmed step's device time goes, by the names of
   ``common/device_names.py`` (device_profile.py; imported on first use).
 - ``merge_snapshots`` — pod-wide aggregation of per-rank snapshots
-  (aggregate.py; used by the runner's DriverService, MetricsCallback and
-  ``bench.py --metrics``).
+  (aggregate.py; used by the runner's DriverService and MetricsCallback).
 
 Full reference: docs/metrics.md.
 """

@@ -4,8 +4,8 @@ A snapshot (registry.MetricsRegistry.snapshot) is process-local. The pod
 view merges one snapshot per rank — collected either by the launcher's
 DriverService (workers attach a snapshot to their result payload and may
 push mid-run ``metrics`` messages, runner/service.py) or in-band over the
-eager engine (`hvd.allgather_object`, used by callbacks.MetricsCallback and
-``bench.py --metrics``). Merge rules:
+eager engine (`hvd.allgather_object`, used by callbacks.MetricsCallback).
+Merge rules:
 
 - counters: summed (they are per-rank totals; the pod total is the sum);
 - gauges: min / max / mean across ranks (a pod has no single "the" value —

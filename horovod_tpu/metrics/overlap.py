@@ -21,7 +21,7 @@ complementary instruments fix that:
    carries per-op device spans (TPU); on CPU hosts the report is
    ``ok=False`` and only the plan gauges are populated.
 
-Both write the same registry, so `bench.py --metrics` snapshots carry
+Both write the same registry, so a metrics snapshot carries
 `horovod_overlap_*` gauges either way.
 """
 

@@ -452,7 +452,7 @@ _ONE_SUBLAYER = {"mamba_only": "mixer", "attention_only": "mixer",
 
 
 class TransformerLM(nn.Module):
-    # TPU sizing note (docs/benchmarks.md "head_dim and the MXU"): prefer
+    # TPU sizing note: prefer
     # head_dim = dim // heads >= 128 where the architecture is yours to
     # choose. The MXU contracts 128 lanes per pass, so head_dim 64 runs every
     # attention matmul at half width. Round 3 (the kernels of that time)
@@ -511,8 +511,8 @@ class TransformerLM(nn.Module):
     # dtype of the lm_head matmul AND the stored logits. f32 (default) is
     # the conservative choice; bf16 halves the logits pipeline's HBM
     # traffic (B*T*vocab bytes through head matmul epilogue, reshape,
-    # softmax-CE and its backward — measured ~10% of the 4k batch-1 step,
-    # docs/benchmarks.md r5 rows). With bf16, upcast to f32 BEFORE the
+    # softmax-CE and its backward — ~10% of the 4k batch-1 step on an
+    # earlier installation). With bf16, upcast to f32 BEFORE the
     # cross entropy (the convert fuses into the CE read, costing no HBM):
     # the remaining numerics change is the one-time bf16 rounding of the
     # logit values themselves. Kernel params stay f32 either way.

@@ -693,8 +693,8 @@ def _fit_block(t, want, quantum):
 
 # Default kernel tiles — the single source of truth (Block/TransformerLM
 # and the benchmark read these). Chosen by the r3 sweep
-# (examples/transformer_benchmark.py --sweep-blocks, table in
-# docs/benchmarks.md) at D=64, with the forward the kernels had then:
+# (examples/transformer_benchmark.py --sweep-blocks, on an earlier
+# installation) at D=64, with the forward the kernels had then:
 # 1024/1024 won at every feasible sequence length on v5e (+12% over the old
 # 1024/512 at seq 4k, +27% at 16k); block_q=2048 exceeds the backward
 # kernel's scoped VMEM (19.3M > 16M). Not re-swept at D=128 since the

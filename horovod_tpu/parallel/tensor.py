@@ -255,6 +255,6 @@ def tp_rank_pairs(pairs: Sequence[dict], model_size: int, rank: int) -> list:
 def tp_wire_bytes_per_pair(batch: int, d_out: int,
                            dtype=jnp.float32) -> int:
     """Bytes ONE pair's psum moves per device per step (the activation
-    tensor, at its storage dtype) — the analytic figure bench.py --tp-ab
-    checks its measured plan against."""
+    tensor, at its storage dtype) — the analytic TP wire volume a
+    measured plan is checked against."""
     return int(batch) * int(d_out) * jnp.dtype(dtype).itemsize

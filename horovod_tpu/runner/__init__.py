@@ -220,8 +220,8 @@ def run(fn: Callable, args: tuple = (), kwargs: Optional[dict] = None,
 def _emit_pod_metrics(driver: DriverService) -> None:
     """Pod-wide telemetry at job end (ISSUE 2): every worker attached its
     final metrics snapshot to its result payload; write the merged view to
-    HOROVOD_METRICS_SNAPSHOT when set (JSON file — the launcher-side analog
-    of bench.py --metrics) and log a one-line summary. Never fatal."""
+    HOROVOD_METRICS_SNAPSHOT when set (a JSON file) and log a one-line
+    summary. Never fatal."""
     path = os.environ.get("HOROVOD_METRICS_SNAPSHOT", "")
     try:
         pod = driver.pod_metrics()

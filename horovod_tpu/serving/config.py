@@ -3,7 +3,7 @@
 All knobs are environment variables with the ``HOROVOD_SERVE_`` prefix
 (README "serving" table, docs/inference.md), resolved once at server
 construction by :meth:`ServeConfig.from_env`; programmatic overrides win
-over the environment so tests and ``bench.py --serve`` can pin a config
+over the environment so tests and tools/serve_smoke.py can pin a config
 without mutating ``os.environ``.
 """
 

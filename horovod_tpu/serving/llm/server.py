@@ -15,7 +15,7 @@ one ``both``-role pool, prompts go straight into the decode engine and
 the handoff never serializes (``horovod_serve_llm_handoffs_total{
 path="local"}`` vs ``{path="wire"}``).
 
-Programmatic use (tests, ``bench.py --serve-llm``, tools/llm_smoke.py)::
+Programmatic use (tests, tools/llm_smoke.py)::
 
     server = llm.LLMServer().start()      # TinyLM from the seed knobs
     server.wait_ready(60)

@@ -7,7 +7,7 @@ replica can jit: ``builder(state) -> apply_fn`` with
 builders are named by an importable ``"module:function"`` spec (the same
 convention the launcher uses for entry points) rather than passed as
 closures. :func:`mlp_builder` is the built-in used by the smoke tests and
-``bench.py --serve``; real deployments point at their own model module.
+tools/serve_smoke.py; real deployments point at their own model module.
 
 jax imports stay inside functions: the ROUTER process imports this module
 for the builder-spec validation and must never pay (or wedge on) backend

@@ -407,7 +407,7 @@ class RankTelemetryClient:
 
     def push(self, snap: Optional[dict] = None) -> dict:
         """Push the current snapshot (delta-compressed); returns the wire
-        request actually sent (tests and the bench read its size)."""
+        request actually sent (tests and the smokes read its size)."""
         snap = snap if snap is not None else self._snapshot()
         with self._lock:
             acked, seq = self._acked, self._seq

@@ -1,9 +1,9 @@
 """Where the persistent XLA compilation cache lives.
 
-One rule for every entry point (``chip_smoke.py``, ``bench.py``, the test
-harness): a directory placed from outside with ``JAX_COMPILATION_CACHE_DIR``
-is JAX's to read — nothing is set in code, so the operator's directory is
-the only one used. Without it the cache sits at a FIXED path inside the
+One rule for every entry point (``chip_smoke.py``, ``benchmarks/run.py``,
+the test harness): a directory placed from outside with
+``JAX_COMPILATION_CACHE_DIR`` is JAX's to read — nothing is set in code, so
+the operator's directory is the only one used. Without it the cache sits at a FIXED path inside the
 checkout (``<checkout>/.jax_cache``): the path is part of the cache's
 identity across runs, so it carries no pid, time or temp component.
 

@@ -1,29 +1,10 @@
-"""Roofline measurement from the XLA device profile.
+"""Published per-chip peaks, for the "% of roof" figures.
 
-Answers "is this step compute- or HBM-bound?" with measured numbers
-instead of assertions (VERDICT r3 weak #1): run a step under
-``jax.profiler.trace``, parse the trace's per-op ``bytes_accessed`` /
-``model_flops`` / ``device_duration_ps`` fields, and aggregate achieved
-bandwidth and FLOP rate per HLO category.
-
-Caveats, stated once here and echoed in docs/benchmarks.md: XLA's
-``bytes_accessed`` is the compiler's MODEL of memory traffic (operand +
-output bytes per op), not a DRAM counter — ops whose operands sit in
-VMEM/SMEM can "exceed" the HBM roof, and re-read operands are counted per
-op. The per-category rates over multi-millisecond windows are still the
-standard roofline evidence: a category sustaining ~90% of nominal HBM
-bandwidth for most of the step IS bandwidth-bound.
+The table and its accessor alone: where a step's device time goes is read by
+``hvd.metrics.profile_step`` (``metrics/device_profile.py``).
 """
 
 from __future__ import annotations
-
-import collections
-import glob
-import gzip
-import json
-import os
-import tempfile
-from typing import Callable, Optional
 
 # Published per-chip peaks for the "% of roof" columns, keyed by
 # ``jax.devices()[0].device_kind``. Source: Google Cloud documentation,
@@ -46,117 +27,3 @@ def device_peaks(device_kind: str) -> dict:
             f"{sorted(DEVICE_PEAKS)}); add it to "
             "horovod_tpu.utils.roofline.DEVICE_PEAKS with its source"
         ) from None
-
-
-def _load_latest_trace(logdir: str) -> list:
-    paths = sorted(glob.glob(os.path.join(logdir, "**", "*.trace.json.gz"),
-                             recursive=True))
-    if not paths:
-        raise FileNotFoundError(f"no trace.json.gz under {logdir}")
-    with gzip.open(paths[-1]) as f:
-        return json.load(f)["traceEvents"]
-
-
-def profile_device_ops(run_step: Callable[[], None], steps: int = 5,
-                       sync: Optional[Callable[[], None]] = None,
-                       logdir: Optional[str] = None) -> dict:
-    """Profile ``steps`` calls of ``run_step`` and aggregate device ops.
-
-    The caller must have warmed the step (compile outside the trace).
-    Returns a report dict; ``ok=False`` with a reason when the platform's
-    trace carries no per-op cost fields (e.g. CPU)."""
-    import jax
-
-    fence = sync or (lambda: None)
-    logdir = logdir or tempfile.mkdtemp(prefix="hvd_roofline_")
-    with jax.profiler.trace(logdir):
-        for _ in range(steps):
-            run_step()
-        fence()
-    ev = _load_latest_trace(logdir)
-    pids = {e["pid"]: e["args"].get("name", "")
-            for e in ev if e.get("ph") == "M" and e.get("name") == "process_name"
-            and "args" in e}
-
-    cat = collections.defaultdict(lambda: [0.0, 0, 0])   # t_s, bytes, flops
-    ops = collections.defaultdict(lambda: [0.0, 0, 0])
-    tot_t = 0.0
-    tot_b = tot_f = 0
-    for e in ev:
-        if e.get("ph") != "X":
-            continue
-        a = e.get("args") or {}
-        if "device_duration_ps" not in a:
-            continue
-        pname = pids.get(e["pid"], "")
-        if "TPU" not in pname:
-            continue
-        c = a.get("hlo_category")
-        if c is None:
-            continue  # envelopes (jit_..., per-step frames) — no cost fields
-        t = int(a["device_duration_ps"]) / 1e12
-        b = int(a.get("bytes_accessed", 0))
-        f = int(a.get("model_flops", 0) or 0)
-        for table, key in ((cat, c), (ops, a.get("tf_op", e["name"]))):
-            table[key][0] += t
-            table[key][1] += b
-            table[key][2] += f
-        tot_t += t
-        tot_b += b
-        tot_f += f
-    if tot_t == 0:
-        return {"ok": False,
-                "reason": "no TPU device ops with cost fields in trace "
-                          f"(tracks: {sorted(set(pids.values()))})"}
-    device_kind = jax.devices()[0].device_kind
-    hbm_gbs = device_peaks(device_kind)["hbm_gbs"]
-
-    def row(key, t, b, f):
-        return {
-            "name": key,
-            "ms_per_step": round(t / steps * 1e3, 3),
-            "gbs": round(b / t / 1e9, 1) if t else 0.0,
-            "pct_hbm_roof": round(b / t / 1e9 / hbm_gbs * 100, 1) if t else 0.0,
-            "tflops": round(f / t / 1e12, 2) if t else 0.0,
-        }
-
-    categories = [row(k, *v) for k, v in
-                  sorted(cat.items(), key=lambda kv: -kv[1][0])]
-    top_ops = [row(k, *v) for k, v in
-               sorted(ops.items(), key=lambda kv: -kv[1][0])[:12]]
-    return {
-        "ok": True,
-        "device_kind": device_kind,
-        "steps": steps,
-        "device_ms_per_step": round(tot_t / steps * 1e3, 2),
-        "model_bytes_gb_per_step": round(tot_b / steps / 1e9, 2),
-        "achieved_gbs": round(tot_b / tot_t / 1e9, 1),
-        "pct_hbm_roof": round(tot_b / tot_t / 1e9 / hbm_gbs * 100, 1),
-        "model_tflop_per_step": round(tot_f / steps / 1e12, 3),
-        "achieved_tflops": round(tot_f / tot_t / 1e12, 1),
-        "categories": categories,
-        "top_ops": top_ops,
-        "logdir": logdir,
-    }
-
-
-def format_report(rep: dict) -> str:
-    if not rep.get("ok"):
-        return f"roofline: unavailable ({rep.get('reason')})"
-    kind = rep["device_kind"]
-    bf16_tflops = device_peaks(kind)["bf16_tflops"]
-    lines = [
-        f"device busy {rep['device_ms_per_step']} ms/step | "
-        f"XLA-model bytes {rep['model_bytes_gb_per_step']} GB/step | "
-        f"achieved {rep['achieved_gbs']} GB/s "
-        f"({rep['pct_hbm_roof']}% of {kind} HBM) | "
-        f"{rep['achieved_tflops']} TFLOP/s "
-        f"({round(rep['achieved_tflops'] / bf16_tflops * 100, 1)}% of bf16 peak)",
-        f"{'category':<24}{'ms/step':>9}{'GB/s':>8}{'%roof':>7}{'TFLOP/s':>9}",
-    ]
-    for r in rep["categories"]:
-        if r["ms_per_step"] < 0.01:
-            continue
-        lines.append(f"{r['name']:<24}{r['ms_per_step']:>9}{r['gbs']:>8}"
-                     f"{r['pct_hbm_roof']:>7}{r['tflops']:>9}")
-    return "\n".join(lines)

@@ -15,7 +15,6 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-import bench  # noqa: E402
 import chip_smoke  # noqa: E402
 
 
@@ -26,6 +25,37 @@ def test_off_chip_exits_nonzero_and_names_the_platform():
     assert proc.returncode != 0
     assert "platform='cpu'" in proc.stderr
     assert '"ok"' not in proc.stdout          # no result line
+
+
+def test_build_raises_off_chip(hvd):
+    """The full-width ResNet step never shrinks to fit a CPU: without a TPU
+    it exits non-zero and the message names the platform it found."""
+    with pytest.raises(SystemExit, match="platform='cpu'"):
+        chip_smoke._build()
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("hierarchical", [False, True])
+def test_build_runs_one_step(hvd, hierarchical):
+    """The step builds and runs on the virtual mesh in BOTH data-plane
+    shapes (flat hvd axis and the hierarchical ('dcn','ici') ladder)."""
+    import jax
+    import numpy as np
+
+    from horovod_tpu.models import ResNet50
+
+    step, state, (x, y), batch, n_dev = chip_smoke.build_resnet_step(
+        ResNet50(num_classes=1000), image=32, per_dev_batch=1,
+        hierarchical=hierarchical)
+    assert x.sharding.device_set == set(jax.devices())
+    # snapshot BEFORE the call: the step donates its inputs
+    leaves0 = [np.array(a) for a in jax.tree_util.tree_leaves(state[0])]
+    params, batch_stats, opt_state, loss = step(*state, x, y)
+    assert np.isfinite(float(loss))
+    assert batch == n_dev  # 1 per device
+    # the step must actually move parameters (optimizer ran)
+    leaves1 = [np.asarray(a) for a in jax.tree_util.tree_leaves(params)]
+    assert any(not np.array_equal(a, b) for a, b in zip(leaves0, leaves1))
 
 
 def test_collective_check(hvd, capsys):
@@ -51,7 +81,8 @@ def test_tiny_resnet_step(hvd, capsys):
     tiny = ResNet(stage_sizes=(1, 1), block_cls=BottleneckBlock,
                   num_classes=10, num_filters=8)
     chip_smoke.phase_resnet(
-        bench.build_resnet_step(tiny, image=32, per_dev_batch=2), steps=5)
+        chip_smoke.build_resnet_step(tiny, image=32, per_dev_batch=2),
+        steps=5)
     line = capsys.readouterr().out
     assert "phase=resnet" in line and "replicas_bit_equal=True" in line
 

@@ -94,7 +94,7 @@ def test_device_cache_epoch_contract():
     """DeviceCache.sample visits every shard row exactly once per epoch in a
     seeded order that changes across epochs — the in-jit realization of
     DistributedSampler.set_epoch's reshuffle contract (the device-resident
-    pipeline of docs/benchmarks.md 'Real-data input pipeline')."""
+    pipeline of examples/realdata_benchmark.py)."""
     import jax
     import jax.numpy as jnp
 

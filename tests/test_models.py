@@ -1,5 +1,5 @@
 """Model zoo shape/grad sanity — every benchmark family the reference
-measures (ResNet, VGG, Inception; docs/benchmarks.md) plus the long-context
+measures (ResNet, VGG, Inception; BASELINE.md) plus the long-context
 transformer builds, runs forward, and produces finite gradients."""
 
 import jax

@@ -17,7 +17,8 @@ Coverage map:
   dense DP oracle within pinned tolerance, with the model-stacked
   ``(model*shard, chunk)`` host layout and ``P(('model','shard'))``
   specs;
-- trace-time gauges record the model axis.
+- trace-time gauges record the model axis;
+- per-chip parameter + optimizer state falls >= 1.8x at model=2.
 """
 
 from __future__ import annotations
@@ -409,6 +410,28 @@ def test_tp_gauges_record_model_axis(mesh8):
     plan = hvd_metrics.last_shard_plan()
     assert plan is not None
     assert plan["batch"] == 2 and plan["shard"] == 2 and plan["model"] == 2
+
+
+def test_tp_halves_per_chip_state(mesh8):
+    """At model=2 beside shard=2 a chip holds >= 1.8x less parameter +
+    optimizer state than at model=1 (adam, the same two pairs; the slack
+    is one bucket's pad)."""
+    del mesh8
+    # Bytes alone are counted: two 64 -> 512 -> 64 pairs of zeros.
+    pairs = [{"w_col": jnp.zeros((64, 512)), "b_col": jnp.zeros((512,)),
+              "w_row": jnp.zeros((512, 64)), "b_row": jnp.zeros((64,))}] * 2
+    shard = 2
+
+    def per_chip(model):
+        local = tp.tp_local_pairs(pairs, model)
+        plan = sh.build_shard_plan(local[0], shard, model_size=model)
+        sp = sh.shard_params_model(local, plan)
+        opt = hvd.jax.DistributedOptimizer(optax.adam(1e-3), sharded=True,
+                                           shard_plan=plan)
+        return sh.state_bytes({"params": sp, "opt": opt.init(sp)}) // (
+            model * shard)
+
+    assert per_chip(1) / per_chip(2) >= 1.8
 
 
 # ------------------------------------------------------- sixth dimension

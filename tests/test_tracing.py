@@ -362,7 +362,7 @@ def test_perf_gate_skips_nonzero_rc_bench_records(tmp_path):
     import perf_gate
 
     bad = _write(tmp_path, "BENCH_bad.json", {
-        "n": 5, "cmd": "python bench.py", "rc": 124, "parsed": None,
+        "n": 5, "cmd": "python some_bench", "rc": 124, "parsed": None,
         # A metric line stranded in the killed process's stderr tail:
         # scraping it would fabricate a 9000 img/s baseline.
         "tail": json.dumps(dict(REC, value=9000.0))})

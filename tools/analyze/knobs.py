@@ -29,7 +29,7 @@ from .common import (KNOB_MENTION_RE, Finding, make_finding,
 REGISTRY_REL = os.path.join("docs", "config_registry.json")
 
 #: python scan scope (tools/analyze is always excluded by py_files)
-PY_SCOPE = ("horovod_tpu", "tools", "bench.py")
+PY_SCOPE = ("horovod_tpu", "tools")
 CPP_DIR = os.path.join("horovod_tpu", "cc", "src")
 DOC_FILES = ("README.md",)
 DOC_DIR = "docs"
@@ -219,7 +219,7 @@ def check(root: str, extracted: Optional[dict] = None) -> list[Finding]:
             findings.append(make_finding(
                 "knobs", "documented-dead", name,
                 f"{name} appears in README/docs but nothing in "
-                "horovod_tpu/, tools/ or bench.py reads or sets it — "
+                "horovod_tpu/ or tools/ reads or sets it — "
                 "delete the stale mention or alias the knob"))
     return findings
 

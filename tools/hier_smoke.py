@@ -11,7 +11,7 @@ asserts the two-level contract end to end:
    coordinator relays zero tensor bytes either way;
 2. cross-host bytes: the two-level plane's worst-rank cross-host bytes are
    <= 0.35x the flat ring's (measured ~1/3 on 2x2: 2*(B/L)*(C-1)/C against
-   the flat boundary rank's 2*B*(N-1)/N — the SCALING_r05 cliff, cut);
+   the flat boundary rank's 2*B*(N-1)/N);
 3. bitwise identity: flat == hier == star, uncompressed AND under bf16
    wire compression. Payloads are integer-valued floats, so every
    accumulation order is exact (f64/f32/bf16 alike) and any hash mismatch
@@ -175,7 +175,7 @@ def main() -> int:
             fail(f"rank {r['rank']} ({r['plane']}): coordinator relayed "
                  f"{r['star_bytes']} tensor bytes (want 0)")
 
-    # 2. the cross-byte cut (the SCALING_r05 cliff): worst-rank cross-host
+    # 2. the cross-byte cut: worst-rank cross-host
     #    bytes <= 0.35x flat (measured ~1/3 on the 2x2 grid).
     flat_cross = max(r["tier_cross"] for r in flat)
     hier_cross = max(r["tier_cross"] for r in hier)

@@ -1,15 +1,17 @@
 #!/usr/bin/env python
-"""CI perf-regression gate (ISSUE 6): compare bench.py structured output
-against baselines and exit nonzero on a regression.
+"""CI perf-regression gate (ISSUE 6): hold the structured output of the
+``tools/*_smoke.py`` legs to floors and baselines; exit nonzero on a
+regression.
 
-Every bench mode prints one JSON line ``{"metric": ..., "value": ...,
-"unit": ...}`` (the _Budget contract guarantees the line appears even on a
-wedged run, flagged ``"partial": true``). This gate reads those lines from:
+A smoke prints one JSON line ``{"metric": ..., "value": ..., "unit": ...}``
+per gated figure (``"partial": true`` flags a line of a wedged run, which
+the gate skips). This gate reads those lines from:
 
-- ``--current FILE`` — the run under test (a bench log, a raw JSON line,
+- ``--current FILE`` — the run under test (a smoke's log, a raw JSON line,
   or a harness-shaped ``{"parsed": {...}}`` file);
-- ``--baseline FILE`` / ``--history GLOB`` — prior results
-  (``BASELINE.json``, ``BENCH_r0*.json``, or saved bench logs).
+- ``--baseline FILE`` / ``--history GLOB`` — prior results (saved logs or
+  harness-shaped records; ci.sh passes none: its legs gate on
+  ``--require-metric`` / ``--min-abs`` alone).
 
 A current metric is compared against the BEST comparable baseline value —
 same metric name and same smoke flag (a tiny-model CPU smoke number must

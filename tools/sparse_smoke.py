@@ -8,7 +8,7 @@ end to end:
 
 1. DCN byte cut: with HOROVOD_COMPRESSION=topk at HOROVOD_TOPK_RATIO=0.01
    the two-level plane's worst-rank cross-host (DCN) wire bytes drop
-   >= 10x vs the dense hier world — the SCALING_r05 cliff, cut again;
+   >= 10x vs the dense hier world;
 2. bitwise identity with sparsification ON: star == flat ring == hier.
    Payloads are integer-valued floats with partial sums inside f32's
    exact-integer range, so every fold order is exact and any hash
