@@ -13,6 +13,12 @@ FLASH_BWD_DKV = "hvd_flash_bwd_dkv"
 FLASH_WIN_FWD = "hvd_flash_win_fwd"
 FLASH_WIN_BWD_DQ = "hvd_flash_win_bwd_dq"
 FLASH_WIN_BWD_DKV = "hvd_flash_win_bwd_dkv"
+# The same three kernels under a selection that is data
+# (``ops.flash_attention.selected_attention``): the mask one more operand, a
+# block step with no selected pair fetched and run by nobody.
+FLASH_SEL_FWD = "hvd_flash_sel_fwd"
+FLASH_SEL_BWD_DQ = "hvd_flash_sel_bwd_dq"
+FLASH_SEL_BWD_DKV = "hvd_flash_sel_bwd_dkv"
 RING_FLASH_FWD = "hvd_ring_flash_fwd"
 RING_FLASH_BWD_DQ = "hvd_ring_flash_bwd_dq"
 RING_FLASH_BWD_DKV = "hvd_ring_flash_bwd_dkv"
@@ -61,6 +67,14 @@ MAMBA_GATE_NORM_FWD = "hvd_mamba_gate_norm_fwd"
 MAMBA_GATE_NORM_BWD = "hvd_mamba_gate_norm_bwd"
 SSD_SCAN = "hvd_ssd_scan"               # the scan over blocks of chunks: chunk
 #                                         states, the recurrence, the outputs
+
+# Learned sparse attention (ops/sparse_attention.py, ``Block.sparse``). The
+# benchmark finds each part's time by the scope's name and its kernel's.
+DSA_INDEXER = "hvd_dsa_indexer"         # the indexer's projections, norm, rotary
+DSA_INDEXER_SCORES = "hvd_dsa_indexer_scores"   # its score tiles' kernel
+DSA_SELECT = "hvd_dsa_select"           # the exact top-k of a chunk + packing
+DSA_ALIGN = "hvd_dsa_align"             # the alignment loss's relayouts and sums
+DSA_ALIGN_TILES = "hvd_dsa_align_tiles"     # its kernel: p, r, KL, the indexer's backward
 
 # Names that a number completes in the module (``hvd_fused_allreduce_k3``); a
 # reader of a device profile finds these by prefix, every other by equality.

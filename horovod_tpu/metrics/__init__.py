@@ -35,6 +35,7 @@ from .overlap import (  # noqa: F401
     last_wire_plan,
     measure_overlap,
     record_chunked_loss_plan,
+    record_dsa_census,
     record_flash_plan,
     record_flash_window_plan,
     record_mamba_fused_passes,

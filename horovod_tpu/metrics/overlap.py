@@ -263,6 +263,46 @@ def record_moe_live_rows(live_rows, window: int) -> None:
           / len(steps))
 
 
+def record_dsa_census(census, dense_steps: int) -> None:
+    """Record what the sparse-attention layers REALLY selected in the steps a
+    training loop hands in: ``census`` is (steps, 2) of the selected (query,
+    key) pairs and the live block steps a head of the selected flash kernels
+    ran, each summed over a step's layers from what the layers sowed
+    (``dsa_selected_pairs``, ``dsa_live_block_steps``), CONCRETE (read on the
+    host between steps, never from inside a jitted step). ``dense_steps``:
+    the block steps of the causal-dense call at the same blocks, over the
+    same layers. Three gauges, each the mean over the recorded steps, and
+    ``horovod_flash_dead_step_share`` as the selection leaves it: the share
+    of the causal-dense grid's steps in which no pair is selected, which
+    fetch and run nothing."""
+    steps = [[int(v) for v in step] for step in census]
+    if not steps:
+        return
+    pairs = sum(step[0] for step in steps) / len(steps)
+    live = sum(step[1] for step in steps) / len(steps)
+    registry().gauge(
+        "horovod_dsa_selected_pairs_per_step",
+        help="(query, key) pairs the sparse-attention layers selected a step "
+             "(sum over layers and rows, mean over the recorded steps)"
+    ).set(pairs)
+    registry().gauge(
+        "horovod_dsa_live_block_steps_per_step",
+        help="block steps a head of the selected flash kernels ran a step: "
+             "those with a selected pair (sum over layers and rows, mean over "
+             "the recorded steps)"
+    ).set(live)
+    registry().gauge(
+        "horovod_dsa_dense_block_steps_per_step",
+        help="block steps of the causal-dense flash call at the selected "
+             "kernels' blocks, over the same layers and rows"
+    ).set(dense_steps)
+    registry().gauge(
+        "horovod_flash_dead_step_share",
+        help="share of the grid steps a head of the latest traced "
+             "causal-dense flash forward holds that run nothing"
+    ).set((dense_steps - live) / max(1, dense_steps))
+
+
 def record_ssd_plan(chunk: int, kernel: bool) -> None:
     """Record the chunk length the latest traced ``ops.ssd.ssd`` cut its rows
     into (trace time, once per compile): the configured chunk, or the row's

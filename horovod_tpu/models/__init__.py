@@ -12,6 +12,7 @@ from .pipeline_lm import (  # noqa: F401
     pipeline_lm_loss_and_grads,
     split_lm_params,
 )
-from .transformer import LatentDims, RotaryScheme, TransformerLM  # noqa: F401
+from .transformer import (LatentDims, RotaryScheme, SparseDims,  # noqa: F401
+                          TransformerLM, align_losses)
 from .vgg import VGG, VGG16, VGG19  # noqa: F401
 from .inception import InceptionV3  # noqa: F401

@@ -18,7 +18,8 @@ after it: a dispatched row is ``latent`` wide (a quarter of the bytes at
 1,024 of 4,096). The router and the shared expert read the full hidden state.
 
 ``router="softmax"`` (OLMoE, arXiv:2409.02060): softmax, then the ``top_k``
-largest probabilities, not renormalised. ``router="sigmoid"`` (DeepSeek-V3,
+largest probabilities, not renormalised - or, with ``norm_topk``
+(Qwen3-MoE's ``norm_topk_prob``), divided by their sum. ``router="sigmoid"`` (DeepSeek-V3,
 arXiv:2412.19437 §2.1.2, ``noaux_tc`` with one group): sigmoid scores, the
 ``top_k`` by score + bias, the chosen scores renormalised and multiplied by
 ``route_scale``; the bias is the variable ``router_bias`` of the collection
@@ -77,6 +78,7 @@ class MoEMLP(nn.Module):
     route_scale: float = 1.0
     shared_hidden: int = 0
     held: Optional[tuple] = None    # (first, count) of n_experts; None: all
+    norm_topk: bool = False         # softmax router: weights over their sum
     # What a Nemotron-3 configuration states (the module docstring has the
     # equations): experts without a gate, in a latent of this width.
     activation: str = "swiglu"      # "swiglu" | "relu2"
@@ -145,7 +147,9 @@ class MoEMLP(nn.Module):
             self.sow("intermediates", "moe_live_rows",
                      jnp.sum(counts[first:first + here]))
         else:
-            probs, weights, experts = topk_route(logits, self.top_k)
+            probs, weights, experts = (
+                topk_route(logits, self.top_k, True) if self.norm_topk
+                else topk_route(logits, self.top_k))    # OLMoE's call, as ever
             self.sow("intermediates", "moe_lb_loss",
                      topk_load_balancing_loss(probs, experts))
             self.sow("intermediates", "moe_z_loss", router_z_loss(logits))
@@ -167,15 +171,22 @@ class MoEMLP(nn.Module):
         return out
 
 
+def sown_sums(intermediates, names):
+    """``{name: sum over the layers}`` of what the layers sowed under each of
+    ``names`` in a model's ``intermediates`` collection; 0 where none did."""
+    sums = dict.fromkeys(names, 0)
+    for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
+        for name in names:
+            if any(getattr(p, "key", None) == name for p in path):
+                sums[name] = sums[name] + leaf
+    return sums
+
+
 def aux_losses(intermediates):
     """(load-balancing loss, router z-loss), each summed over the MoE layers
     found in a model's ``intermediates`` collection; zeros where there is
     none."""
-    sums = {"moe_lb_loss": 0.0, "moe_z_loss": 0.0}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
-        for name in sums:
-            if any(getattr(p, "key", None) == name for p in path):
-                sums[name] = sums[name] + leaf
+    sums = sown_sums(intermediates, ("moe_lb_loss", "moe_z_loss"))
     return sums["moe_lb_loss"], sums["moe_z_loss"]
 
 

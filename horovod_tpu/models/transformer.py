@@ -26,6 +26,7 @@ import numpy as np
 
 from ..common import device_names
 from ..ops.moe import CHOSEN_EXPERTS
+from ..ops.sparse_attention import ALIGN_GRADS, SELECTED
 from .mamba import Mamba2Dims, Mamba2Mixer
 
 
@@ -41,6 +42,22 @@ class LatentDims:
 
 
 @dataclasses.dataclass(frozen=True)
+class SparseDims:
+    """Learned sparse attention's sizes, as a configuration's ``sa_config``
+    states them (DeepSeek Sparse Attention; docs/sparse-attention.md): an
+    indexer of ``index_heads`` query heads of ``index_dim`` against ONE shared
+    key head scores every earlier token, each query keeps its ``topk`` best,
+    and attention runs over that selection. ``kv_chunk`` / ``q_chunk``: the
+    tile in which scores are computed, selected and packed (they change no
+    result)."""
+    index_heads: int
+    index_dim: int
+    topk: int
+    kv_chunk: int = 512
+    q_chunk: int = 512
+
+
+@dataclasses.dataclass(frozen=True)
 class RotaryScheme:
     """One kind of layer's rotary embedding, as a Hugging Face
     ``rope_parameters`` entry states it: the base ``theta``; ``dims``, how
@@ -52,7 +69,11 @@ class RotaryScheme:
     them with ``truncate`` at its default), and ``attention_factor``, which
     multiplies cos and sin, so q's and k's rotated dimensions alone (None:
     YaRN's ``0.1 ln(factor) + 1``, and 1 without a factor). Pairs are
-    ``(i, i + dims / 2)``."""
+    ``(i, i + dims / 2)``. ``sections`` (``rope_scaling.mrope_section``: three
+    counts that sum to ``dims / 2``): positions come in three streams
+    (temporal, height, width: ``(3, B, T)``), the first ``sections[0]`` pairs
+    turned by the first stream, the next ``sections[1]`` by the second, the
+    rest by the third; one stream given stands for all three (a text row)."""
     theta: float = 10000.0
     dims: Optional[int] = None
     factor: Optional[float] = None
@@ -60,6 +81,7 @@ class RotaryScheme:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: Optional[float] = None
+    sections: Optional[tuple] = None
 
     def inv_freq(self, dims):
         """(dims / 2,) float32: the angle a position turns each pair by."""
@@ -110,8 +132,18 @@ def _rope_scheme(x, positions, scheme):
     d = x.shape[-1]
     dims = d if scheme.dims is None else scheme.dims
     half = dims // 2
+    if positions.ndim == 3 and scheme.sections is None:
+        positions = positions[0]    # three streams, no sections: the temporal
     angles = positions[..., None].astype(jnp.float32) * jnp.asarray(
         scheme.inv_freq(dims))
+    if scheme.sections is not None:
+        if sum(scheme.sections) != half or len(scheme.sections) != 3:
+            raise ValueError(f"sections {scheme.sections} are not three "
+                             f"counts of the {half} pairs")
+        if positions.ndim == 3:     # (3, B, T): pair i by its section's stream
+            stream = np.repeat(np.arange(3), scheme.sections)
+            angles = sum(jnp.where(stream == c, angles[c], 0.0)
+                         for c in range(3))
 
     def widen(turned, passing):     # (..., T, half) -> (..., T, 1, D)
         rest = jnp.full(angles.shape[:-1] + (d - dims,), passing, jnp.float32)
@@ -219,6 +251,13 @@ class Block(nn.Module):
     sublayers: str = "both"
     moe_activation: str = "swiglu"
     moe_latent: int = 0
+    # What a sparse-attention mixture of experts' configuration states
+    # (Keye-VL-2.0's language model: TransformerLM documents them): an indexer
+    # and a selection inside attention, RMSNorm over EACH head of q and k, the
+    # softmax router's weights over their sum.
+    sparse: Optional[SparseDims] = None
+    qk_head_norm: bool = False
+    moe_norm_topk: bool = False
 
     @nn.compact
     def __call__(self, x, positions):
@@ -243,7 +282,7 @@ class Block(nn.Module):
                 route_scale=self.moe_route_scale,
                 shared_hidden=self.moe_shared_hidden, held=self.moe_held,
                 activation=self.moe_activation, latent=self.moe_latent,
-                name="moe")(h))
+                norm_topk=self.moe_norm_topk, name="moe")(h))
         if self.mlp_hidden is not None:
             gate, up = (nn.Dense(self.mlp_hidden, use_bias=False,
                                  dtype=self.dtype, name=name)(h)
@@ -285,6 +324,8 @@ class Block(nn.Module):
                                interpret=self.flash_interpret, name="mixer")(h)
         if self.mla is not None:
             return self._latent_attention(h, positions)
+        if self.sparse is not None:
+            return self._selected_attention(h, positions)
         return self._attention(h, positions)
 
     def _add(self, x, branch):
@@ -389,6 +430,85 @@ class Block(nn.Module):
                 attn = attn * gate[..., None]
         attn = attn.reshape(b, t, width)
         return nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="o_proj")(attn)
+
+    def _selected_attention(self, h, positions):
+        """Grouped-query attention of the normed ``h`` over a learned
+        selection (docs/sparse-attention.md), through o_proj. The indexer
+        reads ``stop_gradient(h)``: queries ``index_q`` (``index_heads`` x
+        ``index_dim``), ONE key head ``index_k`` under a LayerNorm with bias,
+        both turned by the temporal positions at ``rope_theta``, and a weight
+        a head and token ``index_w`` x ``index_heads^-0.5 index_dim^-0.5``.
+        The selection (``ops.sparse_attention.select``) carries no gradient
+        and is saved across a recomputation by name; every head attends over
+        it (``ops.flash_attention.selected_attention``); the alignment loss
+        (``ops.sparse_attention.align_loss``) is sown as ``dsa_align_loss``
+        and is the ONLY source of the indexer's gradients, as the
+        language-model loss is of every other parameter's. Also sown:
+        ``dsa_words``, the selection as bits, and ``dsa_selected_pairs`` and
+        ``dsa_live_block_steps``, the layer's census from the data."""
+        from ..ops import sparse_attention as dsa
+        from ..ops.flash_attention import _plan, selected_attention
+
+        sp, heads = self.sparse, self.heads
+        if (self.attention != "flash" or self.sp_axis is not None
+                or self.window is not None or self.attn_gate or self.qk_norm):
+            raise ValueError(
+                "sparse attention runs through the flash kernels on one "
+                "chip: attention='flash', no sp_axis, window, gate or "
+                "whole-projection qk_norm")
+        head_dim = (self.dim // heads if self.head_dim is None
+                    else self.head_dim)
+        kvh = heads if self.kv_heads is None else self.kv_heads
+        b, t = h.shape[0], h.shape[1]
+        time = positions[0] if positions.ndim == 3 else positions
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype, name=name)
+
+        q = dense(heads * head_dim, "q_proj")(h).reshape(b, t, heads, head_dim)
+        k, v = (x.reshape(b, t, kvh, head_dim) for x in jnp.split(
+            dense(2 * kvh * head_dim, "kv_proj")(h), 2, axis=-1))
+        if self.qk_head_norm:
+            q = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype,
+                           name="q_head_norm")(q)
+            k = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype,
+                           name="k_head_norm")(k)
+        if self.rotary is not None:
+            with jax.named_scope(device_names.ATTN_ROPE):
+                q, k = (_rope_scheme(x, positions, self.rotary) for x in (q, k))
+        elif self.rope:
+            q, k = (_rope(x, time, self.rope_theta, self.rope_interleave)
+                    for x in (q, k))
+        with jax.named_scope(device_names.DSA_INDEXER):
+            hd = jax.lax.stop_gradient(h)
+            # a scheme's angles are float64 on the host before they are
+            # float32: at base 1e7 a float32 power on the device is a few
+            # 1e-4 rad off by position 4,096, which moves the selection
+            turn = RotaryScheme(theta=self.rope_theta)
+            qi = _rope_scheme(dense(sp.index_heads * sp.index_dim, "index_q")(
+                hd).reshape(b, t, sp.index_heads, sp.index_dim), time, turn)
+            ki = nn.LayerNorm(epsilon=1e-6, dtype=self.dtype,
+                              name="index_k_norm")(
+                dense(sp.index_dim, "index_k")(hd))
+            ki = _rope_scheme(ki[:, :, None, :], time, turn)[:, :, 0]
+            w = dense(sp.index_heads, "index_w")(hd).astype(jnp.float32) * (
+                sp.index_heads ** -0.5 * sp.index_dim ** -0.5)
+        words, lse_i = dsa.select(qi, ki, w, sp.topk, sp.kv_chunk, sp.q_chunk,
+                                  self.flash_interpret)
+        # positional: custom_vjp nondiff_argnums
+        attn, lse = selected_attention(
+            q, k, v, words, self.block_q, self.block_k, self.flash_interpret,
+            self.attention_scale, sp.kv_chunk)
+        self.sow("intermediates", "dsa_align_loss", dsa.align_loss(
+            *map(jax.lax.stop_gradient, (q, k, lse)), qi, ki, w, words, lse_i,
+            self.attention_scale, sp.kv_chunk, self.flash_interpret))
+        pairs, live = dsa.census(words, *_plan(
+            t, self.block_q, self.block_k, self.flash_interpret, None)[:2],
+            sp.kv_chunk)
+        self.sow("intermediates", "dsa_words", words)
+        self.sow("intermediates", "dsa_selected_pairs", pairs)
+        self.sow("intermediates", "dsa_live_block_steps", live)
+        return dense(self.dim, "o_proj")(attn.reshape(b, t, heads * head_dim))
 
     def _flash_blocks(self):
         """(block_q, block_k) for the flash kernels: the fields, or the
@@ -602,6 +722,23 @@ class TransformerLM(nn.Module):
     moe_activation: str = "swiglu"
     moe_latent: int = 0
     mtp_layer_types: Optional[tuple] = None
+    # A sparse-attention mixture of experts (Keye-VL-2.0-30B-A3B's language
+    # model: docs/sparse-attention.md), each as the model's own configuration
+    # states it. sparse: every attention layer scores all earlier tokens with
+    # an indexer, keeps each query's ``topk`` best and attends over those
+    # (``sa_config``); the layers then sow ``dsa_align_loss``, which
+    # ``models.transformer.align_losses`` sums for the caller's loss function
+    # as ``models.moe.aux_losses`` sums the routers'. qk_head_norm: RMSNorm
+    # with a weight of head_dim over EACH head of q and of k, before the
+    # rotary embedding (Qwen3's; ``qk_norm`` is OLMoE's, over the whole
+    # projection). rotary: a RotaryScheme for "attention" layers, whose
+    # ``sections`` take positions in three streams ``(3, B, T)``
+    # (``mrope_section``). moe_norm_topk: the softmax router's chosen
+    # probabilities divided by their sum (``norm_topk_prob``).
+    sparse: Optional[SparseDims] = None
+    qk_head_norm: bool = False
+    rotary: Optional[RotaryScheme] = None
+    moe_norm_topk: bool = False
 
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden: bool = False):
@@ -652,10 +789,14 @@ class TransformerLM(nn.Module):
             x = x * jnp.asarray(self.embedding_multiplier, x.dtype)
         block_cls = Block
         if self.remat:
-            # the routers' choice is saved, never recomputed (ops/moe.py)
+            # the routers' choice and attention's selection are saved, never
+            # recomputed (ops/moe.py, ops/sparse_attention.py); so are the
+            # alignment loss's gradients, which its forward pass produced
+            saved = ([CHOSEN_EXPERTS] if self.moe_experts > 0 else []) + (
+                [SELECTED, ALIGN_GRADS] if self.sparse is not None else [])
             block_cls = nn.remat(Block, policy=(
-                jax.checkpoint_policies.save_only_these_names(CHOSEN_EXPERTS)
-                if self.moe_experts > 0 else None))
+                jax.checkpoint_policies.save_only_these_names(*saved)
+                if saved else None))
 
         def block(kind, name, heads, second_is_experts):
             """One layer of ``kind``; ``second_is_experts``: whether a layer
@@ -695,11 +836,14 @@ class TransformerLM(nn.Module):
                 head_dim=self.head_dim,
                 window=(self.sliding_window if kind == "sliding_attention"
                         else None),
-                rotary=rotary.get(kind),
+                rotary=rotary.get(kind, self.rotary),
                 attn_gate=self.attn_gate,
                 sublayers=sublayers,
                 moe_activation=self.moe_activation,
                 moe_latent=self.moe_latent,
+                sparse=self.sparse if sublayers != "mlp" else None,
+                qk_head_norm=self.qk_head_norm,
+                moe_norm_topk=self.moe_norm_topk,
                 name=name,
             )
 
@@ -748,6 +892,17 @@ class TransformerLM(nn.Module):
                 head(x[:, :1])  # param tree must not depend on the flag
             return (x, y) if mtp_kinds else x
         return (head(x), head(y)) if mtp_kinds else head(x)
+
+
+def align_losses(intermediates):
+    """(alignment loss, selected pairs, live block steps), each summed over
+    the sparse-attention layers found in a model's ``intermediates``
+    collection; zeros where there is none."""
+    from .moe import sown_sums
+
+    return tuple(sown_sums(intermediates, (
+        "dsa_align_loss", "dsa_selected_pairs", "dsa_live_block_steps")
+    ).values())
 
 
 def chunked_lm_loss(hidden, head_kernel, targets, chunk: int = 2048):

@@ -5,7 +5,10 @@ flash-attention kernel (fused, trainable) for the hot op, and Mamba-2's
 chunked state-space scan with its causal depthwise convolution (the module
 ``ssd``: ``from horovod_tpu.ops.ssd import ssd, causal_depthwise_conv``) and
 the mixer's two elementwise chains as pallas kernels (the module
-``mamba_fused``: ``conv_silu``, ``gate_norm``)."""
+``mamba_fused``: ``conv_silu``, ``gate_norm``), and learned sparse attention
+(the module ``sparse_attention``: an indexer's scores, each query's exact
+top-k, the selection as bits, the alignment loss; the flash kernels over the
+selection are ``flash_attention.selected_attention``)."""
 
 from .flash_attention import flash_attention  # noqa: F401
 

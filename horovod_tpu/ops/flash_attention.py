@@ -63,6 +63,15 @@ backward's in sub-tiles of 256 x 256 there.
 engages and ``horovod_flash_bwd_skipped_subtile_share`` how much of the live
 blocks the backward never computes (:func:`block_census`).
 
+A third kind of call, :func:`selected_attention`, runs the same three kernels
+over a selection that is DATA (learned sparse attention:
+ops/sparse_attention.py): each query's kept keys arrive as bits, one a pair,
+every head's the same; a sub-tile's mask is a shift of a lane-aligned slice of
+the q block's words, a block step none of whose pairs is kept is told by a
+scalar-prefetched table, runs nothing and - its index maps naming the block
+already resident - fetches nothing. Grids, walk and sub-tiles are the
+causal-dense call's; the kernels go by ``hvd_flash_sel_*``.
+
 Pairs with the sequence-parallel schedules in ring_attention.py (which move
 K/V between chips); `causal_reference` is the oracle both are tested
 against. The kernels compile for the TPU (Mosaic); ``interpret=True`` runs
@@ -74,6 +83,7 @@ them in the Pallas interpreter instead — something the caller asks for
 from __future__ import annotations
 
 import functools
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -81,8 +91,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..common.device_names import (FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD,
-                                   FLASH_WIN_BWD_DKV, FLASH_WIN_BWD_DQ,
-                                   FLASH_WIN_FWD)
+                                   FLASH_SEL_BWD_DKV, FLASH_SEL_BWD_DQ,
+                                   FLASH_SEL_FWD, FLASH_WIN_BWD_DKV,
+                                   FLASH_WIN_BWD_DQ, FLASH_WIN_FWD)
 
 NEG_INF = -1e30
 
@@ -325,6 +336,39 @@ def _q_major_specs(t, block_q, block_k, heads, causal, window):
     return (rows, steps), q_spec, kv_spec, stat_spec
 
 
+# ------------------------------------------------- a selection that is data
+
+class _Sel(NamedTuple):
+    """What a selected call's kernels hold beside their operands
+    (:func:`selected_attention`): ``live_ref``, the scalar-prefetched table
+    (B, nq, nk) of the block steps that hold a selected pair; ``words_ref``,
+    the q block's packed selection (``ops.sparse_attention.pack``; the
+    transposed words in dK/dV's kernel, whose score tiles are); ``per_batch``:
+    the rows of the grid's first axis a batch row takes."""
+    live_ref: Any
+    words_ref: Any
+    chunk: int
+    nq: int
+    nk: int
+    per_batch: int
+    transposed: bool = False
+
+    def live(self, qi, ki):
+        b = pl.program_id(0) // self.per_batch
+        return self.live_ref[(b * self.nq + jnp.minimum(qi, self.nq - 1))
+                             * self.nk + jnp.minimum(ki, self.nk - 1)] != 0
+
+    def keep(self, s, queries, base, c0, width, block_k):
+        """The score sub-tile ``s`` with its pairs outside the selection at
+        NEG_INF: ``queries`` the q block's slice, the keys ``[base + c0, base
+        + c0 + width)`` of the k block that starts at ``base``."""
+        from .sparse_attention import tile_bits
+
+        return jnp.where(tile_bits(self.words_ref, (0,), queries, (base, c0),
+                                   width, self.chunk, block_k,
+                                   self.transposed), s, NEG_INF)
+
+
 # ------------------------------------------------------------------- forward
 
 # Row statistics (running max m, running sum l) live as (block_q, 128) f32,
@@ -358,7 +402,8 @@ def _sub_tile(block, want):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                block_q, block_k, nk, causal, sm_scale, window=None, t=None):
+                block_q, block_k, nk, causal, sm_scale, window=None, t=None,
+                sel=None):
     qi, ki, step, steps = _q_major_step(
         pl.program_id(1), pl.program_id(2), block_q, block_k, nk, causal,
         window)
@@ -386,6 +431,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         s = _masked(jax.lax.dot_general(
             q_ref[0, rows, :], k_ref[0, cols, :], _NT,
             preferred_element_type=jnp.float32) * sm_scale, off, 0)
+        if sel is not None:
+            s = sel.keep(s, rows, ki * block_k, c0, sub_k, block_k)
         m_prev = m_ref[rows, :]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -437,10 +484,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     elif causal:
         # Two bodies: blocks wholly below the diagonal never build a mask;
         # the ``ratio`` blocks the diagonal crosses each know where.
-        pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k)))(below)
-        for j in range(ratio):
-            pl.when(ki == qi * ratio + j)(
-                functools.partial(crossed, j * block_k))
+        if sel is None:
+            pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k)))(below)
+            for j in range(ratio):
+                pl.when(ki == qi * ratio + j)(
+                    functools.partial(crossed, j * block_k))
+        else:       # and a block step with no selected pair runs nothing
+            live = sel.live(qi, ki)
+            pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k))
+                    & live)(below)
+            for j in range(ratio):
+                pl.when((ki == qi * ratio + j) & live)(
+                    functools.partial(crossed, j * block_k))
     else:
         below()
 
@@ -507,7 +562,7 @@ def _masked(s, off, q_axis):
 
 
 def _walk(causal, q_major, qi, ki, block_q, block_k, tile, add, window=None,
-          t=None):
+          t=None, live=None):
     """The walk both backward kernels share over block step ``(qi, ki)``.
     The accumulators' rows lie along the queries (``q_major``: dq) or along
     the keys (dk/dv); the other axis is summed over. ``tile(rows, cols,
@@ -516,7 +571,8 @@ def _walk(causal, q_major, qi, ki, block_q, block_k, tile, add, window=None,
     or :func:`_mask_offset`'s offset); ``add(rows, parts)`` adds a row
     group's sum once. With a ``window`` (of a sequence of ``t``) a block step
     outside the sequence, which a windowed grid's first or last rows hold,
-    runs nothing."""
+    runs nothing. ``live`` (a selected call's): whether any pair of the block
+    step is selected; nothing runs where none is."""
     def extents(sub):
         """(rows, a row group's, columns, a strip's) of the loaded tile."""
         sub_q, sub_k = _sub_tile(block_q, sub[0]), _sub_tile(block_k, sub[1])
@@ -572,14 +628,21 @@ def _walk(causal, q_major, qi, ki, block_q, block_k, tile, add, window=None,
         return
     # Blocks above the diagonal match neither: nothing runs there.
     ratio = block_q // block_k
-    pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k)))(below)
+    if live is None:
+        pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k)))(below)
+        for j in range(ratio):
+            pl.when(ki == qi * ratio + j)(
+                functools.partial(crossed, j * block_k))
+        return
+    pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k)) & live)(below)
     for j in range(ratio):
-        pl.when(ki == qi * ratio + j)(functools.partial(crossed, j * block_k))
+        pl.when((ki == qi * ratio + j) & live)(
+            functools.partial(crossed, j * block_k))
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                dq_acc_ref, lse_rows_ref, delta_rows_ref, *, block_q, block_k,
-               nk, causal, sm_scale, window=None, t=None):
+               nk, causal, sm_scale, window=None, t=None, sel=None):
     """Query-major: a score sub-tile has its queries down the sublanes, and
     lse and delta are read from lane-replicated scratch, as the forward
     keeps m and l."""
@@ -604,6 +667,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         s = _masked(jax.lax.dot_general(
             q_ref[0, rows, :], k, _NT,
             preferred_element_type=jnp.float32) * sm_scale, off, 0)
+        if sel is not None:
+            s = sel.keep(s, rows, ki * block_k, cols.start, cols.size, block_k)
         p = jnp.exp(s - _lanes(lse_rows_ref[rows, :], s.shape[1]))
         dp = jax.lax.dot_general(
             do_ref[0, rows, :], v_ref[0, cols, :], _NT,
@@ -615,7 +680,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def add(rows, parts):
         dq_acc_ref[0, rows, :] = dq_acc_ref[0, rows, :] + parts[0]
 
-    _walk(causal, True, qi, ki, block_q, block_k, tile, add, window, t)
+    _walk(causal, True, qi, ki, block_q, block_k, tile, add, window, t,
+          None if sel is None else sel.live(qi, ki))
 
     @pl.when(step == steps - 1)
     def _finalize():
@@ -624,7 +690,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                 dv_ref, dk_acc_ref, dv_acc_ref, *, block_q, block_k, nq,
-                group, causal, sm_scale, window=None, t=None):
+                group, causal, sm_scale, window=None, t=None, sel=None):
     """Key-major: a score sub-tile is TRANSPOSED, keys down the sublanes and
     queries along the lanes, so the four products are a @ b.T (k . q^T,
     v . dO^T) and a @ b (pT @ dO, dsT @ q), none with a transposed left
@@ -648,6 +714,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         st = _masked(jax.lax.dot_general(
             k_ref[0, rows, :], q, _NT,
             preferred_element_type=jnp.float32) * sm_scale, off, 1)
+        if sel is not None:     # the transposed words: keys down the sublanes
+            st = sel.keep(st, cols, ki * block_k, rows.start, rows.size,
+                          block_k)
         pt = jnp.exp(st - lse_ref[0, :1, cols])
         dpt = jax.lax.dot_general(
             v_ref[0, rows, :], do, _NT, preferred_element_type=jnp.float32)
@@ -661,7 +730,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dk_acc_ref[0, rows, :] = dk_acc_ref[0, rows, :] + parts[0]
         dv_acc_ref[0, rows, :] = dv_acc_ref[0, rows, :] + parts[1]
 
-    _walk(causal, False, qi, ki, block_q, block_k, tile, add, window, t)
+    _walk(causal, False, qi, ki, block_q, block_k, tile, add, window, t,
+          None if sel is None else sel.live(qi, ki))
 
     @pl.when(step == steps - 1)
     def _finalize():
@@ -968,3 +1038,238 @@ def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, window, res,
 
 
 flash_attention.defvjp(_fwd, _bwd)
+
+
+# ------------------------------------------- attention over a selection
+
+def _selection_tables(words, block_q, block_k, chunk):
+    """The scalar-prefetched tables of a selected call, flat int32: (live (B,
+    nq, nk); the k block to hold at each step of a q block's sweep; the q
+    block to hold at each step of a k block's) - a dead step's index maps
+    name the block already resident, so nothing is fetched for it."""
+    from .sparse_attention import block_liveness, fetch_table
+
+    live = block_liveness(words, block_q, block_k, chunk)
+    return (live.astype(jnp.int32).reshape(-1), fetch_table(live).reshape(-1),
+            fetch_table(jnp.swapaxes(live, 1, 2)).reshape(-1))
+
+
+def _word_group(k_block, block_k, chunk):
+    """The group of word columns (32 chunks of keys each) that holds a k
+    block's chunks."""
+    return _div(k_block * block_k, 32 * chunk)
+
+
+def _sel_q_major_specs(t, block_q, block_k, heads, chunk):
+    """:func:`_q_major_specs` of a selected call (causal-dense grids), its
+    index maps taking the two scalar-prefetched tables too: ``kv_spec`` and
+    ``words_spec`` name, at a step whose block holds no selected pair, the
+    k block already resident (the second table: ``fetch_table`` of the
+    liveness). -> ((rows, steps), q_spec, kv_spec, stat_spec, words_spec)."""
+    h, hkv, group = heads
+    nq, nk = t // block_q, t // block_k
+    rows, steps, q_block, k_block = _q_major_grid(t, block_q, block_k, True,
+                                                  None)
+
+    def held_k(r, p, s, k_at):
+        return k_at[((r // h) * nq + q_block(p, s)) * nk + k_block(p, s)]
+
+    def q_spec(width):
+        return pl.BlockSpec((1, block_q, width), lambda r, p, s, live, k_at: (
+            r, q_block(p, s), 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, block_k, width), lambda r, p, s, live, k_at: (
+            _kv_row(r, h, hkv, group), held_k(r, p, s, k_at), 0))
+
+    stat_spec = pl.BlockSpec((1, 8, block_q), lambda r, p, s, live, k_at: (
+        r, 0, q_block(p, s)))
+    words_spec = pl.BlockSpec(
+        (1, block_q, chunk), lambda r, p, s, live, k_at: (
+            r // h, q_block(p, s),
+            _word_group(held_k(r, p, s, k_at), block_k, chunk)))
+    return (rows, steps), q_spec, kv_spec, stat_spec, words_spec
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def selected_attention(q, k, v, words, block_q: int | None = None,
+                       block_k: int | None = None, interpret: bool = False,
+                       sm_scale: float | None = None, chunk: int = 512):
+    """Causal attention of each query over ITS selection of the earlier keys:
+    ``(out (B, T, H, Dv), lse (B, H, T) float32)``. ``words`` (B, T, columns)
+    int32 is the selection as bits, one a pair, every head's the same
+    (``ops.sparse_attention.select`` / ``pack``: key ``s`` of query ``t`` in
+    bit ``(s // chunk) % 32`` of column ``(s // chunk // 32) * chunk + s %
+    chunk``); it carries no gradient, and only pairs ``s <= t`` may be set,
+    at least one a query. The kernels are :func:`flash_attention`'s own -
+    tile loop, online softmax, folded causal grids, grouped-query index maps -
+    with the mask one more operand, under names of their own
+    (``hvd_flash_sel_*``): a sub-tile's mask is a shift of a lane-aligned
+    slice of the words (dK/dV's kernel, whose score tiles are transposed,
+    reads the transposed words, a transient of the backward); a block step
+    none of whose pairs is selected runs nothing (the liveness table is
+    scalar-prefetched) and fetches nothing (its index maps name the block
+    already resident). ``lse`` is the per-head logsumexp over the selection,
+    for ``ops.sparse_attention.align_loss``; its cotangent is not used.
+    ``block_k`` must hold whole chunks on the TPU."""
+    return _sel_fwd(q, k, v, words, block_q, block_k, interpret, sm_scale,
+                    chunk)[0]
+
+
+def _sel_fwd(q, k, v, words, block_q, block_k, interpret, sm_scale, chunk):
+    from ..metrics import record_flash_plan
+    b, t, h, _ = q.shape
+    block_q, block_k, _ = _plan(t, block_q, block_k, interpret, None)
+    if (block_k % chunk and not interpret) or block_k > 32 * chunk:
+        raise ValueError(f"block_k {block_k} must hold whole chunks of "
+                         f"{chunk} keys, 32 at most")
+    rows, steps = _q_major_grid(t, block_q, block_k, True, None)[:2]
+    record_flash_plan(*block_census(t, block_q, block_k, True),
+                      grid_steps=rows * steps)
+    out, res = _sel_fwd_call(q, k, v, words, block_q, block_k, interpret,
+                             sm_scale, chunk)
+    return (out, res[-1][:, 0, :].reshape(b, h, t)), res
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def _sel_fwd_call(q, k, v, words, block_q, block_k, interpret, sm_scale,
+                  chunk):
+    b, t, h, d = q.shape
+    h, hkv, group = _gqa_group(q, k, v)
+    dv = v.shape[3]
+    nq, nk = t // block_q, t // block_k
+    live, k_at, _ = _selection_tables(words, block_q, block_k, chunk)
+    (rows, steps), q_spec, kv_spec, stat_spec, words_spec = _sel_q_major_specs(
+        t, block_q, block_k, (h, hkv, group), chunk)
+
+    def kernel(live_ref, k_at_ref, q_ref, k_ref, v_ref, words_ref, *rest):
+        _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_q=block_q,
+                    block_k=block_k, nk=nk, causal=True,
+                    sm_scale=d ** -0.5 if sm_scale is None else sm_scale,
+                    sel=_Sel(live_ref, words_ref, chunk, nq, nk, h))
+
+    out, lse = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b * h, rows, steps),
+            in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), words_spec],
+            out_specs=[q_spec(dv), stat_spec],
+            scratch_shapes=[
+                pltpu.VMEM((1, block_q, dv), jnp.float32),       # acc
+                pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # m
+                pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # l
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((b * h, t, dv), q.dtype),
+            jax.ShapeDtypeStruct((b * h, 8, t), jnp.float32),
+        ],
+        interpret=interpret,
+        name=FLASH_SEL_FWD,
+    )(live, k_at, _rows(q, b, t, h, d), _rows(k, b, t, hkv, d),
+      _rows(v, b, t, hkv, dv), words)
+    return _unrows(out, b, t, h, dv), (q, k, v, words, out, lse)
+
+
+def _sel_bwd(block_q, block_k, interpret, sm_scale, chunk, res, cotangents):
+    block_q, block_k, _ = _plan(res[0].shape[1], block_q, block_k, interpret,
+                                None)
+    return (*_sel_bwd_rule(block_q, block_k, interpret, sm_scale, chunk, res,
+                           cotangents[0]), None)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _sel_bwd_rule(block_q, block_k, interpret, sm_scale, chunk, res, dout):
+    q, k, v, words, out, lse = res
+    b, t, h, d = q.shape
+    h, hkv, group = _gqa_group(q, k, v)
+    dv = v.shape[3]
+    nq, nk = t // block_q, t // block_k
+    live, k_at, q_at = _selection_tables(words, block_q, block_k, chunk)
+    qr, dor = _rows(q, b, t, h, d), _rows(dout, b, t, h, dv)
+    kr, vr = _rows(k, b, t, hkv, d), _rows(v, b, t, hkv, dv)
+    delta = jnp.sum(dor.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.broadcast_to(delta[:, None, :], (b * h, 8, t))
+    common = dict(block_q=block_q, block_k=block_k, causal=True,
+                  sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
+
+    (rows, steps), q_spec, kv_spec, stat_spec, words_spec = _sel_q_major_specs(
+        t, block_q, block_k, (h, hkv, group), chunk)
+
+    def dq_kernel(live_ref, at_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                  delta_ref, words_ref, *rest):
+        _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                   nk=nk, sel=_Sel(live_ref, words_ref, chunk, nq, nk, h),
+                   **common)
+
+    dq = pl.pallas_call(
+        dq_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b * h, rows, steps),
+            in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv),
+                      stat_spec, stat_spec, words_spec],
+            out_specs=q_spec(d),
+            scratch_shapes=[
+                pltpu.VMEM((1, block_q, d), jnp.float32),        # dq acc
+                pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # lse, by rows
+                pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # delta, by rows
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+        interpret=interpret,
+        name=FLASH_SEL_BWD_DQ,
+    )(live, k_at, qr, kr, vr, dor, lse, delta, words)
+
+    rows, steps, k_block, head, q_block = _k_major_grid(
+        t, block_q, block_k, group, True, None)
+
+    def held_q(r, p, s, at):
+        return at[((r // hkv) * nk + k_block(p, s)) * nq + q_block(p, s)]
+
+    def q_row(r, p, s):
+        return _group_q_row(r, h, hkv, group) + head(p, s)
+
+    def qd(width):
+        return pl.BlockSpec((1, block_q, width), lambda r, p, s, live, at: (
+            q_row(r, p, s), held_q(r, p, s, at), 0))
+
+    def kd(width):
+        return pl.BlockSpec((1, block_k, width), lambda r, p, s, live, at: (
+            r, k_block(p, s), 0))
+
+    row = pl.BlockSpec((1, 8, block_q), lambda r, p, s, live, at: (
+        q_row(r, p, s), 0, held_q(r, p, s, at)))
+    # dK/dV's score tiles have their keys down the sublanes: the words too
+    words_t = pl.BlockSpec(
+        (1, chunk, block_q), lambda r, p, s, live, at: (
+            r // hkv, _word_group(k_block(p, s), block_k, chunk),
+            held_q(r, p, s, at)))
+
+    def dkv_kernel(live_ref, at_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, words_ref, *rest):
+        _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                    nq=nq, group=group,
+                    sel=_Sel(live_ref, words_ref, chunk, nq, nk, hkv, True),
+                    **common)
+
+    dk, dv_rows = pl.pallas_call(
+        dkv_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b * hkv, rows, steps),
+            in_specs=[qd(d), kd(d), kd(dv), qd(dv), row, row, words_t],
+            out_specs=[kd(d), kd(dv)],
+            scratch_shapes=[
+                pltpu.VMEM((1, block_k, d), jnp.float32),   # dk acc
+                pltpu.VMEM((1, block_k, dv), jnp.float32),  # dv acc
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((b * hkv, t, d), k.dtype),
+            jax.ShapeDtypeStruct((b * hkv, t, dv), v.dtype),
+        ],
+        interpret=interpret,
+        name=FLASH_SEL_BWD_DKV,
+    )(live, q_at, qr, kr, vr, dor, lse, delta, jnp.swapaxes(words, 1, 2))
+
+    return (_unrows(dq, b, t, h, d), _unrows(dk, b, t, hkv, d),
+            _unrows(dv_rows, b, t, hkv, dv))
+
+
+selected_attention.defvjp(_sel_fwd, _sel_bwd)

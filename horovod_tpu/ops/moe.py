@@ -77,12 +77,13 @@ from . import grouped_matmul as gm
 CHOSEN_EXPERTS = "moe_chosen_experts"
 
 
-def topk_route(logits, top_k: int):
+def topk_route(logits, top_k: int, renormalise: bool = False):
     """Softmax in float32, then the ``top_k`` largest probabilities.
 
     Returns (probs (N, E), weights (N, top_k), experts (N, top_k)). The
     weights are the chosen probabilities as they are: not renormalised, so a
-    token's weights sum to less than 1."""
+    token's weights sum to less than 1 (OLMoE) - or, with ``renormalise``
+    (Qwen3-MoE's ``norm_topk_prob``), divided by their sum."""
     with jax.named_scope(device_names.MOE_ROUTE):
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
         experts = checkpoint_name(lax.top_k(probs, top_k)[1], CHOSEN_EXPERTS)
@@ -91,6 +92,8 @@ def topk_route(logits, top_k: int):
         # N x top_k scalars.
         onehot = experts[:, :, None] == jnp.arange(probs.shape[-1])
         weights = jnp.sum(jnp.where(onehot, probs[:, None, :], 0.0), axis=-1)
+        if renormalise:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return probs, weights, experts
 
 

@@ -507,3 +507,63 @@ def test_the_hybrids_mixers_compile_for_v5e_at_the_held_share(
         assert name in text, f"{name} is not in the compiled module"
     if part == "scan":
         assert " while(" not in text
+
+
+# keye_vl2_seq16384_1chip: 32 query heads over 4 key/value heads of 128 over a
+# selection that is data (the packed words one more operand, the liveness and
+# fetch tables scalar-prefetched), an indexer of 16 heads of 64 against one
+# key head, 2,048 keys a query in chunks of 512: the step's row of 16,384 in
+# bf16 at the default blocks, and the check's 4,096-token prefix in float32
+# under "highest" at 512 blocks.
+SPARSE = {"step_bf16": (16384, jnp.bfloat16, (None, None), None),
+          "check_f32_highest": (4096, jnp.float32, (512, 512), "highest")}
+
+
+@pytest.mark.parametrize("case", sorted(SPARSE))
+@pytest.mark.parametrize("piece", ["select", "flash", "align"])
+def test_sparse_attention_kernels_compile_for_v5e(one_chip, no_persistent_cache,
+                                                  case, piece):
+    from horovod_tpu.common import device_names
+    from horovod_tpu.ops import sparse_attention as dsa
+    from horovod_tpu.ops.flash_attention import selected_attention
+
+    t, dtype, blocks, precision = SPARSE[case]
+    heads, kv_heads, d, index_heads, index_dim = 32, 4, 128, 16, 64
+
+    def shape(*dims, of=dtype):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    q, kv = shape(1, t, heads, d), shape(1, t, kv_heads, d)
+    qi, ki = shape(1, t, index_heads, index_dim), shape(1, t, index_dim)
+    w = shape(1, t, index_heads, of=jnp.float32)
+    words = shape(1, t, dsa.word_columns(t, 512), of=jnp.int32)
+    lse, lse_i = (shape(1, heads, t, of=jnp.float32),
+                  shape(1, t, of=jnp.float32))
+
+    def flash(q, k, v, words):
+        return jax.grad(lambda q, k, v: jnp.sum(selected_attention(
+            q, k, v, words, *blocks)[0].astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    def align(q, k, lse, qi, ki, w, words, lse_i):
+        return jax.value_and_grad(lambda qi, ki, w: dsa.align_loss(
+            q, k, lse, qi, ki, w, words, lse_i), argnums=(0, 1, 2))(qi, ki, w)
+
+    fn, args, names = {
+        "select": (lambda qi, ki, w: dsa.select(qi, ki, w, 2048), (qi, ki, w),
+                   (device_names.DSA_INDEXER_SCORES,)),
+        "flash": (flash, (q, kv, kv, words),
+                  (device_names.FLASH_SEL_FWD, device_names.FLASH_SEL_BWD_DQ,
+                   device_names.FLASH_SEL_BWD_DKV)),
+        "align": (align, (q, kv, lse, qi, ki, w, words, lse_i),
+                  (device_names.DSA_ALIGN_TILES,)),
+    }[piece]
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    for name in names:
+        assert name in text, f"{name} is not in the compiled module"
+    # nothing (T x T) lives in HBM: no array of the module has two axes of T
+    import re
+    for dims in re.findall(r"\b(?:f32|bf16|s32|u32|pred|s8|u8)\[([0-9,]+)\]", text):
+        assert sum(int(n) >= t for n in dims.split(",")) < 2, dims

@@ -1,0 +1,331 @@
+"""Learned sparse attention's pieces (``ops/sparse_attention.py``,
+``ops.flash_attention.selected_attention``) in the Pallas interpreter: the
+packed selection and its inverse; the exact top-k of a chunk against
+``lax.top_k``, ties included; the three selected flash kernels against dense
+attention under the same mask, at blocks that fold the grid, at a chunk
+narrower than a sub-tile, with a block that holds no selected pair (a dead
+step, counted) and on a row shorter than ``topk`` (the causal-dense call's
+bits); the alignment loss and the indexer's backward against ``jax.grad`` of
+their definition; the fetch table; and a recomputed block that keeps the
+forward's selection."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.ops import sparse_attention as dsa
+from horovod_tpu.ops.flash_attention import flash_attention, selected_attention
+
+B, T, H, KV, D, HI, DI = 2, 128, 4, 2, 16, 2, 8
+
+
+def _operands(seed, t=T, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    shapes = [(B, t, H, D), (B, t, KV, D), (B, t, KV, D), (B, t, H, D),
+              (B, t, HI, DI), (B, t, DI), (B, t, HI)]
+    return [jax.random.normal(k, s, jnp.float32).astype(dtype)
+            for k, s in zip(ks, shapes)]
+
+
+def _causal(t):
+    return jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+
+
+def _top_k_mask(scores, topk):
+    """The definition: ``lax.top_k`` over the causal scores, ties to the
+    lower position; all causal keys while there are no more than ``topk``."""
+    t = scores.shape[-1]
+    _, best = jax.lax.top_k(jnp.where(_causal(t), scores, -jnp.inf),
+                            min(topk, t))
+    picked = jnp.zeros(scores.shape, bool)
+    picked = picked.at[jnp.arange(scores.shape[0])[:, None, None],
+                       jnp.arange(t)[None, :, None], best].set(True)
+    return picked & _causal(t)
+
+
+def _index_scores(qi, ki, w):
+    z = jnp.einsum("bthd,bsd->bhts", qi, ki)
+    return jnp.einsum("bth,bhts->bts", w, jax.nn.relu(z))
+
+
+def _masked_attention(q, k, v, mask):
+    """Dense attention of (B, T, H, D) over the pairs ``mask`` (B, T, T)
+    keeps; -> (out, per-head logsumexp (B, H, T))."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    s = jnp.where(mask[:, None], s, -jnp.inf)
+    return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v),
+            jax.nn.logsumexp(s, axis=-1))
+
+
+def close(got, want, tol):
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol,
+                               rtol=tol)
+
+
+@pytest.mark.parametrize("t,chunk", [(128, 16), (96, 32), (2048, 32)])
+def test_pack_and_unpack_are_inverses(t, chunk):
+    mask = jax.random.bernoulli(jax.random.PRNGKey(t), 0.3, (3, 5, t))
+    words = dsa.pack(mask, chunk)
+    assert words.shape == (3, 5, dsa.word_columns(t, chunk))
+    assert words.dtype == jnp.int32
+    np.testing.assert_array_equal(dsa.unpack(words, t, chunk), mask)
+    # key s: bit (s // chunk) % 32 of column (s // chunk // 32) * chunk + s % chunk
+    one = dsa.pack(jnp.arange(t) == t - 1, chunk)
+    n, c = (t - 1) // chunk, (t - 1) % chunk
+    assert int(one[n // 32 * chunk + c]) == np.int32(np.uint32(1 << (n % 32)))
+    assert int(jnp.sum(one != 0)) == 1
+
+
+@pytest.mark.parametrize("levels", [0, 3, 1000])
+@pytest.mark.parametrize("topk", [8, 24, 200])
+def test_select_is_the_exact_top_k_with_ties_to_the_lower_position(topk, levels):
+    """``levels`` distinct values make ties at the threshold in most rows
+    (0: every score equal); none are left to chance."""
+    _, _, _, _, qi, ki, w = _operands(topk + levels)
+    if levels:
+        qi, ki, w = (jnp.round(x * 2) / 2 for x in (qi, ki, w))
+        if levels < 10:
+            w = jnp.round(w)
+    else:
+        w = jnp.zeros_like(w)
+    want = _top_k_mask(_index_scores(qi, ki, w), topk)
+    words, lse_i = dsa.select(qi, ki, w, topk, 16, 32, True)
+    got = dsa.unpack(words, T, 16)
+    np.testing.assert_array_equal(got, want)
+    # all of its causal keys while a query has no more than topk
+    np.testing.assert_array_equal(got[:, :min(topk, T)],
+                                  jnp.broadcast_to(_causal(T), got.shape)[
+                                      :, :min(topk, T)])
+    assert int(jnp.sum(got)) == B * sum(min(t + 1, topk) for t in range(T))
+    close(lse_i, jax.nn.logsumexp(jnp.where(
+        want, _index_scores(qi, ki, w), -jnp.inf), axis=-1), 1e-5)
+
+
+def _mask_with_a_dead_block():
+    """Every query keeps its own block's causal keys and the first 32 keys:
+    the blocks (2, 1), (3, 1) and (3, 2) of 32 x 32 hold no pair."""
+    pos = jnp.arange(T)
+    own = (pos[:, None] // 32 == pos[None, :] // 32) | (pos[None, :] < 32)
+    return jnp.broadcast_to(own & _causal(T), (B, T, T))
+
+
+@pytest.mark.parametrize("block_q,block_k,chunk", [
+    (32, 32, 16),       # four q blocks: the folded grid
+    (64, 32, 32),       # ratio 2: two crossed blocks a q block
+    (128, 128, 16),     # one block: sub-tiles wider than a chunk
+    (96, 32, 32),       # never: 128 % 96 - fitted down to 64
+    (32, 16, 32),       # a k block narrower than a chunk (interpreter only)
+])
+@pytest.mark.parametrize("mask_kind", ["top_k", "dead_block"])
+def test_selected_kernels_are_dense_attention_under_the_mask(
+        block_q, block_k, chunk, mask_kind):
+    q, k, v, g, qi, ki, w = _operands(1)
+    if mask_kind == "top_k":
+        mask = _top_k_mask(_index_scores(qi, ki, w), 24)
+    else:
+        mask = _mask_with_a_dead_block()
+    words = dsa.pack(mask, chunk)
+
+    def system(q, k, v):
+        return selected_attention(q, k, v, words, block_q, block_k, True,
+                                  None, chunk)
+
+    (out, lse), vjp = jax.vjp(system, q, k, v)
+    (want, want_lse), want_vjp = jax.vjp(
+        lambda q, k, v: _masked_attention(q, k, v, mask), q, k, v)
+    close(out, want, 2e-5)
+    close(lse, want_lse, 2e-5)
+    for got, ref in zip(vjp((g, jnp.zeros_like(lse))),
+                        want_vjp((g, jnp.zeros_like(want_lse)))):
+        close(got, ref, 5e-5)
+
+
+def test_a_block_without_a_selected_pair_is_a_dead_step_and_counted():
+    mask = _mask_with_a_dead_block()
+    words = dsa.pack(mask, 16)
+    live = dsa.block_liveness(words, 32, 32, 16)
+    want = np.array([[1, 0, 0, 0], [1, 1, 0, 0], [1, 0, 1, 0], [1, 0, 0, 1]],
+                    bool)
+    np.testing.assert_array_equal(live, np.broadcast_to(want, (B, 4, 4)))
+    pairs, steps = dsa.census(words, 32, 32, 16)
+    assert int(pairs) == int(jnp.sum(mask)) and int(steps) == B * 7
+    # a dead step's index maps name the block already resident
+    np.testing.assert_array_equal(dsa.fetch_table(jnp.asarray(want)),
+                                  [[0, 0, 0, 0], [0, 1, 1, 1], [0, 0, 2, 2],
+                                   [0, 0, 0, 3]])
+    np.testing.assert_array_equal(
+        dsa.fetch_table(jnp.asarray([[False, False, True, False],
+                                     [False, False, False, False]])),
+        [[2, 2, 2, 2], [0, 1, 2, 3]])
+    # keys nobody selects receive no gradient
+    q, k, v, g, *_ = _operands(2)
+    lonely = mask & (jnp.arange(T)[None, :] != 40)[None]
+    _, dk, dv = jax.grad(lambda q, k, v: jnp.sum(selected_attention(
+        q, k, v, dsa.pack(lonely, 16), 32, 32, True, None, 16)[0] * g),
+        argnums=(0, 1, 2))(q, k, v)
+    assert float(jnp.max(jnp.abs(dk[:, 40]))) == 0.0
+    assert float(jnp.max(jnp.abs(dv[:, 40]))) == 0.0
+    assert float(jnp.max(jnp.abs(dv[:, 41]))) > 0.0
+
+
+def test_the_gauges_say_what_the_steps_selected(hvd):
+    hvd.metrics.record_dsa_census(np.zeros((0, 2), np.int32), 20)
+    hvd.metrics.record_dsa_census(np.asarray([[1000, 14], [1200, 16]]), 20)
+    gauges = hvd.metrics.registry().snapshot()["gauges"]
+    assert gauges["horovod_dsa_selected_pairs_per_step"] == 1100
+    assert gauges["horovod_dsa_live_block_steps_per_step"] == 15
+    assert gauges["horovod_dsa_dense_block_steps_per_step"] == 20
+    assert gauges["horovod_flash_dead_step_share"] == pytest.approx(0.25)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_row_shorter_than_topk_is_the_causal_dense_call(dtype):
+    """While ``t < topk`` the selection is every causal key: out and the
+    three gradients are the causal-dense kernels', bit for bit."""
+    q, k, v, g, qi, ki, w = _operands(3, 64, dtype)
+    words, _ = dsa.select(qi, ki, w, 100, 16, 16, True)
+    np.testing.assert_array_equal(
+        dsa.unpack(words, 64, 16), jnp.broadcast_to(_causal(64), (B, 64, 64)))
+
+    def selected(q, k, v):
+        return selected_attention(q, k, v, words, 32, 32, True, None, 16)[0]
+
+    def dense(q, k, v):
+        return flash_attention(q, k, v, True, 32, 32, True)
+
+    for fn in (lambda f: f(q, k, v),
+               lambda f: jax.vjp(f, q, k, v)[1](g.astype(dtype))):
+        for got, want in zip(jax.tree_util.tree_leaves(fn(selected)),
+                             jax.tree_util.tree_leaves(fn(dense))):
+            np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                          np.asarray(want, np.float32))
+
+
+def _align_definition(q, k, qi, ki, w, mask):
+    """The loss as it is written: p a constant, r the softmax of the
+    indexer's score over the selection."""
+    group = q.shape[2] // k.shape[2]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, group, axis=2)
+                   ) * q.shape[-1] ** -0.5
+    p = jax.lax.stop_gradient(jnp.mean(jax.nn.softmax(
+        jnp.where(mask[:, None], s, -jnp.inf), axis=-1), axis=1))
+    log_r = jax.nn.log_softmax(jnp.where(mask, _index_scores(qi, ki, w),
+                                         -jnp.inf), axis=-1)
+    kept = mask & (p > 0)
+    return jnp.mean(jnp.sum(jnp.where(kept, p * (
+        jnp.log(jnp.where(kept, p, 1.0)) - jnp.where(kept, log_r, 0.0)), 0.0),
+        axis=-1))
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_alignment_loss_and_the_indexers_backward(chunk):
+    q, k, v, _, qi, ki, w = _operands(4)
+    words, lse_i = dsa.select(qi, ki, w, 24, chunk, 32, True)
+    mask = dsa.unpack(words, T, chunk)
+    _, lse = selected_attention(q, k, v, words, 32, 32, True, None, chunk)
+
+    def system(qi, ki, w):
+        return dsa.align_loss(q, k, lse, qi, ki, w, words, lse_i, None, chunk,
+                              True)
+
+    got, grads = jax.value_and_grad(system, argnums=(0, 1, 2))(qi, ki, w)
+    want, want_grads = jax.value_and_grad(
+        lambda *a: _align_definition(q, k, *a, mask), argnums=(0, 1, 2))(
+        qi, ki, w)
+    assert float(want) > 1e-2
+    close(got, want, 1e-5)
+    close(system(qi, ki, w), want, 1e-5)        # the undifferentiated call
+    for g, r in zip(grads, want_grads):
+        assert float(jnp.max(jnp.abs(r))) > 1e-5
+        close(g, r, 2e-5 * float(jnp.max(jnp.abs(r))) / 1e-2 + 1e-7)
+    # q, k and the logsumexp are constants of it
+    assert all(float(jnp.max(jnp.abs(g))) == 0.0 for g in jax.grad(
+        lambda q, k, lse: dsa.align_loss(q, k, lse, qi, ki, w, words, lse_i,
+                                         None, chunk, True),
+        argnums=(0, 1, 2))(q, k, lse))
+
+
+def _model(remat, **fields):
+    from horovod_tpu.models import RotaryScheme, SparseDims, TransformerLM
+
+    return TransformerLM(
+        vocab=64, dim=32, heads=4, kv_heads=2, head_dim=16, layers=2,
+        dtype=jnp.float32, attention="flash", flash_interpret=True,
+        block_q=32, block_k=32, qk_head_norm=True, rope_theta=1e4,
+        rotary=RotaryScheme(theta=1e4, sections=(2, 3, 3)),
+        sparse=SparseDims(index_heads=2, index_dim=8, topk=12, kv_chunk=16,
+                          q_chunk=16),
+        moe_experts=4, moe_top_k=2, moe_hidden=16, moe_every=1,
+        moe_norm_topk=True, remat=remat, **fields)
+
+
+def test_a_recomputed_block_keeps_the_forwards_selection():
+    """``TransformerLM(remat=True)`` saves the selection (the name
+    ``ops.sparse_attention.SELECTED``) and the alignment loss's gradients:
+    the backward runs no second score pass, top-k or alignment pass, and on a
+    seeded tie (the indexer's weights zero: every score equal, the first
+    ``topk`` keys kept) its gradients are the unrecomputed model's."""
+    from horovod_tpu.common import device_names
+    from horovod_tpu.models import align_losses
+
+    tokens = jnp.arange(64, dtype=jnp.int32).reshape(1, 64) % 64
+    variables = _model(False).init(jax.random.PRNGKey(0), tokens)
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, x: jnp.zeros_like(x) if any(
+            getattr(p, "key", None) == "index_w" for p in path) else x,
+        variables["params"])
+
+    def grad_of(remat):
+        def loss(params):
+            logits, state = _model(remat).apply(
+                {"params": params}, tokens, mutable=["intermediates"])
+            return jnp.sum(logits ** 2) * 1e-3 + align_losses(
+                state["intermediates"])[0]
+        return jax.grad(loss)
+
+    plain, recomputed = grad_of(False), grad_of(True)
+    for name, calls in ((device_names.DSA_INDEXER_SCORES, 2),
+                        (device_names.DSA_ALIGN_TILES, 2),
+                        (device_names.FLASH_SEL_FWD, None)):
+        counts = [str(jax.make_jaxpr(f)(params)).count(name)
+                  for f in (plain, recomputed)]
+        if calls is None:       # the forward kernel alone runs again
+            assert counts[1] == 2 * counts[0] > 0
+        else:
+            assert counts[0] == counts[1] > 0, (name, counts)
+    for got, want in zip(*(jax.tree_util.tree_leaves(f(params))
+                           for f in (recomputed, plain))):
+        close(got, want, 1e-5)
+    # the tie: every query kept its first 12 keys
+    _, state = _model(False).apply({"params": params}, tokens,
+                                   mutable=["intermediates"])
+    kept = dsa.unpack(state["intermediates"]["block_0"]["dsa_words"][0], 64, 16)
+    np.testing.assert_array_equal(
+        kept[0], _causal(64) & (jnp.arange(64)[None, :] < 12))
+
+
+def test_the_selection_carries_no_gradient_and_the_losses_do_not_mix():
+    """The indexer's parameters move by the alignment loss alone, every other
+    parameter by the language-model loss alone."""
+    from horovod_tpu.models import align_losses
+
+    tokens = (jnp.arange(64, dtype=jnp.int32).reshape(1, 64) * 7) % 64
+    model = _model(False)
+    params = model.init(jax.random.PRNGKey(1), tokens)["params"]
+
+    def terms(params):
+        logits, state = model.apply({"params": params}, tokens,
+                                    mutable=["intermediates"])
+        return jnp.sum(logits ** 2), align_losses(state["intermediates"])[0]
+
+    def is_indexer(path):
+        return any(str(getattr(p, "key", "")).startswith("index_") for p in path)
+
+    for which, own in ((0, False), (1, True)):
+        grads = jax.grad(lambda p: terms(p)[which])(params)
+        for path, leaf in jax.tree_util.tree_flatten_with_path(grads)[0]:
+            moved = float(jnp.max(jnp.abs(leaf))) > 0.0
+            assert moved == (is_indexer(path) == own), (which, path)
