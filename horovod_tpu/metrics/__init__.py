@@ -36,6 +36,7 @@ from .overlap import (  # noqa: F401
     measure_overlap,
     record_chunked_loss_plan,
     record_dsa_census,
+    record_dsa_select_plan,
     record_flash_plan,
     record_flash_window_plan,
     record_mamba_fused_passes,

@@ -303,6 +303,20 @@ def record_dsa_census(census, dense_steps: int) -> None:
     ).set((dense_steps - live) / max(1, dense_steps))
 
 
+def record_dsa_select_plan(visited_share: float) -> None:
+    """Record how far the latest traced ``ops.sparse_attention.select`` stops
+    at the causal diagonal (trace time, once per compile, from shapes): the
+    key columns the selection kernel's counting passes visit over queries x
+    keys (``ops.sparse_attention.select_share``: a row tile visits the key
+    chunks at or before its last query). About a half on a long row."""
+    registry().gauge(
+        "horovod_dsa_select_visited_share",
+        help="key columns the counting passes of the latest traced "
+             "sparse-attention selection visit, over queries x keys; 0 = "
+             "none traced"
+    ).set(visited_share)
+
+
 def record_ssd_plan(chunk: int, kernel: bool) -> None:
     """Record the chunk length the latest traced ``ops.ssd.ssd`` cut its rows
     into (trace time, once per compile): the configured chunk, or the row's

@@ -10,12 +10,15 @@ Four pieces, none of which holds a (T x T) array of floats in HBM:
   float32) in tiles by a kernel (``hvd_dsa_indexer_scores``), ``q_chunk``
   query rows at a time; of each row the EXACT ``topk`` largest among its
   causal keys (all of them while ``t < topk``; ties to the lower position, as
-  ``lax.top_k``) by a radix select on the scores' bit patterns - 32 counting
-  passes over the chunk, no sort; and the selection as BITS, one a pair
-  (:func:`pack`): ``words[b, t, c]`` holds, in bit ``n % 32``, whether query
-  ``t`` keeps key ``n * chunk + c % chunk`` for the 32 key chunks ``n`` of word
-  group ``c // chunk``. A score sub-tile's mask is then ``(words >> n) & 1``
-  on a lane-aligned slice of the query block's words: no lane is moved.
+  ``lax.top_k``) by a radix select on the scores' bit patterns in ONE kernel
+  (``hvd_dsa_select``) that holds a tile of 128 queries' order keys in VMEM -
+  32 counting passes over the key chunks at or before the tile's last query
+  and over no other, no sort - and writes the selection as BITS, one a pair
+  (:func:`pack`'s layout): ``words[b, t, c]`` holds, in bit ``n % 32``, whether
+  query ``t`` keeps key ``n * chunk + c % chunk`` for the 32 key chunks ``n``
+  of word group ``c // chunk``. A score sub-tile's mask is then ``(words >>
+  n) & 1`` on a lane-aligned slice of the query block's words: no lane is
+  moved.
 * ``ops.flash_attention.selected_attention`` - the three flash kernels with
   that mask as one more operand; a block step none of whose pairs is selected
   fetches and runs nothing (:func:`block_liveness`, scalar-prefetched).
@@ -37,6 +40,7 @@ differentiate another set than the forward ran (ops/moe.py ``CHOSEN_EXPERTS``).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -182,45 +186,180 @@ def _scores(qi, ki, w, row0, tile_k, interpret):
 
 # ---------------------------------------------------------------- selection
 
+_DEAD = -2 ** 31        # the order key of a key no query may keep: below all
+_SELECT_ROWS = 128      # a row tile: its order keys, (T x 128) int32, in VMEM
+_SELECT_SLAB = 2048     # columns of scores a grid step fetches
+_SELECT_SUMS = 64       # rows of a counting pass's partial sums: 8 registers
+
+
 def _order_keys(scores):
-    """float32 -> uint32 whose unsigned order is the floats' (-0.0 as 0.0)."""
-    bits = lax.bitcast_convert_type(scores + 0.0, jnp.uint32)
-    return jnp.where(bits >> 31 == 0, bits | jnp.uint32(1 << 31), ~bits)
+    """float32 -> int32 whose signed order is the floats' (-0.0 as 0.0);
+    ``_DEAD`` where the score is -inf (a key after the query) or NaN."""
+    scores = jnp.where(scores == 0.0, 0.0, scores)
+    bits = lax.bitcast_convert_type(scores, jnp.int32)
+    keys = jnp.where(bits < 0, bits ^ jnp.int32(2 ** 31 - 1), bits)
+    return jnp.where(scores > -jnp.inf, keys, jnp.int32(_DEAD))
 
 
-def select_rows(scores, row0, topk):
+def _scores_of(keys):
+    """:func:`_order_keys`' inverse on the keys of finite scores."""
+    return lax.bitcast_convert_type(
+        jnp.where(keys < 0, keys ^ jnp.int32(2 ** 31 - 1), keys), jnp.float32)
+
+
+def _reach(first, tq, width):
+    """Blocks of ``width`` keys that the ``tq`` queries from ``first`` on can
+    see: those at or before the last one's own."""
+    return (first + tq - 1) // width + 1
+
+
+def _select_kernel(row0_ref, s_ref, words_ref, lse_ref, keys_ref, top_ref,
+                   upto_ref, packed_ref, *, tq, tk, t, topk, chunk, sums):
+    """One row tile of ``tq`` queries, held TRANSPOSED: a query a lane, the
+    keys down the sublanes, so a count over a row's keys is a sum of whole
+    registers and nothing crosses lanes (``sums``: the rows of a pass's
+    partial sums). Grid steps below the tile's reach: the slab's order keys
+    into ``keys_ref`` (T, tq) and their running maximum into ``top_ref``. At
+    the last grid step everything else, from ``keys_ref`` and
+    over the key chunks at or before the tile's last query alone: the k-th
+    largest key of every row by 32 counting passes; if a row has more keys
+    equal to it than it may keep, the position of the last one it keeps by
+    ``bit_length(T - 1)`` more over the positions of the equal ones
+    (``upto_ref``); then one pass that packs the kept pairs and sums their
+    exponentials."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    first = row0_ref[0] + i * tq            # the tile's first query
+
+    def folded(x, op):      # (n x sums, tq) or (n, sums, tq) -> (sums, tq)
+        return op(x.reshape(-1, sums, tq), axis=0)
+
+    def down(x, op):        # over the sublanes, the result in every one
+        return jnp.broadcast_to(op(x, axis=0, keepdims=True), x.shape)
+
+    @pl.when(j == 0)
+    def _first():
+        top_ref[...] = jnp.full_like(top_ref, _DEAD)
+
+    @pl.when(j < _reach(first, tq, tk))
+    def _fill():
+        keys = _order_keys(s_ref[...]).T
+        keys_ref[pl.ds(pl.multiple_of(j * tk, tk), tk), :] = keys
+        top_ref[...] = jnp.maximum(top_ref[...], folded(keys, jnp.max))
+
+    # at the tile's last grid step, so that the next tile's first slab is
+    # fetched under the passes
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _select():
+        live = _reach(first, tq, chunk)     # key chunks to visit
+        row = first + lax.broadcasted_iota(jnp.int32, (sums, tq), 1)
+        k_row = jnp.minimum(row + 1, topk)
+        within = (lax.broadcasted_iota(jnp.int32, (chunk // sums, sums, tq), 0)
+                  * sums + lax.broadcasted_iota(
+                      jnp.int32, (chunk // sums, sums, tq), 1))
+
+        def piece(n):       # key chunk n: (keys, positions), (.., sums, tq)
+            at = pl.multiple_of(n * chunk, chunk)
+            return (keys_ref[pl.ds(at, chunk), :].reshape(within.shape),
+                    at + within)
+
+        def count(hit):     # per row, over the live chunks, in every sublane
+            def one_chunk(n, partial):
+                return partial + folded(
+                    hit(*piece(n)).astype(jnp.int32), jnp.sum)
+            return down(lax.fori_loop(
+                0, live, one_chunk, jnp.zeros((sums, tq), jnp.int32)), jnp.sum)
+
+        def narrow(b, carry):   # bit 31 - b of the k-th largest: sign first
+            kth, reached = carry
+            cand = kth ^ lax.shift_left(jnp.int32(1), 31 - b)
+            n = count(lambda keys, _: keys >= cand)
+            return (jnp.where(n >= k_row, cand, kth),
+                    jnp.where(n >= k_row, n, reached))
+
+        kth, reached = lax.fori_loop(
+            0, 32, narrow, (jnp.full((sums, tq), _DEAD, jnp.int32),
+                            jnp.zeros((sums, tq), jnp.int32)))
+        # reached: the keys >= kth. More than the row may keep: ties at the
+        # threshold beyond the wanted, the lower positions then (rare)
+        upto_ref[...] = jnp.full((sums, tq), t, jnp.int32)
+
+        @pl.when(jnp.max(reached - k_row) > 0)
+        def _ties():
+            wanted = k_row - count(lambda keys, _: keys > kth)      # >= 1
+
+            def later(b, upto):     # the most positions holding < wanted
+                cand = upto | lax.shift_left(
+                    jnp.int32(1), (t - 1).bit_length() - 1 - b)
+                n = count(lambda keys, at: (keys == kth) & (at < cand))
+                return jnp.where(n < wanted, cand, upto)
+
+            upto_ref[...] = lax.fori_loop(
+                0, (t - 1).bit_length(), later,
+                jnp.zeros((sums, tq), jnp.int32))
+
+        upto = upto_ref[...]
+        top = _scores_of(down(top_ref[...], jnp.max))
+        packed_ref[...] = jnp.zeros_like(packed_ref)
+
+        def keep(n, total):
+            keys, at = piece(n)
+            kept = (keys > kth) | ((keys == kth) & (at <= upto))
+            cols = pl.ds(pl.multiple_of(n // WORD_BITS * chunk, chunk), chunk)
+            packed_ref[cols, :] = packed_ref[cols, :] | jnp.where(
+                kept, lax.shift_left(jnp.int32(1), n % WORD_BITS), 0
+            ).reshape(chunk, tq)
+            return total + folded(
+                jnp.where(kept, jnp.exp(_scores_of(keys) - top), 0.0), jnp.sum)
+
+        total = lax.fori_loop(0, live, keep,
+                              jnp.zeros((sums, tq), jnp.float32))
+        lse_ref[0] = (top + jnp.log(down(total, jnp.sum)))[:1]
+        words_ref[...] = packed_ref[...].T
+
+
+def select_share(t, rows, chunk):
+    """Key columns the selection's passes visit over ``t x t``: every row
+    tile of a chunk of ``rows`` queries stops at the key chunk of its last
+    query."""
+    tq = _fit_block(rows, _SELECT_ROWS, 8)
+    return sum(_reach(first, tq, chunk) * chunk * tq
+               for first in range(0, t, tq)) / (t * t)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _select_call(scores, row0, topk, chunk, interpret):
     """Of each row of ``scores`` (rows, T) - the query at ``row0 + i``, -inf
     at the keys after it - the ``min(topk, row0 + i + 1)`` largest among the
-    keys ``s <= row0 + i``, ties to the lower position: (mask (rows, T),
-    logsumexp of the kept scores (rows,)). A radix select: the k-th largest
-    bit pattern by 32 counting passes, everything above it, and of those
-    equal to it the first few. (The causal keys are told by the scores and
-    not by an iota: a mask of positions alone XLA would compute for every
-    chunk ahead of the loop, T x T bytes.)"""
+    keys ``s <= row0 + i``, ties to the lower position: (words (rows,
+    word_columns) as :func:`pack` gives them, logsumexp of the kept scores
+    (rows,)). A radix select in one kernel; slabs after the tile's last query
+    are neither fetched nor visited."""
     rows, t = scores.shape
-    valid = scores > -jnp.inf
-    keys = jnp.where(valid, _order_keys(scores), jnp.uint32(0))  # valid: >= 1
-    k_row = jnp.minimum(row0 + jnp.arange(rows) + 1, topk).astype(jnp.int32)
-
-    def narrow(i, kth):
-        cand = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-        enough = jnp.sum(keys >= cand[:, None], axis=1, dtype=jnp.int32) >= k_row
-        return jnp.where(enough, cand, kth)
-
-    kth = lax.fori_loop(0, 32, narrow, jnp.zeros((rows,), jnp.uint32))
-    above, equal = keys > kth[:, None], keys == kth[:, None]
-    wanted = k_row - jnp.sum(above, axis=1, dtype=jnp.int32)    # >= 1
-    # ties at the threshold beyond the wanted: rare; the first ``wanted`` then
-    first = lax.cond(
-        jnp.any(jnp.sum(equal, axis=1, dtype=jnp.int32) > wanted),
-        lambda: equal & (jnp.cumsum(equal, axis=1, dtype=jnp.int32)
-                         <= wanted[:, None]),
-        lambda: equal)
-    mask = above | first
-    kept = jnp.where(mask, scores, -jnp.inf)
-    top = jnp.max(kept, axis=1)
-    lse = top + jnp.log(jnp.sum(jnp.exp(kept - top[:, None]), axis=1))
-    return mask, lse
+    tq = _fit_block(rows, _SELECT_ROWS, 8)
+    tk = _fit_block(t, _SELECT_SLAB, chunk)      # whole chunks a slab
+    cols = word_columns(t, chunk)
+    sums = math.gcd(chunk, _SELECT_SUMS)
+    words, lse = pl.pallas_call(
+        functools.partial(_select_kernel, tq=tq, tk=tk, t=t, topk=topk,
+                          chunk=chunk, sums=sums),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows // tq, t // tk),
+            in_specs=[pl.BlockSpec((tq, tk), lambda i, j, r: (
+                i, jnp.minimum(j, _reach(r[0] + i * tq, tq, tk) - 1)))],
+            out_specs=[pl.BlockSpec((tq, cols), lambda i, j, r: (i, 0)),
+                       pl.BlockSpec((1, 1, tq), lambda i, j, r: (i, 0, 0))],
+            scratch_shapes=[pltpu.VMEM((t, tq), jnp.int32),
+                            pltpu.VMEM((sums, tq), jnp.int32),
+                            pltpu.VMEM((sums, tq), jnp.int32),
+                            pltpu.VMEM((cols, tq), jnp.int32)]),
+        out_shape=[jax.ShapeDtypeStruct((rows, cols), jnp.int32),
+                   jax.ShapeDtypeStruct((rows // tq, 1, tq), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name=DSA_SELECT,
+    )(jnp.asarray(row0, jnp.int32).reshape(1), scores)
+    return words, lse.reshape(rows)
 
 
 def select(qi, ki, w, topk, chunk=512, q_chunk=512, interpret=False):
@@ -231,8 +370,11 @@ def select(qi, ki, w, topk, chunk=512, q_chunk=512, interpret=False):
     each query's kept scores (what :func:`align_loss` normalises ``r`` by).
     No gradient flows into or out of either; both carry the name
     ``SELECTED``. Scores exist ``q_chunk`` query rows at a time."""
+    from ..metrics import record_dsa_select_plan
+
     b, t, heads, d = qi.shape
     rows = _fit_block(t, q_chunk, 8)
+    record_dsa_select_plan(select_share(t, rows, chunk))
     qi, ki, w = (lax.stop_gradient(x) for x in (qi, ki, w))
     qh = jnp.moveaxis(qi, 2, 1)                               # (B, heads, T, d)
     w = w.astype(jnp.float32)
@@ -248,8 +390,7 @@ def select(qi, ki, w, topk, chunk=512, q_chunk=512, interpret=False):
                     lax.dynamic_slice_in_dim(w, row0, rows, axis=0),
                     row0, chunk, interpret)
             with jax.named_scope(DSA_SELECT):
-                mask, lse = select_rows(scores, row0, topk)
-                return pack(mask, chunk), lse
+                return _select_call(scores, row0, topk, chunk, interpret)
 
         words, lse = lax.map(one_chunk, jnp.arange(t // rows))
         return words.reshape(t, -1), lse.reshape(t)

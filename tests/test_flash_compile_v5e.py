@@ -551,7 +551,8 @@ def test_sparse_attention_kernels_compile_for_v5e(one_chip, no_persistent_cache,
 
     fn, args, names = {
         "select": (lambda qi, ki, w: dsa.select(qi, ki, w, 2048), (qi, ki, w),
-                   (device_names.DSA_INDEXER_SCORES,)),
+                   (device_names.DSA_INDEXER_SCORES,
+                    f'{device_names.DSA_SELECT}/pallas_call')),
         "flash": (flash, (q, kv, kv, words),
                   (device_names.FLASH_SEL_FWD, device_names.FLASH_SEL_BWD_DQ,
                    device_names.FLASH_SEL_BWD_DKV)),

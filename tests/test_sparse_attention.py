@@ -79,29 +79,85 @@ def test_pack_and_unpack_are_inverses(t, chunk):
     assert int(jnp.sum(one != 0)) == 1
 
 
-@pytest.mark.parametrize("levels", [0, 3, 1000])
-@pytest.mark.parametrize("topk", [8, 24, 200])
-def test_select_is_the_exact_top_k_with_ties_to_the_lower_position(topk, levels):
-    """``levels`` distinct values make ties at the threshold in most rows
-    (0: every score equal); none are left to chance."""
-    _, _, _, _, qi, ki, w = _operands(topk + levels)
-    if levels:
-        qi, ki, w = (jnp.round(x * 2) / 2 for x in (qi, ki, w))
-        if levels < 10:
-            w = jnp.round(w)
+# (T, chunk, q_chunk, topk, levels, the weights' sign). ``levels`` distinct
+# values make ties at the threshold in most rows (0: every score equal); none
+# are left to chance.
+SELECT_CASES = {
+    **{f"topk{topk}-levels{levels}": (T, 16, 32, topk, levels, 1)
+       for topk in (8, 24, 200) for levels in (0, 3, 1000)},
+    # row0 < topk <= row0 + rows: some rows keep all their keys, some choose
+    "a-chunk-straddles-topk": (T, 16, 32, 40, 1000, 1),
+    # every score <= 0 and many exactly 0: the ties are on top
+    "negative-scores": (T, 16, 32, 24, 3, -1),
+    # 40 key chunks: two word groups, the second 8 bits deep; every score equal
+    "all-equal-over-two-word-groups": (640, 16, 64, 24, 0, 1),
+    # three key chunks in a word of 32
+    "a-word-group-partly-padding": (96, 32, 32, 24, 3, 1),
+    # scores of its own: see _signed_zero_scores
+    "signed-zeros-at-the-threshold": (T, 16, 32, 24, None, 1),
+}
+
+
+def _signed_zero_scores(t):
+    """(B, T, T) causal scores of -2, -0.0, +0.0 and 1 in turn: from the 25th
+    query on the threshold lies among the zeros of both signs, which count as
+    equal and go to the lower position."""
+    values = jnp.asarray([-2.0, -0.0, 0.0, 1.0, 0.0, -0.0], jnp.float32)
+    at = (jnp.arange(t)[None, :] * 5 + jnp.arange(t)[:, None] * 3) % 6
+    return jnp.broadcast_to(jnp.where(_causal(t), values[at], -jnp.inf),
+                            (B, t, t))
+
+
+@pytest.mark.parametrize("case", sorted(SELECT_CASES))
+def test_select_is_the_exact_top_k_with_ties_to_the_lower_position(case):
+    t, chunk, q_chunk, topk, levels, sign = SELECT_CASES[case]
+    if levels is None:      # the kernel alone, chunk by chunk as select does
+        scores = _signed_zero_scores(t)
+        assert bool(jnp.any(jnp.signbit(scores) & (scores == 0)))
+        parts = [[dsa._select_call(row[at:at + q_chunk], at, topk, chunk, True)
+                  for at in range(0, t, q_chunk)] for row in scores]
+        words, lse_i = (jnp.stack([jnp.concatenate([p[i] for p in row])
+                                   for row in parts]) for i in (0, 1))
     else:
-        w = jnp.zeros_like(w)
-    want = _top_k_mask(_index_scores(qi, ki, w), topk)
-    words, lse_i = dsa.select(qi, ki, w, topk, 16, 32, True)
-    got = dsa.unpack(words, T, 16)
-    np.testing.assert_array_equal(got, want)
+        _, _, _, _, qi, ki, w = _operands(topk + levels, t)
+        if levels:
+            qi, ki, w = (jnp.round(x * 2) / 2 for x in (qi, ki, w))
+            if levels < 10:
+                w = jnp.round(w)
+        else:
+            w = jnp.zeros_like(w)
+        w = sign * jnp.abs(w) if sign < 0 else w
+        scores = _index_scores(qi, ki, w)
+        words, lse_i = dsa.select(qi, ki, w, topk, chunk, q_chunk, True)
+    want = _top_k_mask(scores + 0.0, topk)      # -0.0 as 0.0
+    np.testing.assert_array_equal(words, dsa.pack(want, chunk))   # bit-equal
+    got = dsa.unpack(words, t, chunk)
     # all of its causal keys while a query has no more than topk
-    np.testing.assert_array_equal(got[:, :min(topk, T)],
-                                  jnp.broadcast_to(_causal(T), got.shape)[
-                                      :, :min(topk, T)])
-    assert int(jnp.sum(got)) == B * sum(min(t + 1, topk) for t in range(T))
-    close(lse_i, jax.nn.logsumexp(jnp.where(
-        want, _index_scores(qi, ki, w), -jnp.inf), axis=-1), 1e-5)
+    np.testing.assert_array_equal(got[:, :min(topk, t)],
+                                  jnp.broadcast_to(_causal(t), got.shape)[
+                                      :, :min(topk, t)])
+    assert int(jnp.sum(got)) == B * sum(min(s + 1, topk) for s in range(t))
+    close(lse_i, jax.nn.logsumexp(jnp.where(want, scores, -jnp.inf), axis=-1),
+          1e-6)
+
+
+@pytest.mark.parametrize("t,chunk,q_chunk", [(16384, 512, 512), (T, 16, 32)])
+def test_the_gauge_says_which_share_of_the_keys_the_passes_visit(
+        hvd, t, chunk, q_chunk):
+    """From shapes, at trace time: a row tile (128 queries, or the chunk's if
+    fewer) visits the key chunks at or before its last query."""
+    shape = jax.ShapeDtypeStruct
+    jax.eval_shape(
+        lambda qi, ki, w: dsa.select(qi, ki, w, 24, chunk, q_chunk, True),
+        shape((1, t, HI, DI), jnp.float32), shape((1, t, DI), jnp.float32),
+        shape((1, t, HI), jnp.float32))
+    tile = min(q_chunk, 128)
+    visited = sum((((query // tile + 1) * tile - 1) // chunk + 1) * chunk
+                  for query in range(t))
+    share = hvd.metrics.registry().snapshot()["gauges"][
+        "horovod_dsa_select_visited_share"]
+    assert share == pytest.approx(visited / (t * t), rel=1e-12)
+    assert 0.5 < share < (0.53 if t == 16384 else 0.7)
 
 
 def _mask_with_a_dead_block():
@@ -329,3 +385,70 @@ def test_the_selection_carries_no_gradient_and_the_losses_do_not_mix():
         for path, leaf in jax.tree_util.tree_flatten_with_path(grads)[0]:
             moved = float(jnp.max(jnp.abs(leaf))) > 0.0
             assert moved == (is_indexer(path) == own), (which, path)
+
+
+def test_a_choice_moved_by_hand_is_held_under_the_systems_choice(
+        hvd, monkeypatch):
+    """The keye configuration's own check (``benchmarks/configs``, at the
+    tiny sizes of ``tests/benchmark/test_benchmark_keye.py``) with the
+    selection's kernel replaced by one that exchanges query 90's last kept
+    key for the first it left out - the two bits flipped in ``words``,
+    ``lse_i`` recomputed over the kept - as a float32 tie between its 24th
+    and 25th score would, and a token whose 2nd expert is exchanged for its
+    3rd: both shares are held as shares, and losses, logits and gradients
+    against the reference computed under the system's choices
+    (``selection(forced=)``, ``route(forced=)``), at the float32 limits."""
+    import os
+
+    monkeypatch.syspath_prepend(
+        os.path.join(os.path.dirname(__file__), "benchmark"))
+    from test_benchmark_keye import check_alone
+
+    from horovod_tpu.models import moe as models_moe
+
+    real_call, real_route = dsa._select_call, models_moe.topk_route
+
+    def other_key(scores, row0, topk, chunk, interpret):
+        words, _ = real_call(scores, row0, topk, chunk, interpret)
+        rows, t = scores.shape
+        mask = dsa.unpack(words, t, chunk)
+        pos = jnp.arange(t)
+        last = jnp.max(jnp.where(mask, pos, -1), axis=1)
+        free = jnp.min(jnp.where((scores > -jnp.inf) & ~mask, pos, t), axis=1)
+        here = (row0 + jnp.arange(rows) == 90) & (free < t)
+        mask ^= here[:, None] & ((pos == last[:, None]) | (pos == free[:, None]))
+        kept = jnp.where(mask, scores, -jnp.inf)
+        return dsa.pack(mask, chunk), jax.nn.logsumexp(kept, axis=1)
+
+    def other_expert(logits, top_k, renormalise=False):
+        probs, _, experts = real_route(logits, top_k, renormalise)
+        _, wider = jax.lax.top_k(probs, top_k + 1)
+        first = jnp.arange(experts.shape[0])[:, None] == 0      # token 0 alone
+        last = jnp.arange(top_k)[None, :] == top_k - 1
+        experts = jnp.where(first & last, wider[:, top_k:], experts)
+        onehot = experts[:, :, None] == jnp.arange(probs.shape[-1])
+        weights = jnp.sum(jnp.where(onehot, probs[:, None, :], 0.0), axis=-1)
+        return probs, weights / weights.sum(-1, keepdims=True), experts
+
+    # the ops' jitted calls keep their traces: start and end without them
+    jax.clear_caches()
+    monkeypatch.setattr(dsa, "_select_call", other_key)
+    monkeypatch.setattr(models_moe, "topk_route", other_expert)
+    try:
+        resolved, check = check_alone(hvd)
+        resolved["config"]["tolerance"] = {
+            **resolved["config"]["tolerance"], "f32_flipped_share": 0.05,
+            "f32_selection_share": 0.01, "bf16_logits_rel": 1.0}
+        observed = check()["observed"]
+    finally:
+        jax.clear_caches()
+    f32 = observed["f32"]
+    assert f32["held_under"] == "the system's choice"
+    # token 0 by hand; the query whose key moved may choose others after it
+    assert 1 / 96 - 1e-6 <= f32["flipped_share"] <= 3 / 96 + 1e-6
+    kept = sum(min(t + 1, 24) for t in range(96))
+    # a pair a layer by hand; the query's later selection may follow it
+    assert 2 / (2 * kept) - 1e-9 <= f32["selection_share"] <= 8 / (2 * kept)
+    assert f32["logits"] <= 2e-6 and f32["lm"] <= 1e-6 and f32["align"] <= 4e-5
+    assert max(f32["grads_rel"].values()) <= 3e-5
+    assert observed["bf16"]["held_under"] == "the reference's own choice"
