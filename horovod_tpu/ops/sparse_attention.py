@@ -27,7 +27,8 @@ Four pieces, none of which holds a (T x T) array of floats in HBM:
   averaged over the heads (recomputed tile by tile from the forward's per-head
   logsumexp), ``r`` the softmax of ``I`` over the selection; and its gradient
   ``r - p`` into ``I`` carried on through the weighted sum and the ReLU into
-  ``qI``, ``kI`` and ``w`` in the same tile loop (``hvd_dsa_align_tiles``).
+  ``qI``, ``kI`` and ``w`` in the same tile loop (``hvd_dsa_align_tiles``),
+  which keeps a tile's ``ReLU(z)`` in VMEM between the two.
 * the names and the census (:func:`census`): selected pairs and live block
   steps of a step, from the data.
 
@@ -61,7 +62,15 @@ ALIGN_GRADS = "dsa_align_grads"
 WORD_BITS = 32
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _NN = (((1,), (0,)), ((), ()))      # a @ b
-_VMEM_LIMIT = 64 * 2 ** 20          # the align kernel's: of 128 MiB on a v5e
+# The align kernel's scoped VMEM, of 128 MiB on a v5e. At the cell's shape (32
+# heads over 4 of 128, an indexer of 16 x 64, tiles of 256 x 512, bf16) its
+# blocks, each twice for the pipeline: q 2 x 2 MiB, k 2 x 0.5, qI 2 x 1 (64
+# lanes padded to 128), qI^T 2 x 0.5, words 2 x 0.5, dqI 2 x 2, dkI^T (64 x T,
+# whole) 2 x 4, the six narrow ones (lse, kI, w, lse_i, loss, dw) 2 x 0.125
+# each; the scratch of ReLU(z), 16 x 256 x 512 float32, 8; a tile's
+# temporaries (p, the index score, r - p, the running sum, the bits) ~3:
+# ~35 MiB (float32 operands, as the check's legs: ~41).
+_VMEM_LIMIT = 64 * 2 ** 20
 
 
 def _relu(z):
@@ -442,46 +451,51 @@ def census(words, block_q, block_k, chunk):
 def _align_kernel(live_ref, q_ref, k_ref, lse_ref, qi_ref, qit_ref, ki_ref,
                   w_ref, lsei_ref, words_ref, loss_ref, *grad_refs, tq, tk,
                   nk, heads, group, index_heads, sm_scale, chunk, grads):
+    """One tile of ``tq`` queries x ``tk`` keys. With ``grads`` the last of
+    ``grad_refs`` is a VMEM scratch (index_heads, tq, tk) float32: the loss
+    loop leaves every indexer head's ``ReLU(z_j)`` there and the gradient
+    loop reads it back (``z > 0`` is ``ReLU(z) > 0``), so a score tile is one
+    product, not two. Both loops stand inside the same live tile: no tile
+    reads what another wrote. float32: a rounded copy would round dw's sum."""
     qt, kt = pl.program_id(0), pl.program_id(1)
 
     if grads:
+        dqi_ref, dw_ref, dkit_ref, relu_ref = grad_refs
+
         @pl.when((qt == 0) & (kt == 0))
         def _first():
-            grad_refs[2][...] = jnp.zeros_like(grad_refs[2])    # dkI^T, whole
+            dkit_ref[...] = jnp.zeros_like(dkit_ref)            # dkI^T, whole
 
     @pl.when(kt == 0)
     def _init():
         loss_ref[...] = jnp.zeros_like(loss_ref)
         if grads:
-            grad_refs[0][...] = jnp.zeros_like(grad_refs[0])
-            grad_refs[1][...] = jnp.zeros_like(grad_refs[1])
+            dqi_ref[...] = jnp.zeros_like(dqi_ref)
+            dw_ref[...] = jnp.zeros_like(dw_ref)
 
     @pl.when(live_ref[qt * nk + kt] != 0)
     def _tile():
         rows = pl.ds(0, tq)
         sel = tile_bits(words_ref, (), rows, (kt * tk, 0), tk, chunk, tk)
         lse = lse_ref[...]                                      # (tq, heads)
-        lane = lax.broadcasted_iota(jnp.int32, lse.shape, 1)
-
-        def one_head(a, total):
+        # the heads one after another in ONE block of code, not a loop: a
+        # loop's body is a product and then the vector work on it, and the
+        # next head's product does not start under it
+        total = jnp.zeros((tq, tk), jnp.float32)
+        for a in range(heads):
             s = lax.dot_general(q_ref[a], k_ref[a // group], _NT,
                                 preferred_element_type=jnp.float32) * sm_scale
-            lse_a = jnp.sum(jnp.where(lane == a, lse, 0.0), axis=1,
-                            keepdims=True)
-            return total + jnp.exp(s - lse_a)
-
-        p = jnp.where(sel, lax.fori_loop(
-            0, heads, one_head, jnp.zeros((tq, tk), jnp.float32)), 0.0) / heads
+            total = total + jnp.exp(s - lse[:, a:a + 1])
+        p = jnp.where(sel, total, 0.0) / heads
         kI = ki_ref[...]
         w = w_ref[...]
-
-        def score(j):
-            return lax.dot_general(qi_ref[j], kI, _NT,
-                                   preferred_element_type=jnp.float32)
-
         index = jnp.zeros((tq, tk), jnp.float32)
         for j in range(index_heads):
-            index = index + w[:, j:j + 1] * _relu(score(j))
+            relu_z = _relu(lax.dot_general(
+                qi_ref[j], kI, _NT, preferred_element_type=jnp.float32))
+            if grads:
+                relu_ref[j] = relu_z
+            index = index + w[:, j:j + 1] * relu_z
         log_r = index - lsei_ref[...]
         loss_ref[...] += jnp.sum(
             jnp.where(sel & (p > 0), p * (jnp.log(jnp.where(p > 0, p, 1.0))
@@ -489,16 +503,16 @@ def _align_kernel(live_ref, q_ref, k_ref, lse_ref, qi_ref, qit_ref, ki_ref,
             axis=1, keepdims=True)
         if not grads:
             return
-        dqi_ref, dw_ref, dkit_ref = grad_refs
         d_index = jnp.where(sel, jnp.exp(log_r) - p, 0.0)
         w_lane = lax.broadcasted_iota(jnp.int32, w.shape, 1)
         dw = jnp.zeros(w.shape, jnp.float32)
         cols = pl.ds(pl.multiple_of(kt * tk, tk), tk)
         for j in range(index_heads):
-            z = score(j)
+            relu_z = relu_ref[j]        # z > 0 is ReLU(z) > 0
             dw = dw + jnp.where(w_lane == j, jnp.sum(
-                d_index * _relu(z), axis=1, keepdims=True), 0.0)
-            g = jnp.where(z > 0, d_index * w[:, j:j + 1], 0.0).astype(kI.dtype)
+                d_index * relu_z, axis=1, keepdims=True), 0.0)
+            g = jnp.where(relu_z > 0, d_index * w[:, j:j + 1],
+                          0.0).astype(kI.dtype)
             dqi_ref[j] += lax.dot_general(g, kI, _NN,
                                           preferred_element_type=jnp.float32)
             dkit_ref[:, cols] += lax.dot_general(
@@ -512,7 +526,12 @@ def _align_call(q, k, lse, qi, ki, w, lse_i, words, sm_scale, chunk, interpret,
     """One batch row: ``q`` (H, T, D), ``k`` (Hkv, T, D), ``lse`` (T, H), ``qi``
     (Hi, T, Di), ``ki`` (T, Di), ``w`` (T, Hi) f32, ``lse_i`` (T, 1), ``words``
     (T, cols). -> the rows' loss terms (T, 1) and, with ``grads``, the
-    gradients of their SUM: dqI (Hi, T, Di), dw (T, Hi), dkI^T (Di, T), f32."""
+    gradients of their SUM: dqI (Hi, T, Di), dw (T, Hi), dkI^T (Di, T), f32.
+    A live tile of 256 queries x ``chunk`` keys holds in VMEM what it makes:
+    the heads' running sum, the index score and, with ``grads``, every
+    indexer head's ReLU(z) (a scratch, ``_VMEM_LIMIT`` has the budget), so
+    each score tile is computed once; without ``grads`` there is no second
+    loop and no scratch."""
     heads, t, d = q.shape
     kv_heads = k.shape[0]
     index_heads, _, di = qi.shape
@@ -546,7 +565,9 @@ def _align_call(q, k, lse, qi, ki, w, lse_i, words, sm_scale, chunk, interpret,
     ]
     out_specs = [at_q((tq, 1), lambda i: (i, 0))]
     out_shape = [jax.ShapeDtypeStruct((t, 1), jnp.float32)]
+    scratch = []
     if grads:
+        scratch = [pltpu.VMEM((index_heads, tq, tk), jnp.float32)]
         out_specs += [at_q((index_heads, tq, di), lambda i: (0, i, 0)),
                       at_q((tq, index_heads), lambda i: (i, 0)),
                       pl.BlockSpec((di, t), lambda i, j, live: (0, 0))]
@@ -561,7 +582,7 @@ def _align_call(q, k, lse, qi, ki, w, lse_i, words, sm_scale, chunk, interpret,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(nq, nk), in_specs=in_specs,
-            out_specs=out_specs),
+            out_specs=out_specs, scratch_shapes=scratch),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
@@ -620,8 +641,9 @@ def align_loss(q, k, lse, qi, ki, w, words, lse_i, sm_scale=None, chunk=512,
     (B, T, Hi, Di), ``ki`` (B, T, Di) and ``w`` (B, T, Hi), normalised by
     ``lse_i`` (:func:`select`'s). Differentiable in ``qi``, ``ki`` and ``w``
     alone; the gradient comes out of the same tile loop as the value (``r -
-    p`` on the selected pairs, through the weighted sum and the ReLU), so a
-    differentiated call runs the kernel once."""
+    p`` on the selected pairs, through the weighted sum and the ReLU, whose
+    ``ReLU(z)`` the tile keeps in VMEM from the value's loop: no score is
+    computed twice), so a differentiated call runs the kernel once."""
     return _align(q, k, lse, qi, ki, w, words, lse_i, sm_scale, chunk,
                   interpret, False)[0]
 

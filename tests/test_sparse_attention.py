@@ -6,8 +6,9 @@ attention under the same mask, at blocks that fold the grid, at a chunk
 narrower than a sub-tile, with a block that holds no selected pair (a dead
 step, counted) and on a row shorter than ``topk`` (the causal-dense call's
 bits); the alignment loss and the indexer's backward against ``jax.grad`` of
-their definition; the fetch table; and a recomputed block that keeps the
-forward's selection."""
+their definition, over several q tiles, dead tiles between live ones and
+ReLU products at exactly zero, and the products a tile of it runs; the fetch
+table; and a recomputed block that keeps the forward's selection."""
 
 import jax
 import jax.numpy as jnp
@@ -160,12 +161,15 @@ def test_the_gauge_says_which_share_of_the_keys_the_passes_visit(
     assert 0.5 < share < (0.53 if t == 16384 else 0.7)
 
 
-def _mask_with_a_dead_block():
-    """Every query keeps its own block's causal keys and the first 32 keys:
-    the blocks (2, 1), (3, 1) and (3, 2) of 32 x 32 hold no pair."""
-    pos = jnp.arange(T)
-    own = (pos[:, None] // 32 == pos[None, :] // 32) | (pos[None, :] < 32)
-    return jnp.broadcast_to(own & _causal(T), (B, T, T))
+def _mask_with_a_dead_block(t=T, block=32):
+    """Every query keeps its own block's causal keys and the first ``block``
+    keys: at the defaults the blocks (2, 1), (3, 1) and (3, 2) of 32 x 32 hold
+    no pair; in a wider tile's row the k tiles between the first and the
+    tile's own are DEAD between live ones."""
+    pos = jnp.arange(t)
+    own = (pos[:, None] // block == pos[None, :] // block) | (
+        pos[None, :] < block)
+    return jnp.broadcast_to(own & _causal(t), (B, t, t))
 
 
 @pytest.mark.parametrize("block_q,block_k,chunk", [
@@ -276,12 +280,40 @@ def _align_definition(q, k, qi, ki, w, mask):
         axis=-1))
 
 
-@pytest.mark.parametrize("chunk", [16, 64])
-def test_alignment_loss_and_the_indexers_backward(chunk):
-    q, k, v, _, qi, ki, w = _operands(4)
-    words, lse_i = dsa.select(qi, ki, w, 24, chunk, 32, True)
-    mask = dsa.unpack(words, T, chunk)
-    _, lse = selected_attention(q, k, v, words, 32, 32, True, None, chunk)
+# (T, chunk, the selection, whether the indexer's operands are whole numbers:
+# then many ReLU products are EXACTLY zero, where the gradient is zero). A q
+# tile is 256 queries: rows of 512 and 768 have two and three.
+ALIGN_CASES = {
+    "one-q-tile-chunk16": (T, 16, "top_k", False),
+    "one-q-tile-chunk64": (T, 64, "top_k", False),
+    "two-q-tiles": (512, 64, "top_k", False),
+    "two-q-tiles-zero-products": (512, 64, "top_k", True),
+    "dead-tiles-between-live-ones": (512, 64, "own_chunk", False),
+    "dead-tiles-and-zero-products": (768, 128, "own_chunk", True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ALIGN_CASES))
+def test_alignment_loss_and_the_indexers_backward(case):
+    t, chunk, selection, whole = ALIGN_CASES[case]
+    q, k, v, _, qi, ki, w = _operands(4, t)
+    if whole:
+        qi, ki = jnp.round(qi), jnp.round(ki)
+        zero = jnp.einsum("bthd,bsd->bhts", qi, ki) == 0
+        assert float(jnp.mean(zero)) > 0.05
+    if selection == "top_k":
+        words, lse_i = dsa.select(qi, ki, w, 24, chunk, 32, True)
+        mask = dsa.unpack(words, t, chunk)
+    else:
+        mask = _mask_with_a_dead_block(t, chunk)
+        words = dsa.pack(mask, chunk)
+        lse_i = jax.nn.logsumexp(
+            jnp.where(mask, _index_scores(qi, ki, w), -jnp.inf), axis=-1)
+        live = np.asarray(dsa.block_liveness(words, 256, chunk, chunk)[0, -1])
+        first_dead, last_live = np.argmin(live), np.flatnonzero(live)[-1]
+        assert live[0] and 0 < first_dead < last_live     # dead between live
+    block = 32 if t == T else 128
+    _, lse = selected_attention(q, k, v, words, block, block, True, None, chunk)
 
     def system(qi, ki, w):
         return dsa.align_loss(q, k, lse, qi, ki, w, words, lse_i, None, chunk,
@@ -297,11 +329,51 @@ def test_alignment_loss_and_the_indexers_backward(chunk):
     for g, r in zip(grads, want_grads):
         assert float(jnp.max(jnp.abs(r))) > 1e-5
         close(g, r, 2e-5 * float(jnp.max(jnp.abs(r))) / 1e-2 + 1e-7)
-    # q, k and the logsumexp are constants of it
-    assert all(float(jnp.max(jnp.abs(g))) == 0.0 for g in jax.grad(
+    # q, k and the logsumexp are constants of it (once is enough: the short row)
+    assert t > T or all(float(jnp.max(jnp.abs(g))) == 0.0 for g in jax.grad(
         lambda q, k, lse: dsa.align_loss(q, k, lse, qi, ki, w, words, lse_i,
                                          None, chunk, True),
         argnums=(0, 1, 2))(q, k, lse))
+
+
+def _equations(jaxpr, name):
+    """The equations of one primitive in a jaxpr and everything it nests."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub, name)
+
+
+@pytest.mark.parametrize("grads", [True, False])
+def test_a_tile_of_the_alignment_pass_computes_each_score_once(grads):
+    """The products of the kernel's body at the cell's 16 indexer heads: one
+    a main head, ONE an indexer head for its score tile (a (tq, tk) product
+    over ``index_dim``; the parent ran each twice) and with ``grads`` two an
+    indexer head for dqI and dkI^T - the gradient loop reads the loss loop's
+    ReLU(z) from a VMEM scratch. Without ``grads`` there is no second loop
+    and no scratch."""
+    t, heads, kv_heads, d, index_heads, di, chunk = 512, 4, 2, 16, 16, 8, 64
+    shape = jax.ShapeDtypeStruct
+    f32 = jnp.float32
+    jaxpr = jax.make_jaxpr(
+        lambda *a: dsa._align_call(*a, d ** -0.5, chunk, False, grads))(
+        shape((heads, t, d), f32), shape((kv_heads, t, d), f32),
+        shape((t, heads), f32), shape((index_heads, t, di), f32),
+        shape((t, di), f32), shape((t, index_heads), f32), shape((t, 1), f32),
+        shape((t, dsa.word_columns(t, chunk)), jnp.int32))
+    (call,) = _equations(jaxpr.jaxpr, "pallas_call")
+    products = list(_equations(call.params["jaxpr"], "dot_general"))
+    scores = [eqn for eqn in products
+              if eqn.outvars[0].aval.shape == (256, chunk)
+              and eqn.invars[0].aval.shape == (256, di)]
+    scratch = call.params["grid_mapping"].num_scratch_operands
+    assert len(scores) == index_heads
+    assert len(products) == heads + (3 if grads else 1) * index_heads
+    assert scratch == int(grads)
 
 
 def _model(remat, **fields):
