@@ -1,0 +1,9 @@
+"""KDA mixer layer: positions a chunk of the latest traced ``ops.kda.kda``
+(gauge ``horovod_kda_chunk_len``, set at trace time from the call); a program
+without the gauge, or one that traced no delta-rule scan, gives nothing."""
+
+from benchmarks.program_counters import gauge
+
+
+def read(run):
+    return gauge("horovod_kda_chunk_len") or None

@@ -68,6 +68,19 @@ MAMBA_GATE_NORM_BWD = "hvd_mamba_gate_norm_bwd"
 SSD_SCAN = "hvd_ssd_scan"               # the scan over blocks of chunks: chunk
 #                                         states, the recurrence, the outputs
 
+# Kimi Delta Attention's mixer (models/kda.py) and its chunked gated delta
+# rule (ops/kda.py). The benchmark finds the mixer's time by the names that
+# start with ``hvd_kda``.
+KDA_PROJ = "hvd_kda_proj"               # q, k, v, o and the low-rank gates' products
+KDA_CONV = "hvd_kda_conv"               # the three causal depthwise convolutions + silu
+# ops/mamba_fused.py's convolution kernels under this layer's names, for the
+# shapes they tile (``conv_silu(names=)``).
+KDA_CONV_FWD = "hvd_kda_conv_fwd"
+KDA_CONV_BWD = "hvd_kda_conv_bwd"
+KDA_GATE = "hvd_kda_gate"               # L2 norms, softplus, the log-decay, beta
+KDA_SCAN = "hvd_kda_scan"               # the chunked delta rule, forward and backward
+KDA_OUT_NORM = "hvd_kda_out_norm"       # the head-wise RMSNorm, then the sigmoid gate
+
 # Learned sparse attention (ops/sparse_attention.py, ``Block.sparse``). The
 # benchmark finds each part's time by the scope's name and its kernel's.
 DSA_INDEXER = "hvd_dsa_indexer"         # the indexer's projections, norm, rotary

@@ -340,6 +340,25 @@ def record_ssd_plan(chunk: int, kernel: bool) -> None:
         scans.set(0)
 
 
+def record_kda_plan(chunk: int, saved_state_bytes: int) -> None:
+    """Record what the latest traced ``ops.kda.kda`` cut its rows into (trace
+    time, once per compile, from the call's own shapes): the chunk length
+    (the configured one, or the row's own where that is shorter) and the
+    bytes its backward keeps of the carried states (the state each block of
+    chunks starts from; 0 would mean they are recomputed). Both 0 until a
+    delta-rule scan is traced."""
+    registry().gauge(
+        "horovod_kda_chunk_len",
+        help="positions a chunk of the latest traced ops.kda.kda (the "
+             "chunked gated delta rule); 0 = none traced"
+    ).set(chunk)
+    registry().gauge(
+        "horovod_kda_saved_state_bytes_per_layer",
+        help="bytes of carried states the backward of the latest traced "
+             "ops.kda.kda keeps (one call = one layer); 0 = none traced"
+    ).set(saved_state_bytes)
+
+
 def record_mamba_fused_passes(passes: int) -> None:
     """Record how many of the latest traced ``models.mamba.Mamba2Mixer``'s two
     elementwise chains (convolution + silu, the gated norm) went through a

@@ -1,0 +1,145 @@
+"""Kimi Delta Attention's mixer as a flax module (Kimi Linear,
+arXiv:2510.26692; the layer Kimi-Linear-48B-A3B puts in three of four
+places, latent attention in the fourth; docs/linear-attention.md).
+
+For normed hidden states ``h`` (B, T, dim), ``H`` heads of ``d``, ``inner = H
+d``, no bias anywhere::
+
+    q~ = h Wq;  k~ = h Wk;  v~ = h Wv          dim -> inner each
+    q, k, v = silu(conv(q~)), silu(conv(k~)), silu(conv(v~))
+                                        each its OWN causal depthwise
+                                        convolution of ``conv`` taps, no bias
+    q = L2norm(q) d^-0.5;  k = L2norm(k)       a head, eps 1e-6
+    g = -exp(A_log) softplus((h Wf_a) Wf_b + dt_bias)
+                                        dim -> d -> inner, float32: the log
+                                        of a decay a CHANNEL; A_log a head
+    beta = sigmoid(h Wb)                       dim -> H, one a head
+    o = kda(q, k, v, g, beta)                  ops/kda.py: the gated delta rule
+    o = RMSNorm(o) sigmoid((h Wg_a) Wg_b)      the norm a head with ONE weight
+                                        of d shared by the heads, THEN the
+                                        gate (Mamba-2's layer gates first)
+    out = o Wo                                 inner -> dim
+
+Numerics: float32 parameters; projections, convolutions, q, k, v and the
+gate in ``dtype`` (bf16 as trained); the L2 norms' and the head norm's
+statistics, ``g`` and ``beta`` in float32.
+
+The convolution + silu runs as ``ops/mamba_fused.py``'s kernel pair under
+this layer's names where its shape tiles (``conv_takes_kernel``), three calls
+a layer, and as ``jax.numpy`` otherwise. The scan is ``jax.numpy`` whatever
+the shape (``ops/kda.py``).
+
+Initialisation: ``A_log`` the log of uniform(1, 16) a head and ``dt_bias``
+by Mamba-2's inverse-softplus rule (``models/mamba.py``), the family's; norm
+weight 1; projections and taps at flax's defaults. ``A_log``, ``dt_bias``,
+the norm's weight and the taps take no weight decay: the optimizer's to
+arrange.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..common import device_names
+from ..ops import mamba_fused
+from ..ops.kda import CHUNK, kda
+from ..ops.ssd import causal_depthwise_conv
+from .mamba import _a_log_init, _dt_bias_init
+
+CONV_NAMES = (device_names.KDA_CONV_FWD, device_names.KDA_CONV_BWD)
+
+
+@dataclasses.dataclass(frozen=True)
+class KDADims:
+    """The mixer's sizes as a model's ``linear_attn_config`` states them
+    (``num_heads``, ``head_dim``, ``short_conv_kernel_size``); ``chunk`` is
+    the training path's, which no result depends on in exact arithmetic."""
+    heads: int
+    head_dim: int
+    conv: int = 4
+    chunk: int = CHUNK
+
+
+def conv_silu(x, kernel):
+    """``silu`` of the causal depthwise convolution without a bias
+    (``ops/ssd.py``'s, under this layer's name): what shapes that do not tile
+    run; the others take the kernels."""
+    conv = causal_depthwise_conv(x, kernel, jnp.zeros(kernel.shape[1:]),
+                                 scope=device_names.KDA_CONV)
+    with jax.named_scope(device_names.KDA_CONV):
+        return nn.silu(conv)
+
+
+def l2_norm(x, eps=1e-6):
+    """``x / sqrt(sum(x^2) + eps)`` over the last axis, in float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def head_norm_then_gate(o, gate, scale, eps):
+    """``RMSNorm(o) * scale * sigmoid(gate)``: the norm a head (o: (B, T, H,
+    d); ``scale`` ONE weight of d shared by the heads), THEN the gate (B, T,
+    H d), in float32. Returns (B, T, H d) float32."""
+    with jax.named_scope(device_names.KDA_OUT_NORM):
+        o = o.astype(jnp.float32)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+        return (o * scale).reshape(gate.shape) * nn.sigmoid(
+            gate.astype(jnp.float32))
+
+
+class KDAMixer(nn.Module):
+    dim: int
+    dims: KDADims
+    rms_norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    # True runs the convolution's kernels, where the shapes take them, in the
+    # Pallas interpreter: ``Block`` hands its ``flash_interpret`` down.
+    interpret: bool = False
+
+    @nn.compact
+    def __call__(self, h):
+        m = self.dims
+        b, t, _ = h.shape
+        inner = m.heads * m.head_dim
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype, name=name)
+
+        def conv(x, name):
+            taps = self.param(name, nn.initializers.lecun_normal(),
+                              (m.conv, inner), jnp.float32)
+            if mamba_fused.conv_takes_kernel(x, taps):
+                return mamba_fused.conv_silu(
+                    x, taps, jnp.zeros((inner,), jnp.float32), self.interpret,
+                    names=CONV_NAMES)
+            return conv_silu(x, taps)
+
+        with jax.named_scope(device_names.KDA_PROJ):
+            q, k, v = (dense(inner, name)(h)
+                       for name in ("q_proj", "k_proj", "v_proj"))
+            decay = dense(inner, "f_b_proj")(dense(m.head_dim, "f_a_proj")(h))
+            beta = dense(m.heads, "b_proj")(h)
+            gate = dense(inner, "g_b_proj")(dense(m.head_dim, "g_a_proj")(h))
+        q, k, v = (conv(x, name).reshape(b, t, m.heads, m.head_dim)
+                   for x, name in ((q, "q_conv"), (k, "k_conv"), (v, "v_conv")))
+        a_log = self.param("A_log", _a_log_init, (m.heads,), jnp.float32)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (inner,), jnp.float32)
+        with jax.named_scope(device_names.KDA_GATE):
+            q = (l2_norm(q) * m.head_dim ** -0.5).astype(self.dtype)
+            k = l2_norm(k).astype(self.dtype)
+            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                decay.astype(jnp.float32) + dt_bias
+            ).reshape(b, t, m.heads, m.head_dim)
+            beta = nn.sigmoid(beta.astype(jnp.float32))
+        o = kda(q, k, v, g, beta, m.chunk)
+        scale = self.param("o_norm", nn.initializers.ones, (m.head_dim,),
+                           jnp.float32)
+        o = head_norm_then_gate(o, gate, scale,
+                                self.rms_norm_eps).astype(self.dtype)
+        with jax.named_scope(device_names.KDA_PROJ):
+            return dense(self.dim, "o_proj")(o)

@@ -27,6 +27,7 @@ import numpy as np
 from ..common import device_names
 from ..ops.moe import CHOSEN_EXPERTS
 from ..ops.sparse_attention import ALIGN_GRADS, SELECTED
+from .kda import KDADims, KDAMixer
 from .mamba import Mamba2Dims, Mamba2Mixer
 
 
@@ -258,6 +259,10 @@ class Block(nn.Module):
     sparse: Optional[SparseDims] = None
     qk_head_norm: bool = False
     moe_norm_topk: bool = False
+    # What a linear-attention hybrid's configuration states (Kimi-Linear:
+    # TransformerLM documents it): a Kimi Delta Attention mixer in
+    # attention's place.
+    kda: Optional[KDADims] = None
 
     @nn.compact
     def __call__(self, x, positions):
@@ -303,7 +308,8 @@ class Block(nn.Module):
         stated = {"mixer": {"mlp_hidden": self.mlp_hidden is not None,
                             "moe_experts": self.moe_experts > 0},
                   "mlp": {"mamba": self.mamba is not None,
-                          "mla": self.mla is not None},
+                          "mla": self.mla is not None,
+                          "kda": self.kda is not None},
                   "both": {}}[self.sublayers]
         stated["moe_shared_hidden"] = (self.moe_shared_hidden > 0
                                        and self.moe_experts <= 0)
@@ -315,9 +321,13 @@ class Block(nn.Module):
                 f"has no such half")
 
     def _mixer(self, x, positions):
-        """The mixer's branch of the normed ``x``: a Mamba-2 mixer, latent
-        attention or multi-head attention."""
+        """The mixer's branch of the normed ``x``: a Mamba-2 mixer, a Kimi
+        Delta Attention mixer, latent attention or multi-head attention."""
         h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
+        if self.kda is not None:
+            return KDAMixer(dim=self.dim, dims=self.kda,
+                            rms_norm_eps=self.rms_norm_eps, dtype=self.dtype,
+                            interpret=self.flash_interpret, name="mixer")(h)
         if self.mamba is not None:
             return Mamba2Mixer(dim=self.dim, dims=self.mamba,
                                rms_norm_eps=self.rms_norm_eps, dtype=self.dtype,
@@ -526,7 +536,9 @@ class Block(nn.Module):
         qk_rope`` where v has ``v``. The shared rotary key is broadcast to the
         heads where k is assembled (its gradient is summed over them by JAX);
         a kernel that reads it through an index map, as the grouped-query
-        path reads a shared head, is not built."""
+        path reads a shared head, is not built. ``rope=False``
+        (``mla_use_nope``): neither rotary part is turned; the split and the
+        assembling stay."""
         m, heads = self.mla, self.heads
         if self.sp_axis is not None or self.kv_heads not in (None, heads):
             raise ValueError("latent attention is multi-head on one chip: "
@@ -548,9 +560,13 @@ class Block(nn.Module):
                 q.reshape(b, t, heads, m.qk_nope + m.qk_rope), [m.qk_nope], axis=-1)
             k_nope, v = jnp.split(
                 kv.reshape(b, t, heads, m.qk_nope + m.v), [m.qk_nope], axis=-1)
-            q_rope = _rope(q_rope, positions, self.rope_theta, self.rope_interleave)
-            k_rope = _rope(k_rope.reshape(b, t, 1, m.qk_rope), positions,
-                           self.rope_theta, self.rope_interleave)
+            if self.rope:
+                q_rope = _rope(q_rope, positions, self.rope_theta,
+                               self.rope_interleave)
+            k_rope = k_rope.reshape(b, t, 1, m.qk_rope)
+            if self.rope:
+                k_rope = _rope(k_rope, positions, self.rope_theta,
+                               self.rope_interleave)
             q = jnp.concatenate([q_nope, q_rope], axis=-1)
             k = jnp.concatenate(
                 [k_nope, jnp.broadcast_to(k_rope, (b, t, heads, m.qk_rope))], axis=-1)
@@ -739,6 +755,14 @@ class TransformerLM(nn.Module):
     qk_head_norm: bool = False
     rotary: Optional[RotaryScheme] = None
     moe_norm_topk: bool = False
+    # A linear-attention hybrid (Kimi-Linear-48B-A3B: docs/linear-attention.md),
+    # as the model's own configuration states it. layer_types may also name
+    # "kda": a Kimi Delta Attention mixer of the sizes in ``kda``
+    # (models/kda.py: a gated delta rule with a decay a channel) in
+    # attention's place; with ``mla`` set its "full_attention" layers are
+    # latent attention, and ``rope=False`` (``mla_use_nope``) turns neither
+    # rotary part: no position information anywhere in the model.
+    kda: Optional[KDADims] = None
 
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden: bool = False):
@@ -749,15 +773,17 @@ class TransformerLM(nn.Module):
         mtp_kinds = tuple(self.mtp_layer_types or ())
         if len(kinds) != self.layers or set(kinds + mtp_kinds) - {
                 "attention", "mamba", "full_attention", "sliding_attention",
-                *_ONE_SUBLAYER}:
+                "kda", *_ONE_SUBLAYER}:
             raise ValueError(
                 f"layer_types {kinds} (mtp_layer_types {mtp_kinds}) must name "
-                f"'attention', 'mamba', 'full_attention', 'sliding_attention' "
-                f"or, for a layer that is one sub-layer, 'mamba_only', "
+                f"'attention', 'mamba', 'full_attention', 'sliding_attention', "
+                f"'kda' or, for a layer that is one sub-layer, 'mamba_only', "
                 f"'attention_only' or 'experts_only' for each of the "
                 f"{self.layers} layers")
         if {"mamba", "mamba_only"} & set(kinds + mtp_kinds) and self.mamba is None:
             raise ValueError("a 'mamba' layer needs the mixer's sizes (mamba=)")
+        if "kda" in kinds + mtp_kinds and self.kda is None:
+            raise ValueError("a 'kda' layer needs the mixer's sizes (kda=)")
         if "experts_only" in kinds + mtp_kinds and self.moe_experts <= 0:
             raise ValueError("an 'experts_only' layer needs its experts "
                              "(moe_experts=, moe_top_k=)")
@@ -826,7 +852,8 @@ class TransformerLM(nn.Module):
                 rope=self.rope,
                 attention_scale=self.attention_multiplier,
                 residual_scale=self.residual_multiplier,
-                mla=self.mla if sublayers != "mlp" else None,
+                mla=(self.mla if sublayers != "mlp" and kind != "kda"
+                     else None),
                 rope_theta=self.rope_theta,
                 rope_interleave=self.rope_interleave,
                 moe_router=self.moe_router,
@@ -844,6 +871,7 @@ class TransformerLM(nn.Module):
                 sparse=self.sparse if sublayers != "mlp" else None,
                 qk_head_norm=self.qk_head_norm,
                 moe_norm_topk=self.moe_norm_topk,
+                kda=self.kda if kind == "kda" else None,
                 name=name,
             )
 

@@ -298,8 +298,9 @@ def _conv_specs(wide, start, c, k):
 # The calls are jitted so that the layers of a model and the recomputed
 # forward, which call them with one signature, share ONE traced and lowered
 # copy of each kernel (ops/flash_attention.py says why).
-@functools.partial(jax.jit, static_argnums=(3, 4, 5))
-def _conv_fwd_call(wide, kernel, bias, start, splits, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _conv_fwd_call(wide, kernel, bias, start, splits, interpret,
+                   name=MAMBA_CONV_FWD):
     (b, t, _), (k, c) = wide.shape, kernel.shape
     grid, spec = _conv_specs(wide, start, c, k)
     return pl.pallas_call(
@@ -316,12 +317,13 @@ def _conv_fwd_call(wide, kernel, bias, start, splits, interpret):
             flops=(2 * k + 6) * b * t * c, transcendentals=b * t * c,
             bytes_accessed=2 * b * t * c * wide.dtype.itemsize),
         interpret=interpret,
-        name=MAMBA_CONV_FWD,
+        name=name,
     )(wide, wide, kernel, bias.reshape(1, -1))
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5))
-def _conv_bwd_call(wide, kernel, bias, dys, start, interpret):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _conv_bwd_call(wide, kernel, bias, dys, start, interpret,
+                   name=MAMBA_CONV_BWD):
     (b, t, _), (k, c) = wide.shape, kernel.shape
     grid, spec = _conv_specs(wide, start, c, k)
     widths = [dy.shape[2] for dy in dys]
@@ -343,26 +345,28 @@ def _conv_bwd_call(wide, kernel, bias, dys, start, interpret):
             flops=(6 * k + 12) * b * t * c, transcendentals=b * t * c,
             bytes_accessed=3 * b * t * c * wide.dtype.itemsize),
         interpret=interpret,
-        name=MAMBA_CONV_BWD,
+        name=name,
     )(wide, wide, wide, *dys, *dys, kernel, bias.reshape(1, -1))
     return dx, dk, db.reshape(c)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _conv_silu(wide, x, kernel, bias, start, splits, interpret):
-    return tuple(_conv_fwd_call(wide, kernel, bias, start, splits, interpret))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _conv_silu(wide, x, kernel, bias, start, splits, interpret, names):
+    return tuple(_conv_fwd_call(wide, kernel, bias, start, splits, interpret,
+                                names[0]))
 
 
-def _conv_forward(wide, x, kernel, bias, start, splits, interpret):
-    return (tuple(_conv_fwd_call(wide, kernel, bias, start, splits, interpret)),
+def _conv_forward(wide, x, kernel, bias, start, splits, interpret, names):
+    return (tuple(_conv_fwd_call(wide, kernel, bias, start, splits, interpret,
+                                 names[0])),
             (wide, kernel, bias))
 
 
-def _conv_backward(start, splits, interpret, res, dys):
+def _conv_backward(start, splits, interpret, names, res, dys):
     wide, kernel, bias = res
     dx, dk, db = _conv_bwd_call(
         wide, kernel, bias, tuple(dy.astype(wide.dtype) for dy in dys), start,
-        interpret)
+        interpret, names[1])
     # x's cotangent alone: the forward read x out of ``wide``, whose other
     # columns belong to other readers
     return None, dx, dk.astype(kernel.dtype), db.astype(bias.dtype)
@@ -372,7 +376,8 @@ _conv_silu.defvjp(_conv_forward, _conv_backward)
 
 
 def conv_silu(x, kernel, bias, interpret: bool = False, *, splits=None,
-              wide=None, start: int = 0):
+              wide=None, start: int = 0,
+              names=(MAMBA_CONV_FWD, MAMBA_CONV_BWD)):
     """``silu(causal_depthwise_conv(x, kernel, bias))`` in x's dtype for
     shapes :func:`conv_takes_kernel` accepts: x (B, T, C); kernel (K, C) and
     bias (C,) float32. With ``splits`` (widths that sum to C) the result is a
@@ -380,11 +385,13 @@ def conv_silu(x, kernel, bias, interpret: bool = False, *, splits=None,
     is copied afterwards. Where x is the columns ``start : start + C`` of a
     wider array, pass that as ``wide``: the kernels then read x out of it
     where it lies, the slice that made x is never computed, and x still
-    receives the whole gradient (``wide`` none)."""
+    receives the whole gradient (``wide`` none). ``names``: what the forward
+    and the backward kernel are called in the program, for another layer
+    that runs this convolution (``models/kda.py``)."""
     if start % 128:
         raise ValueError(f"x starts at column {start}, not at a whole lane tile")
     outs = _conv_silu(x if wide is None else wide, x, kernel, bias, start,
-                      tuple(splits or (x.shape[2],)), interpret)
+                      tuple(splits or (x.shape[2],)), interpret, tuple(names))
     return outs if splits else outs[0]
 
 

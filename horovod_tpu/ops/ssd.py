@@ -84,15 +84,16 @@ from ..common import device_names
 CHUNK_BLOCK = 8
 
 
-def causal_depthwise_conv(x, kernel, bias):
+def causal_depthwise_conv(x, kernel, bias, scope=device_names.MAMBA_CONV):
     """``out[t, c] = bias[c] + sum_j kernel[j, c] * x[t - (K - 1) + j, c]``
     with zeros before the row's start. x: (B, T, C); kernel: (K, C); bias:
     (C,). Computed as K shifted multiply-adds in float32; returns x's dtype.
+    ``scope`` is the name it runs under (another layer's, where it calls).
     The padded row goes through HBM in float32 and its K slices are not
     aligned to a tile: 9x the bytes' time at Granite's widths, which is why
     ``Mamba2Mixer`` takes ``mamba_fused.conv_silu`` where the shape tiles."""
     k, t = kernel.shape[0], x.shape[1]
-    with jax.named_scope(device_names.MAMBA_CONV):
+    with jax.named_scope(scope):
         padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
         out = bias.astype(jnp.float32)
         for j in range(k):
