@@ -340,13 +340,16 @@ def record_ssd_plan(chunk: int, kernel: bool) -> None:
         scans.set(0)
 
 
-def record_kda_plan(chunk: int, saved_state_bytes: int) -> None:
+def record_kda_plan(chunk: int, saved_state_bytes: int, kernel: bool) -> None:
     """Record what the latest traced ``ops.kda.kda`` cut its rows into (trace
     time, once per compile, from the call's own shapes): the chunk length
     (the configured one, or the row's own where that is shorter) and the
     bytes its backward keeps of the carried states (the state each block of
     chunks starts from; 0 would mean they are recomputed). Both 0 until a
-    delta-rule scan is traced."""
+    delta-rule scan is traced. ``kernel``: whether the shapes took the scan's
+    pallas kernels; ``horovod_kda_kernel_scans`` counts the traced scans that
+    did since the latest one that kept ``jax.numpy``, which sets it back to
+    0."""
     registry().gauge(
         "horovod_kda_chunk_len",
         help="positions a chunk of the latest traced ops.kda.kda (the "
@@ -357,6 +360,15 @@ def record_kda_plan(chunk: int, saved_state_bytes: int) -> None:
         help="bytes of carried states the backward of the latest traced "
              "ops.kda.kda keeps (one call = one layer); 0 = none traced"
     ).set(saved_state_bytes)
+    scans = registry().gauge(
+        "horovod_kda_kernel_scans",
+        help="traced ops.kda.kda calls whose shapes took the scan's kernels "
+             "(hvd_kda_scan_fwd / _bwd) since the latest one that kept "
+             "jax.numpy; 0 = none traced, or the latest kept jax.numpy")
+    if kernel:
+        scans.inc()
+    else:
+        scans.set(0)
 
 
 def record_mamba_fused_passes(passes: int) -> None:

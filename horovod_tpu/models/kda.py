@@ -26,8 +26,10 @@ statistics, ``g`` and ``beta`` in float32.
 
 The convolution + silu runs as ``ops/mamba_fused.py``'s kernel pair under
 this layer's names where its shape tiles (``conv_takes_kernel``), three calls
-a layer, and as ``jax.numpy`` otherwise. The scan is ``jax.numpy`` whatever
-the shape (``ops/kda.py``).
+a layer, and as ``jax.numpy`` otherwise. The scan runs as ``ops/kda.py``'s
+kernel pair where ITS shapes tile (``ops.kda.takes_kernel``: heads of 128
+lanes, chunks of 64, bf16 or float32; the gauge ``horovod_kda_kernel_scans``),
+and as ``jax.numpy`` otherwise.
 
 Initialisation: ``A_log`` the log of uniform(1, 16) a head and ``dt_bias``
 by Mamba-2's inverse-softplus rule (``models/mamba.py``), the family's; norm
@@ -97,8 +99,9 @@ class KDAMixer(nn.Module):
     dims: KDADims
     rms_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # True runs the convolution's kernels, where the shapes take them, in the
-    # Pallas interpreter: ``Block`` hands its ``flash_interpret`` down.
+    # True runs the convolution's and the scan's kernels, where the shapes
+    # take them, in the Pallas interpreter: ``Block`` hands its
+    # ``flash_interpret`` down.
     interpret: bool = False
 
     @nn.compact
@@ -136,7 +139,10 @@ class KDAMixer(nn.Module):
                 decay.astype(jnp.float32) + dt_bias
             ).reshape(b, t, m.heads, m.head_dim)
             beta = nn.sigmoid(beta.astype(jnp.float32))
-        o = kda(q, k, v, g, beta, m.chunk)
+        # (``interpret`` named only where it is asked for: the plain call is
+        # the one a stand-in for ``kda`` with its six operands answers)
+        o = kda(q, k, v, g, beta, m.chunk,
+                **({"interpret": True} if self.interpret else {}))
         scale = self.param("o_norm", nn.initializers.ones, (m.head_dim,),
                            jnp.float32)
         o = head_norm_then_gate(o, gate, scale,

@@ -3,8 +3,9 @@ at D = 64 with a softmax scale of its own, and latent attention's 192-wide q
 and k against a 128-wide v), the two grouped-product kernels (all of 64
 experts, and a share of 16 whose groups do not fill the row buffer), the
 expert layer of such a share whole (its loops over the live windows),
-the chunked state-space scan's two kernels and the Mamba-2 mixer's four
-fused kernels (convolution + silu, gated norm) COMPILED for a
+the chunked state-space scan's two kernels, the gated delta rule's two, and
+the Mamba-2 mixer's four fused kernels (convolution + silu, gated norm)
+COMPILED for a
 described v5e at the benchmark cells' shapes (no chip attached, nothing runs): what interpret mode cannot
 see — scoped VMEM, tiling and layout faults of a kernel edit — is refused
 here, on the CPU, by the TPU's own compiler.
@@ -507,6 +508,37 @@ def test_the_hybrids_mixers_compile_for_v5e_at_the_held_share(
         assert name in text, f"{name} is not in the compiled module"
     if part == "scan":
         assert " while(" not in text
+
+
+@pytest.mark.parametrize("dtype,precision", [(jnp.bfloat16, None),
+                                             (jnp.float32, "highest")],
+                         ids=["bf16", "f32_highest"])
+def test_the_delta_rules_kernels_compile_for_v5e_at_the_cells_shape(
+        one_chip, no_persistent_cache, dtype, precision):
+    """kimi_linear_seq16384_1chip's row through ``ops.kda.kda``: (1, 16384, 32
+    heads of 128 | 128), chunks of 64 in blocks of four, the step's bf16 and
+    the check's float32 under "highest". Forward and backward: both kernels
+    are in the compiled program, and no loop over blocks is."""
+    from horovod_tpu.common.device_names import KDA_SCAN
+    from horovod_tpu.ops import kda as kda_ops
+
+    def shape(*dims, of=dtype):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    x = shape(1, 16384, 32, 128)
+    args = (x, x, x, shape(1, 16384, 32, 128, of=jnp.float32),
+            shape(1, 16384, 32, of=jnp.float32))
+    assert kda_ops.takes_kernel(x, x, x, *kda_ops.plan(16384)[::2])
+
+    def value_and_grads(*a):    # the value too: a forward nobody reads is cut
+        out, vjp = jax.vjp(kda_ops.kda, *a)
+        return out, vjp(out)
+
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(value_and_grads).lower(*args).compile().as_text()
+    for name in (KDA_SCAN + "_fwd", KDA_SCAN + "_bwd"):
+        assert name in text, f"{name} is not in the compiled module"
+    assert " while(" not in text
 
 
 # keye_vl2_seq16384_1chip: 32 query heads over 4 key/value heads of 128 over a
