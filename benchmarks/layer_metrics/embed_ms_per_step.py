@@ -1,0 +1,10 @@
+"""Models layer: device time per step of the token embedding's lookup and of
+its gradient's scatter (``hvd_embed``), by the program's own names from the
+whole trace (``benchmarks/named_device_time.py``); 0.0 where the window never
+ran them, nothing for a program that does not know the name."""
+
+from benchmarks.named_device_time import ms
+
+
+def read(run):
+    return ms(run, "hvd_embed")

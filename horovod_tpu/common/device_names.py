@@ -89,6 +89,23 @@ DSA_SELECT = "hvd_dsa_select"           # the exact top-k of a chunk + packing
 DSA_ALIGN = "hvd_dsa_align"             # the alignment loss's relayouts and sums
 DSA_ALIGN_TILES = "hvd_dsa_align_tiles"     # its kernel: p, r, KL, the indexer's backward
 
+# What the step is made of beside its mixers and experts
+# (models/transformer.py, models/moe.py). None of these is opened INSIDE a
+# scope above: the older name stays the last on every path it was the last
+# on, so its metric reads what it read.
+MLP = "hvd_mlp"                         # a block's dense MLP half, gelu or SwiGLU
+# qkv / q_proj / kv_proj / o_proj of multi-head and selected attention
+ATTN_PROJ = "hvd_attn_proj"
+# The whole body of the three attention forms, as an OUTER scope: what inside
+# it has no narrower name (head split and merge, qk norms, the grouped-query
+# repeat, the dense einsum path); every kernel and scope inside keeps its time.
+ATTN = "hvd_attn"
+NORM_ADD = "hvd_norm_add"               # pre-norms, residual adds, the final norm
+EMBED = "hvd_embed"                     # the token lookup (+ its scatter back)
+LM_HEAD = "hvd_lm_head"                 # the MAIN head's products and its loss
+MOE_LOGITS = "hvd_moe_logits"           # the router's float32 product + its cast
+MOE_WEIGHT_CAST = "hvd_moe_weight_cast"     # the expert weights' cast to dtype
+
 # Names that a number completes in the module (``hvd_fused_allreduce_k3``); a
 # reader of a device profile finds these by prefix, every other by equality.
 PREFIXES = (FUSED_ALLREDUCE,)

@@ -41,6 +41,7 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 COLLECTIVE_OPCODES = ("all-reduce", "reduce-scatter", "all-gather",
                       "all-to-all", "collective-permute")
 UNNAMED = "unnamed"
+UNNAMED_OPS = 5     # rows of the table that say what ``unnamed`` holds
 # The operator entry's own host annotations (the benchmark passes its own).
 STEP_ANNOTATION = "hvd_profile_step"
 SYNC_ANNOTATION = "hvd_profile_sync"
@@ -314,11 +315,14 @@ def name_of(op_name, names=device_names.ALL, prefixes=device_names.PREFIXES):
 
 
 def _partition(ops, lo, hi, names, prefixes):
-    """``({name: ns}, {name: events}, busy intervals)`` of nested events
-    inside ``[lo, hi]``: each moment to the innermost event covering it (of
-    two that cover it, the one that started later), each event to its own
-    name, else to that of the nearest event around it, else to UNNAMED."""
-    time, count, busy, stack = {}, {}, [], []    # stack: [(end, name)]
+    """``({name: ns}, {name: events}, busy intervals, {(instruction, opcode,
+    op_name): ns of UNNAMED})`` of nested events inside ``[lo, hi]``: each
+    moment to the innermost event covering it (of two that cover it, the one
+    that started later), each event to its own name, else to that of the
+    nearest event around it, else to UNNAMED, where the innermost event's
+    instruction says what it was."""
+    time, count, busy, loose = {}, {}, [], {}
+    stack = []      # [(end, name, (instruction, opcode, op_name))]
     clock = lo
     cache = {}
 
@@ -326,10 +330,12 @@ def _partition(ops, lo, hi, names, prefixes):
         """Hand out the time up to ``until`` to the events that cover it."""
         nonlocal clock
         while stack and clock < until:
-            end, name = stack[-1]
+            end, name, what = stack[-1]
             if end > clock:
                 upto = min(end, until)
                 time[name] = time.get(name, 0.0) + (upto - clock)
+                if name == UNNAMED:
+                    loose[what] = loose.get(what, 0.0) + (upto - clock)
                 busy.append((clock, upto))
                 clock = upto
             if end <= until:
@@ -345,11 +351,12 @@ def _partition(ops, lo, hi, names, prefixes):
             cache[op.op_name] = name_of(op.op_name, names, prefixes)
         name = cache[op.op_name]
         if name is None:
-            name = next((n for e, n in reversed(stack) if e > start), UNNAMED)
+            name = next((n for e, n, _ in reversed(stack) if e > start),
+                        UNNAMED)
         count[name] = count.get(name, 0) + 1
-        stack.append((end, name))
+        stack.append((end, name, op[2:]))
     close(hi)
-    return time, count, union(busy)
+    return time, count, union(busy), loose
 
 
 def window_of(profile, opens, closes):
@@ -374,10 +381,15 @@ def by_name(profile, steps, window=None, names=device_names.ALL,
     the trace without one) by the program's names. Returns ``{"seconds":
     {name: s per step}, "calls": {name: events per step}, "unnamed": s per
     step, "busy": s per step, "idle": s per step, "idle_gaps": {host state:
-    s per step}, "device": plane, "steps": steps}``; every name of ``names``
-    is a key of ``seconds`` (0.0 where it took no time), and the names and
-    ``unnamed`` sum to ``busy``. ``host_states`` are host annotations: each
-    idle gap goes to the one that covers most of it, else to ``other``.
+    s per step}, "unnamed_ops": [(instruction, opcode, op_name, s per step)],
+    "device": plane, "steps": steps}``; every name of ``names`` is a key of
+    ``seconds`` (0.0 where it took no time), and the names and ``unnamed``
+    sum to ``busy``. ``unnamed_ops`` are the five instructions that hold most
+    of ``unnamed``, the longest first, with the numbers cut out of their names
+    (``fusion.N``: every layer's and every leaf's copy of an op is one row):
+    what no name of the program reaches. ``host_states`` are host
+    annotations: each idle gap goes to the one that covers most of it, else
+    to ``other``.
     A profile without a device plane gives the same table, empty."""
     plane = first_device(profile)
     found = profile["devices"].get(plane, {"ops": [], "async": []})
@@ -386,7 +398,8 @@ def by_name(profile, steps, window=None, names=device_names.ALL,
         window = ((min(op.start_ns for op in every),
                    max(op.end_ns for op in every)) if every else (0.0, 0.0))
     lo, hi = window
-    time, count, busy = _partition(found["ops"], lo, hi, names, prefixes)
+    time, count, busy, loose = _partition(found["ops"], lo, hi, names,
+                                          prefixes)
     # An asynchronous collective's span counts where no op covers it (its
     # exposed part), under its own name; its halves on "XLA Ops" are ops.
     for op in found["async"]:
@@ -394,8 +407,18 @@ def by_name(profile, steps, window=None, names=device_names.ALL,
         exposed = subtract(union(span), busy)
         if exposed:
             name = name_of(op.op_name, names, prefixes) or UNNAMED
-            time[name] = time.get(name, 0.0) + sum(e - s for s, e in exposed)
+            alone = sum(e - s for s, e in exposed)
+            time[name] = time.get(name, 0.0) + alone
+            if name == UNNAMED:
+                loose[op[2:]] = loose.get(op[2:], 0.0) + alone
             busy = union(busy + exposed)
+    # numbers cut (fusion.692 -> fusion.N): every layer's and every leaf's
+    # copy of an op is one row
+    rows = {}
+    for (instruction, opcode, op_name), ns in loose.items():
+        what = (re.sub(r"\d+", "N", instruction), opcode,
+                re.sub(r"\d+", "N", op_name))
+        rows[what] = rows.get(what, 0.0) + ns
     busy_ns = sum(e - s for s, e in busy)
     assert abs(sum(time.values()) - busy_ns) <= 1e-6 * max(busy_ns, 1.0), (
         "the names do not sum to the busy time", sum(time.values()), busy_ns)
@@ -413,6 +436,8 @@ def by_name(profile, steps, window=None, names=device_names.ALL,
         "calls": {name: count.get(name, 0) / max(steps, 1)
                   for name in (*names, UNNAMED)},
         "unnamed": time.get(UNNAMED, 0.0) * per_step,
+        "unnamed_ops": [(*what, ns * per_step) for what, ns in sorted(
+            rows.items(), key=lambda kv: -kv[1])[:UNNAMED_OPS]],
         "busy": busy_ns * per_step,
         "idle": (hi - lo - busy_ns) * per_step,
         "idle_gaps": {s: ns * per_step for s, ns in gaps.items()},
@@ -441,17 +466,26 @@ def collective_overlap(profile):
 
 def format_table(table):
     """The table as text: name, ms/step, share of busy, calls/step; then
-    ``unnamed``, idle and the idle gaps by host state."""
+    ``unnamed`` with the instructions that hold most of it (instruction,
+    ms/step, share of busy, opcode and ``op_name``), idle and the idle gaps
+    by host state."""
     busy = table["busy"] or 1.0
     rows = sorted(((s, n) for n, s in table["seconds"].items() if s > 0),
                   reverse=True) + [(table["unnamed"], UNNAMED)]
     lines = [f"{table['device']}: busy {table['busy'] * 1e3:.3f} ms/step over "
-             f"{table['steps']} steps, idle {table['idle'] * 1e3:.3f}",
+             f"{table['steps']} steps, idle {table['idle'] * 1e3:.3f}, named "
+             f"{100 * (table['busy'] - table['unnamed']) / busy:.1f}% of busy",
              f"{'name':<28}{'ms/step':>10}{'% busy':>8}{'calls/step':>12}"]
     for seconds, name in rows:
         lines.append(f"{name:<28}{seconds * 1e3:>10.3f}"
                      f"{100 * seconds / busy:>8.2f}"
                      f"{table['calls'].get(name, 0):>12.1f}")
+    for instruction, opcode, op_name, seconds in table["unnamed_ops"]:
+        # the end of a path says most: its innermost scopes and the primitive
+        source = "/".join(op_name.split("/")[-4:]) or "(no op_name)"
+        lines.append(f"  {instruction[-26:]:<26}{seconds * 1e3:>10.3f}"
+                     f"{100 * seconds / busy:>8.2f}  {opcode or 'op'} "
+                     f"{source}")
     for state, seconds in sorted(table["idle_gaps"].items(),
                                  key=lambda kv: -kv[1]):
         lines.append(f"idle, host in {state:<14}{seconds * 1e3:>10.3f}")

@@ -130,13 +130,15 @@ class MoEMLP(nn.Module):
                   "w_down": (here, h, width)}
         if self.activation == "relu2":
             del shapes["w_gate"]    # experts without a gate
-        experts_w = {name: self.param(name, init, shape, jnp.float32).astype(
-            self.dtype) for name, shape in shapes.items()}
+        with jax.named_scope(device_names.MOE_WEIGHT_CAST):
+            experts_w = {name: self.param(name, init, shape, jnp.float32).astype(
+                self.dtype) for name, shape in shapes.items()}
         # The router runs in float32 at full precision whatever the
         # activations' dtype: 2*N*D*E operations, and a coarser product
         # flips a token's 8th expert against its 9th far more often.
-        logits = jnp.dot(tokens.astype(jnp.float32), router,
-                         precision=jax.lax.Precision.HIGHEST)
+        with jax.named_scope(device_names.MOE_LOGITS):
+            logits = jnp.dot(tokens.astype(jnp.float32), router,
+                             precision=jax.lax.Precision.HIGHEST)
         if self.router == "sigmoid":
             bias = self.variable(BIAS_COLLECTION, "router_bias", jnp.zeros,
                                  (e,), jnp.float32).value

@@ -274,7 +274,8 @@ class Block(nn.Module):
             x = self._add(x, self._mixer(x, positions))
         if self.sublayers == "mixer":
             return x
-        h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
+        with jax.named_scope(device_names.NORM_ADD):
+            h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
         if self.moe_experts > 0:
             from .moe import MoEMLP
 
@@ -288,16 +289,19 @@ class Block(nn.Module):
                 shared_hidden=self.moe_shared_hidden, held=self.moe_held,
                 activation=self.moe_activation, latent=self.moe_latent,
                 norm_topk=self.moe_norm_topk, name="moe")(h))
-        if self.mlp_hidden is not None:
-            gate, up = (nn.Dense(self.mlp_hidden, use_bias=False,
-                                 dtype=self.dtype, name=name)(h)
-                        for name in ("mlp_gate", "mlp_up"))
-            return self._add(x, nn.Dense(self.dim, use_bias=False,
-                                         dtype=self.dtype, name="mlp_down")(
-                nn.silu(gate) * up))
-        h = nn.Dense(self.mlp_ratio * self.dim, use_bias=False, dtype=self.dtype, name="mlp_in")(h)
-        h = nn.gelu(h)
-        return self._add(x, nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="mlp_out")(h))
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype, name=name)
+
+        with jax.named_scope(device_names.MLP):
+            if self.mlp_hidden is not None:
+                gate, up = (dense(self.mlp_hidden, name)(h)
+                            for name in ("mlp_gate", "mlp_up"))
+                h = dense(self.dim, "mlp_down")(nn.silu(gate) * up)
+            else:
+                h = nn.gelu(dense(self.mlp_ratio * self.dim, "mlp_in")(h))
+                h = dense(self.dim, "mlp_out")(h)
+        return self._add(x, h)
 
     def _check_halves(self):
         """A size stated for a half this layer does not have is an error,
@@ -323,7 +327,8 @@ class Block(nn.Module):
     def _mixer(self, x, positions):
         """The mixer's branch of the normed ``x``: a Mamba-2 mixer, a Kimi
         Delta Attention mixer, latent attention or multi-head attention."""
-        h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
+        with jax.named_scope(device_names.NORM_ADD):
+            h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
         if self.kda is not None:
             return KDAMixer(dim=self.dim, dims=self.kda,
                             rms_norm_eps=self.rms_norm_eps, dtype=self.dtype,
@@ -332,16 +337,20 @@ class Block(nn.Module):
             return Mamba2Mixer(dim=self.dim, dims=self.mamba,
                                rms_norm_eps=self.rms_norm_eps, dtype=self.dtype,
                                interpret=self.flash_interpret, name="mixer")(h)
-        if self.mla is not None:
-            return self._latent_attention(h, positions)
-        if self.sparse is not None:
-            return self._selected_attention(h, positions)
-        return self._attention(h, positions)
+        # the outer name of the three attention forms: every kernel and
+        # scope inside is the last name on its own path and keeps its time
+        with jax.named_scope(device_names.ATTN):
+            if self.mla is not None:
+                return self._latent_attention(h, positions)
+            if self.sparse is not None:
+                return self._selected_attention(h, positions)
+            return self._attention(h, positions)
 
     def _add(self, x, branch):
-        if self.residual_scale != 1.0:
-            branch = branch * jnp.asarray(self.residual_scale, branch.dtype)
-        return x + branch
+        with jax.named_scope(device_names.NORM_ADD):
+            if self.residual_scale != 1.0:
+                branch = branch * jnp.asarray(self.residual_scale, branch.dtype)
+            return x + branch
 
     def _attention(self, h, positions):
         """Causal self-attention of the normed ``h``, through o_proj."""
@@ -361,15 +370,16 @@ class Block(nn.Module):
             raise ValueError("the ring schedules have no window: "
                              "window needs sp_axis=None")
         b, t = h.shape[0], h.shape[1]
-        if kvh == self.heads:
-            qkv = nn.Dense(3 * width, use_bias=False, dtype=self.dtype, name="qkv")(h)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-        else:
-            q = nn.Dense(width, use_bias=False, dtype=self.dtype,
-                         name="q_proj")(h)
-            kv = nn.Dense(2 * kvh * head_dim, use_bias=False,
-                          dtype=self.dtype, name="kv_proj")(h)
-            k, v = jnp.split(kv, 2, axis=-1)
+        with jax.named_scope(device_names.ATTN_PROJ):
+            if kvh == self.heads:
+                qkv = nn.Dense(3 * width, use_bias=False, dtype=self.dtype, name="qkv")(h)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+            else:
+                q = nn.Dense(width, use_bias=False, dtype=self.dtype,
+                             name="q_proj")(h)
+                kv = nn.Dense(2 * kvh * head_dim, use_bias=False,
+                              dtype=self.dtype, name="kv_proj")(h)
+                k, v = jnp.split(kv, 2, axis=-1)
         if self.qk_norm:
             # OLMoE: over ALL heads x head_dim features, before the split
             # into heads, each with a weight of that length.
@@ -439,7 +449,8 @@ class Block(nn.Module):
                                            dtype=self.dtype, name="gate_proj")(h))
                 attn = attn * gate[..., None]
         attn = attn.reshape(b, t, width)
-        return nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="o_proj")(attn)
+        with jax.named_scope(device_names.ATTN_PROJ):
+            return nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="o_proj")(attn)
 
     def _selected_attention(self, h, positions):
         """Grouped-query attention of the normed ``h`` over a learned
@@ -475,9 +486,10 @@ class Block(nn.Module):
         def dense(width, name):
             return nn.Dense(width, use_bias=False, dtype=self.dtype, name=name)
 
-        q = dense(heads * head_dim, "q_proj")(h).reshape(b, t, heads, head_dim)
-        k, v = (x.reshape(b, t, kvh, head_dim) for x in jnp.split(
-            dense(2 * kvh * head_dim, "kv_proj")(h), 2, axis=-1))
+        with jax.named_scope(device_names.ATTN_PROJ):
+            q = dense(heads * head_dim, "q_proj")(h).reshape(b, t, heads, head_dim)
+            k, v = (x.reshape(b, t, kvh, head_dim) for x in jnp.split(
+                dense(2 * kvh * head_dim, "kv_proj")(h), 2, axis=-1))
         if self.qk_head_norm:
             q = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype,
                            name="q_head_norm")(q)
@@ -518,7 +530,8 @@ class Block(nn.Module):
         self.sow("intermediates", "dsa_words", words)
         self.sow("intermediates", "dsa_selected_pairs", pairs)
         self.sow("intermediates", "dsa_live_block_steps", live)
-        return dense(self.dim, "o_proj")(attn.reshape(b, t, heads * head_dim))
+        with jax.named_scope(device_names.ATTN_PROJ):
+            return dense(self.dim, "o_proj")(attn.reshape(b, t, heads * head_dim))
 
     def _flash_blocks(self):
         """(block_q, block_k) for the flash kernels: the fields, or the
@@ -810,9 +823,10 @@ class TransformerLM(nn.Module):
                 f"before experts in EVERY later layer: it needs moe_experts > 0 "
                 f"and moe_every=1, not {self.moe_experts} and {self.moe_every}")
         embed = nn.Embed(self.vocab, self.dim, dtype=self.dtype, name="embed")
-        x = embed(tokens)
-        if self.embedding_multiplier != 1.0:
-            x = x * jnp.asarray(self.embedding_multiplier, x.dtype)
+        with jax.named_scope(device_names.EMBED):
+            x = embed(tokens)
+            if self.embedding_multiplier != 1.0:
+                x = x * jnp.asarray(self.embedding_multiplier, x.dtype)
         block_cls = Block
         if self.remat:
             # the routers' choice and attention's selection are saved, never
@@ -899,16 +913,18 @@ class TransformerLM(nn.Module):
             with jax.named_scope(device_names.MTP):
                 y = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype,
                                name="mtp_norm")(y)
-        x = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
-        if self.logits_scaling != 1.0:
-            x = x / jnp.asarray(self.logits_scaling, x.dtype)
-            if mtp_kinds:
-                y = y / jnp.asarray(self.logits_scaling, y.dtype)
+        with jax.named_scope(device_names.NORM_ADD):
+            x = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
+            if self.logits_scaling != 1.0:
+                x = x / jnp.asarray(self.logits_scaling, x.dtype)
+        if self.logits_scaling != 1.0 and mtp_kinds:
+            y = y / jnp.asarray(self.logits_scaling, y.dtype)
         if self.tie_embeddings:
             if return_hidden:
                 return x
-            return jnp.dot(x.astype(self.logits_dtype),
-                           embed.embedding.T.astype(self.logits_dtype))
+            with jax.named_scope(device_names.LM_HEAD):
+                return jnp.dot(x.astype(self.logits_dtype),
+                               embed.embedding.T.astype(self.logits_dtype))
         head = nn.Dense(self.vocab, use_bias=False, dtype=self.logits_dtype,
                         name="lm_head")
         if return_hidden:
@@ -919,7 +935,9 @@ class TransformerLM(nn.Module):
             if self.is_initializing():
                 head(x[:, :1])  # param tree must not depend on the flag
             return (x, y) if mtp_kinds else x
-        return (head(x), head(y)) if mtp_kinds else head(x)
+        with jax.named_scope(device_names.LM_HEAD):
+            logits = head(x)    # the main head's; the module's pass has no name
+        return (logits, head(y)) if mtp_kinds else logits
 
 
 def align_losses(intermediates):
@@ -956,7 +974,15 @@ def chunked_lm_loss(hidden, head_kernel, targets, chunk: int = 2048):
     Forward mode (``jvp`` / ``jacfwd`` / ``linearize``) and second
     derivatives (``hessian``) through this loss are not available; nothing
     in horovod_tpu, benchmarks/ or examples/ takes one.
+
+    A device profile shows the loop under ``hvd_lm_head``.
     """
+    with jax.named_scope(device_names.LM_HEAD):
+        return _checked_lm_loss(hidden, head_kernel, targets, chunk)
+
+
+def _checked_lm_loss(hidden, head_kernel, targets, chunk):
+    """:func:`chunked_lm_loss` under no name of its own: its caller's."""
     b, t, d = hidden.shape
     if chunk <= 0:
         raise ValueError(f"loss chunk must be positive, got {chunk}")
@@ -974,12 +1000,13 @@ def lm_loss_with_mtp(hidden, mtp_hidden, head_kernel, tokens,
     main model and the module's loss against the token AFTER the next, each
     a :func:`chunked_lm_loss` over the SAME head, whose gradient is then the
     sum of both passes'. Targets wrap round the row's end, as every loss of
-    this repo's; the module's pass goes by ``hvd_mtp``."""
+    this repo's; the main pass goes by ``hvd_lm_head``, the module's by
+    ``hvd_mtp``."""
     main = chunked_lm_loss(hidden, head_kernel, jnp.roll(tokens, -1, axis=1),
                            chunk)
     with jax.named_scope(device_names.MTP):
-        mtp = chunked_lm_loss(mtp_hidden, head_kernel,
-                              jnp.roll(tokens, -2, axis=1), chunk)
+        mtp = _checked_lm_loss(mtp_hidden, head_kernel,
+                               jnp.roll(tokens, -2, axis=1), chunk)
     return main + mtp_weight * mtp, (main, mtp)
 
 

@@ -436,3 +436,88 @@ def test_the_readers_on_a_v5e_trace(named_device_time, tmp_path):
     # loaded once, logged once, whole
     assert len(logged) == 1 and "hvd_ssd_scan" in logged[0]
     assert "calls/step" in logged[0]
+
+
+# ------------------------------------ what is left unnamed (ISSUE 50's rows)
+
+@pytest.mark.parametrize("op_name,expected", [
+    ("jit(step)/bench_fwd_bwd/jvp(hvd_lm_head)/while/body/closed_call/"
+     "dot_general", "hvd_lm_head"),
+    ("jit(step)/transpose(jvp(hvd_attn))/jit(_bwd_rule)/hvd_flash_bwd_dq/"
+     "pallas_call", "hvd_flash_bwd_dq"),       # the kernel inside keeps its time
+    ("jit(step)/block_0/block_0._mixer/hvd_attn/block_0._attention/"
+     "hvd_attn_proj/o_proj/dot_general", "hvd_attn_proj"),
+    ("jit(step)/block_0/block_0._mixer/hvd_attn/block_0._attention/reshape",
+     "hvd_attn"),
+    ("jit(step)/hvd_mtp/while/body/closed_call/dot_general", "hvd_mtp"),
+    ("jit(step)/block_1/moe/moe._routed/hvd_moe_logits/dot_general",
+     "hvd_moe_logits"),
+    ("jit(step)/block_1/moe/moe._routed/hvd_moe_route/top_k", "hvd_moe_route"),
+])
+def test_name_of_on_the_steps_own_names(op_name, expected):
+    assert dp.name_of(op_name) == expected
+
+
+def test_unnamed_ops_say_what_no_name_reaches():
+    """Sorted, at most five, no more than ``unnamed`` together, each the
+    INNERMOST nameless event's instruction, opcode and ``op_name`` with the
+    numbers cut (every leaf's copy of the optimizer's add is one row); a
+    nameless event inside a named one is the name's, not a row."""
+    update = "jit(train_step)/bench_optimizer/add"
+    ops = [op(0, 10, "jit(f)/hvd_mlp/mlp_in/dot_general"),
+           op(10, 40, "jit(f)/hvd_ssd_scan/while", "while"),
+           op(12, 20, "", "copy", "copy.7"),              # the scan's
+           op(40, 70, update, "fusion", "fusion.12"),
+           op(70, 90, update, "fusion", "fusion.345"),
+           op(90, 130, "", "while", "while.3"),
+           op(95, 125, "jit(f)/loss/reduce_max", "reduce", "reduce.9")]
+    ops += [op(130 + 2 * i, 132 + 2 * i, "", kind, f"{kind}.{i}")
+            for i, kind in enumerate(("reshape", "copy-done", "slice", "pad"))]
+    found = table(ops, steps=2)
+    rows = found["unnamed_ops"]
+    assert len(rows) == dp.UNNAMED_OPS == 5
+    assert [r[:3] for r in rows[:3]] == [
+        ("fusion.N", "fusion", update),
+        ("reduce.N", "reduce", "jit(f)/loss/reduce_max"),
+        ("while.N", "while", "")]                    # what its body left
+    assert [r[3] * 2e9 for r in rows[:3]] == pytest.approx([50, 30, 10])
+    seconds = [r[3] for r in rows]
+    assert seconds == sorted(seconds, reverse=True)
+    assert sum(seconds) <= found["unnamed"] == pytest.approx(98 / 2e9)
+    assert not any(r[0].startswith("copy.") for r in rows)
+    text = dp.format_table(found)
+    lines = text.splitlines()
+    assert lines[0].endswith("named 29.0% of busy")     # 40 of 138
+    at = next(i for i, line in enumerate(lines) if line.startswith("unnamed"))
+    assert lines[at + 1].split() == ["fusion.N", "0.000", "36.23", "fusion",
+                                     update]
+    assert lines[at + 3].split()[-3:] == ["while", "(no", "op_name)"]
+    assert len([l for l in lines if l.startswith("  ")]) == 5
+
+
+def test_an_exposed_nameless_collective_is_a_row_of_unnamed():
+    found = table([op(0, 10, "jit(f)/hvd_mlp/dot_general")], asyncs=[
+        op(5, 30, "jit(f)/psum", "all-reduce-start", "all-reduce-start.4")])
+    assert found["unnamed_ops"] == [
+        ("all-reduce-start.N", "all-reduce-start", "jit(f)/psum",
+         pytest.approx(20e-9))]
+    assert found["unnamed"] == pytest.approx(20e-9)
+
+
+def test_a_table_with_nothing_unnamed_has_no_rows():
+    found = table([op(0, 10, "jit(f)/hvd_mlp/dot_general")])
+    assert found["unnamed_ops"] == [] and found["unnamed"] == 0.0
+    assert "named 100.0% of busy" in dp.format_table(found)
+    empty = dp.by_name({"devices": {}, "host": {}}, 1)
+    assert empty["unnamed_ops"] == []
+    assert "named 0.0% of busy" in dp.format_table(empty)
+
+
+def test_the_v5e_traces_unnamed_rows():
+    found = dp.by_name(dp.load(TPU_TRACE), 3)
+    rows = found["unnamed_ops"]
+    assert [r[0] for r in rows[:3]] == ["copy-done.N", "convert.N",
+                                        "reduce_sum.N"]
+    assert rows[2][1:3] == ("reduce", "jit(step)/reduce_sum")
+    assert 0 < sum(r[3] for r in rows) <= found["unnamed"] * (1 + 1e-9)
+    assert "named 75.9% of busy" in dp.format_table(found).splitlines()[0]
