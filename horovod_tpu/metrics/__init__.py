@@ -39,6 +39,7 @@ from .overlap import (  # noqa: F401
     record_dsa_select_plan,
     record_flash_plan,
     record_flash_window_plan,
+    record_kda_fused_mixer,
     record_kda_plan,
     record_mamba_fused_passes,
     record_moe_dispatch_rows,

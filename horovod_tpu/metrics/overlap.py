@@ -371,6 +371,24 @@ def record_kda_plan(chunk: int, saved_state_bytes: int, kernel: bool) -> None:
         scans.set(0)
 
 
+def record_kda_fused_mixer(fused: bool) -> None:
+    """Record whether the latest traced ``models.kda.KDAMixer`` ran its gate
+    and its output norm as ``ops.kda_fused``'s kernels on the (B, T, H x 128)
+    form (trace time, once per compile): ``horovod_kda_fused_mixers`` counts
+    the traced mixers that did since the latest one that kept ``jax.numpy``,
+    which sets it back to 0 (``horovod_kda_kernel_scans``' rule)."""
+    mixers = registry().gauge(
+        "horovod_kda_fused_mixers",
+        help="traced KDAMixers whose gate and output norm took the fused "
+             "kernels (hvd_kda_gate_fwd / _bwd, hvd_kda_out_norm_fwd / _bwd) "
+             "since the latest one that kept jax.numpy; 0 = none traced, or "
+             "the latest kept jax.numpy")
+    if fused:
+        mixers.inc()
+    else:
+        mixers.set(0)
+
+
 def record_mamba_fused_passes(passes: int) -> None:
     """Record how many of the latest traced ``models.mamba.Mamba2Mixer``'s two
     elementwise chains (convolution + silu, the gated norm) went through a

@@ -29,7 +29,15 @@ this layer's names where its shape tiles (``conv_takes_kernel``), three calls
 a layer, and as ``jax.numpy`` otherwise. The scan runs as ``ops/kda.py``'s
 kernel pair where ITS shapes tile (``ops.kda.takes_kernel``: heads of 128
 lanes, chunks of 64, bf16 or float32; the gauge ``horovod_kda_kernel_scans``),
-and as ``jax.numpy`` otherwise.
+and as ``jax.numpy`` otherwise. Where the scan takes its kernels AND the rows
+are a whole number of row tiles (``ops.kda_fused.takes_kernel``; the gauge
+``horovod_kda_fused_mixers``), the two elementwise chains round it run as
+``ops/kda_fused.py``'s kernel pairs too (the L2 norms with ``g``; the head norm
+then the gate) and the scan through ``ops.kda.kda_lanes``: q, k, v, g and o
+then go from the convolutions' kernels to ``o_proj`` as (B, T, H d), each read
+once and written once, and are never laid out (B, T, H, d). Every other shape
+runs ``l2_norm``, the softplus line, ``kda`` and ``head_norm_then_gate``
+below, which are the definitions.
 
 Initialisation: ``A_log`` the log of uniform(1, 16) a head and ``dt_bias``
 by Mamba-2's inverse-softplus rule (``models/mamba.py``), the family's; norm
@@ -48,8 +56,8 @@ import jax
 import jax.numpy as jnp
 
 from ..common import device_names
-from ..ops import mamba_fused
-from ..ops.kda import CHUNK, kda
+from ..ops import kda_fused, mamba_fused
+from ..ops.kda import CHUNK, kda, kda_lanes
 from ..ops.ssd import causal_depthwise_conv
 from .mamba import _a_log_init, _dt_bias_init
 
@@ -77,7 +85,7 @@ def conv_silu(x, kernel):
         return nn.silu(conv)
 
 
-def l2_norm(x, eps=1e-6):
+def l2_norm(x, eps=kda_fused.L2_EPS):
     """``x / sqrt(sum(x^2) + eps)`` over the last axis, in float32."""
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
@@ -99,13 +107,15 @@ class KDAMixer(nn.Module):
     dims: KDADims
     rms_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # True runs the convolution's and the scan's kernels, where the shapes
-    # take them, in the Pallas interpreter: ``Block`` hands its
-    # ``flash_interpret`` down.
+    # True runs the convolution's, the scan's and the elementwise chains'
+    # kernels, where the shapes take them, in the Pallas interpreter:
+    # ``Block`` hands its ``flash_interpret`` down.
     interpret: bool = False
 
     @nn.compact
     def __call__(self, h):
+        from ..metrics import record_kda_fused_mixer
+
         m = self.dims
         b, t, _ = h.shape
         inner = m.heads * m.head_dim
@@ -128,24 +138,35 @@ class KDAMixer(nn.Module):
             decay = dense(inner, "f_b_proj")(dense(m.head_dim, "f_a_proj")(h))
             beta = dense(m.heads, "b_proj")(h)
             gate = dense(inner, "g_b_proj")(dense(m.head_dim, "g_a_proj")(h))
-        q, k, v = (conv(x, name).reshape(b, t, m.heads, m.head_dim)
+        q, k, v = (conv(x, name)
                    for x, name in ((q, "q_conv"), (k, "k_conv"), (v, "v_conv")))
         a_log = self.param("A_log", _a_log_init, (m.heads,), jnp.float32)
         dt_bias = self.param("dt_bias", _dt_bias_init, (inner,), jnp.float32)
-        with jax.named_scope(device_names.KDA_GATE):
-            q = (l2_norm(q) * m.head_dim ** -0.5).astype(self.dtype)
-            k = l2_norm(k).astype(self.dtype)
-            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
-                decay.astype(jnp.float32) + dt_bias
-            ).reshape(b, t, m.heads, m.head_dim)
-            beta = nn.sigmoid(beta.astype(jnp.float32))
-        # (``interpret`` named only where it is asked for: the plain call is
-        # the one a stand-in for ``kda`` with its six operands answers)
-        o = kda(q, k, v, g, beta, m.chunk,
-                **({"interpret": True} if self.interpret else {}))
         scale = self.param("o_norm", nn.initializers.ones, (m.head_dim,),
                            jnp.float32)
-        o = head_norm_then_gate(o, gate, scale,
-                                self.rms_norm_eps).astype(self.dtype)
+        with jax.named_scope(device_names.KDA_GATE):
+            beta = nn.sigmoid(beta.astype(jnp.float32))
+        fused = kda_fused.takes_kernel(q, k, v, decay, gate, m.heads, m.chunk)
+        record_kda_fused_mixer(fused)
+        if fused:
+            q, k, g = kda_fused.gate(q, k, decay, a_log, dt_bias,
+                                     self.interpret)
+            o = kda_lanes(q, k, v, g, beta, m.chunk, interpret=self.interpret)
+            o = kda_fused.out_norm(o, gate, scale, self.rms_norm_eps,
+                                   self.interpret)
+        else:
+            q, k, v = (x.reshape(b, t, m.heads, m.head_dim) for x in (q, k, v))
+            with jax.named_scope(device_names.KDA_GATE):
+                q = (l2_norm(q) * m.head_dim ** -0.5).astype(self.dtype)
+                k = l2_norm(k).astype(self.dtype)
+                g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                    decay.astype(jnp.float32) + dt_bias
+                ).reshape(b, t, m.heads, m.head_dim)
+            # (``interpret`` named only where it is asked for: the plain call
+            # is the one a stand-in for ``kda`` with its six operands answers)
+            o = kda(q, k, v, g, beta, m.chunk,
+                    **({"interpret": True} if self.interpret else {}))
+            o = head_norm_then_gate(o, gate, scale,
+                                    self.rms_norm_eps).astype(self.dtype)
         with jax.named_scope(device_names.KDA_PROJ):
             return dense(self.dim, "o_proj")(o)

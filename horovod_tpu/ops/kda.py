@@ -977,33 +977,30 @@ def _scan_bwd_call(q, k, v, g, beta, starts, do, block_len, interpret):
 
 
 def _lanes(x):
-    """(B, T, H, 128) as the mixer holds it: (B, T, H x 128)."""
+    """(B, T, H, 128) as the kernels read it: (B, T, H x 128)."""
     return x.reshape(x.shape[0], x.shape[1], -1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _kda_kernels(q, k, v, g, beta, block_len, interpret):
+    """The kernels on q, k, v, g (B, T, H x 128) and beta (B, T, H)."""
     with jax.named_scope(device_names.KDA_SCAN):
-        o, = _scan_fwd_call(_lanes(q), _lanes(k), _lanes(v), _lanes(g), beta,
-                            block_len, False, interpret)
-    return o.reshape(v.shape)
+        o, = _scan_fwd_call(q, k, v, g, beta, block_len, False, interpret)
+    return o
 
 
 def _kda_kernels_forward(q, k, v, g, beta, block_len, interpret):
     with jax.named_scope(device_names.KDA_SCAN):
-        o, starts = _scan_fwd_call(_lanes(q), _lanes(k), _lanes(v), _lanes(g),
-                                   beta, block_len, True, interpret)
-    return o.reshape(v.shape), (q, k, v, g, beta, starts)
+        o, starts = _scan_fwd_call(q, k, v, g, beta, block_len, True,
+                                   interpret)
+    return o, (q, k, v, g, beta, starts)
 
 
 def _kda_kernels_backward(block_len, interpret, res, do):
     q, k, v, g, beta, starts = res
     with jax.named_scope(device_names.KDA_SCAN):
-        dq, dk, dv, dg, dbeta = _scan_bwd_call(
-            _lanes(q), _lanes(k), _lanes(v), _lanes(g), beta, starts,
-            _lanes(do.astype(v.dtype)), block_len, interpret)
-    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
-            dg.reshape(g.shape), dbeta)
+        return tuple(_scan_bwd_call(q, k, v, g, beta, starts,
+                                    do.astype(v.dtype), block_len, interpret))
 
 
 _kda_kernels.defvjp(_kda_kernels_forward, _kda_kernels_backward)
@@ -1030,6 +1027,12 @@ def saved_state_bytes(b, t, h, dk, dv, chunk: int = CHUNK) -> int:
     return t // plan(t, chunk)[2] * b * h * dk * dv * 4
 
 
+def _record_plan(b, t, h, dk, dv, chunk, kernel):
+    from ..metrics import record_kda_plan
+
+    record_kda_plan(chunk, saved_state_bytes(b, t, h, dk, dv, chunk), kernel)
+
+
 def kda(q, k, v, g, beta, chunk: int = CHUNK, *, interpret: bool = False):
     """The chunked gated delta rule. q, k: (B, T, H, K) (k of unit length a
     head where the layer is KDA's; q scaled by the caller); v: (B, T, H, V);
@@ -1041,14 +1044,57 @@ def kda(q, k, v, g, beta, chunk: int = CHUNK, *, interpret: bool = False):
     in the Pallas interpreter, asked for by the caller and never inferred
     from the platform; a machine without a TPU raises at lowering without
     it); every other shape runs the ``jax.numpy`` scan."""
-    from ..metrics import record_kda_plan
-
     b, t, h, dk = k.shape
     chunk, sub, block_len = plan(t, chunk)
     kernel = takes_kernel(q, k, v, chunk, block_len)
-    record_kda_plan(chunk, saved_state_bytes(b, t, h, dk, v.shape[-1], chunk),
-                    kernel)
+    _record_plan(b, t, h, dk, v.shape[-1], chunk, kernel)
     g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
     if kernel:
-        return _kda_kernels(q, k, v, g, beta, block_len, interpret)
+        return _kda_kernels(_lanes(q), _lanes(k), _lanes(v), _lanes(g), beta,
+                            block_len, interpret).reshape(v.shape)
     return _kda(q, k, v, g, beta, chunk, sub, block_len)
+
+
+def _lanes_block_len(q, k, v, heads, chunk):
+    """The block length of the row's plan where q, k, v (B, T, H x 128) are
+    operands the kernels take (their (B, T, H, 128) views pass
+    :func:`takes_kernel`), else None."""
+    if any(x.ndim != 3 or x.shape[2] % heads for x in (q, k, v)):
+        return None
+    b, t, _ = q.shape
+    if chunk <= 0 or t % min(chunk, t):
+        return None
+    chunk, _, block_len = plan(t, chunk)
+
+    def view(x):
+        return jax.ShapeDtypeStruct(
+            (b, t, heads, x.shape[2] // heads), x.dtype)
+
+    taken = takes_kernel(view(q), view(k), view(v), chunk, block_len)
+    return block_len if taken else None
+
+
+def lanes_take_kernel(q, k, v, heads: int, chunk: int) -> bool:
+    """Whether q, k, v (B, T, H x 128) are operands :func:`kda_lanes` runs."""
+    return _lanes_block_len(q, k, v, heads, chunk) is not None
+
+
+def kda_lanes(q, k, v, g, beta, chunk: int = CHUNK, *,
+              interpret: bool = False):
+    """:func:`kda` on the arrays as the kernels read them, for a caller that
+    holds them so (``models/kda.py`` between its fused passes): q, k, v, g
+    (B, T, H x 128), beta (B, T, H); returns o (B, T, H x 128) in v's dtype,
+    and the gradients come back in that form too: no (B, T, H, 128) array is
+    made on either side. Only for operands :func:`lanes_take_kernel`
+    accepts: there is no ``jax.numpy`` scan on this form."""
+    (b, t, _), heads = q.shape, beta.shape[2]
+    block_len = _lanes_block_len(q, k, v, heads, chunk)
+    if block_len is None:
+        raise ValueError(
+            f"kda_lanes: q {q.shape} {q.dtype}, k {k.shape} {k.dtype}, v "
+            f"{v.shape} {v.dtype} under chunks of {chunk} are no operands of "
+            "the scan's kernels (lanes_take_kernel); call kda on the "
+            "(B, T, H, K) form")
+    _record_plan(b, t, heads, _LANES, _LANES, chunk, True)
+    return _kda_kernels(q, k, v, g.astype(jnp.float32),
+                        beta.astype(jnp.float32), block_len, interpret)

@@ -3,8 +3,10 @@ at D = 64 with a softmax scale of its own, and latent attention's 192-wide q
 and k against a 128-wide v), the two grouped-product kernels (all of 64
 experts, and a share of 16 whose groups do not fill the row buffer), the
 expert layer of such a share whole (its loops over the live windows),
-the chunked state-space scan's two kernels, the gated delta rule's two, and
-the Mamba-2 mixer's four fused kernels (convolution + silu, gated norm)
+the chunked state-space scan's two kernels, the gated delta rule's two with
+the four of its mixer's fused passes (L2 norms + log-decay, head norm then
+gate), and the Mamba-2 mixer's four fused kernels (convolution + silu, gated
+norm)
 COMPILED for a
 described v5e at the benchmark cells' shapes (no chip attached, nothing runs): what interpret mode cannot
 see — scoped VMEM, tiling and layout faults of a kernel edit — is refused
@@ -539,6 +541,51 @@ def test_the_delta_rules_kernels_compile_for_v5e_at_the_cells_shape(
     for name in (KDA_SCAN + "_fwd", KDA_SCAN + "_bwd"):
         assert name in text, f"{name} is not in the compiled module"
     assert " while(" not in text
+
+
+@pytest.mark.parametrize("t,dtype", [(16384, jnp.bfloat16),
+                                     (2048, jnp.float32)],
+                         ids=["step_bf16", "check_f32"])
+def test_the_delta_rule_mixers_fused_passes_compile_for_v5e(
+        one_chip, no_persistent_cache, t, dtype):
+    """kimi_linear_seq16384_1chip's mixer between its convolutions and
+    ``o_proj`` on the (1, T, 32 x 128) form: ``ops.kda_fused.gate``,
+    ``ops.kda.kda_lanes`` and ``ops.kda_fused.out_norm``, forward and
+    backward, at the step's row in bf16 and the check's prefix in float32. All
+    six kernels are in the compiled program and nothing in it is laid out
+    (.., 32, 128)."""
+    from horovod_tpu.common.device_names import (KDA_GATE, KDA_OUT_NORM,
+                                                 KDA_SCAN)
+    from horovod_tpu.ops import kda as kda_ops
+    from horovod_tpu.ops import kda_fused
+
+    heads, d = 32, 128
+
+    def shape(*dims, of=dtype):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    x = shape(1, t, heads * d)
+    args = (x, x, x, x, x, shape(1, t, heads, of=jnp.float32),
+            shape(heads, of=jnp.float32), shape(heads * d, of=jnp.float32),
+            shape(d, of=jnp.float32))
+    assert kda_fused.takes_kernel(x, x, x, x, x, heads, kda_ops.CHUNK)
+
+    def between(q, k, v, decay, gate, beta, a_log, dt_bias, scale):
+        q, k, g = kda_fused.gate(q, k, decay, a_log, dt_bias)
+        return kda_fused.out_norm(kda_ops.kda_lanes(q, k, v, g, beta), gate,
+                                  scale, 1e-5)
+
+    def value_and_grads(*a):    # the value too: a forward nobody reads is cut
+        out, vjp = jax.vjp(between, *a)
+        return out, vjp(out)
+
+    precision = "highest" if dtype == jnp.float32 else None
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(value_and_grads).lower(*args).compile().as_text()
+    for name in (KDA_GATE, KDA_SCAN, KDA_OUT_NORM):
+        for kernel in ("_fwd", "_bwd"):
+            assert name + kernel in text, f"{name + kernel} is not compiled"
+    assert f",{heads},{d}]" not in text
 
 
 # keye_vl2_seq16384_1chip: 32 query heads over 4 key/value heads of 128 over a
