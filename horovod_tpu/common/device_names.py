@@ -50,7 +50,11 @@ MLA_ROPE = "hvd_mla_rope"               # split, rotary, assembling q and k
 # Multi-head attention's parts that only some models have (models/transformer.py,
 # ``Block.rotary`` / ``Block.attn_gate``).
 ATTN_ROPE = "hvd_attn_rope"             # a rotary scheme: part of a head, YaRN
-ATTN_GATE = "hvd_attn_gate"             # the per-head sigmoid gate on the output
+# The sigmoid gate on softmax attention's output, in either form: a gate a
+# HEAD (Laguna: its projection dim -> heads, the sigmoid, the product) or a
+# gate an ELEMENT (Solar-Open2: the sigmoid and the product; its projection,
+# as wide as q's, goes by ``hvd_attn_proj``).
+ATTN_GATE = "hvd_attn_gate"
 # The Mamba-2 mixer (models/mamba.py) and its chunked state-space scan
 # (ops/ssd.py). The benchmark finds the mixer's time by the substrings
 # ``hvd_mamba`` and ``hvd_ssd``, the scan's by ``hvd_ssd``.

@@ -34,11 +34,13 @@ from .overlap import (  # noqa: F401
     last_tier_plan,
     last_wire_plan,
     measure_overlap,
+    record_attn_gate_width,
     record_chunked_loss_plan,
     record_dsa_census,
     record_dsa_select_plan,
     record_flash_plan,
     record_flash_window_plan,
+    record_kda_beta_range,
     record_kda_fused_mixer,
     record_kda_plan,
     record_mamba_fused_passes,

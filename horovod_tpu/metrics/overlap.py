@@ -389,6 +389,34 @@ def record_kda_fused_mixer(fused: bool) -> None:
         mixers.set(0)
 
 
+def record_kda_beta_range(upper: int) -> None:
+    """Record the range of beta in the latest traced ``models.kda.KDAMixer``
+    (trace time, once per compile): 2 where the mixer doubles the sigmoid
+    (``KDADims.allow_neg_eigval``: beta in (0, 2), the delta rule's
+    transition with an eigenvalue in (-1, 1)), 1 where beta is the sigmoid
+    itself. 0 until a mixer is traced."""
+    registry().gauge(
+        "horovod_kda_beta_range",
+        help="upper end of beta's range in the latest traced KDAMixer: 2 = "
+             "twice the sigmoid (negative eigenvalues allowed), 1 = the "
+             "sigmoid; 0 = none traced"
+    ).set(upper)
+
+
+def record_attn_gate_width(width: int) -> None:
+    """Record how many gate values a token the latest traced gated softmax
+    attention (``models.transformer.Block.attn_gate``) multiplies its output
+    by (trace time, once per compile): the layer's heads where the gate is
+    one number a head, heads x head_dim where it is one an element. 0 until
+    such a layer is traced."""
+    registry().gauge(
+        "horovod_attn_gate_width",
+        help="sigmoid gate values a token on the output of the latest "
+             "traced gated attention layer: heads (a gate a head) or heads "
+             "x head_dim (a gate an element); 0 = none traced"
+    ).set(width)
+
+
 def record_mamba_fused_passes(passes: int) -> None:
     """Record how many of the latest traced ``models.mamba.Mamba2Mixer``'s two
     elementwise chains (convolution + silu, the gated norm) went through a

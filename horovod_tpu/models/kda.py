@@ -13,7 +13,10 @@ d``, no bias anywhere::
     g = -exp(A_log) softplus((h Wf_a) Wf_b + dt_bias)
                                         dim -> d -> inner, float32: the log
                                         of a decay a CHANNEL; A_log a head
-    beta = sigmoid(h Wb)                       dim -> H, one a head
+    beta = sigmoid(h Wb)                       dim -> H, one a head; TWICE
+                                        that where the configuration allows
+                                        negative eigenvalues
+                                        (``KDADims.allow_neg_eigval``)
     o = kda(q, k, v, g, beta)                  ops/kda.py: the gated delta rule
     o = RMSNorm(o) sigmoid((h Wg_a) Wg_b)      the norm a head with ONE weight
                                         of d shared by the heads, THEN the
@@ -23,6 +26,13 @@ d``, no bias anywhere::
 Numerics: float32 parameters; projections, convolutions, q, k, v and the
 gate in ``dtype`` (bf16 as trained); the L2 norms' and the head norm's
 statistics, ``g`` and ``beta`` in float32.
+
+A rank that holds some of the layer's heads (tensor parallelism) builds the
+mixer with that many in ``KDADims.heads``: q, k, v, the convolutions, the
+second factors of the two low-rank gates, ``A_log``, ``dt_bias``, beta and
+``o_proj``'s rows are cut by heads; the gates' FIRST factors (dim -> d) and
+the head norm's one weight are whole on every rank, and ``o_proj`` gives the
+rank's partial sum (docs/linear-attention.md, "cut by heads").
 
 The convolution + silu runs as ``ops/mamba_fused.py``'s kernel pair under
 this layer's names where its shape tiles (``conv_takes_kernel``), three calls
@@ -68,11 +78,21 @@ CONV_NAMES = (device_names.KDA_CONV_FWD, device_names.KDA_CONV_BWD)
 class KDADims:
     """The mixer's sizes as a model's ``linear_attn_config`` states them
     (``num_heads``, ``head_dim``, ``short_conv_kernel_size``); ``chunk`` is
-    the training path's, which no result depends on in exact arithmetic."""
+    the training path's, which no result depends on in exact arithmetic.
+    ``allow_neg_eigval``, as the model's own configuration states it
+    (``kda_allow_neg_eigval``): beta is ``2 sigmoid`` in (0, 2) where it is
+    ``sigmoid`` in (0, 1) otherwise, so that the transition ``I - beta k
+    k^T`` has an eigenvalue in (-1, 1) along the key."""
     heads: int
     head_dim: int
     conv: int = 4
     chunk: int = CHUNK
+    allow_neg_eigval: bool = False
+
+    def __post_init__(self):
+        if min(self.heads, self.head_dim, self.conv, self.chunk) < 1:
+            raise ValueError(f"{self}: heads, head_dim, conv and chunk are "
+                             f"counts, each at least 1")
 
 
 def conv_silu(x, kernel):
@@ -114,7 +134,7 @@ class KDAMixer(nn.Module):
 
     @nn.compact
     def __call__(self, h):
-        from ..metrics import record_kda_fused_mixer
+        from ..metrics import record_kda_beta_range, record_kda_fused_mixer
 
         m = self.dims
         b, t, _ = h.shape
@@ -146,12 +166,18 @@ class KDAMixer(nn.Module):
                            jnp.float32)
         with jax.named_scope(device_names.KDA_GATE):
             beta = nn.sigmoid(beta.astype(jnp.float32))
+            if m.allow_neg_eigval:
+                beta = 2.0 * beta
+        record_kda_beta_range(2 if m.allow_neg_eigval else 1)
+        # (named only where asked for, as ``interpret`` below)
+        solve = {"neg_eigval": True} if m.allow_neg_eigval else {}
         fused = kda_fused.takes_kernel(q, k, v, decay, gate, m.heads, m.chunk)
         record_kda_fused_mixer(fused)
         if fused:
             q, k, g = kda_fused.gate(q, k, decay, a_log, dt_bias,
                                      self.interpret)
-            o = kda_lanes(q, k, v, g, beta, m.chunk, interpret=self.interpret)
+            o = kda_lanes(q, k, v, g, beta, m.chunk, interpret=self.interpret,
+                          **solve)
             o = kda_fused.out_norm(o, gate, scale, self.rms_norm_eps,
                                    self.interpret)
         else:
@@ -164,7 +190,7 @@ class KDAMixer(nn.Module):
                 ).reshape(b, t, m.heads, m.head_dim)
             # (``interpret`` named only where it is asked for: the plain call
             # is the one a stand-in for ``kda`` with its six operands answers)
-            o = kda(q, k, v, g, beta, m.chunk,
+            o = kda(q, k, v, g, beta, m.chunk, **solve,
                     **({"interpret": True} if self.interpret else {}))
             o = head_norm_then_gate(o, gate, scale,
                                     self.rms_norm_eps).astype(self.dtype)

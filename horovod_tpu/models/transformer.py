@@ -176,6 +176,18 @@ def _rope(x, positions, theta=10000.0, interleave=False):
     return (x * cos + swapped * sin).astype(x.dtype)
 
 
+def gate_form(attn_gate):
+    """``Block.attn_gate`` / ``TransformerLM.attn_gate`` as one of None (no
+    gate), ``"head"`` (one sigmoid gate a head and token; True says the
+    same) or ``"element"`` (one an element of the attention output)."""
+    forms = {False: None, None: None, True: "head", "head": "head",
+             "element": "element"}
+    if not isinstance(attn_gate, (bool, str, type(None))) or attn_gate not in forms:
+        raise ValueError(f"attn_gate {attn_gate!r}: False, 'head' (or True) "
+                         f"or 'element'")
+    return forms[attn_gate]
+
+
 def causal_attention(q, k, v, seq_offset=0, scale=None, window=None):
     """Dense causal attention. q,k,v: [B, T, H, D]. Runs on-chip in one block —
     fine up to ~8k tokens; ring attention takes over beyond that. ``scale``
@@ -239,11 +251,12 @@ class Block(nn.Module):
     # TransformerLM documents them): a head size of its own, q and o then
     # heads x head_dim wide whatever dim is; a window (the query at p sees
     # the keys p - window < j <= p); a rotary scheme in place of rope_theta's
-    # plain one; a sigmoid gate a head and token on the attention output.
+    # plain one; a sigmoid gate on the attention output, "head" (or True:
+    # one number a head and token) or "element" (one an element).
     head_dim: Optional[int] = None
     window: Optional[int] = None
     rotary: Optional[RotaryScheme] = None
-    attn_gate: bool = False
+    attn_gate: Any = False
     # What a Nemotron-H-family configuration states (TransformerLM documents
     # them): a layer that is ONE sub-layer, ``x + f(norm x)`` with f the
     # mixer alone ("mixer") or the experts alone ("mlp") where every other
@@ -323,6 +336,14 @@ class Block(nn.Module):
                 f"{', '.join(extra)} stated for a layer (sublayers="
                 f"{self.sublayers!r}, moe_experts={self.moe_experts}) that "
                 f"has no such half")
+        other = [name for name in ("kda", "mamba", "mla")
+                 if getattr(self, name) is not None]
+        if gate_form(self.attn_gate) is not None and (
+                other or self.sublayers == "mlp"):
+            raise ValueError(
+                f"attn_gate={self.attn_gate!r} gates softmax attention's "
+                f"output before o_proj: this layer (sublayers="
+                f"{self.sublayers!r}, mixer {other or 'none'}) runs none")
 
     def _mixer(self, x, positions):
         """The mixer's branch of the normed ``x``: a Mamba-2 mixer, a Kimi
@@ -443,12 +464,26 @@ class Block(nn.Module):
             # reference swaps this function for one that takes none
             attn = (causal_attention(q, k, v) if self.attention_scale is None
                     else causal_attention(q, k, v, scale=self.attention_scale))
-        if self.attn_gate:
+        form = gate_form(self.attn_gate)
+        if form is not None:
+            from ..metrics import record_attn_gate_width
+
+            record_attn_gate_width(self.heads if form == "head" else width)
+        if form == "head":
             with jax.named_scope(device_names.ATTN_GATE):
                 gate = nn.sigmoid(nn.Dense(self.heads, use_bias=False,
                                            dtype=self.dtype, name="gate_proj")(h))
                 attn = attn * gate[..., None]
         attn = attn.reshape(b, t, width)
+        if form == "element":
+            # arXiv:2505.06708's gate: sigmoid(h Wg) an element of the
+            # output, its projection as wide as q's and counted with q's
+            with jax.named_scope(device_names.ATTN_PROJ):
+                gate = nn.Dense(width, use_bias=False, dtype=self.dtype,
+                                name="gate_proj")(h)
+            with jax.named_scope(device_names.ATTN_GATE):
+                attn = (attn.astype(jnp.float32) * nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(self.dtype)
         with jax.named_scope(device_names.ATTN_PROJ):
             return nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="o_proj")(attn)
 
@@ -472,7 +507,8 @@ class Block(nn.Module):
 
         sp, heads = self.sparse, self.heads
         if (self.attention != "flash" or self.sp_axis is not None
-                or self.window is not None or self.attn_gate or self.qk_norm):
+                or self.window is not None or gate_form(self.attn_gate)
+                or self.qk_norm):
             raise ValueError(
                 "sparse attention runs through the flash kernels on one "
                 "chip: attention='flash', no sp_axis, window, gate or "
@@ -722,15 +758,22 @@ class TransformerLM(nn.Module):
     # None: rope_theta's plain one). head_dim: a head's size where it is not
     # dim // heads; q and o are then heads x head_dim wide. heads_per_layer:
     # the query heads of each layer (len == layers; None: heads everywhere)
-    # over the same kv_heads. attn_gate: the attention output of head a is
-    # multiplied by sigmoid(h Wg)_a, one number a head and token, before
-    # o_proj.
+    # over the same kv_heads. attn_gate: a sigmoid gate on softmax
+    # attention's output before o_proj, in the form the model's own
+    # configuration states: "head" (or True; Laguna's) multiplies the output
+    # of head a by sigmoid(h Wg)_a, one number a head and token (Wg dim ->
+    # heads); "element" (Solar-Open2's ``use_gqa_gate``; arXiv:2505.06708)
+    # multiplies every element by its own, sigmoid(h Wg) with Wg dim ->
+    # heads x head_dim. It belongs to the layers that run softmax attention:
+    # a "kda", "mamba" or one-sub-layer expert layer of the same model takes
+    # none, and a model with no such layer, or with latent or sparse
+    # attention, that states one raises.
     head_dim: Optional[int] = None
     heads_per_layer: Optional[tuple] = None
     sliding_window: Optional[int] = None
     full_rotary: Optional[RotaryScheme] = None
     sliding_rotary: Optional[RotaryScheme] = None
-    attn_gate: bool = False
+    attn_gate: Any = False
     # A Nemotron-H-family hybrid (Nemotron-3-Super: docs/latent-moe.md), each
     # as the model's own configuration states it. layer_types may also name
     # "mamba_only" / "attention_only" / "experts_only" (the pattern's M, * and
@@ -774,7 +817,12 @@ class TransformerLM(nn.Module):
     # (models/kda.py: a gated delta rule with a decay a channel) in
     # attention's place; with ``mla`` set its "full_attention" layers are
     # latent attention, and ``rope=False`` (``mla_use_nope``) turns neither
-    # rotary part: no position information anywhere in the model.
+    # rotary part: no position information anywhere in the model. Without
+    # ``mla`` (Solar-Open2-250B) they are grouped-query softmax attention of
+    # ``heads`` over ``kv_heads`` of ``head_dim``, gated as ``attn_gate``
+    # says; ``kda.allow_neg_eigval`` (``kda_allow_neg_eigval``) doubles the
+    # delta rule's beta. ``heads`` / ``kv_heads`` / ``kda.heads`` are what
+    # THIS rank holds where a layer's heads are cut over tensor ranks.
     kda: Optional[KDADims] = None
 
     @nn.compact
@@ -807,6 +855,15 @@ class TransformerLM(nn.Module):
         if mtp_kinds and self.tie_embeddings:
             raise ValueError("the multi-token-prediction module shares an "
                              "untied head: tie_embeddings must be False")
+        softmax_kinds = {"attention", "full_attention", "sliding_attention",
+                         "attention_only"}
+        if gate_form(self.attn_gate) is not None and (
+                self.mla is not None or not softmax_kinds & set(kinds + mtp_kinds)):
+            raise ValueError(
+                f"attn_gate={self.attn_gate!r} gates softmax attention's "
+                f"output, and no layer of {kinds + mtp_kinds} "
+                f"{'(latent attention: mla=) ' if self.mla is not None else ''}"
+                f"runs it")
         if "sliding_attention" in kinds and self.sliding_window is None:
             raise ValueError("a 'sliding_attention' layer needs its window "
                              "(sliding_window=)")
@@ -878,7 +935,7 @@ class TransformerLM(nn.Module):
                 window=(self.sliding_window if kind == "sliding_attention"
                         else None),
                 rotary=rotary.get(kind, self.rotary),
-                attn_gate=self.attn_gate,
+                attn_gate=self.attn_gate if kind in softmax_kinds else False,
                 sublayers=sublayers,
                 moe_activation=self.moe_activation,
                 moe_latent=self.moe_latent,
@@ -1084,7 +1141,8 @@ def tp_param_specs(params, tp_axis: str = "tp"):
         names = [getattr(p, "key", getattr(p, "name", "")) for p in path]
         joined = "/".join(str(n) for n in names)
         if leaf.ndim == 2:
-            # the gate is one column a query head: sharded with q_proj's
+            # the gate is one column a query head, or one an element of
+            # the heads' output: either way sharded with q_proj's
             if ("qkv" in joined or "q_proj" in joined or "kv_proj" in joined
                     or "gate_proj" in joined or "mlp_in" in joined):
                 return P(None, tp_axis)

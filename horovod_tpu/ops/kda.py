@@ -135,6 +135,28 @@ def _unit_lower_inverse(n, precision=lax.Precision.HIGHEST):
     return result
 
 
+def _unit_lower_inverse_by_halves(n, precision=lax.Precision.HIGHEST):
+    """``(I + n)^-1`` for strictly lower triangular ``n`` (..., C, C), float32,
+    block by block: the inverse of ``[[A, 0], [C, B]]`` is ``[[A^-1, 0],
+    [-B^-1 C A^-1, B^-1]]``, from blocks of two rows up to the whole, two
+    products a doubling. Every factor is the inverse of a block of ``I + n``
+    itself, so where that inverse is bounded nothing on the way to it is
+    larger: :func:`_unit_lower_inverse`'s powers of ``n`` grow as
+    ``|n|^p binom(C, p)`` where the keys of a chunk are close to each other,
+    and its sum of them then cancels to nothing (docs/linear-attention.md,
+    "beta in (0, 2)")."""
+    size = n.shape[-1]
+    mm = functools.partial(jnp.matmul, precision=precision)
+    r, s = np.arange(size)[:, None], np.arange(size)
+    result = jnp.eye(size, dtype=n.dtype) - jnp.where(r // 2 == s // 2, n, 0.0)
+    width = 2
+    while width < size:
+        across = (r // (2 * width) == s // (2 * width)) & (r // width != s // width)
+        result = result - mm(mm(result, jnp.where(across, n, 0.0)), result)
+        width *= 2
+    return result
+
+
 @jax.checkpoint
 def _diagonal_tiles(qf, kf, gl):
     """The tiles on the diagonal, entry by entry. qf, kf, gl: (b, N, n, c, h,
@@ -154,9 +176,9 @@ def _diagonal_tiles(qf, kf, gl):
     return against(kf), against(qf)
 
 
-def _state_free_parts(q, k, v, g, beta, chunk, sub):
+def _state_free_parts(q, k, v, g, beta, chunk, sub, by_halves=False):
     """What a block of N chunks needs that does not depend on the carried
-    state. q, k, g: (b, N chunk, h, dk); v: (.., dv); beta: (b, N chunk, h).
+    state (``by_halves``: which of the two solves). q, k, g: (b, N chunk, h, dk); v: (.., dv); beta: (b, N chunk, h).
     Returns W (b, N, h, C, dk) and U (b, N, h, C, dv) float32, the causal
     scores M (b, N, h, C, C) float32, Q+ and K exp(G_C - G) (b, N, C, h, dk)
     in q's dtype, and the chunk's whole decay (b, N, h, dk) float32."""
@@ -200,9 +222,9 @@ def _state_free_parts(q, k, v, g, beta, chunk, sub):
 
     beta = jnp.moveaxis(beta.astype(f32).reshape(b, count, chunk, h), 2, 3)
     strict = np.tril(np.ones((chunk, chunk), bool), -1)
-    solved = _unit_lower_inverse(
-        jnp.where(strict, kk * beta[..., :, None], 0.0),
-        SOLVE_PRECISION[act == f32]) * beta[..., None, :]
+    solve = _unit_lower_inverse_by_halves if by_halves else _unit_lower_inverse
+    solved = solve(jnp.where(strict, kk * beta[..., :, None], 0.0),
+                   SOLVE_PRECISION[act == f32]) * beta[..., None, :]
     whole = (b, count, chunk, h, dk)
     k_plus = (kf * jnp.exp(in_chunk)).reshape(whole).astype(act)
     a = solved.astype(act)
@@ -215,7 +237,7 @@ def _state_free_parts(q, k, v, g, beta, chunk, sub):
     return w, u, scores, q_plus, k_end, jnp.exp(total)
 
 
-def _block(q, k, v, g, beta, state, chunk, sub):
+def _block(q, k, v, g, beta, state, chunk, sub, by_halves=False):
     """A block of chunks from the state it starts from (b, h, dk, dv)
     float32: (o (b, N chunk, h, dv) float32, the state after it)."""
     act = q.dtype
@@ -232,7 +254,7 @@ def _block(q, k, v, g, beta, state, chunk, sub):
                                         ((0, 2), (0, 1)))
         return s, o
 
-    parts = _state_free_parts(q, k, v, g, beta, chunk, sub)
+    parts = _state_free_parts(q, k, v, g, beta, chunk, sub, by_halves)
     state, o = lax.scan(step, state,
                         tuple(jnp.moveaxis(x, 1, 0) for x in parts))
     # (N, b, h, C, dv) -> (b, N C, h, dv)
@@ -252,12 +274,12 @@ def _row(x):
     return x.reshape(x.shape[0], x.shape[1] * x.shape[2], *x.shape[3:])
 
 
-def _scan_blocks(q, k, v, g, beta, chunk, sub, block_len):
+def _scan_blocks(q, k, v, g, beta, chunk, sub, block_len, by_halves):
     """(o in v's dtype, the state each block starts from)."""
     b, _, h, dk = k.shape
 
     def step(state, x):
-        o, after = _block(*x, state, chunk, sub)
+        o, after = _block(*x, state, chunk, sub, by_halves)
         return after, (o.astype(v.dtype), state)
 
     with jax.named_scope(device_names.KDA_SCAN):
@@ -267,23 +289,25 @@ def _scan_blocks(q, k, v, g, beta, chunk, sub, block_len):
     return _row(o), starts
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _kda(q, k, v, g, beta, chunk, sub, block_len):
-    return _scan_blocks(q, k, v, g, beta, chunk, sub, block_len)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _kda(q, k, v, g, beta, chunk, sub, block_len, by_halves):
+    return _scan_blocks(q, k, v, g, beta, chunk, sub, block_len, by_halves)[0]
 
 
-def _kda_forward(q, k, v, g, beta, chunk, sub, block_len):
-    o, starts = _scan_blocks(q, k, v, g, beta, chunk, sub, block_len)
+def _kda_forward(q, k, v, g, beta, chunk, sub, block_len, by_halves):
+    o, starts = _scan_blocks(q, k, v, g, beta, chunk, sub, block_len,
+                             by_halves)
     return o, (q, k, v, g, beta, starts)
 
 
-def _kda_backward(chunk, sub, block_len, res, do):
+def _kda_backward(chunk, sub, block_len, by_halves, res, do):
     *operands, starts = res
 
     def step(d_state, x):
         *block, state, d_o = x
         _, vjp = jax.vjp(
-            functools.partial(_block, chunk=chunk, sub=sub), *block, state)
+            functools.partial(_block, chunk=chunk, sub=sub,
+                              by_halves=by_halves), *block, state)
         *grads, d_state = vjp((d_o.astype(jnp.float32), d_state))
         return d_state, tuple(grads)
 
@@ -543,6 +567,25 @@ def _unit_lower_inverses(powers, eye, exact):
             for result, power in zip(results, powers)]
 
 
+def _unit_lower_inverses_by_halves(powers, eye, exact):
+    """``(I - power)^-1`` as :func:`_unit_lower_inverses` gives it, block by
+    block (:func:`_unit_lower_inverse_by_halves`): from blocks of two rows
+    up to a chunk's square, each doubling two products of whole (128 x 128)
+    tiles, the rows and columns of other blocks masked to zero; ten products
+    each waiting for the one before, the matrices in step."""
+    r, s = _iota((_PAIR, _PAIR), 0), _iota((_PAIR, _PAIR), 1)
+    results = [eye + jnp.where((r >> 1) == (s >> 1), power, 0.0)
+               for power in powers]
+    for level in range(1, CHUNK.bit_length() - 1):      # blocks of 2, .., 32
+        across = jnp.logical_and((r >> (level + 1)) == (s >> (level + 1)),
+                                 (r >> level) != (s >> level))
+        inner = [_solve_mm(jnp.where(across, power, 0.0), result, _NN, exact)
+                 for power, result in zip(powers, results)]
+        results = [result + _solve_mm(result, x, _NN, exact)
+                   for result, x in zip(results, inner)]
+    return results
+
+
 class _Parts(NamedTuple):
     """What a block of one head holds that does not need the carried state,
     each (rows, 128) float32 unless said; a (row x column) matrix's columns
@@ -564,7 +607,7 @@ class _Parts(NamedTuple):
     u: Any
 
 
-def _state_free(q, k, v, g, beta, masks, exact):
+def _state_free(q, k, v, g, beta, masks, exact, by_halves=False):
     """``_Parts`` of a block of some heads, a head's rows below another's.
     q, k, v: (rows, 128); g: float32; beta: (rows, 1)."""
     act, rows = q.dtype, q.shape[0]
@@ -594,9 +637,9 @@ def _state_free(q, k, v, g, beta, masks, exact):
                 diag[p.start + i * SUB:p.start + (i + 1) * SUB]
                 + (below[i][half:half + SUB] if i in below else 0.0)
                 for i in range(_PAIR // SUB)], axis=0))
-    solved = _unit_lower_inverses(
-        [jnp.where(masks["strict"], -x * beta[p], 0.0)
-         for x, p in zip(kk, pairs)], masks["eye"], exact)
+    solve = _unit_lower_inverses_by_halves if by_halves else _unit_lower_inverses
+    solved = solve([jnp.where(masks["strict"], -x * beta[p], 0.0)
+                    for x, p in zip(kk, pairs)], masks["eye"], exact)
     w = [_mm(x.astype(act), k_plus_b[p], _NN) for x, p in zip(solved, pairs)]
     u = [_mm(x.astype(act), v_b[p], _NN) for x, p in zip(solved, pairs)]
 
@@ -662,7 +705,8 @@ def _plus(a, b):
     return b if a is None else a + b
 
 
-def _block_backward(q, k, v, g, beta, states, do, dstates, masks, exact):
+def _block_backward(q, k, v, g, beta, states, do, dstates, masks, exact,
+                    by_halves=False):
     """The cotangents of a block of some heads (a head's rows below
     another's), by hand (the autodiff of ``_block`` is the definition it is
     tested against). states: what each head's block starts from; do: (rows,
@@ -670,7 +714,7 @@ def _block_backward(q, k, v, g, beta, states, do, dstates, masks, exact):
     key, float32). Returns (dq, dk, dv, dg, dbeta (rows, 1), the cotangents
     of ``states``), float32."""
     act, rows = q.dtype, q.shape[0]
-    parts = _state_free(q, k, v, g, beta, masks, exact)
+    parts = _state_free(q, k, v, g, beta, masks, exact, by_halves)
     _, _, starts, d = _recurrence(parts, states, masks, act, False)
     kf, qf, vf = parts.kf, parts.qf, parts.vf
     k_plus, q_plus = kf * parts.from_start, qf * parts.from_start
@@ -830,7 +874,7 @@ def _by_head(x, heads):
 
 
 def _scan_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
-                     heads, save, exact):
+                     heads, save, exact, by_halves):
     starts_ref, state_ref = rest if save else (None,) + rest
     masks, first = _pair_masks(), pl.program_id(1) == 0
 
@@ -842,7 +886,7 @@ def _scan_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
     def work(group, loaded):
         states = [x[-1] for x in loaded]
         parts = _state_free(*_below_each_other([x[:-1] for x in loaded]),
-                            masks, exact)
+                            masks, exact, by_halves)
         o, after, _, _ = _recurrence(parts, states, masks, q_ref.dtype, True)
         return zip(_by_head(o, len(group)), states, after)
 
@@ -858,7 +902,7 @@ def _scan_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
 
 def _scan_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref,
                      dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate_ref, *,
-                     heads, exact):
+                     heads, exact, by_halves):
     masks, first = _pair_masks(), pl.program_id(1) == 0
     every = _iota(beta_ref.shape, 1)
 
@@ -876,7 +920,7 @@ def _scan_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref,
         q, k, v, g, beta, do = _below_each_other([x[:-2] for x in loaded])
         *grads, dstates = _block_backward(
             q, k, v, g, beta, [x[-2] for x in loaded], do,
-            [x[-1] for x in loaded], masks, exact)
+            [x[-1] for x in loaded], masks, exact, by_halves)
         return zip(*(_by_head(x, len(group)) for x in grads), dstates)
 
     def store(head, done):
@@ -931,14 +975,15 @@ def _compiler_params():
 
 # The calls are jitted so that a model's layers and the recomputed forward
 # share ONE traced and lowered copy of each kernel (ops/flash_attention.py).
-@functools.partial(jax.jit, static_argnums=(5, 6, 7))
-def _scan_fwd_call(q, k, v, g, beta, block_len, save, interpret):
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _scan_fwd_call(q, k, v, g, beta, block_len, save, interpret,
+                   by_halves=False):
     (b, t, lanes), f32 = q.shape, jnp.float32
     grid, heads, spec = _scan_specs(q, beta, block_len, False)
     rows = spec["rows"]
     out = pl.pallas_call(
         functools.partial(_scan_fwd_kernel, heads=heads, save=save,
-                          exact=q.dtype == f32),
+                          exact=q.dtype == f32, by_halves=by_halves),
         grid=grid,
         in_specs=[rows, rows, rows, rows, spec["beta"]],
         out_specs=[rows] + [spec["states"]] * save,
@@ -953,8 +998,9 @@ def _scan_fwd_call(q, k, v, g, beta, block_len, save, interpret):
     return tuple(out)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8))
-def _scan_bwd_call(q, k, v, g, beta, starts, do, block_len, interpret):
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def _scan_bwd_call(q, k, v, g, beta, starts, do, block_len, interpret,
+                   by_halves=False):
     grid, heads, spec = _scan_specs(q, beta, block_len, True)
     rows = spec["rows"]
 
@@ -963,7 +1009,7 @@ def _scan_bwd_call(q, k, v, g, beta, starts, do, block_len, interpret):
 
     return pl.pallas_call(
         functools.partial(_scan_bwd_kernel, heads=heads,
-                          exact=q.dtype == jnp.float32),
+                          exact=q.dtype == jnp.float32, by_halves=by_halves),
         grid=grid,
         in_specs=[rows, rows, rows, rows, spec["beta"], spec["states"], rows],
         out_specs=[rows, rows, rows, rows, spec["beta"]],
@@ -981,26 +1027,28 @@ def _lanes(x):
     return x.reshape(x.shape[0], x.shape[1], -1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _kda_kernels(q, k, v, g, beta, block_len, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _kda_kernels(q, k, v, g, beta, block_len, interpret, by_halves):
     """The kernels on q, k, v, g (B, T, H x 128) and beta (B, T, H)."""
     with jax.named_scope(device_names.KDA_SCAN):
-        o, = _scan_fwd_call(q, k, v, g, beta, block_len, False, interpret)
+        o, = _scan_fwd_call(q, k, v, g, beta, block_len, False, interpret,
+                            by_halves)
     return o
 
 
-def _kda_kernels_forward(q, k, v, g, beta, block_len, interpret):
+def _kda_kernels_forward(q, k, v, g, beta, block_len, interpret, by_halves):
     with jax.named_scope(device_names.KDA_SCAN):
         o, starts = _scan_fwd_call(q, k, v, g, beta, block_len, True,
-                                   interpret)
+                                   interpret, by_halves)
     return o, (q, k, v, g, beta, starts)
 
 
-def _kda_kernels_backward(block_len, interpret, res, do):
+def _kda_kernels_backward(block_len, interpret, by_halves, res, do):
     q, k, v, g, beta, starts = res
     with jax.named_scope(device_names.KDA_SCAN):
         return tuple(_scan_bwd_call(q, k, v, g, beta, starts,
-                                    do.astype(v.dtype), block_len, interpret))
+                                    do.astype(v.dtype), block_len, interpret,
+                                    by_halves))
 
 
 _kda_kernels.defvjp(_kda_kernels_forward, _kda_kernels_backward)
@@ -1033,12 +1081,18 @@ def _record_plan(b, t, h, dk, dv, chunk, kernel):
     record_kda_plan(chunk, saved_state_bytes(b, t, h, dk, dv, chunk), kernel)
 
 
-def kda(q, k, v, g, beta, chunk: int = CHUNK, *, interpret: bool = False):
+def kda(q, k, v, g, beta, chunk: int = CHUNK, *, interpret: bool = False,
+        neg_eigval: bool = False):
     """The chunked gated delta rule. q, k: (B, T, H, K) (k of unit length a
     head where the layer is KDA's; q scaled by the caller); v: (B, T, H, V);
     g: (B, T, H, K) float32, the log of each channel's decay, <= 0; beta:
-    (B, T, H) in (0, 1). ``T`` a whole number of chunks (or shorter than
-    one). Returns o (B, T, H, V) in v's dtype.
+    (B, T, H) in (0, 1), or with ``neg_eigval`` in (0, 2): the transition
+    ``I - beta k k^T`` then has an eigenvalue in (-1, 1), and a chunk's
+    triangular system is solved block by block
+    (:func:`_unit_lower_inverse_by_halves`), which stays exact where keys of
+    a chunk are close to each other and the other solve's powers are not.
+    ``T`` a whole number of chunks (or shorter than one). Returns o (B, T, H,
+    V) in v's dtype.
 
     Shapes :func:`takes_kernel` accepts run the kernels (``interpret=True``:
     in the Pallas interpreter, asked for by the caller and never inferred
@@ -1051,8 +1105,8 @@ def kda(q, k, v, g, beta, chunk: int = CHUNK, *, interpret: bool = False):
     g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
     if kernel:
         return _kda_kernels(_lanes(q), _lanes(k), _lanes(v), _lanes(g), beta,
-                            block_len, interpret).reshape(v.shape)
-    return _kda(q, k, v, g, beta, chunk, sub, block_len)
+                            block_len, interpret, neg_eigval).reshape(v.shape)
+    return _kda(q, k, v, g, beta, chunk, sub, block_len, neg_eigval)
 
 
 def _lanes_block_len(q, k, v, heads, chunk):
@@ -1080,7 +1134,7 @@ def lanes_take_kernel(q, k, v, heads: int, chunk: int) -> bool:
 
 
 def kda_lanes(q, k, v, g, beta, chunk: int = CHUNK, *,
-              interpret: bool = False):
+              interpret: bool = False, neg_eigval: bool = False):
     """:func:`kda` on the arrays as the kernels read them, for a caller that
     holds them so (``models/kda.py`` between its fused passes): q, k, v, g
     (B, T, H x 128), beta (B, T, H); returns o (B, T, H x 128) in v's dtype,
@@ -1097,4 +1151,5 @@ def kda_lanes(q, k, v, g, beta, chunk: int = CHUNK, *,
             "(B, T, H, K) form")
     _record_plan(b, t, heads, _LANES, _LANES, chunk, True)
     return _kda_kernels(q, k, v, g.astype(jnp.float32),
-                        beta.astype(jnp.float32), block_len, interpret)
+                        beta.astype(jnp.float32), block_len, interpret,
+                        neg_eigval)

@@ -512,28 +512,38 @@ def test_the_hybrids_mixers_compile_for_v5e_at_the_held_share(
         assert " while(" not in text
 
 
+# (row, heads, beta in (0, 2): the solve block by block)
+DELTA_RULE_CELLS = {"kimi": (16384, 32, False), "solar_open2": (8192, 8, True)}
+
+
 @pytest.mark.parametrize("dtype,precision", [(jnp.bfloat16, None),
                                              (jnp.float32, "highest")],
                          ids=["bf16", "f32_highest"])
+@pytest.mark.parametrize("cell", sorted(DELTA_RULE_CELLS))
 def test_the_delta_rules_kernels_compile_for_v5e_at_the_cells_shape(
-        one_chip, no_persistent_cache, dtype, precision):
+        one_chip, no_persistent_cache, cell, dtype, precision):
     """kimi_linear_seq16384_1chip's row through ``ops.kda.kda``: (1, 16384, 32
-    heads of 128 | 128), chunks of 64 in blocks of four, the step's bf16 and
-    the check's float32 under "highest". Forward and backward: both kernels
-    are in the compiled program, and no loop over blocks is."""
+    heads of 128 | 128), and solar_open2_seq8192_1chip's: (1, 8192, 8 heads)
+    with beta in (0, 2) (``neg_eigval``: the solve block by block); chunks of
+    64 in blocks of four, the step's bf16 and the check's float32 under
+    "highest". Forward and backward: both kernels are in the compiled
+    program, and no loop over blocks is."""
     from horovod_tpu.common.device_names import KDA_SCAN
     from horovod_tpu.ops import kda as kda_ops
+
+    t, heads, neg_eigval = DELTA_RULE_CELLS[cell]
 
     def shape(*dims, of=dtype):
         return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
 
-    x = shape(1, 16384, 32, 128)
-    args = (x, x, x, shape(1, 16384, 32, 128, of=jnp.float32),
-            shape(1, 16384, 32, of=jnp.float32))
-    assert kda_ops.takes_kernel(x, x, x, *kda_ops.plan(16384)[::2])
+    x = shape(1, t, heads, 128)
+    args = (x, x, x, shape(1, t, heads, 128, of=jnp.float32),
+            shape(1, t, heads, of=jnp.float32))
+    assert kda_ops.takes_kernel(x, x, x, *kda_ops.plan(t)[::2])
 
     def value_and_grads(*a):    # the value too: a forward nobody reads is cut
-        out, vjp = jax.vjp(kda_ops.kda, *a)
+        out, vjp = jax.vjp(lambda *a: kda_ops.kda(*a, neg_eigval=neg_eigval),
+                           *a)
         return out, vjp(out)
 
     with jax.default_matmul_precision(precision):
