@@ -151,7 +151,8 @@ def _update_bias(bias, counts, rate, reduce=lambda c: c):
 
 def _init_state(model, config):
     """``key -> (params, bias)``, the file's ``assumed`` initialisation:
-    every matrix normal with std ``initializer_std``, the out-projections of
+    every matrix normal with std ``initializer_std`` (the embedding's rows
+    with ``init.embedding_std``), the out-projections of
     the mixers (``out_proj``, ``o_proj``) divided by sqrt(2 x the published
     depth) (``rescale_prenorm_residual``), norm weights 1, the Mamba-2
     leaves as ``models/mamba.py`` draws them (``A`` uniform in [1, 16], ``dt``
@@ -166,6 +167,7 @@ def _init_state(model, config):
     from horovod_tpu.models import mamba
 
     std = config["initializer_std"]
+    embed_std = config["init"]["embedding_std"]
     rescale = (2 * config["num_hidden_layers"]) ** -0.5
     special = {"A_log": mamba._a_log_init, "dt_bias": mamba._dt_bias_init,
                "conv_kernel": nn.initializers.lecun_normal(),
@@ -177,7 +179,11 @@ def _init_state(model, config):
             return special[names[-1]](key, leaf.shape, leaf.dtype)
         if leaf.ndim < 2:       # norm weights, D
             return jnp.ones(leaf.shape, leaf.dtype)
-        scale = std * (rescale if {"out_proj", "o_proj"} & set(names) else 1.0)
+        if names[-1] == "embedding":
+            scale = embed_std
+        else:
+            scale = std * (rescale if {"out_proj", "o_proj"} & set(names)
+                           else 1.0)
         return scale * jax.random.normal(key, leaf.shape, leaf.dtype)
 
     def init(key):

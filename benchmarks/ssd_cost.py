@@ -11,7 +11,6 @@ from benchmarks import flops
 # (horovod_tpu/common/device_names.py): the mixer is its projections,
 # convolution, gated norm (``hvd_mamba_*``) and the scan (``hvd_ssd_*``).
 MIXER_LABELS = ("hvd_mamba", "hvd_ssd")
-SSD_LABELS = ("hvd_ssd",)
 
 
 def ssd_forward_flops(seq, heads, head_dim, state, groups, chunk):

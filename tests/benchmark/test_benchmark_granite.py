@@ -208,14 +208,23 @@ def test_costs_against_hand_counts():
     assert cost["kernel"]["flops"] == 7 * 16384 * 16384 * 64 * 32   # one layer
 
 
-def test_mixer_readers_on_a_hand_made_breakdown(hvd):
+def test_mixer_readers_on_a_hand_made_breakdown(hvd, monkeypatch):
+    from benchmarks import named_device_time
+
     trace = {"steps": 4, "breakdown": {"device_ops": [
         ["fusion [bench_fwd_bwd] hvd_mamba_proj/in_proj/dot_general", 2.0],
         ["fusion [bench_fwd_bwd] block_N/mlp_gate/dot_general", 1.6],
         ["while [bench_fwd_bwd] mixer/hvd_ssd_scan/while", 0.4],
         ["while [bench_fwd_bwd] hvd_ssd_scan/closed_call/while", 0.2],
         ["fusion [bench_fwd_bwd] mixer/hvd_mamba_conv/add", 0.4]]}}
-    context = {"trace": trace, "log": lambda *a: None,
+    # the scan by the program's names, whatever the rank of its labels: the
+    # roofline's divisor since PR 58 (``ssd_ms_per_step``, which stood on the
+    # ten longest labels and read nothing since PR 40, went with it)
+    table = {"seconds": {"hvd_ssd_scan": 0.01896, "hvd_mamba_proj": 0.1091},
+             "unnamed": 0.02}
+    monkeypatch.setattr(named_device_time, "_tables", [table])
+    lines = []
+    context = {"trace": trace, "log": lines.append,
                "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
                "cost": {"ssd": {"flops": 0.788e12, "bytes": 4.1e9}}}
 
@@ -224,19 +233,41 @@ def test_mixer_readers_on_a_hand_made_breakdown(hvd):
             REPO, "benchmarks", "layer_metrics", name + ".py")).read(context)
 
     assert read("mamba_mixer_ms_per_step") == pytest.approx(750.0)
-    assert read("ssd_ms_per_step") == pytest.approx(150.0)
+    assert read("ssd_scan_ms_per_step") == pytest.approx(18.96)
     # bound by bytes: 4.1e9 / 819e9 = 5.006 ms against 4.0 ms by operations
-    assert read("ssd_roofline_pct") == pytest.approx(100 * 5.00610 / 150.0, rel=1e-4)
+    assert read("ssd_roofline_pct") == pytest.approx(100 * 5.00610 / 18.96, rel=1e-4)
+    assert "bound by HBM bandwidth" in lines[-1]
     hvd.metrics.registry().gauge("horovod_ssd_chunk_len").set(256)
     assert read("ssd_chunk_len") == 256
     # a program without the scopes or the gauge (the parent): nothing, no raise
     trace["breakdown"]["device_ops"] = [["fusion x/mlp_in/dot_general", 1.0]]
+    table["seconds"] = {"hvd_mamba_proj": 0.1091}
     hvd.metrics.registry().gauge("horovod_ssd_chunk_len").set(0)
-    for name in ("mamba_mixer_ms_per_step", "ssd_ms_per_step",
+    for name in ("mamba_mixer_ms_per_step", "ssd_scan_ms_per_step",
                  "ssd_roofline_pct", "ssd_chunk_len"):
         assert read(name) is None
+    # a window that never ran the scan gives no share, not 0 nor a division
+    table["seconds"] = {"hvd_ssd_scan": 0.0}
+    assert read("ssd_roofline_pct") is None
+    table["seconds"] = {"hvd_ssd_scan": 0.01896}
     context["cost"] = {}
     assert read("ssd_roofline_pct") is None
+
+
+def test_no_reader_stands_on_a_name_the_manifest_dropped():
+    """``ssd_ms_per_step`` left ``BENCHMARK.json`` with its reader (PR 58):
+    every listed metric has a reader, every reader is listed, and granite's
+    roofline share is still asked for in its cell."""
+    manifest = run.load_manifest()
+    listed = {m["name"] for m in manifest["per_layer"]}
+    readers = {os.path.splitext(f)[0] for f in os.listdir(os.path.join(
+        REPO, "benchmarks", "layer_metrics")) if f.endswith(".py")}
+    assert listed == readers
+    assert "ssd_ms_per_step" not in listed
+    roofline = next(m for m in manifest["per_layer"]
+                    if m["name"] == "ssd_roofline_pct")
+    assert roofline["workloads"] == ["granite4h_long_1chip"]
+    assert (roofline["unit"], roofline["moves"]) == ("%", "step_ms")
 
 
 def test_the_two_reference_copies_agree():

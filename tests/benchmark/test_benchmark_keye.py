@@ -278,18 +278,22 @@ def test_a_choice_moved_by_hand_is_held_under_the_systems_choice(
     from horovod_tpu.models import moe as models_moe
     from horovod_tpu.ops import sparse_attention as dsa
 
-    real_rows, real_route = dsa.select_rows, models_moe.topk_route
+    # the selection's one kernel call since PR 46 (the XLA path's
+    # ``select_rows`` went with it): the seam tests/test_sparse_attention.py
+    # takes, the two bits flipped in ``words``
+    real_call, real_route = dsa._select_call, models_moe.topk_route
 
-    def other_key(scores, row0, topk):
-        mask, _ = real_rows(scores, row0, topk)
+    def other_key(scores, row0, topk, chunk, interpret):
+        words, _ = real_call(scores, row0, topk, chunk, interpret)
         rows, t = scores.shape
+        mask = dsa.unpack(words, t, chunk)
         pos = jnp.arange(t)
         last = jnp.max(jnp.where(mask, pos, -1), axis=1)
         free = jnp.min(jnp.where((scores > -jnp.inf) & ~mask, pos, t), axis=1)
         here = (row0 + jnp.arange(rows) == 90) & (free < t)
         mask ^= here[:, None] & ((pos == last[:, None]) | (pos == free[:, None]))
         kept = jnp.where(mask, scores, -jnp.inf)
-        return mask, jax.nn.logsumexp(kept, axis=1)
+        return dsa.pack(mask, chunk), jax.nn.logsumexp(kept, axis=1)
 
     def other_expert(logits, top_k, renormalise=False):
         probs, _, experts = real_route(logits, top_k, renormalise)
@@ -301,7 +305,7 @@ def test_a_choice_moved_by_hand_is_held_under_the_systems_choice(
         weights = jnp.sum(jnp.where(onehot, probs[:, None, :], 0.0), axis=-1)
         return probs, weights / weights.sum(-1, keepdims=True), experts
 
-    monkeypatch.setattr(dsa, "select_rows", other_key)
+    monkeypatch.setattr(dsa, "_select_call", other_key)
     monkeypatch.setattr(models_moe, "topk_route", other_expert)
     resolved, check = check_alone(hvd)
     resolved["config"]["tolerance"] = {

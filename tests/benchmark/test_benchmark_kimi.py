@@ -484,7 +484,8 @@ def test_the_manifest_holds_the_new_cell():
     cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
     assert cell == {**cell, "config": "kimi_linear_48b_a3b",
                     "traffic": "seq16384x1_fence5_kda", "chips": 1}
-    assert manifest["workloads"][-1]["name"] == CELL
+    # by name, not by place: later cells come after it and join its lists
+    assert [w["name"] for w in manifest["workloads"]].count(CELL) == 1
     resolved = run.resolve_cell(manifest, CELL)
     names = {m["name"] for m in resolved["per_layer"]}
     new = {"kda_mixer_ms_per_step", "kda_proj_ms_per_step",
@@ -499,8 +500,9 @@ def test_the_manifest_holds_the_new_cell():
                 "mla_ms_per_step", "ssd_ms_per_step"} & names
     for metric in manifest["per_layer"]:
         if metric["name"] in new:
-            assert (metric["layer"], metric["moves"], metric["workloads"]) == (
-                "KDA mixer", "step_ms", [CELL])
+            assert (metric["layer"], metric["moves"]) == ("KDA mixer",
+                                                          "step_ms")
+            assert CELL in metric["workloads"]
             assert os.path.exists(os.path.join(
                 REPO, "benchmarks", "layer_metrics", metric["name"] + ".py"))
     assert {m["name"] for m in resolved["end_to_end"]} == {

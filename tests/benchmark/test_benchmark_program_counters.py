@@ -158,13 +158,30 @@ def test_the_harness_relowering_of_a_real_step_moves_no_compile_reader(hvd, clea
         train_step, mesh=mesh, in_specs=(P(), P(), P(hvd.HVD_AXIS)),
         out_specs=(P(), P()), check_vma=False))
     *state, = step(*state, *batch)
-    float(state[0]["w"].sum())      # set-up goes on after the step's compile
+
+    # set-up goes on after the step's compile, as in a run: under a name this
+    # process has not compiled before (the readers leave out the ledger's LAST
+    # name back to its first compile, and a worker that has run other tests
+    # has compiled ``train_step`` and ``_reduce_sum`` many times)
+    def set_up_goes_on(w):
+        return w.sum()
+
+    set_up_goes_on.__name__ = f"set_up_goes_on_{cleared}"
+    float(jax.jit(set_up_goes_on)(state[0]["w"]))
+    assert compile_cache.compile_ledger()["entries"][-1]["fun_name"] == (
+        set_up_goes_on.__name__)
     before = {name: read(name) for name in SET_UP}
-    entries = len(compile_cache.compile_ledger()["entries"])
+    # by the ledger's totals: ``entries`` is a deque capped at
+    # ``LEDGER_ENTRIES``, full in a whole run of the tests, so its length
+    # says nothing about what one call added
+    entries = sum(compile_cache.compile_ledger()["count"].values())
     if cleared:
         jax.clear_caches()
     step.lower(*run.abstract(state), *run.abstract(batch)).compile().as_text()
-    added = compile_cache.compile_ledger()["entries"][entries:]
+    ledger = compile_cache.compile_ledger()
+    n_added = sum(ledger["count"].values()) - entries
+    assert 0 < n_added < compile_cache.LEDGER_ENTRIES
+    added = ledger["entries"][-n_added:]
     assert added and {e["fun_name"] for e in added} == {"train_step"}
     assert ("backend" in {e["phase"] for e in added}) == cleared
     assert {name: read(name) for name in SET_UP} == pytest.approx(before)
