@@ -22,8 +22,15 @@ FLASH_SEL_BWD_DKV = "hvd_flash_sel_bwd_dkv"
 RING_FLASH_FWD = "hvd_ring_flash_fwd"
 RING_FLASH_BWD_DQ = "hvd_ring_flash_bwd_dq"
 RING_FLASH_BWD_DKV = "hvd_ring_flash_bwd_dkv"
-FUSION_PACK = "hvd_fusion_pack"         # fuse + compress + the wire cast
-FUSION_UNPACK = "hvd_fusion_unpack"     # the cast back + decompress + unfuse
+# Round the exchange of ``parallel/fusion.py``. On the flat path a bucket's
+# leaves go to the collective as they are (PR 59), so the two scopes hold the
+# casts of a wire format (and the legacy compress / decompress) alone and no
+# operation where none is set; under ``hierarchical=True`` also the copy into
+# the padded buffer and back. What the copies cost on the flat path: 0.87 +
+# 6.07 ms of ``solar_open2_seq8192_1chip``'s 181.56 ms step, 0.45 + 0.25 ms
+# in ``resnet50_4chip`` (PERF_LEDGER.jsonl, PR 58).
+FUSION_PACK = "hvd_fusion_pack"         # compress + the wire cast (+ fuse)
+FUSION_UNPACK = "hvd_fusion_unpack"     # the cast back + decompress (+ unfuse)
 OPTIMIZER_UPDATE = "hvd_optimizer_update"   # the wrapped optax update
 FUSED_ALLREDUCE = "hvd_fused_allreduce_k"   # + the number of buckets
 MOE_ROUTE = "hvd_moe_route"             # softmax + top-k of the router

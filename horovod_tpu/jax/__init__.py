@@ -4,10 +4,12 @@ Parity map to the reference bindings:
 
 - :func:`DistributedOptimizer`      ↔ hvd.DistributedOptimizer
   (torch/__init__.py:52-151, tensorflow/__init__.py:151-249). Wraps any optax
-  GradientTransformation; grads are fused into flat buckets and allreduced
-  with one psum per bucket before the inner update. Hook machinery is
-  unnecessary: JAX grads arrive as a complete pytree, so "fuse → psum →
-  unfuse" replaces the per-parameter grad-accumulator hooks.
+  GradientTransformation; grads are planned into buckets and allreduced
+  in the plan's order before the inner update (a bucket's leaves as they
+  are on the flat path, one padded buffer a bucket on the hierarchical
+  ladder: parallel/fusion.py). Hook machinery is unnecessary: JAX grads
+  arrive as a complete pytree, so "plan → psum" replaces the
+  per-parameter grad-accumulator hooks.
 - :func:`distributed_gradients` / :func:`grad` ↔ DistributedGradientTape
   (tensorflow/__init__.py:252-326).
 - :func:`broadcast_parameters`      ↔ hvd.broadcast_parameters

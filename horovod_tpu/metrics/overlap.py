@@ -37,9 +37,14 @@ from .registry import DEFAULT_BYTE_BUCKETS, registry
 _last_plan: Optional[list] = None
 
 
-def record_plan(plan, threshold: int) -> list:
+def record_plan(plan, threshold: int, staged: bool = True) -> list:
     """Record a FusionPlan's bucket geometry into the registry (called from
     fusion.fused_allreduce at trace time — once per compile, not per step).
+
+    ``staged``: whether the caller copies each bucket into a flat buffer
+    (the hierarchical and sharded planners, whose reduce-scatter needs one
+    divisible length a bucket). The flat data-parallel path hands a bucket's
+    leaves to the collective as they are and passes False.
 
     Returns the recorded [(issue_index, nbytes), ...] list."""
     global _last_plan
@@ -53,11 +58,17 @@ def record_plan(plan, threshold: int) -> list:
             if rem:
                 nbytes += (plan.pad_to - rem) * bucket[0].dtype.itemsize
         sizes.append((i, nbytes))
-    total = sum(n for _, n in sizes) or 1
+    planned_bytes = sum(n for _, n in sizes)
+    total = planned_bytes or 1
     reg.gauge("horovod_fusion_buckets",
               help="buckets in the latest compiled fusion plan").set(len(sizes))
     reg.gauge("horovod_fusion_planned_bytes",
               help="total gradient bytes in the latest fusion plan").set(total)
+    reg.gauge("horovod_fusion_staged_bytes",
+              help="bytes a step copies into flat fusion buffers in the "
+                   "latest plan, padding included (0 = the leaves go to the "
+                   "collective as they are)").set(
+        planned_bytes if staged else 0)
     occ = reg.gauge("horovod_fusion_buffer_occupancy",
                     help="largest bucket bytes / fusion threshold")
     occ.set(max(n for _, n in sizes) / max(1, threshold))

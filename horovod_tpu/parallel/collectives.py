@@ -69,16 +69,22 @@ def allreduce(x, axis_name: str = HVD_AXIS, op: ReduceOp = ReduceOp.AVERAGE):
 
 def bucketed_allreduce(buffers: Sequence, axis_name: str = HVD_AXIS,
                        op: ReduceOp = ReduceOp.AVERAGE) -> list:
-    """One independent collective per flat bucket buffer, in ISSUE order.
+    """One independent collective per array, in ISSUE order.
 
-    The buffers come from fusion.build_plan's reverse-backward-order split:
-    bucket 0 holds the last layers' gradients, which the backward pass
-    produces first, so its psum's operand is ready while the rest of the
-    backward compute is still running. Each psum is emitted as its own op
-    (no jnp-level dependency between buckets), which is exactly the shape
-    XLA's latency-hiding scheduler needs to overlap the ICI transfer of early buckets with the remaining
-    compute — the compiled plane analog of Horovod's background thread
-    starting allreduces mid-backward (operations.cc PerformOperation)."""
+    On the flat data-parallel path ``fusion.fused_allreduce`` calls this once
+    a bucket with the bucket's LEAVES as they are (PR 59: no flat buffer is
+    filled; the copies in and out cost 0.45 + 0.25 + 0.95 ms round
+    ``resnet50_4chip``'s 97.49 MiB bucket and 6.94 ms of
+    ``solar_open2_seq8192_1chip``'s step: PERF_LEDGER.jsonl, PR 58); the
+    sharded planner's degenerate ``shard=1`` case still passes its rank-1
+    buffers. The order comes from fusion.build_plan's reverse-backward-order
+    split: bucket 0 holds the last layers' gradients, which the backward
+    pass produces first, so its psums' operands are ready while the rest of
+    the backward compute is still running. Each psum is emitted as its own op
+    (no jnp-level dependency between them) and forming the transfers is left
+    to XLA's all-reduce combiner and latency-hiding scheduler — the compiled
+    plane analog of Horovod's background thread starting allreduces
+    mid-backward (operations.cc PerformOperation)."""
     return [allreduce(b, axis_name, op) for b in buffers]
 
 

@@ -1,5 +1,5 @@
-"""Tensor fusion: batch many small gradients into few flat buffers before a
-single collective.
+"""Tensor fusion: plan many small gradients into few buckets, one exchange a
+bucket.
 
 TPU-native equivalent of the reference's fusion pipeline — the coordinator's
 greedy same-dtype/device merge up to HOROVOD_FUSION_THRESHOLD
@@ -12,9 +12,20 @@ Differences by design:
   every rank builds identical buckets deterministically (tree_flatten order) —
   no runtime negotiation needed for the compiled path. This resolves the
   async-enqueue-vs-XLA ordering problem called out in SURVEY.md §7.
-- The "memcpy into fusion buffer" is a concatenate that XLA fuses; the
-  collective runs once per bucket, preserving Horovod's
-  fewer-larger-collectives behaviour on ICI.
+- On the flat data-parallel path a bucket is NOT a copy (PR 59): its leaves
+  go to the collective as they are, in the plan's order, and XLA's all-reduce
+  combiner forms the transfers. The plan still decides the order in which the
+  reductions are emitted, the wire verdict (on the bucket's dtype and total
+  bytes) and every trace-time gauge. The "memcpy into fusion buffer" was a
+  concatenate into a rank-1 buffer and a slice + reshape back; on the chip
+  neither is a bitcast of a tiled 2-D or 3-D array, and the pair cost 6.94 ms
+  of ``solar_open2_seq8192_1chip``'s 181.56 ms step, in a world of one where
+  XLA drops the collective itself (PERF_LEDGER.jsonl, PR 58:
+  ``reshape__bench_optimizer__bench_optimizer/hvd_fusion_unpack/`` 0.1273 s).
+- ``fuse`` / ``unfuse`` stay for the two planners whose reduce-scatter needs
+  one divisible length a bucket: ``hierarchical=True`` here and
+  ``parallel/sharded.py``. ``horovod_fusion_staged_bytes`` says how many bytes
+  a step copies into such buffers (0 on the flat path).
 """
 
 from __future__ import annotations
@@ -265,21 +276,33 @@ def fused_allreduce(
     dcn_compression=None,
     dcn_threshold: Optional[int] = None,
 ):
-    """The Horovod fast path: fuse → (compress) → one collective per bucket →
-    (decompress) → unfuse.
+    """The Horovod fast path: plan → (compress) → the buckets' collectives in
+    issue order → (decompress).
+
+    Flat (``hierarchical=False``): a bucket's leaves go to the collective as
+    they are, one ``collectives.allreduce`` a leaf, buckets in issue order and
+    a bucket's leaves in its own; nothing is ravelled, concatenated, padded,
+    sliced or reshaped, and XLA's all-reduce combiner forms the transfers
+    (PR 59; the copies cost 6.94 ms of a 181.56 ms step in
+    ``solar_open2_seq8192_1chip`` and 0.70 + 0.95 ms round the 97.49 MiB
+    bucket of ``resnet50_4chip``: PERF_LEDGER.jsonl, PR 58). Hierarchical:
+    fuse → ladder → unfuse, one padded rank-1 buffer a bucket, because the
+    reduce-scatter needs a length the ``ici`` axis divides.
 
     ``compression`` (a :class:`horovod_tpu.compression.Compressor`, a
     HOROVOD_COMPRESSION name, or None) is the wire optimization: eligible
     buckets are cast to the 16-bit wire dtype right before their collective
     and cast back right after, halving the bytes every ``psum`` moves over
-    ICI/DCN (reference FP16Compressor semantics, applied per fused bucket
-    instead of per tensor). Eligibility is per bucket — see
-    :func:`wire_dtype_for_bucket`. The legacy ``compress``/``decompress``
-    callables are still honored for callers that pre-date the wire path.
+    ICI/DCN (reference FP16Compressor semantics, decided per bucket
+    instead of per tensor). Eligibility is per bucket, on the bucket's dtype
+    and total bytes — see :func:`wire_dtype_for_bucket`; on the flat path the
+    cast pair is applied to each of an eligible bucket's leaves. The legacy
+    ``compress``/``decompress`` callables are still honored for callers that
+    pre-date the wire path (a leaf at a time on the flat path).
 
     ``num_buckets > 1`` switches to the reverse-backward-order overlap plan
-    (build_plan): K independent collectives, issued last-layer-first, each
-    becoming schedulable as soon as its bucket's gradients exist — the knob
+    (build_plan): the collectives are issued last-layer-first, each
+    becoming schedulable as soon as its gradients exist — the knob
     the A/B bench and the autotuner drive (HOROVOD_NUM_BUCKETS).
 
     Fabric-aware tiering (ISSUE 7, ``hierarchical=True`` only):
@@ -350,38 +373,47 @@ def fused_allreduce(
         threshold = dcn_capped_threshold(threshold, dcn_threshold, pad_to)
     plan = build_plan(tree, threshold, pad_to=pad_to, num_buckets=num_buckets)
     # Telemetry (ISSUE 2): record the bucket geometry — count, per-bucket
-    # bytes in issue order, buffer occupancy, planned overlap bound — in
-    # the metrics registry. Runs at TRACE time (once per compile), so the
-    # compiled hot path carries zero instrumentation cost.
+    # bytes in issue order, buffer occupancy, planned overlap bound, the
+    # bytes staged into flat buffers — in the metrics registry. Runs at TRACE
+    # time (once per compile), so the compiled hot path carries zero
+    # instrumentation cost.
     from ..metrics import record_plan, record_wire_plan
 
-    record_plan(plan, threshold)
+    record_plan(plan, threshold, staged=hierarchical)
+    # A bucket is a list of arrays: its leaves as they are on the flat path,
+    # its one padded rank-1 buffer where the ladder's reduce-scatter needs
+    # one. Every verdict and gauge below reads the bucket's dtype and the
+    # sum over its arrays, so both forms record the same numbers.
     with jax.named_scope(FUSION_PACK):
-        buffers = fuse(tree, plan)
-        orig_dtypes = [buf.dtype for buf in buffers]
+        if hierarchical:
+            buckets = [[buf] for buf in fuse(tree, plan)]
+        else:
+            leaves = jax.tree_util.tree_leaves(tree)
+            buckets = [[jnp.asarray(leaves[d.index]) for d in bucket]
+                       for bucket in plan.buckets]
+        orig_dtypes = [b[0].dtype for b in buckets]
         if compress is not None:
-            buffers = [compress(buf) for buf in buffers]
+            buckets = [[compress(x) for x in b] for b in buckets]
         # Wire compression (ISSUE 5): per-bucket cast to the 16-bit wire
         # dtype around the collective. Decided at trace time, so the hot
-        # path carries exactly one convert pair per eligible bucket and
-        # nothing else.
-        wire = [wire_dtype_for_bucket(compression, buf.dtype,
-                                      int(buf.nbytes), op,
-                                      compression_min_bytes)
-                for buf in buffers]
+        # path carries exactly one convert pair per array of an eligible
+        # bucket and nothing else.
+        wire = [wire_dtype_for_bucket(compression, b[0].dtype, _nbytes(b),
+                                      op, compression_min_bytes)
+                for b in buckets]
         record_wire_plan(
             compression_name(compression),
-            [(int(b.nbytes), w is not None,
-              int(b.size) * (jnp.dtype(w).itemsize if w is not None else 0))
-             for b, w in zip(buffers, wire)])
-        buffers = [b.astype(w) if w is not None else b
-                   for b, w in zip(buffers, wire)]
+            [(_nbytes(b), w is not None,
+              _size(b) * (jnp.dtype(w).itemsize if w is not None else 0))
+             for b, w in zip(buckets, wire)])
+        buckets = [[x.astype(w) for x in b] if w is not None else b
+                   for b, w in zip(buckets, wire)]
     # Per-fabric-tier wire dtype (ISSUE 7): the DCN psum of the hierarchical
     # ladder may run at its own (usually narrower) wire dtype. Computed
     # against the AS-SHIPPED buffer dtype — a bucket already cast to a
     # 16-bit ICI wire opts out (nothing narrower to gain), and all the
     # per-bucket opt-outs of wire_dtype_for_bucket apply unchanged.
-    dcn_wire = [None] * len(buffers)
+    dcn_wire = [None] * len(buckets)
     _dcn_plan_name = ""
     if hierarchical:
         if (_adaptive and dcn_compression is None
@@ -397,14 +429,14 @@ def fused_allreduce(
 
             _fmts = []
             _fallbacks = 0
-            for buf in buffers:
+            for b in buckets:
                 fmt, substituted = compiled_tier_format(
-                    int(buf.nbytes), buf.dtype, "dcn", with_fallback=True)
+                    _nbytes(b), b[0].dtype, "dcn", with_fallback=True)
                 _fallbacks += 1 if substituted else 0
                 _fmts.append(fmt)
-            dcn_wire = [wire_dtype_for_bucket(f, buf.dtype, int(buf.nbytes),
+            dcn_wire = [wire_dtype_for_bucket(f, b[0].dtype, _nbytes(b),
                                               op, compression_min_bytes)
-                        for f, buf in zip(_fmts, buffers)]
+                        for f, b in zip(_fmts, buckets)]
             _dcn_plan_name = "adaptive"
             if _fallbacks:
                 from ..metrics import registry as _metrics_registry
@@ -422,10 +454,10 @@ def fused_allreduce(
                 dcn_compression = (
                     os.environ.get("HOROVOD_DCN_COMPRESSION", "")
                     or compression)
-            dcn_wire = [wire_dtype_for_bucket(dcn_compression, buf.dtype,
-                                              int(buf.nbytes), op,
+            dcn_wire = [wire_dtype_for_bucket(dcn_compression, b[0].dtype,
+                                              _nbytes(b), op,
                                               compression_min_bytes)
-                        for buf in buffers]
+                        for b in buckets]
             _dcn_plan_name = compression_name(dcn_compression)
     from ..metrics import record_tier_plan
 
@@ -434,33 +466,51 @@ def fused_allreduce(
         ici_wire=compression_name(compression),
         dcn_wire=_dcn_plan_name,
         ici_size=pad_to,
-        bucket_bytes=[int(b.nbytes) for b in buffers],
+        bucket_bytes=[_nbytes(b) for b in buckets],
         dcn_bucket_bytes=[
-            (int(b.size) // pad_to) * int(jnp.dtype(dw).itemsize
-                                          if dw is not None
-                                          else b.dtype.itemsize)
-            for b, dw in zip(buffers, dcn_wire)] if hierarchical else [])
+            (_size(b) // pad_to) * int(jnp.dtype(dw).itemsize
+                                       if dw is not None
+                                       else b[0].dtype.itemsize)
+            for b, dw in zip(buckets, dcn_wire)] if hierarchical else [])
     # Named scopes (common/device_names.py): the device profile's HLO ops
     # carry the bucket count on the collectives and pack / unpack on the
-    # copies and casts around them. Metadata only: no operation is added.
-    with jax.named_scope(f"{FUSED_ALLREDUCE}{len(buffers)}"):
+    # casts (and, hierarchical, the copies) around them. Metadata only: no
+    # operation is added.
+    with jax.named_scope(f"{FUSED_ALLREDUCE}{len(buckets)}"):
         if hierarchical:
             reduced = [
-                collectives.hierarchical_allreduce(
-                    buf, ici_axis=ici_axis, dcn_axis=dcn_axis,
+                [collectives.hierarchical_allreduce(
+                    b[0], ici_axis=ici_axis, dcn_axis=dcn_axis,
                     average=(op == collectives.ReduceOp.AVERAGE),
-                    dcn_wire_dtype=dw)
-                for buf, dw in zip(buffers, dcn_wire)
+                    dcn_wire_dtype=dw)]
+                for b, dw in zip(buckets, dcn_wire)
             ]
         else:
-            reduced = collectives.bucketed_allreduce(buffers, axis_name, op)
+            reduced = [collectives.bucketed_allreduce(b, axis_name, op)
+                       for b in buckets]
     with jax.named_scope(FUSION_UNPACK):
-        reduced = [r.astype(dt) if w is not None else r
+        reduced = [[x.astype(dt) for x in r] if w is not None else r
                    for r, w, dt in zip(reduced, wire, orig_dtypes)]
         if decompress is not None:
-            reduced = [decompress(r, dt)
+            reduced = [[decompress(x, dt) for x in r]
                        for r, dt in zip(reduced, orig_dtypes)]
-        return unfuse(reduced, plan)
+        if hierarchical:
+            return unfuse([r[0] for r in reduced], plan)
+        out: list = [None] * plan.treedef.num_leaves
+        for bucket, r in zip(plan.buckets, reduced):
+            for d, x in zip(bucket, r):
+                out[d.index] = x
+        return jax.tree_util.tree_unflatten(plan.treedef, out)
+
+
+def _nbytes(arrays) -> int:
+    """Bytes of a bucket's arrays together."""
+    return sum(int(x.nbytes) for x in arrays)
+
+
+def _size(arrays) -> int:
+    """Elements of a bucket's arrays together."""
+    return sum(int(x.size) for x in arrays)
 
 
 def _axis_size(axis_name: str):

@@ -245,11 +245,13 @@ def reduce_scatter_gradients(
     per-bucket verdicts unchanged (wire_dtype_for_bucket opt-outs; the cast
     wraps BOTH collectives, so scatter and batch-psum ship wire-width).
 
-    On a degenerate ``shard=1`` mesh the exchange is literally
-    ``collectives.bucketed_allreduce`` over ``batch_axis`` — the same call,
-    cast sequence, and plan the DP path traces — so the exchange is
-    sharded==DP equation for equation there (the two whole steps remain
-    separately compiled programs and agree to float32 rounding).
+    On a degenerate ``shard=1`` mesh the exchange is
+    ``collectives.bucketed_allreduce`` over ``batch_axis`` on the plan's
+    buffers — the same call, cast sequence, and plan the DP path traces,
+    which since PR 59 hands that call a bucket's leaves where this one hands
+    it the bucket's buffer — so the exchange is sharded==DP element for
+    element there (the two whole steps remain separately compiled programs
+    and agree to float32 rounding).
 
     On a 3-D ``('batch','shard','model')`` mesh (ISSUE 19) NOTHING extra
     goes on the wire here: ``grads`` is one model rank's LOCAL gradient

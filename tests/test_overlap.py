@@ -95,7 +95,8 @@ def test_single_bucket_plan_unchanged():
 
 def test_k_bucket_equals_single_bucket_allreduce(mesh8):
     """K-bucket and single-bucket fused allreduce must agree bitwise on the
-    8-device CPU mesh: bucketing regroups the concatenation, not the
+    8-device CPU mesh: bucketing reorders the leaves' reductions (a
+    bucket's leaves go to the collective as they are, PR 59), not the
     per-element cross-rank sums."""
     key = jax.random.PRNGKey(0)
     grads = {

@@ -290,12 +290,27 @@ def _exchange_eqns(fn, mesh, out_specs, grads):
             for e in eqns[:last + 2]]
 
 
+def _psum_elements(eqns):
+    """Elements each psum of an exchange reduces, in the order emitted."""
+    sizes = []
+    for name, invars, _ in eqns:
+        if name == "psum":
+            (aval,) = invars
+            dims = aval[aval.index("[") + 1:-1]
+            sizes.append(int(np.prod([int(n) for n in dims.split(",") if n])))
+    return sizes
+
+
 def test_sharded_equals_dp_on_shard1(mesh8):
     """The acceptance headline, stated as what the code controls: on a
-    degenerate shard=1 mesh the exchange traces to the SAME equations as
-    today's DP path — same fuse, same buckets in the same order, one psum
-    and one divide per bucket, same casts — and the training loops agree
-    to float32 rounding. (Bit equality of the two loops was the earlier
+    degenerate shard=1 mesh the exchange follows the SAME plan as today's
+    DP path — same buckets in the same order, the same elements reduced and
+    divided the same way — and the training loops agree to float32
+    rounding. Since PR 59 the two differ in what a bucket IS: the sharded
+    planner fills one rank-1 buffer a bucket (one psum and one divide a
+    bucket), the DP path hands the collective the bucket's leaves as they
+    are (one psum and one divide a leaf, buckets in issue order, a bucket's
+    leaves in its own). (Bit equality of the two loops was the earlier
     form; they are two separately compiled XLA programs — the sharded one
     updates (1, chunk) bucket rows, the DP one the parameter leaves — and
     XLA owes them no common fusion or FMA choice: 1 ULP apart on jax 0.9.0.)"""
@@ -309,8 +324,14 @@ def test_sharded_equals_dp_on_shard1(mesh8):
     sharded_eqns = _exchange_eqns(
         lambda g: sh.reduce_scatter_gradients(g, plan),
         grid_mesh(4, 1), P("shard"), params)
-    assert sum(name == "psum" for name, _, _ in dp_eqns) == 2
-    assert sharded_eqns == dp_eqns
+    buckets = [[d.size for d in b] for b in plan.base.buckets]
+    assert len(buckets) == 2
+    assert _psum_elements(sharded_eqns) == [sum(b) for b in buckets]
+    assert _psum_elements(dp_eqns) == [n for b in buckets for n in b]
+    for eqns in (sharded_eqns, dp_eqns):    # the DP path fills no buffer
+        names = [name for name, _, _ in eqns]
+        assert ("concatenate" in names) is (eqns is sharded_eqns)
+        assert names.count("div") == names.count("psum")
 
     x, y = make_data(4)
     dp = _train_dp(params, x, y, world=4)
