@@ -63,14 +63,14 @@ backward's in sub-tiles of 256 x 256 there.
 engages and ``horovod_flash_bwd_skipped_subtile_share`` how much of the live
 blocks the backward never computes (:func:`block_census`).
 
-A third kind of call, :func:`selected_attention`, runs the same three kernels
-over a selection that is DATA (learned sparse attention:
-ops/sparse_attention.py): each query's kept keys arrive as bits, one a pair,
-every head's the same; a sub-tile's mask is a shift of a lane-aligned slice of
-the q block's words, a block step none of whose pairs is kept is told by a
-scalar-prefetched table, runs nothing and - its index maps naming the block
-already resident - fetches nothing. Grids, walk and sub-tiles are the
-causal-dense call's; the kernels go by ``hvd_flash_sel_*``.
+Three kinds of call run these kernels, and ONE place builds their three
+``pallas_call``s (:func:`_calls`: grids, block specs, scratch, output shapes,
+device names). A dense call hands it its operands alone; a windowed one
+(``window=``) the band, whose blocks alone the grids then hold
+(``hvd_flash_win_*``); :func:`selected_attention` a selection that is DATA
+(learned sparse attention: ops/sparse_attention.py), each query's kept keys
+as bits: two scalar-prefetched tables, the words as one more operand and the
+kernels' ``sel=``, on the causal-dense grids (``hvd_flash_sel_*``).
 
 Pairs with the sequence-parallel schedules in ring_attention.py (which move
 K/V between chips); `causal_reference` is the oracle both are tested
@@ -315,25 +315,41 @@ def _k_major_step(row, j, block_q, block_k, nq, group, causal, window):
     return qi, ki, i, count
 
 
-def _q_major_specs(t, block_q, block_k, heads, causal, window):
-    """((rows, steps), q_spec, kv_spec, stat_spec) of the forward's and dQ's
-    grids (:func:`_q_major_grid`): ``q_spec(width)`` for q, o, dO and dq,
-    ``kv_spec(width)`` for k and v at the q row's key/value head (``heads =
-    (h, hkv, group)``), ``stat_spec`` for lse and delta."""
+def _q_major_specs(t, block_q, block_k, heads, causal, window, chunk=None):
+    """((rows, steps), q_spec, kv_spec, stat_spec, words_spec) of the
+    forward's and dQ's grids (:func:`_q_major_grid`): ``q_spec(width)`` for
+    q, o, dO and dq, ``kv_spec(width)`` for k and v at the q row's key/value
+    head (``heads = (h, hkv, group)``), ``stat_spec`` for lse and delta. The
+    index maps take a call's scalar-prefetched tables after the grid's
+    indices: none, or a selected call's two (``chunk`` given). Then
+    ``kv_spec`` and ``words_spec`` (of the q block's packed selection; ``()``
+    without one) name the k block the second table holds: at a step whose
+    block has no selected pair, the one already resident."""
+    h, hkv, group = heads
+    nq, nk = t // block_q, t // block_k
     rows, steps, q_block, k_block = _q_major_grid(t, block_q, block_k, causal,
                                                   window)
 
+    def held_k(r, p, s, tables):
+        if not tables:
+            return k_block(p, s)
+        return tables[1][((r // h) * nq + q_block(p, s)) * nk + k_block(p, s)]
+
     def q_spec(width):
-        return pl.BlockSpec((1, block_q, width), lambda r, p, s: (
+        return pl.BlockSpec((1, block_q, width), lambda r, p, s, *tables: (
             r, q_block(p, s), 0))
 
     def kv_spec(width):
-        return pl.BlockSpec((1, block_k, width), lambda r, p, s: (
-            _kv_row(r, *heads), k_block(p, s), 0))
+        return pl.BlockSpec((1, block_k, width), lambda r, p, s, *tables: (
+            _kv_row(r, h, hkv, group), held_k(r, p, s, tables), 0))
 
-    stat_spec = pl.BlockSpec((1, 8, block_q), lambda r, p, s: (
+    stat_spec = pl.BlockSpec((1, 8, block_q), lambda r, p, s, *tables: (
         r, 0, q_block(p, s)))
-    return (rows, steps), q_spec, kv_spec, stat_spec
+    words_spec = () if chunk is None else (pl.BlockSpec(
+        (1, block_q, chunk), lambda r, p, s, *tables: (
+            r // h, q_block(p, s),
+            _word_group(held_k(r, p, s, tables), block_k, chunk))),)
+    return (rows, steps), q_spec, kv_spec, stat_spec, words_spec
 
 
 # ------------------------------------------------- a selection that is data
@@ -369,6 +385,24 @@ class _Sel(NamedTuple):
                                    self.transposed), s, NEG_INF)
 
 
+def _selection_tables(words, block_q, block_k, chunk):
+    """The scalar-prefetched tables of a selected call, flat int32: (live (B,
+    nq, nk); the k block to hold at each step of a q block's sweep; the q
+    block to hold at each step of a k block's) - a dead step's index maps
+    name the block already resident, so nothing is fetched for it."""
+    from .sparse_attention import block_liveness, fetch_table
+
+    live = block_liveness(words, block_q, block_k, chunk)
+    return (live.astype(jnp.int32).reshape(-1), fetch_table(live).reshape(-1),
+            fetch_table(jnp.swapaxes(live, 1, 2)).reshape(-1))
+
+
+def _word_group(k_block, block_k, chunk):
+    """The group of word columns (32 chunks of keys each) that holds a k
+    block's chunks."""
+    return _div(k_block * block_k, 32 * chunk)
+
+
 # ------------------------------------------------------------------- forward
 
 # Row statistics (running max m, running sum l) live as (block_q, 128) f32,
@@ -399,6 +433,23 @@ def _sub_tile(block, want):
     """Width of the sub-tiles a block is walked in: ``want`` where it
     divides a larger block, else the whole block."""
     return want if block > want and block % want == 0 else block
+
+
+def _causal_bodies(qi, ki, block_q, block_k, below, crossed, sel):
+    """Block step ``(qi, ki)`` of a causal-dense grid, in two bodies: blocks
+    wholly below the diagonal never build a mask (``below()``); the ``ratio``
+    blocks it crosses each know where (``crossed(first)``). Blocks above it
+    match neither: nothing runs there, nor (``sel``, a selected call's) where
+    no pair of the block step is selected."""
+    live = None if sel is None else sel.live(qi, ki)
+
+    def when(cond):
+        return pl.when(cond if live is None else cond & live)
+
+    when(jnp.logical_not(_crossed(qi, ki, block_q, block_k)))(below)
+    for j in range(block_q // block_k):
+        when(ki == qi * (block_q // block_k) + j)(
+            functools.partial(crossed, j * block_k))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
@@ -482,20 +533,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
             pl.when((ki >= 0) & (first == at))(
                 functools.partial(crossed, at, fine))
     elif causal:
-        # Two bodies: blocks wholly below the diagonal never build a mask;
-        # the ``ratio`` blocks the diagonal crosses each know where.
-        if sel is None:
-            pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k)))(below)
-            for j in range(ratio):
-                pl.when(ki == qi * ratio + j)(
-                    functools.partial(crossed, j * block_k))
-        else:       # and a block step with no selected pair runs nothing
-            live = sel.live(qi, ki)
-            pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k))
-                    & live)(below)
-            for j in range(ratio):
-                pl.when((ki == qi * ratio + j) & live)(
-                    functools.partial(crossed, j * block_k))
+        _causal_bodies(qi, ki, block_q, block_k, below, crossed, sel)
     else:
         below()
 
@@ -562,7 +600,7 @@ def _masked(s, off, q_axis):
 
 
 def _walk(causal, q_major, qi, ki, block_q, block_k, tile, add, window=None,
-          t=None, live=None):
+          t=None, sel=None):
     """The walk both backward kernels share over block step ``(qi, ki)``.
     The accumulators' rows lie along the queries (``q_major``: dq) or along
     the keys (dk/dv); the other axis is summed over. ``tile(rows, cols,
@@ -571,8 +609,8 @@ def _walk(causal, q_major, qi, ki, block_q, block_k, tile, add, window=None,
     or :func:`_mask_offset`'s offset); ``add(rows, parts)`` adds a row
     group's sum once. With a ``window`` (of a sequence of ``t``) a block step
     outside the sequence, which a windowed grid's first or last rows hold,
-    runs nothing. ``live`` (a selected call's): whether any pair of the block
-    step is selected; nothing runs where none is."""
+    runs nothing. ``sel`` (a selected call's): nothing runs at a block step
+    none of whose pairs is selected."""
     def extents(sub):
         """(rows, a row group's, columns, a strip's) of the loaded tile."""
         sub_q, sub_k = _sub_tile(block_q, sub[0]), _sub_tile(block_k, sub[1])
@@ -626,18 +664,7 @@ def _walk(causal, q_major, qi, ki, block_q, block_k, tile, add, window=None,
         for at in crossed_at:
             pl.when(here & (first == at))(functools.partial(crossed, at))
         return
-    # Blocks above the diagonal match neither: nothing runs there.
-    ratio = block_q // block_k
-    if live is None:
-        pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k)))(below)
-        for j in range(ratio):
-            pl.when(ki == qi * ratio + j)(
-                functools.partial(crossed, j * block_k))
-        return
-    pl.when(jnp.logical_not(_crossed(qi, ki, block_q, block_k)) & live)(below)
-    for j in range(ratio):
-        pl.when((ki == qi * ratio + j) & live)(
-            functools.partial(crossed, j * block_k))
+    _causal_bodies(qi, ki, block_q, block_k, below, crossed, sel)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -680,8 +707,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def add(rows, parts):
         dq_acc_ref[0, rows, :] = dq_acc_ref[0, rows, :] + parts[0]
 
-    _walk(causal, True, qi, ki, block_q, block_k, tile, add, window, t,
-          None if sel is None else sel.live(qi, ki))
+    _walk(causal, True, qi, ki, block_q, block_k, tile, add, window, t, sel)
 
     @pl.when(step == steps - 1)
     def _finalize():
@@ -730,8 +756,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dk_acc_ref[0, rows, :] = dk_acc_ref[0, rows, :] + parts[0]
         dv_acc_ref[0, rows, :] = dv_acc_ref[0, rows, :] + parts[1]
 
-    _walk(causal, False, qi, ki, block_q, block_k, tile, add, window, t,
-          None if sel is None else sel.live(qi, ki))
+    _walk(causal, False, qi, ki, block_q, block_k, tile, add, window, t, sel)
 
     @pl.when(step == steps - 1)
     def _finalize():
@@ -744,8 +769,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 def _fit_block(t, want, quantum):
     """Largest block <= want that divides t and is a multiple of quantum
     (TPU tiling), or t itself when t <= want. A ceiling below the quantum
-    rounds up to the quantum (a sub-quantum block can never lower on TPU).
-    None when nothing fits."""
+    rounds up to the quantum (a sub-quantum block can never lower on TPU)."""
     if t <= want:
         return t
     want = max(want, quantum)
@@ -908,6 +932,152 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, sm_scale, window):
                      window)
 
 
+def _calls(q, k, v, causal, block_q, block_k, interpret, sm_scale, window,
+           words=None, chunk=None):
+    """(forward, backward), the builders of a call's three ``pallas_call``s
+    at fitted blocks (:func:`_plan`): the grid, block specs, scratch, output
+    shapes and device name of each are stated here and nowhere else. A dense
+    call hands in its operands alone; a ``window`` makes the grids the band's
+    and goes to the kernels; a selection (``words``, ``chunk``) brings each
+    call two scalar-prefetched tables, the words as one more operand and the
+    kernels' ``sel=``. ``forward()`` -> (out, residuals: the operands, out
+    head-major, lse (B * H, 8, T)); ``backward(out, lse, dout)`` -> grads."""
+    b, t, h, d = q.shape
+    h, hkv, group = _gqa_group(q, k, v)
+    dv = v.shape[3]
+    static = dict(block_q=block_q, block_k=block_k, causal=causal,
+                  sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
+    # a row's k blocks and a column's q blocks, as the kernels count them
+    k_steps, q_steps = t // block_k, t // block_q
+    names = FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV      # on the device
+    tables, extra, sel = (), (), None
+    if window is not None:      # those a band can touch; never with a selection
+        names = FLASH_WIN_FWD, FLASH_WIN_BWD_DQ, FLASH_WIN_BWD_DKV
+        static.update(window=window, t=t)
+        k_steps, q_steps = _band_steps(block_q, block_k, window)
+    elif words is not None:
+        names = FLASH_SEL_FWD, FLASH_SEL_BWD_DQ, FLASH_SEL_BWD_DKV
+        tables = _selection_tables(words, block_q, block_k, chunk)
+        extra, sel = (words,), (chunk, q_steps, k_steps)
+    (rows, steps), q_spec, kv_spec, stat_spec, words_spec = _q_major_specs(
+        t, block_q, block_k, (h, hkv, group), causal, window, chunk)
+
+    def kernel(body, n_in, *per_batch, **extent):
+        """``body`` as a ``pallas_call`` runs it: a selected call's is given
+        the two prefetched tables first and the words after the ``n_in``
+        operands, the body's ``sel=`` (``per_batch``: :class:`_Sel`'s own)."""
+        if sel is None:
+            return functools.partial(body, **extent, **static)
+
+        def selected(live_ref, at_ref, *refs):
+            body(*refs[:n_in], *refs[n_in + 1:], **extent, **static,
+                 sel=_Sel(live_ref, refs[n_in], *sel, *per_batch))
+        return selected
+
+    def forward():
+        out, lse = pl.pallas_call(
+            kernel(_fwd_kernel, 3, h, nk=k_steps),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(tables[:2]), grid=(b * h, rows, steps),
+                in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), *words_spec],
+                out_specs=[q_spec(dv), stat_spec],
+                scratch_shapes=[
+                    pltpu.VMEM((1, block_q, dv), jnp.float32),       # acc
+                    pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # m
+                    pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # l
+                ]),
+            out_shape=[
+                jax.ShapeDtypeStruct((b * h, t, dv), q.dtype),
+                jax.ShapeDtypeStruct((b * h, 8, t), jnp.float32),
+            ],
+            interpret=interpret,
+            name=names[0],
+        )(*tables[:2], _rows(q, b, t, h, d), _rows(k, b, t, hkv, d),
+          _rows(v, b, t, hkv, dv), *extra)
+        return _unrows(out, b, t, h, dv), (q, k, v, *extra, out, lse)
+
+    def backward(out, lse, dout):
+        qr, dor = _rows(q, b, t, h, d), _rows(dout, b, t, h, dv)
+        kr, vr = _rows(k, b, t, hkv, d), _rows(v, b, t, hkv, dv)
+        # D_i = rowsum(dO ∘ O): cheap elementwise reduction, done outside;
+        # broadcast to the same (rows, 8, t) sublane layout as lse
+        delta = jnp.sum(dor.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1)
+        delta = jnp.broadcast_to(delta[:, None, :], (b * h, 8, t))
+        dq = pl.pallas_call(
+            kernel(_dq_kernel, 6, h, nk=k_steps),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(tables[:2]), grid=(b * h, rows, steps),
+                in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv),
+                          stat_spec, stat_spec, *words_spec],
+                out_specs=q_spec(d),
+                scratch_shapes=[
+                    pltpu.VMEM((1, block_q, d), jnp.float32),   # dq acc
+                    pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # lse
+                    pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # delta
+                ]),                             # the two by rows
+            out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+            interpret=interpret,
+            name=names[1],
+        )(*tables[:2], qr, kr, vr, dor, lse, delta, *extra)
+
+        # dK/dV: one grid row per KV row; the innermost dim sweeps (g, qi) so
+        # a shared kv head accumulates all of its group's q-head contributions
+        # in scratch before writing out (grid dim 0 = b*hkv, not b*h). Of a
+        # selection's tables it takes the first and the third: the q block to
+        # name, at a step with no selected pair the one already resident.
+        k_rows, sweep, k_block, head, q_block = _k_major_grid(
+            t, block_q, block_k, group, causal, window)
+
+        def held_q(r, p, s, tables):
+            if not tables:
+                return q_block(p, s)
+            return tables[1][((r // hkv) * k_steps + k_block(p, s)) * q_steps
+                             + q_block(p, s)]
+
+        def q_row(r, p, s):
+            return _group_q_row(r, h, hkv, group) + head(p, s)
+
+        def qd(width):
+            return pl.BlockSpec((1, block_q, width), lambda r, p, s, *tables: (
+                q_row(r, p, s), held_q(r, p, s, tables), 0))
+
+        def kd(width):
+            return pl.BlockSpec((1, block_k, width), lambda r, p, s, *tables: (
+                r, k_block(p, s), 0))
+
+        row = pl.BlockSpec((1, 8, block_q), lambda r, p, s, *tables: (
+            q_row(r, p, s), 0, held_q(r, p, s, tables)))
+        # dK/dV's score tiles have their keys down the sublanes: the words too
+        words_t = () if sel is None else (pl.BlockSpec(
+            (1, chunk, block_q), lambda r, p, s, *tables: (
+                r // hkv, _word_group(k_block(p, s), block_k, chunk),
+                held_q(r, p, s, tables))),)
+        dk, dv_rows = pl.pallas_call(
+            kernel(_dkv_kernel, 6, hkv, True, nq=q_steps, group=group),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(tables[::2]),
+                grid=(b * hkv, k_rows, sweep),
+                in_specs=[qd(d), kd(d), kd(dv), qd(dv), row, row, *words_t],
+                out_specs=[kd(d), kd(dv)],
+                scratch_shapes=[
+                    pltpu.VMEM((1, block_k, d), jnp.float32),   # dk acc
+                    pltpu.VMEM((1, block_k, dv), jnp.float32),  # dv acc
+                ]),
+            out_shape=[
+                jax.ShapeDtypeStruct((b * hkv, t, d), k.dtype),
+                jax.ShapeDtypeStruct((b * hkv, t, dv), v.dtype),
+            ],
+            interpret=interpret,
+            name=names[2],
+        )(*tables[::2], qr, kr, vr, dor, lse, delta,
+          *(jnp.swapaxes(w, 1, 2) for w in extra))
+        return (_unrows(dq, b, t, h, d), _unrows(dk, b, t, hkv, d),
+                _unrows(dv_rows, b, t, hkv, dv))
+
+    return forward, backward
+
+
 # The calls are jitted so that the layers of a model, which call them with
 # one signature, share ONE traced and lowered copy of each kernel: the
 # kernels' bodies are unrolled and cost seconds to trace and lower, and a
@@ -915,38 +1085,9 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, sm_scale, window):
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
 def _fwd_call(q, k, v, causal, block_q, block_k, interpret, sm_scale,
               window=None):
-    b, t, h, d = q.shape
-    h, hkv, group = _gqa_group(q, k, v)
-    dv = v.shape[3]
-    block_q, block_k = _check_blocks(t, block_q, block_k, interpret)
-    qr = _rows(q, b, t, h, d)
-    kr, vr = _rows(k, b, t, hkv, d), _rows(v, b, t, hkv, dv)
-    (rows, steps), q_spec, kv_spec, stat_spec = _q_major_specs(
-        t, block_q, block_k, (h, hkv, group), causal, window)
-    banded = {} if window is None else dict(window=window, t=t)
-    kernel = functools.partial(
-        _fwd_kernel, block_q=block_q, block_k=block_k,
-        nk=t // block_k if window is None else steps, causal=causal,
-        sm_scale=d ** -0.5 if sm_scale is None else sm_scale, **banded)
-
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, rows, steps),
-        in_specs=[q_spec(d), kv_spec(d), kv_spec(dv)],
-        out_specs=[q_spec(dv), stat_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, dv), q.dtype),
-            jax.ShapeDtypeStruct((b * h, 8, t), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, block_q, dv), jnp.float32),       # acc
-            pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # m
-            pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # l
-        ],
-        interpret=interpret,
-        name=FLASH_FWD if window is None else FLASH_WIN_FWD,
-    )(qr, kr, vr)
-    return _unrows(out, b, t, h, dv), (q, k, v, out, lse)
+    forward, _ = _calls(q, k, v, causal, block_q, block_k, interpret,
+                        sm_scale, window)
+    return forward()
 
 
 def _bwd(causal, block_q, block_k, interpret, sm_scale, window, res, dout):
@@ -959,137 +1100,15 @@ def _bwd(causal, block_q, block_k, interpret, sm_scale, window, res, dout):
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
 def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, window, res,
               dout):
-    q, k, v, out, lse = res
-    b, t, h, d = q.shape
-    h, hkv, group = _gqa_group(q, k, v)
-    dv = v.shape[3]
-    block_q, block_k = _check_blocks(t, block_q, block_k, interpret)
-    qr, dor = _rows(q, b, t, h, d), _rows(dout, b, t, h, dv)
-    kr, vr = _rows(k, b, t, hkv, d), _rows(v, b, t, hkv, dv)
-    outr = out  # saved in rows layout by _fwd
-    # D_i = rowsum(dO ∘ O): cheap elementwise reduction, done outside;
-    # broadcast to the same (rows, 8, t) sublane layout as lse
-    delta = jnp.sum(dor.astype(jnp.float32) * outr.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[:, None, :], (b * h, 8, t))
-
-    common = dict(block_q=block_q, block_k=block_k, causal=causal,
-                  sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
-    k_steps, q_steps = t // block_k, t // block_q   # a row's blocks, a column's
-    if window is not None:
-        k_steps, q_steps = _band_steps(block_q, block_k, window)
-        common.update(window=window, t=t)
-
-    (rows, steps), q_spec, kv_spec, stat_spec = _q_major_specs(
-        t, block_q, block_k, (h, hkv, group), causal, window)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, nk=k_steps, **common),
-        grid=(b * h, rows, steps),
-        in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv), stat_spec,
-                  stat_spec],
-        out_specs=q_spec(d),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, block_q, d), jnp.float32),        # dq acc
-            pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # lse, by rows
-            pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # delta, by rows
-        ],
-        interpret=interpret,
-        name=FLASH_BWD_DQ if window is None else FLASH_WIN_BWD_DQ,
-    )(qr, kr, vr, dor, lse, delta)
-
-    # dK/dV: one grid row per KV row; the innermost dim sweeps (g, qi) so a
-    # shared kv head accumulates all of its group's q-head contributions in
-    # scratch before writing out (grid dim 0 = b*hkv, not b*h).
-    rows, steps, k_block, head, q_block = _k_major_grid(
-        t, block_q, block_k, group, causal, window)
-
-    def q_row(r, p, s):
-        return _group_q_row(r, h, hkv, group) + head(p, s)
-
-    def qd(width):
-        return pl.BlockSpec((1, block_q, width), lambda r, p, s: (
-            q_row(r, p, s), q_block(p, s), 0))
-
-    def kd(width):
-        return pl.BlockSpec((1, block_k, width), lambda r, p, s: (
-            r, k_block(p, s), 0))
-
-    row = pl.BlockSpec((1, 8, block_q), lambda r, p, s: (
-        q_row(r, p, s), 0, q_block(p, s)))
-    dk, dv_rows = pl.pallas_call(
-        functools.partial(_dkv_kernel, nq=q_steps, group=group, **common),
-        grid=(b * hkv, rows, steps),
-        in_specs=[qd(d), kd(d), kd(dv), qd(dv), row, row],
-        out_specs=[kd(d), kd(dv)],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * hkv, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b * hkv, t, dv), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, block_k, d), jnp.float32),   # dk acc
-            pltpu.VMEM((1, block_k, dv), jnp.float32),  # dv acc
-        ],
-        interpret=interpret,
-        name=FLASH_BWD_DKV if window is None else FLASH_WIN_BWD_DKV,
-    )(qr, kr, vr, dor, lse, delta)
-
-    return (_unrows(dq, b, t, h, d), _unrows(dk, b, t, hkv, d),
-            _unrows(dv_rows, b, t, hkv, dv))
+    _, backward = _calls(*res[:3], causal, block_q, block_k, interpret,
+                         sm_scale, window)
+    return backward(*res[3:], dout)
 
 
 flash_attention.defvjp(_fwd, _bwd)
 
 
 # ------------------------------------------- attention over a selection
-
-def _selection_tables(words, block_q, block_k, chunk):
-    """The scalar-prefetched tables of a selected call, flat int32: (live (B,
-    nq, nk); the k block to hold at each step of a q block's sweep; the q
-    block to hold at each step of a k block's) - a dead step's index maps
-    name the block already resident, so nothing is fetched for it."""
-    from .sparse_attention import block_liveness, fetch_table
-
-    live = block_liveness(words, block_q, block_k, chunk)
-    return (live.astype(jnp.int32).reshape(-1), fetch_table(live).reshape(-1),
-            fetch_table(jnp.swapaxes(live, 1, 2)).reshape(-1))
-
-
-def _word_group(k_block, block_k, chunk):
-    """The group of word columns (32 chunks of keys each) that holds a k
-    block's chunks."""
-    return _div(k_block * block_k, 32 * chunk)
-
-
-def _sel_q_major_specs(t, block_q, block_k, heads, chunk):
-    """:func:`_q_major_specs` of a selected call (causal-dense grids), its
-    index maps taking the two scalar-prefetched tables too: ``kv_spec`` and
-    ``words_spec`` name, at a step whose block holds no selected pair, the
-    k block already resident (the second table: ``fetch_table`` of the
-    liveness). -> ((rows, steps), q_spec, kv_spec, stat_spec, words_spec)."""
-    h, hkv, group = heads
-    nq, nk = t // block_q, t // block_k
-    rows, steps, q_block, k_block = _q_major_grid(t, block_q, block_k, True,
-                                                  None)
-
-    def held_k(r, p, s, k_at):
-        return k_at[((r // h) * nq + q_block(p, s)) * nk + k_block(p, s)]
-
-    def q_spec(width):
-        return pl.BlockSpec((1, block_q, width), lambda r, p, s, live, k_at: (
-            r, q_block(p, s), 0))
-
-    def kv_spec(width):
-        return pl.BlockSpec((1, block_k, width), lambda r, p, s, live, k_at: (
-            _kv_row(r, h, hkv, group), held_k(r, p, s, k_at), 0))
-
-    stat_spec = pl.BlockSpec((1, 8, block_q), lambda r, p, s, live, k_at: (
-        r, 0, q_block(p, s)))
-    words_spec = pl.BlockSpec(
-        (1, block_q, chunk), lambda r, p, s, live, k_at: (
-            r // h, q_block(p, s),
-            _word_group(held_k(r, p, s, k_at), block_k, chunk)))
-    return (rows, steps), q_spec, kv_spec, stat_spec, words_spec
-
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def selected_attention(q, k, v, words, block_q: int | None = None,
@@ -1134,40 +1153,9 @@ def _sel_fwd(q, k, v, words, block_q, block_k, interpret, sm_scale, chunk):
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
 def _sel_fwd_call(q, k, v, words, block_q, block_k, interpret, sm_scale,
                   chunk):
-    b, t, h, d = q.shape
-    h, hkv, group = _gqa_group(q, k, v)
-    dv = v.shape[3]
-    nq, nk = t // block_q, t // block_k
-    live, k_at, _ = _selection_tables(words, block_q, block_k, chunk)
-    (rows, steps), q_spec, kv_spec, stat_spec, words_spec = _sel_q_major_specs(
-        t, block_q, block_k, (h, hkv, group), chunk)
-
-    def kernel(live_ref, k_at_ref, q_ref, k_ref, v_ref, words_ref, *rest):
-        _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_q=block_q,
-                    block_k=block_k, nk=nk, causal=True,
-                    sm_scale=d ** -0.5 if sm_scale is None else sm_scale,
-                    sel=_Sel(live_ref, words_ref, chunk, nq, nk, h))
-
-    out, lse = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b * h, rows, steps),
-            in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), words_spec],
-            out_specs=[q_spec(dv), stat_spec],
-            scratch_shapes=[
-                pltpu.VMEM((1, block_q, dv), jnp.float32),       # acc
-                pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # m
-                pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # l
-            ]),
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, dv), q.dtype),
-            jax.ShapeDtypeStruct((b * h, 8, t), jnp.float32),
-        ],
-        interpret=interpret,
-        name=FLASH_SEL_FWD,
-    )(live, k_at, _rows(q, b, t, h, d), _rows(k, b, t, hkv, d),
-      _rows(v, b, t, hkv, dv), words)
-    return _unrows(out, b, t, h, dv), (q, k, v, words, out, lse)
+    forward, _ = _calls(q, k, v, True, block_q, block_k, interpret, sm_scale,
+                        None, words, chunk)
+    return forward()
 
 
 def _sel_bwd(block_q, block_k, interpret, sm_scale, chunk, res, cotangents):
@@ -1179,97 +1167,9 @@ def _sel_bwd(block_q, block_k, interpret, sm_scale, chunk, res, cotangents):
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
 def _sel_bwd_rule(block_q, block_k, interpret, sm_scale, chunk, res, dout):
-    q, k, v, words, out, lse = res
-    b, t, h, d = q.shape
-    h, hkv, group = _gqa_group(q, k, v)
-    dv = v.shape[3]
-    nq, nk = t // block_q, t // block_k
-    live, k_at, q_at = _selection_tables(words, block_q, block_k, chunk)
-    qr, dor = _rows(q, b, t, h, d), _rows(dout, b, t, h, dv)
-    kr, vr = _rows(k, b, t, hkv, d), _rows(v, b, t, hkv, dv)
-    delta = jnp.sum(dor.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[:, None, :], (b * h, 8, t))
-    common = dict(block_q=block_q, block_k=block_k, causal=True,
-                  sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
-
-    (rows, steps), q_spec, kv_spec, stat_spec, words_spec = _sel_q_major_specs(
-        t, block_q, block_k, (h, hkv, group), chunk)
-
-    def dq_kernel(live_ref, at_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                  delta_ref, words_ref, *rest):
-        _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                   nk=nk, sel=_Sel(live_ref, words_ref, chunk, nq, nk, h),
-                   **common)
-
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b * h, rows, steps),
-            in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv),
-                      stat_spec, stat_spec, words_spec],
-            out_specs=q_spec(d),
-            scratch_shapes=[
-                pltpu.VMEM((1, block_q, d), jnp.float32),        # dq acc
-                pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # lse, by rows
-                pltpu.VMEM((block_q, STAT_LANES), jnp.float32),  # delta, by rows
-            ]),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-        interpret=interpret,
-        name=FLASH_SEL_BWD_DQ,
-    )(live, k_at, qr, kr, vr, dor, lse, delta, words)
-
-    rows, steps, k_block, head, q_block = _k_major_grid(
-        t, block_q, block_k, group, True, None)
-
-    def held_q(r, p, s, at):
-        return at[((r // hkv) * nk + k_block(p, s)) * nq + q_block(p, s)]
-
-    def q_row(r, p, s):
-        return _group_q_row(r, h, hkv, group) + head(p, s)
-
-    def qd(width):
-        return pl.BlockSpec((1, block_q, width), lambda r, p, s, live, at: (
-            q_row(r, p, s), held_q(r, p, s, at), 0))
-
-    def kd(width):
-        return pl.BlockSpec((1, block_k, width), lambda r, p, s, live, at: (
-            r, k_block(p, s), 0))
-
-    row = pl.BlockSpec((1, 8, block_q), lambda r, p, s, live, at: (
-        q_row(r, p, s), 0, held_q(r, p, s, at)))
-    # dK/dV's score tiles have their keys down the sublanes: the words too
-    words_t = pl.BlockSpec(
-        (1, chunk, block_q), lambda r, p, s, live, at: (
-            r // hkv, _word_group(k_block(p, s), block_k, chunk),
-            held_q(r, p, s, at)))
-
-    def dkv_kernel(live_ref, at_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   delta_ref, words_ref, *rest):
-        _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                    nq=nq, group=group,
-                    sel=_Sel(live_ref, words_ref, chunk, nq, nk, hkv, True),
-                    **common)
-
-    dk, dv_rows = pl.pallas_call(
-        dkv_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b * hkv, rows, steps),
-            in_specs=[qd(d), kd(d), kd(dv), qd(dv), row, row, words_t],
-            out_specs=[kd(d), kd(dv)],
-            scratch_shapes=[
-                pltpu.VMEM((1, block_k, d), jnp.float32),   # dk acc
-                pltpu.VMEM((1, block_k, dv), jnp.float32),  # dv acc
-            ]),
-        out_shape=[
-            jax.ShapeDtypeStruct((b * hkv, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b * hkv, t, dv), v.dtype),
-        ],
-        interpret=interpret,
-        name=FLASH_SEL_BWD_DKV,
-    )(live, q_at, qr, kr, vr, dor, lse, delta, jnp.swapaxes(words, 1, 2))
-
-    return (_unrows(dq, b, t, h, d), _unrows(dk, b, t, hkv, d),
-            _unrows(dv_rows, b, t, hkv, dv))
+    _, backward = _calls(*res[:3], True, block_q, block_k, interpret,
+                         sm_scale, None, res[3], chunk)
+    return backward(*res[4:], dout)
 
 
 selected_attention.defvjp(_sel_fwd, _sel_bwd)

@@ -32,7 +32,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass
-from typing import Any, Callable, Optional, Sequence
+from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -265,8 +265,6 @@ def fused_allreduce(
     axis_name: str = HVD_AXIS,
     threshold: int = DEFAULT_FUSION_THRESHOLD,
     op: collectives.ReduceOp = collectives.ReduceOp.AVERAGE,
-    compress: Callable | None = None,
-    decompress: Callable | None = None,
     hierarchical: bool = False,
     ici_axis: str = "ici",
     dcn_axis: str = "dcn",
@@ -276,8 +274,8 @@ def fused_allreduce(
     dcn_compression=None,
     dcn_threshold: Optional[int] = None,
 ):
-    """The Horovod fast path: plan → (compress) → the buckets' collectives in
-    issue order → (decompress).
+    """The Horovod fast path: plan → the buckets' collectives in issue
+    order.
 
     Flat (``hierarchical=False``): a bucket's leaves go to the collective as
     they are, one ``collectives.allreduce`` a leaf, buckets in issue order and
@@ -296,9 +294,7 @@ def fused_allreduce(
     ICI/DCN (reference FP16Compressor semantics, decided per bucket
     instead of per tensor). Eligibility is per bucket, on the bucket's dtype
     and total bytes — see :func:`wire_dtype_for_bucket`; on the flat path the
-    cast pair is applied to each of an eligible bucket's leaves. The legacy
-    ``compress``/``decompress`` callables are still honored for callers that
-    pre-date the wire path (a leaf at a time on the flat path).
+    cast pair is applied to each of an eligible bucket's leaves.
 
     ``num_buckets > 1`` switches to the reverse-backward-order overlap plan
     (build_plan): the collectives are issued last-layer-first, each
@@ -392,8 +388,6 @@ def fused_allreduce(
             buckets = [[jnp.asarray(leaves[d.index]) for d in bucket]
                        for bucket in plan.buckets]
         orig_dtypes = [b[0].dtype for b in buckets]
-        if compress is not None:
-            buckets = [[compress(x) for x in b] for b in buckets]
         # Wire compression (ISSUE 5): per-bucket cast to the 16-bit wire
         # dtype around the collective. Decided at trace time, so the hot
         # path carries exactly one convert pair per array of an eligible
@@ -491,9 +485,6 @@ def fused_allreduce(
     with jax.named_scope(FUSION_UNPACK):
         reduced = [[x.astype(dt) for x in r] if w is not None else r
                    for r, w, dt in zip(reduced, wire, orig_dtypes)]
-        if decompress is not None:
-            reduced = [[decompress(x, dt) for x in r]
-                       for r, dt in zip(reduced, orig_dtypes)]
         if hierarchical:
             return unfuse([r[0] for r in reduced], plan)
         out: list = [None] * plan.treedef.num_leaves

@@ -55,7 +55,7 @@ __all__ = [
     "copy_to_model", "reduce_from_model",
     "column_parallel", "row_parallel", "tp_pair_apply", "tp_apply",
     "dense_pair_apply", "dense_apply", "tp_pair_slices", "tp_local_pairs",
-    "tp_rank_pairs", "tp_wire_bytes_per_pair",
+    "tp_rank_pairs",
 ]
 
 
@@ -247,14 +247,3 @@ def tp_local_pairs(pairs: Sequence[dict], model_size: int) -> list:
 def tp_rank_pairs(pairs: Sequence[dict], model_size: int, rank: int) -> list:
     """One model rank's local pair stack (host side)."""
     return tp_local_pairs(pairs, model_size)[rank]
-
-
-# ------------------------------------------------------------- wire math
-
-
-def tp_wire_bytes_per_pair(batch: int, d_out: int,
-                           dtype=jnp.float32) -> int:
-    """Bytes ONE pair's psum moves per device per step (the activation
-    tensor, at its storage dtype) — the analytic TP wire volume a
-    measured plan is checked against."""
-    return int(batch) * int(d_out) * jnp.dtype(dtype).itemsize

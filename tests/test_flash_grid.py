@@ -1,7 +1,11 @@
 """The causal-dense flash grids hold only live block steps: the folded
 triangle's steps, decoded by the functions the index maps and the kernels use,
 visit every live block exactly once in the parent's order; the grids a call
-really traces; and the gauge that counts the dead steps of that grid."""
+really traces; the gauge that counts the dead steps of that grid; and the one
+trace of each kernel body that a model's layers share."""
+
+import collections
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -225,3 +229,49 @@ def test_dead_step_share_is_left_alone_by_other_calls():
     assert _dead_step_share() == pytest.approx(0.25)
     _trace(4096, None)
     assert _dead_step_share() == 0.0
+
+
+@pytest.mark.parametrize("kind,backward,scale", [
+    ("dense", False, 0.0611), ("dense", True, 0.0612),
+    ("windowed", False, 0.0613), ("windowed", True, 0.0614),
+    ("selected", False, 0.0615), ("selected", True, 0.0616),
+])
+def test_layers_of_one_signature_share_one_trace_of_each_kernel(
+        monkeypatch, kind, backward, scale):
+    """A step is traced and lowered on every start and the kernels' bodies
+    are unrolled: a kernel traced once a LAYER is set-up time (``setup_s``,
+    ``trace_lower_s``). The jitted boundaries hold one copy a signature;
+    ``scale`` is one no other test calls with, so none is held yet."""
+    from horovod_tpu.ops import sparse_attention as dsa
+
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+
+    traced = collections.Counter()
+
+    def counted(name, body):
+        def kernel(*refs, **static):
+            traced[name] += 1
+            return body(*refs, **static)
+        return kernel
+
+    for name in ("_fwd_kernel", "_dq_kernel", "_dkv_kernel"):
+        monkeypatch.setattr(fa, name, counted(name, getattr(fa, name)))
+    t = 64
+    words = dsa.pack(jnp.tril(jnp.ones((1, t, t), bool)), 16)
+
+    def layer(x, k, v):
+        if kind == "selected":
+            return fa.selected_attention(x, k, v, words, 32, 32, True, scale,
+                                         16)[0]
+        return fa.flash_attention(x, k, v, True, 32, 32, True, scale,
+                                  24 if kind == "windowed" else None)
+
+    def two_layers(q, k, v):
+        return jnp.sum(layer(layer(q, k, v), k, v))
+
+    q = jax.ShapeDtypeStruct((1, t, 2, 16), jnp.float32)
+    jax.make_jaxpr(jax.grad(two_layers, argnums=(0, 1, 2)) if backward
+                   else two_layers)(q, q, q)
+    assert traced == {name: 1 for name in (
+        ("_fwd_kernel", "_dq_kernel", "_dkv_kernel") if backward
+        else ("_fwd_kernel",))}
