@@ -140,7 +140,8 @@ def last_wire_plan() -> Optional[tuple]:
 
 def record_flash_plan(live: int, masked: int, bwd_sub_tiles: int,
                       bwd_skipped: int,
-                      grid_steps: Optional[int] = None) -> float:
+                      grid_steps: Optional[int] = None,
+                      shared_key_lanes: int = 0) -> float:
     """Record how the latest traced flash-attention call splits its work
     (trace time, once per compile — same reasoning as record_wire_plan;
     ``ops.flash_attention.block_census`` counts all four). ``live``: block
@@ -155,7 +156,11 @@ def record_flash_plan(live: int, masked: int, bwd_sub_tiles: int,
     third gauge as it was): the steps a head of the forward's grid really
     holds, live or not. The third gauge is the share of them that run
     nothing: 0 where the grid is the folded triangle of an even number of q
-    blocks or one block, ``1 / (nq + 1)`` for an odd number."""
+    blocks or one block, ``1 / (nq + 1)`` for an odd number.
+    ``shared_key_lanes``: the width of the call's shared key part
+    (``flash_attention(k_shared=)``: latent attention's rotary key, read as
+    ONE head through an index map), 0 for a call without one: the fourth
+    gauge."""
     share = (live - masked) / max(1, live)
     registry().gauge(
         "horovod_flash_unmasked_block_share",
@@ -174,6 +179,12 @@ def record_flash_plan(live: int, masked: int, bwd_sub_tiles: int,
             help="share of the grid steps a head of the latest traced "
                  "causal-dense flash forward holds that run nothing"
         ).set((grid_steps - live) / max(1, grid_steps))
+    registry().gauge(
+        "horovod_flash_shared_key_lanes",
+        help="lanes of the key part that ONE head holds for every head of "
+             "the latest traced flash call (latent attention's rotary key); "
+             "0 for a call whose keys are each head's own"
+    ).set(shared_key_lanes)
     return share
 
 

@@ -157,23 +157,62 @@ def _rope_scheme(x, positions, scheme):
             + swapped * widen(jnp.sin(angles), 0.0)).astype(x.dtype)
 
 
-def _rope(x, positions, theta=10000.0, interleave=False):
+def _rope(x, positions, theta=10000.0, interleave=False, lead=0):
     """Rotary position embedding on the last dim (pairs): component i with
     i + half, or with ``interleave`` 2i with 2i + 1, turned by
     ``positions * theta ** (-i / half)``: ``x * cos + swap(x) * sin``
-    (:func:`_swap`) on whole heads, whichever way the pairs lie."""
-    half = x.shape[-1] // 2
+    (:func:`_swap`) on whole heads, whichever way the pairs lie. The first
+    ``lead`` lanes of a head pass (cos 1, sin 0, no pair: latent attention's
+    q, whose rotary lanes trail) and the pairs are the others': the head is
+    neither cut nor put together again, forward or backward
+    (:func:`_turned`)."""
+    half = (x.shape[-1] - lead) // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., T, half]
     cos, sin = jnp.cos(angles)[..., None, :], jnp.sin(angles)[..., None, :]  # add head dim
     i = np.arange(half)
     lo, hi = (2 * i, 2 * i + 1) if interleave else (i, i + half)
-    swapped = _swap(x, lo, hi)
+    if not lead:
+        swapped = _swap(x, lo, hi)
     if interleave:
         cos, sin = (jnp.repeat(t, 2, axis=-1) for t in (cos, sin))
     else:
         cos, sin = (jnp.concatenate([t, t], axis=-1) for t in (cos, sin))
-    return (x * cos + swapped * sin).astype(x.dtype)
+    if not lead:
+        return (x * cos + swapped * sin).astype(x.dtype)
+    cos, sin = (jnp.concatenate([jnp.full(
+        t.shape[:-1] + (lead,), passing, t.dtype), t], axis=-1)
+        for t, passing in ((cos, 1.0), (sin, 0.0)))
+    return _turned(x, cos, sin, (tuple(lead + lo), tuple(lead + hi)))
+
+
+def _turn(x, cos, sin, pairs):
+    return (x * cos + _swap(x, *map(np.asarray, pairs)) * sin).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _turned(x, cos, sin, pairs):
+    """``x * cos + swap(x) * sin`` over whole heads, ``pairs`` the (lo, hi)
+    lanes of :func:`_swap`, whose gradient is the same pass turned the other
+    way, ``g * cos - swap(g) * sin``: the swap is antisymmetric and a pair's
+    two lanes share one sine, so ``(g * sin) @ swap.T`` is that. JAX's own
+    transpose writes ``g * sin`` out in float32 at every lane of the head
+    before the product (6.8 ms a latent layer of kanana2's where this form
+    takes 3.7: PERF.md §6, PR 61) and rounds the turned term to x's dtype
+    twice; this rounds it once. cos and sin carry no gradient."""
+    return _turn(x, cos, sin, pairs)
+
+
+def _turned_fwd(x, cos, sin, pairs):
+    return _turn(x, cos, sin, pairs), (cos, sin)
+
+
+def _turned_bwd(pairs, res, g):
+    cos, sin = res
+    return _turn(g, cos, -sin, pairs), None, None
+
+
+_turned.defvjp(_turned_fwd, _turned_bwd)
 
 
 def gate_form(attn_gate):
@@ -582,12 +621,17 @@ class Block(nn.Module):
         of the normed ``h``, through o_proj: keys and values come out of a
         normed latent of ``kv_rank``, the rotary part of the key is ONE head
         that every query head shares, and q | k have head size ``qk_nope +
-        qk_rope`` where v has ``v``. The shared rotary key is broadcast to the
-        heads where k is assembled (its gradient is summed over them by JAX);
-        a kernel that reads it through an index map, as the grouped-query
-        path reads a shared head, is not built. ``rope=False``
-        (``mla_use_nope``): neither rotary part is turned; the split and the
-        assembling stay."""
+        qk_rope`` where v has ``v``. q, k and v reach the flash kernels in the
+        parts the projections wrote: ``q_proj``'s output, head by head, its
+        rotary lanes turned in place (:func:`_rope`'s ``lead``);
+        ``kv_b_proj``'s output whole, ``[k_nope | v]`` a head
+        (``flash_attention``'s ``v=None``, where ``qk_nope`` is a whole number
+        of ``v``; else split); and the shared rotary key as ONE head,
+        ``k_shared``, which the kernels read through an index map as the
+        grouped-query path reads a shared head and whose gradient they sum
+        over the heads. No part of q alone and no k of ``qk_nope + qk_rope``
+        lanes is built (the dense branch splits kv and assembles k).
+        ``rope=False`` (``mla_use_nope``): neither rotary part is turned."""
         m, heads = self.mla, self.heads
         if self.sp_axis is not None or self.kv_heads not in (None, heads):
             raise ValueError("latent attention is multi-head on one chip: "
@@ -605,27 +649,31 @@ class Block(nn.Module):
                                 name="kv_a_norm")(latent)
             kv = dense(heads * (m.qk_nope + m.v), "kv_b_proj")(latent)
         with jax.named_scope(device_names.MLA_ROPE):
-            q_nope, q_rope = jnp.split(
-                q.reshape(b, t, heads, m.qk_nope + m.qk_rope), [m.qk_nope], axis=-1)
-            k_nope, v = jnp.split(
-                kv.reshape(b, t, heads, m.qk_nope + m.v), [m.qk_nope], axis=-1)
-            if self.rope:
-                q_rope = _rope(q_rope, positions, self.rope_theta,
-                               self.rope_interleave)
+            q = q.reshape(b, t, heads, m.qk_nope + m.qk_rope)
+            kv = kv.reshape(b, t, heads, m.qk_nope + m.v)
             k_rope = k_rope.reshape(b, t, 1, m.qk_rope)
-            if self.rope:
+            if self.rope:   # q's trailing lanes in place; the one key head
+                q = _rope(q, positions, self.rope_theta,
+                          self.rope_interleave, lead=m.qk_nope)
                 k_rope = _rope(k_rope, positions, self.rope_theta,
                                self.rope_interleave)
-            q = jnp.concatenate([q_nope, q_rope], axis=-1)
-            k = jnp.concatenate(
-                [k_nope, jnp.broadcast_to(k_rope, (b, t, heads, m.qk_rope))], axis=-1)
+        if self.attention == "flash" and m.qk_nope % m.v == 0:
+            # [k_nope | v] goes to the kernels as kv_b_proj wrote it: their
+            # index maps read each by its lane block
+            k_nope, v = kv, None
+        else:
+            with jax.named_scope(device_names.MLA_ROPE):
+                k_nope, v = jnp.split(kv, [m.qk_nope], axis=-1)
         if self.attention == "flash":
             from ..ops.flash_attention import flash_attention
 
             # positional: custom_vjp nondiff_argnums
-            attn = flash_attention(q, k, v, True, *self._flash_blocks(),
-                                   self.flash_interpret, self.attention_scale)
+            attn = flash_attention(q, k_nope, v, True, *self._flash_blocks(),
+                                   self.flash_interpret, self.attention_scale,
+                                   None, k_rope)
         else:
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, (b, t, heads, m.qk_rope))], axis=-1)
             attn = causal_attention(q, k, v, scale=self.attention_scale)
         with jax.named_scope(device_names.MLA_PROJ):
             return dense(self.dim, "o_proj")(attn.reshape(b, t, heads * m.v))

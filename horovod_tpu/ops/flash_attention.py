@@ -319,7 +319,10 @@ def _q_major_specs(t, block_q, block_k, heads, causal, window, chunk=None):
     """((rows, steps), q_spec, kv_spec, stat_spec, words_spec) of the
     forward's and dQ's grids (:func:`_q_major_grid`): ``q_spec(width)`` for
     q, o, dO and dq, ``kv_spec(width)`` for k and v at the q row's key/value
-    head (``heads = (h, hkv, group)``), ``stat_spec`` for lse and delta. The
+    head (``heads = (h, hkv, group)``; ``kv_spec(width, (h, 1, h))`` for a key
+    part that ONE head holds for all; ``lanes``: the block of that width
+    along the lanes, where k and v lie side by side in one array),
+    ``stat_spec`` for lse and delta. The
     index maps take a call's scalar-prefetched tables after the grid's
     indices: none, or a selected call's two (``chunk`` given). Then
     ``kv_spec`` and ``words_spec`` (of the q block's packed selection; ``()``
@@ -339,9 +342,9 @@ def _q_major_specs(t, block_q, block_k, heads, causal, window, chunk=None):
         return pl.BlockSpec((1, block_q, width), lambda r, p, s, *tables: (
             r, q_block(p, s), 0))
 
-    def kv_spec(width):
+    def kv_spec(width, kv_heads=(h, hkv, group), lanes=0):
         return pl.BlockSpec((1, block_k, width), lambda r, p, s, *tables: (
-            _kv_row(r, h, hkv, group), held_k(r, p, s, tables), 0))
+            _kv_row(r, *kv_heads), held_k(r, p, s, tables), lanes))
 
     stat_spec = pl.BlockSpec((1, 8, block_q), lambda r, p, s, *tables: (
         r, 0, q_block(p, s)))
@@ -452,9 +455,21 @@ def _causal_bodies(qi, ki, block_q, block_k, below, crossed, sel):
             functools.partial(crossed, j * block_k))
 
 
+def _keys(k_ref, shared, at):
+    """Rows ``at`` of the loaded k block. With a shared part (``shared[0]``:
+    the ONE head's block at the same positions, :func:`flash_attention`'s
+    ``k_shared``) a head's own lanes and the shared ones side by side: joined
+    in registers at the own part's last lane (a multiple of 128 on the TPU:
+    whole registers), so every product that follows is an assembled k's."""
+    k = k_ref[0, at, :]
+    if shared is None:
+        return k
+    return jnp.concatenate([k, shared[0][0, at, :]], axis=1)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                 block_q, block_k, nk, causal, sm_scale, window=None, t=None,
-                sel=None):
+                sel=None, shared=None):
     qi, ki, step, steps = _q_major_step(
         pl.program_id(1), pl.program_id(2), block_q, block_k, nk, causal,
         window)
@@ -480,7 +495,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
         v = v_ref[0, cols, :]
         s = _masked(jax.lax.dot_general(
-            q_ref[0, rows, :], k_ref[0, cols, :], _NT,
+            q_ref[0, rows, :], _keys(k_ref, shared, cols), _NT,
             preferred_element_type=jnp.float32) * sm_scale, off, 0)
         if sel is not None:
             s = sel.keep(s, rows, ki * block_k, c0, sub_k, block_k)
@@ -669,7 +684,8 @@ def _walk(causal, q_major, qi, ki, block_q, block_k, tile, add, window=None,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                dq_acc_ref, lse_rows_ref, delta_rows_ref, *, block_q, block_k,
-               nk, causal, sm_scale, window=None, t=None, sel=None):
+               nk, causal, sm_scale, window=None, t=None, sel=None,
+               shared=None):
     """Query-major: a score sub-tile has its queries down the sublanes, and
     lse and delta are read from lane-replicated scratch, as the forward
     keeps m and l."""
@@ -690,7 +706,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 ref[0, :1, :], (STAT_LANES, block_q)).T
 
     def tile(rows, cols, off):
-        k = k_ref[0, cols, :]
+        k = _keys(k_ref, shared, cols)
         s = _masked(jax.lax.dot_general(
             q_ref[0, rows, :], k, _NT,
             preferred_element_type=jnp.float32) * sm_scale, off, 0)
@@ -716,12 +732,17 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                 dv_ref, dk_acc_ref, dv_acc_ref, *, block_q, block_k, nq,
-                group, causal, sm_scale, window=None, t=None, sel=None):
+                group, causal, sm_scale, window=None, t=None, sel=None,
+                shared=None):
     """Key-major: a score sub-tile is TRANSPOSED, keys down the sublanes and
     queries along the lanes, so the four products are a @ b.T (k . q^T,
     v . dO^T) and a @ b (pT @ dO, dsT @ q), none with a transposed left
     operand, and lse and delta are used as they are stored: along the lanes,
-    broadcast down the sublanes."""
+    broadcast down the sublanes. With a shared key part (``shared``: its
+    block, and the block of its gradient) dk is accumulated whole and written
+    in its two parts: the head's own lanes to ``dk_ref``, the shared lanes'
+    to this head's row of the shared part's gradient, which the call sums
+    over the heads."""
     # The innermost grid dim walks (g, qi): for GQA (group > 1) the same
     # k/v-head block accumulates gradient contributions from every q head
     # in its group — the grid dim 0 row is a KV row, and the sweep runs over
@@ -738,7 +759,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     def tile(rows, cols, off):
         q, do = q_ref[0, cols, :], do_ref[0, cols, :]
         st = _masked(jax.lax.dot_general(
-            k_ref[0, rows, :], q, _NT,
+            _keys(k_ref, shared, rows), q, _NT,
             preferred_element_type=jnp.float32) * sm_scale, off, 1)
         if sel is not None:     # the transposed words: keys down the sublanes
             st = sel.keep(st, cols, ki * block_k, rows.start, rows.size,
@@ -760,7 +781,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 
     @pl.when(step == steps - 1)
     def _finalize():
-        dk_ref[0] = (dk_acc_ref[0] * sm_scale).astype(dk_ref.dtype)
+        dk = (dk_acc_ref[0] * sm_scale).astype(dk_ref.dtype)
+        if shared is None:
+            dk_ref[0] = dk
+        else:
+            own = dk_ref.shape[-1]
+            dk_ref[0], shared[1][0] = dk[:, :own], dk[:, own:]
         dv_ref[0] = dv_acc_ref[0].astype(dv_ref.dtype)
 
 
@@ -826,18 +852,41 @@ def _unrows(x, b, t, h, d):
     return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
 
-def _gqa_group(q, k, v):
+def _gqa_group(q, k, v, shared=None):
     """(h, hkv, group) for grouped-query attention: q has h heads, k/v may
     have fewer (hkv), each shared by a contiguous group of h//hkv q heads
-    (the standard GQA layout). h == hkv is plain multi-head."""
+    (the standard GQA layout). h == hkv is plain multi-head. ``shared``: the
+    key part ONE head holds for all, q's trailing lanes' partner. ``v``
+    None: k's array holds ``[k | v]`` a head (:func:`_kv_widths`)."""
     h, hkv = q.shape[2], k.shape[2]
-    if v.shape[2] != hkv:
+    if v is not None and v.shape[2] != hkv:
         raise ValueError(f"k has {hkv} heads but v has {v.shape[2]}")
     if h % hkv:
         raise ValueError(f"q heads {h} not divisible by kv heads {hkv}")
-    if q.shape[3] != k.shape[3]:
-        raise ValueError(f"q has head size {q.shape[3]} but k has {k.shape[3]}")
+    if shared is not None and shared.shape[:3] != (*k.shape[:2], 1):
+        raise ValueError(f"the shared key part {shared.shape} is not one "
+                         f"head at k's {k.shape[:2]} positions")
+    _kv_widths(q, k, v, shared)
     return h, hkv, h // hkv
+
+
+def _kv_widths(q, k, v, shared=None):
+    """(dn, dv): the lanes of a key that a head owns (q's, less a shared
+    part's) and v's. With ``v`` None k's array is ``[k | v]`` side by side a
+    head, as ONE projection wrote them, and v is what follows k's ``dn``
+    lanes: the index maps then read the two out of the one array by lane
+    block, so ``dn`` must be a whole number of v's widths."""
+    dn = q.shape[3] - (0 if shared is None else shared.shape[3])
+    if v is not None:
+        if k.shape[3] != dn:
+            raise ValueError(f"q has head size {q.shape[3]} but k has "
+                             f"{k.shape[3] + q.shape[3] - dn}")
+        return dn, v.shape[3]
+    dv = k.shape[3] - dn
+    if dv < 1 or dn % dv:
+        raise ValueError(f"[k | v] of {k.shape[3]} lanes a head is no k of "
+                         f"{dn} and a v that divides it")
+    return dn, dv
 
 
 def _kv_row(r, h, hkv, group):
@@ -882,7 +931,8 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_k: int | None = None,
                     interpret: bool = False,
                     sm_scale: float | None = None,
-                    window: int | None = None):
+                    window: int | None = None,
+                    k_shared=None):
     """Fused attention, trainable. q: ``(B, T, H, D)``, k/v: ``(B, T, H, D)``
     or ``(B, T, Hkv, D)`` with ``H % Hkv == 0`` for grouped-query attention
     (each kv head serves a contiguous group of q heads — no head
@@ -892,6 +942,21 @@ def flash_attention(q, k, v, causal: bool = True,
     follow v, dq and dk follow q; the default scale is q's ``D ** -0.5``.
     No width crosses into the kernels without a copy: every operand and
     result is relaid head-major round the call (:func:`_rows`).
+    ``k_shared`` ``(B, T, 1, Dr)`` (latent attention's rotary key): the part
+    of every head's key that ONE head holds for all. ``k`` is then the heads'
+    own ``D - Dr`` leading lanes, the score of a pair is ``q[:D - Dr] . k +
+    q[D - Dr:] . k_shared``, and no k of ``D`` lanes a head exists outside the
+    kernels: their index maps read the one head's block for every row, as the
+    grouped-query path reads a shared head, and dK/dV writes that part's
+    gradient a head, summed over the heads here. It is one more
+    differentiable operand; not with a ``window``. On the TPU ``D - Dr`` is a
+    multiple of 128.
+    ``v=None``: ``k`` holds ``[k | v]`` side by side a head, ``(B, T, Hkv, Dk
+    + Dv)`` as ONE projection wrote them (latent attention's ``kv_b_proj``),
+    ``Dk`` a whole number of ``Dv`` (on the TPU both multiples of 128). The
+    index maps read k and v out of the one head-major copy by lane block,
+    dK/dV writes ``[dk | dv]`` the same way, and ``k``'s gradient is that
+    array: no split before the call and no concatenation after it.
     Sequence length must be a multiple of
     ``block_q`` and ``block_q`` of ``block_k`` (both clamp down to the
     sequence length for short inputs; None: ``DEFAULT_BLOCK_Q`` /
@@ -911,40 +976,51 @@ def flash_attention(q, k, v, causal: bool = True,
     tests); the default compiles them for the TPU and raises on a machine
     that has none."""
     out, _ = _fwd(q, k, v, causal, block_q, block_k, interpret, sm_scale,
-                  window)
+                  window, k_shared)
     return out
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret, sm_scale, window):
+def _fwd(q, k, v, causal, block_q, block_k, interpret, sm_scale, window,
+         k_shared=None):
     from ..metrics import record_flash_plan, record_flash_window_plan
     if window is not None and (not causal or window < 1):
         raise ValueError(f"window={window} needs causal=True and window >= 1")
+    if window is not None and k_shared is not None:
+        raise ValueError("a shared key part under a window is not built")
     t = q.shape[1]
     block_q, block_k, window = _plan(t, block_q, block_k, interpret, window)
     census = block_census(t, block_q, block_k, causal, window)
     rows, steps = _q_major_grid(t, block_q, block_k, causal, window)[:2]
     record_flash_plan(*census, grid_steps=(
-        rows * steps if causal and window is None else None))
+        rows * steps if causal and window is None else None),
+        shared_key_lanes=0 if k_shared is None else k_shared.shape[3])
     if window is not None:
         record_flash_window_plan(
             census[0], block_census(t, block_q, block_k, causal)[0])
     return _fwd_call(q, k, v, causal, block_q, block_k, interpret, sm_scale,
-                     window)
+                     window, k_shared)
 
 
 def _calls(q, k, v, causal, block_q, block_k, interpret, sm_scale, window,
-           words=None, chunk=None):
+           words=None, chunk=None, shared=None):
     """(forward, backward), the builders of a call's three ``pallas_call``s
     at fitted blocks (:func:`_plan`): the grid, block specs, scratch, output
     shapes and device name of each are stated here and nowhere else. A dense
     call hands in its operands alone; a ``window`` makes the grids the band's
     and goes to the kernels; a selection (``words``, ``chunk``) brings each
     call two scalar-prefetched tables, the words as one more operand and the
-    kernels' ``sel=``. ``forward()`` -> (out, residuals: the operands, out
-    head-major, lse (B * H, 8, T)); ``backward(out, lse, dout)`` -> grads."""
+    kernels' ``sel=``; a ``shared`` key part (a dense call's alone) is one
+    more operand of each call behind k's own index map, the kernels'
+    ``shared=``, and one more output of dK/dV's; ``v`` None makes k's array
+    both the k and the v operand, each a lane block of it, and dK/dV's two
+    results one. ``forward()`` -> (out, residuals: the operands, out
+    head-major, lse (B * H, 8, T)); ``backward(out, lse, dout)`` -> grads,
+    None for a v that k's array holds, the shared part's last."""
     b, t, h, d = q.shape
-    h, hkv, group = _gqa_group(q, k, v)
-    dv = v.shape[3]
+    h, hkv, group = _gqa_group(q, k, v, shared)
+    dn, dv = _kv_widths(q, k, v, shared)    # dn: the lanes a head's key owns
+    fused = v is None       # [k | v] in one array: v the lane block after k's
+    dkv, v_lanes = k.shape[3], dn // dv if fused else 0
     static = dict(block_q=block_q, block_k=block_k, causal=causal,
                   sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
     # a row's k blocks and a column's q blocks, as the kernels count them
@@ -959,13 +1035,27 @@ def _calls(q, k, v, causal, block_q, block_k, interpret, sm_scale, window,
         names = FLASH_SEL_FWD, FLASH_SEL_BWD_DQ, FLASH_SEL_BWD_DKV
         tables = _selection_tables(words, block_q, block_k, chunk)
         extra, sel = (words,), (chunk, q_steps, k_steps)
-    (rows, steps), q_spec, kv_spec, stat_spec, words_spec = _q_major_specs(
+    (rows, steps), q_spec, kv_spec, stat_spec, extra_spec = _q_major_specs(
         t, block_q, block_k, (h, hkv, group), causal, window, chunk)
+    if shared is not None:      # one head: head-major as it lies
+        extra = (shared.reshape(b, t, d - dn),)
+        extra_spec = (kv_spec(d - dn, (h, 1, h)),)
 
-    def kernel(body, n_in, *per_batch, **extent):
+    def kernel(body, n_in, *per_batch, grad_at=None, **extent):
         """``body`` as a ``pallas_call`` runs it: a selected call's is given
         the two prefetched tables first and the words after the ``n_in``
-        operands, the body's ``sel=`` (``per_batch``: :class:`_Sel`'s own)."""
+        operands, the body's ``sel=`` (``per_batch``: :class:`_Sel`'s own);
+        a call with a shared key part hands that operand's ref, which follows
+        the ``n_in`` too, to the body's ``shared=``, and with it the ref of
+        its gradient (dK/dV's: the body's ``grad_at``-th without it)."""
+        if shared is not None:
+            def with_shared(*refs):
+                refs = list(refs)
+                part = (refs.pop(n_in),)
+                if grad_at is not None:
+                    part += (refs.pop(grad_at),)
+                body(*refs, **extent, **static, shared=part)
+            return with_shared
         if sel is None:
             return functools.partial(body, **extent, **static)
 
@@ -974,12 +1064,17 @@ def _calls(q, k, v, causal, block_q, block_k, interpret, sm_scale, window,
                  sel=_Sel(live_ref, refs[n_in], *sel, *per_batch))
         return selected
 
+    def kv_rows():
+        kr = _rows(k, b, t, hkv, dkv)
+        return kr, kr if fused else _rows(v, b, t, hkv, dv)
+
     def forward():
         out, lse = pl.pallas_call(
             kernel(_fwd_kernel, 3, h, nk=k_steps),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(tables[:2]), grid=(b * h, rows, steps),
-                in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), *words_spec],
+                in_specs=[q_spec(d), kv_spec(dn), kv_spec(dv, lanes=v_lanes),
+                          *extra_spec],
                 out_specs=[q_spec(dv), stat_spec],
                 scratch_shapes=[
                     pltpu.VMEM((1, block_q, dv), jnp.float32),       # acc
@@ -992,13 +1087,13 @@ def _calls(q, k, v, causal, block_q, block_k, interpret, sm_scale, window,
             ],
             interpret=interpret,
             name=names[0],
-        )(*tables[:2], _rows(q, b, t, h, d), _rows(k, b, t, hkv, d),
-          _rows(v, b, t, hkv, dv), *extra)
-        return _unrows(out, b, t, h, dv), (q, k, v, *extra, out, lse)
+        )(*tables[:2], _rows(q, b, t, h, d), *kv_rows(), *extra)
+        return _unrows(out, b, t, h, dv), (
+            q, k, v, *(extra if shared is None else (shared,)), out, lse)
 
     def backward(out, lse, dout):
         qr, dor = _rows(q, b, t, h, d), _rows(dout, b, t, h, dv)
-        kr, vr = _rows(k, b, t, hkv, d), _rows(v, b, t, hkv, dv)
+        kr, vr = kv_rows()
         # D_i = rowsum(dO ∘ O): cheap elementwise reduction, done outside;
         # broadcast to the same (rows, 8, t) sublane layout as lse
         delta = jnp.sum(dor.astype(jnp.float32) * out.astype(jnp.float32),
@@ -1008,8 +1103,8 @@ def _calls(q, k, v, causal, block_q, block_k, interpret, sm_scale, window,
             kernel(_dq_kernel, 6, h, nk=k_steps),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(tables[:2]), grid=(b * h, rows, steps),
-                in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv),
-                          stat_spec, stat_spec, *words_spec],
+                in_specs=[q_spec(d), kv_spec(dn), kv_spec(dv, lanes=v_lanes),
+                          q_spec(dv), stat_spec, stat_spec, *extra_spec],
                 out_specs=q_spec(d),
                 scratch_shapes=[
                     pltpu.VMEM((1, block_q, d), jnp.float32),   # dq acc
@@ -1042,38 +1137,66 @@ def _calls(q, k, v, causal, block_q, block_k, interpret, sm_scale, window,
             return pl.BlockSpec((1, block_q, width), lambda r, p, s, *tables: (
                 q_row(r, p, s), held_q(r, p, s, tables), 0))
 
-        def kd(width):
+        def kd(width, lanes=0):
             return pl.BlockSpec((1, block_k, width), lambda r, p, s, *tables: (
-                r, k_block(p, s), 0))
+                r, k_block(p, s), lanes))
 
         row = pl.BlockSpec((1, 8, block_q), lambda r, p, s, *tables: (
             q_row(r, p, s), 0, held_q(r, p, s, tables)))
         # dK/dV's score tiles have their keys down the sublanes: the words too
-        words_t = () if sel is None else (pl.BlockSpec(
-            (1, chunk, block_q), lambda r, p, s, *tables: (
-                r // hkv, _word_group(k_block(p, s), block_k, chunk),
-                held_q(r, p, s, tables))),)
-        dk, dv_rows = pl.pallas_call(
-            kernel(_dkv_kernel, 6, hkv, True, nq=q_steps, group=group),
+        extra_t, spec_t, grad_spec, grad_shape = (), (), (), ()
+        if sel is not None:
+            extra_t = (jnp.swapaxes(words, 1, 2),)
+            spec_t = (pl.BlockSpec(
+                (1, chunk, block_q), lambda r, p, s, *tables: (
+                    r // hkv, _word_group(k_block(p, s), block_k, chunk),
+                    held_q(r, p, s, tables))),)
+        if shared is not None:
+            # the one head's block in, a head's share of its gradient out
+            extra_t = extra
+            spec_t = (pl.BlockSpec(
+                (1, block_k, d - dn), lambda r, p, s, *tables: (
+                    r // hkv, k_block(p, s), 0)),)
+            grad_spec = (kd(d - dn),)
+            grad_shape = (jax.ShapeDtypeStruct((b * hkv, t, d - dn), k.dtype),)
+        body, widths = _dkv_kernel, (dn, dv)
+        if fused:
+            # ONE result a kv row, [dk | dv] as the operand lies: the body
+            # writes each through a view of its lanes
+            widths = (dkv,)
+
+            def body(*refs, **kw):
+                _dkv_kernel(*refs[:6], refs[6].at[:, :, :dn],
+                            refs[6].at[:, :, dn:], *refs[7:], **kw)
+        results = pl.pallas_call(
+            kernel(body, 6, hkv, True, grad_at=6 + len(widths), nq=q_steps,
+                   group=group),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(tables[::2]),
                 grid=(b * hkv, k_rows, sweep),
-                in_specs=[qd(d), kd(d), kd(dv), qd(dv), row, row, *words_t],
-                out_specs=[kd(d), kd(dv)],
+                in_specs=[qd(d), kd(dn), kd(dv, v_lanes), qd(dv), row, row,
+                          *spec_t],
+                out_specs=[*map(kd, widths), *grad_spec],
                 scratch_shapes=[
                     pltpu.VMEM((1, block_k, d), jnp.float32),   # dk acc
                     pltpu.VMEM((1, block_k, dv), jnp.float32),  # dv acc
                 ]),
             out_shape=[
-                jax.ShapeDtypeStruct((b * hkv, t, d), k.dtype),
-                jax.ShapeDtypeStruct((b * hkv, t, dv), v.dtype),
+                *(jax.ShapeDtypeStruct((b * hkv, t, width), x.dtype)
+                  for width, x in zip(widths, (k, v))),
+                *grad_shape,
             ],
             interpret=interpret,
             name=names[2],
-        )(*tables[::2], qr, kr, vr, dor, lse, delta,
-          *(jnp.swapaxes(w, 1, 2) for w in extra))
-        return (_unrows(dq, b, t, h, d), _unrows(dk, b, t, hkv, d),
-                _unrows(dv_rows, b, t, hkv, dv))
+        )(*tables[::2], qr, kr, vr, dor, lse, delta, *extra_t)
+        by_head = results[len(widths):]
+        return (_unrows(dq, b, t, h, d),
+                *(_unrows(x, b, t, hkv, width)
+                  for x, width in zip(results, widths)),
+                *((None,) if fused else ()),
+                *(jnp.sum(x.reshape(b, hkv, t, 1, d - dn), axis=1,
+                          dtype=jnp.float32).astype(shared.dtype)
+                  for x in by_head))
 
     return forward, backward
 
@@ -1084,25 +1207,28 @@ def _calls(q, k, v, causal, block_q, block_k, interpret, sm_scale, window,
 # step is traced and lowered on every start, warm or cold.
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
 def _fwd_call(q, k, v, causal, block_q, block_k, interpret, sm_scale,
-              window=None):
+              window=None, shared=None):
     forward, _ = _calls(q, k, v, causal, block_q, block_k, interpret,
-                        sm_scale, window)
+                        sm_scale, window, shared=shared)
     return forward()
 
 
 def _bwd(causal, block_q, block_k, interpret, sm_scale, window, res, dout):
     block_q, block_k, window = _plan(res[0].shape[1], block_q, block_k,
                                      interpret, window)
-    return _bwd_rule(causal, block_q, block_k, interpret, sm_scale, window,
-                     res, dout)
+    grads = _bwd_rule(causal, block_q, block_k, interpret, sm_scale, window,
+                      res, dout)
+    return (*grads, None)[:4]       # a call without a shared part: None
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
 def _bwd_rule(causal, block_q, block_k, interpret, sm_scale, window, res,
               dout):
-    _, backward = _calls(*res[:3], causal, block_q, block_k, interpret,
-                         sm_scale, window)
-    return backward(*res[3:], dout)
+    q, k, v, *shared, out, lse = res
+    _, backward = _calls(q, k, v, causal, block_q, block_k, interpret,
+                         sm_scale, window,
+                         shared=shared[0] if shared else None)
+    return backward(out, lse, dout)
 
 
 flash_attention.defvjp(_fwd, _bwd)

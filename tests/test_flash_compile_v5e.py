@@ -272,6 +272,85 @@ def test_latent_attention_kernels_at_192_128_compile_for_v5e(
         assert out.shape == (MLA["b"], MLA["t"], MLA["heads"], MLA["d_v"])
 
 
+# Since PR 61 the cell's layers hand q and k over in the parts the projections
+# wrote: q whole, ``[k_nope | v]`` whole as ``kv_b_proj`` wrote it (``v=None``:
+# the index maps read each by its lane block of 128 and dK/dV writes ``[dk |
+# dv]`` the same way), and the rotary 64 lanes that ONE head holds for all 32
+# (``k_shared``: the kernels join the two in registers at lane 128). The
+# step's shape, and the check's float32 leg under ``highest``; k and v in one
+# array, and apart.
+@pytest.mark.parametrize("kv_in_one", [True, False], ids=["kv", "k_v"])
+@pytest.mark.parametrize("b,t,dtype,precision,blocks", [
+    (MLA["b"], MLA["t"], jnp.bfloat16, None, (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)),
+    (1, 2048, jnp.float32, "highest", (512, 512)),
+], ids=["step", "float32_leg"])
+def test_latent_attention_kernels_with_a_shared_key_compile_for_v5e(
+        one_chip, no_persistent_cache, b, t, dtype, precision, blocks,
+        kv_in_one):
+    own = MLA["d_v"]            # 128 | 64: q's 192 = the own lanes + the shared
+
+    def grads(*operands):
+        def loss(q, k_nope, v, k_shared):
+            return jnp.sum(flash_attention(
+                q, k_nope, v, True, *blocks, False, None, None,
+                k_shared).astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 3) if kv_in_one else (0, 1, 2, 3))(
+            *operands)
+
+    def shape(heads, width):
+        return jax.ShapeDtypeStruct((b, t, heads, width), dtype,
+                                    sharding=one_chip)
+
+    operands = (shape(MLA["heads"], MLA["d_qk"]),
+                shape(MLA["heads"], own + MLA["d_v"] * kv_in_one),
+                None if kv_in_one else shape(MLA["heads"], MLA["d_v"]),
+                shape(1, MLA["d_qk"] - own))
+    with jax.default_matmul_precision(precision or "default"):
+        compiled = jax.jit(grads).lower(*operands).compile()
+    text = compiled.as_text()
+    for name in (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV):
+        assert name in text, f"{name} is not in the compiled module"
+    assert [x.shape for x in jax.tree_util.tree_leaves(
+        jax.eval_shape(grads, *operands))] == [
+            x.shape for x in operands if x is not None]
+
+
+# What a call WITHOUT a shared key part lowers to, forward and backward, at
+# (1, 2048, heads, width | 128) and the default blocks in bf16: the sha256 of
+# the lowered text with the kernels' bodies re-printed without locations
+# (``tools/lowered_step_sha.without_locations``), read on the tree BEFORE
+# PR 61 gave the kernels their ``shared=``. A PR that changes what the dense
+# kernels lower to on purpose re-reads them (print ``digest`` below).
+DENSE_LOWERINGS = {
+    # grouped-query 4 over 2 at 128: the older cells' kind of call
+    (4, 2, 128): "0a4ec69a9513df7b83690a6a40b234a3cb73e0890305e36940d00cb36fb31fb6",
+    # 192 | 128 with an ASSEMBLED k: what latent attention called until PR 61
+    (2, 2, 192): "6bc0d7b813d21e19df22ff13d746f3961dc1f8a1be1380f318355090ef998aa9",
+}
+
+
+@pytest.mark.parametrize("heads,kv_heads,width", sorted(DENSE_LOWERINGS))
+def test_a_call_without_a_shared_key_lowers_to_what_it_did(
+        one_chip, no_persistent_cache, heads, kv_heads, width):
+    import hashlib
+
+    from tools.lowered_step_sha import without_locations
+
+    def grads(q, k, v):     # the digests hold this function's name
+        return jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, True, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    def shape(h, d):
+        return jax.ShapeDtypeStruct((1, 2048, h, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    text = jax.jit(grads).lower(shape(heads, width), shape(kv_heads, width),
+                                shape(kv_heads, 128)).as_text()
+    digest = hashlib.sha256(without_locations(text).encode()).hexdigest()
+    assert digest == DENSE_LOWERINGS[heads, kv_heads, width]
+
+
 # float32 operands stay float32 in all three kernels and are traced under
 # ``highest``: the kanana2 check's float32 leg (512 / 512 blocks, the row's
 # first 2,048 tokens, 192 | 128), and the DEFAULT blocks at 16 heads x 4096 x

@@ -133,12 +133,15 @@ def test_dkv_grid_holds_each_live_block_once(nq, ratio, group):
     assert _contiguous(mk[:, None])
 
 
-def _traced_grids(causal, block_q, block_k, window, t=128, h=2, hkv=2):
-    """{kernel name: grid} of a forward + backward trace."""
+def _traced_grids(causal, block_q, block_k, window, t=128, h=2, hkv=2,
+                  shared=0):
+    """{kernel name: grid} of a forward + backward trace; ``shared``: that
+    many of k's 16 lanes are ONE head's for all (``k_shared``)."""
     q = jax.ShapeDtypeStruct((1, t, h, 16), jnp.float32)
     k = jax.ShapeDtypeStruct((1, t, hkv, 16), jnp.float32)
-    jaxpr = jax.make_jaxpr(jax.grad(lambda *x: jnp.sum(flash_attention(
-        *x, causal, block_q, block_k, True, None, window)),
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k[..., :16 - shared], v, causal, block_q, block_k, True, None,
+        window, k[:, :, :1, 16 - shared:] if shared else None)),
         argnums=(0, 1, 2)))(q, k, k)
     grids = {}
 
@@ -155,6 +158,15 @@ def _traced_grids(causal, block_q, block_k, window, t=128, h=2, hkv=2):
 
     walk(jaxpr.jaxpr)
     return grids
+
+
+def test_a_shared_key_part_leaves_the_grids_and_names_what_they_are():
+    """The shared part is one more operand behind k's own index map: the
+    three grids and the kernels' device names are the dense call's."""
+    for causal, block_q, block_k in ((True, 32, 16), (True, 128, 128),
+                                     (False, 32, 16)):
+        assert (_traced_grids(causal, block_q, block_k, None, shared=4)
+                == _traced_grids(causal, block_q, block_k, None))
 
 
 def test_windowed_and_non_causal_calls_trace_the_grids_they_had():
@@ -235,6 +247,8 @@ def test_dead_step_share_is_left_alone_by_other_calls():
     ("dense", False, 0.0611), ("dense", True, 0.0612),
     ("windowed", False, 0.0613), ("windowed", True, 0.0614),
     ("selected", False, 0.0615), ("selected", True, 0.0616),
+    ("shared_key", False, 0.0617), ("shared_key", True, 0.0618),
+    ("kv_in_one", False, 0.0619), ("kv_in_one", True, 0.0620),
 ])
 def test_layers_of_one_signature_share_one_trace_of_each_kernel(
         monkeypatch, kind, backward, scale):
@@ -263,6 +277,14 @@ def test_layers_of_one_signature_share_one_trace_of_each_kernel(
         if kind == "selected":
             return fa.selected_attention(x, k, v, words, 32, 32, True, scale,
                                          16)[0]
+        if kind == "shared_key":    # k in two parts, one of them ONE head's
+            return fa.flash_attention(x, k[..., :12], v, True, 32, 32, True,
+                                      scale, None, k[:, :, :1, 12:])
+        if kind == "kv_in_one":     # latent attention's call: [k | v] whole too
+            out = fa.flash_attention(
+                x, jnp.concatenate([k[..., :8], v[..., :8]], axis=-1), None,
+                True, 32, 32, True, scale, None, k[:, :, :1, 8:])
+            return jnp.concatenate([out, out], axis=-1)     # q's 16 lanes
         return fa.flash_attention(x, k, v, True, 32, 32, True, scale,
                                   24 if kind == "windowed" else None)
 
