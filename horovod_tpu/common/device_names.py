@@ -92,6 +92,16 @@ KDA_GATE = "hvd_kda_gate"               # L2 norms, softplus, the log-decay, bet
 KDA_SCAN = "hvd_kda_scan"               # the chunked delta rule, forward and backward
 KDA_OUT_NORM = "hvd_kda_out_norm"       # the head-wise RMSNorm, then the sigmoid gate
 
+# The gated short-convolution mixer (models/short_conv.py; LFM2's ``conv``
+# layers). The benchmark finds the mixer's time by the names that start with
+# ``hvd_sconv``.
+SCONV_PROJ = "hvd_sconv_proj"           # the input (dim -> 3 dim) and output projections
+SCONV_CONV = "hvd_sconv_conv"           # C * conv(B * x): the two products and the taps
+# ops/mamba_fused.py's kernels of that pass, for the shapes they tile; the
+# scope above stays on the jax.numpy form of every other shape.
+SCONV_CONV_FWD = "hvd_sconv_conv_fwd"
+SCONV_CONV_BWD = "hvd_sconv_conv_bwd"
+
 # Learned sparse attention (ops/sparse_attention.py, ``Block.sparse``). The
 # benchmark finds each part's time by the scope's name and its kernel's.
 DSA_INDEXER = "hvd_dsa_indexer"         # the indexer's projections, norm, rotary

@@ -49,6 +49,7 @@ from .overlap import (  # noqa: F401
     record_moe_live_rows,
     record_plan,
     record_shard_plan,
+    record_short_conv_plan,
     record_ssd_plan,
     record_sharded_state_bytes,
     record_tier_plan,

@@ -425,6 +425,30 @@ def record_kda_beta_range(upper: int) -> None:
     ).set(upper)
 
 
+def record_short_conv_plan(taps: int, kernel: bool) -> None:
+    """Record the taps of the latest traced gated short convolution
+    (``models.short_conv.ShortConvMixer``; trace time, once per compile, from
+    the call's own shapes: ``horovod_kda_chunk_len``'s rule). 0 until such a
+    mixer is traced. ``kernel``: whether its shapes took the pass's pallas
+    kernels; ``horovod_short_conv_kernel_passes`` counts the traced passes
+    that did since the latest one that kept ``jax.numpy``, which sets it back
+    to 0 (``horovod_kda_kernel_scans``' rule)."""
+    registry().gauge(
+        "horovod_short_conv_taps",
+        help="taps of the latest traced gated short convolution "
+             "(models/short_conv.py); 0 = none traced"
+    ).set(taps)
+    passes = registry().gauge(
+        "horovod_short_conv_kernel_passes",
+        help="traced gated short convolutions whose shapes took the pass's "
+             "kernels (hvd_sconv_conv_fwd / _bwd) since the latest one that "
+             "kept jax.numpy; 0 = none traced, or the latest kept jax.numpy")
+    if kernel:
+        passes.inc()
+    else:
+        passes.set(0)
+
+
 def record_attn_gate_width(width: int) -> None:
     """Record how many gate values a token the latest traced gated softmax
     attention (``models.transformer.Block.attn_gate``) multiplies its output

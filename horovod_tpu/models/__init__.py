@@ -13,6 +13,7 @@ from .pipeline_lm import (  # noqa: F401
     pipeline_lm_loss_and_grads,
     split_lm_params,
 )
+from .short_conv import ShortConvDims, ShortConvMixer  # noqa: F401
 from .transformer import (LatentDims, RotaryScheme, SparseDims,  # noqa: F401
                           TransformerLM, align_losses)
 from .vgg import VGG, VGG16, VGG19  # noqa: F401

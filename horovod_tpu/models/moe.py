@@ -83,6 +83,9 @@ class MoEMLP(nn.Module):
     # equations): experts without a gate, in a latent of this width.
     activation: str = "swiglu"      # "swiglu" | "relu2"
     latent: int = 0
+    # What an LFM2 configuration's released code states: the epsilon under
+    # the sigmoid router's chosen scores (``ops.moe.sigmoid_route``).
+    route_eps: float = 1e-20
 
     @nn.compact
     def __call__(self, x):
@@ -142,8 +145,11 @@ class MoEMLP(nn.Module):
         if self.router == "sigmoid":
             bias = self.variable(BIAS_COLLECTION, "router_bias", jnp.zeros,
                                  (e,), jnp.float32).value
+            # the epsilon only where one is stated: the benchmark's tests swap
+            # this function for variants of today's four arguments
+            stated = () if self.route_eps == 1e-20 else (self.route_eps,)
             _, weights, experts = sigmoid_route(logits, bias, self.top_k,
-                                                self.route_scale)
+                                                self.route_scale, *stated)
             counts = _expert_counts(experts.reshape(-1), e)
             self.sow("intermediates", "moe_expert_counts", counts)
             self.sow("intermediates", "moe_live_rows",

@@ -29,6 +29,7 @@ from ..ops.moe import CHOSEN_EXPERTS
 from ..ops.sparse_attention import ALIGN_GRADS, SELECTED
 from .kda import KDADims, KDAMixer
 from .mamba import Mamba2Dims, Mamba2Mixer
+from .short_conv import ShortConvDims, ShortConvMixer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -315,6 +316,11 @@ class Block(nn.Module):
     # TransformerLM documents it): a Kimi Delta Attention mixer in
     # attention's place.
     kda: Optional[KDADims] = None
+    # What a short-convolution hybrid's configuration states (LFM2:
+    # TransformerLM documents them): a gated short-convolution mixer in
+    # attention's place; the epsilon under the sigmoid router's chosen scores.
+    conv: Optional[ShortConvDims] = None
+    moe_route_eps: float = 1e-20
 
     @nn.compact
     def __call__(self, x, positions):
@@ -340,7 +346,8 @@ class Block(nn.Module):
                 route_scale=self.moe_route_scale,
                 shared_hidden=self.moe_shared_hidden, held=self.moe_held,
                 activation=self.moe_activation, latent=self.moe_latent,
-                norm_topk=self.moe_norm_topk, name="moe")(h))
+                norm_topk=self.moe_norm_topk, route_eps=self.moe_route_eps,
+                name="moe")(h))
 
         def dense(width, name):
             return nn.Dense(width, use_bias=False, dtype=self.dtype, name=name)
@@ -365,7 +372,8 @@ class Block(nn.Module):
                             "moe_experts": self.moe_experts > 0},
                   "mlp": {"mamba": self.mamba is not None,
                           "mla": self.mla is not None,
-                          "kda": self.kda is not None},
+                          "kda": self.kda is not None,
+                          "conv": self.conv is not None},
                   "both": {}}[self.sublayers]
         stated["moe_shared_hidden"] = (self.moe_shared_hidden > 0
                                        and self.moe_experts <= 0)
@@ -375,8 +383,11 @@ class Block(nn.Module):
                 f"{', '.join(extra)} stated for a layer (sublayers="
                 f"{self.sublayers!r}, moe_experts={self.moe_experts}) that "
                 f"has no such half")
-        other = [name for name in ("kda", "mamba", "mla")
+        other = [name for name in ("conv", "kda", "mamba", "mla")
                  if getattr(self, name) is not None]
+        if self.conv is not None and len(other) > 1:
+            raise ValueError(f"{' and '.join(other)} stated for ONE layer: "
+                             f"a 'conv' layer's mixer is the convolution")
         if gate_form(self.attn_gate) is not None and (
                 other or self.sublayers == "mlp"):
             raise ValueError(
@@ -385,10 +396,16 @@ class Block(nn.Module):
                 f"{self.sublayers!r}, mixer {other or 'none'}) runs none")
 
     def _mixer(self, x, positions):
-        """The mixer's branch of the normed ``x``: a Mamba-2 mixer, a Kimi
-        Delta Attention mixer, latent attention or multi-head attention."""
+        """The mixer's branch of the normed ``x``: a gated short convolution,
+        a Mamba-2 mixer, a Kimi Delta Attention mixer, latent attention or
+        multi-head attention."""
         with jax.named_scope(device_names.NORM_ADD):
             h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
+        if self.conv is not None:
+            return ShortConvMixer(dim=self.dim, dims=self.conv,
+                                  dtype=self.dtype,
+                                  interpret=self.flash_interpret,
+                                  name="mixer")(h)
         if self.kda is not None:
             return KDAMixer(dim=self.dim, dims=self.kda,
                             rms_norm_eps=self.rms_norm_eps, dtype=self.dtype,
@@ -450,9 +467,17 @@ class Block(nn.Module):
         # q wholly before k, as ever
         plain_rope = self.rope and self.rotary is None
         q = q.reshape(b, t, self.heads, head_dim)
+        if self.qk_head_norm:
+            # Qwen3's and LFM2's: over EACH head, one weight of head_dim,
+            # before the rotation
+            q = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype,
+                           name="q_head_norm")(q)
         if plain_rope:
             q = _rope(q, positions, self.rope_theta, self.rope_interleave)
         k = k.reshape(b, t, kvh, head_dim)
+        if self.qk_head_norm:
+            k = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype,
+                           name="k_head_norm")(k)
         if plain_rope:
             k = _rope(k, positions, self.rope_theta, self.rope_interleave)
         if self.rotary is not None:
@@ -872,6 +897,20 @@ class TransformerLM(nn.Module):
     # delta rule's beta. ``heads`` / ``kv_heads`` / ``kda.heads`` are what
     # THIS rank holds where a layer's heads are cut over tensor ranks.
     kda: Optional[KDADims] = None
+    # A short-convolution hybrid (LFM2-24B-A2B: docs/short-conv.md), each as
+    # the model's own configuration states it. layer_types may also name
+    # "conv": a gated short-convolution mixer of the sizes in ``conv``
+    # (models/short_conv.py: one projection dim -> 3 dim, a product, a causal
+    # depthwise convolution of ``conv_L_cache`` taps with no activation, a
+    # second product, an output projection) in attention's place; its
+    # "full_attention" layers are grouped-query softmax attention with
+    # ``qk_head_norm`` before the rotation at ``rope_theta``. ``first_k_dense``
+    # counts layers of any kind: a dense layer's mixer may be a "conv".
+    # moe_route_eps: what the sigmoid router adds to the sum of the chosen
+    # scores before it divides by it (LFM2's released code: 1e-6; the default
+    # is DeepSeek-V3's 1e-20).
+    conv: Optional[ShortConvDims] = None
+    moe_route_eps: float = 1e-20
 
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden: bool = False):
@@ -882,17 +921,19 @@ class TransformerLM(nn.Module):
         mtp_kinds = tuple(self.mtp_layer_types or ())
         if len(kinds) != self.layers or set(kinds + mtp_kinds) - {
                 "attention", "mamba", "full_attention", "sliding_attention",
-                "kda", *_ONE_SUBLAYER}:
+                "kda", "conv", *_ONE_SUBLAYER}:
             raise ValueError(
                 f"layer_types {kinds} (mtp_layer_types {mtp_kinds}) must name "
                 f"'attention', 'mamba', 'full_attention', 'sliding_attention', "
-                f"'kda' or, for a layer that is one sub-layer, 'mamba_only', "
+                f"'kda', 'conv' or, for a layer that is one sub-layer, 'mamba_only', "
                 f"'attention_only' or 'experts_only' for each of the "
                 f"{self.layers} layers")
         if {"mamba", "mamba_only"} & set(kinds + mtp_kinds) and self.mamba is None:
             raise ValueError("a 'mamba' layer needs the mixer's sizes (mamba=)")
         if "kda" in kinds + mtp_kinds and self.kda is None:
             raise ValueError("a 'kda' layer needs the mixer's sizes (kda=)")
+        if "conv" in kinds + mtp_kinds and self.conv is None:
+            raise ValueError("a 'conv' layer needs the mixer's sizes (conv=)")
         if "experts_only" in kinds + mtp_kinds and self.moe_experts <= 0:
             raise ValueError("an 'experts_only' layer needs its experts "
                              "(moe_experts=, moe_top_k=)")
@@ -971,8 +1012,8 @@ class TransformerLM(nn.Module):
                 rope=self.rope,
                 attention_scale=self.attention_multiplier,
                 residual_scale=self.residual_multiplier,
-                mla=(self.mla if sublayers != "mlp" and kind != "kda"
-                     else None),
+                mla=(self.mla if sublayers != "mlp"
+                     and kind not in ("kda", "conv") else None),
                 rope_theta=self.rope_theta,
                 rope_interleave=self.rope_interleave,
                 moe_router=self.moe_router,
@@ -991,6 +1032,8 @@ class TransformerLM(nn.Module):
                 qk_head_norm=self.qk_head_norm,
                 moe_norm_topk=self.moe_norm_topk,
                 kda=self.kda if kind == "kda" else None,
+                conv=self.conv if kind == "conv" else None,
+                moe_route_eps=self.moe_route_eps,
                 name=name,
             )
 

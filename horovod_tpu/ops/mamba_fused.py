@@ -1,5 +1,6 @@
-"""The Mamba-2 mixer's two elementwise chains as pallas TPU kernels, forward
-AND backward, one pass over HBM each way:
+"""The Mamba-2 mixer's two elementwise chains, and LFM2's gated short
+convolution, as pallas TPU kernels, forward AND backward, one pass over HBM
+each way:
 
 - :func:`conv_silu`: ``silu(causal_depthwise_conv(x, kernel, bias))``
   (``ops.ssd.causal_depthwise_conv`` is the definition), kernels
@@ -7,6 +8,16 @@ AND backward, one pass over HBM each way:
 - :func:`gate_norm`: ``RMSNorm(y * silu(z))`` in the activations' dtype
   (``models.mamba.gated_rms_norm`` is the definition), kernels
   ``hvd_mamba_gate_norm_fwd`` / ``hvd_mamba_gate_norm_bwd``.
+
+- :func:`gated_conv`: ``C * conv(B * X)`` of ``[B | C | X]`` in one array,
+  no activation and no bias (``models.short_conv.gated_conv`` is the
+  definition; docs/short-conv.md), kernels ``hvd_sconv_conv_fwd`` /
+  ``hvd_sconv_conv_bwd``: a sibling pair of the first, on its tiling helpers.
+
+Three callers: ``models/mamba.py`` (Mamba-2: both chains),
+``models/kda.py`` (Kimi Delta Attention: :func:`conv_silu` under its own
+kernel names, three calls a layer) and ``models/short_conv.py`` (LFM2's
+gated short convolution: :func:`gated_conv`).
 
 Each is a ``jax.custom_vjp`` whose backward recomputes the chain from its
 inputs inside the kernel: the residuals are the inputs alone, and no float32
@@ -54,7 +65,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..common.device_names import (MAMBA_CONV_BWD, MAMBA_CONV_FWD,
-                                   MAMBA_GATE_NORM_BWD, MAMBA_GATE_NORM_FWD)
+                                   MAMBA_GATE_NORM_BWD, MAMBA_GATE_NORM_FWD,
+                                   SCONV_CONV_BWD, SCONV_CONV_FWD)
 
 # A block of rows at Granite's 4,352 channels is 4.25 MiB. The gated norm's
 # backward holds five blocks (y, z, do, dy, dz) and their doubles: 50 MiB at
@@ -62,6 +74,10 @@ from ..common.device_names import (MAMBA_CONV_BWD, MAMBA_CONV_FWD,
 # libtpu, well inside the v5e's 128 MiB of VMEM.
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _BLOCK_BYTES = 5 * 1024 * 1024      # the most one block of rows may hold
+# ... and of the gated convolution's [B | C | X]: 512 rows of LFM2's 3 x 2,048
+# in bf16. Its backward holds that block, its gradient's and dy's, and their
+# doubles: 28 MiB.
+_GATED_BLOCK_BYTES = 6 * 1024 * 1024
 _EDGE = 8               # rows of f32 that ride along at a piece's border
 _PIECE_ROWS = 64        # rows of a piece: a multiple of a bf16 tile's 16
 _MAX_TAPS = _EDGE + 1   # a convolution wider than this keeps jax.numpy
@@ -161,7 +177,8 @@ def _conv(taps, bias, shifted, dtype):
 
 def _for_each_chunk(k_ref, b_ref, widths, body):
     """``body(its columns in x, which output, its columns there, the K taps
-    (1, cw) each, the bias (1, cw))`` for every chunk of lanes of a block,
+    (1, cw) each, the bias (1, cw); None without a ``b_ref``)`` for every
+    chunk of lanes of a block,
     none across a border between two outputs. An output's chunks are one
     traced loop over a lane offset: 17 unrolled copies of the body cost the
     cell 2 s of tracing and lowering a compiled program (PERF.md §6, PR 31)."""
@@ -174,7 +191,7 @@ def _for_each_chunk(k_ref, b_ref, widths, body):
             cols = pl.ds(first + here, cw)
             body(cols, out, pl.ds(here, cw),
                  [k_ref[j:j + 1, cols] for j in range(k_ref.shape[0])],
-                 b_ref[:, cols])
+                 None if b_ref is None else b_ref[:, cols])
             return carry
 
         lax.fori_loop(0, width // cw, chunk, None)
@@ -393,6 +410,188 @@ def conv_silu(x, kernel, bias, interpret: bool = False, *, splits=None,
     outs = _conv_silu(x if wide is None else wide, x, kernel, bias, start,
                       tuple(splits or (x.shape[2],)), interpret, tuple(names))
     return outs if splits else outs[0]
+
+
+# ------------------------------------------- doubly gated short convolution
+
+def gated_conv_takes_kernel(bcx, kernel) -> bool:
+    """Whether ``bcx (B, T, 3 D)`` = ``[B | C | X]`` under ``kernel (K, D)`` is
+    a shape the gated convolution's kernels tile: bf16 or f32, D a multiple of
+    128, T a multiple of the row tile, a block of rows by all 3 D columns at
+    most ``_GATED_BLOCK_BYTES``, K of at most 9 taps."""
+    if bcx.ndim != 3 or bcx.dtype not in (jnp.bfloat16, jnp.float32):
+        return False
+    d, tr = kernel.shape[1], row_tile(bcx.dtype.itemsize)
+    return (bcx.shape[2] == 3 * d and d % 128 == 0 and bcx.shape[1] % tr == 0
+            and 1 <= kernel.shape[0] <= _MAX_TAPS
+            and bcx.shape[2] * tr * bcx.dtype.itemsize <= _GATED_BLOCK_BYTES)
+
+
+def _taps_of(taps, shifted):
+    """``sum_j taps[j] * shifted[j]`` in f32: the convolution with no bias."""
+    return functools.reduce(jnp.add, (k * x for k, x in zip(taps, shifted)))
+
+
+def _gated_fwd_kernel(w_ref, before_ref, k_ref, o_ref, *, piece):
+    k, d = k_ref.shape
+    at_start = pl.program_id(1) == 0
+
+    def chunk(cols, out, there, taps, bias):
+        gate, x = (pl.ds(cols.start + n * d, cols.size) for n in (1, 2))
+
+        def rows_of(i, prev):
+            rows = pl.ds(pl.multiple_of(i * piece, piece), piece)
+            u = _f32(w_ref.at[0], rows, cols) * _f32(w_ref.at[0], rows, x)
+            conv = _taps_of(taps, _taps_in(prev, u, k))
+            o_ref[rows, there] = (_f32(w_ref.at[0], rows, gate)
+                                  * conv).astype(o_ref.dtype)
+            return u[piece - _EDGE:]
+
+        before = (before_ref[0, :, cols].astype(jnp.float32)
+                  * before_ref[0, :, x].astype(jnp.float32))[-_EDGE:]
+        lax.fori_loop(0, o_ref.shape[0] // piece, rows_of,
+                      jnp.where(at_start, 0.0, before))
+
+    _for_each_chunk(k_ref, None, [d], chunk)
+
+
+def _gated_bwd_kernel(w_ref, before_ref, after_ref, dy_ref, dy_after_ref,
+                      k_ref, dw_ref, dk_ref, acc_ref, *, piece):
+    k, d = k_ref.shape
+    tr, halo = dy_ref.shape[0], before_ref.shape[1]
+    pieces = tr // piece
+    row, last_row = pl.program_id(1), pl.num_programs(1) - 1
+    first_step, last_step = _grid_ends()
+
+    @pl.when(first_step)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def chunk(cols, out, there, taps, bias):
+        gate, x = (pl.ds(cols.start + n * d, cols.size) for n in (1, 2))
+
+        def u_of(ref, rows):
+            return _f32(ref, rows, cols) * _f32(ref, rows, x)
+
+        def rows_of(n, g_after):
+            i = pieces - 1 - n
+            rows = pl.ds(pl.multiple_of(i * piece, piece), piece)
+            # u of the rows before the piece: the block's own, or at its top
+            # the small block's (the load above row 0 is clamped, not used)
+            above = pl.ds(pl.multiple_of(jnp.maximum(i * piece - halo, 0),
+                                         halo), halo)
+            prev = jnp.where(i > 0, u_of(w_ref.at[0], above)[-_EDGE:], before)
+            b, xs = _f32(w_ref.at[0], rows, cols), _f32(w_ref.at[0], rows, x)
+            shifted = _taps_in(prev, b * xs, k)
+            dy = _f32(dy_ref, rows, there)
+            g = dy * _f32(w_ref.at[0], rows, gate)      # d conv
+            joined = jnp.concatenate([g, g_after], axis=0)
+            du = None
+            for j, tap in enumerate(taps):
+                ahead = k - 1 - j       # du[t] += kernel[j] g[t + K - 1 - j]
+                term = tap * (g if ahead == 0 else pltpu.roll(
+                    joined, piece + _EDGE - ahead, 0)[:piece])
+                du = term if du is None else du + term
+                acc_ref[j, :, cols] += _fold(g * shifted[j])
+            dw_ref[rows, cols] = (du * xs).astype(dw_ref.dtype)
+            dw_ref[rows, gate] = (dy * _taps_of(taps, shifted)).astype(
+                dw_ref.dtype)
+            dw_ref[rows, x] = (du * b).astype(dw_ref.dtype)
+            return g[:_EDGE]
+
+        before = jnp.where(row == 0, 0.0, u_of(before_ref.at[0],
+                                               slice(None))[-_EDGE:])
+        # g of the rows after the block; nothing after the row's end
+        g_after = (dy_after_ref[:, there].astype(jnp.float32)
+                   * after_ref[0, :, gate].astype(jnp.float32))[:_EDGE]
+        # upwards: a piece hands the g of its first rows to the one above it
+        lax.fori_loop(0, pieces, rows_of,
+                      jnp.where(row == last_row, 0.0, g_after))
+
+    _for_each_chunk(k_ref, None, [d], chunk)
+
+    @pl.when(last_step)
+    def _store():
+        dk_ref[...] = jnp.sum(acc_ref[...], axis=1)
+
+
+def _gated_specs(bcx, k):
+    """:func:`_conv_specs` over all of ``bcx``'s columns, the taps' block as
+    wide as ONE of its three runs."""
+    d = bcx.shape[2] // 3
+    grid, spec = _conv_specs(bcx, 0, 3 * d, k)
+    return grid, d, {**spec, "taps": pl.BlockSpec((k, d), lambda n, i: (0, 0))}
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _gated_fwd_call(bcx, kernel, interpret):
+    (b, t, _), k = bcx.shape, kernel.shape[0]
+    grid, d, spec = _gated_specs(bcx, k)
+    return pl.pallas_call(
+        functools.partial(_gated_fwd_kernel, piece=_PIECE_ROWS),
+        grid=grid,
+        in_specs=[spec["x"], spec["before"], spec["taps"]],
+        out_specs=spec["rows"](d),
+        out_shape=jax.ShapeDtypeStruct((b, t, d), bcx.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=(2 * k + 2) * b * t * d, transcendentals=0,
+            bytes_accessed=4 * b * t * d * bcx.dtype.itemsize),
+        interpret=interpret,
+        name=SCONV_CONV_FWD,
+    )(bcx, bcx, kernel)
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def _gated_bwd_call(bcx, kernel, dy, interpret):
+    (b, t, _), k = bcx.shape, kernel.shape[0]
+    grid, d, spec = _gated_specs(bcx, k)
+    return pl.pallas_call(
+        functools.partial(_gated_bwd_kernel, piece=_PIECE_ROWS),
+        grid=grid,
+        in_specs=[spec["x"], spec["before"], spec["after"], spec["rows"](d),
+                  spec["rows_after"](d), spec["taps"]],
+        out_specs=[spec["rows"](3 * d), spec["taps"]],
+        out_shape=[jax.ShapeDtypeStruct(bcx.shape, bcx.dtype),
+                   jax.ShapeDtypeStruct((k, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((k, _EDGE, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=(6 * k + 6) * b * t * d, transcendentals=0,
+            bytes_accessed=7 * b * t * d * bcx.dtype.itemsize),
+        interpret=interpret,
+        name=SCONV_CONV_BWD,
+    )(bcx, bcx, bcx, dy, dy, kernel)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def gated_conv(bcx, kernel, interpret: bool = False):
+    """``C * conv(B * X)`` in ``bcx``'s dtype for shapes
+    :func:`gated_conv_takes_kernel` accepts: ``bcx`` (B, T, 3 D) = ``[B | C |
+    X]`` as ONE projection wrote it, ``kernel`` (K, D) float32; a causal
+    depthwise convolution with no activation and no bias
+    (``models.short_conv.gated_conv`` is the definition). The forward reads
+    ``bcx`` once and writes the result once; the backward reads ``bcx`` and
+    the cotangent once, recomputes the convolution, and writes ONE gradient
+    as wide as ``bcx`` and the taps' (float32)."""
+    return _gated_fwd_call(bcx, kernel, interpret)
+
+
+def _gated_forward(bcx, kernel, interpret):
+    return _gated_fwd_call(bcx, kernel, interpret), (bcx, kernel)
+
+
+def _gated_backward(interpret, res, dy):
+    bcx, kernel = res
+    dw, dk = _gated_bwd_call(bcx, kernel, dy.astype(bcx.dtype), interpret)
+    return dw, dk.astype(kernel.dtype)
+
+
+gated_conv.defvjp(_gated_forward, _gated_backward)
 
 
 # ---------------------------------------------------------------- gated norm

@@ -97,11 +97,16 @@ def topk_route(logits, top_k: int, renormalise: bool = False):
     return probs, weights, experts
 
 
-def sigmoid_route(logits, bias, top_k: int, scale: float):
+def sigmoid_route(logits, bias, top_k: int, scale: float,
+                  eps: float = 1e-20):
     """DeepSeek-V3's router with one group: scores ``sigmoid(logits)`` in
     float32, the ``top_k`` experts by ``score + bias``, their weights the
     scores themselves (the bias chooses and never weighs), renormalised over
-    the ``top_k`` and multiplied by ``scale``.
+    the ``top_k`` and multiplied by ``scale``. ``eps`` is what the
+    renormalisation adds to the chosen scores' sum before it divides: no
+    configuration file has a key for it, it is the constant of a family's
+    released modelling code (DeepSeek-V3's ``1e-20``, the default; LFM2's
+    ``1e-6``, which a configuration module states: ``MoEMLP.route_eps``).
 
     Returns (scores (N, E), weights (N, top_k), experts (N, top_k)). ``bias``
     (E,) receives no gradient: it is state, moved by
@@ -115,7 +120,7 @@ def sigmoid_route(logits, bias, top_k: int, scale: float):
         onehot = experts[:, :, None] == jnp.arange(scores.shape[-1])
         weights = jnp.sum(jnp.where(onehot, scores[:, None, :], 0.0), axis=-1)
         weights = scale * weights / (jnp.sum(weights, axis=-1, keepdims=True)
-                                     + 1e-20)
+                                     + eps)
     return scores, weights, experts
 
 

@@ -240,6 +240,33 @@ def test_the_mixers_fused_kernels_compile_for_v5e(one_chip,
         assert name in text, f"{name} is not in the compiled module"
 
 
+@pytest.mark.parametrize("rows,dtype", [(8192, jnp.bfloat16),
+                                        (2048, jnp.float32)],
+                         ids=["cell_bf16", "check_f32"])
+def test_the_gated_convolutions_kernels_compile_for_v5e(one_chip,
+                                                        no_persistent_cache,
+                                                        rows, dtype):
+    """lfm2_seq8192_1chip's mixer: ``C * conv(B * X)`` over (2, 8192, 3 x
+    2048) with 3 taps, forward and backward, as trained and at the 2,048 rows
+    of the check's float32 leg (``ops/mamba_fused.py`` ``gated_conv``: blocks
+    of 6 MiB, 28 MiB held by the backward under a 64 MiB VMEM limit)."""
+    from horovod_tpu.common.device_names import (SCONV_CONV_BWD,
+                                                 SCONV_CONV_FWD)
+    from horovod_tpu.ops import mamba_fused
+
+    args = (jax.ShapeDtypeStruct((2, rows, 6144), dtype, sharding=one_chip),
+            jax.ShapeDtypeStruct((3, 2048), jnp.float32, sharding=one_chip))
+    assert mamba_fused.gated_conv_takes_kernel(*args)
+
+    def value_and_grads(bcx, kernel):   # the value too: see above
+        out, vjp = jax.vjp(mamba_fused.gated_conv, bcx, kernel)
+        return out, vjp(out)
+
+    text = jax.jit(value_and_grads).lower(*args).compile().as_text()
+    for name in (SCONV_CONV_FWD, SCONV_CONV_BWD):
+        assert name in text, f"{name} is not in the compiled module"
+
+
 # kanana2_seq8192_1chip: latent attention, 32 heads whose q and k are 192
 # wide (1.5 x the MXU's 128 lanes: the first shape of the repo that is no power
 # of two) against v, the output, dO and dV at 128; 2 rows of 8,192 in the step.
