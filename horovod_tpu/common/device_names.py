@@ -125,7 +125,9 @@ NORM_ADD = "hvd_norm_add"               # pre-norms, residual adds, the final no
 EMBED = "hvd_embed"                     # the token lookup (+ its scatter back)
 LM_HEAD = "hvd_lm_head"                 # the MAIN head's products and its loss
 MOE_LOGITS = "hvd_moe_logits"           # the router's float32 product + its cast
-MOE_WEIGHT_CAST = "hvd_moe_weight_cast"     # the expert weights' cast to dtype
+# the expert weights' cast to the rows' dtype before ``lax.ragged_dot``
+# (``ops/moe.py``); the repo's kernels read the parameters: nothing under it
+MOE_WEIGHT_CAST = "hvd_moe_weight_cast"
 
 # Names that a number completes in the module (``hvd_fused_allreduce_k3``); a
 # reader of a device profile finds these by prefix, every other by equality.

@@ -217,18 +217,28 @@ def record_chunked_loss_plan(products: int) -> None:
     ).set(products)
 
 
-def record_moe_grouped_plan(border_overhead: float) -> None:
+def record_moe_grouped_plan(border_overhead: float,
+                            weight_itemsize: int) -> None:
     """Record which path the latest traced ``ops.moe.dropless_experts`` gave
     its grouped products (trace time, once per compile): the worst-case row
     blocks multiplied over row blocks of work, ``(B + E - 1) / B`` with ``B =
     rows / 128`` (``ops.grouped_matmul.border_overhead``), of the repo's
-    kernels, or 0 where the shapes kept ``lax.ragged_dot``."""
+    kernels, or 0 where the shapes kept ``lax.ragged_dot``; and the itemsize
+    of the weights the kernels read: 4 the float32 parameters themselves,
+    rounded in VMEM, 2 a bf16 copy someone cast, 0 under ``lax.ragged_dot``
+    (whose weights are cast beforehand, ``hvd_moe_weight_cast``)."""
     registry().gauge(
         "horovod_moe_grouped_border_overhead",
         help="worst-case row blocks multiplied over row blocks of work of the "
              "grouped-product kernels in the latest traced dropless_experts; "
              "0 = lax.ragged_dot"
     ).set(border_overhead)
+    registry().gauge(
+        "horovod_moe_grouped_weight_itemsize",
+        help="bytes a weight the grouped-product kernels of the latest traced "
+             "dropless_experts read: 4 = the f32 parameters, rounded in VMEM; "
+             "2 = a cast copy; 0 = lax.ragged_dot"
+    ).set(weight_itemsize)
 
 
 def record_moe_dispatch_rows(rows: int, row_bytes: int) -> None:

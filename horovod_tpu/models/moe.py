@@ -4,7 +4,10 @@ families.
 NO capacity and no dropped pair under any; experts of width ``hidden`` (a
 number of its own: 1024 = dim / 2 in OLMoE-1B-7B, 768 in kanana-2-30b-a3b,
 2688 in Nemotron-3-Super), computed as grouped products over the pairs sorted
-by expert (``ops.moe.dropless_experts``). ``activation="swiglu"``: gated
+by expert (``ops.moe.dropless_experts``): rows in the layer's ``dtype``
+against the float32 expert parameters, which the products round to that dtype
+where they read them (no copy of an expert stack in ``dtype`` is made where
+the repo's kernels run). ``activation="swiglu"``: gated
 experts ``w_gate`` / ``w_up`` / ``w_down``, ``down(silu(gate x) * up x)``.
 ``activation="relu2"`` (Nemotron-H's ``mlp_hidden_act``): experts WITHOUT a
 gate, ``w_up`` / ``w_down`` alone, ``down(relu(up x)^2)``; the shared expert
@@ -133,9 +136,12 @@ class MoEMLP(nn.Module):
                   "w_down": (here, h, width)}
         if self.activation == "relu2":
             del shapes["w_gate"]    # experts without a gate
-        with jax.named_scope(device_names.MOE_WEIGHT_CAST):
-            experts_w = {name: self.param(name, init, shape, jnp.float32).astype(
-                self.dtype) for name, shape in shapes.items()}
+        # The float32 parameters as they are: ``self.dtype`` reaches the
+        # products through the rows, and ``dropless_experts`` rounds the
+        # weights to it where they are multiplied (in the kernels' VMEM, or
+        # by a cast under ``hvd_moe_weight_cast`` before ``lax.ragged_dot``).
+        experts_w = {name: self.param(name, init, shape, jnp.float32)
+                     for name, shape in shapes.items()}
         # The router runs in float32 at full precision whatever the
         # activations' dtype: 2*N*D*E operations, and a coarser product
         # flips a token's 8th expert against its 9th far more often.

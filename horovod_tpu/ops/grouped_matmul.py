@@ -26,10 +26,16 @@ sizes known only on the device. Two kernels behind one ``jax.custom_vjp``
 Which tile visits which group is a few small integer arrays
 (:func:`grouped_plan`) made on the device from the group sizes and handed to
 the kernels by scalar prefetch; one plan serves every product of a layer.
-The tiles are a rule on shapes and itemsize (:func:`row_tile`,
+The tiles are a rule on shapes and the ROWS' itemsize (:func:`row_tile`,
 ``BORDER_ROWS``, :func:`_column_tile`, :func:`_weight_grad_tiles`), chosen
-by the sweep in PERF.md §6 (PR 29). Operands stay in the caller's dtype and accumulate in
-f32; float32 operands traced under ``jax.default_matmul_precision("highest")``
+by the sweep in PERF.md §6 (PR 29). Rows and weights are both bf16 or both
+f32, or bf16 rows with FLOAT32 weights (a layer's master parameters as they
+are, PR 63): the rows x weights kernel then rounds the group's resident block
+to bf16 in VMEM where it multiplies it - the numbers a cast of the weights
+beforehand would have given it, with no bf16 copy of the stack written to
+HBM and read back; the weight gradient comes back in the weights' dtype.
+Every product accumulates in f32 and is rounded once to the rows' dtype;
+float32 operands traced under ``jax.default_matmul_precision("highest")``
 are multiplied at that precision. ``interpret=True`` runs the kernels in the
 Pallas interpreter, something the caller asks for and never inferred from
 the platform, as for ``flash_attention``.
@@ -47,12 +53,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..common.device_names import MOE_EXPERTS_GMM, MOE_EXPERTS_TGMM
 
-# A resident weight block and its double are 8 MiB, the weight gradient's
+# A resident weight block and its double are 8 MiB (f32 weights under bf16
+# rows: 16, and the block rounded to bf16 as Mosaic keeps it), the weight
+# gradient's
 # accumulator 8 MiB and its output block's two buffers up to 16, the row
 # tiles and the f32 product a few more: above the 16 MiB a kernel gets by
 # default on this libtpu, well inside the v5e's 128 MiB of VMEM.
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
-_WEIGHT_BLOCK_BYTES = 4 * 1024 * 1024   # one expert's resident block
+_WEIGHT_BLOCK_BYTES = 4 * 1024 * 1024   # one expert's block as the MXU reads it
 _ACCUMULATOR_BYTES = 8 * 1024 * 1024    # the weight gradient's, in f32
 BORDER_ROWS = 128       # rows a border tile is multiplied in blocks of
 
@@ -64,9 +72,11 @@ def row_tile(itemsize: int) -> int:
 
 def takes_kernel(x, w) -> bool:
     """Whether ``x (M, K) @ w (E, K, N)`` is a shape the kernels tile: bf16
-    or f32 operands of one dtype, K and N multiples of 128, M a multiple of
-    the row tile."""
-    if x.dtype != w.dtype or x.dtype not in (jnp.bfloat16, jnp.float32):
+    or f32 operands of one dtype, or bf16 rows with f32 weights (rounded to
+    bf16 in VMEM), K and N multiples of 128, M a multiple of the row tile."""
+    if (x.dtype, w.dtype) not in ((jnp.bfloat16, jnp.bfloat16),
+                                  (jnp.float32, jnp.float32),
+                                  (jnp.bfloat16, jnp.float32)):
         return False
     (m, k), n = x.shape, w.shape[2]
     return (k % 128 == 0 and n % 128 == 0 and w.shape[1] == k
@@ -83,7 +93,8 @@ def border_overhead(rows: int, groups: int) -> float:
 
 def _column_tile(k: int, n: int, itemsize: int) -> int:
     """The widest multiple-of-128 divisor of ``n`` whose ``k x tile`` weight
-    block is at most ``_WEIGHT_BLOCK_BYTES``."""
+    block is at most ``_WEIGHT_BLOCK_BYTES`` at the ROWS' ``itemsize``, which
+    is the MXU's operand: f32 weights under bf16 rows arrive at twice that."""
     tile = n
     while k * tile * itemsize > _WEIGHT_BLOCK_BYTES and tile % 256 == 0:
         tile //= 2
@@ -164,9 +175,13 @@ def _gmm_kernel(offsets, groups, tiles, steps, x_ref, w_ref, o_ref, *, tm,
                                      pl.program_id(1), tm)
 
     def product(x):
+        # f32 weights under bf16 rows: the MXU reads the resident block
+        # rounded to the rows' dtype, here and not once a group into a
+        # scratch of its own: that read 0.13-0.28 ms a step SLOWER (PERF.md
+        # §6, PR 63)
         contract = (((1,), (1 if transpose_w else 0,)), ((), ()))
         return lax.dot_general(
-            x, w_ref[...], contract,
+            x, w_ref[...].astype(x.dtype), contract,
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
     @pl.when(jnp.logical_and(live, whole))
@@ -187,7 +202,9 @@ def _gmm_kernel(offsets, groups, tiles, steps, x_ref, w_ref, o_ref, *, tm,
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
 def _gmm_call(x, w, plan, tm, tn, transpose_w, interpret):
     """``x (M, K)`` times ``w (E, K, N)``, or ``w (E, N, K)`` read
-    transposed, over the plan's groups: (M, N) in x's dtype."""
+    transposed, over the plan's groups: (M, N) in x's dtype. ``w`` in
+    another dtype than x's (f32 under bf16 rows) is rounded to x's in VMEM,
+    the resident block where it is multiplied."""
     (m, k), n = x.shape, w.shape[1 if transpose_w else 2]
     if transpose_w:
         w_spec = pl.BlockSpec((None, tn, k),
@@ -213,8 +230,8 @@ def _gmm_call(x, w, plan, tm, tn, transpose_w, interpret):
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, transcendentals=0,
-            bytes_accessed=(m * k * (n // tn) + m * n + w.size)
-            * x.dtype.itemsize),
+            bytes_accessed=(m * k * (n // tn) + m * n) * x.dtype.itemsize
+            + w.size * w.dtype.itemsize),
         interpret=interpret,
         name=MOE_EXPERTS_GMM,
     )(*plan, x, w)
@@ -297,8 +314,10 @@ def _tgmm_call(x, dy, plan, n_groups, tm, tk, tn, interpret):
 def grouped_matmul(x, w, plan, interpret: bool = False):
     """``x[rows of g] @ w[g]`` for the groups of ``plan``
     (:func:`grouped_plan` at ``row_tile(x.dtype.itemsize)``): ``x (M, K)``,
-    ``w (E, K, N)``, both bf16 or both f32 (:func:`takes_kernel`). Returns
-    (M, N) in x's dtype, accumulated in f32 and rounded once."""
+    ``w (E, K, N)``, both bf16, both f32, or bf16 rows with f32 weights,
+    which the kernel rounds to bf16 in VMEM (:func:`takes_kernel`). Returns
+    (M, N) in x's dtype, accumulated in f32 and rounded once; the gradients
+    come back in x's dtype and in w's."""
     return _forward(x, w, plan, interpret)[0]
 
 
@@ -317,7 +336,7 @@ def _backward(interpret, res, dy):
                    interpret)
     dw = _tgmm_call(x, dy, plan, n_groups, tm, *_weight_grad_tiles(k, n),
                     interpret)
-    return dx, dw, None
+    return dx, dw.astype(w.dtype), None
 
 
 grouped_matmul.defvjp(_forward, _backward)

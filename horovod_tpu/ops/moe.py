@@ -21,9 +21,12 @@ under any imbalance, at static shapes (:func:`dropless_experts`):
    tile (``grouped_matmul.takes_kernel``: bf16 or f32, both widths multiples
    of 128, the rows a multiple of the row tile) go through them: an expert's
    weight block resident, row tiles of 512 cut into blocks of 128 where a
-   group border crosses them (PERF.md, PR 29). Any other shape
+   group border crosses them (PERF.md, PR 29). The weights may be a layer's
+   float32 master parameters under bf16 rows: the kernels read them as they
+   are and round a block in VMEM (PR 63). Any other shape
    goes through ``jax.lax.ragged_dot``, the general path (on the TPU libtpu's
-   grouped matmul at 512-cube tiles, PERF.md, PR 26);
+   grouped matmul at 512-cube tiles, PERF.md, PR 26), on weights cast to the
+   rows' dtype beforehand (``hvd_moe_weight_cast``);
 3. the rows go back to token order through the inverse permutation and are
    summed with their weights.
 
@@ -162,18 +165,33 @@ def _expert_counts(flat_experts, n_experts: int):
                    axis=0, dtype=jnp.int32)
 
 
+def _takes_kernel(rows: int, dtype, w) -> bool:
+    """Whether ``rows`` rows of ``dtype`` and the weights ``w`` as they are
+    go through the repo's kernels."""
+    return gm.takes_kernel(jax.ShapeDtypeStruct((rows, w.shape[1]), dtype), w)
+
+
 def _grouped_product(group_sizes, rows: int, dtype, w, interpret: bool):
-    """``a, w -> a[rows of g] @ w[g]`` for ``a`` of ``rows`` rows in groups
-    of ``group_sizes``: the repo's kernels where the shapes take them, else
-    ``lax.ragged_dot``; which, goes to ``horovod_moe_grouped_border_overhead``."""
+    """``a, w -> a[rows of g] @ w[g]`` for ``a`` of ``rows`` rows of ``dtype``
+    in groups of ``group_sizes``: the repo's kernels where the shapes take
+    them, on the weights AS THEY ARE (a layer's float32 parameters under bf16
+    rows are rounded in VMEM); else ``lax.ragged_dot`` on the weights cast to
+    the rows' dtype. Which, goes to ``horovod_moe_grouped_border_overhead``
+    and ``horovod_moe_grouped_weight_itemsize``."""
     from ..metrics import record_moe_grouped_plan
 
-    like = jax.ShapeDtypeStruct((rows, w.shape[1]), dtype)
-    if not gm.takes_kernel(like, w):
-        record_moe_grouped_plan(0.0)
-        return lambda a, w: lax.ragged_dot(a, w, group_sizes)
-    plan = gm.grouped_plan(group_sizes, rows, gm.row_tile(like.dtype.itemsize))
-    record_moe_grouped_plan(gm.border_overhead(rows, w.shape[0]))
+    if not _takes_kernel(rows, dtype, w):
+        record_moe_grouped_plan(0.0, 0)
+
+        def ragged(a, w):
+            with jax.named_scope(device_names.MOE_WEIGHT_CAST):
+                w = w.astype(a.dtype)
+            return lax.ragged_dot(a, w, group_sizes)
+
+        return ragged
+    plan = gm.grouped_plan(group_sizes, rows, gm.row_tile(dtype.itemsize))
+    record_moe_grouped_plan(gm.border_overhead(rows, w.shape[0]),
+                            w.dtype.itemsize)
     return lambda a, w: gm.grouped_matmul(a, w, plan, interpret)
 
 
@@ -246,8 +264,9 @@ def _as_it_comes(after, shape, dtype, interpret):
         out_specs=pl.BlockSpec(memory_space=pl.ANY), interpret=interpret)(after)
 
 
-def _row_buffer(rows: int, width: int, w, after, interpret: bool):
-    """``(rows, width)`` in the dtype of the weights ``w``, whose rows a caller
+def _row_buffer(rows: int, width: int, dtype, w, after, interpret: bool):
+    """``(rows, width)`` of ``dtype``, the ROWS' (never the weights': those
+    may be float32 parameters under bf16 rows), whose rows a caller
     writes before it reads them. Beside the kernels (``rows`` rows take them
     through ``w``) it is memory as it comes (a ``pallas_call`` that writes
     nothing, jitted so that a model's layers share one copy): zeroing 98,304
@@ -256,9 +275,9 @@ def _row_buffer(rows: int, width: int, w, after, interpret: bool):
     call takes it as an operand it never reads, or XLA, seeing a call that
     depends on nothing, makes every layer's buffers at the program's start
     and keeps them. Elsewhere zeros."""
-    if not gm.takes_kernel(jax.ShapeDtypeStruct((rows, w.shape[1]), w.dtype), w):
-        return jnp.zeros((rows, width), w.dtype)
-    return _as_it_comes(after, (rows, width), w.dtype, interpret)
+    if not _takes_kernel(rows, dtype, w):
+        return jnp.zeros((rows, width), dtype)
+    return _as_it_comes(after, (rows, width), dtype, interpret)
 
 
 def _sorted_share(flat, top_k: int, n_experts: int):
@@ -346,7 +365,7 @@ def _held_forward(x, weights, flat, w_gate, w_up, w_down, interpret):
             return lax.dynamic_update_slice(rows, x[pair // top_k], (r, 0))
 
         rows = _over_live_windows(live, pairs, gather,
-                                  _row_buffer(held_rows, d, w_up,
+                                  _row_buffer(held_rows, d, x.dtype, w_up,
                                               share["order"], interpret))
     with jax.named_scope(device_names.MOE_EXPERTS):
         product = _grouped_product(share["group_sizes"], held_rows, x.dtype,
@@ -361,14 +380,14 @@ def _held_forward(x, weights, flat, w_gate, w_up, w_down, interpret):
                                 for a in before)), (r, 0))
 
         h = _over_live_windows(live, pairs, activate,
-                               _row_buffer(held_rows, hidden, w_up, before[0],
-                                           interpret))
+                               _row_buffer(held_rows, hidden, x.dtype, w_up,
+                                           before[0], interpret))
         out = product(h, w_down)
     with jax.named_scope(device_names.MOE_COMBINE):
         y = _sum_by_token(
             share, lambda rows, pair: out[rows].astype(jnp.float32)
             * weights.reshape(-1)[pair][:, None], top_k,
-            _row_buffer(held_rows, d, w_up, out, interpret))
+            _row_buffer(held_rows, d, x.dtype, w_up, out, interpret))
     return y, (share, weights, rows, before, h, out, w_gate, w_up, w_down)
 
 
@@ -406,7 +425,7 @@ def _held_backward(interpret, res, g):
 
         dout, dweights = _over_live_windows(
             live, pairs, pull,
-            (_row_buffer(held_rows, d, w_up, g, interpret),
+            (_row_buffer(held_rows, d, g.dtype, w_up, g, interpret),
              jnp.zeros((pairs,), jnp.float32)))
     with jax.named_scope(device_names.MOE_EXPERTS):
         dh, dw_down = grads(h, w_down, dout)
@@ -427,7 +446,7 @@ def _held_backward(interpret, res, g):
             share, lambda rows, pair: functools.reduce(
                 operator.add, (b[rows].astype(jnp.float32) for b in by)),
             top_k,
-            _row_buffer(held_rows, d, w_up, by[-1], interpret))
+            _row_buffer(held_rows, d, g.dtype, w_up, by[-1], interpret))
     dw_gate, dw_up = (None, *dws) if w_gate is None else dws
     return (dx, dweights.reshape(weights.shape), None, dw_gate, dw_up, dw_down)
 
@@ -446,7 +465,10 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down,
     forward and four backward where the gated ones take three and six.
 
     x: (N, D); weights, experts: (N, top_k); w_gate (or None), w_up:
-    (E, D, H); w_down: (E, H, D), all in x's dtype. Returns (N, D) in x's dtype.
+    (E, D, H); w_down: (E, H, D), in x's dtype or the float32 parameters as
+    they are (the products multiply them rounded to x's dtype, in the kernels'
+    VMEM or by a cast before ``lax.ragged_dot``; their gradients come back
+    in their own dtype). Returns (N, D) in x's dtype.
     ``held`` None: the weights are every expert's, and the work is N x top_k
     rows whatever the routing. ``held = (first, count, of)``: ``experts``
     index the ``of`` experts the router chooses among, the weights are those

@@ -105,6 +105,63 @@ def test_tiny_unaligned_experts_keep_ragged_dot(grad):
     assert overhead == 0.0
 
 
+@pytest.fixture(scope="module")
+def recomputed_expert_block():
+    """``shape -> (locs, text without debug info)`` of a bf16 expert layer
+    that holds 2 of 4 experts inside ``jax.checkpoint``, its value and its
+    gradients: the forward, the forward again and the backward."""
+    from horovod_tpu.models import MoEMLP
+
+    def lower(dim, hidden, tokens):
+        layer = MoEMLP(dim=dim, hidden=hidden, n_experts=4, top_k=2,
+                       dtype=jnp.bfloat16, interpret=True, router="sigmoid",
+                       held=(0, 2))
+        x = jnp.ones((1, tokens, dim), jnp.float32)
+        variables = layer.init(jax.random.PRNGKey(0), x)
+        state = {k: v for k, v in variables.items() if k != "params"}
+        block = jax.checkpoint(
+            lambda p, x: layer.apply({"params": p, **state}, x))
+        lowered = jax.jit(jax.value_and_grad(
+            lambda p, x: block(p, x).astype(jnp.float32).sum())).lower(
+                variables["params"], x)
+        return _locs(lowered), lowered.as_text(debug_info=False)
+
+    return lower
+
+
+@pytest.mark.parametrize("shape,kernels", [((128, 128, 256), True),
+                                           ((16, 8, 8), False)],
+                         ids=["kernels", "ragged_dot"])
+def test_the_weights_cast_is_only_where_the_kernels_are_not(
+        shape, kernels, recomputed_expert_block):
+    """Where the kernels take the products they read the float32 parameters
+    themselves: no ``(E, K, N)`` stack is converted to bf16, forward or
+    recomputed, and nothing is under ``hvd_moe_weight_cast``. Where they
+    refuse the shapes, the three stacks are cast under that name before
+    ``lax.ragged_dot``: in the forward, in the forward again and (the
+    gradients' cast back) in the backward."""
+    import re
+
+    found, text = recomputed_expert_block(*shape)
+    dim, hidden, _ = shape
+    stacks = {f"2x{dim}x{hidden}", f"2x{hidden}x{dim}"}
+    to_bf16 = [s for s in re.findall(
+        r"stablehlo\.convert[^\n]*\(tensor<(\w+)xf32>\) -> tensor<\w+xbf16>",
+        text) if s in stacks]
+    casts = sorted(n for n in found if names.MOE_WEIGHT_CAST in n)
+    assert any(names.MOE_EXPERTS_GMM in n for n in found) is kernels
+    assert any("ragged_dot" in n for n in found) is not kernels
+    if kernels:
+        assert not to_bf16 and not casts
+        return
+    assert len(to_bf16) >= 6            # three stacks, forward and again
+    assert all(n.endswith("/convert_element_type") for n in casts)
+    assert any("rematted_computation" in n for n in casts)
+    assert any("rematted_computation" not in n and "transpose(" not in n
+               for n in casts)
+    assert any(f"transpose(jvp({names.MOE_WEIGHT_CAST}))" in n for n in casts)
+
+
 @pytest.mark.parametrize("name", [names.MOE_ROUTE, names.MOE_DISPATCH,
                                   names.MOE_EXPERTS, names.MOE_COMBINE])
 def test_moe_scope_is_in_the_lowered_module_as_metadata_only(name, moe_text):

@@ -88,18 +88,12 @@ def test_kernels_compile_for_v5e(one_chip, no_persistent_cache, cell, fn,
         assert name in text, f"{name} is not in the compiled module"
 
 
-# olmoe_seq4096_1chip: 16,384 tokens x top-8 rows through 64 experts of
-# 2048 x 1024 in the step (bf16); one row of 4096 tokens in the check's two
-# legs (bf16 as trained, f32 traced under "highest").
-@pytest.mark.parametrize("rows,dtype,precision", [
-    (131072, jnp.bfloat16, None),
-    (32768, jnp.bfloat16, None),
-    (32768, jnp.float32, "highest"),
-], ids=["step_bf16", "check_bf16", "check_f32_highest"])
-def test_grouped_kernels_compile_for_v5e(one_chip, no_persistent_cache, rows,
-                                         dtype, precision):
-    dim, width, experts = 2048, 1024, 64
-
+def _swiglu_grads_compiled(one_chip, rows, dim, width, experts, dtype,
+                           precision=None, weights=None):
+    """(compiled gradients of ``sum(gmm(gmm(x, w_gate), w_down))`` w.r.t. all
+    three, their shapes) at ``rows`` rows of ``dtype`` through ``experts``
+    experts of ``dim x width`` whose weights are ``weights`` (default: the
+    rows' dtype): both kernels are in the module and no ``ragged_dot``."""
     def swiglu_grads(x, w_gate, w_down, sizes):
         plan = gm.grouped_plan(sizes, rows, gm.row_tile(x.dtype.itemsize))
 
@@ -110,17 +104,34 @@ def test_grouped_kernels_compile_for_v5e(one_chip, no_persistent_cache, rows,
 
         return jax.grad(loss, argnums=(0, 1, 2))(x, w_gate, w_down)
 
-    def shape(*dims, of=dtype):
+    def shape(*dims, of):
         return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
 
-    args = (shape(rows, dim), shape(experts, dim, width),
-            shape(experts, width, dim), shape(experts, of=jnp.int32))
+    weights = weights or dtype
+    args = (shape(rows, dim, of=dtype), shape(experts, dim, width, of=weights),
+            shape(experts, width, dim, of=weights),
+            shape(experts, of=jnp.int32))
     assert gm.takes_kernel(*args[:2])
     with jax.default_matmul_precision(precision):
-        text = jax.jit(swiglu_grads).lower(*args).compile().as_text()
+        compiled = jax.jit(swiglu_grads).lower(*args).compile()
+    text = compiled.as_text()
     for name in (MOE_EXPERTS_GMM, MOE_EXPERTS_TGMM):
         assert name in text, f"{name} is not in the compiled module"
     assert "ragged" not in text
+    return text, jax.eval_shape(swiglu_grads, *args)
+
+
+# olmoe_seq4096_1chip: 16,384 tokens x top-8 rows through 64 experts of
+# 2048 x 1024 in the step (bf16); one row of 4096 tokens in the check's two
+# legs (bf16 as trained, f32 traced under "highest").
+@pytest.mark.parametrize("rows,dtype,precision", [
+    (131072, jnp.bfloat16, None),
+    (32768, jnp.bfloat16, None),
+    (32768, jnp.float32, "highest"),
+], ids=["step_bf16", "check_bf16", "check_f32_highest"])
+def test_grouped_kernels_compile_for_v5e(one_chip, no_persistent_cache, rows,
+                                         dtype, precision):
+    _swiglu_grads_compiled(one_chip, rows, 2048, 1024, 64, dtype, precision)
 
 
 # granite4h_long_1chip: 32 query heads over 8 key/value heads of 64 at 16,384
@@ -414,29 +425,7 @@ def test_float32_kernels_under_highest_compile_for_v5e(
 ], ids=["step_bf16", "check_bf16", "check_f32_highest"])
 def test_grouped_kernels_compile_for_v5e_at_a_share_of_the_experts(
         one_chip, no_persistent_cache, rows, dtype, precision):
-    dim, width, experts = 2048, 768, 16
-
-    def swiglu_grads(x, w_gate, w_down, sizes):
-        plan = gm.grouped_plan(sizes, rows, gm.row_tile(x.dtype.itemsize))
-
-        def loss(x, w_gate, w_down):
-            h = gm.grouped_matmul(x, w_gate, plan)
-            return jnp.sum(gm.grouped_matmul(h, w_down, plan)
-                           .astype(jnp.float32))
-
-        return jax.grad(loss, argnums=(0, 1, 2))(x, w_gate, w_down)
-
-    def shape(*dims, of=dtype):
-        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
-
-    args = (shape(rows, dim), shape(experts, dim, width),
-            shape(experts, width, dim), shape(experts, of=jnp.int32))
-    assert gm.takes_kernel(*args[:2])
-    with jax.default_matmul_precision(precision):
-        text = jax.jit(swiglu_grads).lower(*args).compile().as_text()
-    for name in (MOE_EXPERTS_GMM, MOE_EXPERTS_TGMM):
-        assert name in text, f"{name} is not in the compiled module"
-    assert "ragged" not in text
+    _swiglu_grads_compiled(one_chip, rows, 2048, 768, 16, dtype, precision)
 
 
 def test_the_held_layer_compiles_for_v5e_at_the_cells_shape(
@@ -472,6 +461,70 @@ def test_the_held_layer_compiles_for_v5e_at_the_cells_shape(
             assert rows not in line.split(" = ")[1].split("(")[0], line
     # rows, gate | up | h, out, a run-sum buffer each way, dout, dx's two
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+
+
+# bf16 rows against the FLOAT32 master weights (PR 63), which the rows x
+# weights kernel rounds in VMEM where it multiplies them: the f32 block's two
+# buffers and its bf16 form at each expert cell's widest block. OLMoE's
+# 2048 x 1024 (8 + 8 MiB + 4), solar's 4096 x 640 of 4096 x 1280 (10.5 + 10.5
+# + 5.2), the latent experts' 1024 x 2688 (10.5 + 10.5 + 5.25).
+@pytest.mark.parametrize("rows,dim,width,experts", [
+    (131072, 2048, 1024, 64), (65536, 4096, 1280, 8), (131072, 1024, 2688, 8),
+], ids=["olmoe", "solar_open2", "nemotron3s_latent"])
+def test_grouped_kernels_round_master_weights_for_v5e(
+        one_chip, no_persistent_cache, rows, dim, width, experts):
+    text, (dx, dw_gate, dw_down) = _swiglu_grads_compiled(
+        one_chip, rows, dim, width, experts, jnp.bfloat16,
+        weights=jnp.float32)
+    _no_bf16_copy_of_a_stack(text, experts, dim, width)
+    assert dx.dtype == jnp.bfloat16
+    assert dw_gate.dtype == dw_down.dtype == jnp.float32
+
+
+def _no_bf16_copy_of_a_stack(text, experts, dim, width):
+    """No instruction of a compiled module COMPUTES a bf16 ``(E, K, N)`` but
+    the weight gradient's kernel (its output, as ever; XLA may move that)."""
+    for stack in (f"bf16[{experts},{dim},{width}]",
+                  f"bf16[{experts},{width},{dim}]"):
+        for line in text.splitlines():
+            if f" = {stack}" in line.replace(", ", ","):
+                assert MOE_EXPERTS_TGMM in line or not any(
+                    op in line for op in (" fusion(", " convert(")), line[:300]
+
+
+def test_the_held_layer_on_master_weights_compiles_for_v5e(
+        one_chip, no_persistent_cache):
+    """``dropless_experts`` for a rank's share at ``solar_open2_seq8192_1chip``'s
+    shapes (8 of 320 experts of 4096 x 1280, 8,192 tokens x top-8), bf16 rows
+    on the float32 parameters, forward and backward: the kernels, no bf16
+    copy of a weight stack, and row buffers of bf16."""
+    from horovod_tpu.ops import moe
+
+    tokens, top_k, dim, width, held, of = 8192, 8, 4096, 1280, 8, 320
+
+    def loss(x, weights, w_gate, w_up, w_down, experts):
+        return jnp.sum(moe.dropless_experts(
+            x, weights, experts, w_gate, w_up, w_down,
+            held=(0, held, of)).astype(jnp.float32))
+
+    def shape(*dims, of=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        shape(tokens, dim, of=jnp.bfloat16), shape(tokens, top_k),
+        shape(held, dim, width), shape(held, dim, width),
+        shape(held, width, dim), shape(tokens, top_k, of=jnp.int32)).compile()
+    text = compiled.as_text()
+    for name in (MOE_EXPERTS_GMM, MOE_EXPERTS_TGMM):
+        assert name in text, f"{name} is not in the compiled module"
+    assert "ragged" not in text
+    _no_bf16_copy_of_a_stack(text, held, dim, width)
+    # every buffer of all the pairs' rows is bf16
+    text = text.replace(", ", ",")
+    pairs = tokens * top_k
+    assert f"bf16[{pairs},{dim}]" in text and f"bf16[{pairs},{width}]" in text
+    assert f"f32[{pairs},{dim}]" not in text
+    assert f"f32[{pairs},{width}]" not in text
 
 
 # laguna_xs2_seq16384_1chip: 64 query heads over 8 key/value heads of 128

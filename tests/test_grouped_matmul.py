@@ -83,6 +83,91 @@ def test_kernels_match_a_per_group_loop(computed, layout, dtype, product):
             assert not got[g].any()         # exact zeros, not small numbers
 
 
+@pytest.fixture(scope="module")
+def master_weights():
+    """(gradients and forward of bf16 rows against FLOAT32 weights, the same
+    against the weights cast to bf16 beforehand) per (layout, column tiles)."""
+    cache = {}
+
+    def get(layout, column_tiles):
+        if (layout, column_tiles) not in cache:
+            cache[layout, column_tiles] = (
+                _both_weights(*SHORT_OF_THE_ROWS[layout][:1], column_tiles,
+                              SHORT_OF_THE_ROWS[layout][1])
+                if layout in SHORT_OF_THE_ROWS
+                else _both_weights(LAYOUTS[layout], column_tiles))
+        return cache[layout, column_tiles]
+
+    return get
+
+
+# One group that ends inside the second of two tiles, as a rank's held share
+# leaves a buffer: the last border visit of a column tile and the first of the
+# next are the SAME group's, with another column's block (a kernel that kept
+# a group's rounded block from one visit to the next would multiply a stale
+# one).
+SHORT_OF_THE_ROWS = {"one_group_short_of_the_rows": ([24], 32)}
+
+
+def _both_weights(sixteenths, column_tiles, rows=None):
+    k = n = 256
+    sizes = np.asarray(sixteenths, np.int32) * (gm.row_tile(2) // 16)
+    m, e = (rows or sum(sixteenths)) * (gm.row_tile(2) // 16), len(sizes)
+    kx, kw, kd = jax.random.split(jax.random.PRNGKey(63), 3)
+    x = jax.random.normal(kx, (m, k), jnp.bfloat16)
+    w = jax.random.normal(kw, (e, k, n), jnp.float32)
+    dy = jax.random.normal(kd, (m, n), jnp.bfloat16)
+    assert gm.takes_kernel(x, w)
+    plan = gm.grouped_plan(jnp.asarray(sizes), m, gm.row_tile(2))
+    with pytest.MonkeyPatch.context() as patch:
+        # a resident block of at most k x 128 bf16: two column tiles, forward
+        # (k x n read as it is) and dX (n x k read transposed)
+        if column_tiles == 2:
+            patch.setattr(gm, "_WEIGHT_BLOCK_BYTES", k * 128 * 2)
+        assert n // gm._column_tile(k, n, 2) == column_tiles
+        out = []
+        for weights in (w, w.astype(jnp.bfloat16)):
+            y, vjp = jax.vjp(
+                lambda x, w: gm.grouped_matmul(x, w, plan, True), x, weights)
+            dx, dw = vjp(dy)
+            # rows behind the groups' are never written
+            live = int(sizes.sum())
+            out.append({"forward": y[:live], "dx": dx[:live], "dw": dw})
+    return out
+
+
+@pytest.mark.parametrize("product", ["forward", "dx", "dw"])
+@pytest.mark.parametrize("column_tiles", [1, 2])
+@pytest.mark.parametrize("layout", ["borders_inside_tiles", "empty_groups",
+                                    "groups_smaller_than_a_block",
+                                    *SHORT_OF_THE_ROWS])
+def test_f32_weights_rounded_in_vmem_equal_a_cast_beforehand(
+        master_weights, layout, column_tiles, product):
+    """bf16 rows against the float32 master weights, the resident block
+    rounded inside the rows x weights kernel (forward as they are, dX read
+    transposed), are BIT-equal to the products of the weights cast to bf16
+    beforehand; dW arrives in the weights' dtype."""
+    master, cast = (np.asarray(side[product]) for side in
+                    master_weights(layout, column_tiles))
+    want = np.float32 if product == "dw" else jnp.bfloat16
+    assert master.dtype == want and cast.dtype == jnp.bfloat16
+    assert np.isfinite(master.astype(np.float32)).all() and master.any()
+    np.testing.assert_array_equal(master.astype(np.float32),
+                                  cast.astype(np.float32))
+
+
+@pytest.mark.parametrize("rows, weights, takes", [
+    (jnp.bfloat16, jnp.bfloat16, True),
+    (jnp.float32, jnp.float32, True),
+    (jnp.bfloat16, jnp.float32, True),      # rounded in VMEM
+    (jnp.float32, jnp.bfloat16, False),     # nothing widens a weight
+], ids=["bf16_bf16", "f32_f32", "bf16_f32", "f32_bf16"])
+def test_which_dtype_pairs_take_the_kernels(rows, weights, takes):
+    x = jax.ShapeDtypeStruct((512, 128), rows)
+    w = jax.ShapeDtypeStruct((4, 128, 256), weights)
+    assert gm.takes_kernel(x, w) is takes
+
+
 @pytest.mark.parametrize("sizes, tm", [
     ([100, 0, 28, 300, 84, 0], 128),
     ([0, 0, 512], 256),
@@ -126,6 +211,9 @@ def test_tiles_follow_shapes_and_itemsize():
     assert gm._column_tile(2048, 1024, 2) == 1024
     assert gm._column_tile(1024, 2048, 2) == 2048
     assert gm._column_tile(2048, 1024, 4) == 512     # f32: half the columns
+    # solar's 4096 x 1280: sized by the bf16 the block becomes under bf16
+    # rows (the rows' itemsize), whether the weights arrive bf16 or f32
+    assert gm._column_tile(4096, 1280, 2) == 640
     assert gm.row_tile(2) == 512 and gm.row_tile(4) == 256
     # the weight gradient accumulates an expert's whole block in f32
     assert gm._weight_grad_tiles(2048, 1024) == (2048, 1024)

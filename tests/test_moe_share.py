@@ -326,6 +326,110 @@ def test_the_held_path_through_the_kernels(monkeypatch, live):
     assert ops_moe_gauges()["horovod_moe_grouped_border_overhead"] > 1.0
 
 
+# ------------------------------ the float32 master weights under bf16 rows
+
+MASTER_PATHS = {"held_kernels": ((4, 4, E), 128), "all_held_kernels": (None, 128),
+                "held_ragged_dot": ((4, 4, E), 48)}
+MASTER_LEAVES = ("y", "dx", "dweights", "dw_gate", "dw_up", "dw_down")
+
+
+def _master_operands(held, width):
+    """512 tokens of ``width`` (128: the kernels take the products; 48: they
+    refuse them) routed over ``held``'s experts, float32 expert weights."""
+    n, count = 512, E if held is None else held[1]
+    experts, weights = routed(n, 700, 63) if held else routed(
+        n, n * TOP_K, 63, 0, E)
+    x = seeded((n, width), 5, 1.0).astype(jnp.bfloat16)
+    ws = tuple(seeded(shape, seed, 0.2) for shape, seed in (
+        ((count, width, width), 6), ((count, width, width), 7),
+        ((count, width, width), 8)))
+    return x, weights, experts, ws, seeded((n, width), 9, 1.0)
+
+
+@pytest.fixture(scope="module")
+def master_and_cast():
+    """path -> ((output, gradients) on the float32 weights as they are, the
+    same on the weights cast to bf16 BEFORE the call: the parent's program),
+    the gradients in both w.r.t. the float32 weights."""
+    cache = {}
+
+    def get(path):
+        if path not in cache:
+            held, width = MASTER_PATHS[path]
+            x, weights, experts, ws, g = _master_operands(held, width)
+
+            def outputs(cast):
+                def loss(x, weights, *ws):
+                    y = ops_moe.dropless_experts(
+                        x, weights, experts, *(cast(w) for w in ws),
+                        interpret=True, held=held)
+                    return jnp.sum(y.astype(jnp.float32) * g), y
+
+                (_, y), grads = jax.value_and_grad(
+                    loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+                        x, weights, *ws)
+                return dict(zip(MASTER_LEAVES, (y, *grads)))
+
+            cache[path] = (outputs(lambda w: w),
+                           outputs(lambda w: w.astype(jnp.bfloat16)))
+        return cache[path]
+
+    return get
+
+
+@pytest.mark.parametrize("leaf", MASTER_LEAVES)
+@pytest.mark.parametrize("path", list(MASTER_PATHS))
+def test_master_weights_give_the_cast_copys_bits(master_and_cast, path, leaf):
+    """``dropless_experts`` on bf16 rows and the float32 parameters themselves
+    (rounded in the kernels' VMEM, or cast inside before ``lax.ragged_dot``)
+    is, bit for bit, the call on a bf16 copy cast beforehand: the output and
+    every gradient, the weights' in float32."""
+    master, cast = (np.asarray(side[leaf]) for side in master_and_cast(path))
+    want = jnp.bfloat16 if leaf in ("y", "dx") else np.float32
+    assert master.dtype == cast.dtype == want
+    assert master.any() and np.isfinite(master.astype(np.float32)).all()
+    np.testing.assert_array_equal(master.astype(np.float32),
+                                  cast.astype(np.float32))
+
+
+@pytest.mark.parametrize("width,cast,itemsize", [
+    (128, False, 4),    # the kernels on the parameters, rounded in VMEM
+    (128, True, 2),     # the kernels on a copy a caller cast
+    (48, False, 0),     # lax.ragged_dot
+], ids=["master", "cast_copy", "ragged_dot"])
+def test_the_gauge_says_which_weights_the_kernels_read(width, cast, itemsize):
+    x, weights, experts, ws, _ = _master_operands((4, 4, E), width)
+    if cast:
+        ws = tuple(w.astype(jnp.bfloat16) for w in ws)
+    jax.eval_shape(lambda *a: ops_moe.dropless_experts(
+        *a, interpret=True, held=(4, 4, E)), x, weights, experts, *ws)
+    gauges = ops_moe_gauges()
+    assert gauges["horovod_moe_grouped_weight_itemsize"] == itemsize
+    assert (gauges["horovod_moe_grouped_border_overhead"] > 0) is (width == 128)
+
+
+@pytest.mark.parametrize("width", [128, 48], ids=["kernels", "ragged_dot"])
+def test_the_held_paths_row_buffers_are_the_rows_dtype(width):
+    """The dispatch, hidden and output buffers and what the backward returns
+    for the rows are bf16 under float32 weights (never the weights' dtype:
+    twice the bytes of every pass); the residuals hold the float32 parameters
+    themselves, and their gradients are float32."""
+    x, weights, experts, ws, g = _master_operands((4, 4, E), width)
+    flat = jnp.where((experts >= 4) & (experts < 8), experts - 4, 4).reshape(-1)
+    y, res = jax.eval_shape(
+        lambda *a: ops_moe._held_forward(*a, True), x, weights, flat, *ws)
+    _, _, rows, before, h, out, *kept = res
+    assert all(a.dtype == jnp.bfloat16 for a in (y, rows, *before, h, out))
+    assert rows.shape == (512 * TOP_K, width)
+    assert all(w.dtype == jnp.float32 for w in kept) and len(kept) == 3
+    dx, _, _, *dws = jax.eval_shape(
+        lambda res, g: ops_moe._held_backward(True, res, g), res,
+        g.astype(jnp.bfloat16))
+    assert dx.dtype == jnp.bfloat16
+    assert all(dw.dtype == jnp.float32 and dw.shape == w.shape
+               for dw, w in zip(dws, ws)) and len(dws) == 3
+
+
 def test_every_expert_held_by_all_lowers_to_what_it_did():
     """``held=None`` (OLMoE's layer) is the function it was before the held
     path followed the live rows: its lowered text, forward and backward, at a

@@ -304,18 +304,20 @@ def test_expert_load_gauge_is_set_by_the_helper_alone(hvd):
     assert moe_ops.record_expert_load(logits, 2) == pytest.approx(2.0)
     layer, x = one_layer([0.0] * 8)
     # tracing and running the layer leaves the load as the helper set it; the
-    # one gauge a trace sets says which path the grouped products took
+    # two gauges a trace sets say which path the grouped products took
     moe_system(layer, x)
     gauges = hvd.metrics.registry().snapshot()["gauges"]
     assert gauges["horovod_moe_expert_load_max_over_mean"] == pytest.approx(2.0)
     assert gauges["horovod_moe_grouped_border_overhead"] == 0.0
+    assert gauges["horovod_moe_grouped_weight_itemsize"] == 0      # ragged_dot
     assert gauges["horovod_moe_dispatch_rows"] == 96 * CFG["top_k"]   # N x top_k
     assert gauges["horovod_moe_dispatch_row_bytes"] == x.shape[-1] * x.dtype.itemsize
     assert sorted(name for name in gauges if name.startswith("horovod_moe_")) == [
         "horovod_moe_dispatch_row_bytes",
         "horovod_moe_dispatch_rows",
         "horovod_moe_expert_load_max_over_mean",
-        "horovod_moe_grouped_border_overhead"]
+        "horovod_moe_grouped_border_overhead",
+        "horovod_moe_grouped_weight_itemsize"]
 
 
 def test_data_parallel_through_distributed_optimizer(hvd, seeded):
