@@ -217,8 +217,8 @@ def record_chunked_loss_plan(products: int) -> None:
     ).set(products)
 
 
-def record_moe_grouped_plan(border_overhead: float,
-                            weight_itemsize: int) -> None:
+def record_moe_grouped_plan(border_overhead: float, weight_itemsize: int,
+                            lookahead_share: float) -> None:
     """Record which path the latest traced ``ops.moe.dropless_experts`` gave
     its grouped products (trace time, once per compile): the worst-case row
     blocks multiplied over row blocks of work, ``(B + E - 1) / B`` with ``B =
@@ -226,7 +226,11 @@ def record_moe_grouped_plan(border_overhead: float,
     kernels, or 0 where the shapes kept ``lax.ragged_dot``; and the itemsize
     of the weights the kernels read: 4 the float32 parameters themselves,
     rounded in VMEM, 2 a bf16 copy someone cast, 0 under ``lax.ragged_dot``
-    (whose weights are cast beforehand, ``hvd_moe_weight_cast``)."""
+    (whose weights are cast beforehand, ``hvd_moe_weight_cast``); and the
+    share of a rows x weights call's weight-block fetches that the kernel
+    starts a whole group ahead, under that group's products
+    (``ops.grouped_matmul.lookahead_share``: ``(E - 1) / E``, all but the
+    first of a column tile), 0 under ``lax.ragged_dot``."""
     registry().gauge(
         "horovod_moe_grouped_border_overhead",
         help="worst-case row blocks multiplied over row blocks of work of the "
@@ -239,6 +243,12 @@ def record_moe_grouped_plan(border_overhead: float,
              "dropless_experts read: 4 = the f32 parameters, rounded in VMEM; "
              "2 = a cast copy; 0 = lax.ragged_dot"
     ).set(weight_itemsize)
+    registry().gauge(
+        "horovod_moe_grouped_weight_lookahead_share",
+        help="share of the weight-block fetches of a rows x weights kernel of "
+             "the latest traced dropless_experts that start a whole group "
+             "ahead: (E - 1) / E; 0 = lax.ragged_dot"
+    ).set(lookahead_share)
 
 
 def record_moe_dispatch_rows(rows: int, row_bytes: int) -> None:

@@ -8,9 +8,18 @@ sizes known only on the device. Two kernels behind one ``jax.custom_vjp``
 - rows x weights (``hvd_moe_experts_gmm``): the forward, and with the
   weight block read transposed the input gradient ``dX = dY W[g]^T``. The
   grid walks row tiles group by group. A group's whole weight block (or a
-  half of its columns) is one VMEM block whose index changes only when the
-  group does, so it is fetched once per group and the rows stream through
-  once: the product is bound by the MXU, not by HBM, at any row tile. A
+  half of its columns) is resident in one of two VMEM slots for all the
+  group's visits, fetched once per group, and the rows stream through once.
+  The weights stay where they lie in HBM and the kernel copies the blocks
+  itself, a GROUP ahead: a group's first visit starts its successor's copy
+  into the other slot and waits for its own, started at its predecessor's
+  first visit (a pipelined ``BlockSpec`` would ask for the block one grid
+  STEP ahead, at the predecessor's last visit: a border visit of a few
+  microseconds, shorter than an 8 MiB fetch; PERF.md §6, PR 64).
+  What bounds the kernel: a group's products against ITS SUCCESSOR's
+  fetch, whichever is longer (the MXU where an expert's rows take longer
+  to multiply than a block's bytes to arrive, HBM where they do not), plus
+  the first fetch of every column tile, which nothing hides. A
   tile wholly inside a group is one product of ``row_tile`` rows (the
   larger, the closer to the MXU's peak). A tile that a group border crosses
   is visited once per group it holds rows of, and each visit multiplies
@@ -53,12 +62,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..common.device_names import MOE_EXPERTS_GMM, MOE_EXPERTS_TGMM
 
-# A resident weight block and its double are 8 MiB (f32 weights under bf16
-# rows: 16, and the block rounded to bf16 as Mosaic keeps it), the weight
-# gradient's
-# accumulator 8 MiB and its output block's two buffers up to 16, the row
-# tiles and the f32 product a few more: above the 16 MiB a kernel gets by
-# default on this libtpu, well inside the v5e's 128 MiB of VMEM.
+# The rows x weights kernel's two weight slots are 8 MiB (f32 weights under
+# bf16 rows: 16, and the block rounded to bf16 as Mosaic keeps it), no more
+# than a pipelined block's double buffer would take.
+# The weight gradient's accumulator is 8 MiB and its output block's two
+# buffers up to 16, the row tiles and the f32 product a few more: above the
+# 16 MiB a kernel gets by default on this libtpu, well inside the v5e's
+# 128 MiB of VMEM.
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _WEIGHT_BLOCK_BYTES = 4 * 1024 * 1024   # one expert's block as the MXU reads it
 _ACCUMULATOR_BYTES = 8 * 1024 * 1024    # the weight gradient's, in f32
@@ -89,6 +99,13 @@ def border_overhead(rows: int, groups: int) -> float:
     through the MXU twice, ``(B + E - 1) / B`` with ``B`` the blocks."""
     blocks = rows // BORDER_ROWS
     return (blocks + groups - 1) / blocks
+
+
+def lookahead_share(groups: int) -> float:
+    """Share of a rows x weights call's weight-block fetches that are started
+    a whole group ahead: all but the first of a column tile,
+    ``(E - 1) / E``."""
+    return (groups - 1) / groups
 
 
 def _column_tile(k: int, n: int, itemsize: int) -> int:
@@ -169,10 +186,40 @@ def _border_blocks(start, end, tm, body):
 
 # ------------------------------------------------------------ rows x weights
 
-def _gmm_kernel(offsets, groups, tiles, steps, x_ref, w_ref, o_ref, *, tm,
-                transpose_w):
-    live, whole, start, end = _visit((offsets, groups, tiles, steps),
-                                     pl.program_id(1), tm)
+def _gmm_kernel(offsets, groups, tiles, steps, x_ref, w_hbm, o_ref, w_ref,
+                arrived, *, tm, transpose_w):
+    column, step = pl.program_id(0), pl.program_id(1)
+    live, whole, start, end = _visit((offsets, groups, tiles, steps), step, tm)
+    group, tn = groups[step], o_ref.shape[1]
+
+    def fetch(g):
+        """The copy of group ``g``'s block of this column tile into slot
+        ``g % 2``."""
+        slot = lax.rem(g, 2)
+        columns = pl.ds(pl.multiple_of(column * tn, tn), tn)
+        block = (w_hbm.at[g, columns, :] if transpose_w
+                 else w_hbm.at[g, :, columns])
+        return pltpu.make_async_copy(block, w_ref.at[slot], arrived.at[slot])
+
+    # A group's FIRST visit asks for its successor's block and waits for its
+    # own, asked for at its predecessor's first visit: a group's products
+    # hide the next group's fetch. Every group has a visit and a group's
+    # visits are consecutive (grouped_plan), so the successor is group + 1
+    # and the slot it fills was last read by group - 1, whose visits are
+    # over. The steps past the plan's end repeat its last one and open no
+    # group: every copy started is waited for inside its column tile.
+    @pl.when(jnp.logical_or(step == 0,
+                            groups[jnp.maximum(step - 1, 0)] != group))
+    def _arrive():
+        @pl.when(step == 0)
+        def _first_of_the_column_tile():
+            fetch(group).start()
+
+        @pl.when(group + 1 < w_hbm.shape[0])
+        def _ahead():
+            fetch(group + 1).start()
+
+        fetch(group).wait()
 
     def product(x):
         # f32 weights under bf16 rows: the MXU reads the resident block
@@ -181,7 +228,7 @@ def _gmm_kernel(offsets, groups, tiles, steps, x_ref, w_ref, o_ref, *, tm,
         # §6, PR 63)
         contract = (((1,), (1 if transpose_w else 0,)), ((), ()))
         return lax.dot_general(
-            x, w_ref[...].astype(x.dtype), contract,
+            x, w_ref[lax.rem(group, 2)].astype(x.dtype), contract,
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
     @pl.when(jnp.logical_and(live, whole))
@@ -202,16 +249,12 @@ def _gmm_kernel(offsets, groups, tiles, steps, x_ref, w_ref, o_ref, *, tm,
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
 def _gmm_call(x, w, plan, tm, tn, transpose_w, interpret):
     """``x (M, K)`` times ``w (E, K, N)``, or ``w (E, N, K)`` read
-    transposed, over the plan's groups: (M, N) in x's dtype. ``w`` in
-    another dtype than x's (f32 under bf16 rows) is rounded to x's in VMEM,
-    the resident block where it is multiplied."""
+    transposed, over the plan's groups: (M, N) in x's dtype. ``w`` stays
+    where it lies and the kernel copies a group's block into one of two VMEM
+    slots a group ahead; ``w`` in another dtype than x's (f32 under bf16
+    rows) is rounded to x's in VMEM, the resident block where it is
+    multiplied."""
     (m, k), n = x.shape, w.shape[1 if transpose_w else 2]
-    if transpose_w:
-        w_spec = pl.BlockSpec((None, tn, k),
-                              lambda j, s, o, g, t, c: (g[s], j, 0))
-    else:
-        w_spec = pl.BlockSpec((None, k, tn),
-                              lambda j, s, o, g, t, c: (g[s], 0, j))
     return pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm, transpose_w=transpose_w),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -219,10 +262,14 @@ def _gmm_call(x, w, plan, tm, tn, transpose_w, interpret):
             grid=(n // tn, plan[1].shape[0]),
             in_specs=[
                 pl.BlockSpec((tm, k), lambda j, s, o, g, t, c: (t[s], 0)),
-                w_spec,
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((tm, tn),
                                    lambda j, s, o, g, t, c: (t[s], j)),
+            scratch_shapes=[
+                pltpu.VMEM((2, tn, k) if transpose_w else (2, k, tn), w.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         compiler_params=pltpu.CompilerParams(

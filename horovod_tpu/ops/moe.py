@@ -176,12 +176,13 @@ def _grouped_product(group_sizes, rows: int, dtype, w, interpret: bool):
     in groups of ``group_sizes``: the repo's kernels where the shapes take
     them, on the weights AS THEY ARE (a layer's float32 parameters under bf16
     rows are rounded in VMEM); else ``lax.ragged_dot`` on the weights cast to
-    the rows' dtype. Which, goes to ``horovod_moe_grouped_border_overhead``
-    and ``horovod_moe_grouped_weight_itemsize``."""
+    the rows' dtype. Which, goes to ``horovod_moe_grouped_border_overhead``,
+    ``horovod_moe_grouped_weight_itemsize`` and
+    ``horovod_moe_grouped_weight_lookahead_share``."""
     from ..metrics import record_moe_grouped_plan
 
     if not _takes_kernel(rows, dtype, w):
-        record_moe_grouped_plan(0.0, 0)
+        record_moe_grouped_plan(0.0, 0, 0.0)
 
         def ragged(a, w):
             with jax.named_scope(device_names.MOE_WEIGHT_CAST):
@@ -191,7 +192,7 @@ def _grouped_product(group_sizes, rows: int, dtype, w, interpret: bool):
         return ragged
     plan = gm.grouped_plan(group_sizes, rows, gm.row_tile(dtype.itemsize))
     record_moe_grouped_plan(gm.border_overhead(rows, w.shape[0]),
-                            w.dtype.itemsize)
+                            w.dtype.itemsize, gm.lookahead_share(w.shape[0]))
     return lambda a, w: gm.grouped_matmul(a, w, plan, interpret)
 
 

@@ -27,6 +27,10 @@ LAYOUTS = {
     "empty_groups": [5, 0, 18, 0, 9, 0],
     "all_rows_in_one_group": [0, 0, 32, 0],
     "borders_on_tile_edges": [16, 0, 32, 16],
+    # the weights' copies (PR 64): no successor to ask for; and a successor
+    # asked for at every step, each group one visit of the same tile
+    "one_group_only": [32],
+    "one_visit_a_group_in_one_tile": [1] * 16,
 }
 
 
@@ -34,30 +38,41 @@ LAYOUTS = {
 def computed():
     cache = {}
 
-    def get(layout, dtype):
-        if (layout, dtype) not in cache:
-            cache[layout, dtype] = _compute(LAYOUTS[layout], dtype)
-        return cache[layout, dtype]
+    def get(layout, dtype, weights=None, **shape):
+        key = (layout, dtype, weights, *sorted(shape.items()))
+        if key not in cache:
+            cache[key] = _compute(LAYOUTS[layout], dtype, weights, **shape)
+        return cache[key]
 
     return get
 
 
-def _compute(sixteenths, dtype):
-    tm = gm.row_tile(jnp.dtype(dtype).itemsize)
+def _compute(sixteenths, dtype, weights=None, k=K, n=N, column_tiles=1):
+    itemsize = jnp.dtype(dtype).itemsize
+    tm = gm.row_tile(itemsize)
     sizes = np.asarray(sixteenths, np.int32) * (tm // 16)
     m, e = int(sizes.sum()), len(sizes)
     kx, kw, kd = jax.random.split(jax.random.PRNGKey(len(sixteenths)), 3)
-    x = jax.random.normal(kx, (m, K), dtype)
-    w = jax.random.normal(kw, (e, K, N), dtype)
-    dy = jax.random.normal(kd, (m, N), dtype)
+    x = jax.random.normal(kx, (m, k), dtype)
+    w = jax.random.normal(kw, (e, k, n), weights or dtype)
+    dy = jax.random.normal(kd, (m, n), dtype)
     assert gm.takes_kernel(x, w)
     plan = gm.grouped_plan(jnp.asarray(sizes), m, tm)
-    with jax.default_matmul_precision("highest"):
+    with pytest.MonkeyPatch.context() as patch, \
+            jax.default_matmul_precision("highest"):
+        if column_tiles == 2:
+            # a resident block of at most half an expert's at the rows'
+            # itemsize: two column tiles, forward (k x n read as it is) and,
+            # where k == n, dX (n x k read transposed)
+            patch.setattr(gm, "_WEIGHT_BLOCK_BYTES", k * n * itemsize // 2)
+        assert n // gm._column_tile(k, n, itemsize) == column_tiles
         y, vjp = jax.vjp(lambda x, w: gm.grouped_matmul(x, w, plan, True), x, w)
         dx, dw = vjp(dy)
     x64, w64, dy64 = (np.asarray(a, np.float64) for a in (x, w, dy))
-    want = {"forward": np.zeros((m, N)), "dx": np.zeros((m, K)),
-            "dw": np.zeros((e, K, N))}
+    if w.dtype != x.dtype:      # the block the MXU reads, rounded in VMEM
+        w64 = np.asarray(w.astype(x.dtype), np.float64)
+    want = {"forward": np.zeros((m, n)), "dx": np.zeros((m, k)),
+            "dw": np.zeros((e, k, n))}
     start = 0
     for g, size in enumerate(sizes):
         rows = slice(start, start + size)
@@ -68,19 +83,64 @@ def _compute(sixteenths, dtype):
     return {"forward": y, "dx": dx, "dw": dw}, want, sizes
 
 
+def _close(got, want, dtype):
+    got = np.asarray(got, np.float64)
+    assert got.shape == want.shape and np.isfinite(got).all()
+    tol = 4e-3 if dtype == jnp.bfloat16 else 1e-5
+    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want))
+    return got
+
+
 @pytest.mark.parametrize("product", ["forward", "dx", "dw"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("layout", list(LAYOUTS))
 def test_kernels_match_a_per_group_loop(computed, layout, dtype, product):
     got, want, sizes = computed(layout, dtype)
-    got, want = np.asarray(got[product], np.float64), want[product]
-    assert got.shape == want.shape and np.isfinite(got).all()
-    tol = 4e-3 if dtype == jnp.bfloat16 else 1e-5
-    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want))
+    got = _close(got[product], want[product], dtype)
     if product == "dw":
         for g in np.flatnonzero(sizes == 0):
             assert not got[g].any()         # exact zeros, not small numbers
+
+
+@pytest.mark.parametrize("product", ["forward", "dx"])
+@pytest.mark.parametrize("rows, weights", [
+    (jnp.bfloat16, jnp.bfloat16), (jnp.float32, jnp.float32),
+    (jnp.bfloat16, jnp.float32)], ids=["bf16_bf16", "f32_f32", "bf16_f32"])
+@pytest.mark.parametrize("layout", ["borders_inside_tiles", "empty_groups",
+                                    "one_group_only",
+                                    "one_visit_a_group_in_one_tile"])
+def test_the_weights_copies_start_again_at_every_column_tile(
+        computed, layout, rows, weights, product):
+    """The rows x weights kernel copies a group's block itself, a group
+    ahead, and the chain of copies lives inside ONE column tile: with two of
+    them, forward and read transposed, the second starts with its own first
+    fetch and every block multiplied is its group's and its columns'."""
+    got, want, _ = computed(layout, rows, weights, k=256, n=256,
+                            column_tiles=2)
+    _close(got[product], want[product], rows)
+
+
+@pytest.mark.parametrize("tm", [128, 512])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_plan_gives_what_the_weights_copies_rely_on(layout, tm):
+    """``_gmm_kernel`` starts group g + 1's copy at group g's first visit and
+    waits for it at g + 1's: every group has a visit, a group's visits are
+    consecutive, the groups come in order, and the steps past the plan's end
+    open no group (no copy is started that nobody waits for)."""
+    sizes = np.asarray(LAYOUTS[layout], np.int32) * (tm // 16)
+    e, rows = len(sizes), int(sizes.sum())
+    _, groups, _, steps = (np.asarray(a) for a in gm.grouped_plan(
+        jnp.asarray(sizes), rows, tm))
+    steps, groups = int(steps[0]), groups.tolist()
+    first = [s for s in range(len(groups))
+             if s == 0 or groups[s - 1] != groups[s]]
+    # the groups opened are 0 .. E - 1, each once and in order: a group's
+    # visits are consecutive and group g + 1's first follows group g's last
+    assert [groups[s] for s in first] == list(range(e))
+    assert np.all(np.diff(groups) >= 0)
+    assert first[-1] < steps <= len(groups)
+    assert set(groups[steps:]) <= {e - 1}
 
 
 @pytest.fixture(scope="module")

@@ -406,6 +406,10 @@ def test_the_gauge_says_which_weights_the_kernels_read(width, cast, itemsize):
     gauges = ops_moe_gauges()
     assert gauges["horovod_moe_grouped_weight_itemsize"] == itemsize
     assert (gauges["horovod_moe_grouped_border_overhead"] > 0) is (width == 128)
+    # the kernels ask for all but a column tile's first block a group ahead
+    # (the rank's 4 held experts are a call's groups)
+    assert gauges["horovod_moe_grouped_weight_lookahead_share"] == (
+        3 / 4 if width == 128 else 0)
 
 
 @pytest.mark.parametrize("width", [128, 48], ids=["kernels", "ragged_dot"])

@@ -310,6 +310,7 @@ def test_expert_load_gauge_is_set_by_the_helper_alone(hvd):
     assert gauges["horovod_moe_expert_load_max_over_mean"] == pytest.approx(2.0)
     assert gauges["horovod_moe_grouped_border_overhead"] == 0.0
     assert gauges["horovod_moe_grouped_weight_itemsize"] == 0      # ragged_dot
+    assert gauges["horovod_moe_grouped_weight_lookahead_share"] == 0
     assert gauges["horovod_moe_dispatch_rows"] == 96 * CFG["top_k"]   # N x top_k
     assert gauges["horovod_moe_dispatch_row_bytes"] == x.shape[-1] * x.dtype.itemsize
     assert sorted(name for name in gauges if name.startswith("horovod_moe_")) == [
@@ -317,7 +318,8 @@ def test_expert_load_gauge_is_set_by_the_helper_alone(hvd):
         "horovod_moe_dispatch_rows",
         "horovod_moe_expert_load_max_over_mean",
         "horovod_moe_grouped_border_overhead",
-        "horovod_moe_grouped_weight_itemsize"]
+        "horovod_moe_grouped_weight_itemsize",
+        "horovod_moe_grouped_weight_lookahead_share"]
 
 
 def test_data_parallel_through_distributed_optimizer(hvd, seeded):
