@@ -92,6 +92,23 @@ KDA_GATE = "hvd_kda_gate"               # L2 norms, softplus, the log-decay, bet
 KDA_SCAN = "hvd_kda_scan"               # the chunked delta rule, forward and backward
 KDA_OUT_NORM = "hvd_kda_out_norm"       # the head-wise RMSNorm, then the sigmoid gate
 
+# The gated delta rule's mixer with one decay a head (models/gdn.py; Gated
+# DeltaNet's layer, Olmo-Hybrid's ``linear_attention``) and its chunked rule
+# (ops/gdn.py). The benchmark finds the mixer's time by the names that start
+# with ``hvd_gdn``.
+GDN_PROJ = "hvd_gdn_proj"               # q, k, v, the decay's, beta's, the gate's and o's products
+GDN_CONV = "hvd_gdn_conv"               # the three causal depthwise convolutions + silu
+# ops/mamba_fused.py's convolution kernels under this layer's names, for the
+# shapes they tile (``conv_silu(names=)``).
+GDN_CONV_FWD = "hvd_gdn_conv_fwd"
+GDN_CONV_BWD = "hvd_gdn_conv_bwd"
+GDN_GATE = "hvd_gdn_gate"               # L2 norms, softplus, the log-decay, beta
+GDN_SCAN = "hvd_gdn_scan"               # the chunked delta rule: the lanes' padding round the kernels,
+#                                         and the jax.numpy scan of shapes that do not tile
+GDN_SCAN_FWD = "hvd_gdn_scan_fwd"       # ops/gdn.py's two kernels
+GDN_SCAN_BWD = "hvd_gdn_scan_bwd"
+GDN_OUT_NORM = "hvd_gdn_out_norm"       # the head-wise RMSNorm, then the silu gate
+
 # The gated short-convolution mixer (models/short_conv.py; LFM2's ``conv``
 # layers). The benchmark finds the mixer's time by the names that start with
 # ``hvd_sconv``.

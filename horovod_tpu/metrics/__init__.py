@@ -40,6 +40,8 @@ from .overlap import (  # noqa: F401
     record_dsa_select_plan,
     record_flash_plan,
     record_flash_window_plan,
+    record_gdn_kernel_scan,
+    record_gdn_plan,
     record_kda_beta_range,
     record_kda_fused_mixer,
     record_kda_plan,

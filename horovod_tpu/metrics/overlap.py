@@ -445,6 +445,52 @@ def record_kda_beta_range(upper: int) -> None:
     ).set(upper)
 
 
+def record_gdn_plan(chunk: int, saved_state_bytes: int, key_lanes: int) -> None:
+    """Record what the latest traced ``ops.gdn.gdn`` (the gated delta rule
+    with one decay a head) cut its rows into, by ``record_kda_plan``'s rules
+    (trace time, once per compile, from the call's own shapes): the chunk
+    length, the bytes its backward keeps of the carried states, and the lanes
+    a head's keys take where the rule runs (128 in the kernels, zero lanes
+    after the head's own; the head's own width in ``jax.numpy``). All 0 until
+    such a scan is traced."""
+    registry().gauge(
+        "horovod_gdn_chunk_len",
+        help="positions a chunk of the latest traced ops.gdn.gdn (the gated "
+             "delta rule with one decay a head); 0 = none traced"
+    ).set(chunk)
+    registry().gauge(
+        "horovod_gdn_saved_state_bytes_per_layer",
+        help="bytes of carried states the backward of the latest traced "
+             "ops.gdn.gdn keeps (one call = one layer); 0 = none traced"
+    ).set(saved_state_bytes)
+    registry().gauge(
+        "horovod_gdn_key_lanes_padded",
+        help="lanes a head's keys take where the latest traced ops.gdn.gdn "
+             "runs (128 in the kernels, the head's own width in jax.numpy); "
+             "0 = none traced"
+    ).set(key_lanes)
+
+
+_GDN_LAYERS = {}    # {a mixer's path in its model: its latest traced scan took the kernels}
+
+
+def record_gdn_kernel_scan(layer: str, kernel: bool) -> None:
+    """Record whether the scan of the ``models.gdn.GDNMixer`` at ``layer``
+    (its path in the model, ``block_2/mixer``) took ``ops/gdn.py``'s pallas
+    kernels when it was last traced (trace time; a layer is traced for every
+    program that holds it, and again for its recomputation).
+    ``horovod_gdn_kernel_scans`` is the number of layers whose latest traced
+    scan did: a model of three such layers reads 3 while every one of them
+    runs the kernels, forward and backward, in whatever was traced last."""
+    _GDN_LAYERS[layer] = bool(kernel)
+    registry().gauge(
+        "horovod_gdn_kernel_scans",
+        help="GDNMixer layers (by their path in the model) whose latest "
+             "traced scan took the kernels hvd_gdn_scan_fwd / _bwd; 0 = none "
+             "traced, or every one kept jax.numpy"
+    ).set(sum(_GDN_LAYERS.values()))
+
+
 def record_short_conv_plan(taps: int, kernel: bool) -> None:
     """Record the taps of the latest traced gated short convolution
     (``models.short_conv.ShortConvMixer``; trace time, once per compile, from

@@ -3,6 +3,7 @@ examples/ + tf_cnn_benchmarks; here they are a first-class subpackage)."""
 
 from .resnet import ResNet, ResNet18, ResNet34, ResNet50, ResNet101, ResNet152  # noqa: F401
 from .mlp import MLP, ConvNet  # noqa: F401
+from .gdn import GDNDims, GDNMixer  # noqa: F401
 from .kda import KDADims, KDAMixer  # noqa: F401
 from .mamba import Mamba2Dims, Mamba2Mixer  # noqa: F401
 from .moe import (BIAS_COLLECTION, MoEMLP, aux_losses, ep_param_specs,  # noqa: F401

@@ -27,6 +27,7 @@ import numpy as np
 from ..common import device_names
 from ..ops.moe import CHOSEN_EXPERTS
 from ..ops.sparse_attention import ALIGN_GRADS, SELECTED
+from .gdn import GDNDims, GDNMixer
 from .kda import KDADims, KDAMixer
 from .mamba import Mamba2Dims, Mamba2Mixer
 from .short_conv import ShortConvDims, ShortConvMixer
@@ -321,6 +322,12 @@ class Block(nn.Module):
     # attention's place; the epsilon under the sigmoid router's chosen scores.
     conv: Optional[ShortConvDims] = None
     moe_route_eps: float = 1e-20
+    # What a delta-rule hybrid of the OLMo family's configuration states
+    # (Olmo-Hybrid: TransformerLM documents them): a Gated DeltaNet mixer in
+    # attention's place; the norm of each half on its OUTPUT,
+    # ``x + norm(f(x))``, where every other model's is ``x + f(norm(x))``.
+    gdn: Optional[GDNDims] = None
+    norm_after: bool = False
 
     @nn.compact
     def __call__(self, x, positions):
@@ -332,10 +339,13 @@ class Block(nn.Module):
             x = self._add(x, self._mixer(x, positions))
         if self.sublayers == "mixer":
             return x
-        with jax.named_scope(device_names.NORM_ADD):
-            h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
+        h = x if self.norm_after else self._norm(x)
         if self.moe_experts > 0:
             from .moe import MoEMLP
+
+            if self.norm_after:
+                raise ValueError("norm_after is stated for dense MLP halves: "
+                                 "this layer's second half is experts")
 
             hidden = (self.mlp_ratio * self.dim if self.moe_hidden is None
                       else self.moe_hidden)
@@ -360,7 +370,14 @@ class Block(nn.Module):
             else:
                 h = nn.gelu(dense(self.mlp_ratio * self.dim, "mlp_in")(h))
                 h = dense(self.dim, "mlp_out")(h)
-        return self._add(x, h)
+        return self._add(x, self._norm(h) if self.norm_after else h)
+
+    def _norm(self, x):
+        """A half's RMSNorm (the block's first call is the mixer's, its
+        second the MLP's): of the half's input, or with ``norm_after`` of its
+        output."""
+        with jax.named_scope(device_names.NORM_ADD):
+            return nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
 
     def _check_halves(self):
         """A size stated for a half this layer does not have is an error,
@@ -373,7 +390,8 @@ class Block(nn.Module):
                   "mlp": {"mamba": self.mamba is not None,
                           "mla": self.mla is not None,
                           "kda": self.kda is not None,
-                          "conv": self.conv is not None},
+                          "conv": self.conv is not None,
+                          "gdn": self.gdn is not None},
                   "both": {}}[self.sublayers]
         stated["moe_shared_hidden"] = (self.moe_shared_hidden > 0
                                        and self.moe_experts <= 0)
@@ -383,8 +401,12 @@ class Block(nn.Module):
                 f"{', '.join(extra)} stated for a layer (sublayers="
                 f"{self.sublayers!r}, moe_experts={self.moe_experts}) that "
                 f"has no such half")
-        other = [name for name in ("conv", "kda", "mamba", "mla")
+        other = [name for name in ("conv", "gdn", "kda", "mamba", "mla")
                  if getattr(self, name) is not None]
+        if self.gdn is not None and len(other) > 1:
+            raise ValueError(f"{' and '.join(other)} stated for ONE layer: "
+                             f"a 'linear_attention' layer's mixer is the "
+                             f"gated delta rule")
         if self.conv is not None and len(other) > 1:
             raise ValueError(f"{' and '.join(other)} stated for ONE layer: "
                              f"a 'conv' layer's mixer is the convolution")
@@ -396,11 +418,20 @@ class Block(nn.Module):
                 f"{self.sublayers!r}, mixer {other or 'none'}) runs none")
 
     def _mixer(self, x, positions):
-        """The mixer's branch of the normed ``x``: a gated short convolution,
-        a Mamba-2 mixer, a Kimi Delta Attention mixer, latent attention or
-        multi-head attention."""
-        with jax.named_scope(device_names.NORM_ADD):
-            h = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
+        """The mixer's branch: of the normed ``x``, or with ``norm_after``
+        the normed branch of ``x`` itself."""
+        if self.norm_after:
+            return self._norm(self._mixed(x, positions))
+        return self._mixed(self._norm(x), positions)
+
+    def _mixed(self, h, positions):
+        """The mixer on ``h``: a gated short convolution, a Mamba-2 mixer, a
+        Kimi Delta Attention mixer, a Gated DeltaNet mixer, latent attention
+        or multi-head attention."""
+        if self.gdn is not None:
+            return GDNMixer(dim=self.dim, dims=self.gdn,
+                            rms_norm_eps=self.rms_norm_eps, dtype=self.dtype,
+                            interpret=self.flash_interpret, name="mixer")(h)
         if self.conv is not None:
             return ShortConvMixer(dim=self.dim, dims=self.conv,
                                   dtype=self.dtype,
@@ -911,6 +942,19 @@ class TransformerLM(nn.Module):
     # is DeepSeek-V3's 1e-20).
     conv: Optional[ShortConvDims] = None
     moe_route_eps: float = 1e-20
+    # What a delta-rule hybrid of the OLMo family's configuration states
+    # (Olmo-Hybrid-7B: ``linear_num_key_heads``, ``linear_key_head_dim``,
+    # ``linear_value_head_dim``, ``linear_conv_kernel_dim``,
+    # ``linear_allow_neg_eigval``). layer_types may also name
+    # "linear_attention": a Gated DeltaNet mixer of the sizes in ``gdn``
+    # (models/gdn.py: a gated delta rule with ONE decay a head, keys and
+    # values of different widths, a silu-gated head norm) in attention's
+    # place. norm_after: OLMo 2's reordered norm, ``x + norm(f(x))`` for both
+    # halves of every layer with no norm before ``f`` (dense MLP halves only);
+    # its "full_attention" layers are ``qk_norm`` attention with ``rope``
+    # as stated.
+    gdn: Optional[GDNDims] = None
+    norm_after: bool = False
 
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden: bool = False):
@@ -921,11 +965,12 @@ class TransformerLM(nn.Module):
         mtp_kinds = tuple(self.mtp_layer_types or ())
         if len(kinds) != self.layers or set(kinds + mtp_kinds) - {
                 "attention", "mamba", "full_attention", "sliding_attention",
-                "kda", "conv", *_ONE_SUBLAYER}:
+                "kda", "conv", "linear_attention", *_ONE_SUBLAYER}:
             raise ValueError(
                 f"layer_types {kinds} (mtp_layer_types {mtp_kinds}) must name "
                 f"'attention', 'mamba', 'full_attention', 'sliding_attention', "
-                f"'kda', 'conv' or, for a layer that is one sub-layer, 'mamba_only', "
+                f"'kda', 'conv', 'linear_attention' or, for a layer that is one "
+                f"sub-layer, 'mamba_only', "
                 f"'attention_only' or 'experts_only' for each of the "
                 f"{self.layers} layers")
         if {"mamba", "mamba_only"} & set(kinds + mtp_kinds) and self.mamba is None:
@@ -934,6 +979,9 @@ class TransformerLM(nn.Module):
             raise ValueError("a 'kda' layer needs the mixer's sizes (kda=)")
         if "conv" in kinds + mtp_kinds and self.conv is None:
             raise ValueError("a 'conv' layer needs the mixer's sizes (conv=)")
+        if "linear_attention" in kinds + mtp_kinds and self.gdn is None:
+            raise ValueError("a 'linear_attention' layer needs the mixer's "
+                             "sizes (gdn=)")
         if "experts_only" in kinds + mtp_kinds and self.moe_experts <= 0:
             raise ValueError("an 'experts_only' layer needs its experts "
                              "(moe_experts=, moe_top_k=)")
@@ -1013,7 +1061,8 @@ class TransformerLM(nn.Module):
                 attention_scale=self.attention_multiplier,
                 residual_scale=self.residual_multiplier,
                 mla=(self.mla if sublayers != "mlp"
-                     and kind not in ("kda", "conv") else None),
+                     and kind not in ("kda", "conv", "linear_attention")
+                     else None),
                 rope_theta=self.rope_theta,
                 rope_interleave=self.rope_interleave,
                 moe_router=self.moe_router,
@@ -1034,6 +1083,8 @@ class TransformerLM(nn.Module):
                 kda=self.kda if kind == "kda" else None,
                 conv=self.conv if kind == "conv" else None,
                 moe_route_eps=self.moe_route_eps,
+                gdn=self.gdn if kind == "linear_attention" else None,
+                norm_after=self.norm_after,
                 name=name,
             )
 

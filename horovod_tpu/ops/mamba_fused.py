@@ -74,6 +74,10 @@ from ..common.device_names import (MAMBA_CONV_BWD, MAMBA_CONV_FWD,
 # libtpu, well inside the v5e's 128 MiB of VMEM.
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _BLOCK_BYTES = 5 * 1024 * 1024      # the most one block of rows may hold
+# ... and of the convolution's alone, whose backward holds three blocks (x,
+# dy, dx) and their doubles: 34 MiB at the 5,760 channels of Olmo-Hybrid's
+# [q | k | v] side by side (15 heads of 96 | 96 | 192), 5.6 MiB a block.
+_CONV_BLOCK_BYTES = 6 * 1024 * 1024
 # ... and of the gated convolution's [B | C | X]: 512 rows of LFM2's 3 x 2,048
 # in bf16. Its backward holds that block, its gradient's and dy's, and their
 # doubles: 28 MiB.
@@ -88,14 +92,14 @@ def row_tile(itemsize: int) -> int:
     return 1024 // itemsize
 
 
-def _tiles(x) -> bool:
+def _tiles(x, block_bytes=_BLOCK_BYTES) -> bool:
     """bf16 or f32, the rows a whole number of row tiles, a block of rows by
-    all the features at most ``_BLOCK_BYTES``."""
+    all the features at most ``block_bytes``."""
     if x.ndim != 3 or x.dtype not in (jnp.bfloat16, jnp.float32):
         return False
     tile_bytes = row_tile(x.dtype.itemsize) * x.dtype.itemsize
     return (x.shape[1] % row_tile(x.dtype.itemsize) == 0
-            and x.shape[2] * tile_bytes <= _BLOCK_BYTES)
+            and x.shape[2] * tile_bytes <= block_bytes)
 
 
 def conv_takes_kernel(x, kernel, splits=None) -> bool:
@@ -103,7 +107,7 @@ def conv_takes_kernel(x, kernel, splits=None) -> bool:
     column runs of widths ``splits``, is a shape the convolution's kernels
     tile: bf16 or f32, C and every run a multiple of 128, T a multiple of the
     row tile, K of at most 9 taps."""
-    return (_tiles(x) and 1 <= kernel.shape[0] <= _MAX_TAPS
+    return (_tiles(x, _CONV_BLOCK_BYTES) and 1 <= kernel.shape[0] <= _MAX_TAPS
             and all(w % 128 == 0 for w in splits or (x.shape[2],)))
 
 

@@ -816,3 +816,62 @@ def test_sparse_attention_kernels_compile_for_v5e(one_chip, no_persistent_cache,
     import re
     for dims in re.findall(r"\b(?:f32|bf16|s32|u32|pred|s8|u8)\[([0-9,]+)\]", text):
         assert sum(int(n) >= t for n in dims.split(",")) < 2, dims
+
+
+@pytest.mark.parametrize("dtype,precision", [(jnp.bfloat16, None),
+                                             (jnp.float32, "highest")],
+                         ids=["bf16", "f32_highest"])
+def test_the_scalar_decay_rules_kernels_compile_for_v5e_at_the_cells_shape(
+        one_chip, no_persistent_cache, dtype, precision):
+    """olmo_hybrid_seq16384_1chip's row through ``ops.gdn.gdn``: (1, 16384, 15
+    heads of 96 | 192) with ONE decay a head and beta in (0, 2), laid out on
+    128 | 256 lanes a head, blocks of 512 positions; the step's bf16 and the
+    check's float32 under "highest". Forward and backward: both kernels are
+    in the compiled program, and no loop over blocks is."""
+    from horovod_tpu.common.device_names import GDN_SCAN_BWD, GDN_SCAN_FWD
+    from horovod_tpu.ops import gdn as gdn_ops
+
+    t, heads = 16384, 15
+
+    def shape(*dims, of=dtype):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    args = (shape(1, t, heads, 96), shape(1, t, heads, 96),
+            shape(1, t, heads, 192), shape(1, t, heads, of=jnp.float32),
+            shape(1, t, heads, of=jnp.float32))
+    assert gdn_ops.takes_kernel(*args[:3], 64)
+
+    def value_and_grads(*a):    # the value too: a forward nobody reads is cut
+        out, vjp = jax.vjp(lambda *a: gdn_ops.gdn(*a, neg_eigval=True), *a)
+        return out, vjp(out)
+
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(value_and_grads).lower(*args).compile().as_text()
+    for name in (GDN_SCAN_FWD, GDN_SCAN_BWD):
+        assert name in text, f"{name} is not in the compiled module"
+    assert " while(" not in text
+
+
+def test_the_three_convolutions_side_by_side_compile_for_v5e(
+        one_chip, no_persistent_cache):
+    """olmo_hybrid_seq16384_1chip's [q | k | v] through ONE call of the
+    convolution's kernel pair: (1, 16384, 5760) bf16, 5.6 MiB a block of rows
+    (``_CONV_BLOCK_BYTES``), under the mixer's names."""
+    from horovod_tpu.models.gdn import CONV_NAMES
+    from horovod_tpu.ops import mamba_fused
+
+    def shape(*dims, of=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    args = (shape(1, 16384, 5760, of=jnp.bfloat16), shape(4, 5760),
+            shape(5760))
+    assert mamba_fused.conv_takes_kernel(*args[:2])
+
+    def value_and_grads(*a):
+        out, vjp = jax.vjp(lambda *a: mamba_fused.conv_silu(
+            *a, names=CONV_NAMES), *a)
+        return out, vjp(out)
+
+    text = jax.jit(value_and_grads).lower(*args).compile().as_text()
+    for name in CONV_NAMES:
+        assert name in text, f"{name} is not in the compiled module"

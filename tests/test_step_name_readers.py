@@ -31,7 +31,8 @@ LM_CELLS = {"lm217m_long_1chip", "lm217m_short_1chip", "olmoe_seq4096_1chip",
             "granite4h_long_1chip", "kanana2_seq8192_1chip",
             "laguna_xs2_seq16384_1chip", "nemotron3s_seq8192_1chip",
             "keye_vl2_seq16384_1chip", "kimi_linear_seq16384_1chip",
-            "solar_open2_seq8192_1chip", "lfm2_seq8192_1chip"}
+            "solar_open2_seq8192_1chip", "lfm2_seq8192_1chip",
+            "olmo_hybrid_seq16384_1chip"}
 PINNED_CELLS = {"laguna_xs2_seq16384_1chip", "keye_vl2_seq16384_1chip"}
 
 
