@@ -1161,13 +1161,19 @@ def chunked_lm_loss(hidden, head_kernel, targets, chunk: int = 2048):
 
     Use with ``model.apply(..., return_hidden=True)``; ``head_kernel`` is
     ``params["lm_head"]["kernel"]``. Peak extra memory is at most one chunk's
-    logits and their gradient (B·chunk·vocab f32 each) plus, when
-    differentiated, the f32 (d, vocab) accumulator of the kernel's
+    logits and their gradient (rows·vocab f32 each, rows = B·chunk) plus,
+    when differentiated, the f32 (d, vocab) accumulator of the kernel's
     gradient — the difference between OOM and training at 32k+ tokens
     with a 32k vocab. The loss is the mean over every position; logits,
     softmax and the kernel's gradient are float32 whatever ``hidden``'s
     dtype, and the three products follow ``jax.default_matmul_precision``
     as a plain ``@`` does.
+
+    The loop takes a chunk of every sequence as ONE block of rows,
+    ``(B·chunk, d)``, not ``(B, chunk, d)``: handed 2-D logits the TPU
+    compiler fuses the row max into the product at every shape the benchmark
+    has, where 3-D logits of 2 x 2048 x 8192 got a softmax fusion that wrote
+    ``logits - max`` back to HBM (PR 66).
 
     The gradients come from a ``jax.custom_vjp``: reverse mode only, once.
     Forward mode (``jvp`` / ``jacfwd`` / ``linearize``) and second
@@ -1210,10 +1216,17 @@ def lm_loss_with_mtp(hidden, mtp_hidden, head_kernel, tokens,
 
 
 def _loss_chunks(hidden, targets, chunk):
-    b, t, d = hidden.shape
+    """``(n, b * chunk, d)`` hidden states and ``(n, b * chunk)`` targets:
+    iteration ``i`` holds tokens ``[i * chunk, (i + 1) * chunk)`` of every
+    sequence, as one block of rows."""
+    b, t = targets.shape
     n = t // chunk
-    return (hidden.reshape(b, n, chunk, d).swapaxes(0, 1),  # (n, b, chunk, d)
-            targets.reshape(b, n, chunk).swapaxes(0, 1))
+
+    def rows(x):
+        return (x.reshape(b, n, chunk, *x.shape[2:]).swapaxes(0, 1)
+                .reshape(n, b * chunk, *x.shape[2:]))
+
+    return rows(hidden), rows(targets)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -1226,7 +1239,7 @@ def _chunked_lm_loss(hidden, head_kernel, targets, chunk):
 
     def one(ht):
         hc, tc = ht
-        logits = hc.astype(jnp.float32) @ head_kernel    # (b, chunk, vocab)
+        logits = hc.astype(jnp.float32) @ head_kernel    # (rows, vocab)
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, tc).mean()
 
@@ -1235,7 +1248,7 @@ def _chunked_lm_loss(hidden, head_kernel, targets, chunk):
 
 def _chunked_lm_loss_fwd(hidden, head_kernel, targets, chunk):
     """The loss AND its two gradients (for a cotangent of 1) from one scan
-    over chunks; the residuals are the gradients themselves."""
+    over blocks of rows; the residuals are the gradients themselves."""
     from ..metrics import record_chunked_loss_plan
 
     record_chunked_loss_plan(3)
@@ -1245,22 +1258,23 @@ def _chunked_lm_loss_fwd(hidden, head_kernel, targets, chunk):
     def one(d_kernel, ht):
         hc, tc = ht
         hf = hc.astype(jnp.float32)
-        logits = hf @ head_kernel                        # (b, chunk, vocab)
+        logits = hf @ head_kernel                        # (rows, vocab)
         shifted = logits - logits.max(-1, keepdims=True)
         e = jnp.exp(shifted)
         z = e.sum(-1, keepdims=True)
-        hit = jax.lax.broadcasted_iota(tc.dtype, shifted.shape, 2) == tc[..., None]
-        loss = (jnp.log(z[..., 0])
+        hit = jax.lax.broadcasted_iota(tc.dtype, shifted.shape, 1) == tc[:, None]
+        loss = (jnp.log(z[:, 0])
                 - jnp.where(hit, shifted, 0.0).sum(-1)).mean()
         dlogits = (e / z - hit) / (b * t)
         d_hidden = (dlogits @ head_kernel.T).astype(hidden.dtype)
-        d_kernel = d_kernel + jnp.tensordot(hf, dlogits, ((0, 1), (0, 1)))
+        d_kernel = d_kernel + jnp.tensordot(hf, dlogits, ((0,), (0,)))
         return d_kernel, (loss, d_hidden)
 
     d_kernel, (losses, d_hidden) = jax.lax.scan(
         one, jnp.zeros((d, vocab), jnp.float32),
         _loss_chunks(hidden, targets, chunk))
-    return losses.mean(), (d_hidden.swapaxes(0, 1).reshape(b, t, d),
+    d_hidden = d_hidden.reshape(-1, b, chunk, d).swapaxes(0, 1)
+    return losses.mean(), (d_hidden.reshape(b, t, d),
                            d_kernel.astype(head_kernel.dtype))
 
 

@@ -5,8 +5,9 @@ experts, and a share of 16 whose groups do not fill the row buffer), the
 expert layer of such a share whole (its loops over the live windows),
 the chunked state-space scan's two kernels, the gated delta rule's two with
 the four of its mixer's fused passes (L2 norms + log-decay, head norm then
-gate), and the Mamba-2 mixer's four fused kernels (convolution + silu, gated
-norm)
+gate), the Mamba-2 mixer's four fused kernels (convolution + silu, gated
+norm), and the chunked loss's loop at three cells' heads (what its body
+writes, read off the compiled module)
 COMPILED for a
 described v5e at the benchmark cells' shapes (no chip attached, nothing runs): what interpret mode cannot
 see — scoped VMEM, tiling and layout faults of a kernel edit — is refused
@@ -17,7 +18,9 @@ one process may load the TPU's library, and every xdist worker imports
 every test file. Keep these tests in this one file.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -875,3 +878,83 @@ def test_the_three_convolutions_side_by_side_compile_for_v5e(
     text = jax.jit(value_and_grads).lower(*args).compile().as_text()
     for name in CONV_NAMES:
         assert name in text, f"{name} is not in the compiled module"
+
+
+LOSS_CHUNK = 2048
+
+# (B, T, d) hidden states x vocabulary, and whether the kernel is a transposed
+# embedding, of lfm2_seq8192_1chip, kanana2_seq8192_1chip, olmoe_seq4096_1chip
+CELL_HEADS = {"lfm2": ((2, 8192, 2048), 8192, True),
+              "kanana2": ((2, 8192, 2048), 16032, False),
+              "olmoe": ((4, 4096, 2048), 50304, False)}
+
+
+def _loop_bodies(text, scope):
+    """The text of each computation that a ``while`` of ``text`` names as its
+    body and that carries the name ``scope``."""
+    bodies = []
+    for name in set(re.findall(r"\bwhile\(.*\bbody=(%[\w.\-]+)", text)):
+        start = text.index(f"\n{name} (")
+        body = text[start:text.index("\n}", start)]
+        if scope in body:
+            bodies.append(body)
+    return bodies
+
+
+def _fusion_outputs(body):
+    """``(instruction, [(dtype, dims), ...])`` of each fusion called from
+    ``body`` (the lines of nested computations are not in ``body``)."""
+    for line in body.splitlines():
+        called = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.*?) fusion\(", line)
+        if called:
+            yield called.group(1), [
+                (dtype, tuple(int(n) for n in dims.split(",")))
+                for dtype, dims in re.findall(r"\b([a-z]+\d+)\[([\d,]+)\]",
+                                              called.group(2))]
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_HEADS))
+def test_loss_loop_writes_its_logits_once(one_chip, no_persistent_cache,
+                                          cell):
+    """``chunked_lm_loss`` under ``jax.value_and_grad`` at three cells' shapes,
+    and the form of its loop's body read off the compiled module: the logits
+    are written ONCE, by their own product. At lfm2's 2 x 2048 rows of
+    vocabulary 8192 the compiler gave a loop handed ``(batch, chunk, d)`` a
+    softmax fusion of three outputs, the row max, a row sum and ``logits -
+    max`` WRITTEN BACK at the logits' size: 47 of the cell's 57 ms of
+    ``hvd_lm_head`` a step (ledger, PR 65). Handed the same rows merged,
+    ``(batch * chunk, d)``, it fuses the row max into the product's epilogue
+    and reads the logits once for both sums, at every shape here (PR 66)."""
+    from horovod_tpu.common.device_names import LM_HEAD
+    from horovod_tpu.models.transformer import chunked_lm_loss
+
+    (b, t, d), vocab, tied = CELL_HEADS[cell]
+    rows = b * LOSS_CHUNK
+
+    def loss(hidden, kernel, targets):
+        return chunked_lm_loss(hidden, kernel.T if tied else kernel, targets,
+                               LOSS_CHUNK)
+
+    def shape(*dims, of):
+        return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
+
+    text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        shape(b, t, d, of=jnp.bfloat16),
+        shape(*((vocab, d) if tied else (d, vocab)), of=jnp.float32),
+        shape(b, t, of=jnp.int32)).compile().as_text()
+    (body,) = _loop_bodies(text, LM_HEAD)
+
+    def logits_sized(dtype, dims):
+        return (dtype == "f32" and dims[-1] == vocab
+                and math.prod(dims[:-1]) == rows)
+
+    writers = {name: sum(logits_sized(*out) for out in outs)
+               for name, outs in _fusion_outputs(body)}
+    assert len(writers) >= 4, f"the loop's body was not read: {writers}"
+    assert sum(writers.values()) == 1, (
+        f"{rows} x {vocab} float32 written by {writers}: the loop's logits "
+        "leave one fusion, their product's, and no softmax pass writes "
+        "another array of their size")
+    (product,) = (name for name, n in writers.items() if n)
+    assert re.search(rf"{re.escape(product)} = .*kind=kOutput.*dot_general",
+                     body), f"{product} is not the logits' product"

@@ -164,21 +164,36 @@ def _assert_head_grads(got, want, hidden_dtype, scale=1.0):
 @pytest.mark.parametrize("b", [1, 3])
 @pytest.mark.parametrize("chunks", [1, 2, 4])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_chunked_lm_loss_and_gradients_match_full_logits(dtype, chunks, b):
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_chunked_lm_loss_and_gradients_match_full_logits(tied, dtype, chunks,
+                                                         b):
     """The loss (differentiated or not) and both gradients of the chunked
-    head against optax's cross entropy on the full logits."""
+    head against optax's cross entropy on the full logits. ``tied``: the
+    kernel is a TRANSPOSED ``(vocab, d)`` embedding (lfm2's and granite's
+    call) and the gradient is the embedding's; at ``b`` = 3 over several
+    chunks that holds the un-merge of ``d_hidden``'s rows as well."""
     from horovod_tpu.models.transformer import chunked_lm_loss
 
     hidden, kernel, targets = _tiny_head(dtype, b)
     chunk = hidden.shape[1] // chunks
+    turned = jnp.transpose if tied else (lambda w: w)
+    kernel = turned(kernel)
+
+    def full(hidden, kernel, targets):
+        return _full_logits_loss(hidden, turned(kernel), targets)
+
+    def chunked(hidden, kernel, targets, chunk):
+        return chunked_lm_loss(hidden, turned(kernel), targets, chunk)
+
     with jax.default_matmul_precision("highest"):
-        want, want_grads = jax.value_and_grad(_full_logits_loss, (0, 1))(
+        want, want_grads = jax.value_and_grad(full, (0, 1))(
             hidden, kernel, targets)
-        plain = chunked_lm_loss(hidden, kernel, targets, chunk)
-        got, got_grads = jax.value_and_grad(chunked_lm_loss, (0, 1))(
+        plain = chunked(hidden, kernel, targets, chunk)
+        got, got_grads = jax.value_and_grad(chunked, (0, 1))(
             hidden, kernel, targets, chunk)
     np.testing.assert_allclose(float(plain), float(want), atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(float(got), float(want), atol=1e-5, rtol=1e-5)
+    assert got_grads[1].shape == kernel.shape
     _assert_head_grads(got_grads, want_grads, dtype)
 
 
