@@ -140,7 +140,14 @@ ATTN_PROJ = "hvd_attn_proj"
 ATTN = "hvd_attn"
 NORM_ADD = "hvd_norm_add"               # pre-norms, residual adds, the final norm
 EMBED = "hvd_embed"                     # the token lookup (+ its scatter back)
-LM_HEAD = "hvd_lm_head"                 # the MAIN head's products and its loss
+# the MAIN head's products and its loss; a looped model's head passes, one a
+# pass of the stack, and their weighted loss are all the main head's
+LM_HEAD = "hvd_lm_head"
+# A looped model's early exit (models/transformer.py, ``TransformerLM.passes``
+# / ``exit_gate``, ``loop_lm_loss``): the gates' product and sigmoid, the exit
+# distribution, its entropy and the weights handed to the loss, forward and
+# backward.
+LOOP_EXIT = "hvd_loop_exit"
 MOE_LOGITS = "hvd_moe_logits"           # the router's float32 product + its cast
 # the expert weights' cast to the rows' dtype before ``lax.ragged_dot``
 # (``ops/moe.py``); the repo's kernels read the parameters: nothing under it

@@ -45,6 +45,8 @@ from .overlap import (  # noqa: F401
     record_kda_beta_range,
     record_kda_fused_mixer,
     record_kda_plan,
+    record_loop_exit_mass,
+    record_loop_plan,
     record_mamba_fused_passes,
     record_moe_dispatch_rows,
     record_moe_grouped_plan,

@@ -491,6 +491,48 @@ def record_gdn_kernel_scan(layer: str, kernel: bool) -> None:
     ).set(sum(_GDN_LAYERS.values()))
 
 
+def record_loop_plan(passes: int, block_applications: int) -> None:
+    """Record the loop of the latest traced looped ``TransformerLM``
+    (``passes`` > 1 or ``exit_gate``; trace time, once per compile): the
+    passes the residual stream makes over the one stack, and the block
+    applications that is (``layers x passes``: what the step runs, and under
+    ``remat`` recomputes one by one). Both 0 until such a model is traced."""
+    registry().gauge(
+        "horovod_loop_passes",
+        help="passes over the shared stack of layers in the latest traced "
+             "looped TransformerLM; 0 = none traced"
+    ).set(passes)
+    registry().gauge(
+        "horovod_loop_block_applications",
+        help="block applications (layers x passes) of the latest traced "
+             "looped TransformerLM; 0 = none traced"
+    ).set(block_applications)
+
+
+def record_loop_exit_mass(exit_mass) -> None:
+    """Record where a looped model's tokens EXIT in the steps a training loop
+    hands in: ``exit_mass`` is (steps, passes) of the mean ``p_t`` over each
+    step's tokens (``models.transformer.loop_lm_loss``'s ``exit_mass``),
+    CONCRETE (read on the host between steps, as ``record_moe_live_rows``'
+    rows are; never from inside a jitted step). ``horovod_loop_exit_mass``,
+    labelled by the pass (``loop_pass``, 1-based: ``pass`` is no keyword a
+    call can give), is the mean over the steps; the passes'
+    values sum to 1 and ``sum_t t x mass[t]`` is the mean exit pass: a gate
+    that died reads 1.0 (everything leaves after the first pass) or the
+    number of passes (nothing leaves early)."""
+    steps = [[float(p) for p in step] for step in exit_mass]
+    if not steps:
+        return
+    for t in range(len(steps[0])):
+        registry().gauge(
+            "horovod_loop_exit_mass",
+            help="mean share of a step's tokens whose exit distribution "
+                 "leaves after this pass of a looped model (mean over the "
+                 "recorded steps; the passes sum to 1)",
+            loop_pass=str(t + 1)
+        ).set(sum(step[t] for step in steps) / len(steps))
+
+
 def record_short_conv_plan(taps: int, kernel: bool) -> None:
     """Record the taps of the latest traced gated short convolution
     (``models.short_conv.ShortConvMixer``; trace time, once per compile, from

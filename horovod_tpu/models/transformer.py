@@ -328,12 +328,19 @@ class Block(nn.Module):
     # ``x + norm(f(x))``, where every other model's is ``x + f(norm(x))``.
     gdn: Optional[GDNDims] = None
     norm_after: bool = False
+    # What a looped model's configuration states (Ouro: TransformerLM
+    # documents it): a norm on BOTH sides of each half,
+    # ``x + norm(f(norm(x)))``, four RMSNorms a layer.
+    sandwich_norm: bool = False
 
     @nn.compact
     def __call__(self, x, positions):
         if self.attention not in ("dense", "flash"):
             raise ValueError(
                 f"unknown attention={self.attention!r}; use 'dense' or 'flash'")
+        if self.norm_after and self.sandwich_norm:
+            raise ValueError("norm_after and sandwich_norm state two "
+                             "placements of one half's norm")
         self._check_halves()
         if self.sublayers != "mlp":
             x = self._add(x, self._mixer(x, positions))
@@ -343,9 +350,10 @@ class Block(nn.Module):
         if self.moe_experts > 0:
             from .moe import MoEMLP
 
-            if self.norm_after:
-                raise ValueError("norm_after is stated for dense MLP halves: "
-                                 "this layer's second half is experts")
+            if self.norm_after or self.sandwich_norm:
+                raise ValueError("norm_after and sandwich_norm are stated for "
+                                 "dense MLP halves: this layer's second half "
+                                 "is experts")
 
             hidden = (self.mlp_ratio * self.dim if self.moe_hidden is None
                       else self.moe_hidden)
@@ -370,12 +378,15 @@ class Block(nn.Module):
             else:
                 h = nn.gelu(dense(self.mlp_ratio * self.dim, "mlp_in")(h))
                 h = dense(self.dim, "mlp_out")(h)
-        return self._add(x, self._norm(h) if self.norm_after else h)
+        return self._add(x, self._norm(h) if self.norm_after
+                         or self.sandwich_norm else h)
 
     def _norm(self, x):
-        """A half's RMSNorm (the block's first call is the mixer's, its
-        second the MLP's): of the half's input, or with ``norm_after`` of its
-        output."""
+        """One RMSNorm of a half, in one of three placements: of the half's
+        input (the block's first call is then the mixer's, its second the
+        MLP's); with ``norm_after`` of its output; with ``sandwich_norm`` of
+        both (four calls a layer: the mixer's input and output, then the
+        MLP's)."""
         with jax.named_scope(device_names.NORM_ADD):
             return nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype)(x)
 
@@ -418,11 +429,13 @@ class Block(nn.Module):
                 f"{self.sublayers!r}, mixer {other or 'none'}) runs none")
 
     def _mixer(self, x, positions):
-        """The mixer's branch: of the normed ``x``, or with ``norm_after``
-        the normed branch of ``x`` itself."""
+        """The mixer's branch: of the normed ``x``, with ``norm_after`` the
+        normed branch of ``x`` itself, with ``sandwich_norm`` the normed
+        branch of the normed ``x``."""
         if self.norm_after:
             return self._norm(self._mixed(x, positions))
-        return self._mixed(self._norm(x), positions)
+        branch = self._mixed(self._norm(x), positions)
+        return self._norm(branch) if self.sandwich_norm else branch
 
     def _mixed(self, h, positions):
         """The mixer on ``h``: a gated short convolution, a Mamba-2 mixer, a
@@ -955,6 +968,27 @@ class TransformerLM(nn.Module):
     # as stated.
     gdn: Optional[GDNDims] = None
     norm_after: bool = False
+    # A looped model (Ouro-2.6B: docs/looped.md), each as the model's own
+    # configuration states it. passes (``total_ut_steps``): the residual
+    # stream goes through the ONE stack of ``layers`` blocks that many times,
+    # every pass over the same parameters (one ``block_{i}`` leaf set, whose
+    # gradient is the sum of the passes'); the model's one final RMSNorm
+    # closes EVERY pass, and its output is what enters the next. With
+    # ``remat`` each block APPLICATION is recomputed on its own (layers x
+    # passes saved streams). exit_gate: a one-number early-exit gate
+    # ``x w_e + b_e`` (leaf ``exit_gate``, float32) reads every pass's normed
+    # stream. With ``passes`` > 1 or ``exit_gate`` the call returns a PAIR:
+    # the passes' results stacked on a leading axis ((passes, B, T, dim)
+    # hidden states with return_hidden, else (passes, B, T, vocab) logits
+    # through the SAME head) and the gates' logits ((passes, B, T) float32;
+    # None without a gate), for ``loop_lm_loss``, which takes the loss
+    # expected over the exit distribution the gates define. sandwich_norm: a
+    # norm on BOTH sides of each half, ``x + norm(f(norm(x)))``, four RMSNorms
+    # a layer (dense MLP halves only), where ``norm_after`` has the second
+    # alone and every other model the first.
+    passes: int = 1
+    exit_gate: bool = False
+    sandwich_norm: bool = False
 
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden: bool = False):
@@ -1016,6 +1050,17 @@ class TransformerLM(nn.Module):
                 f"first_k_dense={self.first_k_dense} states dense layers "
                 f"before experts in EVERY later layer: it needs moe_experts > 0 "
                 f"and moe_every=1, not {self.moe_experts} and {self.moe_every}")
+        looped = self.passes > 1 or self.exit_gate
+        if self.passes < 1:
+            raise ValueError(f"passes {self.passes}: the stack runs at least "
+                             f"once")
+        if looped and (mtp_kinds or self.logits_scaling != 1.0
+                       or self.tie_embeddings):
+            raise ValueError(
+                "a looped model (passes > 1 or exit_gate) hands every pass's "
+                "normed stream on as it is, to an untied head: no "
+                "multi-token-prediction module, logits_scaling or "
+                "tie_embeddings")
         embed = nn.Embed(self.vocab, self.dim, dtype=self.dtype, name="embed")
         with jax.named_scope(device_names.EMBED):
             x = embed(tokens)
@@ -1085,14 +1130,23 @@ class TransformerLM(nn.Module):
                 moe_route_eps=self.moe_route_eps,
                 gdn=self.gdn if kind == "linear_attention" else None,
                 norm_after=self.norm_after,
+                sandwich_norm=self.sandwich_norm,
                 name=name,
             )
 
-        for i in range(self.layers):
-            x = block(kinds[i], f"block_{i}", heads[i],
-                      self.moe_experts > 0
-                      and i % self.moe_every == self.moe_every - 1
-                      and i >= self.first_k_dense)(x, positions)
+        def stack():
+            """The model's layers, in order; built where they are called (a
+            looped model builds them inside its scanned pass)."""
+            return [block(kinds[i], f"block_{i}", heads[i],
+                          self.moe_experts > 0
+                          and i % self.moe_every == self.moe_every - 1
+                          and i >= self.first_k_dense)
+                    for i in range(self.layers)]
+
+        if looped:
+            return self._loop(stack, x, positions, return_hidden)
+        for layer in stack():
+            x = layer(x, positions)
         if mtp_kinds:
             with jax.named_scope(device_names.MTP):
                 following = embed(jnp.roll(tokens, -1, axis=1))
@@ -1138,6 +1192,48 @@ class TransformerLM(nn.Module):
             logits = head(x)    # the main head's; the module's pass has no name
         return (logits, head(y)) if mtp_kinds else logits
 
+    def _loop(self, stack, x, positions, return_hidden):
+        """``passes`` passes of ``x`` through ``stack()``, the same parameters
+        each time, each closed by the model's ONE final norm, whose output is
+        read by the exit gate and the head and enters the next pass. Returns
+        (the passes' normed streams or their logits, stacked; the gates'
+        logits or None). The pass is the body of ONE ``lax.scan`` over the
+        passes (``nn.scan`` with the parameters broadcast): traced, lowered
+        and compiled once whatever ``passes`` is, and under ``remat`` each
+        block application of each pass its own recomputation. An unrolled
+        trace of the same 32 applications took 0.6% LONGER a step on the v5e
+        and 2.6 times as long to compile (PERF.md section 6, PR 67)."""
+        from ..metrics import record_loop_plan
+
+        record_loop_plan(self.passes, self.layers * self.passes)
+
+        def one_pass(mdl, x, _):
+            # the stack, the final norm and the gate are built INSIDE the
+            # scanned function, under the scan's own scope; their parameters
+            # are broadcast over the passes
+            for layer in stack():
+                x = layer(x, positions)
+            with jax.named_scope(device_names.NORM_ADD):
+                x = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=self.dtype,
+                               name="RMSNorm_0")(x)
+            if not self.exit_gate:
+                return x, (x, None)
+            with jax.named_scope(device_names.LOOP_EXIT):
+                gate = nn.Dense(1, dtype=jnp.float32, name="exit_gate")(x)
+            return x, (x, gate[..., 0])
+
+        x, (hidden, gates) = nn.scan(
+            one_pass, variable_broadcast="params",
+            split_rngs={"params": False}, length=self.passes)(self, x, None)
+        head = nn.Dense(self.vocab, use_bias=False, dtype=self.logits_dtype,
+                        name="lm_head")
+        if return_hidden:
+            if self.is_initializing():
+                head(x[:, :1])  # param tree must not depend on the flag
+            return hidden, gates
+        with jax.named_scope(device_names.LM_HEAD):
+            return head(hidden), gates
+
 
 def align_losses(intermediates):
     """(alignment loss, selected pairs, live block steps), each summed over
@@ -1150,7 +1246,8 @@ def align_losses(intermediates):
     ).values())
 
 
-def chunked_lm_loss(hidden, head_kernel, targets, chunk: int = 2048):
+def chunked_lm_loss(hidden, head_kernel, targets, chunk: int = 2048,
+                    weights=None):
     """Next-token cross entropy without ever materializing the full
     (B, T, vocab) logits: the lm_head + softmax-CE run over sequence chunks,
     and under ``jax.grad`` each chunk's gradients are formed in the same
@@ -1169,6 +1266,17 @@ def chunked_lm_loss(hidden, head_kernel, targets, chunk: int = 2048):
     dtype, and the three products follow ``jax.default_matmul_precision``
     as a plain ``@`` does.
 
+    ``weights`` (float32, ``hidden``'s shape without its last axis): a weight
+    a ROW, which is data and takes a gradient: the loss is then ``(1 / N)
+    sum_rows weights[row] CE[row]`` with N = B·T. ``hidden`` may then carry a
+    leading axis of PASSES, ``(P, B, T, d)`` under weights ``(P, B, T)``
+    against the one ``targets`` (B, T): the readings of one head by the P
+    passes of a looped model (``TransformerLM.passes``), summed over the
+    passes, in ONE loop over (pass, chunk) that holds one accumulator of the
+    kernel's gradient. Weights of 1 on a ``(B, T, d)`` ``hidden`` give the
+    unweighted loss and its gradients, bit for bit. Without ``weights`` the
+    loop traces as it did before there were any.
+
     The loop takes a chunk of every sequence as ONE block of rows,
     ``(B·chunk, d)``, not ``(B, chunk, d)``: handed 2-D logits the TPU
     compiler fuses the row max into the product at every shape the benchmark
@@ -1176,25 +1284,44 @@ def chunked_lm_loss(hidden, head_kernel, targets, chunk: int = 2048):
     ``logits - max`` back to HBM (PR 66).
 
     The gradients come from a ``jax.custom_vjp``: reverse mode only, once.
-    Forward mode (``jvp`` / ``jacfwd`` / ``linearize``) and second
-    derivatives (``hessian``) through this loss are not available; nothing
-    in horovod_tpu, benchmarks/ or examples/ takes one.
+    Its forward pass forms the loss AND every gradient for a cotangent of 1
+    (the weights sit INSIDE the row sums of ``d_kernel = sum_rows w h
+    (softmax - hit) / N``, so they are an argument of the loop, not a
+    rescaling after it); the gradient of the weights is the per-row cross
+    entropies over N, which the same loop hands back. Forward mode (``jvp``
+    / ``jacfwd`` / ``linearize``) and second derivatives (``hessian``)
+    through this loss are still not available, weighted or not; nothing in
+    horovod_tpu, benchmarks/ or examples/ takes one.
 
     A device profile shows the loop under ``hvd_lm_head``.
     """
     with jax.named_scope(device_names.LM_HEAD):
-        return _checked_lm_loss(hidden, head_kernel, targets, chunk)
+        return _checked_lm_loss(hidden, head_kernel, targets, chunk, weights)
 
 
-def _checked_lm_loss(hidden, head_kernel, targets, chunk):
+def _checked_lm_loss(hidden, head_kernel, targets, chunk, weights=None):
     """:func:`chunked_lm_loss` under no name of its own: its caller's."""
-    b, t, d = hidden.shape
+    t = hidden.shape[-2]
     if chunk <= 0:
         raise ValueError(f"loss chunk must be positive, got {chunk}")
     chunk = min(chunk, t)
     if t % chunk:
         raise ValueError(f"sequence {t} not divisible by loss chunk {chunk}")
-    return _chunked_lm_loss(hidden, head_kernel, targets, chunk)
+    if weights is None:
+        if hidden.ndim != 3:
+            raise ValueError(f"hidden states {hidden.shape} with a leading "
+                             f"axis of passes need their weights")
+        return _chunked_lm_loss(hidden, head_kernel, targets, chunk)
+    if (weights.shape != hidden.shape[:-1] or hidden.ndim not in (3, 4)
+            or hidden.shape[-3:-1] != targets.shape):
+        raise ValueError(
+            f"weights {weights.shape} must be a number a row of the hidden "
+            f"states {hidden.shape}, (B, T, d) or (P, B, T, d) against "
+            f"targets {targets.shape}")
+    if hidden.ndim == 3:
+        hidden, weights = hidden[None], weights[None]
+    return _weighted_lm_loss(hidden, head_kernel, targets,
+                             weights.astype(jnp.float32), chunk)
 
 
 def lm_loss_with_mtp(hidden, mtp_hidden, head_kernel, tokens,
@@ -1215,10 +1342,55 @@ def lm_loss_with_mtp(hidden, mtp_hidden, head_kernel, tokens,
     return main + mtp_weight * mtp, (main, mtp)
 
 
-def _loss_chunks(hidden, targets, chunk):
+def exit_log_distribution(gate_logits):
+    """``log p`` (P, ...) float32 of a looped model's exit distribution from
+    its gates' logits (P, ...): with ``lambda_t = sigmoid(gate_logits[t])``,
+    ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` for t < P and ``p_P =
+    prod_{j<P} (1 - lambda_j)``: the last pass takes what is left, and its
+    own gate is read by nothing (no gradient reaches it from here). In logs,
+    ``log lambda = -softplus(-g)`` and ``log (1 - lambda) = -softplus(g)``:
+    finite for every finite gate, so that ``p log p`` has no 0 x inf."""
+    g = gate_logits.astype(jnp.float32)
+    g, none = g[:-1], jnp.zeros_like(g[:1])
+    stay = -jax.nn.softplus(g)                          # log (1 - lambda_j)
+    stayed = jnp.concatenate([none, jnp.cumsum(stay, 0)])
+    leave = jnp.concatenate([-jax.nn.softplus(-g), none])
+    return stayed + leave
+
+
+def loop_lm_loss(hidden, gate_logits, head_kernel, targets, beta: float,
+                 chunk: int = 2048):
+    """``(L, parts)`` of a looped model (``TransformerLM.passes`` with
+    ``exit_gate``; ``hidden`` (P, B, T, d) and ``gate_logits`` (P, B, T) from
+    ``return_hidden=True``): the next-token loss EXPECTED over the exit
+    distribution a token that the gates define
+    (:func:`exit_log_distribution`), less ``beta`` times that distribution's
+    entropy (arXiv:2510.25741's entropy-regularised objective): ``L = mean
+    over tokens of [sum_t p_t CE_t - beta H(p)]``, ``H(p) = -sum_t p_t log
+    p_t``. The P readings of the one head are ONE weighted
+    :func:`chunked_lm_loss` with ``p`` as its weights: the gates receive the
+    per-token cross entropies as their gradient through it. ``parts``:
+    ``expected`` (the first term), ``entropy`` (the mean ``H``) and
+    ``exit_mass`` ((P,): the mean ``p_t`` over the tokens). The gates, the
+    distribution and the entropy are float32 and go by ``hvd_loop_exit``, the
+    head's passes and their loss by ``hvd_lm_head``."""
+    with jax.named_scope(device_names.LOOP_EXIT):
+        log_p = exit_log_distribution(gate_logits)
+        p = jnp.exp(log_p)
+    expected = chunked_lm_loss(hidden, head_kernel, targets, chunk, weights=p)
+    with jax.named_scope(device_names.LOOP_EXIT):
+        entropy = -jnp.mean(jnp.sum(p * log_p, axis=0))
+        mass = jax.lax.stop_gradient(jnp.mean(p, axis=tuple(range(1, p.ndim))))
+    return expected - beta * entropy, {"expected": expected,
+                                       "entropy": entropy, "exit_mass": mass}
+
+
+def _loss_chunks(hidden, targets, chunk, weights=None):
     """``(n, b * chunk, d)`` hidden states and ``(n, b * chunk)`` targets:
     iteration ``i`` holds tokens ``[i * chunk, (i + 1) * chunk)`` of every
-    sequence, as one block of rows."""
+    sequence, as one block of rows. With ``weights`` (P, b, t) under hidden
+    states (P, b, t, d): ``P * n`` iterations, pass by pass, each pass
+    against the same targets, and the weights a row as a third array."""
     b, t = targets.shape
     n = t // chunk
 
@@ -1226,56 +1398,89 @@ def _loss_chunks(hidden, targets, chunk):
         return (x.reshape(b, n, chunk, *x.shape[2:]).swapaxes(0, 1)
                 .reshape(n, b * chunk, *x.shape[2:]))
 
-    return rows(hidden), rows(targets)
+    if weights is None:
+        return rows(hidden), rows(targets)
+
+    def passes(x):
+        return jax.vmap(rows)(x).reshape(-1, b * chunk, *x.shape[3:])
+
+    return (passes(hidden), jnp.tile(rows(targets), (hidden.shape[0], 1)),
+            passes(weights))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _chunked_lm_loss(hidden, head_kernel, targets, chunk):
+def _loss_alone(hidden, head_kernel, targets, chunk, weights=None):
+    """The loss where nobody asks for its gradients: one vocabulary product
+    a chunk (and pass), no accumulator."""
     import optax
 
     from ..metrics import record_chunked_loss_plan
 
     record_chunked_loss_plan(1)
 
-    def one(ht):
-        hc, tc = ht
+    def one(block):
+        hc, tc, *wc = block
         logits = hc.astype(jnp.float32) @ head_kernel    # (rows, vocab)
-        return optax.softmax_cross_entropy_with_integer_labels(
-            logits, tc).mean()
+        ce = optax.softmax_cross_entropy_with_integer_labels(logits, tc)
+        return (wc[0] * ce).mean() if wc else ce.mean()
 
-    return jax.lax.map(one, _loss_chunks(hidden, targets, chunk)).mean()
+    losses = jax.lax.map(one, _loss_chunks(hidden, targets, chunk, weights))
+    if weights is None:
+        return losses.mean()
+    return losses.reshape(hidden.shape[0], -1).mean(1).sum()
 
 
-def _chunked_lm_loss_fwd(hidden, head_kernel, targets, chunk):
-    """The loss AND its two gradients (for a cotangent of 1) from one scan
-    over blocks of rows; the residuals are the gradients themselves."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _chunked_lm_loss(hidden, head_kernel, targets, chunk):
+    return _loss_alone(hidden, head_kernel, targets, chunk)
+
+
+def _loss_and_gradients(hidden, head_kernel, targets, chunk, weights=None):
+    """The loss AND its gradients (for a cotangent of 1) from one scan over
+    blocks of rows: ``(loss, (d_hidden, d_kernel))``, and with ``weights``
+    (hidden states (P, b, t, d)) ``(loss, (d_hidden, d_kernel, d_weights))``,
+    the weight a row inside both products' row sums and the per-row cross
+    entropies over ``b * t`` handed back as the weights' gradient."""
     from ..metrics import record_chunked_loss_plan
 
     record_chunked_loss_plan(3)
-    b, t, d = hidden.shape
+    b, t = targets.shape
+    d = hidden.shape[-1]
     vocab = head_kernel.shape[-1]
 
-    def one(d_kernel, ht):
-        hc, tc = ht
+    def one(d_kernel, block):
+        hc, tc, *wc = block
         hf = hc.astype(jnp.float32)
         logits = hf @ head_kernel                        # (rows, vocab)
         shifted = logits - logits.max(-1, keepdims=True)
         e = jnp.exp(shifted)
         z = e.sum(-1, keepdims=True)
         hit = jax.lax.broadcasted_iota(tc.dtype, shifted.shape, 1) == tc[:, None]
-        loss = (jnp.log(z[:, 0])
-                - jnp.where(hit, shifted, 0.0).sum(-1)).mean()
-        dlogits = (e / z - hit) / (b * t)
+        ce = jnp.log(z[:, 0]) - jnp.where(hit, shifted, 0.0).sum(-1)
+        loss = (wc[0] * ce).mean() if wc else ce.mean()
+        dlogits = e / z - hit
+        if wc:
+            dlogits = dlogits * wc[0][:, None]
+        dlogits = dlogits / (b * t)
         d_hidden = (dlogits @ head_kernel.T).astype(hidden.dtype)
         d_kernel = d_kernel + jnp.tensordot(hf, dlogits, ((0,), (0,)))
+        if wc:
+            return d_kernel, (loss, d_hidden, ce / (b * t))
         return d_kernel, (loss, d_hidden)
 
-    d_kernel, (losses, d_hidden) = jax.lax.scan(
+    d_kernel, (losses, d_hidden, *d_weights) = jax.lax.scan(
         one, jnp.zeros((d, vocab), jnp.float32),
-        _loss_chunks(hidden, targets, chunk))
-    d_hidden = d_hidden.reshape(-1, b, chunk, d).swapaxes(0, 1)
-    return losses.mean(), (d_hidden.reshape(b, t, d),
-                           d_kernel.astype(head_kernel.dtype))
+        _loss_chunks(hidden, targets, chunk, weights))
+    d_kernel = d_kernel.astype(head_kernel.dtype)
+    if weights is None:
+        d_hidden = d_hidden.reshape(-1, b, chunk, d).swapaxes(0, 1)
+        return losses.mean(), (d_hidden.reshape(b, t, d), d_kernel)
+
+    def unchunked(x):       # (P * n, b * chunk, ...) -> (P, b, t, ...)
+        x = x.reshape(hidden.shape[0], -1, b, chunk, *x.shape[2:])
+        return x.swapaxes(1, 2).reshape(hidden.shape[0], b, t, *x.shape[4:])
+
+    return (losses.reshape(hidden.shape[0], -1).mean(1).sum(),
+            (unchunked(d_hidden), d_kernel, unchunked(d_weights[0])))
 
 
 def _chunked_lm_loss_bwd(chunk, gradients, g):
@@ -1284,7 +1489,28 @@ def _chunked_lm_loss_bwd(chunk, gradients, g):
             (g * d_kernel).astype(d_kernel.dtype), None)
 
 
-_chunked_lm_loss.defvjp(_chunked_lm_loss_fwd, _chunked_lm_loss_bwd)
+# the residuals are the gradients themselves
+_chunked_lm_loss.defvjp(_loss_and_gradients, _chunked_lm_loss_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _weighted_lm_loss(hidden, head_kernel, targets, weights, chunk):
+    """``(1 / (b t)) sum_{pass, row} weights CE`` of hidden states (P, b, t,
+    d) under weights (P, b, t)."""
+    return _loss_alone(hidden, head_kernel, targets, chunk, weights)
+
+
+def _weighted_lm_loss_fwd(hidden, head_kernel, targets, weights, chunk):
+    return _loss_and_gradients(hidden, head_kernel, targets, chunk, weights)
+
+
+def _weighted_lm_loss_bwd(chunk, gradients, g):
+    d_hidden, d_kernel, d_weights = gradients
+    return ((g * d_hidden).astype(d_hidden.dtype),
+            (g * d_kernel).astype(d_kernel.dtype), None, g * d_weights)
+
+
+_weighted_lm_loss.defvjp(_weighted_lm_loss_fwd, _weighted_lm_loss_bwd)
 
 
 def tp_param_specs(params, tp_axis: str = "tp"):

@@ -883,10 +883,13 @@ def test_the_three_convolutions_side_by_side_compile_for_v5e(
 LOSS_CHUNK = 2048
 
 # (B, T, d) hidden states x vocabulary, and whether the kernel is a transposed
-# embedding, of lfm2_seq8192_1chip, kanana2_seq8192_1chip, olmoe_seq4096_1chip
+# embedding, of lfm2_seq8192_1chip, kanana2_seq8192_1chip, olmoe_seq4096_1chip;
+# and ouro_seq8192_1chip's, whose one loop reads the head once a PASS under
+# weights a row (a fourth entry: the passes)
 CELL_HEADS = {"lfm2": ((2, 8192, 2048), 8192, True),
               "kanana2": ((2, 8192, 2048), 16032, False),
-              "olmoe": ((4, 4096, 2048), 50304, False)}
+              "olmoe": ((4, 4096, 2048), 50304, False),
+              "ouro": ((1, 8192, 2048), 49152, False, 4)}
 
 
 def _loop_bodies(text, scope):
@@ -924,24 +927,30 @@ def test_loss_loop_writes_its_logits_once(one_chip, no_persistent_cache,
     max`` WRITTEN BACK at the logits' size: 47 of the cell's 57 ms of
     ``hvd_lm_head`` a step (ledger, PR 65). Handed the same rows merged,
     ``(batch * chunk, d)``, it fuses the row max into the product's epilogue
-    and reads the logits once for both sums, at every shape here (PR 66)."""
+    and reads the logits once for both sums, at every shape here (PR 66);
+    so does the WEIGHTED loop over (pass, chunk) of a looped model's four
+    readings (PR 67), which multiplies the logits' gradient by a weight a
+    row."""
     from horovod_tpu.common.device_names import LM_HEAD
     from horovod_tpu.models.transformer import chunked_lm_loss
 
-    (b, t, d), vocab, tied = CELL_HEADS[cell]
+    (b, t, d), vocab, tied, *passes = CELL_HEADS[cell]
     rows = b * LOSS_CHUNK
 
-    def loss(hidden, kernel, targets):
+    def loss(hidden, kernel, targets, *weights):
         return chunked_lm_loss(hidden, kernel.T if tied else kernel, targets,
-                               LOSS_CHUNK)
+                               LOSS_CHUNK, *weights)
 
     def shape(*dims, of):
         return jax.ShapeDtypeStruct(dims, of, sharding=one_chip)
 
-    text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
-        shape(b, t, d, of=jnp.bfloat16),
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 3) if passes else (0, 1))
+                   ).lower(
+        shape(*passes, b, t, d, of=jnp.bfloat16),
         shape(*((vocab, d) if tied else (d, vocab)), of=jnp.float32),
-        shape(b, t, of=jnp.int32)).compile().as_text()
+        shape(b, t, of=jnp.int32),
+        *[shape(*passes, b, t, of=jnp.float32)] * len(passes)
+    ).compile().as_text()
     (body,) = _loop_bodies(text, LM_HEAD)
 
     def logits_sized(dtype, dims):
@@ -951,6 +960,13 @@ def test_loss_loop_writes_its_logits_once(one_chip, no_persistent_cache,
     writers = {name: sum(logits_sized(*out) for out in outs)
                for name, outs in _fusion_outputs(body)}
     assert len(writers) >= 4, f"the loop's body was not read: {writers}"
+    if d == rows:
+        # a row block as tall as the model is wide (ouro's 2,048): the
+        # kernel's float32 gradient, (d, vocab), has the logits' size, and
+        # the product that adds into it is no writer of logits
+        (accumulate,) = (name for name, n in writers.items()
+                         if n and "convolution_add" in name)
+        del writers[accumulate]
     assert sum(writers.values()) == 1, (
         f"{rows} x {vocab} float32 written by {writers}: the loop's logits "
         "leave one fusion, their product's, and no softmax pass writes "

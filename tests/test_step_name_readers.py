@@ -32,7 +32,7 @@ LM_CELLS = {"lm217m_long_1chip", "lm217m_short_1chip", "olmoe_seq4096_1chip",
             "laguna_xs2_seq16384_1chip", "nemotron3s_seq8192_1chip",
             "keye_vl2_seq16384_1chip", "kimi_linear_seq16384_1chip",
             "solar_open2_seq8192_1chip", "lfm2_seq8192_1chip",
-            "olmo_hybrid_seq16384_1chip"}
+            "olmo_hybrid_seq16384_1chip", "ouro_seq8192_1chip"}
 PINNED_CELLS = {"laguna_xs2_seq16384_1chip", "keye_vl2_seq16384_1chip"}
 
 
@@ -93,6 +93,20 @@ def test_a_reader_reads_its_one_name(name, scope, named_device_time,
     # a program older than the name: nothing, and no error
     del seconds[scope]
     assert load_reader(name).read({}) is None
+
+
+def test_the_loop_exit_reader_reads_its_one_name(named_device_time,
+                                                  monkeypatch):
+    """``loop_exit_ms_per_step`` (PR 67) is not among the nine: the cell that
+    added it lists it alone. It reads ``hvd_loop_exit`` and no other name."""
+    seconds = {n: 0.0 for n in device_names.ALL}
+    seconds[device_names.LOOP_EXIT] = 0.002
+    seconds[device_names.LM_HEAD] = 0.25    # the head's passes are not the exit's
+    monkeypatch.setattr(named_device_time, "_tables", [
+        {"seconds": seconds, "unnamed": 0.5, "busy": 1.0}])
+    assert load_reader("loop_exit_ms_per_step").read({}) == 2.0
+    del seconds[device_names.LOOP_EXIT]     # a program older than the name
+    assert load_reader("loop_exit_ms_per_step").read({}) is None
 
 
 def test_the_named_share_on_a_v5e_trace(named_device_time, tmp_path):
