@@ -51,6 +51,8 @@ from .overlap import (  # noqa: F401
     record_moe_dispatch_rows,
     record_moe_grouped_plan,
     record_moe_live_rows,
+    record_moe_router_recomputed,
+    record_moe_router_saved_bytes,
     record_plan,
     record_shard_plan,
     record_short_conv_plan,

@@ -278,6 +278,40 @@ def record_moe_dispatch_rows(rows: int, row_bytes: int) -> None:
     ).set(rows)
 
 
+def record_moe_router_saved_bytes(saved_bytes: int) -> None:
+    """Record what the backward of the latest traced
+    ``ops.moe.sigmoid_route_tokens`` keeps of its router (trace time, once
+    per compile, from the call's own shapes): the bytes of the two arrays it
+    names, the sigmoid scores at the chosen experts and the weights, ``2 x N
+    x top_k x 4`` - where plain autodiff kept the ``N x E`` scores. A caller
+    that recomputes the layer saves them (with the chosen experts) and runs
+    no router again; 0 until a sigmoid-routed layer is traced."""
+    registry().gauge(
+        "horovod_moe_router_saved_bytes_per_layer",
+        help="bytes of the chosen scores and the weights, (N, top_k) float32 "
+             "each, that the latest traced sigmoid router names for its "
+             "backward (one call = one layer); 0 = none traced"
+    ).set(saved_bytes)
+
+
+def record_moe_router_recomputed(recomputed: bool) -> None:
+    """Record whether the backward pass of the latest traced sigmoid-routed
+    layer runs its router again (trace time, once per compile). The forward
+    rule of ``ops.moe.sigmoid_route_tokens`` says 1 where ``jax.checkpoint``
+    recomputes the layer; a policy of ``ops.moe.save_names`` that holds
+    ``ops.moe.ROUTER_SAVED`` says 0 when it is asked about the layer's
+    chosen scores, which is after (``TransformerLM(remat=True)``). So 1 =
+    recomputed under a policy that does not save them by that function: four
+    float32 router products a layer and not three."""
+    registry().gauge(
+        "horovod_moe_router_recomputed",
+        help="1 = the latest traced sigmoid-routed layer is recomputed "
+             "(jax.checkpoint) under a policy that does not save its router's "
+             "names (ops.moe.save_names): its router runs again in the "
+             "backward pass; 0 = saved, or nothing recomputed"
+    ).set(int(recomputed))
+
+
 def record_moe_live_rows(live_rows, window: int) -> None:
     """Record what the expert layers' passes REALLY visited in the steps a
     training loop hands in: ``live_rows`` is (steps, layers) of the

@@ -58,8 +58,9 @@ import jax
 import jax.numpy as jnp
 
 from ..common import device_names
-from ..ops.moe import (_expert_counts, dropless_experts, router_z_loss,
-                       sigmoid_route, topk_load_balancing_loss, topk_route)
+from ..ops.moe import (_expert_counts, dropless_experts, router_logits,
+                       router_z_loss, sigmoid_route, sigmoid_route_tokens,
+                       topk_load_balancing_loss, topk_route)
 
 BIAS_COLLECTION = "moe_bias"    # the sigmoid router's bias: state, not params
 
@@ -142,25 +143,22 @@ class MoEMLP(nn.Module):
         # by a cast under ``hvd_moe_weight_cast`` before ``lax.ragged_dot``).
         experts_w = {name: self.param(name, init, shape, jnp.float32)
                      for name, shape in shapes.items()}
-        # The router runs in float32 at full precision whatever the
-        # activations' dtype: 2*N*D*E operations, and a coarser product
-        # flips a token's 8th expert against its 9th far more often.
-        with jax.named_scope(device_names.MOE_LOGITS):
-            logits = jnp.dot(tokens.astype(jnp.float32), router,
-                             precision=jax.lax.Precision.HIGHEST)
         if self.router == "sigmoid":
             bias = self.variable(BIAS_COLLECTION, "router_bias", jnp.zeros,
                                  (e,), jnp.float32).value
-            # the epsilon only where one is stated: the benchmark's tests swap
-            # this function for variants of today's four arguments
-            stated = () if self.route_eps == 1e-20 else (self.route_eps,)
-            _, weights, experts = sigmoid_route(logits, bias, self.top_k,
-                                                self.route_scale, *stated)
+            # The product and the rule as ONE unit, whose backward is formed
+            # from the (N, top_k) chosen scores: a recomputed block reads
+            # them from memory and runs no router. The rule is this module's
+            # name at the time of the call: the benchmark's tests swap it.
+            logits, weights, experts = sigmoid_route_tokens(
+                tokens, router, bias, self.top_k, self.route_scale,
+                self.route_eps, sigmoid_route)
             counts = _expert_counts(experts.reshape(-1), e)
             self.sow("intermediates", "moe_expert_counts", counts)
             self.sow("intermediates", "moe_live_rows",
                      jnp.sum(counts[first:first + here]))
         else:
+            logits = router_logits(tokens, router)
             probs, weights, experts = (
                 topk_route(logits, self.top_k, True) if self.norm_topk
                 else topk_route(logits, self.top_k))    # OLMoE's call, as ever

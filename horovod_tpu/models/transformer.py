@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common import device_names
-from ..ops.moe import CHOSEN_EXPERTS
+from ..ops.moe import ROUTER_SAVED, save_names
 from ..ops.sparse_attention import ALIGN_GRADS, SELECTED
 from .gdn import GDNDims, GDNMixer
 from .kda import KDADims, KDAMixer
@@ -1068,14 +1068,16 @@ class TransformerLM(nn.Module):
                 x = x * jnp.asarray(self.embedding_multiplier, x.dtype)
         block_cls = Block
         if self.remat:
-            # the routers' choice and attention's selection are saved, never
-            # recomputed (ops/moe.py, ops/sparse_attention.py); so are the
-            # alignment loss's gradients, which its forward pass produced
-            saved = ([CHOSEN_EXPERTS] if self.moe_experts > 0 else []) + (
+            # What the routers chose and attention's selection are saved,
+            # never recomputed (ops/moe.py, ops/sparse_attention.py): the
+            # chosen experts and, of a sigmoid router, the scores at them and
+            # the weights, (N, top_k) each, from which its backward is formed
+            # - so a recomputed expert layer runs no router product. So are
+            # the alignment loss's gradients, which its forward pass produced.
+            saved = (list(ROUTER_SAVED) if self.moe_experts > 0 else []) + (
                 [SELECTED, ALIGN_GRADS] if self.sparse is not None else [])
-            block_cls = nn.remat(Block, policy=(
-                jax.checkpoint_policies.save_only_these_names(*saved)
-                if saved else None))
+            block_cls = nn.remat(
+                Block, policy=save_names(*saved) if saved else None)
 
         def block(kind, name, heads, second_is_experts):
             """One layer of ``kind``; ``second_is_experts``: whether a layer

@@ -59,6 +59,7 @@ from __future__ import annotations
 
 import functools
 import operator
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +79,16 @@ from . import grouped_matmul as gm
 # forward ran (seen on the v5e: one token of 2,048, up to 5% of the largest
 # entry of the layer's gradients; PERF.md, PR 36).
 CHOSEN_EXPERTS = "moe_chosen_experts"
+# What :func:`sigmoid_route_tokens` names beside them, (N, top_k) float32
+# each: the sigmoid scores AT the chosen experts, from which alone its
+# backward is formed, and the weights the combine multiplies by. With the
+# three saved, a recomputed layer reads what its router gave from memory, and
+# the router's product, the sigmoid over (N, E) and the one-hot sums are dead
+# in it: three float32 products at ``highest`` a layer (the forward and the
+# backward's two), not four (PERF.md, PR 68).
+CHOSEN_SCORES = "moe_chosen_scores"
+CHOSEN_WEIGHTS = "moe_chosen_weights"
+ROUTER_SAVED = (CHOSEN_EXPERTS, CHOSEN_SCORES, CHOSEN_WEIGHTS)
 
 
 def topk_route(logits, top_k: int, renormalise: bool = False):
@@ -100,6 +111,14 @@ def topk_route(logits, top_k: int, renormalise: bool = False):
     return probs, weights, experts
 
 
+def _at_chosen(scores, experts):
+    """``scores`` (N, E) at ``experts`` (N, top_k), through a one-hot
+    product and not ``top_k``'s values: the backward is then a product too,
+    where ``top_k``'s is a scatter-add of N x top_k scalars."""
+    onehot = experts[:, :, None] == jnp.arange(scores.shape[-1])
+    return jnp.sum(jnp.where(onehot, scores[:, None, :], 0.0), axis=-1)
+
+
 def sigmoid_route(logits, bias, top_k: int, scale: float,
                   eps: float = 1e-20):
     """DeepSeek-V3's router with one group: scores ``sigmoid(logits)`` in
@@ -119,12 +138,126 @@ def sigmoid_route(logits, bias, top_k: int, scale: float,
         experts = checkpoint_name(
             lax.top_k(scores + lax.stop_gradient(bias), top_k)[1],
             CHOSEN_EXPERTS)
-        # the weights through a one-hot product, as in topk_route
-        onehot = experts[:, :, None] == jnp.arange(scores.shape[-1])
-        weights = jnp.sum(jnp.where(onehot, scores[:, None, :], 0.0), axis=-1)
+        weights = _at_chosen(scores, experts)
         weights = scale * weights / (jnp.sum(weights, axis=-1, keepdims=True)
                                      + eps)
     return scores, weights, experts
+
+
+def router_logits(tokens, router):
+    """``tokens @ router`` in float32 at full precision whatever the
+    activations' dtype: 2*N*D*E operations, and a coarser product flips a
+    token's 8th expert against its 9th far more often."""
+    with jax.named_scope(device_names.MOE_LOGITS):
+        return jnp.dot(tokens.astype(jnp.float32), router,
+                       precision=lax.Precision.HIGHEST)
+
+
+def _under_checkpoint() -> bool:
+    """Whether ``jax.checkpoint`` (``nn.remat``) is tracing or
+    differentiating the caller: one of its frames is on the stack. JAX has
+    no public question for it; only a gauge hangs on the answer."""
+    frame = sys._getframe(1)
+    while frame is not None:
+        if frame.f_code.co_filename.endswith("ad_checkpoint.py"):
+            return True
+        frame = frame.f_back
+    return False
+
+
+def save_names(*names):
+    """``jax.checkpoint_policies.save_only_these_names(*names)``; where the
+    names hold :data:`ROUTER_SAVED`, a policy that besides tells
+    ``horovod_moe_router_recomputed`` when it is asked about a sigmoid
+    router's chosen scores and saves them: that layer's router is not run
+    again."""
+    policy = jax.checkpoint_policies.save_only_these_names(*names)
+    if not set(ROUTER_SAVED) <= set(names):
+        return policy
+    from ..metrics import record_moe_router_recomputed
+
+    def observed(prim, *avals, **params):
+        if params.get("name") == CHOSEN_SCORES:
+            record_moe_router_recomputed(False)
+        return policy(prim, *avals, **params)
+
+    return observed
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def sigmoid_route_tokens(tokens, router, bias, top_k: int, scale: float,
+                         eps: float, route):
+    """The router's product and the sigmoid routing rule as ONE
+    differentiable unit: ``route(router_logits(tokens, router), bias, top_k,
+    scale[, eps])`` with ``route`` :func:`sigmoid_route` (the epsilon handed
+    on only where it is not the default's ``1e-20``) or a function of its
+    signature that forms the stated weights on an expert set of its own.
+    Returns (logits (N, E), weights (N, top_k), experts (N, top_k)).
+
+    Its backward is formed from ``tokens``, ``router``, the experts and the
+    sigmoid scores AT them as the call returned them, (N, top_k), never from
+    the (N, E) scores: ``w = scale c / (sum c + eps)`` and ``dc/dl = c (1 -
+    c)`` at the chosen columns, a cotangent on ``logits`` added, then the two
+    float32 products at ``highest`` that autodiff issues. ``bias`` receives
+    none. The forward rule names the chosen scores and the weights
+    (:data:`CHOSEN_SCORES`, :data:`CHOSEN_WEIGHTS`) and the experts again
+    (:data:`CHOSEN_EXPERTS`: a ``route`` of the caller's may not have), so a
+    caller that recomputes the layer under a policy that saves
+    :data:`ROUTER_SAVED` (:func:`save_names`) runs no router in the
+    recomputation. Forward mode (``jax.jvp``, ``jax.jacfwd``) raises, as for
+    any ``jax.custom_vjp``."""
+    return _route_tokens(tokens, router, bias, top_k, scale, eps, route)[0]
+
+
+def _route_tokens(tokens, router, bias, top_k, scale, eps, route):
+    """(what the unit returns, what its backward keeps)."""
+    logits = router_logits(tokens, router)
+    # the epsilon only where one is stated: the benchmark's tests hand in
+    # variants of sigmoid_route's first four arguments
+    stated = () if eps == 1e-20 else (eps,)
+    scores, weights, experts = route(logits, bias, top_k, scale, *stated)
+    with jax.named_scope(device_names.MOE_ROUTE):
+        experts = checkpoint_name(experts, CHOSEN_EXPERTS)
+        chosen = checkpoint_name(_at_chosen(scores, experts), CHOSEN_SCORES)
+        weights = checkpoint_name(weights, CHOSEN_WEIGHTS)
+    return (logits, weights, experts), (tokens, router, experts, chosen)
+
+
+def _route_tokens_fwd(tokens, router, bias, top_k, scale, eps, route):
+    from ..metrics import (record_moe_router_recomputed,
+                           record_moe_router_saved_bytes)
+
+    out, kept = _route_tokens(tokens, router, bias, top_k, scale, eps, route)
+    (_, weights, _), (*_, chosen) = out, kept
+    record_moe_router_saved_bytes(
+        sum(named.size * named.dtype.itemsize for named in (chosen, weights)))
+    # a policy that saves the names says so after this (save_names)
+    record_moe_router_recomputed(_under_checkpoint())
+    return out, kept
+
+
+def _route_tokens_bwd(top_k, scale, eps, route, residuals, cotangents):
+    tokens, router, experts, chosen = residuals
+    d_logits, d_weights, _ = cotangents
+    with jax.named_scope(device_names.MOE_ROUTE):
+        total = jnp.sum(chosen, axis=-1, keepdims=True) + eps
+        d_chosen = scale / total * (d_weights - jnp.sum(
+            d_weights * chosen, axis=-1, keepdims=True) / total)
+        at_chosen = d_chosen * chosen * (1.0 - chosen)
+        onehot = experts[:, :, None] == jnp.arange(router.shape[-1])
+        d_logits = d_logits + jnp.sum(
+            jnp.where(onehot, at_chosen[:, :, None], 0.0), axis=1)
+    with jax.named_scope(device_names.MOE_LOGITS):
+        d_tokens = lax.dot_general(
+            d_logits, router, (((1,), (1,)), ((), ())),
+            precision=lax.Precision.HIGHEST).astype(tokens.dtype)
+        d_router = lax.dot_general(
+            tokens.astype(jnp.float32), d_logits, (((0,), (0,)), ((), ())),
+            precision=lax.Precision.HIGHEST)
+    return d_tokens, d_router, None
+
+
+sigmoid_route_tokens.defvjp(_route_tokens_fwd, _route_tokens_bwd)
 
 
 def router_bias_update(bias, counts, rate: float):

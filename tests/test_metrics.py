@@ -419,6 +419,49 @@ def test_bucket_overlap_metrics_consistent_with_plan(mesh8):
 # test_measure_overlap_keeps_its_keys_and_gauges).
 
 
+# ------------------------------- the sigmoid router's names under jax.checkpoint
+
+
+@pytest.mark.parametrize("how,recomputed", [
+    ("remat_model", 0), ("bare_checkpoint", 1), ("experts_alone", 1),
+    ("nothing_recomputed", 0)])
+def test_moe_router_gauges_say_whether_the_router_runs_again(how, recomputed):
+    """``horovod_moe_router_recomputed`` (trace time): 0 where
+    ``TransformerLM(remat=True)``'s policy saves the sigmoid router's names
+    (``ops.moe.ROUTER_SAVED``) and where nothing is recomputed, 1 under a bare
+    ``jax.checkpoint`` or a policy without the two new names; beside it the
+    bytes of the two (N, top_k) float32 arrays the layer names."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import metrics
+    from horovod_tpu.models import MoEMLP, TransformerLM
+    from horovod_tpu.ops import moe as ops_moe
+
+    if how == "remat_model":
+        x = jnp.arange(48, dtype=jnp.int32).reshape(2, 24) % 64
+        module = TransformerLM(
+            vocab=64, dim=32, heads=2, layers=2, moe_experts=8, moe_top_k=3,
+            moe_hidden=16, moe_every=1, moe_router="sigmoid",
+            dtype=jnp.float32, remat=True)
+    else:
+        x = jnp.ones((2, 24, 32))
+        module = MoEMLP(dim=32, hidden=16, n_experts=8, top_k=3,
+                        dtype=jnp.float32, router="sigmoid")
+    variables = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)
+
+    def loss(params):   # a new function a case: the gauges are set by a trace
+        return jnp.sum(module.apply({**variables, "params": params}, x) ** 2)
+
+    if how in ("bare_checkpoint", "experts_alone"):
+        loss = jax.checkpoint(loss, policy=None if how == "bare_checkpoint"
+                              else ops_moe.save_names(ops_moe.CHOSEN_EXPERTS))
+    jax.eval_shape(jax.grad(loss), variables["params"])
+    gauges = metrics.registry().snapshot()["gauges"]
+    assert gauges["horovod_moe_router_recomputed"] == recomputed
+    assert gauges["horovod_moe_router_saved_bytes_per_layer"] == 2 * 48 * 3 * 4
+
+
 # ------------------------------------------------------- runner aggregation
 
 

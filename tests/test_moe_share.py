@@ -561,3 +561,167 @@ def test_a_recomputed_block_keeps_the_forwards_chosen_experts(router):
     for got, want in zip(*(jax.tree_util.tree_leaves(f(variables["params"]))
                            for f in (recomputed, plain))):
         close(got, want, 1e-5)
+
+
+# ----------------------------------------- the router, run once under remat
+
+def _router_model(remat, router="sigmoid", **fields):
+    from horovod_tpu.models import TransformerLM
+
+    # 32 tokens over 8 experts: (32, 8) is the router's shape alone
+    return TransformerLM(vocab=64, dim=32, heads=2, layers=3, moe_experts=8,
+                         moe_top_k=2, moe_hidden=16, moe_every=1,
+                         moe_router=router, dtype=jnp.float32, remat=remat,
+                         **fields)
+
+
+def _router_products(program):
+    """The router's float32 products at ``highest``, its logits and its
+    backward's two: in a jaxpr (walked through its sub-jaxprs) the products
+    with an (N, E)- or (D, E)-shaped operand or result, in a compiled
+    module's text the ``dot`` instructions under ``hvd_moe_logits``."""
+    if isinstance(program, str):
+        return sum(" dot(" in line and "hvd_moe_logits" in line
+                   and "operand_precision={highest,highest}" in line
+                   for line in program.splitlines())
+    found = 0
+    for eqn in program.eqns:
+        if (eqn.primitive.name == "dot_general" and eqn.params["precision"]
+                == (jax.lax.Precision.HIGHEST,) * 2):
+            found += any(v.aval.shape == (32, 8)
+                         for v in (*eqn.invars, *eqn.outvars))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _router_products(sub)
+    return found
+
+
+ROUTER_CASES = {
+    "as_published": {},
+    "eps_1e-6": {"moe_route_eps": 1e-6},
+    "scale_2.5": {"moe_route_scale": 2.5},
+    "bias": {},
+    "held": {"moe_held": (2, 4)},
+    "latent": {"moe_latent": 16, "moe_activation": "relu2"},
+}
+
+
+@pytest.mark.parametrize("case", list(ROUTER_CASES))
+def test_a_recomputed_sigmoid_layer_runs_its_router_once(monkeypatch, case):
+    """``TransformerLM(remat=True)`` saves the sigmoid router's chosen scores
+    and weights beside its chosen experts (``ops.moe.ROUTER_SAVED``), and the
+    router's backward is formed from them: THREE float32 products at
+    ``highest`` a layer (the forward, d-tokens, d-router) in ``jax.grad``'s
+    jaxpr and in the compiled module, one fewer a layer than under a policy
+    without the two new names; the gradients are the unrecomputed model's."""
+    from horovod_tpu.models import transformer
+
+    tokens = jnp.arange(32, dtype=jnp.int32).reshape(2, 16) % 64
+    fields = ROUTER_CASES[case]
+    variables = _router_model(False, **fields).init(jax.random.PRNGKey(0), tokens)
+    if case == "bias":      # a bias that moves the choice
+        variables = {**variables, BIAS_COLLECTION: jax.tree_util.tree_map(
+            lambda b: seeded(b.shape, 3, 0.3), variables[BIAS_COLLECTION])}
+
+    def grad_of(remat):
+        def loss(params):
+            return jnp.sum(_router_model(remat, **fields).apply(
+                {**variables, "params": params}, tokens) ** 2)
+        return jax.jit(jax.grad(loss))
+
+    params = variables["params"]
+    recomputed = grad_of(True)
+    jaxpr = jax.make_jaxpr(recomputed)(params)
+    assert _router_products(jaxpr.jaxpr) == 3 * 3
+    # the sigmoid over (N, E) and the choice: the forward's alone
+    assert str(jaxpr).count("f32[32,8] = logistic") == 3
+    assert str(jaxpr).count(" top_k[") == 3
+    if case == "as_published":
+        compiled = recomputed.lower(params).compile().as_text()
+        assert _router_products(compiled) == 3 * 3
+        assert ops_moe_gauges()["horovod_moe_router_recomputed"] == 0
+        assert ops_moe_gauges()["horovod_moe_router_saved_bytes_per_layer"] == (
+            2 * 32 * 2 * 4)
+    for got, want in zip(*(jax.tree_util.tree_leaves(f(params))
+                           for f in (recomputed, grad_of(False)))):
+        close(got, want, 1e-5)
+    # the policy without the two new names: the router runs again
+    monkeypatch.setattr(transformer, "ROUTER_SAVED", (ops_moe.CHOSEN_EXPERTS,))
+    before = grad_of(True)
+    jaxpr = jax.make_jaxpr(before)(params)
+    assert _router_products(jaxpr.jaxpr) == 3 * 4
+    assert str(jaxpr).count("f32[32,8] = logistic") == 3 * 2
+    if case == "as_published":
+        assert _router_products(before.lower(params).compile().as_text()) == 3 * 4
+        assert ops_moe_gauges()["horovod_moe_router_recomputed"] == 1
+
+
+def test_the_softmax_routers_recomputed_products_are_what_they_were():
+    """``topk_route`` is not the unit's: a recomputed softmax-routed layer
+    runs its router's product again (its losses read the whole
+    probabilities), four a layer, whatever the policy's names."""
+    tokens = jnp.arange(32, dtype=jnp.int32).reshape(2, 16) % 64
+    variables = _router_model(False, "softmax").init(jax.random.PRNGKey(0), tokens)
+
+    def grad_of(remat):
+        def loss(params):
+            return jnp.sum(_router_model(remat, "softmax").apply(
+                {**variables, "params": params}, tokens) ** 2)
+        return jax.grad(loss)
+
+    count = lambda f: _router_products(jax.make_jaxpr(f)(variables["params"]).jaxpr)
+    assert (count(grad_of(False)), count(grad_of(True))) == (3 * 3, 3 * 4)
+
+
+def _written_out(tokens, router, bias, top_k, scale, eps):
+    """What ``sigmoid_route_tokens`` is, for plain autodiff."""
+    logits = jnp.dot(tokens.astype(jnp.float32), router,
+                     precision=jax.lax.Precision.HIGHEST)
+    _, weights, experts = ops_moe.sigmoid_route(logits, bias, top_k, scale, eps)
+    return logits, weights, experts
+
+
+@pytest.mark.parametrize("eps,scale", [(1e-20, 1.0), (1e-6, 2.5)])
+def test_the_routers_rule_against_plain_autodiff(eps, scale):
+    """``jax.grad`` through the unit is ``jax.grad`` through the product and
+    ``sigmoid_route`` written out: for the tokens, the router and with a
+    cotangent on the logits, to float32 rounding; the bias receives none.
+    Token 0's last chosen expert and the next are TIED (two equal router
+    columns, ``ops/moe.py`` over ``CHOSEN_EXPERTS``): both sides
+    differentiate the experts the forward chose."""
+    tokens, router, bias = seeded((24, D), 0, 1.0), seeded((D, E), 1), seeded((E,), 2, 0.2)
+    first = jnp.argsort(-(jax.nn.sigmoid(tokens[0] @ router) + bias))
+    router = router.at[:, first[TOP_K]].set(router[:, first[TOP_K - 1]])
+    bias = bias.at[first[TOP_K]].set(bias[first[TOP_K - 1]])
+    mix, on_logits = seeded((24, TOP_K), 3, 1.0), seeded((24, E), 4, 1.0)
+
+    def loss(route):
+        def f(tokens, router, bias):
+            logits, weights, experts = route(tokens, router, bias)
+            return (jnp.sum(weights * mix * (1 + experts))
+                    + jnp.sum(jnp.tanh(logits) * on_logits)), experts
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2), has_aux=True))
+
+    got, experts = loss(lambda *a: ops_moe.sigmoid_route_tokens(
+        *a, TOP_K, scale, eps, ops_moe.sigmoid_route))(tokens, router, bias)
+    want, reference = loss(lambda *a: _written_out(
+        *a, TOP_K, scale, eps))(tokens, router, bias)
+    np.testing.assert_array_equal(np.asarray(experts), np.asarray(reference))
+    assert set(np.asarray(first[TOP_K - 1:TOP_K + 1])) & set(np.asarray(experts[0]))
+    for g, w in zip(got[:2], want[:2]):
+        assert g.dtype == w.dtype
+        close(g, w, 2e-6)
+    assert not np.any(np.asarray(got[2])) and not np.any(np.asarray(want[2]))
+
+
+def test_the_routers_rule_has_no_forward_mode():
+    """As any ``jax.custom_vjp`` (``chunked_lm_loss`` is another): ``jax.jvp``
+    through the unit raises, and its docstring says so."""
+    tokens, router, bias = seeded((8, D), 0), seeded((D, E), 1), jnp.zeros(E)
+    route = lambda t: ops_moe.sigmoid_route_tokens(
+        t, router, bias, TOP_K, 1.0, 1e-20, ops_moe.sigmoid_route)[1]
+    with pytest.raises(TypeError, match="forward-mode"):
+        jax.jvp(route, (tokens,), (tokens,))
+    assert "Forward mode" in ops_moe.sigmoid_route_tokens.__doc__

@@ -302,6 +302,11 @@ def test_expert_load_gauge_is_set_by_the_helper_alone(hvd):
     logits = np.zeros((8, 4), np.float32)
     logits[:, 0], logits[:, 1] = 3.0, 2.0          # everyone picks 0 and 1
     assert moe_ops.record_expert_load(logits, 2) == pytest.approx(2.0)
+    # what a sigmoid-routed layer of another test in this process set: a
+    # softmax layer's trace sets neither
+    for name in ("horovod_moe_router_recomputed",
+                 "horovod_moe_router_saved_bytes_per_layer"):
+        hvd.metrics.registry().remove(name)
     layer, x = one_layer([0.0] * 8)
     # tracing and running the layer leaves the load as the helper set it; the
     # two gauges a trace sets say which path the grouped products took
